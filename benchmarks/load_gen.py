@@ -416,7 +416,6 @@ def main():
         port = int(port)
         edge = driver = None
     else:
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
         router, driver, edge, mk_engine = build_fleet(
             args.replicas, args.batch,
             max_seq_len=2 * (args.prompt_len + args.max_new) + 32,

@@ -123,8 +123,9 @@ def main():
     rows = {}
     for impl in ("einsum", "grouped"):
         rows[impl] = round(bench_path(impl, **shape), 1)
-    # EP ring on the virtual mesh: separate process (the backend must be
-    # forced to CPU before jax initializes)
+    # EP ring on the virtual mesh: a child PINNED TO THE CPU (the backend
+    # must be forced before jax initializes) — it never asks for the chip
+    # this process holds, so one process still owns each chip
     import subprocess
     ep_row = None
     try:

@@ -32,12 +32,11 @@ def get_accelerator():
         accelerator_name = os.environ["DS_ACCELERATOR"]
         _validate_accelerator(accelerator_name)
     else:
-        try:
-            import jax
-            platforms = {d.platform for d in jax.devices()}
-            accelerator_name = "tpu" if "tpu" in platforms else "cpu"
-        except Exception:
-            accelerator_name = "cpu"
+        # a backend that fails to come up is an error, not "cpu": the CPU
+        # is asked for explicitly (JAX_PLATFORMS=cpu, as the tests do)
+        import jax
+        platforms = {d.platform for d in jax.devices()}
+        accelerator_name = "tpu" if "tpu" in platforms else "cpu"
 
     from .tpu_accelerator import CPU_Accelerator, TPU_Accelerator
     if accelerator_name == "tpu":

@@ -14,10 +14,7 @@ class TPU_Accelerator(DeepSpeedAccelerator):
 
     def is_available(self):
         import jax
-        try:
-            return any(d.platform == "tpu" for d in jax.devices())
-        except Exception:
-            return False
+        return any(d.platform == "tpu" for d in jax.devices())
 
     def device_name(self, device_index=None):
         if device_index is None:

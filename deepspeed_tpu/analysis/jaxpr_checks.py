@@ -18,10 +18,10 @@ and walks the resulting ``ClosedJaxpr``:
   every collective names an axis that is manual on the enclosing mesh,
   every ``ppermute`` permutation is a true permutation (distinct sources,
   distinct targets, no data created or lost), and every output DECLARED
-  replicated (empty out_names) is replica-invariant by dataflow — a taint
+  replicated (empty out spec) is replica-invariant by dataflow — a taint
   pass seeded at sharded inputs and ``axis_index``, cleared only by a
   collective reduction over the tainted axis. This is the static
-  replacement for the ``check_rep=False`` the frame loops compile with.
+  replacement for the ``check_vma=False`` the frame loops compile with.
   Scope note: a *dropped* psum whose surrounding program still reduces
   later produces replica-invariant-but-WRONG values — that is a parity
   bug the dynamic token-parity suites own; this pass owns replica
@@ -44,7 +44,8 @@ JAXPR_PATH = "<jaxpr>"     # pseudo-path for program-level findings
 
 #: primitives that synchronize with / call back into the host
 HOST_SYNC_PRIMITIVES = {
-    "debug_callback", "pure_callback", "io_callback", "callback",
+    "debug_callback", "debug_print", "pure_callback", "io_callback",
+    "callback",
     "outside_call", "infeed", "outfeed", "host_callback_call",
 }
 
@@ -349,6 +350,12 @@ def _taint_scan(eqn, read, manual_axes):
     return [c | o for c, o in zip(carry, outs[:ncar])] + outs[ncar:]
 
 
+def _spec_axes(spec) -> frozenset:
+    """Mesh axes a shard_map in/out ``PartitionSpec`` shards over."""
+    return frozenset(ax for entry in spec if entry is not None
+                     for ax in (entry if isinstance(entry, tuple) else (entry,)))
+
+
 def check_collectives(prog: TracedProgram) -> List[Finding]:
     err = _trace_failure(prog)
     if err is not None:
@@ -365,9 +372,7 @@ def check_collectives(prog: TracedProgram) -> List[Finding]:
     for eqn, _ in _walk_eqns(_closed(prog.traced()).jaxpr):
         if eqn.primitive.name != "shard_map":
             continue
-        mesh = eqn.params["mesh"]
-        mesh_axes = set(getattr(mesh, "axis_names", ()))
-        manual = mesh_axes - set(eqn.params.get("auto", frozenset()))
+        manual = set(eqn.params["manual_axes"])
         body = eqn.params["jaxpr"]
         body = body.jaxpr if hasattr(body, "jaxpr") else body
         # (a) axis existence + (b) ppermute permutation validity
@@ -398,22 +403,21 @@ def check_collectives(prog: TracedProgram) -> List[Finding]:
                         "exchange built from this loses chunks",
                         context=prog.name))
         # (c) replicated-declared outputs must be replica-invariant
-        in_taints = [frozenset(ax for axes_ in names.values() for ax in axes_)
-                     & manual
-                     for names in eqn.params["in_names"]]
+        in_taints = [_spec_axes(spec) & manual
+                     for spec in eqn.params["in_specs"]]
         out_taints = _taint_jaxpr(body, in_taints, manual)
-        for i, (names, taint) in enumerate(
-                zip(eqn.params["out_names"], out_taints)):
-            declared = {ax for axes_ in names.values() for ax in axes_}
+        for i, (spec, taint) in enumerate(
+                zip(eqn.params["out_specs"], out_taints)):
+            declared = _spec_axes(spec)
             leaked = taint - declared
-            if not names and leaked:
+            if not declared and leaked:
                 findings.append(Finding(
                     "GL003", JAXPR_PATH, 0,
                     f"shard_map output {i} is declared REPLICATED but is "
                     f"shard-varying over {sorted(leaked)} by dataflow "
                     "(derives from a sharded input or axis_index with no "
                     "collective reduction in between) — with "
-                    "check_rep=False this silently returns shard 0's "
+                    "check_vma=False this silently returns shard 0's "
                     "value", context=prog.name))
     return findings
 

@@ -22,14 +22,6 @@ from ..utils import groups
 from ..utils.logging import logger
 
 
-def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-    """Version-portable shard_map (jax>=0.8 moved it to jax.shard_map)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_rep)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_rep)
-
-
 class ReduceOp:
     SUM = "sum"
     MAX = "max"
@@ -160,7 +152,8 @@ class XlaBackend:
         else:
             raise ValueError(kind)
 
-        smapped = shard_map(fn, mesh, (in_spec,), out_spec, check_rep=False)
+        smapped = jax.shard_map(fn, mesh=mesh, in_specs=(in_spec,),
+                                out_specs=out_spec, check_vma=False)
         jitted = jax.jit(smapped)
         if len(self._collective_cache) > 512:
             self._collective_cache.clear()

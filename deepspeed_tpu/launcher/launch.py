@@ -10,6 +10,13 @@ kills the remaining workers (SIGTERM, then SIGKILL after a grace period),
 signals received by the spawner propagate to the whole group, and per-rank
 logs can be redirected with ``--log-dir`` (reference ``launch.py:133``
 signal handling + per-rank output files).
+
+A chip belongs to one process at a time. This spawner never imports JAX,
+so it holds no chip itself; with ``--nproc`` > 1 it hands local chip
+``LOCAL_RANK`` to each worker (``TPU_VISIBLE_CHIPS`` and one-chip process
+bounds) instead of letting every worker inherit — and fight over — all of
+them. Workers pinned to the CPU (``JAX_PLATFORMS=cpu``) and a caller that
+set ``TPU_VISIBLE_CHIPS`` itself are left alone.
 """
 
 import argparse
@@ -32,6 +39,16 @@ def _terminate(procs, grace_s: float = 5.0):
             p.kill()
 
 
+def _chip_env(local_rank: int, nproc: int) -> dict:
+    """Env that shows worker ``local_rank`` one chip of its own."""
+    if (nproc == 1 or os.environ.get("JAX_PLATFORMS") == "cpu"
+            or "TPU_VISIBLE_CHIPS" in os.environ):
+        return {}
+    return {"TPU_VISIBLE_CHIPS": str(local_rank),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1"}
+
+
 def main(argv=None):
     parser = argparse.ArgumentParser()
     parser.add_argument("--nproc", type=int, default=1)
@@ -47,7 +64,7 @@ def main(argv=None):
     if args.log_dir:
         os.makedirs(args.log_dir, exist_ok=True)
     for local_rank in range(args.nproc):
-        env = dict(os.environ)
+        env = dict(os.environ, **_chip_env(local_rank, args.nproc))
         env["LOCAL_RANK"] = str(local_rank)
         env["RANK"] = str(rank_offset + local_rank)
         out = None

@@ -527,14 +527,7 @@ def apply_moe_grouped_ep(params, x, cfg: TransformerConfig, mesh):
                  in_specs=(P(), P("expert"), P("expert"), P("expert"),
                            tok_spec),
                  out_specs=(tok_spec, P()))
-    if hasattr(jax, "shard_map"):          # jax>=0.8 surface
-        fn = jax.shard_map(body, axis_names=manual, **specs)
-    else:
-        # pre-0.8: manual axes are expressed as the complement (`auto`)
-        from jax.experimental.shard_map import shard_map as _sm
-        fn = _sm(body, check_rep=False,
-                 auto=frozenset(mesh.axis_names) - frozenset(manual),
-                 **specs)
+    fn = jax.shard_map(body, axis_names=manual, **specs)
     out, aux = fn(params["router"], params["wi_gate"], params["wi_up"],
                   params["wo"], x)
     if cfg.moe_shared_expert_size:

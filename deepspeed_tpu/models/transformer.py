@@ -266,14 +266,18 @@ class CausalLM:
         r_emb, r_layers = jax.random.split(rng)
         emb, _ = L.init_embeddings(r_emb, cfg)
         layer_rngs = jax.random.split(r_layers, cfg.num_layers)
+        # vmap over the per-layer keys: the same values as a Python loop
+        # over layers, but ONE layer's init in the program (a 24-layer
+        # loop compiled for 90 s on the chip) and no second copy of every
+        # layer held while stacking
         if self._groups is None:
-            per_layer = [self._init_layer(r)[0] for r in layer_rngs]
-            stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *per_layer)
+            stacked = jax.vmap(lambda r: self._init_layer(r)[0])(layer_rngs)
         else:
             stacked = {}
             for gi, (tag, idxs) in enumerate(self._groups):
-                per = [self._init_layer(layer_rngs[i], tag)[0] for i in idxs]
-                stacked[f"g{gi}"] = jax.tree.map(lambda *xs: jnp.stack(xs), *per)
+                stacked[f"g{gi}"] = jax.vmap(
+                    lambda r, _tag=tag: self._init_layer(r, _tag)[0])(
+                        layer_rngs[jnp.asarray(idxs)])
         out = {"embed": emb, "layers": stacked}
         if not cfg.post_norm:   # post-norm (BERT) normalizes inside each layer
             out["final_norm"] = L.init_norm(cfg)[0]
@@ -429,10 +433,7 @@ class CausalLM:
         from ..parallel.sharding import current_manual_axes
         manual = current_manual_axes()
         if manual:
-            if hasattr(jax.lax, "pcast"):
-                aux0 = jax.lax.pcast(aux0, tuple(manual), to="varying")
-            else:
-                aux0 = jax.lax.pvary(aux0, tuple(manual))
+            aux0 = jax.lax.pcast(aux0, tuple(manual), to="varying")
         carry = (h, aux0)
 
         def make_body(fn):

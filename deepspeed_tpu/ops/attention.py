@@ -12,7 +12,7 @@ is expressed here as sharding constraints: activations arrive sequence-sharded
 ``_SeqAllToAll`` hand-codes, riding ICI.
 """
 
-import functools
+import math
 from typing import Optional
 
 import jax
@@ -21,14 +21,55 @@ from jax.sharding import PartitionSpec as P
 
 from ..utils import groups
 
-_FALLBACK_WARNED = set()
-
 
 def _use_pallas() -> bool:
     import os
     if os.environ.get("DS_TPU_DISABLE_PALLAS", "0") == "1":
         return False
     return jax.default_backend() == "tpu"
+
+
+def _flash_shape_ok(s: int, d: int) -> bool:
+    """Shapes the flash kernel tiles: whole 128-row tiles, and a sequence
+    its (at most 512-wide) blocks divide."""
+    from .pallas.flash_attention import DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q
+    return (s >= 128 and s % 128 == 0 and d in (64, 128, 256)
+            and s % min(DEFAULT_BLOCK_Q, s) == 0
+            and s % min(DEFAULT_BLOCK_K, s) == 0)
+
+
+def _flash(mesh, q, k, v, *, causal, segment_ids, scale, alibi_slopes, window):
+    """The flash kernel, on one device or many. XLA's SPMD pass cannot
+    partition a Mosaic call ("wrap the call in a shard_map"), so on a mesh
+    of more than one device the kernel runs under ``shard_map``: batch over
+    the data-like axes, heads over the axes that shard them (Ulysses' head
+    exchange, tensor-parallel weights) where those divide the head counts —
+    a dim an axis does not divide is gathered and computed whole. Inside a
+    manual region (ZeRO++ step, pipeline stages) the caller's ``shard_map``
+    already made the operands local."""
+    from ..parallel.sharding import current_manual_axes
+    from .pallas.flash_attention import flash_attention
+
+    def local(q, k, v, seg, slopes):
+        return flash_attention(q, k, v, causal=causal, segment_ids=seg,
+                               scale=scale, alibi_slopes=slopes, window=window)
+
+    if mesh is None or mesh.devices.size == 1 or current_manual_axes():
+        return local(q, k, v, segment_ids, alibi_slopes)
+
+    def axes_dividing(names, *dims):
+        names = tuple(a for a in names if mesh.shape[a] > 1)
+        n = math.prod(mesh.shape[a] for a in names)
+        return names if names and not any(d % n for d in dims) else None
+
+    batch = axes_dividing(groups.BATCH_AXES, q.shape[0])
+    heads = axes_dividing(("seq", "tensor"), q.shape[2], k.shape[2])
+    qkv = P(batch, None, heads, None)
+    # absent operands are empty pytrees: their spec matches no leaf
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(qkv, qkv, qkv, P(batch, None), P(heads)),
+        out_specs=qkv, check_vma=False)(q, k, v, segment_ids, alibi_slopes)
 
 
 def window_mask(q_pos, k_pos, window):
@@ -190,30 +231,14 @@ def multihead_attention(q, k, v, *, causal=True, bias=None, segment_ids=None, sc
             "routes these there)")
 
     def dispatch(q, k, v):
-        if impl == "flash" or (impl is None and _use_pallas() and q.shape[1] >= 128 and
-                               q.shape[3] in (64, 128, 256) and bias is None and
-                               not softcap and flash_window_ok):
-            try:
-                from .pallas.flash_attention import flash_attention
-                return flash_attention(q, k, v, causal=causal, segment_ids=segment_ids,
-                                       scale=scale, alibi_slopes=alibi_slopes,
-                                       window=window)
-            except Exception as e:
-                # A silent fallback here would quietly cost O(S^2) memory and
-                # a large fraction of peak throughput — warn loudly, once per
-                # shape.
-                global _FALLBACK_WARNED
-                key = (q.shape, str(q.dtype))
-                if key not in _FALLBACK_WARNED:
-                    _FALLBACK_WARNED.add(key)
-                    import logging
-                    logging.getLogger("DeepSpeedTPU").warning(
-                        "Pallas flash attention FAILED for shape %s (%s: %s); "
-                        "falling back to O(S^2) XLA attention. Performance "
-                        "will suffer — set DS_TPU_DISABLE_PALLAS=1 to silence.",
-                        q.shape, type(e).__name__, e)
-                if impl == "flash":
-                    raise
+        # shape-based choice only: a kernel that was chosen and then fails
+        # to compile or run raises — it never drops to the O(S^2) path
+        if impl == "flash" or (impl is None and _use_pallas()
+                               and _flash_shape_ok(q.shape[1], q.shape[3])
+                               and bias is None and not softcap
+                               and flash_window_ok):
+            return _flash(mesh, q, k, v, causal=causal, segment_ids=segment_ids,
+                          scale=scale, alibi_slopes=alibi_slopes, window=window)
         return _reference_with_slopes(q, k, v, causal, bias, alibi_slopes,
                                       segment_ids, scale, window, softcap)
 
@@ -251,22 +276,13 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, bias=None, scale=None,
             and k_cache.shape[1] >= 8192
             and k_cache.shape[1] % 128 == 0 and d % 64 == 0
             and h % k_cache.shape[2] == 0):
-        try:
-            from .pallas.decode_attention import fused_decode_attention
-            block = min(512, k_cache.shape[1])
-            if k_cache.shape[1] % block:
-                block = 128
-            out = fused_decode_attention(q[:, 0], k_cache, v_cache, cache_len,
-                                         scale=scale, block=block)
-            return out[:, None]
-        except Exception as e:
-            key = ("decode", q.shape, str(q.dtype))
-            if key not in _FALLBACK_WARNED:
-                _FALLBACK_WARNED.add(key)
-                import logging
-                logging.getLogger("DeepSpeedTPU").warning(
-                    "Pallas fused decode FAILED for %s (%s: %s); using XLA "
-                    "masked attention.", q.shape, type(e).__name__, e)
+        from .pallas.decode_attention import fused_decode_attention
+        block = min(512, k_cache.shape[1])
+        if k_cache.shape[1] % block:
+            block = 128
+        out = fused_decode_attention(q[:, 0], k_cache, v_cache, cache_len,
+                                     scale=scale, block=block)
+        return out[:, None]
     kvh = k_cache.shape[2]
     if kvh != h:
         rep = h // kvh

@@ -38,7 +38,8 @@ def DS4Sci_EvoformerAttention(q, k, v, biases: Sequence = (), chunk: int = 256):
     Dispatch: MXU-friendly shapes run the fused Pallas bias-flash forward
     (``pallas/evoformer_flash.py`` — logits never hit HBM) with a
     query-chunked recompute backward; other shapes take the chunked XLA
-    path end-to-end. The env kill switch is read at Python call time
+    path end-to-end. The choice is by shape: a kernel that was chosen and
+    fails, raises. The env kill switch is read at Python call time
     (OUTSIDE the jitted internals) so toggling it mid-process works, like
     every other Pallas dispatcher in this repo.
     """
@@ -55,27 +56,9 @@ def DS4Sci_EvoformerAttention(q, k, v, biases: Sequence = (), chunk: int = 256):
             raise ValueError(f"bias shape {b.shape} matches neither mask "
                              f"{s1} nor pair {s2}")
     from .pallas.evoformer_flash import evoformer_flash_supported
-    fb_key = (q.shape, str(q.dtype))
-    if (_use_pallas() and evoformer_flash_supported(q.shape[2], q.shape[4])
-            and fb_key not in _EVO_FALLBACK_WARNED):
-        try:
-            return _evo_attn_jit(q, k, v, bias1, bias2, chunk)
-        except Exception as e:
-            # same contract as the flash-attention dispatcher: a kernel
-            # failure downgrades to the XLA path LOUDLY, once per shape
-            # (the shape also skips straight to the XLA path afterwards —
-            # no per-step recompile attempts)
-            _EVO_FALLBACK_WARNED.add(fb_key)
-            import logging
-            logging.getLogger("DeepSpeedTPU").warning(
-                "Pallas evoformer attention FAILED for shape %s (%s: %s); "
-                "falling back to the chunked XLA path. Set "
-                "DS_TPU_DISABLE_PALLAS=1 to silence.",
-                q.shape, type(e).__name__, e)
+    if _use_pallas() and evoformer_flash_supported(q.shape[2], q.shape[4]):
+        return _evo_attn_jit(q, k, v, bias1, bias2, chunk)
     return _chunked_jit(q, k, v, bias1, bias2, chunk)
-
-
-_EVO_FALLBACK_WARNED = set()
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))

@@ -26,13 +26,12 @@ def _adam_kernel(p_ref, g_ref, m_ref, v_ref, hyper_ref,
     b2 = hyper_ref[2]
     eps = hyper_ref[3]
     wd = hyper_ref[4]
-    step = hyper_ref[5]
+    bc1 = hyper_ref[5]
+    bc2 = hyper_ref[6]
     p = p_ref[:].astype(jnp.float32)
     g = g_ref[:].astype(jnp.float32)
     m = b1 * m_ref[:] + (1.0 - b1) * g
     v = b2 * v_ref[:] + (1.0 - b2) * g * g
-    bc1 = 1.0 - jnp.power(b1, step)
-    bc2 = 1.0 - jnp.power(b2, step)
     update = (m / bc1) / (jnp.sqrt(v / bc2) + eps) + wd * p
     p_out[:] = (p - lr * update).astype(p_out.dtype)
     m_out[:] = m
@@ -45,7 +44,10 @@ def fused_adam_flat(params, grads, exp_avg, exp_avg_sq, *, step, lr,
     """Flat fp32 buffers (N,) → (new_params, new_m, new_v). N % 128 == 0 for
     the TPU path; other sizes fall back to plain XLA."""
     n = params.size
-    hyper = jnp.asarray([lr, betas[0], betas[1], eps, weight_decay, step], jnp.float32)
+    # bias corrections are computed out here: Mosaic has no scalar powf
+    hyper = jnp.asarray([lr, betas[0], betas[1], eps, weight_decay,
+                         1 - betas[0] ** step, 1 - betas[1] ** step],
+                        jnp.float32)
     if n % 128 != 0:
         # XLA fallback — identical math
         g = grads.astype(jnp.float32)

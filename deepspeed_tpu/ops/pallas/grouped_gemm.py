@@ -3,29 +3,16 @@
 Analog of ``inference/v2/kernels/cutlass_ops/moe_gemm`` (grouped GEMM over
 per-expert token groups). On TPU the idiomatic primitive is
 ``jax.lax.ragged_dot`` (Megablox-style: rows grouped by expert, group sizes
-ragged) which XLA lowers to MXU-tiled grouped matmul; a dense einsum fallback
-covers platforms/shapes where ragged_dot is unavailable.
+ragged) which XLA lowers to MXU-tiled grouped matmul.
 """
 
 import jax
-import jax.numpy as jnp
 
 
 def grouped_gemm(tokens, expert_weights, group_sizes):
     """tokens: (T, E) rows sorted by expert; expert_weights: (X, E, F);
     group_sizes: (X,) rows per expert. Returns (T, F)."""
-    if hasattr(jax.lax, "ragged_dot"):
-        try:
-            return jax.lax.ragged_dot(tokens, expert_weights, group_sizes)
-        except Exception:
-            pass
-    # fallback: dense one-hot dispatch (O(T·X·E·F) worst case, fused by XLA)
-    t = tokens.shape[0]
-    x = expert_weights.shape[0]
-    bounds = jnp.cumsum(group_sizes)
-    expert_of_row = jnp.sum(jnp.arange(t)[:, None] >= bounds[None, :], axis=1)  # (T,)
-    w_per_row = expert_weights[expert_of_row]        # (T, E, F) gather
-    return jnp.einsum("te,tef->tf", tokens, w_per_row)
+    return jax.lax.ragged_dot(tokens, expert_weights, group_sizes)
 
 
 def moe_expert_ffn(tokens, wi_gate, wi_up, wo, group_sizes):

@@ -252,10 +252,6 @@ def current_manual_axes():
     through this so model code works both under plain SPMD jit and inside
     partial-auto shard_map regions (e.g. the ZeRO++ quantized-collective
     step)."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        return set(getattr(am, "manual_axes", ()) or ())
-    except Exception:
-        return set()
+    return set(jax.sharding.get_abstract_mesh().manual_axes)
 
 

@@ -258,7 +258,11 @@ class DeepSpeedEngine:
             log_dist(f"Optimizer state swapped to NVMe at {swap_dir}", ranks=[0])
 
         self.loss_scaler = create_loss_scaler(self._config.fp16, self._config.precision_dtype)
-        self.scaler_state = self.loss_scaler.init_state()
+        # placed on the mesh like the state the step returns: fresh
+        # default-device scalars would type differently from step 1's
+        # outputs and compile the whole step a second time at step 2
+        self.scaler_state = jax.device_put(self.loss_scaler.init_state(),
+                                           self._replicated)
         self.gradient_clipping = float(self._config.gradient_clipping or 0.0)
 
         # ---- lr schedule ----
@@ -308,7 +312,11 @@ class DeepSpeedEngine:
         self.opt_state = None
         self._opt_swapper = None
         self.loss_scaler = create_loss_scaler(self._config.fp16, self._config.precision_dtype)
-        self.scaler_state = self.loss_scaler.init_state()
+        # placed on the mesh like the state the step returns: fresh
+        # default-device scalars would type differently from step 1's
+        # outputs and compile the whole step a second time at step 2
+        self.scaler_state = jax.device_put(self.loss_scaler.init_state(),
+                                           self._replicated)
         self.gradient_clipping = float(self._config.gradient_clipping or 0.0)
         self.lr_scheduler = self._configure_lr_scheduler(None)
         self.client_lr_scheduler = None
@@ -1480,8 +1488,8 @@ class DeepSpeedEngine:
 
     def _next_lr_device(self):
         """Device scalar for the next step's lr, cached while unchanged
-        (a fresh host→device scalar transfer every step is measurable
-        latency on remote/tunneled platforms)."""
+        (a fresh host→device scalar transfer every step is one more
+        dispatch ahead of the step program)."""
         lr = float(self._next_lr())
         cached = getattr(self, "_lr_cache", None)
         if cached is None or cached[0] != lr:

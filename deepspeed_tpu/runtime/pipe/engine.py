@@ -28,9 +28,7 @@ from ...utils import groups
 
 def _pvary(x, axis):
     """Mark a replicated value as varying over ``axis`` (vma typing)."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axis, to="varying")
-    return jax.lax.pvary(x, axis)
+    return jax.lax.pcast(x, axis, to="varying")
 
 
 def pipeline_spmd(layer_fn: Callable, num_stages: int, layers_per_stage: int,

@@ -408,9 +408,7 @@ def _vary(x, axes):
     kernel operands type-check under shard_map's check_vma."""
     if not axes:
         return x
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, tuple(axes), to="varying")
-    return jax.lax.pvary(x, tuple(axes))
+    return jax.lax.pcast(x, tuple(axes), to="varying")
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))

@@ -66,6 +66,16 @@ def log_dist(message, ranks=None, level=logging.INFO):
         logger.log(level, f"[Rank {_process_index()}] {message}")
 
 
+def logs_to_stderr():
+    """Point the package logger at stderr. Its default handler streams to
+    stdout (reference behavior); entry points whose stdout is a JSON
+    contract (``bin/dstpu_serve``, the benches, ``chip_smoke.py``) call
+    this before anything logs."""
+    for handler in logger.handlers:
+        if hasattr(handler, "stream"):
+            handler.stream = sys.stderr
+
+
 def print_rank_0(message):
     if _process_index() == 0:
         print(message, flush=True)

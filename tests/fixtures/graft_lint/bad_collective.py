@@ -7,7 +7,7 @@
   (a ring exchange built from it silently loses a chunk);
 - ``leaky_output`` — an output DECLARED replicated that actually varies by
   shard (``axis_index`` reaches it with no collective in between). The
-  frame loops compile with ``check_rep=False``, so only this static pass
+  frame loops compile with ``check_vma=False``, so only this static pass
   would catch it.
 """
 
@@ -15,7 +15,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -26,8 +25,8 @@ def _mesh():
 def _program(name, fn, out_specs):
     from deepspeed_tpu.analysis.jaxpr_checks import TracedProgram
     mesh = _mesh()
-    mapped = shard_map(fn, mesh=mesh, in_specs=P("tp"), out_specs=out_specs,
-                       check_rep=False)
+    mapped = jax.shard_map(fn, mesh=mesh, in_specs=P("tp"),
+                           out_specs=out_specs, check_vma=False)
 
     def trace():
         return jax.make_jaxpr(mapped)(jnp.ones((8, 4), jnp.float32))

@@ -16,7 +16,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -26,8 +25,8 @@ def _mesh():
 
 def _program(name, fn, out_specs=P()):
     from deepspeed_tpu.analysis.jaxpr_checks import TracedProgram
-    mapped = shard_map(fn, mesh=_mesh(), in_specs=P("tp"),
-                       out_specs=out_specs, check_rep=False)
+    mapped = jax.shard_map(fn, mesh=_mesh(), in_specs=P("tp"),
+                           out_specs=out_specs, check_vma=False)
 
     def trace():
         return jax.make_jaxpr(mapped)(jnp.ones((8, 4), jnp.float32))

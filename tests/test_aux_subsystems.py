@@ -845,3 +845,24 @@ def test_launcher_local_end_to_end(tmp_path):
     finally:
         rmod.EXPORT_ENVS.remove("OUT_DIR")
         os.environ.pop("OUT_DIR", None)
+
+
+@pytest.mark.parametrize("nproc,env,expect_chip", [
+    (1, {}, None),                                  # one worker drives every chip
+    (4, {}, "2"),                                   # else: its own chip each
+    (4, {"JAX_PLATFORMS": "cpu"}, None),            # CPU workers need no chip
+    (4, {"TPU_VISIBLE_CHIPS": "0,1"}, None),        # the caller partitioned
+], ids=["nproc1", "nproc4", "cpu-pinned", "caller-set"])
+def test_launcher_gives_each_worker_its_own_chip(monkeypatch, nproc, env,
+                                                 expect_chip):
+    """A chip belongs to one process at a time: ``--nproc`` > 1 workers
+    must not all inherit every local chip."""
+    from deepspeed_tpu.launcher.launch import _chip_env
+    for var in ("JAX_PLATFORMS", "TPU_VISIBLE_CHIPS"):
+        monkeypatch.delenv(var, raising=False)
+    for var, value in env.items():
+        monkeypatch.setenv(var, value)
+    got = _chip_env(2, nproc)
+    assert got.get("TPU_VISIBLE_CHIPS") == expect_chip
+    if expect_chip is not None:
+        assert got["TPU_PROCESS_BOUNDS"] == "1,1,1"

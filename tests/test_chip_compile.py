@@ -1,0 +1,125 @@
+"""Rehearsal compiles for the chip, without the chip.
+
+The TPU compiler is installed with jaxlib and compiles for a DESCRIBED
+``v5e:2x2`` topology (no device attached): each case lowers one kernel of
+the main path at the shapes ``chip_smoke.py`` runs and asserts the Mosaic
+custom call is in the compiled program. Interpret-mode tests cannot see
+what these see — a slice the tiling refuses, too much fast memory, an op
+the Mosaic verifier rejects (the C=1, GQA, bf16 decode step did exactly
+that). A compile that passes here is not a chip run.
+
+The kernels pick ``interpret`` from ``jax.default_backend()``; the CPU
+suite sees "cpu" there, so the ``as_tpu`` fixture steers it — the program
+itself gets no option for this.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 — no libtpu here: nothing to rehearse
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+    # a compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip — keep the cache off here
+    from jax.experimental.compilation_cache import compilation_cache
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _compile_text(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+# the serve phase's model (mistral-7b): 32 query / 8 KV heads, head_dim 128,
+# engine-default page 128, sliding window 4096, bf16
+H, KVH, D, PAGE, WINDOW = 32, 8, 128, 128, 4096
+
+
+@pytest.mark.parametrize("chunk", [1, 3, 128],
+                         ids=["decode", "spec-verify", "prefill"])
+def test_paged_attention_compiles_at_serve_widths(one_chip, as_tpu, chunk):
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_ragged_attention
+    b, layers, pages, table = 8, 4, 64, 8
+    dt = jnp.bfloat16
+
+    def sds(shape, dtype=dt):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(q, kpool, vpool, tables, positions, ck, cv, layer):
+        return paged_ragged_attention(q, kpool, vpool, tables, positions,
+                                      ck, cv, layer=layer, window=WINDOW)
+
+    pool = sds((layers, KVH, pages, PAGE, D))
+    text = _compile_text(
+        step, sds((b, chunk, H, D)), pool, pool, sds((b, table), jnp.int32),
+        sds((b, chunk), jnp.int32), sds((b, chunk, KVH, D)),
+        sds((b, chunk, KVH, D)), sds((), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("b,s,h,kvh,d,window", [
+    (8, 1024, 16, 16, 64, None),          # train phase: gpt2-medium, micro 8
+    (1, 1024, H, KVH, D, None),           # serve model's widths
+    (1, 8192, H, KVH, D, WINDOW),         # ... with its window binding
+], ids=["gpt2-medium", "mistral-7b", "mistral-7b-window"])
+def test_flash_attention_fwd_bwd_compiles(one_chip, as_tpu, b, s, h, kvh, d,
+                                          window):
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+    dt = jnp.bfloat16
+    q = jax.ShapeDtypeStruct((b, s, h, d), dt, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, s, kvh, d), dt, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, window=window)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compile_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    # forward + the dq and dkv backward kernels
+    assert text.count("tpu_custom_call") >= 3
+
+
+def test_fused_adam_compiles(one_chip, as_tpu):
+    """Mosaic has no scalar powf: the bias corrections stay outside."""
+    from deepspeed_tpu.ops.pallas.fused_adam import fused_adam_flat
+    flat = jax.ShapeDtypeStruct((1 << 22,), jnp.float32, sharding=one_chip)
+    text = _compile_text(
+        lambda p, g, m, v: fused_adam_flat(p, g, m, v, step=jnp.int32(3),
+                                           lr=jnp.float32(1e-3)),
+        flat, flat, flat, flat)
+    assert "tpu_custom_call" in text
+
+
+def test_chip_smoke_fails_without_a_chip():
+    """The suite runs on the CPU: ``chip_smoke.py`` must exit nonzero there
+    and never print its success line (the children stop before any phase)."""
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, os.path.join(root, "chip_smoke.py")],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        capture_output=True, text=True, timeout=300)
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout
+    assert "no accelerator" in proc.stderr

@@ -74,7 +74,6 @@ def test_barrier(mesh_8dp):
 
 def test_in_trace_collectives(mesh_8dp):
     """psum/all_gather/psum_scatter inside shard_map (the hot-path API)."""
-    from deepspeed_tpu.comm import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = groups.get_mesh()
 
@@ -83,7 +82,8 @@ def test_in_trace_collectives(mesh_8dp):
         g = dist.all_gather(x, "data", axis=0, tiled=True)
         return s, g
 
-    f = jax.jit(shard_map(body, mesh, (P("data"),), (P("data"), P())))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("data"),),
+                              out_specs=(P("data"), P()), check_vma=False))
     x = jnp.arange(8.0).reshape(8, 1)
     s, g = f(x)
     np.testing.assert_allclose(np.asarray(s), np.full((8, 1), 28.0))
@@ -91,12 +91,12 @@ def test_in_trace_collectives(mesh_8dp):
 
 
 def test_ring_send_recv(mesh_8dp):
-    from deepspeed_tpu.comm import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = groups.get_mesh()
 
-    f = jax.jit(shard_map(lambda x: dist.ring_send_recv(x, "data", shift=1),
-                          mesh, (P("data"),), P("data")))
+    f = jax.jit(jax.shard_map(
+        lambda x: dist.ring_send_recv(x, "data", shift=1), mesh=mesh,
+        in_specs=(P("data"),), out_specs=P("data"), check_vma=False))
     x = jnp.arange(8.0).reshape(8, 1)
     out = f(x)
     np.testing.assert_allclose(np.asarray(out).ravel(), np.roll(np.arange(8.0), 1))

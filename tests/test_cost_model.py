@@ -76,11 +76,10 @@ def test_batched_dot_general_flops_golden():
 
 
 def test_psum_ring_wire_bytes_golden():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(np.asarray(jax.devices()[:8]).reshape(8), ("tp",))
-    mapped = shard_map(lambda x: jax.lax.psum(x, "tp"), mesh=mesh,
-                       in_specs=P(), out_specs=P(), check_rep=False)
+    mapped = jax.shard_map(lambda x: jax.lax.psum(x, "tp"), mesh=mesh,
+                           in_specs=P(), out_specs=P(), check_vma=False)
     m = C.measure_jaxpr(jax.make_jaxpr(mapped)(jnp.ones((16,), jnp.float32)))
     # ring all-reduce: each device sends 2(N-1)/N x operand bytes
     assert m.coll_payload == {"tp": 2 * 7 / 8 * 64}  # = 112.0
@@ -89,12 +88,11 @@ def test_psum_ring_wire_bytes_golden():
 
 
 def test_all_gather_wire_bytes_golden():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(np.asarray(jax.devices()[:8]).reshape(8), ("tp",))
-    mapped = shard_map(
+    mapped = jax.shard_map(
         lambda x: jax.lax.all_gather(x, "tp", axis=0, tiled=True),
-        mesh=mesh, in_specs=P("tp"), out_specs=P(), check_rep=False)
+        mesh=mesh, in_specs=P("tp"), out_specs=P(), check_vma=False)
     m = C.measure_jaxpr(jax.make_jaxpr(mapped)(jnp.ones((8, 4), jnp.float32)))
     # each device forwards its (1, 4) f32 shard to the N-1 others
     assert m.coll_payload == {"tp": 7 * 16}

@@ -289,3 +289,41 @@ def test_flash_segment_ids_noncausal_and_windowed():
                           block_q=128, block_k=128)
     o_r = reference_attention(q, k, v, causal=True, segment_ids=seg, window=40)
     np.testing.assert_allclose(np.asarray(o_f), np.asarray(o_r), atol=2e-5)
+
+
+@pytest.mark.parametrize("axes,kvh,alibi", [
+    (dict(data=8), 4, False),              # ZeRO data parallel: batch sharded
+    (dict(data=2, tensor=4), 4, True),     # + heads over the tensor axis
+    (dict(data=2, tensor=4), 2, False),    # kv heads tensor cannot divide
+], ids=["dp8", "dp2-tp4-alibi", "dp2-tp4-gqa"])
+def test_flash_dispatch_on_a_multi_device_mesh(axes, kvh, alibi):
+    """XLA cannot partition a Mosaic call, so on a mesh of several devices
+    the dispatcher runs the kernel under shard_map; a dim the mesh does not
+    divide is computed whole. Values and grads match the reference."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from deepspeed_tpu.models.layers import alibi_slopes
+    from deepspeed_tpu.ops.attention import (_reference_with_slopes,
+                                             multihead_attention)
+    from deepspeed_tpu.utils import groups
+    mesh = groups.set_mesh(groups.build_mesh(**axes))
+    rng = np.random.default_rng(0)
+    b, s, h, d = 8, 128, 4, 64
+    rows = NamedSharding(mesh, P(groups.BATCH_AXES))
+    q = jax.device_put(jnp.asarray(rng.normal(size=(b, s, h, d)), jnp.float32), rows)
+    k = jax.device_put(jnp.asarray(rng.normal(size=(b, s, kvh, d)), jnp.float32), rows)
+    v = jax.device_put(jnp.asarray(rng.normal(size=(b, s, kvh, d)), jnp.float32), rows)
+    slopes = alibi_slopes(h) if alibi else None
+
+    def flash(q, k, v):
+        return jnp.sum(multihead_attention(q, k, v, alibi_slopes=slopes,
+                                           impl="flash") ** 2)
+
+    def ref(q, k, v):
+        return jnp.sum(_reference_with_slopes(q, k, v, True, None, slopes,
+                                              None, None, None) ** 2)
+
+    out, grads = jax.jit(jax.value_and_grad(flash, argnums=(0, 1, 2)))(q, k, v)
+    want, want_grads = jax.value_and_grad(ref, argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(out), float(want), rtol=1e-5)
+    for got, exp in zip(grads, want_grads):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(exp), atol=2e-4)

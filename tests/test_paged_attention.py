@@ -187,3 +187,38 @@ def test_paged_decode_window_alibi_wrapper():
                             (lens - 1)[:, None], window=9, alibi_slopes=sl)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref[:, 0]),
                                rtol=3e-5, atol=3e-5)
+
+
+@pytest.mark.parametrize("c,h,kvh,dtype", [
+    (1, 8, 2, jnp.bfloat16),    # GQA decode step: the one-key chunk branch
+    (1, 4, 4, jnp.float32),     # MHA decode step
+    (3, 8, 2, jnp.bfloat16),    # speculative verify width
+    (4, 4, 2, jnp.float32),     # prefill chunk
+])
+def test_paged_ragged_chunk_beside_pool(c, h, kvh, dtype):
+    """The serving runner's contract: the chunk's own KV rides beside a
+    pool that does not hold it yet. Must equal attention over a pool with
+    the chunk already scattered in."""
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_ragged_attention
+    b, d, bs, nb, mb = 2, 64, 16, 10, 4
+    rng = np.random.default_rng(5)
+
+    def rand(*shape, scale=1.0):
+        return jnp.asarray(rng.standard_normal(shape) * scale, dtype)
+
+    q = rand(b, c, h, d, scale=0.1)
+    kpool, vpool = rand(kvh, nb, bs, d), rand(kvh, nb, bs, d)
+    ck, cv = rand(b, c, kvh, d), rand(b, c, kvh, d)
+    tables = jnp.asarray(rng.permutation(nb)[: b * mb].reshape(b, mb), jnp.int32)
+    positions = jnp.asarray(np.stack([17 + np.arange(c), 40 + np.arange(c)]),
+                            jnp.int32)
+    out = paged_ragged_attention(q, kpool, vpool, tables, positions, ck, cv,
+                                 window=20)
+    blk = jnp.take_along_axis(tables, positions // bs, axis=1)   # (B, C)
+    off = positions % bs
+    kfull = kpool.at[:, blk, off].set(ck.transpose(2, 0, 1, 3))
+    vfull = vpool.at[:, blk, off].set(cv.transpose(2, 0, 1, 3))
+    ref = _ragged_reference(q, kfull, vfull, tables, positions, window=20)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 3e-5
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), rtol=tol, atol=tol)

@@ -176,7 +176,6 @@ def test_tp8_quantized_collectives_parity_at_tolerance(tp8_engine,
 
     def one_logits(e):
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         tp = e.tp_ctx
         import functools
@@ -188,9 +187,9 @@ def test_tp8_quantized_collectives_parity_at_tolerance(tp8_engine,
                                kpool, vpool)
             return logits
 
-        f = shard_map(core, mesh=tp.mesh,
-                      in_specs=(tp.param_specs, tp.kv_spec, tp.kv_spec),
-                      out_specs=P(), check_rep=False)
+        f = jax.shard_map(core, mesh=tp.mesh,
+                          in_specs=(tp.param_specs, tp.kv_spec, tp.kv_spec),
+                          out_specs=P(), check_vma=False)
         return np.asarray(jax.jit(f)(e.params, e.kv.k, e.kv.v))
 
     exact = one_logits(tp8_engine)

@@ -124,7 +124,6 @@ def test_taint_pass_descends_into_while_bodies():
     import numpy as np
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     from deepspeed_tpu.analysis.jaxpr_checks import TracedProgram
 
@@ -135,8 +134,8 @@ def test_taint_pass_descends_into_while_bodies():
             return c + jax.lax.axis_index("tp").astype(jnp.float32)
         return jax.lax.while_loop(lambda c: c < 3.0, step, jnp.sum(x))
 
-    mapped = shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
-                      check_rep=False)
+    mapped = jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
+                           check_vma=False)
 
     def trace():
         return jax.make_jaxpr(mapped)(jnp.ones((8,), jnp.float32))
