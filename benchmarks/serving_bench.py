@@ -256,7 +256,6 @@ def bench_mixed_dynamic(model_name, batch, prompt_len, new_tokens,
     step loop at ~1/9.5 of the statically-compiled path; here both
     contenders ingest mid-stream arrivals, so the gap this tracks is pure
     host-scheduling overhead, not admission capability."""
-    from deepspeed_tpu.inference.v2.ragged_manager import DeviceSlotTable
     eng = _mk_engine(model_name, batch,
                      expected_context=prompt_len + new_tokens)
     prompts, offsets = _poisson_schedule(eng.model.cfg.vocab_size, prompt_len,
@@ -266,26 +265,15 @@ def bench_mixed_dynamic(model_name, batch, prompt_len, new_tokens,
         """serve() with wall-clock Poisson arrivals; returns (produced, dt,
         device_time) — dt - device_time is the host boundary cost."""
         arrivals = _wallclock_arrivals(prompts, offsets, time.perf_counter())
-        dev_box = [0.0]
-        orig_run = DeviceSlotTable.run_frame
-
-        def timed_run(self, *a, **kw):
-            s = time.perf_counter()
-            out = orig_run(self, *a, **kw)
-            dev_box[0] += time.perf_counter() - s
-            return out
-
-        DeviceSlotTable.run_frame = timed_run
         produced = 0
-        try:
-            t0 = time.perf_counter()
-            for _uid, toks in eng.serve(arrivals, max_new_tokens=new_tokens,
-                                        frame_steps=frame_steps):
-                produced += len(toks)
-            dt = time.perf_counter() - t0
-        finally:
-            DeviceSlotTable.run_frame = orig_run
-        return produced, dt, dev_box[0]
+        t0 = time.perf_counter()
+        for _uid, toks in eng.serve(arrivals, max_new_tokens=new_tokens,
+                                    frame_steps=frame_steps):
+            produced += len(toks)
+        dt = time.perf_counter() - t0
+        c = eng.telemetry.counters      # this serve()'s dispatch + fetch
+        return produced, dt, (c["host_dispatch_ns"]
+                              + c["host_fetch_ns"]) / 1e9
 
     def run_host_steps():
         """The pre-frame-loop contender: put()+step() per token, same

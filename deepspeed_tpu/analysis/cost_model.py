@@ -328,10 +328,11 @@ class _Measurer:
 # ---------------------------------------------------------------------------
 
 #: program base name -> flat output indices the HOST materializes at the
-#: frame boundary (np.asarray in run_frame / stats_delta / nonfinite_uids /
-#: resync_committed). Maintained exactly like ast_checks.DISPATCH_DONATIONS:
-#: tests/test_cost_model.py cross-checks shapes against the live traces so
-#: a loop that grows an output cannot silently rot the table.
+#: frame boundary (np.asarray in _run_frame_resilient / stats_delta /
+#: nonfinite_uids / resync_committed). Maintained exactly like
+#: ast_checks.DISPATCH_DONATIONS: tests/test_cost_model.py cross-checks
+#: shapes against the live traces so a loop that grows an output cannot
+#: silently rot the table.
 HOST_READ_OUTPUTS: Dict[str, Sequence[int]] = {
     # (toks, emit, cached, produced, last_tok, done, poison, nonfinite,
     #  stats, rng, k, v)

@@ -61,6 +61,17 @@ class PagedModelRunner:
         # path byte-identical to the unsharded runner
         self.tp = None
 
+    @property
+    def stat_window(self):
+        """The sliding window the in-graph work counters (``STAT_KV_READ``,
+        ``STAT_ATTN_PAIRS``) bound a row's KV reads by: the model's uniform
+        window, or None (full context; per-layer local/global patterns
+        count as full context — an upper bound)."""
+        cfg = self.cfg
+        if cfg.sliding_window is None or cfg.local_attention_every is not None:
+            return None
+        return int(cfg.sliding_window)
+
     def set_tp(self, tp_ctx) -> None:
         """Bind a ``tp.TPContext`` (engine setup, before any serving loop
         compiles). The serving entry points close over the context, so any
@@ -99,27 +110,28 @@ class PagedModelRunner:
         model = self.model
         dt = cfg.act_dtype
         b, c = ids.shape
-        if tp is not None and tp.vocab_sharded:
-            # Megatron vocab-parallel lookup: each shard holds rows
-            # [r*V/tp, (r+1)*V/tp) — mask out-of-range ids, psum selects the
-            # one shard holding each token's row
-            tok = params["embed"]["tok"].astype(dt)
-            vs = tok.shape[0]
-            off = jax.lax.axis_index(tp.axis) * vs
-            lid = jnp.clip(ids - off, 0, vs - 1)
-            h = jnp.where(((ids >= off) & (ids < off + vs))[..., None],
-                          tok[lid], jnp.zeros((), dt))
-            h = tp.coll.psum_embed(h)
-        else:
-            h = params["embed"]["tok"].astype(dt)[ids]
-        if cfg.embed_scale != 1.0:
-            h = h * jnp.asarray(cfg.embed_scale, dt)
-        if cfg.position == "learned":
-            h = h + params["embed"]["pos"].astype(dt)[
-                jnp.clip(positions + cfg.position_offset, 0,
-                         params["embed"]["pos"].shape[0] - 1)]
-        if cfg.embedding_norm:   # BLOOM word_embeddings_layernorm
-            h = L.apply_norm(params["embed"]["emb_norm"], h, cfg)
+        with jax.named_scope("embed"):
+            if tp is not None and tp.vocab_sharded:
+                # Megatron vocab-parallel lookup: each shard holds rows
+                # [r*V/tp, (r+1)*V/tp) — mask out-of-range ids, psum selects the
+                # one shard holding each token's row
+                tok = params["embed"]["tok"].astype(dt)
+                vs = tok.shape[0]
+                off = jax.lax.axis_index(tp.axis) * vs
+                lid = jnp.clip(ids - off, 0, vs - 1)
+                h = jnp.where(((ids >= off) & (ids < off + vs))[..., None],
+                              tok[lid], jnp.zeros((), dt))
+                h = tp.coll.psum_embed(h)
+            else:
+                h = params["embed"]["tok"].astype(dt)[ids]
+            if cfg.embed_scale != 1.0:
+                h = h * jnp.asarray(cfg.embed_scale, dt)
+            if cfg.position == "learned":
+                h = h + params["embed"]["pos"].astype(dt)[
+                    jnp.clip(positions + cfg.position_offset, 0,
+                             params["embed"]["pos"].shape[0] - 1)]
+            if cfg.embedding_norm:   # BLOOM word_embeddings_layernorm
+                h = L.apply_norm(params["embed"]["emb_norm"], h, cfg)
         inv_freq = model._inv_freq
         b_idx = jnp.arange(b)[:, None]                      # (B, 1)
         # positions < 0 mark padding: route their writes to trash block 0
@@ -148,13 +160,7 @@ class PagedModelRunner:
                 slopes = jax.lax.dynamic_slice_in_dim(
                     slopes, jax.lax.axis_index(tp.axis) * h_loc, h_loc)
 
-        def layer(h, xs, tag=None):
-            lp, l, win = xs
-            if win is None:
-                win = uniform_window
-            if cfg.act_quant_bits:   # QAT models serve with quantized acts
-                from ...compression.compress import fake_quantize_activation
-                h = fake_quantize_activation(h, cfg.act_quant_bits)
+        def qkv(lp, h):
             a_in = L.apply_norm(lp["norm1"], h, cfg)
             # L.dq dequantizes int8 per-channel weight leaves in-graph (a
             # cast, like .astype for unquantized leaves — XLA fuses it into
@@ -174,6 +180,17 @@ class PagedModelRunner:
                                  interleaved=cfg.rope_interleaved)
                 k = L.apply_rope(k, pos_safe, inv_freq,
                                  interleaved=cfg.rope_interleaved)
+            return q, k, v
+
+        def layer(h, xs, tag=None):
+            lp, l, win = xs
+            if win is None:
+                win = uniform_window
+            if cfg.act_quant_bits:   # QAT models serve with quantized acts
+                from ...compression.compress import fake_quantize_activation
+                h = fake_quantize_activation(h, cfg.act_quant_bits)
+            with jax.named_scope("attn_qkv"):
+                q, k, v = qkv(lp, h)
             # the pools are LOOP-INVARIANT inside the layer scan: this
             # layer's chunk KV rides into the attention as separate blocks
             # and comes back out as scan ys; one token-sized scatter after
@@ -186,73 +203,79 @@ class PagedModelRunner:
             # doesn't decode — quantized KV takes the gather path, where
             # the page rows are unpacked right after the gather
             quantized_kv = kpool.dtype == jnp.int8
-            if _use_pallas_paged() and not quantized_kv:
-                # decode AND chunked prefill read pages in place (no
-                # gather); causal masking, sliding windows (uniform or
-                # per-layer traced), ALiBi, and attention softcapping all
-                # run in-kernel (the FastGen blocked-flash surface); the
-                # kernel indexes (layer, head, page) in the full pool
-                from ...ops.pallas.paged_attention import paged_ragged_attention
-                out = paged_ragged_attention(
-                    q, kpool, vpool, block_tables, positions, k, v, layer=l,
-                    scale=cfg.attn_scale, window=win, alibi_slopes=slopes,
-                    softcap=cfg.attn_softcap)
-            else:
-                kvh_loc = kpool.shape[1]   # local KV heads (KVH/tp under tp)
-                lanes = kpool.shape[-1]    # D, or D + scale lanes when int8
-                kl = jnp.take(kpool, l, axis=0)   # escape hatch: copies 1/L
-                vl = jnp.take(vpool, l, axis=0)
-                kpages = kl[:, block_tables].reshape(
-                    kvh_loc, b, -1, lanes).transpose(1, 2, 0, 3)
-                vpages = vl[:, block_tables].reshape(
-                    kvh_loc, b, -1, lanes).transpose(1, 2, 0, 3)
-                if quantized_kv:
-                    kpages = dequantize_kv_lanes(kpages, dt)
-                    vpages = dequantize_kv_lanes(vpages, dt)
-                # per-query causal mask via positions: query at position p
-                # sees cache slots [0, p]; masks by slot index. The chunk's
-                # own k/v ride in raw (pre-quantization) — only pool pages
-                # pay the quantize/dequantize round-trip.
-                out = _paged_attention(q, kpages, vpages, positions, cfg,
-                                       window=win, chunk_k=k, chunk_v=v,
-                                       chunk_start=chunk_start,
-                                       alibi_slopes=slopes)
-            # row-parallel output projection: under tp the per-shard product
-            # covers only the local heads — all-reduce BEFORE the replicated
-            # bias, so the bias is added exactly once
-            y = jnp.einsum("bshd,hde->bse", out, L.dq(lp["attn"]["wo"], dt))
-            if tp is not None:
-                y = tp.coll.psum_attn(y)
-            if "bo" in lp["attn"]:   # presence-keyed: out_bias may differ from use_bias
-                y = y + L.bcast(lp["attn"]["bo"].astype(dt), y.ndim)
-            if cfg.sandwich_norm:   # Gemma-2 post-attn output norm
-                y = L.apply_norm(lp["norm3"], y, cfg)
-            if cfg.parallel_block:   # NeoX/Falcon: attn and mlp share input
-                m_in = L.apply_norm(lp["norm2"], h, cfg)
-            else:
-                h = h + y
-                m_in = L.apply_norm(lp["norm2"], h, cfg)
-            if cfg.is_moe if tag is None else tag == "moe":   # group tag overrides
-                mlp_out, _ = L.apply_moe_mlp(lp["mlp"], m_in, cfg)
-            else:
-                mlp_out = L.apply_mlp(
-                    lp["mlp"], m_in, cfg,
-                    reduce=tp.coll.psum_mlp if tp is not None else None)
-            if cfg.sandwich_norm:
-                mlp_out = L.apply_norm(lp["norm4"], mlp_out, cfg)
-            h = h + y + mlp_out if cfg.parallel_block else h + mlp_out
+            with jax.named_scope("paged_attn"):
+                if _use_pallas_paged() and not quantized_kv:
+                    # decode AND chunked prefill read pages in place (no
+                    # gather); causal masking, sliding windows (uniform or
+                    # per-layer traced), ALiBi, and attention softcapping all
+                    # run in-kernel (the FastGen blocked-flash surface); the
+                    # kernel indexes (layer, head, page) in the full pool
+                    from ...ops.pallas.paged_attention import \
+                        paged_ragged_attention
+                    out = paged_ragged_attention(
+                        q, kpool, vpool, block_tables, positions, k, v, layer=l,
+                        scale=cfg.attn_scale, window=win, alibi_slopes=slopes,
+                        softcap=cfg.attn_softcap)
+                else:
+                    kvh_loc = kpool.shape[1]   # local KV heads (KVH/tp under tp)
+                    lanes = kpool.shape[-1]    # D, or D + scale lanes when int8
+                    kl = jnp.take(kpool, l, axis=0)   # escape hatch: copies 1/L
+                    vl = jnp.take(vpool, l, axis=0)
+                    kpages = kl[:, block_tables].reshape(
+                        kvh_loc, b, -1, lanes).transpose(1, 2, 0, 3)
+                    vpages = vl[:, block_tables].reshape(
+                        kvh_loc, b, -1, lanes).transpose(1, 2, 0, 3)
+                    if quantized_kv:
+                        kpages = dequantize_kv_lanes(kpages, dt)
+                        vpages = dequantize_kv_lanes(vpages, dt)
+                    # per-query causal mask via positions: query at position p
+                    # sees cache slots [0, p]; masks by slot index. The chunk's
+                    # own k/v ride in raw (pre-quantization) — only pool pages
+                    # pay the quantize/dequantize round-trip.
+                    out = _paged_attention(q, kpages, vpages, positions, cfg,
+                                           window=win, chunk_k=k, chunk_v=v,
+                                           chunk_start=chunk_start,
+                                           alibi_slopes=slopes)
+            with jax.named_scope("attn_out"):
+                # row-parallel output projection: under tp the per-shard product
+                # covers only the local heads — all-reduce BEFORE the replicated
+                # bias, so the bias is added exactly once
+                y = jnp.einsum("bshd,hde->bse", out, L.dq(lp["attn"]["wo"], dt))
+                if tp is not None:
+                    y = tp.coll.psum_attn(y)
+                if "bo" in lp["attn"]:   # presence-keyed: out_bias may differ from use_bias
+                    y = y + L.bcast(lp["attn"]["bo"].astype(dt), y.ndim)
+                if cfg.sandwich_norm:   # Gemma-2 post-attn output norm
+                    y = L.apply_norm(lp["norm3"], y, cfg)
+            with jax.named_scope("mlp"):
+                if cfg.parallel_block:   # NeoX/Falcon: attn and mlp share input
+                    m_in = L.apply_norm(lp["norm2"], h, cfg)
+                else:
+                    h = h + y
+                    m_in = L.apply_norm(lp["norm2"], h, cfg)
+                if cfg.is_moe if tag is None else tag == "moe":   # group tag overrides
+                    mlp_out, _ = L.apply_moe_mlp(lp["mlp"], m_in, cfg)
+                else:
+                    mlp_out = L.apply_mlp(
+                        lp["mlp"], m_in, cfg,
+                        reduce=tp.coll.psum_mlp if tp is not None else None)
+                if cfg.sandwich_norm:
+                    mlp_out = L.apply_norm(lp["norm4"], mlp_out, cfg)
+                h = h + y + mlp_out if cfg.parallel_block else h + mlp_out
             # quantize-at-append: the chunk's KV leaves the layer already in
             # pool representation, so the commit scatter in _run_layers is
             # dtype-blind and the pool never holds a float row
-            if quantized_kv:
-                return h, (quantize_kv_lanes(k), quantize_kv_lanes(v))
-            return h, (k.astype(kpool.dtype), v.astype(vpool.dtype))
+            with jax.named_scope("kv_commit"):
+                if quantized_kv:
+                    return h, (quantize_kv_lanes(k), quantize_kv_lanes(v))
+                return h, (k.astype(kpool.dtype), v.astype(vpool.dtype))
 
         h, kpool, vpool = self._run_layers(layer, h, params, kpool, vpool,
                                            windows, blk, off)
-        h = L.apply_norm(params["final_norm"], h, cfg)
-        return (self._head(params, h, valid_counts, all_logits, tp=tp),
-                kpool, vpool)
+        with jax.named_scope("lm_head"):
+            h = L.apply_norm(params["final_norm"], h, cfg)
+            logits = self._head(params, h, valid_counts, all_logits, tp=tp)
+        return logits, kpool, vpool
 
     def _run_layers(self, layer, h, params, kpool, vpool, windows, blk, off):
         """Drive ``layer`` over the stack following the model's layer plan
@@ -274,11 +297,12 @@ class PagedModelRunner:
         h, (ck_all, cv_all) = walk_layer_plan(
             model._plan, model._groups, params["layers"],
             (layer_ids, windows), h, body)
-        # (L, B, C, KVH, D) chunk KV → pool[:, :, blk, off]: the advanced
-        # (B, C) indices are contiguous, so the indexed window is
-        # (L, KVH, B, C, D)
-        kpool = kpool.at[:, :, blk, off].set(ck_all.transpose(0, 3, 1, 2, 4))
-        vpool = vpool.at[:, :, blk, off].set(cv_all.transpose(0, 3, 1, 2, 4))
+        with jax.named_scope("kv_commit"):
+            # (L, B, C, KVH, D) chunk KV → pool[:, :, blk, off]: the advanced
+            # (B, C) indices are contiguous, so the indexed window is
+            # (L, KVH, B, C, D)
+            kpool = kpool.at[:, :, blk, off].set(ck_all.transpose(0, 3, 1, 2, 4))
+            vpool = vpool.at[:, :, blk, off].set(cv_all.transpose(0, 3, 1, 2, 4))
         return h, kpool, vpool
 
     def _head(self, params, h, valid_counts, all_logits=False, tp=None):
@@ -366,8 +390,8 @@ class PagedModelRunner:
         context's spec tree. check_vma is off — replication of the
         unmapped outputs is by construction (every carry input is
         replicated and every shard-varying intermediate passes through a
-        psum/all-gather before reaching them), and the stats lanes carry a
-        per-shard copy precisely so ``DeviceSlotTable.stats_delta`` can
+        psum/all-gather before reaching them); every device keeps its own
+        copy of the stats vector, so ``DeviceSlotTable.stats_delta`` can
         ASSERT that construction in debug mode instead of trusting it."""
         tp = self.tp
         return jax.shard_map(core, mesh=tp.mesh, in_specs=carry_specs,
@@ -414,7 +438,8 @@ class PagedModelRunner:
                     return _serving_scan_body(fwd, params, prompts,
                                               prompt_lens, new_limits,
                                               no_eos, temps, block_tables,
-                                              width, greedy)
+                                              width, greedy,
+                                              window=self.stat_window)
 
                 zero = jnp.zeros((b,), jnp.int32)
                 no = jnp.zeros((b,), bool)
@@ -483,24 +508,21 @@ class PagedModelRunner:
 
             Tensor-parallel (``self.tp`` set): the same program compiles
             under shard_map on the 1-D tp mesh — params and KV pools
-            sharded, every slot-state carry replicated, and ``stats``
-            per-shard as (tp, N_STATS) (each shard accumulates its own
-            replica-consistent row; the boundary reads shard 0).
+            sharded, every slot-state carry replicated, ``stats`` among
+            them (each shard accumulates its own replica-consistent copy;
+            the boundary reads one).
             """
             def core(params, prompts, prompt_lens, limits, eos_ids, temps,
                      tables, cached, produced, last_tok, done, poison,
                      nonfinite, stats, rng, kpool, vpool):
-                if tp is not None:
-                    stats = stats[0]        # this shard's (N_STATS,) row
                 body = _serving_scan_body(fwd, params, prompts, prompt_lens,
                                           limits, eos_ids, temps, tables,
-                                          width, greedy, repair=repair)
+                                          width, greedy, repair=repair,
+                                          window=self.stat_window)
                 carry = (cached, produced, last_tok, done, poison, nonfinite,
                          stats, rng, kpool, vpool)
                 carry, (toks, emit) = jax.lax.scan(body, carry, None,
                                                    length=steps)
-                if tp is not None:
-                    carry = carry[:6] + (carry[6][None],) + carry[7:]
                 return (toks, emit) + carry
 
             args = (params, prompts, prompt_lens, limits, eos_ids, temps,
@@ -508,11 +530,11 @@ class PagedModelRunner:
                     nonfinite, stats, rng, kpool, vpool)
             if tp is None:
                 return core(*args)
-            rep, kv, st = P(), tp.kv_spec, tp.stats_spec
+            rep, kv = P(), tp.kv_spec
             return self._tp_call(
                 core, args,
-                (tp.param_specs,) + (rep,) * 12 + (st, rep, kv, kv),
-                (rep,) * 8 + (st, rep, kv, kv))
+                (tp.param_specs,) + (rep,) * 14 + (kv, kv),
+                (rep,) * 10 + (kv, kv))
 
         return loop
 
@@ -554,18 +576,15 @@ class PagedModelRunner:
                      eos_ids, temps, tables, cached, produced, last_tok,
                      penult, done, poison, nonfinite, stats, rng, kpool,
                      vpool, dkpool, dvpool):
-                if tp is not None:
-                    stats = stats[0]
                 body = _serving_scan_body(
                     fwd, params, prompts, prompt_lens, limits, eos_ids,
                     temps, tables, width, greedy,
-                    draft=(draft_fwd, draft_params, gamma), repair=repair)
+                    draft=(draft_fwd, draft_params, gamma), repair=repair,
+                    window=self.stat_window)
                 carry = (cached, produced, last_tok, penult, done, poison,
                          nonfinite, stats, rng, kpool, vpool, dkpool, dvpool)
                 carry, (toks, emit) = jax.lax.scan(body, carry, None,
                                                    length=steps)
-                if tp is not None:
-                    carry = carry[:7] + (carry[7][None],) + carry[8:]
                 return (toks, emit) + carry
 
             args = (params, draft_params, prompts, prompt_lens, limits,
@@ -574,12 +593,12 @@ class PagedModelRunner:
                     vpool, dkpool, dvpool)
             if tp is None:
                 return core(*args)
-            rep, kv, st = P(), tp.kv_spec, tp.stats_spec
+            rep, kv = P(), tp.kv_spec
             return self._tp_call(
                 core, args,
                 (tp.param_specs, draft_runner.tp.param_specs)
-                + (rep,) * 13 + (st, rep, kv, kv, kv, kv),
-                (rep,) * 9 + (st, rep, kv, kv, kv, kv))
+                + (rep,) * 15 + (kv, kv, kv, kv),
+                (rep,) * 11 + (kv, kv, kv, kv))
 
         return loop
 
@@ -618,7 +637,8 @@ class PagedModelRunner:
                                               no_eos, temps, block_tables,
                                               width, greedy,
                                               draft=(draft_fwd, draft_params,
-                                                     gamma))
+                                                     gamma),
+                                              window=self.stat_window)
 
                 zero = jnp.zeros((b,), jnp.int32)
                 no = jnp.zeros((b,), bool)
@@ -697,7 +717,7 @@ class PagedModelRunner:
 
 def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                        temps, tables, width, greedy, draft=None,
-                       repair=False):
+                       repair=False, window=None):
     """Shared scan-step for ``mixed_loop`` and ``frame_loop`` — the in-graph
     SplitFuse scheduling arithmetic lives in exactly one place.
 
@@ -740,39 +760,46 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
     if draft is not None:
         return _spec_scan_body(fwd, params, prompts, prompt_lens, limits,
                                eos_ids, temps, tables, width, greedy, *draft,
-                               repair=repair)
+                               repair=repair, window=window)
 
     def body(carry, _):
         (cached, produced, last_tok, done, poison, nonfinite, stats, rng,
          kpool, vpool) = carry
         prev_last, prev_done = last_tok, done
-        prefilling, active, w, ids, positions = _wide_plan(
-            prompts, prompt_lens, limits, width, cached, produced, last_tok,
-            done)
+        with jax.named_scope("frame_plan"):
+            prefilling, active, w, ids, positions = _wide_plan(
+                prompts, prompt_lens, limits, width, cached, produced,
+                last_tok, done)
+            # the attention's work this step (before a repair zeroes w:
+            # the step was computed either way)
+            kv_read, attn_pairs = _attn_work(cached, w, window)
         logits, kpool, vpool = fwd(params, ids, positions, tables, w,
                                    kpool, vpool)
-        logits = _inject_poison(logits, poison)
-        if greedy:
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            rng, sub = jax.random.split(rng)
-            nxt = sample_logits_per_row(logits, sub, temps)
-        emit, last_tok, done = _wide_emit(active, prefilling, cached, w,
-                                          prompt_lens, eos_ids, nxt,
-                                          last_tok, done)
-        emit, done, nonfinite, bad = _finite_check(logits, active, emit,
-                                                   done, nonfinite)
-        if repair:
-            # the row made no progress this step: restore the pre-step
-            # carry (un-freeze, un-advance) — emit is already cleared
-            last_tok = jnp.where(bad, prev_last, last_tok)
-            done = jnp.where(bad, prev_done, done)
-            w = jnp.where(bad, 0, w)
-        stats = stats + _stat_delta(
-            emitted=emit, active=active,
-            prefill_toks=jnp.where(prefilling, w, 0),
-            eos=emit & (nxt == eos_ids),
-            target_fwd=active & ~prefilling)
+        with jax.named_scope("sample"):
+            logits = _inject_poison(logits, poison)
+            if greedy:
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            else:
+                rng, sub = jax.random.split(rng)
+                nxt = sample_logits_per_row(logits, sub, temps)
+        with jax.named_scope("frame_plan"):
+            emit, last_tok, done = _wide_emit(active, prefilling, cached, w,
+                                              prompt_lens, eos_ids, nxt,
+                                              last_tok, done)
+            emit, done, nonfinite, bad = _finite_check(logits, active, emit,
+                                                       done, nonfinite)
+            if repair:
+                # the row made no progress this step: restore the pre-step
+                # carry (un-freeze, un-advance) — emit is already cleared
+                last_tok = jnp.where(bad, prev_last, last_tok)
+                done = jnp.where(bad, prev_done, done)
+                w = jnp.where(bad, 0, w)
+            stats = stats + _stat_delta(
+                emitted=emit, active=active,
+                prefill_toks=jnp.where(prefilling, w, 0),
+                eos=emit & (nxt == eos_ids),
+                target_fwd=active & ~prefilling,
+                kv_read=kv_read, attn_pairs=attn_pairs)
         return ((cached + w, produced + emit.astype(jnp.int32),
                  last_tok, done, poison, nonfinite, stats, rng, kpool,
                  vpool),
@@ -807,13 +834,29 @@ def _finite_check(logits, active, emit, done, nonfinite):
     return emit, done | bad, nonfinite | bad, bad
 
 
+def _attn_work(cached, w, window):
+    """Per row, what the target's attention must do for a step in which
+    the row consumes ``w`` positions after ``cached`` committed ones: KV
+    positions read, ``min(cached + w, window + w)`` (``cached + w`` without
+    a window; 0 for a row that sits the step out), and query x key pairs,
+    ``w`` times that. The host mirror in the telemetry tests replays
+    exactly this."""
+    kv = cached + w
+    if window is not None:
+        kv = jnp.minimum(kv, window + w)
+    kv = jnp.where(w > 0, kv, 0)
+    return kv, w * kv
+
+
 def _stat_delta(emitted=None, active=None, prefill_toks=None, eos=None,
-                target_fwd=None, drafted=None, accepted=None):
+                target_fwd=None, drafted=None, accepted=None, kv_read=None,
+                attn_pairs=None):
     """One step's (N_STATS,) in-graph counter increment. Each keyword is a
     bool mask / int array to sum, or None for zero — the layout is pinned by
     the STAT_* indices in ``telemetry.py`` and the host-mirror replay tests
     assert the resulting totals exactly."""
-    vals = [emitted, active, prefill_toks, eos, target_fwd, drafted, accepted]
+    vals = [emitted, active, prefill_toks, eos, target_fwd, drafted, accepted,
+            kv_read, attn_pairs]
     z = jnp.zeros((), jnp.int32)
     out = [z if v is None else jnp.sum(v.astype(jnp.int32)) for v in vals]
     assert len(out) == N_STATS
@@ -860,7 +903,7 @@ def _wide_emit(active, prefilling, cached, w, prompt_lens, eos_ids, nxt,
 
 def _spec_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                     temps, tables, width, greedy, draft_fwd, draft_params,
-                    gamma, repair=False):
+                    gamma, repair=False, window=None):
     """Speculative variant of the serving scan step (see
     ``_serving_scan_body``). Carry: (cached, produced, last_tok, penult,
     done, poison, nonfinite, stats, rng, kpool, vpool, dkpool, dvpool);
@@ -888,9 +931,11 @@ def _spec_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
              stats, rng, kpool, vpool, dkpool, dvpool) = carry
             prev_last, prev_done = last_tok, done
             b = cached.shape[0]
-            prefilling, active, w, ids, positions = _wide_plan(
-                prompts, prompt_lens, limits, width, cached, produced,
-                last_tok, done)
+            with jax.named_scope("frame_plan"):
+                prefilling, active, w, ids, positions = _wide_plan(
+                    prompts, prompt_lens, limits, width, cached, produced,
+                    last_tok, done)
+                kv_read, attn_pairs = _attn_work(cached, w, window)
             logits, kpool, vpool = fwd(params, ids, positions, tables, w,
                                        kpool, vpool)
             logits = _inject_poison(logits, poison)
@@ -933,7 +978,8 @@ def _spec_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
             stats = stats + _stat_delta(
                 emitted=emit, active=active,
                 prefill_toks=jnp.where(prefilling, w, 0),
-                eos=emit & (nxt == eos_ids))
+                eos=emit & (nxt == eos_ids),
+                kv_read=kv_read, attn_pairs=attn_pairs)
             return ((cached + w, produced + emit.astype(jnp.int32), last_tok,
                      penult, done, poison, nonfinite, stats, rng, kpool,
                      vpool, dkpool, dvpool),
@@ -1029,10 +1075,11 @@ def _spec_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
         # verify forwards == active rows (column 0 of the emit mask); the
         # accepted-draft count is the emit columns past it — the device-side
         # twin of the host arithmetic serve_stats always used
+        kv_read, attn_pairs = _attn_work(cached, k_out * av, window)
         stats = stats + _stat_delta(
             emitted=emit, active=active, eos=emit & is_eos,
             target_fwd=active, drafted=gamma * active.astype(jnp.int32),
-            accepted=emit[:, 1:])
+            accepted=emit[:, 1:], kv_read=kv_read, attn_pairs=attn_pairs)
         return ((cached + m, produced + m, last_tok, penult, done, poison,
                  nonfinite, stats, rng, kpool, vpool, dkpool, dvpool),
                 (jnp.where(emit, e, -1), emit))

@@ -156,8 +156,6 @@ class DeviceSlotTable:
         self.debug_replicas = debug_replicas
         if tp is not None:
             self._rep = tp.rep()
-            self._stats_sharding = jax.sharding.NamedSharding(
-                tp.mesh, tp.stats_spec)
         zi = lambda *shape: self._dev(jnp.zeros(shape, jnp.int32))  # noqa: E731
         # device state (frame-loop inputs; carry arrays are donated)
         self.prompts = zi(n_slots, max(1, prompt_width))
@@ -180,8 +178,7 @@ class DeviceSlotTable:
         # in-graph telemetry counters (telemetry.N_STATS): accumulate on the
         # donated carry; the host reads AND rebases them only at frame
         # boundaries (stats_delta), so the int32 lanes can never wrap
-        # within one read window. Under tp the vector is PER-SHARD,
-        # (tp, N_STATS) laid out one row per shard (tp.stats_spec).
+        # within one read window. Under tp it is replicated like the rest.
         self.stats = self._fresh_stats()
         # host mirrors — admission control only
         self.uid_of_slot = np.full((n_slots,), -1, np.int64)
@@ -204,10 +201,7 @@ class DeviceSlotTable:
         return jax.device_put(jnp.asarray(x), self._rep)
 
     def _fresh_stats(self):
-        if self.tp is None:
-            return zero_stats()
-        return jax.device_put(zero_stats(self.tp.degree),
-                              self._stats_sharding)
+        return self._dev(zero_stats())
 
     @property
     def committed_h(self) -> np.ndarray:
@@ -391,16 +385,6 @@ class DeviceSlotTable:
             steps=steps, greedy=greedy, gamma=gamma, repair=repair)
         return toks, emit
 
-    def run_frame(self, runner, params, kv, width: int, steps: int,
-                  greedy: bool, draft=None, repair=False):
-        """Execute one K-step frame: dispatch, then fetch the
-        (steps, B[, gamma+1]) token/emit pair — the only device→host
-        transfer a frame performs (``stats_delta`` adds one more tiny
-        frame-BOUNDARY read when telemetry is on)."""
-        toks, emit = self.dispatch_frame(runner, params, kv, width, steps,
-                                         greedy, draft=draft, repair=repair)
-        return np.asarray(toks), np.asarray(emit)
-
     def set_poison(self, uids: List[int]) -> None:
         """Arm the device poison flag for live rows (fault injection): the
         next frame NaNs their logits in-graph, exercising the REAL
@@ -460,31 +444,30 @@ class DeviceSlotTable:
         discards the first (backlog, possibly wrapped) delta. Both the
         read and the fresh zero vector are frame-boundary transfers.
 
-        Tensor-parallel: the device vector is (tp, N_STATS), one row per
-        shard. Every row is replica-consistent by construction — each
-        shard's counters derive exclusively from replicated carry values
-        (emit masks, active masks, post-collective logits) — so the
-        steady-state read touches SHARD 0 ONLY (one small host read,
-        preserving the zero-in-frame-D2H budget per boundary). With
-        ``debug_replicas`` the read widens to all shards and ASSERTS they
-        agree, turning a hypothetical replication bug (a collective missed
-        somewhere in the forward) into a loud boundary failure instead of
-        silently skewed telemetry."""
-        if self.tp is None:
-            delta = np.asarray(self.stats).astype(np.int64)
-        elif self.debug_replicas:
-            rows = np.asarray(self.stats).astype(np.int64)   # (tp, N_STATS)
+        Tensor-parallel: the vector is replicated like the rest of the
+        carry, and every device keeps the copy its own shard accumulated.
+        The copies agree by construction — each shard's counters derive
+        exclusively from replicated carry values (emit masks, active
+        masks, post-collective logits) — so the steady-state read fetches
+        ONE copy (one small host read, preserving the zero-in-frame-D2H
+        budget per boundary). With ``debug_replicas`` the read widens to
+        every device's copy and ASSERTS they agree, turning a hypothetical
+        replication bug (a collective missed somewhere in the forward)
+        into a loud boundary failure instead of silently skewed
+        telemetry."""
+        if self.tp is not None and self.debug_replicas:
+            rows = np.stack([np.asarray(s.data) for s in
+                             self.stats.addressable_shards])  # (tp, N_STATS)
             if not (rows == rows[0]).all():
                 raise AssertionError(
                     "frame stats diverged across tp shards — a shard-"
                     f"varying value leaked into the counters:\n{rows}")
-            delta = rows[0]
-        else:
-            shard0 = next(s for s in self.stats.addressable_shards
-                          if (s.index[0].start or 0) == 0)
-            delta = np.asarray(shard0.data).astype(np.int64).reshape(-1)
+        delta = np.asarray(self.stats).astype(np.int64)
         self.stats = self._fresh_stats()
-        return delta
+        # the lanes are int32 counts rebased at every read; read as
+        # unsigned they hold 2^32 before they wrap, which
+        # ``check_stat_range`` holds the largest per-frame delta under
+        return delta & 0xFFFFFFFF
 
     def absorb(self, toks: np.ndarray, emit: np.ndarray, width: int):
         """Replay the frame against the host mirrors (same arithmetic as the

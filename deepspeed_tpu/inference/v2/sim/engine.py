@@ -638,7 +638,7 @@ class SimEngine:
                          live_slots=len(self._rows),
                          kv_blocks_in_use=self.kv.num_blocks
                          - self.kv.free_blocks,
-                         arrival_ewma=ewma, recompiled_programs=0,
+                         arrival_ewma=ewma,
                          queue_depth=sched.queued_count())
             for uid in first_uids:
                 # stamped POST-advance: the first token exists when the

@@ -30,6 +30,14 @@ host round-trips, in three layers:
    ``monitor.MonitorMaster``), and an opt-in ``jax.profiler``
    ``TraceAnnotation`` wrapper so device profiles line up with frames.
 
+4. **Boundary phases** — ``phase(name)`` wraps each stretch of host work
+   between two frames (``PHASES``): its exclusive time accumulates into
+   ``counters["host_<name>_ns"]``, and with ``trace=True`` it is a
+   ``serve/<name>`` span on the profiler's clock, so an idle gap of the
+   device reads as poll, admit, plan, fetch, absorb, publish, retire, the
+   consumer's ``yield`` or an empty server's ``idle`` instead of a JAX
+   internal.
+
 ``engine.serve_stats`` is a thin read-through view over this subsystem
 (``ServingTelemetry.serve_view``): the dict the pre-telemetry tests and
 ``serving_bench.py`` already consume, now fed from the device counters.
@@ -41,11 +49,14 @@ program variant means toggling telemetry never recompiles anything.
 """
 
 import math
+import threading
 import time
 from collections import deque
 from contextlib import nullcontext
 from typing import Dict, List, Optional
 
+import jax
+import jax.monitoring
 import numpy as np
 
 from ...utils.logging import logger
@@ -66,6 +77,13 @@ from ...utils.logging import logger
 #                  verify forwards and are not counted here)
 #   DRAFTED        draft tokens proposed (gamma per verify forward)
 #   ACCEPTED       accepted-and-emitted draft tokens (emit columns >= 1)
+#   KV_READ        KV positions the step's queries must read, over active
+#                  rows: min(cached + w, sliding_window + w), or cached + w
+#                  without a (uniform) window — w is the row's chunk width
+#   ATTN_PAIRS     query x key pairs that attention must score: w * KV_READ
+# The last two are work, not events: the host splits each frame's delta by
+# the frame's width into ``<name>_narrow`` / ``<name>_wide`` counters
+# (SPLIT_STAT_NAMES), the operands of the paged kernels' roofline shares.
 STAT_EMITTED = 0
 STAT_ACTIVE_STEPS = 1
 STAT_PREFILL_TOKS = 2
@@ -73,22 +91,128 @@ STAT_EOS = 3
 STAT_TARGET_FWD = 4
 STAT_DRAFTED = 5
 STAT_ACCEPTED = 6
-N_STATS = 7
+STAT_KV_READ = 7
+STAT_ATTN_PAIRS = 8
+N_STATS = 9
 
 STAT_NAMES = ("tokens_emitted", "active_row_steps", "prefill_tokens",
               "eos_events", "target_forwards", "drafted_tokens",
               "accepted_draft_tokens")
+#: lanes after STAT_NAMES, split by frame width at absorption
+SPLIT_STAT_NAMES = ("kv_positions_read", "attn_pairs")
+
+#: host work between two frames, in loop order; ``dispatch`` and ``fetch``
+#: lie inside the ``serve_frame/...`` span (fetch is the host waiting for
+#: the chip), ``yield`` is the consumer's time. ``idle`` is ``poll`` on an
+#: empty server (nothing live, nothing queued): the wait for the next
+#: arrival, which is no work of the boundary's
+PHASES = ("idle", "poll", "admit", "plan", "dispatch", "fetch", "absorb",
+          "publish", "retire", "yield")
 
 
-def zero_stats(tp_degree=None):
-    """Fresh device stat vector for a frame carry — ``(N_STATS,)``, or the
-    per-shard ``(tp_degree, N_STATS)`` stack a tensor-parallel frame loop
-    carries (row r is shard r's accumulator; see
-    ``DeviceSlotTable.stats_delta``)."""
+class _CompileLog:
+    """Every program this process asks XLA for, by the thread that asked:
+    JAX's backend-compile event (it wraps a load from the persistent cache
+    too — either way the caller waited). One process-wide listener; a
+    serve loop reads its own thread's totals, so replicas driven by
+    threads of one process do not count each other's programs."""
+
+    def __init__(self):
+        try:
+            from jax._src.dispatch import BACKEND_COMPILE_EVENT as event
+        except ImportError:
+            event = "/jax/core/compile/backend_compile_duration"
+        self.event = event
+        self.by_thread: Dict[int, List[int]] = {}
+        jax.monitoring.register_event_duration_secs_listener(self._on_built)
+
+    def _on_built(self, event, duration, **kwargs):
+        del kwargs
+        if event == self.event:
+            rec = self.by_thread.setdefault(threading.get_ident(), [0, 0])
+            rec[0] += 1
+            rec[1] += int(duration * 1e9)
+
+    def totals(self):
+        """(programs, nanoseconds waited) of the calling thread so far."""
+        return tuple(self.by_thread.get(threading.get_ident(), (0, 0)))
+
+
+_COMPILE_LOG: Optional[_CompileLog] = None
+
+
+def compile_log() -> _CompileLog:
+    """The process's one compile listener, registered at first use."""
+    global _COMPILE_LOG
+    if _COMPILE_LOG is None:
+        _COMPILE_LOG = _CompileLog()
+    return _COMPILE_LOG
+
+
+class _Phase:
+    """One ``ServingTelemetry.phase`` span. Phases nest (a ``yield`` inside
+    ``retire``): the outer one pauses while the inner runs, so the
+    ``host_*_ns`` counters are exclusive times and tile the loop."""
+
+    __slots__ = ("tel", "name", "key", "ann", "timed")
+
+    def __init__(self, tel, name):
+        self.tel, self.name, self.key = tel, name, f"host_{name}_ns"
+        self.ann, self.timed = None, False
+
+    def __enter__(self):
+        tel = self.tel
+        if tel.trace:
+            self.ann = jax.profiler.TraceAnnotation("serve/" + self.name)
+            self.ann.__enter__()
+        if tel.enabled:
+            if tel._compile_base is None:
+                tel._sync_compiles()     # programs count from the first phase
+            now = tel.phase_clock()
+            stack, c = tel._phase_stack, tel.counters
+            if stack:
+                outer = stack[-1]
+                c[outer[0]] = c.get(outer[0], 0) + now - outer[1]
+            stack.append([self.key, now])
+            self.timed = True
+        return self
+
+    def __exit__(self, *exc):
+        tel = self.tel
+        stack, c = tel._phase_stack, tel.counters
+        if self.timed and stack and stack[-1][0] == self.key:
+            now = tel.phase_clock()
+            c[self.key] = c.get(self.key, 0) + now - stack.pop()[1]
+            if stack:
+                stack[-1][1] = now
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        return False
+
+
+def check_stat_range(slots: int, width: int, steps: int,
+                     context: int) -> None:
+    """The work lanes are int32 sums that wrap modulo 2^32 and that the
+    host reads as unsigned at every boundary, so a frame's true delta must
+    stay under 2^32. The largest is ATTN_PAIRS with every slot consuming
+    ``width`` positions over ``context`` readable ones (the sliding window,
+    or the longest sequence) at each of ``steps`` steps; a configuration
+    past that is refused here instead of reading wrapped counters."""
+    worst = slots * steps * width * (context + width)
+    if worst >= 1 << 32:
+        raise ValueError(
+            f"a frame of {slots} slots x {steps} steps x {width} positions "
+            f"over {context} of context can score {worst} query x key "
+            "pairs, which the int32 frame counters cannot hold (2^32): "
+            "lower frame_steps or prefill_chunk_size")
+
+
+def zero_stats():
+    """Fresh ``(N_STATS,)`` device stat vector for a frame carry (a
+    tensor-parallel frame loop carries it replicated, like every other slot
+    array; see ``DeviceSlotTable.stats_delta``)."""
     import jax.numpy as jnp
-    if tp_degree is None:
-        return jnp.zeros((N_STATS,), jnp.int32)
-    return jnp.zeros((tp_degree, N_STATS), jnp.int32)
+    return jnp.zeros((N_STATS,), jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -223,10 +347,13 @@ class ServingTelemetry:
                  clock=time.monotonic, record_spans: bool = False,
                  max_spans: int = 1024,
                  defer_warn_interval_s: float = 5.0,
-                 slo_window: int = 64, steps_trace_len: int = 128):
+                 slo_window: int = 64, steps_trace_len: int = 128,
+                 phase_clock=time.perf_counter_ns):
         self.enabled = enabled
         self.trace = trace
         self.clock = clock
+        # nanosecond clock of the boundary phases (injectable like ``clock``)
+        self.phase_clock = phase_clock
         self.record_spans = record_spans
         self.spans: deque = deque(maxlen=max_spans)
         self.defer_warn_interval_s = defer_warn_interval_s
@@ -261,7 +388,24 @@ class ServingTelemetry:
         """Zero every counter, histogram, and open span (new serve() run)."""
         self._gamma = 0
         self._kv_block_bytes = 0
+        self._phase_stack: List[list] = []
+        # the serve loop's thread totals in the compile log when its first
+        # phase began (None until then): programs_requested counts from it
+        self._compile_base = None
         self.counters: Dict[str, int] = {n: 0 for n in STAT_NAMES}
+        # work the frames did and computed: slots x width x steps positions
+        # a frame, and the attention's reads and pairs by frame width
+        self.counters["positions_computed"] = 0
+        for n in SPLIT_STAT_NAMES:
+            self.counters[f"{n}_narrow"] = 0
+            self.counters[f"{n}_wide"] = 0
+        # exclusive host time of each boundary phase (phase())
+        for n in PHASES:
+            self.counters[f"host_{n}_ns"] = 0
+        # every program the serve loop's thread asked XLA for since its
+        # first phase (frame programs AND the small ones admission
+        # dispatches), and the time it waited for them
+        self.counters.update(programs_requested=0, compile_wait_ns=0)
         self.counters.update(requests_enqueued=0, requests_admitted=0,
                              requests_retired=0, admission_deferrals=0,
                              requests_shed=0, requests_preempted=0,
@@ -831,10 +975,30 @@ class ServingTelemetry:
     # frame boundary (device counter absorption + fan-out)
     # ------------------------------------------------------------------
 
+    def phase(self, name: str):
+        """Context manager around one boundary phase of the serve loop
+        (``PHASES``): adds its exclusive elapsed ``phase_clock`` time to
+        ``counters["host_<name>_ns"]`` whenever ``enabled``, and is a
+        ``serve/<name>`` ``TraceAnnotation`` when ``trace`` is set — two
+        clock reads and a dict add otherwise."""
+        return _Phase(self, name)
+
+    def _sync_compiles(self) -> None:
+        """Programs the calling (serve-loop) thread asked XLA for since the
+        loop's first phase — the ``recompiled_programs`` gauge used to see
+        frame programs only, while admission asks for ~18 small ones per
+        new batch size and every stream waits for them."""
+        n, ns = compile_log().totals()
+        if self._compile_base is None:
+            self._compile_base = (n, ns)
+        self.counters["programs_requested"] = n - self._compile_base[0]
+        self.counters["compile_wait_ns"] = ns - self._compile_base[1]
+        self.gauges["recompiled_programs"] = \
+            self.counters["programs_requested"]
+
     def on_frame(self, *, delta: np.ndarray, width: int, steps: int,
                  live_slots: int, kv_blocks_in_use: int,
-                 arrival_ewma: float, recompiled_programs: int,
-                 queue_depth: int) -> None:
+                 arrival_ewma: float, queue_depth: int) -> None:
         """Absorb one frame's device counter DELTA (``(N_STATS,)`` int64)
         plus the host-known frame facts, update the serve_stats view, and
         fan out to the attached monitor. When telemetry is disabled the
@@ -846,6 +1010,24 @@ class ServingTelemetry:
             return
         for i, name in enumerate(STAT_NAMES):
             self.counters[name] += int(delta[i])
+        if self.trace:
+            # this frame's work on the profiler's clock, right after its
+            # serve_frame span: a reduction of the trace matches work to
+            # device time frame by frame, with no second clock
+            with jax.profiler.TraceAnnotation(
+                    "serve/frame_work", width=width, steps=steps,
+                    **{n: int(delta[i]) for i, n in
+                       enumerate(STAT_NAMES + SPLIT_STAT_NAMES)}):
+                pass
+        split = "wide" if width > 1 else "narrow"
+        for i, name in enumerate(SPLIT_STAT_NAMES, len(STAT_NAMES)):
+            self.counters[f"{name}_{split}"] += int(delta[i])
+        # what the frame computed whatever was useful in it: every slot,
+        # every position of the width (a speculative decode step verifies
+        # gamma + 1 positions a row), every step
+        self.counters["positions_computed"] += \
+            int(self.gauges["slot_count"]) * steps * (
+                self._gamma + 1 if width == 1 and self._gamma else width)
         self.counters["frames"] += 1
         self.lifetime_frames += 1
         # run-average occupancy = active_row_steps / slot_steps_capacity
@@ -865,7 +1047,7 @@ class ServingTelemetry:
         self.gauges["kv_blocks_in_use_peak"] = max(
             self.gauges["kv_blocks_in_use_peak"], kv_blocks_in_use)
         self.gauges["queue_depth"] = queue_depth
-        self.gauges["recompiled_programs"] = recompiled_programs
+        self._sync_compiles()
         if self.gauges["slot_count"]:
             self.gauges["occupancy"] = round(
                 int(delta[STAT_ACTIVE_STEPS])
@@ -1085,9 +1267,4 @@ class ServingTelemetry:
         request lifecycle timestamps recorded here."""
         if not self.trace:
             return nullcontext()
-        try:
-            import jax
-            return jax.profiler.TraceAnnotation(
-                f"serve_frame/w{width}/s{steps}")
-        except Exception:          # profiler unavailable: degrade silently
-            return nullcontext()
+        return jax.profiler.TraceAnnotation(f"serve_frame/w{width}/s{steps}")

@@ -56,15 +56,6 @@ class TPContext:
         """Paged KV pools (L, KVH, NB, bs, D): head-wise over tp."""
         return P(None, self.axis)
 
-    @property
-    def stats_spec(self) -> P:
-        """In-graph frame counters ride per-shard as (tp, N_STATS): row r is
-        shard r's accumulator. Replica-consistent by construction (every
-        input the counters derive from is replicated), which
-        ``DeviceSlotTable.stats_delta`` exploits: read shard 0 only, and
-        assert all rows agree in debug mode."""
-        return P(self.axis, None)
-
     def rep(self) -> NamedSharding:
         """Replicated placement for carry/slot-table arrays."""
         return NamedSharding(self.mesh, P())

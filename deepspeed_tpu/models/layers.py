@@ -236,21 +236,22 @@ def apply_attention(params, x, cfg: TransformerConfig, *, positions=None, inv_fr
     if window is None and cfg.sliding_window is not None and cfg.local_attention_every is None:
         window = cfg.sliding_window   # uniform window (Mistral)
     dt = cfg.act_dtype
-    q = jnp.einsum("bse,ehd->bshd", x, params["wq"].astype(dt))
-    k = jnp.einsum("bse,ehd->bshd", x, params["wk"].astype(dt))
-    v = jnp.einsum("bse,ehd->bshd", x, params["wv"].astype(dt))
-    if cfg.use_bias or cfg.qkv_bias:
-        q = q + bcast(params["bq"].astype(dt), q.ndim)
-        k = k + bcast(params["bk"].astype(dt), k.ndim)
-        v = v + bcast(params["bv"].astype(dt), v.ndim)
-    if cfg.qk_norm:
-        q = apply_qk_norm(params["q_norm"], q, cfg)
-        k = apply_qk_norm(params["k_norm"], k, cfg)
-    if cfg.position == "rope":
-        if positions is None:
-            positions = jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
-        q = apply_rope(q, positions, inv_freq, interleaved=cfg.rope_interleaved)
-        k = apply_rope(k, positions, inv_freq, interleaved=cfg.rope_interleaved)
+    with jax.named_scope("attn_qkv"):
+        q = jnp.einsum("bse,ehd->bshd", x, params["wq"].astype(dt))
+        k = jnp.einsum("bse,ehd->bshd", x, params["wk"].astype(dt))
+        v = jnp.einsum("bse,ehd->bshd", x, params["wv"].astype(dt))
+        if cfg.use_bias or cfg.qkv_bias:
+            q = q + bcast(params["bq"].astype(dt), q.ndim)
+            k = k + bcast(params["bk"].astype(dt), k.ndim)
+            v = v + bcast(params["bv"].astype(dt), v.ndim)
+        if cfg.qk_norm:
+            q = apply_qk_norm(params["q_norm"], q, cfg)
+            k = apply_qk_norm(params["k_norm"], k, cfg)
+        if cfg.position == "rope":
+            if positions is None:
+                positions = jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
+            q = apply_rope(q, positions, inv_freq, interleaved=cfg.rope_interleaved)
+            k = apply_rope(k, positions, inv_freq, interleaved=cfg.rope_interleaved)
 
     new_cache = None
     if kv_cache is not None:
@@ -279,9 +280,10 @@ def apply_attention(params, x, cfg: TransformerConfig, *, positions=None, inv_fr
                                   window=window, impl=impl, scale=cfg.attn_scale,
                                   softcap=cfg.attn_softcap)
 
-    y = jnp.einsum("bshd,hde->bse", out, params["wo"].astype(dt))
-    if "bo" in params:
-        y = y + bcast(params["bo"].astype(dt), y.ndim)
+    with jax.named_scope("attn_out"):
+        y = jnp.einsum("bshd,hde->bse", out, params["wo"].astype(dt))
+        if "bo" in params:
+            y = y + bcast(params["bo"].astype(dt), y.ndim)
     return y, new_cache
 
 
@@ -318,6 +320,7 @@ def init_mlp(rng, cfg: TransformerConfig):
     return params, axes
 
 
+@jax.named_scope("mlp")
 def apply_mlp(params, x, cfg: TransformerConfig, reduce=None):
     """``reduce`` (tensor-parallel serving): applied to the w_out product
     BEFORE the output bias — with the intermediate dim sharded, the product
@@ -535,6 +538,7 @@ def apply_moe_grouped_ep(params, x, cfg: TransformerConfig, mesh):
     return out, aux
 
 
+@jax.named_scope("moe_mlp")
 def apply_moe_mlp(params, x, cfg: TransformerConfig):
     """Dispatch/combine via one-hot einsum (GShard-style, reference
     ``deepspeed/moe/sharded_moe.py:96 MOELayer``). Capacity-bounded, dropless

@@ -337,36 +337,44 @@ class CausalLM:
         if cfg.post_norm:
             # BERT block: norm AFTER each residual add, attention reads the
             # raw stream
-            attn_out, _ = L.apply_attention(lp["attn"], h, cfg, positions=positions,
-                                            inv_freq=self._inv_freq,
-                                            segment_ids=segment_ids,
-                                            attn_bias=attn_bias, window=window)
-            h = L.apply_norm(lp["norm1"], h + attn_out, cfg)
-            mlp_out = L.apply_mlp(lp["mlp"], h, cfg)
-            return L.apply_norm(lp["norm2"], h + mlp_out, cfg), jnp.zeros((), jnp.float32)
-        a_in = L.apply_norm(lp["norm1"], h, cfg)
-        attn_out, _ = L.apply_attention(lp["attn"], a_in, cfg, positions=positions,
-                                        inv_freq=self._inv_freq, segment_ids=segment_ids,
-                                        attn_bias=attn_bias, window=window)
-        if cfg.sandwich_norm:   # Gemma-2: norm the sublayer OUTPUT pre-residual
-            attn_out = L.apply_norm(lp["norm3"], attn_out, cfg)
-        if cfg.parallel_block:
-            # NeoX/Falcon parallel residual: attn and mlp both read the
-            # pre-attention stream; one residual add
-            m_in = L.apply_norm(lp["norm2"], h, cfg)
-        else:
-            h = h + attn_out
-            m_in = L.apply_norm(lp["norm2"], h, cfg)
-        if is_moe:
-            mlp_out, aux = L.apply_moe_mlp(lp["mlp"], m_in, cfg)
-        else:
-            mlp_out, aux = L.apply_mlp(lp["mlp"], m_in, cfg), jnp.zeros((), jnp.float32)
-        if cfg.sandwich_norm:
-            mlp_out = L.apply_norm(lp["norm4"], mlp_out, cfg)
-        if cfg.parallel_block:
-            return h + attn_out + mlp_out, aux
-        return h + mlp_out, aux
+            with jax.named_scope("attn"):
+                attn_out, _ = L.apply_attention(
+                    lp["attn"], h, cfg, positions=positions,
+                    inv_freq=self._inv_freq, segment_ids=segment_ids,
+                    attn_bias=attn_bias, window=window)
+                h = L.apply_norm(lp["norm1"], h + attn_out, cfg)
+            with jax.named_scope("mlp"):
+                mlp_out = L.apply_mlp(lp["mlp"], h, cfg)
+                return (L.apply_norm(lp["norm2"], h + mlp_out, cfg),
+                        jnp.zeros((), jnp.float32))
+        with jax.named_scope("attn"):
+            a_in = L.apply_norm(lp["norm1"], h, cfg)
+            attn_out, _ = L.apply_attention(
+                lp["attn"], a_in, cfg, positions=positions,
+                inv_freq=self._inv_freq, segment_ids=segment_ids,
+                attn_bias=attn_bias, window=window)
+            if cfg.sandwich_norm:   # Gemma-2: norm the sublayer OUTPUT pre-residual
+                attn_out = L.apply_norm(lp["norm3"], attn_out, cfg)
+        with jax.named_scope("mlp"):
+            if cfg.parallel_block:
+                # NeoX/Falcon parallel residual: attn and mlp both read the
+                # pre-attention stream; one residual add
+                m_in = L.apply_norm(lp["norm2"], h, cfg)
+            else:
+                h = h + attn_out
+                m_in = L.apply_norm(lp["norm2"], h, cfg)
+            if is_moe:
+                mlp_out, aux = L.apply_moe_mlp(lp["mlp"], m_in, cfg)
+            else:
+                mlp_out, aux = (L.apply_mlp(lp["mlp"], m_in, cfg),
+                                jnp.zeros((), jnp.float32))
+            if cfg.sandwich_norm:
+                mlp_out = L.apply_norm(lp["norm4"], mlp_out, cfg)
+            if cfg.parallel_block:
+                return h + attn_out + mlp_out, aux
+            return h + mlp_out, aux
 
+    @jax.named_scope("embed")
     def embed_fwd(self, embed_params, input_ids, positions=None, token_type_ids=None):
         """Token (+ learned position, + token-type) embedding lookup:
         (B, S) → (B, S, E)."""
@@ -387,6 +395,7 @@ class CausalLM:
             h = L.apply_norm(embed_params["emb_norm"], h, cfg)
         return h
 
+    @jax.named_scope("lm_head_loss")
     def head_loss(self, head_params, h, labels, loss_mask=None):
         """Final norm + lm head + cross-entropy from hidden states.
 
@@ -450,7 +459,8 @@ class CausalLM:
                                    windows, carry, body, wrap=make_body)
         h, aux_total = carry
         if not cfg.post_norm:
-            h = L.apply_norm(params["final_norm"], h, cfg)
+            with jax.named_scope("lm_head_loss"):
+                h = L.apply_norm(params["final_norm"], h, cfg)
         # average the load-balancing aux over layers that HAVE routers
         # (dense interleave layers contribute 0 and must not dilute it)
         n_moe = sum(1 for i in range(cfg.num_layers)
@@ -470,9 +480,10 @@ class CausalLM:
         h, aux_total = self.hidden_states(params, input_ids, positions=positions,
                                           segment_ids=segment_ids)
         w, transpose = self._lm_head_weight(params)
-        logits = lm_head_logits(h, w, transpose, dt,
-                                bias=params["embed"].get("lm_head_bias"),
-                                softcap=self.cfg.logit_softcap)
+        with jax.named_scope("lm_head_loss"):
+            logits = lm_head_logits(h, w, transpose, dt,
+                                    bias=params["embed"].get("lm_head_bias"),
+                                    softcap=self.cfg.logit_softcap)
         if return_aux_loss:
             return logits, aux_total
         return logits
@@ -569,15 +580,18 @@ class CausalLM:
                                         positions=batch.get("positions"),
                                         segment_ids=batch.get("segment_ids"))
             w, transpose = self._lm_head_weight(params)
-            loss = lm_cross_entropy(h, w.astype(h.dtype), labels, loss_mask=mask,
-                                    n_chunks=cfg.loss_chunks, transpose_w=transpose,
-                                    softcap=cfg.logit_softcap)
+            with jax.named_scope("lm_head_loss"):
+                loss = lm_cross_entropy(
+                    h, w.astype(h.dtype), labels, loss_mask=mask,
+                    n_chunks=cfg.loss_chunks, transpose_w=transpose,
+                    softcap=cfg.logit_softcap)
         else:
             logits, aux = self.apply(params, batch["input_ids"],
                                      positions=batch.get("positions"),
                                      segment_ids=batch.get("segment_ids"),
                                      return_aux_loss=True)
-            loss = masked_token_nll(logits, labels, mask)
+            with jax.named_scope("lm_head_loss"):
+                loss = masked_token_nll(logits, labels, mask)
         if cfg.is_moe:
             loss = loss + cfg.moe_aux_loss_coef * aux
         return loss

@@ -73,7 +73,12 @@ struct AioHandle {
                 queue.pop_front();
             }
             if (do_io(req) != 0) errors.fetch_add(1);
-            completed.fetch_add(1);
+            {
+                // under wait_all()'s mutex: a waiter that has read the
+                // counters but not yet blocked must not miss this wake-up
+                std::lock_guard<std::mutex> lk(mu);
+                completed.fetch_add(1);
+            }
             done_cv.notify_all();
         }
     }

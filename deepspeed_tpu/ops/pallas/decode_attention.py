@@ -112,6 +112,7 @@ def fused_decode_attention(q, k_cache, v_cache, cache_len, *, scale=None,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, kvh, group, d), q.dtype),
+        name="decode_attn",
         interpret=jax.default_backend() != "tpu",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),

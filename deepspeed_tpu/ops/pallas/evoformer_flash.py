@@ -104,6 +104,7 @@ def evoformer_flash_fwd(q, k, v, bias1, bias2, *, scale,
         out_specs=pl.BlockSpec((1, 1, block_q, d),
                                lambda bi, hi, qi: (bi, hi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((bn, h, s, d), q.dtype),
+        name="evoformer_flash_fwd",
         interpret=_interpret(),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),

@@ -145,6 +145,7 @@ def _fwd(q, k, v, slopes, seg, causal, alibi, segmented, window, block_q, block_
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((b, h, 1, sq), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=_interpret(),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -310,6 +311,7 @@ def _bwd(causal, alibi, segmented, window, block_q, block_k, residuals, g):
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        name="flash_bwd_dq",
         interpret=_interpret(),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -339,6 +341,7 @@ def _bwd(causal, alibi, segmented, window, block_q, block_k, residuals, g):
             jax.ShapeDtypeStruct((b, h, sk, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, sk, d), q.dtype),
         ],
+        name="flash_bwd_dkv",
         interpret=_interpret(),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),

@@ -64,6 +64,7 @@ def quantize_fp8(x, group_size: int = 256, fmt: str = "e4m3", stochastic: bool =
                    pl.BlockSpec((block_g, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct(flat.shape, dtype),
                    jax.ShapeDtypeStruct((g, 1), jnp.float32)],
+        name="fp_quantize",
         interpret=False,
     )(flat, jnp.asarray([seed], jnp.int32))
     return q.reshape(orig_shape), scale

@@ -70,5 +70,6 @@ def fused_adam_flat(params, grads, exp_avg, exp_avg_sq, *, step, lr,
         out_shape=[jax.ShapeDtypeStruct(params.shape, params.dtype),
                    jax.ShapeDtypeStruct(params.shape, jnp.float32),
                    jax.ShapeDtypeStruct(params.shape, jnp.float32)],
+        name="fused_adam",
         interpret=_interpret(),
     )(params, grads, exp_avg, exp_avg_sq, hyper)

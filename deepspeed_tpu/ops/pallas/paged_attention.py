@@ -269,6 +269,7 @@ def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, kvh, rows, d), q.dtype),
+        name=f"paged_attn_c{c}",
         interpret=jax.default_backend() != "tpu",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),

@@ -48,6 +48,7 @@ def quantize_int8(x, group_size: int = 256):
                    pl.BlockSpec((block_g, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct(flat.shape, jnp.int8),
                    jax.ShapeDtypeStruct((g, 1), jnp.float32)],
+        name="quantize_int8",
         interpret=_interpret(),
     )(flat)
     return q.reshape(orig_shape), scale
@@ -75,6 +76,7 @@ def quantize_int4(x, group_size: int = 256):
                    pl.BlockSpec((block_g, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct(flat.shape, jnp.int8),
                    jax.ShapeDtypeStruct((g, 1), jnp.float32)],
+        name="quantize_int4",
         interpret=_interpret(),
     )(flat)
     # pack pairs of nibbles: (..., 2k) | (..., 2k+1) << 4

@@ -157,6 +157,7 @@ def _fwd_kernel_call(qb, kb, vb, table, counts, masks, *, ma, scale):
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, qt * TILE_Q, d), qb.dtype),
+        name="sparse_flash_fwd",
         interpret=jax.default_backend() != "tpu",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",

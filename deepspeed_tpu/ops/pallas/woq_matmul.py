@@ -194,6 +194,7 @@ def woq_matmul(x, qstate, *, block_n: int = 256):
         out_specs=pl.BlockSpec((1, m, nt), lambda ni, ki: (0, 0, ni)),
         scratch_shapes=[pltpu.VMEM((m, nt), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((1, m, n), x.dtype),
+        name="woq_matmul",
         interpret=_interpret(),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),

@@ -111,6 +111,7 @@ def all_to_all_quant_reduce(tensors: List[jnp.ndarray], groups_=None,
 # manual region over `axis_name`.
 # ----------------------------------------------------------------------
 
+@jax.named_scope("zero_reduce_scatter")
 def quantized_reduce_scatter_along_dim(g, dim: int, axis_name: str = "data",
                                        group_size: int = 256):
     """Reduce-scatter a full-shape cotangent along ``dim`` with an int8 wire
@@ -135,6 +136,7 @@ def quantized_reduce_scatter_along_dim(g, dim: int, axis_name: str = "data",
     return jnp.moveaxis(shard, 0, dim)
 
 
+@jax.named_scope("zero_reduce_scatter")
 def reduce_scatter_along_dim(g, dim: int, axis_name: str = "data"):
     """Full-precision reduce-scatter along ``dim`` (psum_scatter)."""
     gm = jnp.moveaxis(g, dim, 0)
@@ -142,6 +144,7 @@ def reduce_scatter_along_dim(g, dim: int, axis_name: str = "data"):
     return jnp.moveaxis(red, 0, dim)
 
 
+@jax.named_scope("zero_gather")
 def _gather_along_dim(shard, dim: int, axis_name: str, quantized: bool,
                       group_size: int):
     xm = jnp.moveaxis(shard, dim, 0)

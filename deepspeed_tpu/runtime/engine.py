@@ -677,7 +677,9 @@ class DeepSpeedEngine:
             out_shardings=(self.param_shardings, self.opt_state_shardings, None,
                            self._replicated, self._replicated))
         def update_fn(params, opt_state, scaler_state, grads, lr, grad_divisor):
-            return self._apply_update(params, opt_state, scaler_state, grads, lr, grad_divisor)
+            with jax.named_scope("optimizer"):
+                return self._apply_update(params, opt_state, scaler_state,
+                                          grads, lr, grad_divisor)
 
         @functools.partial(
             jax.jit,
@@ -713,8 +715,10 @@ class DeepSpeedEngine:
             # the loops so the reshard is a one-shot exchange
             acc = jax.tree.map(lambda g, s: jax.lax.with_sharding_constraint(g, s),
                                acc, self.grad_shardings)
-            new_params, new_opt, new_scaler, overflow, grad_norm = self._apply_update(
-                params, opt_state, scaler_state, acc, lr, divisor)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt, new_scaler, overflow, grad_norm = \
+                    self._apply_update(params, opt_state, scaler_state, acc,
+                                       lr, divisor)
             return new_params, new_opt, new_scaler, loss_sum / gas, overflow, grad_norm
 
         self._grad_fn = grad_fn
@@ -1421,7 +1425,18 @@ class DeepSpeedEngine:
         """Fused fast path: one compiled step for a full global batch.
 
         ``batch`` leaves: (gas * micro_bs, ...) or (gas, micro_bs, ...).
+
+        On the profiler's clock the call is a ``train_batch`` step
+        (``StepTraceAnnotation``) holding ``train/stage`` (the batch's
+        host->device staging) and ``train/dispatch`` (the step program's
+        enqueue). Always on: with no profiler attached a ``TraceMe`` is one
+        flag test.
         """
+        with jax.profiler.StepTraceAnnotation("train_batch",
+                                              step_num=self.global_steps):
+            return self._train_batch(batch)
+
+    def _train_batch(self, batch):
         if self._infinity is not None:
             gas = self.gradient_accumulation_steps()
             self.tput_timer.start()
@@ -1445,13 +1460,16 @@ class DeepSpeedEngine:
         if getattr(self, "_sparse_grads", False):
             return self._sparse_grads_train_batch(batch)
         gas = self.gradient_accumulation_steps()
-        batch = jax.tree.map(self._stage_leaf, batch)
+        with jax.profiler.TraceAnnotation("train/stage"):
+            batch = jax.tree.map(self._stage_leaf, batch)
         self.tput_timer.start()
         lr = self._next_lr_device()
         self._swap_in_opt_state()
-        (self.module_params, self.opt_state, self.scaler_state, loss, overflow,
-         grad_norm) = self._train_step_fn(self.module_params, self.opt_state,
-                                          self.scaler_state, batch, lr, gas=gas)
+        with jax.profiler.TraceAnnotation("train/dispatch"):
+            (self.module_params, self.opt_state, self.scaler_state, loss,
+             overflow, grad_norm) = self._train_step_fn(
+                 self.module_params, self.opt_state, self.scaler_state, batch,
+                 lr, gas=gas)
         self._swap_out_opt_state()
         self.micro_steps += gas
         self.global_steps += 1

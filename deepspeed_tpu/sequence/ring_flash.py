@@ -171,6 +171,7 @@ def _fwd_step(off, q, k, v, slopes, qseg, kseg, m, l, acc, *,
             jax.ShapeDtypeStruct((b, h, sq, d), jnp.float32, vma=vma),
         ],
         input_output_aliases={7: 0, 8: 1, 9: 2},   # carry updated in place
+        name="ring_flash_fwd",
         interpret=_interpret(),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -347,6 +348,7 @@ def _bwd_step(off, q, k, v, do, lse, delta, slopes, qseg, kseg, *,
             out_specs=pl.BlockSpec((1, 1, block_q, d), qmap),
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), jnp.float32, vma=vma),
+        name="ring_flash_bwd_dq",
         interpret=_interpret(),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -384,6 +386,7 @@ def _bwd_step(off, q, k, v, do, lse, delta, slopes, qseg, kseg, *,
             jax.ShapeDtypeStruct((b, h, sk, d), jnp.float32, vma=vma),
             jax.ShapeDtypeStruct((b, h, sk, d), jnp.float32, vma=vma),
         ],
+        name="ring_flash_bwd_dkv",
         interpret=_interpret(),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),

@@ -197,18 +197,19 @@ def test_spec_counter_parity_with_host_replay(tiny_model_params, monkeypatch):
     e.attach_draft(model, params)           # self-draft: high acceptance
 
     host = {"fwds": 0, "emitted": 0}
-    orig = DeviceSlotTable.run_frame
+    orig = DeviceSlotTable.dispatch_frame
 
     def spy(self, runner, eng_params, kv, width, steps, greedy, draft=None,
             **kw):
         toks, emit = orig(self, runner, eng_params, kv, width, steps, greedy,
                           draft=draft, **kw)
         if emit.ndim == 3 and width == 1:
-            host["fwds"] += int(emit[:, :, 0].sum())
-            host["emitted"] += int(emit.sum())
+            seen = np.asarray(emit)
+            host["fwds"] += int(seen[:, :, 0].sum())
+            host["emitted"] += int(seen.sum())
         return toks, emit
 
-    monkeypatch.setattr(DeviceSlotTable, "run_frame", spy)
+    monkeypatch.setattr(DeviceSlotTable, "dispatch_frame", spy)
     prompts = _prompts()
     outs = dict(e.serve(_arrivals(prompts), max_new_tokens=MAX_NEW, gamma=2))
     sp = e.serve_stats["spec"]

@@ -94,8 +94,8 @@ def test_tp8_greedy_token_parity(tp8_engine, greedy_base):
 
 def test_tp8_device_counters_match_tp1(tp8_engine, tp_model_params,
                                        greedy_base):
-    """The in-graph frame counters (read from shard 0 only) replay the same
-    totals as the single-chip engine — the telemetry surface is
+    """The in-graph frame counters (read from one device's copy) replay the
+    same totals as the single-chip engine — the telemetry surface is
     topology-blind."""
     model, params = tp_model_params
     e1 = _engine(model, params)
@@ -128,8 +128,8 @@ def test_tp8_zero_in_frame_transfers(tp_model_params, greedy_base,
                                      frame_transfer_guard):
     """Sharding must not smuggle device reads into the frame: dispatch
     under a device-to-host transfer guard (conftest's shared definition of
-    "in-frame"), with the per-shard stats rows and replicated carry all
-    surfacing at boundaries only."""
+    "in-frame"), with the frame counters and the rest of the replicated
+    carry all surfacing at boundaries only."""
     model, params = tp_model_params
     e = _engine(model, params, tp=8)
     got = dict(e.serve(iter([[(0, PROMPTS[0]), (1, PROMPTS[1])]]),
@@ -139,10 +139,11 @@ def test_tp8_zero_in_frame_transfers(tp_model_params, greedy_base,
 
 
 def test_tp8_replica_consistency_debug_mode(tp_model_params, greedy_base):
-    """tp_debug_replica_check reads ALL shards' frame-counter rows at every
-    boundary and asserts they agree — the replica-consistency proof of the
-    shard-0-only steady-state read. A full serve under the check passing is
-    the assertion (any shard-varying leak into the counters raises)."""
+    """tp_debug_replica_check reads EVERY device's copy of the frame
+    counters at every boundary and asserts they agree — the
+    replica-consistency proof of the one-copy steady-state read. A full
+    serve under the check passing is the assertion (any shard-varying leak
+    into the counters raises)."""
     model, params = tp_model_params
     e = _engine(model, params, tp=8, tp_debug_replica_check=True)
     got = dict(e.serve(iter([[(0, PROMPTS[0]), (1, PROMPTS[1])]]),

@@ -128,7 +128,7 @@ def test_real_policy_objects_execute_and_no_frames_dispatch(monkeypatch):
     def no_dispatch(self, *a, **kw):
         raise AssertionError("the simulator dispatched a REAL frame")
 
-    monkeypatch.setattr(ragged_manager.DeviceSlotTable, "run_frame",
+    monkeypatch.setattr(ragged_manager.DeviceSlotTable, "dispatch_frame",
                         no_dispatch)
 
     trace = small_trace()
