@@ -29,7 +29,8 @@ from ...models.transformer import CausalLM
 from ...ops.attention import decode_attention
 from ..sampling import sample_logits_per_row, speculative_verify_per_row
 from .kv_cache import dequantize_kv_lanes, quantize_kv_lanes
-from .telemetry import N_STATS   # in-graph frame-counter vector layout
+from .telemetry import (MAX_RUNGS, N_STATS,   # in-graph counter layout
+                        pack_ladder)
 
 
 def _use_pallas_paged() -> bool:
@@ -72,6 +73,20 @@ class PagedModelRunner:
             return None
         return int(cfg.sliding_window)
 
+    def pack_ladder(self, b: int, c: int):
+        """The rungs a (b, c) step may pack its live tokens into
+        (``telemetry.pack_ladder``), the last one the chunk whole. Two
+        layers do not treat every position alike, and a model that has one
+        keeps the chunk whole: per-tensor activation quantization reads its
+        scale off every position, and capacity-routed experts (the
+        ``einsum`` dispatch) let the pad positions compete for an expert's
+        capacity. Dropless routing (``moe_impl="grouped"``) packs."""
+        cfg = self.cfg
+        ladder = pack_ladder(b, c)
+        whole = cfg.act_quant_bits or (cfg.is_moe
+                                       and cfg.moe_impl != "grouped")
+        return ladder[-1:] if whole else ladder
+
     def set_tp(self, tp_ctx) -> None:
         """Bind a ``tp.TPContext`` (engine setup, before any serving loop
         compiles). The serving entry points close over the context, so any
@@ -109,8 +124,15 @@ class PagedModelRunner:
         bs = self.block_size
         model = self.model
         dt = cfg.act_dtype
-        b, c = ids.shape
-        with jax.named_scope("embed"):
+        b = ids.shape[0]
+        # the per-token layers (embedding, norms, projections, MLP) run on
+        # the chunk's LIVE positions only, packed into the smallest rung of
+        # a static ladder that holds them; attention, the commit and the
+        # head keep the (B, C) layout. None = this shape does not pack.
+        with jax.named_scope("frame_plan"):
+            pack = _pack_plan(positions, self.pack_ladder(*ids.shape))
+
+        def embed(ids, positions):
             if tp is not None and tp.vocab_sharded:
                 # Megatron vocab-parallel lookup: each shard holds rows
                 # [r*V/tp, (r+1)*V/tp) — mask out-of-range ids, psum selects the
@@ -132,8 +154,11 @@ class PagedModelRunner:
                              params["embed"]["pos"].shape[0] - 1)]
             if cfg.embedding_norm:   # BLOOM word_embeddings_layernorm
                 h = L.apply_norm(params["embed"]["emb_norm"], h, cfg)
+            return h
+
+        with jax.named_scope("embed"):
+            h = _on_live(pack, embed, ids, positions)
         inv_freq = model._inv_freq
-        b_idx = jnp.arange(b)[:, None]                      # (B, 1)
         # positions < 0 mark padding: route their writes to trash block 0
         is_pad = positions < 0
         pos_safe = jnp.maximum(positions, 0)
@@ -160,14 +185,27 @@ class PagedModelRunner:
                 slopes = jax.lax.dynamic_slice_in_dim(
                     slopes, jax.lax.axis_index(tp.axis) * h_loc, h_loc)
 
-        def qkv(lp, h):
+        def at(lp):
+            """This layer's weights: the slice the layer walk made, or
+            (stacked weights, layer index) sliced HERE, where they are used,
+            so that the slice fuses into the product that reads it (a slice
+            made outside the region that packs is an operand of that region,
+            which XLA materializes: a copy of the layer's weights a step)."""
+            if isinstance(lp, tuple):
+                return jax.tree.map(lambda a: a[lp[1]], lp[0])
+            return lp
+
+        def qkv(lp, h, pos):
+            lp = at(lp)
             a_in = L.apply_norm(lp["norm1"], h, cfg)
             # L.dq dequantizes int8 per-channel weight leaves in-graph (a
             # cast, like .astype for unquantized leaves — XLA fuses it into
             # the einsum read, so the resident copy stays int8)
-            q = jnp.einsum("bse,ehd->bshd", a_in, L.dq(lp["attn"]["wq"], dt))
-            k = jnp.einsum("bse,ehd->bshd", a_in, L.dq(lp["attn"]["wk"], dt))
-            v = jnp.einsum("bse,ehd->bshd", a_in, L.dq(lp["attn"]["wv"], dt))
+            def proj(w):
+                w = L.dq(w, dt)
+                y = jnp.einsum("bse,ef->bsf", a_in, w.reshape(w.shape[0], -1))
+                return y.reshape(y.shape[:2] + w.shape[1:])
+            q, k, v = (proj(lp["attn"][n]) for n in ("wq", "wk", "wv"))
             if cfg.use_bias or cfg.qkv_bias:
                 q = q + L.bcast(lp["attn"]["bq"].astype(dt), q.ndim)
                 k = k + L.bcast(lp["attn"]["bk"].astype(dt), k.ndim)
@@ -176,11 +214,42 @@ class PagedModelRunner:
                 q = L.apply_qk_norm(lp["attn"]["q_norm"], q, cfg)
                 k = L.apply_qk_norm(lp["attn"]["k_norm"], k, cfg)
             if cfg.position == "rope":
-                q = L.apply_rope(q, pos_safe, inv_freq,
+                q = L.apply_rope(q, pos, inv_freq,
                                  interleaved=cfg.rope_interleaved)
-                k = L.apply_rope(k, pos_safe, inv_freq,
+                k = L.apply_rope(k, pos, inv_freq,
                                  interleaved=cfg.rope_interleaved)
             return q, k, v
+
+        def attn_out(lp, out):
+            # row-parallel output projection: under tp the per-shard product
+            # covers only the local heads — all-reduce BEFORE the replicated
+            # bias, so the bias is added exactly once
+            lp = at(lp)
+            y = jnp.einsum("bshd,hde->bse", out, L.dq(lp["attn"]["wo"], dt))
+            if tp is not None:
+                y = tp.coll.psum_attn(y)
+            if "bo" in lp["attn"]:   # presence-keyed: out_bias may differ from use_bias
+                y = y + L.bcast(lp["attn"]["bo"].astype(dt), y.ndim)
+            if cfg.sandwich_norm:   # Gemma-2 post-attn output norm
+                y = L.apply_norm(lp["norm3"], y, cfg)
+            return y
+
+        def mlp(lp, h, y, moe):
+            lp = at(lp)
+            if cfg.parallel_block:   # NeoX/Falcon: attn and mlp share input
+                m_in = L.apply_norm(lp["norm2"], h, cfg)
+            else:
+                h = h + y
+                m_in = L.apply_norm(lp["norm2"], h, cfg)
+            if moe:
+                mlp_out, _ = L.apply_moe_mlp(lp["mlp"], m_in, cfg)
+            else:
+                mlp_out = L.apply_mlp(
+                    lp["mlp"], m_in, cfg,
+                    reduce=tp.coll.psum_mlp if tp is not None else None)
+            if cfg.sandwich_norm:
+                mlp_out = L.apply_norm(lp["norm4"], mlp_out, cfg)
+            return h + y + mlp_out if cfg.parallel_block else h + mlp_out
 
         def layer(h, xs, tag=None):
             lp, l, win = xs
@@ -190,7 +259,8 @@ class PagedModelRunner:
                 from ...compression.compress import fake_quantize_activation
                 h = fake_quantize_activation(h, cfg.act_quant_bits)
             with jax.named_scope("attn_qkv"):
-                q, k, v = qkv(lp, h)
+                q, k, v = _on_live(pack, functools.partial(qkv, lp), h,
+                                   pos_safe)
             # the pools are LOOP-INVARIANT inside the layer scan: this
             # layer's chunk KV rides into the attention as separate blocks
             # and comes back out as scan ys; one token-sized scatter after
@@ -236,32 +306,13 @@ class PagedModelRunner:
                                            window=win, chunk_k=k, chunk_v=v,
                                            chunk_start=chunk_start,
                                            alibi_slopes=slopes)
-            with jax.named_scope("attn_out"):
-                # row-parallel output projection: under tp the per-shard product
-                # covers only the local heads — all-reduce BEFORE the replicated
-                # bias, so the bias is added exactly once
-                y = jnp.einsum("bshd,hde->bse", out, L.dq(lp["attn"]["wo"], dt))
-                if tp is not None:
-                    y = tp.coll.psum_attn(y)
-                if "bo" in lp["attn"]:   # presence-keyed: out_bias may differ from use_bias
-                    y = y + L.bcast(lp["attn"]["bo"].astype(dt), y.ndim)
-                if cfg.sandwich_norm:   # Gemma-2 post-attn output norm
-                    y = L.apply_norm(lp["norm3"], y, cfg)
+            def dense_out(h, out):
+                with jax.named_scope("attn_out"):
+                    y = attn_out(lp, out)
+                # group tag overrides
+                return mlp(lp, h, y, cfg.is_moe if tag is None else tag == "moe")
             with jax.named_scope("mlp"):
-                if cfg.parallel_block:   # NeoX/Falcon: attn and mlp share input
-                    m_in = L.apply_norm(lp["norm2"], h, cfg)
-                else:
-                    h = h + y
-                    m_in = L.apply_norm(lp["norm2"], h, cfg)
-                if cfg.is_moe if tag is None else tag == "moe":   # group tag overrides
-                    mlp_out, _ = L.apply_moe_mlp(lp["mlp"], m_in, cfg)
-                else:
-                    mlp_out = L.apply_mlp(
-                        lp["mlp"], m_in, cfg,
-                        reduce=tp.coll.psum_mlp if tp is not None else None)
-                if cfg.sandwich_norm:
-                    mlp_out = L.apply_norm(lp["norm4"], mlp_out, cfg)
-                h = h + y + mlp_out if cfg.parallel_block else h + mlp_out
+                h = _on_live(pack, dense_out, h, out)
             # quantize-at-append: the chunk's KV leaves the layer already in
             # pool representation, so the commit scatter in _run_layers is
             # dtype-blind and the pool never holds a float row
@@ -271,13 +322,15 @@ class PagedModelRunner:
                 return h, (k.astype(kpool.dtype), v.astype(vpool.dtype))
 
         h, kpool, vpool = self._run_layers(layer, h, params, kpool, vpool,
-                                           windows, blk, off)
+                                           windows, blk, off,
+                                           stacked=pack is not None)
         with jax.named_scope("lm_head"):
             h = L.apply_norm(params["final_norm"], h, cfg)
             logits = self._head(params, h, valid_counts, all_logits, tp=tp)
         return logits, kpool, vpool
 
-    def _run_layers(self, layer, h, params, kpool, vpool, windows, blk, off):
+    def _run_layers(self, layer, h, params, kpool, vpool, windows, blk, off,
+                    stacked=False):
         """Drive ``layer`` over the stack following the model's layer plan
         (heterogeneous stacks: Qwen2-MoE sparse steps, mlp_only prefixes).
         The full pools stay loop-invariant (read through a global layer
@@ -285,17 +338,32 @@ class PagedModelRunner:
         returns as scan ys and is committed with ONE token-sized scatter.
         Per-layer xs are (layer index, window), which the shared
         ``walk_layer_plan`` driver slices to match the grouped param layout
-        exactly like the train forward and the cached decode."""
+        exactly like the train forward and the cached decode.
+
+        ``stacked`` (a step that packs its live tokens): the walk is handed
+        each group's layer INDICES in place of its weights, under the
+        group's own key, and ``layer`` gets (the group's stacked weights,
+        index) to slice where it uses them."""
         from ...models.transformer import walk_layer_plan
         model = self.model
         layer_ids = jnp.arange(self.cfg.num_layers, dtype=jnp.int32)
+        layers = params["layers"]
+        walked = layers
+        if stacked:
+            def indices(key, tree):
+                return {key: jnp.arange(jax.tree.leaves(tree)[0].shape[0])}
+            walked = (indices("", layers) if model._groups is None else
+                      {g: indices(g, tree) for g, tree in layers.items()})
 
         def body(h, lp, xs_t, tag):
             l, win = xs_t
+            if stacked:
+                (key, i), = lp.items()
+                lp = (layers[key] if key else layers, i)
             return layer(h, (lp, l, win), tag=tag)
 
         h, (ck_all, cv_all) = walk_layer_plan(
-            model._plan, model._groups, params["layers"],
+            model._plan, model._groups, walked,
             (layer_ids, windows), h, body)
         with jax.named_scope("kv_commit"):
             # (L, B, C, KVH, D) chunk KV → pool[:, :, blk, off]: the advanced
@@ -439,7 +507,8 @@ class PagedModelRunner:
                                               prompt_lens, new_limits,
                                               no_eos, temps, block_tables,
                                               width, greedy,
-                                              window=self.stat_window)
+                                              window=self.stat_window,
+                                              ladder=self.pack_ladder)
 
                 zero = jnp.zeros((b,), jnp.int32)
                 no = jnp.zeros((b,), bool)
@@ -518,7 +587,8 @@ class PagedModelRunner:
                 body = _serving_scan_body(fwd, params, prompts, prompt_lens,
                                           limits, eos_ids, temps, tables,
                                           width, greedy, repair=repair,
-                                          window=self.stat_window)
+                                          window=self.stat_window,
+                                          ladder=self.pack_ladder)
                 carry = (cached, produced, last_tok, done, poison, nonfinite,
                          stats, rng, kpool, vpool)
                 carry, (toks, emit) = jax.lax.scan(body, carry, None,
@@ -580,7 +650,8 @@ class PagedModelRunner:
                     fwd, params, prompts, prompt_lens, limits, eos_ids,
                     temps, tables, width, greedy,
                     draft=(draft_fwd, draft_params, gamma), repair=repair,
-                    window=self.stat_window)
+                    window=self.stat_window,
+                    ladder=self.pack_ladder)
                 carry = (cached, produced, last_tok, penult, done, poison,
                          nonfinite, stats, rng, kpool, vpool, dkpool, dvpool)
                 carry, (toks, emit) = jax.lax.scan(body, carry, None,
@@ -638,7 +709,8 @@ class PagedModelRunner:
                                               width, greedy,
                                               draft=(draft_fwd, draft_params,
                                                      gamma),
-                                              window=self.stat_window)
+                                              window=self.stat_window,
+                                              ladder=self.pack_ladder)
 
                 zero = jnp.zeros((b,), jnp.int32)
                 no = jnp.zeros((b,), bool)
@@ -715,9 +787,67 @@ class PagedModelRunner:
                 self._evicted_programs += f._cache_size()
 
 
+def _rung_of(positions, ladder):
+    """Index of the smallest rung of ``ladder`` that holds the chunk's live
+    positions (``positions >= 0``)."""
+    n = jnp.sum((positions >= 0).astype(jnp.int32))
+    return sum((n > t).astype(jnp.int32) for t in ladder[:-1])
+
+
+def _pack_plan(positions, ladder):
+    """Where the live positions of a chunk (``positions >= 0``) sit in a
+    packed token buffer, and the rung of ``ladder`` that holds them: returns
+    (rung index, src (T,) flat chunk position of each packed token, dst
+    (B, C) packed index of each chunk position, live (B, C), ladder), or
+    None when the shape has one rung only. Live positions keep their order,
+    so the indices are a cumulative sum and its inverse: no sort."""
+    if len(ladder) == 1:
+        return None
+    live = positions >= 0
+    cum = jnp.cumsum(live.reshape(-1).astype(jnp.int32))
+    # packed token t is the first chunk position with t + 1 live ones up to
+    # it; past the live count that points beyond the chunk, and is clipped
+    src = jnp.minimum(
+        jnp.searchsorted(cum, jnp.arange(ladder[-1], dtype=jnp.int32),
+                         side="right", method="compare_all"),
+        live.size - 1)
+    dst = jnp.maximum(cum - 1, 0).reshape(live.shape)
+    return _rung_of(positions, ladder), src, dst, live, ladder
+
+
+def _on_live(pack, fn, *xs):
+    """``fn(*xs)`` for a ``fn`` that treats every position alike (its
+    output at a position depends on that position's inputs only), computed
+    on the live positions alone: a rung gathers its ``T`` packed tokens
+    from the (B, C, ...) inputs as a (1, T, ...) view, runs ``fn`` there and
+    gathers the outputs back to (B, C, ...) with zeros at the dead
+    positions. The last rung holds the whole chunk and takes the same path,
+    so the rungs differ in ``T`` alone and ask one layout of the weights
+    they share. The rung was chosen in the graph (``_pack_plan``), so one
+    program serves every live count. ``pack`` None is ``fn(*xs)``."""
+    if pack is None:
+        return fn(*xs)
+    rung, src, dst, live, ladder = pack
+
+    def on_rung(t):
+        def run(*xs):
+            ys = fn(*(x.reshape((1, -1) + x.shape[2:])[:, src[:t]]
+                      for x in xs))
+            single = not isinstance(ys, tuple)
+            ys = tuple(
+                jnp.where(live.reshape(live.shape + (1,) * (y.ndim - 2)),
+                          y[0][jnp.minimum(dst, t - 1)],
+                          jnp.zeros((), y.dtype))
+                for y in ((ys,) if single else ys))
+            return ys[0] if single else ys
+        return run
+
+    return jax.lax.switch(rung, [on_rung(t) for t in ladder], *xs)
+
+
 def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                        temps, tables, width, greedy, draft=None,
-                       repair=False, window=None):
+                       repair=False, window=None, ladder=pack_ladder):
     """Shared scan-step for ``mixed_loop`` and ``frame_loop`` — the in-graph
     SplitFuse scheduling arithmetic lives in exactly one place.
 
@@ -760,7 +890,7 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
     if draft is not None:
         return _spec_scan_body(fwd, params, prompts, prompt_lens, limits,
                                eos_ids, temps, tables, width, greedy, *draft,
-                               repair=repair, window=window)
+                               repair=repair, window=window, ladder=ladder)
 
     def body(carry, _):
         (cached, produced, last_tok, done, poison, nonfinite, stats, rng,
@@ -795,6 +925,7 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                 done = jnp.where(bad, prev_done, done)
                 w = jnp.where(bad, 0, w)
             stats = stats + _stat_delta(
+                positions, ladder(*positions.shape),
                 emitted=emit, active=active,
                 prefill_toks=jnp.where(prefilling, w, 0),
                 eos=emit & (nxt == eos_ids),
@@ -848,19 +979,28 @@ def _attn_work(cached, w, window):
     return kv, w * kv
 
 
-def _stat_delta(emitted=None, active=None, prefill_toks=None, eos=None,
-                target_fwd=None, drafted=None, accepted=None, kv_read=None,
-                attn_pairs=None):
+def _stat_delta(positions, ladder, emitted=None, active=None,
+                prefill_toks=None, eos=None, target_fwd=None, drafted=None,
+                accepted=None, kv_read=None, attn_pairs=None):
     """One step's (N_STATS,) in-graph counter increment. Each keyword is a
     bool mask / int array to sum, or None for zero — the layout is pinned by
     the STAT_* indices in ``telemetry.py`` and the host-mirror replay tests
-    assert the resulting totals exactly."""
+    assert the resulting totals exactly. ``positions`` (B, C) is the chunk
+    the target forwarded and ``ladder`` its rungs: the last lanes are the
+    rung the forward chose for it (``_rung_of``, the same arithmetic) and
+    one step at that rung."""
     vals = [emitted, active, prefill_toks, eos, target_fwd, drafted, accepted,
             kv_read, attn_pairs]
     z = jnp.zeros((), jnp.int32)
     out = [z if v is None else jnp.sum(v.astype(jnp.int32)) for v in vals]
-    assert len(out) == N_STATS
-    return jnp.stack(out)
+    rung = _rung_of(positions, ladder)
+    out.append(jnp.asarray(ladder, jnp.int32)[rung])
+    # a shape with one rung counts its steps at none
+    steps = (jnp.arange(MAX_RUNGS) == rung).astype(jnp.int32) \
+        * int(len(ladder) > 1)
+    out = jnp.concatenate([jnp.stack(out), steps])
+    assert out.shape == (N_STATS,)
+    return out
 
 
 def _wide_plan(prompts, prompt_lens, limits, width, cached, produced,
@@ -903,7 +1043,7 @@ def _wide_emit(active, prefilling, cached, w, prompt_lens, eos_ids, nxt,
 
 def _spec_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                     temps, tables, width, greedy, draft_fwd, draft_params,
-                    gamma, repair=False, window=None):
+                    gamma, repair=False, window=None, ladder=pack_ladder):
     """Speculative variant of the serving scan step (see
     ``_serving_scan_body``). Carry: (cached, produced, last_tok, penult,
     done, poison, nonfinite, stats, rng, kpool, vpool, dkpool, dvpool);
@@ -976,6 +1116,7 @@ def _spec_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
             # rows coasting inside a wide mixed frame are plain decode),
             # and the device counters must replay that arithmetic exactly
             stats = stats + _stat_delta(
+                positions, ladder(*positions.shape),
                 emitted=emit, active=active,
                 prefill_toks=jnp.where(prefilling, w, 0),
                 eos=emit & (nxt == eos_ids),
@@ -1077,6 +1218,7 @@ def _spec_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
         # twin of the host arithmetic serve_stats always used
         kv_read, attn_pairs = _attn_work(cached, k_out * av, window)
         stats = stats + _stat_delta(
+            pos_v, ladder(*pos_v.shape),
             emitted=emit, active=active, eos=emit & is_eos,
             target_fwd=active, drafted=gamma * active.astype(jnp.int32),
             accepted=emit[:, 1:], kv_read=kv_read, attn_pairs=attn_pairs)

@@ -39,7 +39,7 @@ from ..engine_v2 import (HandoffEvent, InferenceEngineV2,
 from ..faults import FaultReason, LedgerEntry, snapshot_ledger
 from ..telemetry import (N_STATS, STAT_ACCEPTED, STAT_ACTIVE_STEPS,
                          STAT_DRAFTED, STAT_EMITTED, STAT_EOS,
-                         STAT_PREFILL_TOKS, STAT_TARGET_FWD,
+                         STAT_POSITIONS, STAT_PREFILL_TOKS, STAT_TARGET_FWD,
                          ServingTelemetry)
 from .clock import VirtualClock
 from .cost import FrameCostModel
@@ -630,6 +630,10 @@ class SimEngine:
                 spec=speculate and width == 1, tp=c.tp,
                 quant=c.weight_dtype == "int8"
                 or c.tp_quantized_collectives)
+            # the simulator prices a frame as the chunk whole (no rungs):
+            # slots x steps x positions, a speculative step gamma + 1 wide
+            delta[STAT_POSITIONS] = int(n_slots) * cur_steps * (
+                gamma + 1 if speculate and width == 1 else width)
             self.local_t += dt
             self._clock.seek(self.local_t)
             self.virtual_frames += 1

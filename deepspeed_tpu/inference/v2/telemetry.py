@@ -81,9 +81,16 @@ from ...utils.logging import logger
 #                  rows: min(cached + w, sliding_window + w), or cached + w
 #                  without a (uniform) window — w is the row's chunk width
 #   ATTN_PAIRS     query x key pairs that attention must score: w * KV_READ
-# The last two are work, not events: the host splits each frame's delta by
-# the frame's width into ``<name>_narrow`` / ``<name>_wide`` counters
-# (SPLIT_STAT_NAMES), the operands of the paged kernels' roofline shares.
+#   POSITIONS      token positions the step's per-token layers (embedding,
+#                  projections, MLP) ran: the rung of ``pack_ladder`` the
+#                  step chose in the graph, or slots x width where the
+#                  shape has one rung only
+#   RUNG0..        steps run at rung i of the step's ``pack_ladder``
+#                  (``MAX_RUNGS`` lanes; a one-rung shape counts in none)
+# KV_READ and ATTN_PAIRS are work, not events: the host splits each frame's
+# delta by the frame's width into ``<name>_narrow`` / ``<name>_wide``
+# counters (SPLIT_STAT_NAMES), the operands of the paged kernels' roofline
+# shares.
 STAT_EMITTED = 0
 STAT_ACTIVE_STEPS = 1
 STAT_PREFILL_TOKS = 2
@@ -93,7 +100,14 @@ STAT_DRAFTED = 5
 STAT_ACCEPTED = 6
 STAT_KV_READ = 7
 STAT_ATTN_PAIRS = 8
-N_STATS = 9
+STAT_POSITIONS = 9
+STAT_RUNG0 = 10
+#: rungs a ladder may have (``pack_ladder``), one lane each
+MAX_RUNGS = 6
+N_STATS = STAT_RUNG0 + MAX_RUNGS
+#: a smaller token buffer costs what this one does: the MXU's rows are not
+#: filled and the weights are read all the same
+MIN_RUNG = 128
 
 STAT_NAMES = ("tokens_emitted", "active_row_steps", "prefill_tokens",
               "eos_events", "target_forwards", "drafted_tokens",
@@ -205,6 +219,35 @@ def check_stat_range(slots: int, width: int, steps: int,
             f"over {context} of context can score {worst} query x key "
             "pairs, which the int32 frame counters cannot hold (2^32): "
             "lower frame_steps or prefill_chunk_size")
+
+
+def pack_ladder(slots: int, width: int) -> tuple:
+    """The token-buffer sizes ("rungs") a step of ``slots`` rows x ``width``
+    positions may run its per-token layers at, ascending; the last is the
+    chunk whole (``slots * width``: nothing packed). A rung below it holds
+    ``k`` rows consuming a whole chunk with every other row decoding, for
+    ``k`` = 1, 2, 4, ... under ``slots``, rounded up to 16 rows (a packed
+    bfloat16 tile). A rung under ``MIN_RUNG`` tokens buys nothing over the
+    next, so a decode step, a speculative verify and any small chunk have
+    one rung. Where a shape has such rungs it also has the one for ``k`` =
+    0, every live row decoding: most steps of a wide frame once its prompt
+    tokens are consumed. At most ``MAX_RUNGS`` are kept (the largest, and
+    ``k`` = 0). The frame programs choose a step's rung in the graph from
+    its live count; the host names the per-rung step counters off the same
+    list."""
+    top = slots * width
+
+    def rung(k):
+        return -(-(k * width + slots - k) // 16) * 16
+
+    rungs, k = [], 1
+    while k < slots:
+        if MIN_RUNG <= rung(k) < top and rung(k) not in rungs:
+            rungs.append(rung(k))
+        k *= 2
+    if not rungs:
+        return (top,)
+    return (rung(0),) + tuple(rungs[2 - MAX_RUNGS:]) + (top,)
 
 
 def zero_stats():
@@ -393,9 +436,12 @@ class ServingTelemetry:
         # phase began (None until then): programs_requested counts from it
         self._compile_base = None
         self.counters: Dict[str, int] = {n: 0 for n in STAT_NAMES}
-        # work the frames did and computed: slots x width x steps positions
-        # a frame, and the attention's reads and pairs by frame width
+        # work the frames did and computed: the positions their per-token
+        # layers ran (a device lane), the steps that packed their live
+        # tokens (labeled by the rung's size), and the attention's reads
+        # and pairs by frame width
         self.counters["positions_computed"] = 0
+        self.counters["rung_steps"] = 0
         for n in SPLIT_STAT_NAMES:
             self.counters[f"{n}_narrow"] = 0
             self.counters[f"{n}_wide"] = 0
@@ -1022,12 +1068,19 @@ class ServingTelemetry:
         split = "wide" if width > 1 else "narrow"
         for i, name in enumerate(SPLIT_STAT_NAMES, len(STAT_NAMES)):
             self.counters[f"{name}_{split}"] += int(delta[i])
-        # what the frame computed whatever was useful in it: every slot,
-        # every position of the width (a speculative decode step verifies
-        # gamma + 1 positions a row), every step
-        self.counters["positions_computed"] += \
-            int(self.gauges["slot_count"]) * steps * (
-                self._gamma + 1 if width == 1 and self._gamma else width)
+        # what the frame's per-token layers ran, as the chip counted it:
+        # the rung each step chose for its live tokens, and how many steps
+        # ran at each rung of the frame's ladder (a speculative decode step
+        # verifies gamma + 1 positions a row)
+        self.counters["positions_computed"] += int(delta[STAT_POSITIONS])
+        ladder = pack_ladder(
+            int(self.gauges["slot_count"]),
+            self._gamma + 1 if width == 1 and self._gamma else width)
+        for tokens, n in zip(ladder, delta[STAT_RUNG0:]):
+            if n:
+                self.counters["rung_steps"] += int(n)
+                self._inc_labeled("rung_steps", (("tokens", str(tokens)),),
+                                  int(n))
         self.counters["frames"] += 1
         self.lifetime_frames += 1
         # run-average occupancy = active_row_steps / slot_steps_capacity

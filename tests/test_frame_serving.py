@@ -461,3 +461,179 @@ def test_generate_degrades_to_stepwise_on_small_pool(tiny_model_params):
     np.testing.assert_array_equal(got, full[:len(got)])
     small.flush(list(small.state.seqs))
     assert small.kv.free_blocks == small.kv.num_blocks - 1
+
+
+# ---------------------------------------------------------------------------
+# token packing: a wide step's per-token layers run on its live tokens
+# ---------------------------------------------------------------------------
+
+PACK = dict(max_ragged_batch_size=8, prefill_chunk_size=64, kv_block_size=16,
+            max_tokens_per_step=512, frame_steps=2)
+LADDER = (16, 144, 272, 512)    # pack_ladder(8, 64): 0, 2, 4 whole chunks, all
+
+
+def _ragged_positions(ws, width):
+    offs = np.arange(width)[None, :]
+    return np.where(offs < np.asarray(ws)[:, None], 3 + offs, -1).astype(
+        np.int32)
+
+
+@pytest.mark.parametrize("ws,rung", [
+    ([1, 1, 0, 1, 1, 0, 1, 1], 0),       # every live row decodes
+    ([64, 1, 0, 1, 1, 0, 7, 1], 1),      # one whole chunk, decodes, w = 0
+    ([64, 64, 64, 0, 1, 1, 9, 0], 2),
+    ([64] * 8, 3),                       # every row a whole chunk: unpacked
+    ([0] * 8, 0),                        # nothing live
+])
+def test_pack_unpack_round_trip(ws, rung):
+    """``_on_live`` hands a per-token function the live positions alone, in
+    order, on the rung that holds them, and puts its outputs back where they
+    came from with zeros at the dead positions."""
+    from deepspeed_tpu.inference.v2 import model_runner as mr
+    from deepspeed_tpu.inference.v2.telemetry import pack_ladder
+    assert pack_ladder(8, 64) == LADDER
+    assert pack_ladder(8, 1) == (8,) and pack_ladder(8, 16) == (128,)
+    assert pack_ladder(16, 128) == (16, 144, 272, 528, 1040, 2048)
+    positions = _ragged_positions(ws, 64)
+    x = np.arange(8 * 64 * 3, dtype=np.float32).reshape(8, 64, 3) + 1.0
+    seen = []
+
+    def fn(x, pos):
+        seen.append(x.shape[:2])
+        return x * 2.0, pos[..., None]
+
+    pack = mr._pack_plan(positions, LADDER)
+    assert int(pack[0]) == rung
+    y, p = jax.jit(lambda x, pos: mr._on_live(
+        mr._pack_plan(pos, LADDER), fn, x, pos))(x, positions)
+    live = positions >= 0
+    np.testing.assert_array_equal(np.asarray(y)[live], 2.0 * x[live])
+    np.testing.assert_array_equal(np.asarray(p)[live][:, 0], positions[live])
+    assert not np.asarray(y)[~live].any()
+    assert seen == [(1, 16), (1, 144), (1, 272), (1, 512)]    # a trace a rung
+    # the packed view holds the live positions first, in chunk order
+    n = int(live.sum())
+    np.testing.assert_array_equal(np.asarray(pack[1])[:n],
+                                  np.flatnonzero(live.reshape(-1))[:n])
+
+
+@pytest.fixture(scope="module")
+def pack_engines(tiny_model_params):
+    """Two engines over one model at a shape that packs (8 x 64): as
+    shipped, and with the ladder cut to its top rung (the unpacked
+    program)."""
+    model, params = tiny_model_params
+    packed = _engine(model, params, **PACK)
+    whole = _engine(model, params, **PACK)
+    whole.runner.pack_ladder = lambda b, c: (b * c,)
+    return packed, whole
+
+
+@pytest.mark.parametrize("n_prefill,rung", [(1, 144), (3, 272), (8, 512)])
+def test_packed_wide_frame_matches_unpacked(pack_engines, n_prefill, rung):
+    """A wide frame with 1, 3 and all 8 rows prefilling a whole chunk (the
+    rest decoding) emits the tokens of the unpacked program, takes the rung
+    its live count calls for, and says so in the per-rung counters."""
+    packed, whole = pack_engines
+    rng = np.random.default_rng(11 + n_prefill)
+    late = {u: rng.integers(0, 200, (100,)).astype(np.int32)
+            for u in range(n_prefill)}
+    early = {u: rng.integers(0, 200, (5,)).astype(np.int32)
+             for u in range(n_prefill, 8)}
+
+    def arrivals():
+        yield list(early.items())        # short prompts: decoding by frame 1
+        yield list(late.items())         # these prefill while the rest decode
+
+    outs = {}
+    for name, e in (("packed", packed), ("whole", whole)):
+        before = e.runner.compile_count_total()
+        outs[name] = dict(e.serve(arrivals(), max_new_tokens=12))
+        assert e.kv.free_blocks == e.kv.num_blocks - 1
+        outs[name + "_programs"] = e.runner.compile_count_total() - before
+    assert set(outs["packed"]) == set(late) | set(early)
+    for u in outs["whole"]:
+        np.testing.assert_array_equal(outs["packed"][u], outs["whole"][u],
+                                      err_msg=f"uid={u} diverged")
+    steps = {int(dict(k)["tokens"]): v for k, v in
+             packed.telemetry.labeled["rung_steps"].items()}
+    assert steps.get(rung, 0) >= 1, steps
+    # a late prompt's last chunk is short, so the same wide frames also
+    # hold steps on lower rungs; with every row late there are two steps
+    assert n_prefill == 8 or len(steps) > 1, steps
+    c = packed.telemetry.counters
+    assert c["rung_steps"] == sum(steps.values())
+    assert c["positions_computed"] < whole.telemetry.counters[
+        "positions_computed"] or n_prefill == 8
+    assert "ds_serving_rung_steps_total{" in packed.telemetry.render_prometheus()
+    # no program beyond the unpacked engine's set: the rung is chosen in
+    # the graph, never by a new program
+    assert outs["packed_programs"] == outs["whole_programs"]
+
+
+@pytest.mark.parametrize("ws", [[64, 1, 0, 1, 1, 0, 7, 1],
+                                [64, 64, 40, 1, 1, 1, 0, 0],
+                                [64] * 8])
+def test_packed_forward_logits_match_unpacked(pack_engines, ws):
+    """One forward over a ragged chunk: last-token logits of the live rows
+    and the KV the step commits agree with the unpacked program's."""
+    packed, whole = pack_engines
+    rng = np.random.default_rng(3)
+    positions = _ragged_positions(ws, 64)
+    ids = rng.integers(0, 200, (8, 64)).astype(np.int32)
+    tables = np.arange(1, 8 * 8 + 1, dtype=np.int32).reshape(8, 8)
+    got = {}
+    for name, e in (("packed", packed), ("whole", whole)):
+        logits, k, v = jax.jit(e.runner._forward)(
+            e.params, ids, positions, tables, np.asarray(ws, np.int32),
+            jax.numpy.zeros_like(e.kv.k), jax.numpy.zeros_like(e.kv.v))
+        got[name] = (np.asarray(logits), np.asarray(k), np.asarray(v))
+    rows = np.asarray(ws) > 0
+    np.testing.assert_allclose(got["packed"][0][rows], got["whole"][0][rows],
+                               rtol=1e-5, atol=1e-5)
+    for i in (1, 2):     # pages 1.. hold the live positions' KV (0 is trash)
+        np.testing.assert_allclose(got["packed"][i][:, :, 1:],
+                                   got["whole"][i][:, :, 1:],
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("variant,packs", [
+    ("int8-kv", True),          # gather path: the dense halves pack all the same
+    ("int8-weights", True),     # L.dq dequantizes inside the rung
+    ("moe-grouped", True),      # dropless routing treats every token alike
+    ("moe-einsum", False),      # capacity routing: the chunk stays whole
+])
+def test_packing_adapts_to_the_model(variant, packs):
+    """Who else runs ``_forward``: quantized KV, quantized weights and
+    dropless experts pack like the dense model and emit the tokens of the
+    unpacked program; capacity-routed experts let pad positions compete for
+    capacity, so that model has one rung and its counters say so."""
+    over, preset = {}, "tiny"
+    if variant == "int8-kv":
+        over["kv_dtype"] = "int8"
+    elif variant == "int8-weights":
+        over["weight_dtype"] = "int8"
+    else:
+        preset = "tiny-moe"
+    model = build_model(preset, **({"moe_impl": "grouped"}
+                                   if variant == "moe-grouped" else {}))
+    params = model.init(jax.random.PRNGKey(0))
+    rng = np.random.default_rng(23)
+    prompts = {u: rng.integers(0, 200, (n,)).astype(np.int32)
+               for u, n in enumerate((100, 5, 70, 9))}
+    outs = {}
+    for name in ("packed", "whole"):
+        e = InferenceEngineV2(
+            model, RaggedInferenceEngineConfig(dtype="float32", **PACK, **over),
+            params=params, max_seq_len=128)
+        if name == "whole":
+            e.runner.pack_ladder = lambda b, c: (b * c,)
+        outs[name] = dict(e.serve(iter([list(prompts.items())]),
+                                  max_new_tokens=6))
+        assert e.kv.free_blocks == e.kv.num_blocks - 1
+        if name == "packed":
+            assert (e.telemetry.counters["rung_steps"] > 0) == packs
+            assert (len(e.runner.pack_ladder(8, 64)) > 1) == packs
+    for u in prompts:
+        np.testing.assert_array_equal(outs["packed"][u], outs["whole"][u],
+                                      err_msg=f"{variant} uid={u}")

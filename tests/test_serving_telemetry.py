@@ -137,6 +137,24 @@ def test_counters_match_host_replay(served):
         == c["requests_retired"] == len(PROMPT_LENS)
     assert c["admission_deferrals"] == 0
     assert c["frames"] == snap["serve_view"]["frames"]
+    # positions_computed is a device lane: what each step's per-token
+    # layers ran. 8 slots x 16 positions has one rung (the chunk whole), so
+    # frame by frame it is slots x steps x the frame's width, and a frame
+    # is one chunk wide exactly when it consumed prompt tokens
+    from deepspeed_tpu.inference.v2.telemetry import pack_ladder
+    assert pack_ladder(8, CHUNK) == (8 * CHUNK,) and c["rung_steps"] == 0
+    per_frame = {}
+    for tag, value, step in snap["events"]:
+        per_frame.setdefault(step, {})[tag] = value
+    last = {"serving/positions_computed": 0, "serving/prefill_tokens": 0,
+            "serving/slot_steps_capacity": 0}
+    for step in sorted(per_frame):
+        d = {t: per_frame[step][t] - last[t] for t in last}
+        last = {t: per_frame[step][t] for t in last}
+        width = CHUNK if d["serving/prefill_tokens"] else 1
+        assert d["serving/positions_computed"] \
+            == d["serving/slot_steps_capacity"] * width, (step, d)
+    assert last["serving/positions_computed"] == c["positions_computed"] > 0
 
 
 def test_eos_counted_in_graph(tiny_model_params, served):

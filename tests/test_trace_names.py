@@ -302,50 +302,68 @@ def test_stat_range_is_checked_when_serving_starts(tiny_model_params):
 
 
 def _mirror_frame(slots, width, steps, window):
-    """The in-graph step arithmetic (``_wide_plan`` + ``_attn_work``)
-    replayed on the host mirrors as they stand BEFORE a frame: returns
-    (kv positions read, query x key pairs) of the frame."""
-    kv_read = pairs = 0
-    for i in range(slots.n_slots):
-        if slots.uid_of_slot[i] < 0:
-            continue
-        cached, plen = int(slots.cached_h[i]), int(slots.plen_h[i])
-        produced, limit = int(slots.produced_h[i]), int(slots.limit_h[i])
-        for _ in range(steps):
+    """The in-graph step arithmetic (``_wide_plan`` + ``_attn_work`` +
+    ``_rung_of``) replayed on the host mirrors as they stand BEFORE a
+    frame: returns (kv positions read, query x key pairs, positions the
+    per-token layers ran, {rung: steps}) of the frame."""
+    from deepspeed_tpu.inference.v2.telemetry import pack_ladder
+    ladder = pack_ladder(slots.n_slots, width)
+    kv_read = pairs = positions = 0
+    rung_steps = {}
+    rows = [[int(slots.cached_h[i]), int(slots.plen_h[i]),
+             int(slots.produced_h[i]), int(slots.limit_h[i])]
+            for i in range(slots.n_slots) if slots.uid_of_slot[i] >= 0]
+    for _ in range(steps):
+        live = 0
+        for row in rows:
+            cached, plen, produced, limit = row
             prefilling = cached < plen
             if not (prefilling or produced < limit):
-                break
+                continue
             w = min(width, plen - cached) if prefilling else 1
             kv = cached + w if window is None else min(cached + w, window + w)
             kv_read += kv
             pairs += w * kv
+            live += w
             if not prefilling or cached + w == plen:
-                produced += 1
-            cached += w
-    return kv_read, pairs
+                row[2] += 1
+            row[0] += w
+        rung = next(t for t in ladder if live <= t)
+        positions += rung
+        if len(ladder) > 1:
+            rung_steps[rung] = rung_steps.get(rung, 0) + 1
+    return kv_read, pairs, positions, rung_steps
 
 
 @pytest.mark.parametrize("tp", [1, 8])
 @pytest.mark.parametrize("window", [None, 8])
 def test_work_counters_equal_the_host_mirror(window, tp, monkeypatch):
-    """``positions_computed``, ``kv_positions_read_*`` and ``attn_pairs_*``
-    equal the host-mirror arithmetic exactly on a mixed prefill/decode run
-    (arrivals land mid-decode), with and without a sliding window, tp=1
-    and tp=8."""
+    """``positions_computed`` (the rung each step chose in the graph, a
+    device lane), the per-rung step counters, ``kv_positions_read_*`` and
+    ``attn_pairs_*`` equal the host-mirror arithmetic exactly on a mixed
+    prefill/decode run (arrivals land mid-decode), with and without a
+    sliding window, tp=1 and tp=8. The chunk is 32 wide so that a wide step
+    of the 8 slots has rungs (16 and 144 tokens, and the 256 of the chunk
+    whole) and packs its live tokens, under ``shard_map`` too."""
     over = {} if window is None else {"sliding_window": window}
     model = build_model("tiny", num_heads=8, **over)
-    e = _engine(model, model.init(jax.random.PRNGKey(0)), tp=tp)
+    e = _engine(model, model.init(jax.random.PRNGKey(0)), tp=tp,
+                prefill_chunk_size=32)
     want = {"positions_computed": 0, "kv_positions_read_narrow": 0,
             "kv_positions_read_wide": 0, "attn_pairs_narrow": 0,
             "attn_pairs_wide": 0}
+    want_rungs = {}
     orig = DeviceSlotTable.dispatch_frame
 
     def spy(self, runner, params, kv, width, steps, greedy, **kw):
-        kv_read, pairs = _mirror_frame(self, width, steps, window)
+        kv_read, pairs, positions, rungs = _mirror_frame(self, width, steps,
+                                                         window)
         split = "wide" if width > 1 else "narrow"
         want[f"kv_positions_read_{split}"] += kv_read
         want[f"attn_pairs_{split}"] += pairs
-        want["positions_computed"] += self.n_slots * width * steps
+        want["positions_computed"] += positions
+        for t, n in rungs.items():
+            want_rungs[t] = want_rungs.get(t, 0) + n
         return orig(self, runner, params, kv, width, steps, greedy, **kw)
 
     monkeypatch.setattr(DeviceSlotTable, "dispatch_frame", spy)
@@ -355,6 +373,9 @@ def test_work_counters_equal_the_host_mirror(window, tp, monkeypatch):
     got = {k: e.telemetry.counters[k] for k in want}
     assert got == want
     assert want["kv_positions_read_narrow"] and want["attn_pairs_wide"]
+    got_rungs = {int(dict(k)["tokens"]): n for k, n in
+                 e.telemetry.labeled["rung_steps"].items()}
+    assert got_rungs == want_rungs and set(want_rungs) == {16, 144}
     c = e.telemetry.counters
     # the identity useful_position_share rests on
     assert c["prefill_tokens"] == sum(len(p) for p in PROMPTS.values())
