@@ -56,7 +56,8 @@ def _slot_table(eng):
     import jax
     from ..inference.v2.ragged_manager import DeviceSlotTable
     return DeviceSlotTable(4, prompt_width=8, table_width=4,
-                           rng=jax.random.PRNGKey(0), tp=eng.tp_ctx)
+                           rng=jax.random.PRNGKey(0), tp=eng.tp_ctx,
+                           n_stats=eng.runner.n_stats)
 
 
 def _frame_args(eng, slots):

@@ -1084,7 +1084,8 @@ class InferenceEngineV2:
         slots = DeviceSlotTable(
             n_slots, prompt_width=c.prefill_chunk_size,
             table_width=1, rng=frame_rng, tp=self.tp_ctx,
-            debug_replicas=c.tp_debug_replica_check)
+            debug_replicas=c.tp_debug_replica_check,
+            n_stats=self.runner.n_stats)
         if faults is not None:
             faults.begin_serve()     # rearm the scripted schedule
         if self.prefix_cache is not None:

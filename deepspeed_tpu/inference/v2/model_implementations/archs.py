@@ -173,6 +173,36 @@ class Qwen2MoeContainer(Qwen2Container):
                 _get(hf_cfg, "shared_expert_intermediate_size", default=0)))
 
 
+class OlmoeContainer(LlamaContainer):
+    """OLMoE (``modeling_olmoe.py``): Llama's attention with one RMSNorm
+    over the whole q and the whole k projection before the head split, and
+    every MLP a routed one: softmax over all experts, top-k, the weights not
+    renormalized unless ``norm_topk_prob``; no shared expert. Served
+    dropless (``moe_impl="grouped"``), as the ``olmoe-1b-7b`` preset."""
+
+    layer_mapping = {
+        **{k: v for k, v in LlamaContainer.layer_mapping.items()
+           if not k.startswith("mlp.")},
+        "attn.q_norm.scale": Param("model.layers.{l}.self_attn.q_norm.weight"),
+        "attn.k_norm.scale": Param("model.layers.{l}.self_attn.k_norm.weight"),
+        "mlp.router": Param("model.layers.{l}.mlp.gate.weight", t_linear),
+        "mlp.wi_gate": Param(
+            "model.layers.{l}.mlp.experts.{x}.gate_proj.weight", t_linear),
+        "mlp.wi_up": Param(
+            "model.layers.{l}.mlp.experts.{x}.up_proj.weight", t_linear),
+        "mlp.wo": Param(
+            "model.layers.{l}.mlp.experts.{x}.down_proj.weight", t_linear),
+    }
+
+    @classmethod
+    def config(cls, hf_cfg):
+        return _llama_family_config(
+            hf_cfg, qk_norm="full", qk_norm_bias=False, moe_impl="grouped",
+            num_experts=int(hf_cfg.num_experts),
+            num_experts_per_tok=int(hf_cfg.num_experts_per_tok),
+            moe_norm_topk=bool(_get(hf_cfg, "norm_topk_prob", default=False)))
+
+
 def _t_phi3_q(w, cfg):
     q = w[: cfg.num_heads * cfg.dims_per_head]
     return q.T.reshape(cfg.hidden_size, cfg.num_heads, cfg.dims_per_head)
@@ -1115,6 +1145,7 @@ ARCH_CONTAINERS: Dict[str, Type[LayerContainer]] = {
     "mistral": MistralContainer,
     "mixtral": MixtralContainer,
     "qwen2moe": Qwen2MoeContainer,
+    "olmoe": OlmoeContainer,
     "qwen2": Qwen2Container,
     "phi3": Phi3Container,
     "phi": PhiContainer,

@@ -29,8 +29,8 @@ from ...models.transformer import CausalLM
 from ...ops.attention import decode_attention
 from ..sampling import sample_logits_per_row, speculative_verify_per_row
 from .kv_cache import dequantize_kv_lanes, quantize_kv_lanes
-from .telemetry import (MAX_RUNGS, N_STATS,   # in-graph counter layout
-                        pack_ladder)
+from .telemetry import (MAX_RUNGS, MOE_STAT_NAMES,   # in-graph counter
+                        n_stats, pack_ladder)        # layout
 
 
 def _use_pallas_paged() -> bool:
@@ -87,6 +87,21 @@ class PagedModelRunner:
                                        and cfg.moe_impl != "grouped")
         return ladder[-1:] if whole else ladder
 
+    @property
+    def experts_from_stack(self) -> bool:
+        """Dropless experts run a grouped-product kernel, and a kernel's
+        operand is materialized: a layer's slice of the stacked experts
+        would be copied every layer and step. Such a model walks its layers
+        by index at every width, and the product reads the stack."""
+        return self.cfg.is_moe and self.cfg.moe_impl == "grouped"
+
+    @property
+    def n_stats(self) -> int:
+        """Lanes of the stat vector this model's serving loops carry: a
+        model with routed experts counts their work in lanes of its own
+        (``telemetry.n_stats``)."""
+        return n_stats(self.cfg.is_moe)
+
     def set_tp(self, tp_ctx) -> None:
         """Bind a ``tp.TPContext`` (engine setup, before any serving loop
         compiles). The serving entry points close over the context, so any
@@ -105,13 +120,17 @@ class PagedModelRunner:
         return run
 
     def _forward(self, params, ids, positions, block_tables, valid_counts,
-                 kpool, vpool, *, all_logits=False, tp=None):
+                 kpool, vpool, *, all_logits=False, tp=None, moe_work=False):
         """ids/positions: (B, C); block_tables: (B, MB);
         valid_counts: (B,) number of real (non-pad) tokens in the chunk;
         kpool/vpool: (L, KVH, NB, bs, D). Returns (last_logits (B, V),
         kpool, vpool) — or ((B, C, V) logits at EVERY chunk position when
         ``all_logits`` is set, which is how the speculative verify scores
-        all gamma+1 positions in one batched ragged forward.
+        all gamma+1 positions in one batched ragged forward. With
+        ``moe_work`` (the serving loops, for their stats vector) a fourth
+        value: the routed experts' work summed over the layers, in the
+        order of ``telemetry.MOE_STAT_NAMES``, or None for a model without
+        routed experts, which computes and carries nothing for them.
 
         ``tp`` (a ``tp.TPContext``) marks a trace INSIDE a shard_map manual
         region: params and KV pools are this shard's slices (heads/kv_heads/
@@ -125,6 +144,10 @@ class PagedModelRunner:
         model = self.model
         dt = cfg.act_dtype
         b = ids.shape[0]
+        # some layer routes to experts: the layers then count that work and
+        # learn which positions are live. A model without them traces none
+        # of it
+        routed = cfg.is_moe
         # the per-token layers (embedding, norms, projections, MLP) run on
         # the chunk's LIVE positions only, packed into the smallest rung of
         # a static ladder that holds them; attention, the commit and the
@@ -234,22 +257,38 @@ class PagedModelRunner:
                 y = L.apply_norm(lp["norm3"], y, cfg)
             return y
 
-        def mlp(lp, h, y, moe):
-            lp = at(lp)
+        def mlp(lp, h, y, moe, live=None):
+            """The rest of the layer after attention and, in a model with
+            routed experts (``routed``), their work in this layer
+            (``MOE_STAT_NAMES``): rows sent through experts, experts
+            touched, the largest group."""
+            stack, lp = lp, at(lp)
+            work = jnp.zeros((len(MOE_STAT_NAMES),), jnp.int32)
             if cfg.parallel_block:   # NeoX/Falcon: attn and mlp share input
                 m_in = L.apply_norm(lp["norm2"], h, cfg)
             else:
                 h = h + y
                 m_in = L.apply_norm(lp["norm2"], h, cfg)
             if moe:
-                mlp_out, _ = L.apply_moe_mlp(lp["mlp"], m_in, cfg)
+                experts, at_layer = lp["mlp"], None
+                if isinstance(stack, tuple):
+                    # the grouped product is a kernel: it takes the stacked
+                    # experts whole and the layer's index, never a slice
+                    at_layer = stack[1]
+                    experts = {**experts, **{n: stack[0]["mlp"][n]
+                                             for n in L.EXPERT_MATRICES}}
+                mlp_out, _, groups = L.apply_moe_mlp(
+                    experts, m_in, cfg, live=live, layer=at_layer)
+                work = jnp.stack([jnp.sum(groups), jnp.sum(groups > 0),
+                                  jnp.max(groups)]).astype(jnp.int32)
             else:
                 mlp_out = L.apply_mlp(
                     lp["mlp"], m_in, cfg,
                     reduce=tp.coll.psum_mlp if tp is not None else None)
             if cfg.sandwich_norm:
                 mlp_out = L.apply_norm(lp["norm4"], mlp_out, cfg)
-            return h + y + mlp_out if cfg.parallel_block else h + mlp_out
+            h = h + y + mlp_out if cfg.parallel_block else h + mlp_out
+            return (h, work) if routed else h
 
         def layer(h, xs, tag=None):
             lp, l, win = xs
@@ -306,28 +345,33 @@ class PagedModelRunner:
                                            window=win, chunk_k=k, chunk_v=v,
                                            chunk_start=chunk_start,
                                            alibi_slopes=slopes)
-            def dense_out(h, out):
+            def dense_out(h, out, live=None):
                 with jax.named_scope("attn_out"):
                     y = attn_out(lp, out)
                 # group tag overrides
-                return mlp(lp, h, y, cfg.is_moe if tag is None else tag == "moe")
+                return mlp(lp, h, y,
+                           cfg.is_moe if tag is None else tag == "moe", live)
             with jax.named_scope("mlp"):
-                h = _on_live(pack, dense_out, h, out)
+                h = _on_live(pack, dense_out, h, out,
+                             live=~is_pad if routed else None)
+                h, *work = h if routed else (h,)
             # quantize-at-append: the chunk's KV leaves the layer already in
             # pool representation, so the commit scatter in _run_layers is
             # dtype-blind and the pool never holds a float row
             with jax.named_scope("kv_commit"):
                 if quantized_kv:
-                    return h, (quantize_kv_lanes(k), quantize_kv_lanes(v))
-                return h, (k.astype(kpool.dtype), v.astype(vpool.dtype))
+                    return h, (quantize_kv_lanes(k), quantize_kv_lanes(v),
+                               *work)
+                return h, (k.astype(kpool.dtype), v.astype(vpool.dtype),
+                           *work)
 
-        h, kpool, vpool = self._run_layers(layer, h, params, kpool, vpool,
-                                           windows, blk, off,
-                                           stacked=pack is not None)
+        h, kpool, vpool, work = self._run_layers(
+            layer, h, params, kpool, vpool, windows, blk, off,
+            stacked=pack is not None or self.experts_from_stack)
         with jax.named_scope("lm_head"):
             h = L.apply_norm(params["final_norm"], h, cfg)
             logits = self._head(params, h, valid_counts, all_logits, tp=tp)
-        return logits, kpool, vpool
+        return (logits, kpool, vpool) + ((work,) if moe_work else ())
 
     def _run_layers(self, layer, h, params, kpool, vpool, windows, blk, off,
                     stacked=False):
@@ -336,6 +380,8 @@ class PagedModelRunner:
         The full pools stay loop-invariant (read through a global layer
         index, never a materialized per-layer slice); each layer's chunk KV
         returns as scan ys and is committed with ONE token-sized scatter.
+        A third ys, the layers' routed experts' work, comes back summed
+        (None where the layers give none).
         Per-layer xs are (layer index, window), which the shared
         ``walk_layer_plan`` driver slices to match the grouped param layout
         exactly like the train forward and the cached decode.
@@ -362,7 +408,7 @@ class PagedModelRunner:
                 lp = (layers[key] if key else layers, i)
             return layer(h, (lp, l, win), tag=tag)
 
-        h, (ck_all, cv_all) = walk_layer_plan(
+        h, (ck_all, cv_all, *work) = walk_layer_plan(
             model._plan, model._groups, walked,
             (layer_ids, windows), h, body)
         with jax.named_scope("kv_commit"):
@@ -371,7 +417,7 @@ class PagedModelRunner:
             # (L, KVH, B, C, D)
             kpool = kpool.at[:, :, blk, off].set(ck_all.transpose(0, 3, 1, 2, 4))
             vpool = vpool.at[:, :, blk, off].set(cv_all.transpose(0, 3, 1, 2, 4))
-        return h, kpool, vpool
+        return h, kpool, vpool, jnp.sum(work[0], axis=0) if work else None
 
     def _head(self, params, h, valid_counts, all_logits=False, tp=None):
         """Last-valid-token logits (B, V) from normed hidden states — or
@@ -513,7 +559,8 @@ class PagedModelRunner:
                 zero = jnp.zeros((b,), jnp.int32)
                 no = jnp.zeros((b,), bool)
                 carry = (zero, zero, zero, no, no, no,
-                         jnp.zeros((N_STATS,), jnp.int32), rng, kpool, vpool)
+                         jnp.zeros((self.n_stats,), jnp.int32), rng, kpool,
+                         vpool)
                 carry, (toks_w, emit_w) = jax.lax.scan(
                     make_body(chunk), carry, None, length=wide_steps)
                 carry, (toks_n, emit_n) = jax.lax.scan(
@@ -715,7 +762,7 @@ class PagedModelRunner:
                 zero = jnp.zeros((b,), jnp.int32)
                 no = jnp.zeros((b,), bool)
                 carry = (zero, zero, zero, zero, no, no, no,
-                         jnp.zeros((N_STATS,), jnp.int32), rng,
+                         jnp.zeros((self.n_stats,), jnp.int32), rng,
                          kpool, vpool, dkpool, dvpool)
                 carry, (toks_w, emit_w) = jax.lax.scan(
                     make_body(chunk), carry, None, length=wide_steps)
@@ -815,7 +862,7 @@ def _pack_plan(positions, ladder):
     return _rung_of(positions, ladder), src, dst, live, ladder
 
 
-def _on_live(pack, fn, *xs):
+def _on_live(pack, fn, *xs, live=None):
     """``fn(*xs)`` for a ``fn`` that treats every position alike (its
     output at a position depends on that position's inputs only), computed
     on the live positions alone: a rung gathers its ``T`` packed tokens
@@ -824,22 +871,35 @@ def _on_live(pack, fn, *xs):
     positions. The last rung holds the whole chunk and takes the same path,
     so the rungs differ in ``T`` alone and ask one layout of the weights
     they share. The rung was chosen in the graph (``_pack_plan``), so one
-    program serves every live count. ``pack`` None is ``fn(*xs)``."""
+    program serves every live count. ``pack`` None is ``fn(*xs)``.
+
+    ``live``, the chunk's (B, C) mask of live positions, is for a ``fn``
+    that has to know them (a dead position may reach no routed expert): it
+    is then handed the mask as its last argument, in the layout it runs on
+    (a rung's padding past the live count is dead), and returns (ys,
+    extra); ``extra``, whose shape is no rung's business, comes back as it
+    is."""
     if pack is None:
-        return fn(*xs)
-    rung, src, dst, live, ladder = pack
+        return fn(*xs) if live is None else fn(*xs, live)
+    rung, src, dst, is_live, ladder = pack
 
     def on_rung(t):
         def run(*xs):
-            ys = fn(*(x.reshape((1, -1) + x.shape[2:])[:, src[:t]]
-                      for x in xs))
+            packed = [x.reshape((1, -1) + x.shape[2:])[:, src[:t]]
+                      for x in xs]
+            if live is None:
+                ys, extra = fn(*packed), None
+            else:
+                ys, extra = fn(*packed, (jnp.arange(t) < jnp.sum(is_live))[None])
             single = not isinstance(ys, tuple)
             ys = tuple(
-                jnp.where(live.reshape(live.shape + (1,) * (y.ndim - 2)),
+                jnp.where(is_live.reshape(is_live.shape
+                                          + (1,) * (y.ndim - 2)),
                           y[0][jnp.minimum(dst, t - 1)],
                           jnp.zeros((), y.dtype))
                 for y in ((ys,) if single else ys))
-            return ys[0] if single else ys
+            ys = ys[0] if single else ys
+            return ys if live is None else (ys, extra)
         return run
 
     return jax.lax.switch(rung, [on_rung(t) for t in ladder], *xs)
@@ -903,8 +963,8 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
             # the attention's work this step (before a repair zeroes w:
             # the step was computed either way)
             kv_read, attn_pairs = _attn_work(cached, w, window)
-        logits, kpool, vpool = fwd(params, ids, positions, tables, w,
-                                   kpool, vpool)
+        logits, kpool, vpool, moe_work = fwd(params, ids, positions, tables,
+                                             w, kpool, vpool, moe_work=True)
         with jax.named_scope("sample"):
             logits = _inject_poison(logits, poison)
             if greedy:
@@ -930,7 +990,7 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                 prefill_toks=jnp.where(prefilling, w, 0),
                 eos=emit & (nxt == eos_ids),
                 target_fwd=active & ~prefilling,
-                kv_read=kv_read, attn_pairs=attn_pairs)
+                kv_read=kv_read, attn_pairs=attn_pairs, moe_work=moe_work)
         return ((cached + w, produced + emit.astype(jnp.int32),
                  last_tok, done, poison, nonfinite, stats, rng, kpool,
                  vpool),
@@ -981,14 +1041,16 @@ def _attn_work(cached, w, window):
 
 def _stat_delta(positions, ladder, emitted=None, active=None,
                 prefill_toks=None, eos=None, target_fwd=None, drafted=None,
-                accepted=None, kv_read=None, attn_pairs=None):
+                accepted=None, kv_read=None, attn_pairs=None, moe_work=None):
     """One step's (N_STATS,) in-graph counter increment. Each keyword is a
     bool mask / int array to sum, or None for zero — the layout is pinned by
     the STAT_* indices in ``telemetry.py`` and the host-mirror replay tests
     assert the resulting totals exactly. ``positions`` (B, C) is the chunk
-    the target forwarded and ``ladder`` its rungs: the last lanes are the
-    rung the forward chose for it (``_rung_of``, the same arithmetic) and
-    one step at that rung."""
+    the target forwarded and ``ladder`` its rungs: the lane after the sums
+    is the rung the forward chose for it (``_rung_of``, the same
+    arithmetic), then one step at that rung. Behind them ``moe_work``, the
+    target forward's own count of its routed experts' work
+    (``MOE_STAT_NAMES``), where the model has any (``telemetry.n_stats``)."""
     vals = [emitted, active, prefill_toks, eos, target_fwd, drafted, accepted,
             kv_read, attn_pairs]
     z = jnp.zeros((), jnp.int32)
@@ -998,8 +1060,9 @@ def _stat_delta(positions, ladder, emitted=None, active=None,
     # a shape with one rung counts its steps at none
     steps = (jnp.arange(MAX_RUNGS) == rung).astype(jnp.int32) \
         * int(len(ladder) > 1)
-    out = jnp.concatenate([jnp.stack(out), steps])
-    assert out.shape == (N_STATS,)
+    out = jnp.concatenate([jnp.stack(out), steps]
+                          + ([] if moe_work is None else [moe_work]))
+    assert out.shape == (n_stats(moe_work is not None),)
     return out
 
 
@@ -1076,8 +1139,9 @@ def _spec_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                     prompts, prompt_lens, limits, width, cached, produced,
                     last_tok, done)
                 kv_read, attn_pairs = _attn_work(cached, w, window)
-            logits, kpool, vpool = fwd(params, ids, positions, tables, w,
-                                       kpool, vpool)
+            logits, kpool, vpool, moe_work = fwd(
+                params, ids, positions, tables, w, kpool, vpool,
+                moe_work=True)
             logits = _inject_poison(logits, poison)
             # the draft ingests the identical chunk: prefill rows stream the
             # prompt into the draft pools, decode rows (w=1 inside a wide
@@ -1120,7 +1184,7 @@ def _spec_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                 emitted=emit, active=active,
                 prefill_toks=jnp.where(prefilling, w, 0),
                 eos=emit & (nxt == eos_ids),
-                kv_read=kv_read, attn_pairs=attn_pairs)
+                kv_read=kv_read, attn_pairs=attn_pairs, moe_work=moe_work)
             return ((cached + w, produced + emit.astype(jnp.int32), last_tok,
                      penult, done, poison, nonfinite, stats, rng, kpool,
                      vpool, dkpool, dvpool),
@@ -1182,8 +1246,9 @@ def _spec_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
         # last token + all gamma drafts ----
         ids_v = jnp.concatenate([last_tok[:, None], q], axis=1)
         pos_v = pos_of(cached[:, None] + koffs[None, :])
-        tlogits, kpool, vpool = fwd(params, ids_v, pos_v, tables,
-                                    k_out * av, kpool, vpool, all_logits=True)
+        tlogits, kpool, vpool, moe_work = fwd(
+            params, ids_v, pos_v, tables, k_out * av, kpool, vpool,
+            all_logits=True, moe_work=True)
         tlogits = _inject_poison(tlogits, poison)
         n_acc, repl = speculative_verify_per_row(tlogits, dlogits, q, temps,
                                                  rng=rng_v)
@@ -1221,7 +1286,8 @@ def _spec_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
             pos_v, ladder(*pos_v.shape),
             emitted=emit, active=active, eos=emit & is_eos,
             target_fwd=active, drafted=gamma * active.astype(jnp.int32),
-            accepted=emit[:, 1:], kv_read=kv_read, attn_pairs=attn_pairs)
+            accepted=emit[:, 1:], kv_read=kv_read, attn_pairs=attn_pairs,
+            moe_work=moe_work)
         return ((cached + m, produced + m, last_tok, penult, done, poison,
                  nonfinite, stats, rng, kpool, vpool, dkpool, dvpool),
                 (jnp.where(emit, e, -1), emit))

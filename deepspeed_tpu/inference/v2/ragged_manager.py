@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .telemetry import zero_stats
+from .telemetry import N_STATS, zero_stats
 
 
 @dataclasses.dataclass
@@ -144,8 +144,10 @@ class DeviceSlotTable:
     """
 
     def __init__(self, n_slots: int, prompt_width: int, table_width: int, rng,
-                 tp=None, debug_replicas: bool = False):
+                 tp=None, debug_replicas: bool = False,
+                 n_stats: int = N_STATS):
         self.n_slots = n_slots
+        self.n_stats = n_stats     # lanes of the runner's stat vector
         # tensor-parallel serving (tp.TPContext): every slot array is
         # REPLICATED over the tp mesh — the frame loop's shard_map treats
         # them as unmapped carries, and every frame-boundary mutation
@@ -175,7 +177,7 @@ class DeviceSlotTable:
         self.poison = self._dev(jnp.zeros((n_slots,), bool))
         self.nonfinite = self._dev(jnp.zeros((n_slots,), bool))
         self.rng = self._dev(rng)
-        # in-graph telemetry counters (telemetry.N_STATS): accumulate on the
+        # in-graph telemetry counters (telemetry.n_stats): accumulate on the
         # donated carry; the host reads AND rebases them only at frame
         # boundaries (stats_delta), so the int32 lanes can never wrap
         # within one read window. Under tp it is replicated like the rest.
@@ -201,7 +203,7 @@ class DeviceSlotTable:
         return jax.device_put(jnp.asarray(x), self._rep)
 
     def _fresh_stats(self):
-        return self._dev(zero_stats())
+        return self._dev(zero_stats(self.n_stats))
 
     @property
     def committed_h(self) -> np.ndarray:

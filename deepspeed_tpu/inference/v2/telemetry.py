@@ -87,6 +87,15 @@ from ...utils.logging import logger
 #                  shape has one rung only
 #   RUNG0..        steps run at rung i of the step's ``pack_ladder``
 #                  (``MAX_RUNGS`` lanes; a one-rung shape counts in none)
+# A model with routed experts carries ``len(MOE_STAT_NAMES)`` lanes more,
+# behind these (``n_stats``); a dense model's vector, and so its frame
+# programs, have no trace of them:
+#   EXPERT_ROWS    rows the step sent through routed experts (token x
+#                  chosen expert), summed over the MoE layers: the group
+#                  sizes the grouped product was handed
+#   EXPERTS_TOUCHED  experts that got at least one row, summed over the MoE
+#                  layers: each is a read of that expert's three matrices
+#   EXPERT_ROWS_MAX  the largest group of each MoE layer, summed over them
 # KV_READ and ATTN_PAIRS are work, not events: the host splits each frame's
 # delta by the frame's width into ``<name>_narrow`` / ``<name>_wide``
 # counters (SPLIT_STAT_NAMES), the operands of the paged kernels' roofline
@@ -105,6 +114,8 @@ STAT_RUNG0 = 10
 #: rungs a ladder may have (``pack_ladder``), one lane each
 MAX_RUNGS = 6
 N_STATS = STAT_RUNG0 + MAX_RUNGS
+#: first of the lanes a model with routed experts appends (MOE_STAT_NAMES)
+STAT_EXPERT_ROWS = N_STATS
 #: a smaller token buffer costs what this one does: the MXU's rows are not
 #: filled and the weights are read all the same
 MIN_RUNG = 128
@@ -114,6 +125,15 @@ STAT_NAMES = ("tokens_emitted", "active_row_steps", "prefill_tokens",
               "accepted_draft_tokens")
 #: lanes after STAT_NAMES, split by frame width at absorption
 SPLIT_STAT_NAMES = ("kv_positions_read", "attn_pairs")
+#: the routed experts' work, lanes STAT_EXPERT_ROWS..: counters of their own
+MOE_STAT_NAMES = ("expert_rows", "experts_touched", "expert_rows_max")
+
+
+def n_stats(routed: bool) -> int:
+    """Lanes of the stat vector of a model with (or without) routed
+    experts."""
+    return N_STATS + (len(MOE_STAT_NAMES) if routed else 0)
+
 
 #: host work between two frames, in loop order; ``dispatch`` and ``fetch``
 #: lie inside the ``serve_frame/...`` span (fetch is the host waiting for
@@ -250,12 +270,12 @@ def pack_ladder(slots: int, width: int) -> tuple:
     return (rung(0),) + tuple(rungs[2 - MAX_RUNGS:]) + (top,)
 
 
-def zero_stats():
-    """Fresh ``(N_STATS,)`` device stat vector for a frame carry (a
+def zero_stats(lanes: int = N_STATS):
+    """Fresh ``(lanes,)`` device stat vector for a frame carry (a
     tensor-parallel frame loop carries it replicated, like every other slot
     array; see ``DeviceSlotTable.stats_delta``)."""
     import jax.numpy as jnp
-    return jnp.zeros((N_STATS,), jnp.int32)
+    return jnp.zeros((lanes,), jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -445,6 +465,8 @@ class ServingTelemetry:
         for n in SPLIT_STAT_NAMES:
             self.counters[f"{n}_narrow"] = 0
             self.counters[f"{n}_wide"] = 0
+        for n in MOE_STAT_NAMES:
+            self.counters[n] = 0
         # exclusive host time of each boundary phase (phase())
         for n in PHASES:
             self.counters[f"host_{n}_ns"] = 0
@@ -1045,7 +1067,7 @@ class ServingTelemetry:
     def on_frame(self, *, delta: np.ndarray, width: int, steps: int,
                  live_slots: int, kv_blocks_in_use: int,
                  arrival_ewma: float, queue_depth: int) -> None:
-        """Absorb one frame's device counter DELTA (``(N_STATS,)`` int64)
+        """Absorb one frame's device counter DELTA (``(n_stats,)`` int64)
         plus the host-known frame facts, update the serve_stats view, and
         fan out to the attached monitor. When telemetry is disabled the
         engine calls ``frame_view_update`` instead (so even the argument
@@ -1056,6 +1078,11 @@ class ServingTelemetry:
             return
         for i, name in enumerate(STAT_NAMES):
             self.counters[name] += int(delta[i])
+        # a dense model's vector ends with the rung lanes
+        moe = dict.fromkeys(MOE_STAT_NAMES, 0)
+        moe.update(zip(MOE_STAT_NAMES, map(int, delta[STAT_EXPERT_ROWS:])))
+        for name, value in moe.items():
+            self.counters[name] += value
         if self.trace:
             # this frame's work on the profiler's clock, right after its
             # serve_frame span: a reduction of the trace matches work to
@@ -1063,7 +1090,7 @@ class ServingTelemetry:
             with jax.profiler.TraceAnnotation(
                     "serve/frame_work", width=width, steps=steps,
                     **{n: int(delta[i]) for i, n in
-                       enumerate(STAT_NAMES + SPLIT_STAT_NAMES)}):
+                       enumerate(STAT_NAMES + SPLIT_STAT_NAMES)}, **moe):
                 pass
         split = "wide" if width > 1 else "narrow"
         for i, name in enumerate(SPLIT_STAT_NAMES, len(STAT_NAMES)):
@@ -1076,7 +1103,7 @@ class ServingTelemetry:
         ladder = pack_ladder(
             int(self.gauges["slot_count"]),
             self._gamma + 1 if width == 1 and self._gamma else width)
-        for tokens, n in zip(ladder, delta[STAT_RUNG0:]):
+        for tokens, n in zip(ladder, delta[STAT_RUNG0:N_STATS]):
             if n:
                 self.counters["rung_steps"] += int(n)
                 self._inc_labeled("rung_steps", (("tokens", str(tokens)),),

@@ -228,6 +228,15 @@ PRESETS = {
     "mistral-7b": TransformerConfig(vocab_size=32000, hidden_size=4096, num_layers=32, num_heads=32,
                                     num_kv_heads=8, intermediate_size=14336, max_seq_len=32768,
                                     sliding_window=4096),
+    # OLMoE-1B-7B (allenai/OLMoE-1B-7B-0125-Instruct config.json): 64 routed
+    # experts of width 1024, 8 a token, softmax over all 64 then top-8 with
+    # the weights NOT renormalized, no shared expert; one RMSNorm over the
+    # whole q / k projection before the head split and RoPE. Dropless
+    # routing: a served token that lost its expert is a wrong answer
+    "olmoe-1b-7b": TransformerConfig(vocab_size=50304, hidden_size=2048, num_layers=16, num_heads=16,
+                                     num_kv_heads=16, intermediate_size=1024, max_seq_len=4096,
+                                     num_experts=64, num_experts_per_tok=8, moe_norm_topk=False,
+                                     moe_impl="grouped", qk_norm="full", qk_norm_bias=False),
     # BERT family (post-norm encoder, MLM head; acceptance config 2 trains
     # bert-large under ZeRO-1/2)
     "bert-base": TransformerConfig(vocab_size=30522, hidden_size=768, num_layers=12, num_heads=12,

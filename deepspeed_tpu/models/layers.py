@@ -352,6 +352,10 @@ def apply_mlp(params, x, cfg: TransformerConfig, reduce=None):
 
 # ---- MoE MLP ------------------------------------------------------------
 
+#: the routed experts' weights, stacked over experts: (X, E, F) or (X, F, E)
+EXPERT_MATRICES = ("wi_gate", "wi_up", "wo")
+
+
 def init_moe_mlp(rng, cfg: TransformerConfig):
     """Mixtral-style top-k routed experts with swiglu experts (+ optional
     Qwen2-MoE always-on shared expert with its own sigmoid gate)."""
@@ -395,13 +399,25 @@ def _apply_shared_expert(params, x, cfg: TransformerConfig):
     return gate * sh
 
 
-def apply_moe_grouped(params, x, cfg: TransformerConfig):
+def apply_moe_grouped(params, x, cfg: TransformerConfig, live=None,
+                      layer=None):
     """Dropless grouped-GEMM MoE (megablox pattern; reference analog:
     ``inference/v2/kernels/cutlass_ops/moe_gemm``): tokens are sorted by
     assigned expert and each expert's contiguous row group hits one MXU-tiled
     ``ragged_dot`` — no capacity buffers, no dense (T, X, C) dispatch
     einsums, no token dropping. Selected by ``moe_impl: "grouped"``;
     requires an unsharded expert axis (EP uses the einsum/all-to-all path).
+
+    ``live`` (B, S) bool, serving: the positions that hold a token. A dead
+    position (an idle slot, a packed buffer's padding) reaches no expert:
+    its rows sort behind the last expert and belong to no group, so
+    ``ragged_dot`` skips them, they read no expert's weights and a live
+    token's output does not depend on them. Rows past the groups are not
+    written (zero on the CPU, whatever the buffer held on the chip), so the
+    combine zeroes them. With ``live`` the group sizes come back as a third
+    value (the rows each expert computed).
+    ``layer``: the three expert matrices in ``params`` are stacked over
+    layers and this is the one to use (``grouped_gemm`` says why).
     """
     from ..moe.sharded_moe import topk_gating_grouped
     from ..ops.pallas.grouped_gemm import moe_expert_ffn
@@ -412,26 +428,39 @@ def apply_moe_grouped(params, x, cfg: TransformerConfig):
     tokens = x.reshape(b * s, e)
     t = tokens.shape[0]
 
-    logits = jnp.einsum("te,ex->tx", tokens.astype(jnp.float32),
-                        params["router"].astype(jnp.float32))
-    topk_idx, w, aux_loss = topk_gating_grouped(logits, k=k,
-                                                normalize=cfg.moe_norm_topk)
+    with jax.named_scope("moe_route"):
+        logits = jnp.einsum("te,ex->tx", tokens.astype(jnp.float32),
+                            params["router"].astype(jnp.float32))
+        topk_idx, w, aux_loss = topk_gating_grouped(
+            logits, k=k, normalize=cfg.moe_norm_topk)
 
-    expert_of_row = topk_idx.reshape(-1)                      # (T*k,)
-    order = jnp.argsort(expert_of_row, stable=True)
-    tok_of_sorted = order // k                                # token each row copies
-    sorted_tokens = jnp.take(tokens, tok_of_sorted, axis=0)   # (T*k, E)
-    group_sizes = jnp.bincount(expert_of_row, length=n_exp).astype(jnp.int32)
+    with jax.named_scope("moe_dispatch"):
+        expert_of_row = topk_idx.reshape(-1)                  # (T*k,)
+        if live is not None:
+            expert_of_row = jnp.where(jnp.repeat(live.reshape(-1), k),
+                                      expert_of_row, n_exp)
+        order = jnp.argsort(expert_of_row, stable=True)
+        tok_of_sorted = order // k                            # token each row copies
+        sorted_tokens = jnp.take(tokens, tok_of_sorted, axis=0)   # (T*k, E)
+        # bincount drops what lies past ``length``: the dead rows
+        group_sizes = jnp.bincount(expert_of_row,
+                                   length=n_exp).astype(jnp.int32)
 
-    rows = moe_expert_ffn(sorted_tokens.astype(dt),
-                          params["wi_gate"].astype(dt),
-                          params["wi_up"].astype(dt),
-                          params["wo"].astype(dt), group_sizes)
-    w_sorted = jnp.take(w.reshape(-1), order, axis=0).astype(dt)
-    out = jnp.zeros((t, e), dt).at[tok_of_sorted].add(rows * w_sorted[:, None])
+    with jax.named_scope("moe_experts"):
+        rows = moe_expert_ffn(sorted_tokens.astype(dt), params["wi_gate"],
+                              params["wi_up"], params["wo"], group_sizes,
+                              layer)
+    with jax.named_scope("moe_combine"):
+        if live is not None:
+            in_group = jnp.arange(t * k) < jnp.sum(group_sizes)
+            rows = jnp.where(in_group[:, None], rows, jnp.zeros((), dt))
+        w_sorted = jnp.take(w.reshape(-1), order, axis=0).astype(dt)
+        out = jnp.zeros((t, e), dt).at[tok_of_sorted].add(
+            rows * w_sorted[:, None])
     if cfg.moe_shared_expert_size:
         out = out + _apply_shared_expert(params, tokens.astype(dt), cfg)
-    return out.reshape(b, s, e), aux_loss
+    out = out.reshape(b, s, e)
+    return (out, aux_loss) if live is None else (out, aux_loss, group_sizes)
 
 
 def apply_moe_grouped_ep(params, x, cfg: TransformerConfig, mesh):
@@ -539,13 +568,20 @@ def apply_moe_grouped_ep(params, x, cfg: TransformerConfig, mesh):
 
 
 @jax.named_scope("moe_mlp")
-def apply_moe_mlp(params, x, cfg: TransformerConfig):
+def apply_moe_mlp(params, x, cfg: TransformerConfig, live=None, layer=None):
     """Dispatch/combine via one-hot einsum (GShard-style, reference
     ``deepspeed/moe/sharded_moe.py:96 MOELayer``). Capacity-bounded, dropless
     within capacity; aux load-balancing loss returned alongside.
 
     ``moe_impl: "grouped"`` routes to ``apply_moe_grouped`` (sort-by-expert
     + ragged_dot) when the expert mesh axis is unsharded.
+
+    ``live`` (B, S) bool is the serving runner's mask of positions that
+    hold a token; with it a third value comes back, the rows each expert
+    computed (X,). Only the dropless path keeps dead positions from the
+    experts (``apply_moe_grouped``); the capacity buffers below take every
+    position they are handed, and count them. ``layer`` is the dropless
+    path's (``apply_moe_grouped``).
     """
     from ..moe.sharded_moe import topk_gating_einsum
     dt = cfg.act_dtype
@@ -557,7 +593,8 @@ def apply_moe_mlp(params, x, cfg: TransformerConfig):
         ep = (_g.get_mesh().shape.get("expert", 1)
               if _g.mesh_is_initialized() else 1)
         if ep == 1:
-            return apply_moe_grouped(params, x, cfg)
+            return apply_moe_grouped(params, x, cfg, live, layer)
+        assert live is None, "serving shards no expert axis"
         if not _cma():
             # sharded expert axis: dropless grouped path with an explicit
             # all-to-all ring (cannot nest inside an existing manual region
@@ -575,6 +612,7 @@ def apply_moe_mlp(params, x, cfg: TransformerConfig):
                     and slen % sdiv == 0):
                 return apply_moe_grouped_ep(params, x, cfg, mesh)
 
+    assert layer is None, "only the dropless path reads a stack of layers"
     # Explicit dispatch/combine layouts (the reference's all-to-all
     # semantics, sharded_moe.py:533 _AllToAll): tokens ride the batch axes,
     # expert buffers ride the expert axis. Without these anchors XLA's
@@ -613,7 +651,10 @@ def apply_moe_mlp(params, x, cfg: TransformerConfig):
     out = constrain_tok(jnp.einsum("txc,xce->te", combine.astype(dt), expert_out))
     if cfg.moe_shared_expert_size:
         out = out + _apply_shared_expert(params, tokens, cfg)
-    return out.reshape(b, s, e), aux_loss
+    out = out.reshape(b, s, e)
+    if live is None:
+        return out, aux_loss
+    return out, aux_loss, jnp.sum(dispatch, axis=(0, 2), dtype=jnp.int32)
 
 
 # ---- embeddings ---------------------------------------------------------

@@ -158,6 +158,60 @@ def test_wide_frame_program_fits_the_chip(one_chip, as_tpu):
     assert total < 15.75e9, total
 
 
+@pytest.mark.parametrize("width", [1, 128], ids=["narrow", "wide"])
+def test_olmoe_frame_programs_fit_the_chip(one_chip, as_tpu, width):
+    """The benchmark's OLMoE-1B-7B configuration (published widths, 8 of 16
+    layers, bf16; 16 slots, 8 steps, 416 pages of 128, sequences to 4,096):
+    both frame programs compile with the chip's compiler from shapes alone,
+    keep the paged kernel and XLA's grouped-product kernel (three products
+    a routed layer, at every rung), hold NO buffer shaped like one layer's
+    stack of experts (the products read the stacked weights whole: a
+    layer's slice handed to a kernel is 805 MB copied a layer a step), and
+    their arguments and temporaries stay under the chip's 15.75 GB."""
+    import re
+    from deepspeed_tpu.inference.v2.model_runner import PagedModelRunner
+    from deepspeed_tpu.inference.v2.telemetry import pack_ladder
+    from deepspeed_tpu.models import build_model, get_config
+    slots, steps, pages, seq = 16, 8, 416, 4096
+    cfg = get_config("olmoe-1b-7b", num_layers=8)
+    assert cfg.dtype == "bfloat16"
+    model = build_model(cfg.replace(param_dtype=cfg.dtype))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                          model.abstract_params())
+    i32, flag = jnp.int32, jnp.bool_
+    row = sds((slots,), i32)
+    pool = sds((cfg.num_layers, cfg.kv_heads, pages, PAGE, cfg.dims_per_head),
+               jnp.bfloat16)
+    key = jax.random.PRNGKey(0)
+    runner = PagedModelRunner(model, PAGE, seq // PAGE)
+    compiled = runner._build_frame_loop().lower(
+        params, sds((slots, seq), i32), row, row, row,
+        sds((slots,), jnp.float32), sds((slots, seq // PAGE), i32), row, row,
+        row, sds((slots,), flag), sds((slots,), flag), sds((slots,), flag),
+        sds((runner.n_stats,), i32), sds(key.shape, key.dtype), pool, pool,
+        width=width, steps=steps, greedy=True).compile()
+    text = compiled.as_text()
+    rungs = len(pack_ladder(slots, width))
+    assert len(re.findall(r" conditional\(", text)) == (3 if rungs > 1 else 0)
+    assert len(re.findall(r"%paged_attn_c\d+\S* = ", text)) == 1
+    assert len(re.findall(r"%ragged-dot-none\S* = ", text)) == 3 * rungs
+    # one layer's experts: defined nowhere, in the loop, a conditional or
+    # the entry (the stack itself is a parameter, [8,64,...])
+    one_layer = re.findall(
+        r"= bf16\[(?:1,)?64,(?:2048,1024|1024,2048)\]\S* (\w[\w-]*)\(", text)
+    assert not one_layer, one_layer
+    m = compiled.memory_analysis()
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes
+    print(f"olmoe frame program, width {width}: args "
+          f"{m.argument_size_in_bytes / 1e9:.3f} GB + temp "
+          f"{m.temp_size_in_bytes / 1e9:.3f} GB")
+    assert total < 15.75e9, total
+
+
 def test_chip_smoke_fails_without_a_chip():
     """The suite runs on the CPU: ``chip_smoke.py`` must exit nonzero there
     and never print its success line (the children stop before any phase)."""

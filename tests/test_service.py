@@ -342,14 +342,23 @@ def test_edge_sheds_429_with_retry_after(tiny_model_params):
     edge = ServiceEdge(driver, EdgeConfig(
         max_queued_tokens=24, retry_after_min_s=1.0)).start()
     try:
-        # hold the fleet busy with slow work so pressure sustains
-        hold_done = threading.Event()
-        for i in range(6):
+        # hold the fleet busy with slow work so pressure sustains: 16
+        # requests of 128 tokens against 4 slots keep 3 or more queued (36
+        # tokens > 24; never more than the 16 a tenant may queue) until the
+        # third wave is admitted, 128 frames on. The estimate is blind to
+        # what the engine has taken from its feed until its next boundary
+        # report, and the first frame compiles: POST once a held request
+        # has streamed a token, when that report is in. (With 6 requests of
+        # 64 tokens the 2 left queued were exactly 24, and the POST raced
+        # the first report either way; it lost under load.)
+        streaming = threading.Event()
+        for i in range(16):
             driver.submit({"uid": 10_000 + i, "tokens": PROMPTS[i % 8],
-                           "max_new_tokens": 64},
+                           "max_new_tokens": 128},
                           subscriber=lambda ev: (
-                              hold_done.set()
-                              if ev["type"] == "done" else None))
+                              streaming.set()
+                              if ev["type"] == "tokens" else None))
+        assert streaming.wait(120)
         assert _wait(lambda: driver.queued_tokens_estimate() > 24)
         status, bodytext, headers = _sse_collect(
             "127.0.0.1", edge.edge_port,
@@ -427,9 +436,17 @@ def test_autoscale_flip_round_trip(tiny_model_params, tmp_path):
         e.attach_kv_tier(tier, tag=n)
         engines[n] = e
     router = EngineRouter(engines)
+    # Two races with the machine's speed, both lost under load. A fleet
+    # that goes idle inside the flip's dwell would park the flipped replica
+    # (scale-down sustains in 0.2 s), and a parked replica is never flipped
+    # back: both replicas stay live. And a flip is applied at the replica's
+    # next boundary: where that came later than the dwell, the controller
+    # asked for the same flip again, was refused, and on refusal forgot
+    # that it had flipped the replica at all (``_flipped.pop``): the dwell
+    # outlasts a slow boundary.
     ctl = AutoscaleController(AutoscaleConfig(
-        evaluate_every_s=0.1, sustain=2, min_live_replicas=1,
-        flip_prefill_high=64, flip_dwell_s=1.0,
+        evaluate_every_s=0.1, sustain=2, min_live_replicas=2,
+        flip_prefill_high=64, flip_dwell_s=5.0,
         scale_up_queued_tokens=10 ** 9))
     driver = FleetDriver(router, autoscaler=ctl)
     driver.start(max_new_tokens=4)
@@ -495,7 +512,10 @@ def test_autoscale_scale_down_and_up(tiny_model_params):
         assert _wait(lambda: len(done) == 1, timeout=120)
         assert _wait(lambda: router.counters["scale_down"] >= 1,
                      timeout=60), f"no scale_down: {ctl.events}"
-        assert "drained" in router.replica_status().values()
+        # the counter moves when the drain is asked for; the replica is
+        # ``draining`` until a later tick finds it empty
+        assert _wait(lambda: "drained" in router.replica_status().values(),
+                     timeout=60), router.replica_status()
         # burst: oversubscribe the surviving replica so queued tokens
         # sustain past the watermark
         n_done = []

@@ -471,6 +471,74 @@ def test_container_qwen2_moe_shared_expert():
     _parity(m, tol=1e-2)
 
 
+def _tiny_hf_olmoe(norm_topk_prob=False):
+    from transformers import OlmoeConfig, OlmoeForCausalLM
+    torch.manual_seed(0)
+    m = OlmoeForCausalLM(OlmoeConfig(
+        vocab_size=128, hidden_size=32, num_hidden_layers=2,
+        num_attention_heads=4, num_key_value_heads=4, intermediate_size=48,
+        num_experts=8, num_experts_per_tok=3, norm_topk_prob=norm_topk_prob,
+        max_position_embeddings=64))
+    with torch.no_grad():      # weights that matter: norms off 1, a router
+        for layer in m.model.layers:       # that prefers some experts
+            layer.self_attn.q_norm.weight.uniform_(0.5, 1.5)
+            layer.self_attn.k_norm.weight.uniform_(0.5, 1.5)
+            layer.mlp.gate.weight.mul_(20.0)
+            for expert in layer.mlp.experts:
+                expert.down_proj.weight.mul_(20.0)
+    return m
+
+
+@pytest.mark.parametrize("norm_topk_prob", [False, True])
+def test_container_olmoe_qk_norm_routed_experts(norm_topk_prob):
+    """OLMoE: the public checkpoint's names (``mlp.gate``,
+    ``mlp.experts.{x}.{gate,up,down}_proj``, ``self_attn.{q,k}_norm``) land
+    on the ``olmoe-1b-7b`` preset's pytree, and the native model (whole-
+    projection q/k RMSNorm, softmax then top-k, dropless) agrees with HF's
+    ``modeling_olmoe.py`` on a tiny random model's state dict."""
+    m = _tiny_hf_olmoe(norm_topk_prob)
+    model, params = build_native(m, dtype="float32")
+    cfg = model.cfg
+    assert (cfg.qk_norm, cfg.moe_impl, cfg.moe_norm_topk) == (
+        "full", "grouped", norm_topk_prob)
+    from deepspeed_tpu.models import build_model
+    preset = build_model("olmoe-1b-7b").abstract_params()
+    assert jax.tree.structure(params) == jax.tree.structure(preset)
+    assert params["layers"]["attn"]["q_norm"]["scale"].shape == (2, 32)
+    assert params["layers"]["mlp"]["wi_gate"].shape == (2, 8, 32, 48)
+    _parity(m)
+
+
+@pytest.mark.parametrize("norm_topk_prob", [False, True])
+def test_olmoe_reference_follows_the_published_model(norm_topk_prob):
+    """The benchmark's plain reference (``perfbench/configs/
+    olmoe_reference.py``, which shares no code with the program) against
+    transformers' own ``modeling_olmoe.py`` on the same tiny model, its
+    state dict brought over by the container: the equations the reference
+    was written from are the published ones, with and without the top-k
+    renormalization. Float32 on both sides: 1e-4 on logits up to ~1."""
+    import importlib.util
+    import os
+    spec = importlib.util.spec_from_file_location(
+        "olmoe_reference", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "perfbench", "configs", "olmoe_reference.py"))
+    reference = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reference)
+    m = _tiny_hf_olmoe(norm_topk_prob).eval()
+    ids = np.random.default_rng(0).integers(0, 128, (40,))
+    with torch.no_grad():
+        want = m(torch.tensor(ids[None])).logits[0].numpy()
+    _, params = build_native(m, dtype="float32")
+    config = {k: getattr(m.config, k) for k in (
+        "num_experts", "num_experts_per_tok", "norm_topk_prob", "rope_theta",
+        "rms_norm_eps")}
+    got = reference.logits_rows(jax.tree.map(jnp.asarray, params), ids,
+                                np.arange(len(ids)), config)
+    assert np.abs(want).max() > 0.3
+    np.testing.assert_allclose(got, want, atol=1e-4, rtol=0)
+
+
 def test_auto_container_refuses_non_llama_layout():
     """AutoContainer must refuse checkpoints whose layer layout carries
     tensors outside the Llama mapping (silently dropping them would corrupt
