@@ -6,15 +6,17 @@ inference_transformer_base.py`` + ``kernels/ragged_ops/linear_blocked_kv_rotary`
 chunks — prefill chunks (C>1) and decode steps (C=1) are the same program at
 different chunk widths, which is the Dynamic-SplitFuse unification.
 
-Per layer, inside a ``lax.scan`` over the stacked params zipped with the KV
-pools' layer slices ((KVH, NB, bs, D) — kv-head-major): project q/k/v, RoPE
-at absolute positions, scatter the chunk's KV into its pages, then attend.
-BOTH decode steps (C=1) and prefill chunks (C>1) run the unified Pallas
-paged kernel (``ops/pallas/paged_attention.py``), which reads pages IN
-PLACE via the block table and handles causal masks, sliding windows, ALiBi,
-and attention softcapping in-kernel; the XLA gather path remains as the
-non-TPU/escape-hatch fallback. Pools are donated, so XLA updates pages in
-place.
+Per layer, inside the layer walk (the full (L, KVH, NB, bs, D) kv-head-major
+pools stay loop-invariant, read through the layer's index): project q/k/v,
+RoPE at absolute positions, attend over the row's pages and the chunk's own
+KV; after the walk ONE commit writes every layer's chunk KV into its pages.
+On the chip BOTH decode steps (C=1) and prefill chunks (C>1) run the unified
+Pallas paged kernel (``ops/pallas/paged_attention.py``), which reads pages
+IN PLACE via the block table and handles causal masks, sliding windows,
+ALiBi, and attention softcapping in-kernel, and the commit is its twin
+(``ops/pallas/kv_commit.py``), which writes the touched pages IN PLACE in
+the layout the first reads; the XLA gather attention and scatter commit
+remain as the non-TPU / int8-pool / escape-hatch path. Pools are donated.
 """
 
 import functools
@@ -182,12 +184,9 @@ class PagedModelRunner:
         with jax.named_scope("embed"):
             h = _on_live(pack, embed, ids, positions)
         inv_freq = model._inv_freq
-        # positions < 0 mark padding: route their writes to trash block 0
+        # positions < 0 mark padding
         is_pad = positions < 0
         pos_safe = jnp.maximum(positions, 0)
-        blk = jnp.where(is_pad, 0, jnp.take_along_axis(
-            block_tables, pos_safe // bs, axis=1))          # (B, C)
-        off = pos_safe % bs
         # first chunk position per row: pool slots >= this are stale (the
         # chunk's KV flows beside the pool, committed after the layer walk)
         chunk_start = jnp.min(jnp.where(is_pad, 1 << 30, positions),
@@ -290,6 +289,18 @@ class PagedModelRunner:
             h = h + y + mlp_out if cfg.parallel_block else h + mlp_out
             return (h, work) if routed else h
 
+        # int8 pools carry packed scale-lane rows the Pallas kernels don't
+        # decode: quantized KV takes the gather attention, where the page
+        # rows are unpacked right after the gather, and the commit scatter.
+        # Float pools on the chip are read (``paged_ragged_attention``) and
+        # written (``kv_commit``) in place, in one layout
+        quantized_kv = kpool.dtype == jnp.int8
+        in_place = _use_pallas_paged() and not quantized_kv
+        if in_place:
+            from ...ops.pallas.kv_commit import kv_commit as commit
+        else:
+            commit = commit_scatter
+
         def layer(h, xs, tag=None):
             lp, l, win = xs
             if win is None:
@@ -302,18 +313,11 @@ class PagedModelRunner:
                                    pos_safe)
             # the pools are LOOP-INVARIANT inside the layer scan: this
             # layer's chunk KV rides into the attention as separate blocks
-            # and comes back out as scan ys; one token-sized scatter after
-            # the walk commits every layer at once. (Both alternatives
-            # measured pool-size-bound: scanning per-layer pool slices as
-            # xs/ys restacks the pools every step, and scattering into a
-            # carried full pool makes XLA copy it defensively around the
-            # kernel's read.)
-            # int8 pools carry packed scale-lane rows the Pallas kernel
-            # doesn't decode — quantized KV takes the gather path, where
-            # the page rows are unpacked right after the gather
-            quantized_kv = kpool.dtype == jnp.int8
+            # and comes back out as scan ys; one commit after the walk
+            # writes every layer's at once (``_run_layers``). Scanning
+            # per-layer pool slices as xs/ys restacks the pools every step.
             with jax.named_scope("paged_attn"):
-                if _use_pallas_paged() and not quantized_kv:
+                if in_place:
                     # decode AND chunked prefill read pages in place (no
                     # gather); causal masking, sliding windows (uniform or
                     # per-layer traced), ALiBi, and attention softcapping all
@@ -356,7 +360,7 @@ class PagedModelRunner:
                              live=~is_pad if routed else None)
                 h, *work = h if routed else (h,)
             # quantize-at-append: the chunk's KV leaves the layer already in
-            # pool representation, so the commit scatter in _run_layers is
+            # pool representation, so the commit in _run_layers is
             # dtype-blind and the pool never holds a float row
             with jax.named_scope("kv_commit"):
                 if quantized_kv:
@@ -366,20 +370,28 @@ class PagedModelRunner:
                            *work)
 
         h, kpool, vpool, work = self._run_layers(
-            layer, h, params, kpool, vpool, windows, blk, off,
+            layer, h, params, kpool, vpool, windows,
+            functools.partial(commit, block_tables=block_tables,
+                              positions=positions),
             stacked=pack is not None or self.experts_from_stack)
         with jax.named_scope("lm_head"):
             h = L.apply_norm(params["final_norm"], h, cfg)
             logits = self._head(params, h, valid_counts, all_logits, tp=tp)
         return (logits, kpool, vpool) + ((work,) if moe_work else ())
 
-    def _run_layers(self, layer, h, params, kpool, vpool, windows, blk, off,
+    def _run_layers(self, layer, h, params, kpool, vpool, windows, commit,
                     stacked=False):
         """Drive ``layer`` over the stack following the model's layer plan
         (heterogeneous stacks: Qwen2-MoE sparse steps, mlp_only prefixes).
         The full pools stay loop-invariant (read through a global layer
         index, never a materialized per-layer slice); each layer's chunk KV
-        returns as scan ys and is committed with ONE token-sized scatter.
+        returns as scan ys and ``commit(kpool, vpool, chunk_k, chunk_v)``
+        writes them all at once after the walk: for float pools on the chip
+        the ``kv_commit`` kernel, which writes the pages the live positions
+        land on in the layout the paged kernel reads; otherwise
+        ``commit_scatter``, for which XLA on the chip relays both whole
+        pools (two copies of a pool a step and a second pool of
+        temporaries; PERF.md, PR 27).
         A third ys, the layers' routed experts' work, comes back summed
         (None where the layers give none).
         Per-layer xs are (layer index, window), which the shared
@@ -412,11 +424,7 @@ class PagedModelRunner:
             model._plan, model._groups, walked,
             (layer_ids, windows), h, body)
         with jax.named_scope("kv_commit"):
-            # (L, B, C, KVH, D) chunk KV → pool[:, :, blk, off]: the advanced
-            # (B, C) indices are contiguous, so the indexed window is
-            # (L, KVH, B, C, D)
-            kpool = kpool.at[:, :, blk, off].set(ck_all.transpose(0, 3, 1, 2, 4))
-            vpool = vpool.at[:, :, blk, off].set(cv_all.transpose(0, 3, 1, 2, 4))
+            kpool, vpool = commit(kpool, vpool, ck_all, cv_all)
         return h, kpool, vpool, jnp.sum(work[0], axis=0) if work else None
 
     def _head(self, params, h, valid_counts, all_logits=False, tp=None):
@@ -832,6 +840,22 @@ class PagedModelRunner:
             f = self._fns.pop(name, None)
             if f is not None and hasattr(f, "_cache_size"):
                 self._evicted_programs += f._cache_size()
+
+
+def commit_scatter(kpool, vpool, chunk_k, chunk_v, block_tables, positions):
+    """The chunk's (L, B, C, KVH, D) KV into the (L, KVH, NB, bs, D) pools
+    at ``positions`` (B, C) through the rows' block tables, as one XLA
+    scatter a pool; a pad (``positions < 0``) goes to trash page 0."""
+    bs = kpool.shape[3]
+    is_pad = positions < 0
+    pos_safe = jnp.maximum(positions, 0)
+    blk = jnp.where(is_pad, 0, jnp.take_along_axis(
+        block_tables, pos_safe // bs, axis=1))              # (B, C)
+    off = pos_safe % bs
+    # the advanced (B, C) indices are contiguous, so the indexed window is
+    # (L, KVH, B, C, D)
+    return (kpool.at[:, :, blk, off].set(chunk_k.transpose(0, 3, 1, 2, 4)),
+            vpool.at[:, :, blk, off].set(chunk_v.transpose(0, 3, 1, 2, 4)))
 
 
 def _rung_of(positions, ladder):

@@ -97,8 +97,7 @@ def _paged_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,   # scalar prefetch
     # pool slots >= cs (the current chunk's first position) are stale: the
     # chunk's own KV arrives as separate blocks below, NOT via the pool —
     # keeping the pool read-only inside the layer scan is what lets XLA
-    # leave it in place (a scattered-then-read pool forces pool-sized
-    # defensive copies; measured pool-size-bound decode).
+    # leave it in place; ``kv_commit.py`` writes the chunk in afterwards.
     # One grid step covers K pages fused into ONE (R, K*bs) score matmul —
     # per-step overhead (DMA latency, semaphores) amortizes over K pages and
     # the MXU tile is K× wider.
@@ -148,8 +147,8 @@ def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
     D) carry the chunk's own KV, processed as a final virtual page with
     per-key positions = ``positions`` (pool slots >= the chunk's first
     position are treated as stale and masked). This keeps the pool
-    loop-invariant across the layer scan — the caller scatters all layers'
-    chunk KV in one token-sized update afterwards. With ``chunk_k=None``
+    loop-invariant across the layer scan — the caller commits all layers'
+    chunk KV at once afterwards (``kv_commit.py``). With ``chunk_k=None``
     the pool is taken as ALREADY containing every slot up to each query's
     position (the pre-round-4 contract, kept for the v1 fused-decode path).
 

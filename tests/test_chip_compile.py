@@ -78,6 +78,28 @@ def test_paged_attention_compiles_at_serve_widths(one_chip, as_tpu, chunk):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("chunk", [1, 3, 128],
+                         ids=["decode", "spec-verify", "prefill"])
+def test_kv_commit_compiles_at_serve_widths(one_chip, as_tpu, chunk):
+    """The page commit at the serve phase's widths: a Mosaic call whose
+    pools are its results (donated: no copy of one beside it)."""
+    from deepspeed_tpu.ops.pallas.kv_commit import kv_commit
+    b, layers, pages, table = 8, 4, 64, 8
+    dt = jnp.bfloat16
+
+    def sds(shape, dtype=dt):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((layers, KVH, pages, PAGE, D))
+    compiled = jax.jit(kv_commit, donate_argnums=(0, 1)).lower(
+        pool, pool, sds((layers, b, chunk, KVH, D)),
+        sds((layers, b, chunk, KVH, D)), sds((b, table), jnp.int32),
+        sds((b, chunk), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    pool_bytes = layers * KVH * pages * PAGE * D * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 2
+
+
 @pytest.mark.parametrize("b,s,h,kvh,d,window", [
     (8, 1024, 16, 16, 64, None),          # train phase: gpt2-medium, micro 8
     (1, 1024, H, KVH, D, None),           # serve model's widths
@@ -110,19 +132,40 @@ def test_fused_adam_compiles(one_chip, as_tpu):
     assert "tpu_custom_call" in text
 
 
-def test_wide_frame_program_fits_the_chip(one_chip, as_tpu):
-    """The benchmark's wide frame program (mistral-7b widths, 16 layers,
-    bf16; 16 slots x 128 positions, 8 steps, 416 pages of 128, sequences to
-    8,192) compiles with the chip's compiler from shapes alone, keeps its
-    paged kernel, holds one conditional per packed stage (embedding, q/k/v,
-    output projection + MLP) and no copy of a whole weight stack inside the
-    layer loop, and its arguments and temporaries stay under the chip's
-    15.75 GB (PERF.md section 4 records the sizes)."""
+def _assert_commits_in_place(compiled, text, pool, scatter_temp_gb):
+    """A frame program writes the step's KV into the pools in place: the
+    commit kernel once, no pool-shaped value that XLA made (a relaid or
+    defensive copy, a scatter), and temporaries at least 3 GB under what
+    the program held while the commit was a scatter (``scatter_temp_gb``,
+    PERF.md section 4: the second copy of both pools)."""
+    import re
+    assert len(re.findall(r"%kv_commit_c\d+\S* = ", text)) == 1
+    shape = ",".join(map(str, pool.shape))
+    made = re.findall(
+        rf"= bf16\[{shape}\]\S* (copy|copy-start|fusion|scatter|transpose|"
+        rf"dynamic-update-slice)\(", text)
+    assert not made, made
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (scatter_temp_gb - 3.0) * 1e9, temp
+
+
+@pytest.mark.parametrize("chunk,scatter_temp_gb", [(1, 4.165), (128, 4.735)],
+                         ids=["narrow", "wide"])
+def test_mistral_frame_programs_fit_the_chip(one_chip, as_tpu, chunk,
+                                             scatter_temp_gb):
+    """The benchmark's frame programs (mistral-7b widths, 16 layers, bf16;
+    16 slots x 1 or 128 positions, 8 steps, 416 pages of 128, sequences to
+    8,192) compile with the chip's compiler from shapes alone, keep their
+    paged kernel, commit in place, hold (the wide one) one conditional per
+    packed stage (embedding, q/k/v, output projection + MLP) and no copy of
+    a whole weight stack inside the layer loop, and their arguments and
+    temporaries stay under the chip's 15.75 GB (PERF.md section 4 records
+    the sizes)."""
     import re
     from deepspeed_tpu.inference.v2.model_runner import PagedModelRunner
     from deepspeed_tpu.inference.v2.telemetry import N_STATS
     from deepspeed_tpu.models import build_model, get_config
-    slots, chunk, steps, pages, seq = 16, 128, 8, 416, 8192
+    slots, steps, pages, seq = 16, 8, 416, 8192
     cfg = get_config("mistral-7b", num_layers=16, dtype="bfloat16")
     model = build_model(cfg.replace(param_dtype=cfg.dtype))
 
@@ -143,8 +186,9 @@ def test_wide_frame_program_fits_the_chip(one_chip, as_tpu):
         sds((N_STATS,), i32), sds(key.shape, key.dtype), pool, pool,
         width=chunk, steps=steps, greedy=True).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text
-    assert len(re.findall(r" conditional\(", text)) == 3
+    assert len(re.findall(r"%paged_attn_c\d+\S* = ", text)) == 1
+    _assert_commits_in_place(compiled, text, pool, scatter_temp_gb)
+    assert len(re.findall(r" conditional\(", text)) == (3 if chunk > 1 else 0)
     # a layout copy of a stacked weight belongs to the entry computation
     # (once a frame, as before), never to the body of the layer loop
     stacked = re.findall(
@@ -153,17 +197,21 @@ def test_wide_frame_program_fits_the_chip(one_chip, as_tpu):
     assert all(src.startswith("%params") for src in stacked), stacked
     m = compiled.memory_analysis()
     total = m.argument_size_in_bytes + m.temp_size_in_bytes
-    print(f"wide frame program: args {m.argument_size_in_bytes / 1e9:.3f} GB"
-          f" + temp {m.temp_size_in_bytes / 1e9:.3f} GB")
+    print(f"mistral frame program, width {chunk}: args "
+          f"{m.argument_size_in_bytes / 1e9:.3f} GB + temp "
+          f"{m.temp_size_in_bytes / 1e9:.3f} GB")
     assert total < 15.75e9, total
 
 
-@pytest.mark.parametrize("width", [1, 128], ids=["narrow", "wide"])
-def test_olmoe_frame_programs_fit_the_chip(one_chip, as_tpu, width):
+@pytest.mark.parametrize("width,scatter_temp_gb", [(1, 3.633), (128, 4.209)],
+                         ids=["narrow", "wide"])
+def test_olmoe_frame_programs_fit_the_chip(one_chip, as_tpu, width,
+                                           scatter_temp_gb):
     """The benchmark's OLMoE-1B-7B configuration (published widths, 8 of 16
     layers, bf16; 16 slots, 8 steps, 416 pages of 128, sequences to 4,096):
     both frame programs compile with the chip's compiler from shapes alone,
-    keep the paged kernel and XLA's grouped-product kernel (three products
+    keep the paged kernel, commit in place (MHA: 16 KV heads a block), keep
+    XLA's grouped-product kernel (three products
     a routed layer, at every rung), hold NO buffer shaped like one layer's
     stack of experts (the products read the stacked weights whole: a
     layer's slice handed to a kernel is 805 MB copied a layer a step), and
@@ -198,6 +246,7 @@ def test_olmoe_frame_programs_fit_the_chip(one_chip, as_tpu, width):
     rungs = len(pack_ladder(slots, width))
     assert len(re.findall(r" conditional\(", text)) == (3 if rungs > 1 else 0)
     assert len(re.findall(r"%paged_attn_c\d+\S* = ", text)) == 1
+    _assert_commits_in_place(compiled, text, pool, scatter_temp_gb)
     assert len(re.findall(r"%ragged-dot-none\S* = ", text)) == 3 * rungs
     # one layer's experts: defined nowhere, in the loop, a conditional or
     # the entry (the stack itself is a parameter, [8,64,...])

@@ -64,8 +64,9 @@ def _scope_components(lowered):
 @pytest.mark.parametrize("width", [8, 1])
 def test_frame_loop_lowering_names_scopes_and_kernel(width, monkeypatch):
     """The tiny frame program (the registry's, w=8 and w=1) names every
-    layer scope, and its paged-attention kernel by chunk width: the decode
-    kernel is ``paged_attn_c1`` whatever XLA numbers it."""
+    layer scope, and its two paged kernels by chunk width: the decode
+    kernels are ``paged_attn_c1`` and ``kv_commit_c1`` whatever XLA numbers
+    them."""
     from deepspeed_tpu.analysis import programs as P
     monkeypatch.setattr(model_runner, "_use_pallas_paged", lambda: True)
     eng = P._tiny_engine()
@@ -74,7 +75,7 @@ def test_frame_loop_lowering_names_scopes_and_kernel(width, monkeypatch):
         *args, width=width, steps=2, greedy=True)
     parts = _scope_components(lowered)
     assert set(SERVE_SCOPES) <= parts, set(SERVE_SCOPES) - parts
-    assert f"paged_attn_c{width}" in parts
+    assert {f"paged_attn_c{width}", f"kv_commit_c{width}"} <= parts
 
 
 def test_train_step_lowering_names_scopes_and_flash_kernels():
