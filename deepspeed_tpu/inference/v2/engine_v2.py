@@ -34,6 +34,7 @@ from .faults import (FaultReason, FrameDispatchError, LedgerEntry,
 from .kv_cache import BlockedKVCache
 from .model_runner import PagedModelRunner
 from .ragged_manager import DeviceSlotTable, DSStateManager
+from .scheduler import FifoPolicy
 from .telemetry import ServingTelemetry, check_stat_range
 
 
@@ -914,8 +915,8 @@ class InferenceEngineV2:
         ``generated`` the tokens another engine already committed, and
         ``max_new_tokens`` the ORIGINAL budget. Ingestion folds
         prompt+generated for re-prefill (the crash-resume machinery), the
-        ledger keeps the original prompt/limit, and on the scheduler path
-        the submit bypasses the tenant queue quota — the request was
+        ledger keeps the original prompt/limit, and the submit bypasses a
+        ``RequestScheduler``'s tenant queue quota — the request was
         already accepted once. An empty list is still a resume (a queued,
         never-admitted request migrating off a drained replica).
 
@@ -933,9 +934,9 @@ class InferenceEngineV2:
         target that tightens the scheduler's pressure loop), ``deadline_ms``
         (wall-clock budget from ENQUEUE: past it, the request is cancelled
         at the next frame boundary — queued or live — its KV blocks freed
-        and a ``deadline_expired`` FaultReason recorded; works on BOTH the
-        FIFO and scheduler paths). tenant/priority/slo_ms are inert
-        without a ``scheduler=``."""
+        and a ``deadline_expired`` FaultReason recorded, whatever the
+        admission policy). tenant/priority/slo_ms are inert without a
+        ``scheduler=``."""
         if isinstance(item, dict):
             uid, toks = item["uid"], item["tokens"]
             limit = item.get("max_new_tokens")
@@ -1019,15 +1020,19 @@ class InferenceEngineV2:
         EWMA arrival-rate estimate; an explicit ``frame_steps=`` argument
         pins it.
 
-        ``scheduler`` (a ``scheduler.RequestScheduler``) replaces the FIFO
-        admission deque with the SLO-aware policy object: priority classes
-        with aging, per-tenant weighted fair-share and quotas, TTFT-SLO
-        load shedding/deferral, and frame-boundary preemption (see
-        ``scheduler.py`` and README "Scheduling & SLOs"). Arrivals may then
-        be dicts carrying ``tenant``/``priority``/``slo_ms``. All policy
-        runs host-side at frame boundaries — zero new in-frame transfers —
-        and with ``scheduler=None`` this method keeps the original FIFO
-        code path byte-for-byte.
+        ``scheduler`` is the admission policy: how waiting requests queue
+        and who is admitted next. There is one serve loop and it knows
+        the policy only through the methods ``scheduler.py`` lists; None
+        (the default) is ``scheduler.FifoPolicy``, arrival order. A
+        ``scheduler.RequestScheduler`` is the SLO-aware policy object:
+        priority classes with aging, per-tenant weighted fair-share and
+        quotas, TTFT-SLO load shedding/deferral, and frame-boundary
+        preemption (see ``scheduler.py`` and README "Scheduling & SLOs").
+        Arrivals may then be dicts carrying ``tenant``/``priority``/
+        ``slo_ms``. All policy runs host-side at frame boundaries — zero
+        new in-frame transfers — and a default ``RequestScheduler()``
+        gives FIFO's outputs in FIFO's order
+        (``test_no_scheduler_path_is_fifo_identical``).
 
         Fault tolerance (``faults.py``, README "Fault tolerance & chaos
         testing"): frame dispatch runs under bounded retry with exponential
@@ -1118,64 +1123,33 @@ class InferenceEngineV2:
                                    kv_blocks_total=self.kv.num_blocks,
                                    tp_degree=self._config.tp,
                                    kv_block_bytes=self.kv.block_bytes)
-        if scheduler is not None:
-            scheduler.begin_serve(self)
-            return self._serve_guarded_sched(
-                slots, arrivals, scheduler, steps, max_new_tokens,
-                temperature, eos_token_id, speculate, gamma, adaptive,
-                faults, resume, yield_boundaries)
-        return self._serve_guarded(slots, arrivals, steps, max_new_tokens,
-                                   temperature, eos_token_id, speculate,
-                                   gamma, adaptive, faults, resume,
+        sched = FifoPolicy() if scheduler is None else scheduler
+        sched.begin_serve(self)
+        return self._serve_guarded(slots, arrivals, sched, steps,
+                                   max_new_tokens, temperature, eos_token_id,
+                                   speculate, gamma, adaptive, faults, resume,
                                    yield_boundaries)
 
-    def _serve_guarded(self, slots, arrivals, steps, max_new_tokens,
+    def _serve_guarded(self, slots, arrivals, sched, steps, max_new_tokens,
                        temperature, eos_token_id, speculate, gamma, adaptive,
                        faults, resume, boundaries=False):
-        pending = collections.deque()
         try:
-            yield from self._serve_loop(slots, arrivals, pending, steps,
-                                        max_new_tokens, temperature,
-                                        eos_token_id, speculate=speculate,
-                                        gamma=gamma, adaptive=adaptive,
-                                        faults=faults, resume=resume,
-                                        boundaries=boundaries)
-        finally:
-            # generator abandonment (break / close() / mid-stream error)
-            # must not strand in-flight state: release every slot-held
-            # sequence and every deferred arrival that already has a
-            # descriptor, or their KV blocks leak and a later call reusing
-            # a uid would inherit stale generated tokens. The ledger is
-            # the authoritative accepted-not-retired set — it also covers
-            # rows caught mid-transit by a fault between eviction and
-            # re-admission, which neither the slot table nor the pending
-            # deque sees.
-            for uid in list(slots.slot_of_uid):
-                self.state.flush_sequence(uid)
-            for item in pending:
-                self.state.flush_sequence(item[0])
-            for uid in list(self._ledger):
-                self.state.flush_sequence(uid)
-            self._ledger.clear()
-
-    def _serve_guarded_sched(self, slots, arrivals, sched, steps,
-                             max_new_tokens, temperature, eos_token_id,
-                             speculate, gamma, adaptive, faults, resume,
-                             boundaries=False):
-        try:
-            yield from self._serve_loop_sched(
+            yield from self._serve_loop(
                 slots, arrivals, sched, steps, max_new_tokens, temperature,
                 eos_token_id, speculate=speculate, gamma=gamma,
                 adaptive=adaptive, faults=faults, resume=resume,
                 boundaries=boundaries)
         finally:
-            # same abandonment contract as the FIFO path: slot-held AND
-            # scheduler-queued sequences (including preempted ones holding
-            # their emitted tokens) must release their descriptors/blocks;
-            # the ledger sweep additionally covers a preempted row dropped
-            # between eviction and re-admission (evicted from the slot
-            # table but not yet back in a scheduler queue), whose folded
-            # tokens and descriptor would otherwise leak
+            # generator abandonment (break / close() / mid-stream error)
+            # must not strand in-flight state: release every slot-held
+            # sequence and every queued one that already has a descriptor
+            # (deferred arrivals, preempted rows holding their emitted
+            # tokens), or their KV blocks leak and a later call reusing a
+            # uid would inherit stale generated tokens. The ledger is the
+            # authoritative accepted-not-retired set — it also covers rows
+            # caught mid-transit by a fault between eviction and
+            # re-admission, which neither the slot table nor the policy's
+            # queue sees.
             for uid in list(slots.slot_of_uid):
                 self.state.flush_sequence(uid)
             for uid in sched.queued_uids():
@@ -1200,8 +1174,8 @@ class InferenceEngineV2:
         return min(BlockedKVCache.floor_pow2(target), max_steps)
 
     def _validate_arrival(self, uid, toks, limit, in_flight: bool) -> int:
-        """Shared serve() enqueue-time validation (FIFO and scheduler
-        paths); returns the (possibly clamped) generation budget."""
+        """serve()'s enqueue-time validation; returns the (possibly
+        clamped) generation budget."""
         if uid < 0:
             raise ValueError(
                 f"uid={uid}: serve() uids must be >= 0 (-1 is "
@@ -1233,7 +1207,7 @@ class InferenceEngineV2:
 
     def _sync_frame_stats(self, slots, width, cur_steps, ewma, queue_depth,
                           stats_synced):
-        """Frame-boundary counter absorption, shared by both serve loops.
+        """Frame-boundary counter absorption.
 
         The in-graph counters replay the old host arithmetic exactly
         (verify forwards = emit column 0; accepted drafts = the rest;
@@ -1305,28 +1279,54 @@ class InferenceEngineV2:
         if ent is not None and trace is not None:
             ent.trace = trace
 
-    def _ingest_resume(self, uid, toks, limit, gen, tel):
-        """Shared core of mid-run RESUME-arrival ingestion (router
-        failover / drain migration), used by BOTH serve loops — the
-        FIFO/scheduler difference is only where the folded request is
-        enqueued. Rebuilds the host sequence with the committed tokens and
-        either retires immediately (already over budget: returns
-        ``(None, output)`` — the ledger entry added just before is popped
-        and the retirement recorded) or returns
-        ``((folded_prompt, remaining_budget), None)`` for re-prefill."""
+    def _enqueue(self, sched, uid, toks, limit, temp, eos, dl_ms, gen,
+                 tenant, prio, slo_ms, trace):
+        """Accept one request: ledger, lifecycle span, the policy's queue.
+        Every way in comes through here: a fresh arrival (``gen`` None),
+        and a RESUME (a mid-run arrival from router failover / drain
+        migration / prefill→decode handoff, or a ``resume_from`` snapshot
+        entry), ``gen`` the tokens another run already committed.
+
+        A resume rebuilds the host sequence and queues prompt + committed
+        for re-prefill (the preemption fold), so greedy outputs are
+        token-identical across the restart; the ledger keeps the original
+        prompt and budget. Its submit bypasses the tenant queue quota, its
+        only difference from a fresh one: the request was already ACCEPTED,
+        and ``tenant_max_queued`` must not drop its committed tokens (the
+        quota is submit()'s only shed, so a bypassed submit never sheds).
+        Returns the output of a resume that had already spent its budget
+        (retired here, for the caller to yield), else None."""
+        tel = self.telemetry
+        req = sched.new_request(uid, toks, limit, temp, eos, tenant, prio,
+                                slo_ms)
+        n_gen = len(gen or ())
+        self._ledger_add(uid, toks, limit, temp, eos, dl_ms,
+                         tenant=req.tenant, priority=req.pclass,
+                         slo_ms=req.slo_ms, resumed_from=n_gen, trace=trace)
+        self._enqueue_traced(uid, tenant=req.tenant, pclass=req.pclass,
+                             resumed=n_gen > 0, trace=trace)
+        if gen is None:
+            shed = sched.submit(req)
+            if shed is not None:
+                tel.on_shed(uid, shed.tenant, shed.priority, shed.reason)
+                self._ledger.pop(uid, None)
+            return None
         seq = self.state.get_or_create_sequence(uid)
         seq.generated = list(gen)
         seq.done = False
-        remaining = limit - len(gen)
-        if remaining <= 0:
+        if limit <= n_gen:
+            # finished before the other run could yield it
             out = np.asarray(seq.generated, np.int64)
             self.state.flush_sequence(uid)
             self._ledger.pop(uid, None)
             tel.on_retire(uid)
-            return None, out
-        folded = np.concatenate([toks, np.asarray(gen, np.int32)]) \
-            if gen else toks
-        return (folded, remaining), None
+            return out
+        if gen:
+            req.tokens = np.concatenate([toks, np.asarray(gen, np.int32)])
+        req.limit = limit - n_gen
+        req.resumed_from, req.resumed = n_gen, True
+        sched.submit(req, bypass_quota=True)
+        return None
 
     def _resume_entries(self, resume_from) -> List[Tuple]:
         """Normalize a ``snapshot_serving_state()`` dict into resume
@@ -1396,13 +1396,12 @@ class InferenceEngineV2:
         self.telemetry.on_fault(kind)
         logger.warning(f"serve(): {kind} at frame {frame}: {detail}")
 
-    def _expire_deadlines(self, slots, frame: int, pending=None,
-                          sched=None) -> None:
+    def _expire_deadlines(self, slots, frame: int, sched) -> None:
         """Frame-boundary deadline enforcement for queued AND live rows:
         an expired request is cancelled wherever it sits — popped from the
-        FIFO deque / scheduler queue (BEFORE it can be admitted, aged, or
-        preempted for), or evicted from its live slot — its KV blocks are
-        freed and a ``deadline_expired`` timeout retirement is recorded."""
+        policy's queue (BEFORE it can be admitted, aged, or preempted
+        for), or evicted from its live slot — its KV blocks are freed and
+        a ``deadline_expired`` timeout retirement is recorded."""
         now = self._clock()
         expired = [uid for uid, ent in self._ledger.items()
                    if ent.deadline_at is not None and now >= ent.deadline_at]
@@ -1411,17 +1410,10 @@ class InferenceEngineV2:
             partial = list(seq.generated) if seq is not None else []
             if uid in slots.slot_of_uid:
                 slots.evict(uid)
-                if sched is not None:
-                    sched.on_retire(uid)
+                sched.on_retire(uid)
                 where = f"live row ({len(partial)} tokens committed)"
             else:
-                if sched is not None:
-                    sched.cancel(uid)
-                elif pending is not None:
-                    for item in pending:
-                        if item[0] == uid:
-                            pending.remove(item)
-                            break
+                sched.cancel(uid)
                 where = "queued (never admitted)"
             self.state.flush_sequence(uid)       # frees any KV blocks
             ent = self._ledger.get(uid)
@@ -1435,7 +1427,7 @@ class InferenceEngineV2:
                                           f"{where}",
                                    partial=partial)
 
-    def _quarantine_rows(self, uids, slots, frame: int, sched=None,
+    def _quarantine_rows(self, uids, slots, frame: int, sched,
                          escalated: bool = False) -> None:
         """Poison-row quarantine: latched rows are evicted (the preemption
         path: freeze + free slot + free KV blocks) and retired with a
@@ -1450,8 +1442,7 @@ class InferenceEngineV2:
             seq = self.state.seqs.get(uid)
             partial = list(seq.generated) if seq is not None else []
             slots.evict(uid)
-            if sched is not None:
-                sched.on_retire(uid)
+            sched.on_retire(uid)
             if self.prefix_cache is not None:
                 # pages published by a row whose logits went non-finite
                 # may themselves hold non-finite KV — never hand them to
@@ -1462,7 +1453,7 @@ class InferenceEngineV2:
             self._fault_retire(uid, "poison_row", frame, detail=detail,
                                partial=partial)
 
-    def _handle_nonfinite(self, slots, frame: int, sched=None) -> List[int]:
+    def _handle_nonfinite(self, slots, frame: int, sched) -> List[int]:
         """Frame-boundary dispatch for latched finite-check rows. Under the
         default ``quarantine`` policy every latched row is evicted/retired.
         Under ``repair`` the compiled frame already rolled each latched row
@@ -1480,7 +1471,7 @@ class InferenceEngineV2:
         flagged = slots.nonfinite_uids()
         if not self._nonfinite_repair:
             if flagged:
-                self._quarantine_rows(flagged, slots, frame, sched=sched)
+                self._quarantine_rows(flagged, slots, frame, sched)
             return []
         # a clean boundary resets a row's consecutive-blip count
         for uid in [u for u in self._repair_counts if u not in flagged]:
@@ -1494,7 +1485,7 @@ class InferenceEngineV2:
                 self._repair_counts[uid] = n
                 repaired.append(uid)
         if doomed:
-            self._quarantine_rows(doomed, slots, frame, sched=sched,
+            self._quarantine_rows(doomed, slots, frame, sched,
                                   escalated=True)
         if repaired:
             slots.clear_nonfinite(repaired)
@@ -2033,7 +2024,7 @@ class InferenceEngineV2:
         return item
 
     def _collect_handoffs(self, slots, boundary: int, chunk: int,
-                          sched=None) -> List[HandoffEvent]:
+                          sched) -> List[HandoffEvent]:
         """Prefill-role frame boundary: every live row whose committed
         watermark covers its prompt is DONE here — publish its remaining
         pages (final segment, with the handoff metadata) plus a
@@ -2114,8 +2105,7 @@ class InferenceEngineV2:
             item = self._handoff_arrival(uid, ent, seq)
             pipelined = seq.tier_final
             slots.evict(uid)
-            if sched is not None:
-                sched.on_retire(uid)
+            sched.on_retire(uid)
             self.state.flush_sequence(uid)
             self._ledger.pop(uid, None)
             self.telemetry.on_handoff_out(uid, pipelined=pipelined)
@@ -2126,246 +2116,8 @@ class InferenceEngineV2:
                                     published=published))
         return out
 
-    def _serve_loop(self, slots, arrivals, pending, steps, max_new_tokens,
-                    temperature, eos_token_id, speculate=False, gamma=0,
-                    adaptive=False, faults=None, resume=(),
-                    boundaries=False):
-        c = self._config
-        tel = self.telemetry
-        alpha = c.frame_steps_ewma_alpha
-        ewma = 0.0
-        exhausted = False
-        stats_synced = True     # device stat vector starts at zero
-        boundary = -1           # frame-boundary index (fault schedules key
-        #                         on it; == dispatched-frame index while
-        #                         rows are live)
-        resume_t0 = self._clock()
-        n_resumed = len(resume)
-        # ---- crash-recovery ingestion: re-admit the snapshot's requests
-        # ahead of any new arrival, re-prefilling prompt + committed tokens
-        # (the preemption fold) so greedy outputs are token-identical
-        # across the restart ----
-        for (uid, prompt, limit, temp, eos, dl_ms, generated, _ten, _pri,
-             _slo, trace) in resume:
-            seq = self.state.get_or_create_sequence(uid)
-            seq.generated = list(generated)
-            seq.done = False
-            self._ledger_add(uid, prompt, limit, temp, eos, dl_ms,
-                             resumed_from=len(generated), trace=trace)
-            self._enqueue_traced(uid, resumed=len(generated) > 0, trace=trace)
-            remaining = limit - len(generated)
-            if remaining <= 0:
-                # finished before the crashed run could yield it
-                out = np.asarray(seq.generated, np.int64)
-                self.state.flush_sequence(uid)
-                self._ledger.pop(uid, None)
-                tel.on_retire(uid)
-                with tel.phase("yield"):
-                    yield uid, out
-                continue
-            folded = np.concatenate(
-                [np.asarray(prompt, np.int32),
-                 np.asarray(generated, np.int32)]) if generated else prompt
-            pending.append((uid, folded, remaining, temp, eos))
-        while True:
-            boundary += 1
-            # a poll on an empty server is the wait for the next arrival,
-            # not work between two frames: it is the phase ``idle``
-            with tel.phase("poll" if slots.live_count() or pending
-                           else "idle"):
-                # commit the async swap-out writes queued at the previous
-                # boundary (they overlapped with the frame in between)
-                self._drain_swap_boundary(boundary)
-                if exhausted:
-                    batch = None
-                    ewma = (1.0 - alpha) * ewma
-                else:
-                    try:
-                        batch = next(arrivals)
-                    except StopIteration:
-                        exhausted = True
-                        batch = None
-                    ewma = alpha * len(batch or []) + (1.0 - alpha) * ewma
-                    # validate at ENQUEUE — before any KV reservation is made
-                    # for this round, so a bad request can't strand blocks
-                    # already reserved for earlier items in the same batch
-                    for item in (batch or []):
-                        (uid, toks, limit, temp, eos, _ten, _pri, _slo, dl_ms,
-                         gen, trace) = self._norm_arrival(
-                             item, max_new_tokens, temperature, eos_token_id)
-                        want = limit
-                        limit = self._validate_arrival(
-                            uid, toks, limit,
-                            in_flight=uid in slots.slot_of_uid or
-                            any(p[0] == uid for p in pending))
-                        if gen is not None and limit < want:
-                            self._note_resume_truncated(uid, want, limit,
-                                                        boundary)
-                        if gen is not None:
-                            # mid-run RESUME arrival (router failover /
-                            # drain migration / prefill→decode handoff): the
-                            # crash-recovery ingestion, fed through the
-                            # arrival stream; ledger keeps the originals
-                            self._ledger_add(uid, toks, limit, temp, eos,
-                                             dl_ms, resumed_from=len(gen),
-                                             trace=trace)
-                            self._enqueue_traced(uid, resumed=len(gen) > 0,
-                                                trace=trace)
-                            fold, done_out = self._ingest_resume(
-                                uid, toks, limit, gen, tel)
-                            if done_out is not None:
-                                with tel.phase("yield"):
-                                    yield uid, done_out
-                                continue
-                            folded, remaining = fold
-                            pending.append((uid, folded, remaining, temp, eos))
-                            continue
-                        pending.append((uid, toks, limit, temp, eos))
-                        self._ledger_add(uid, toks, limit, temp, eos, dl_ms,
-                                         trace=trace)
-                        self._enqueue_traced(uid, trace=trace)
-            with tel.phase("admit"):
-                # ---- deadlines: expired work (queued or live) is cancelled
-                # BEFORE admission can spend a slot or blocks on it ----
-                self._expire_deadlines(slots, boundary, pending=pending)
-                # ---- admission control (FIFO; blocks reserved for the whole
-                # prompt + generation budget up front, so block tables never
-                # grow mid-flight) ----
-                alloc_blocked = faults is not None \
-                    and faults.kv_alloc_blocked(boundary)
-                if alloc_blocked and pending:
-                    self._fault_event(
-                        "kv_alloc_failed", boundary,
-                        "injected KV-block allocation failure; admission "
-                        "deferred this boundary")
-                admits = []
-                blocks_before = self.kv.free_blocks
-                while pending and not alloc_blocked and not self._draining \
-                        and len(admits) < slots.free_slots():
-                    uid, toks, limit, temp, eos = pending[0]
-                    seq = self.state.get_or_create_sequence(uid)
-                    cached0 = self._admit_capacity(uid, seq, toks, limit,
-                                                   boundary)
-                    if cached0 is None:
-                        if slots.live_count() == 0 and not admits:
-                            raise RuntimeError(
-                                f"uid={uid}: prompt + budget can never fit the "
-                                f"KV pool ({self.kv.free_blocks} blocks free "
-                                "with no live sequences)")
-                        break        # wait for retirements to free blocks
-                    pending.popleft()
-                    seq.done = False
-                    admits.append((uid, seq, toks, limit, temp, eos, cached0))
-                    tel.on_admit(uid)
-                if pending and not self._draining:
-                    # overload is otherwise invisible: the deferred arrivals
-                    # just wait in FIFO order — count it and warn (rate-limited).
-                    # admit() hasn't executed yet, so subtract this round's
-                    # admits or a full table would be misreported as KV
-                    # pressure; likewise free_blocks already reflects this
-                    # round's reservations, so thread the reserved count through
-                    # to keep standing pressure distinguishable from a busy
-                    # admission round
-                    tel.on_defer(
-                        queue_depth=len(pending),
-                        frame_steps=tel.serve_view["frame_steps_last"] or steps,
-                        free_slots=slots.free_slots() - len(admits),
-                        free_blocks=self.kv.free_blocks,
-                        reserved_blocks=blocks_before - self.kv.free_blocks)
-                if admits:
-                    slots.ensure_widths(
-                        max(len(a[2]) for a in admits),
-                        max(len(a[1].blocks) for a in admits),
-                        self.max_seq_len, self.max_blocks_per_seq)
-                    slots.admit(admits)
-                self._note_recovery_progress(slots, resume_t0, n_resumed)
-            if slots.live_count() == 0:
-                if exhausted and not pending:
-                    return
-                if boundaries:
-                    with tel.phase("yield"):
-                        yield ServeBoundary(
-                            index=boundary, dispatched=False, live=0,
-                            queued=len(pending),
-                            free_slots=slots.free_slots(), t=self._clock(),
-                            queued_tokens=sum(len(p[1]) for p in pending))
-                continue         # arrival gap: poll the clock again
-            with tel.phase("plan"):
-                # ---- frame plan: wide while any slot prefills, else pure
-                # decode at width 1 (two shape buckets total; width-1 frames
-                # are the speculative draft/verify frames when a draft rides) ----
-                width = c.prefill_chunk_size if slots.any_prefilling() else 1
-                cur_steps = steps
-                saturated = slots.free_slots() == 0
-                if adaptive:
-                    cur_steps = self._pick_frame_steps(ewma, steps, saturated)
-                tel.on_frame_plan(ewma, saturated, cur_steps)
-                draft = None
-                if speculate:
-                    draft = (self.draft_runner, self.draft_params, self.draft_kv,
-                             gamma)
-                if faults is not None:
-                    slots.set_poison(faults.poison_uids(boundary))
-            with tel.frame_trace(width, cur_steps):
-                toks, emit = self._run_frame_resilient(
-                    slots, width, cur_steps, slots.all_greedy(), draft,
-                    faults, boundary)
-            with tel.phase("absorb"):
-                stats_synced = self._sync_frame_stats(
-                    slots, width, cur_steps, ewma, len(pending), stats_synced)
-                # quarantine BEFORE the host replay: a poisoned row's slot is
-                # freed here, so absorb neither emits its garbage tail nor
-                # retires it as finished (repair-policy rows survive instead
-                # and get their mirrors resynced after the replay)
-                repaired = self._handle_nonfinite(slots, boundary)
-                emissions, finished = slots.absorb(toks, emit, width)
-                if repaired:
-                    slots.resync_committed(repaired)
-                for uid, new_toks in emissions.items():
-                    seq = self.state.seqs[uid]
-                    seq.generated.extend(new_toks)
-                    # the committed watermark, NOT the speculative write cursor:
-                    # rejected draft positions never count as seen
-                    seq.seen_tokens = int(
-                        slots.committed_h[slots.slot_of_uid[uid]])
-                    tel.on_emit(uid, len(new_toks))
-            with tel.phase("publish"):
-                if self._handoff_mode:
-                    self._tier_publish_progress(slots, boundary, cur_steps)
-                self._publish_prefixes(slots)
-            with tel.phase("retire"):
-                for uid in finished:
-                    seq = self.state.seqs[uid]
-                    seq.done = True
-                    out = np.asarray(seq.generated, np.int64)
-                    slots.retire(uid)
-                    self.state.flush_sequence(uid)
-                    self._ledger.pop(uid, None)
-                    self._drop_swap(uid)
-                    tel.on_retire(uid)
-                    with tel.phase("yield"):
-                        yield uid, out
-            if self._handoff_mode:
-                # prefill complete (and not finished outright): publish
-                # the final pages + prefix record and hand the request
-                # back to the router for decode placement
-                with tel.phase("publish"):
-                    handoffs = self._collect_handoffs(
-                        slots, boundary, c.prefill_chunk_size)
-                for event in handoffs:
-                    with tel.phase("yield"):
-                        yield event
-            if boundaries:
-                with tel.phase("yield"):
-                    yield ServeBoundary(
-                        index=boundary, dispatched=True,
-                        live=slots.live_count(), queued=len(pending),
-                        free_slots=slots.free_slots(), t=self._clock(),
-                        queued_tokens=sum(len(p[1]) for p in pending),
-                        emissions=emissions)
-
     # ------------------------------------------------------------------
-    # SLO-aware scheduled serving (scheduler.RequestScheduler)
+    # the serve loop: one, whatever the admission policy (scheduler.py)
     # ------------------------------------------------------------------
 
     def _evict_to_queue(self, uid, slots, sched, boundary: int = -1):
@@ -2377,7 +2129,6 @@ class InferenceEngineV2:
         which case the victim's committed pages are swapped OUT here (one
         boundary D2H read per pool) and swapped back IN at re-admission,
         replacing the re-prefill with a page restore."""
-        from .scheduler import PRIORITY_NAMES
         seq = self.state.seqs[uid]
         req = sched.on_evict(uid)
         emitted = seq.generated[req.gen_base:]
@@ -2429,71 +2180,48 @@ class InferenceEngineV2:
             self.kv.allocator.free(seq.blocks)
             seq.blocks = []
         sched.requeue_front(req)
-        self.telemetry.on_preempt(uid, req.tenant,
-                                  PRIORITY_NAMES[req.priority])
+        self.telemetry.on_preempt(uid, req.tenant, req.pclass)
 
-    def _serve_loop_sched(self, slots, arrivals, sched, steps,
-                          max_new_tokens, temperature, eos_token_id,
-                          speculate=False, gamma=0, adaptive=False,
-                          faults=None, resume=(), boundaries=False):
-        """The scheduler-driven twin of ``_serve_loop``: same frame
-        execution and retirement contract, but enqueue/admission flow
-        through the ``RequestScheduler`` policy object, with an SLO
-        control pass, optional preemption, and pressure-capped frame
-        sizes at each boundary. All of it is host-side boundary work —
-        the frames themselves are untouched. Deadline expiry runs BEFORE
-        the control pass, so expired work is cancelled before it can be
-        aged, preempted for, or admitted."""
-        from .scheduler import (PRIORITY_NAMES, Request, normalize_priority)
+    def _serve_loop(self, slots, arrivals, sched, steps, max_new_tokens,
+                    temperature, eos_token_id, speculate=False, gamma=0,
+                    adaptive=False, faults=None, resume=(),
+                    boundaries=False):
+        """The frame loop. Enqueue and admission flow through the policy
+        object ``sched`` (``scheduler.FifoPolicy`` or a
+        ``RequestScheduler``), with its control pass, its preemptions and
+        its cap on the frame length at each boundary. All of it is
+        host-side boundary work — the frames themselves are untouched.
+        Deadline expiry runs BEFORE the control pass, so expired work is
+        cancelled before it can be aged, preempted for, or admitted."""
         c = self._config
         tel = self.telemetry
         alpha = c.frame_steps_ewma_alpha
         ewma = 0.0
         exhausted = False
-        stats_synced = True
-        boundary = -1
+        stats_synced = True     # device stat vector starts at zero
+        boundary = -1           # frame-boundary index (fault schedules key
+        #                         on it; == dispatched-frame index while
+        #                         rows are live)
         resume_t0 = self._clock()
         n_resumed = len(resume)
-        # ---- crash-recovery ingestion (see _serve_loop): snapshot
-        # requests re-enter through the scheduler with their original
-        # class/tenant/slo, tokens folded for re-prefill ----
+        # ---- crash-recovery ingestion: the snapshot's requests re-enter
+        # ahead of any new arrival, with their original class/tenant/slo
         for (uid, prompt, limit, temp, eos, dl_ms, generated, tenant, prio,
              slo_ms, trace) in resume:
-            seq = self.state.get_or_create_sequence(uid)
-            seq.generated = list(generated)
-            seq.done = False
-            prio = normalize_priority(prio)
-            tenant = tenant or "default"
-            self._ledger_add(uid, prompt, limit, temp, eos, dl_ms,
-                             tenant=tenant, priority=PRIORITY_NAMES[prio],
-                             slo_ms=slo_ms, resumed_from=len(generated),
-                             trace=trace)
-            self._enqueue_traced(uid, tenant=tenant,
-                                 pclass=PRIORITY_NAMES[prio],
-                                 resumed=len(generated) > 0, trace=trace)
-            remaining = limit - len(generated)
-            if remaining <= 0:
-                out = np.asarray(seq.generated, np.int64)
-                self.state.flush_sequence(uid)
-                self._ledger.pop(uid, None)
-                tel.on_retire(uid)
+            done_out = self._enqueue(sched, uid, prompt, limit, temp, eos,
+                                     dl_ms, generated, tenant, prio, slo_ms,
+                                     trace)
+            if done_out is not None:
                 with tel.phase("yield"):
-                    yield uid, out
-                continue
-            folded = np.concatenate(
-                [np.asarray(prompt, np.int32),
-                 np.asarray(generated, np.int32)]) if generated else \
-                np.asarray(prompt, np.int32)
-            # bypass_quota: this request was already ACCEPTED by the
-            # crashed run (known issue (a) — tenant_max_queued must not
-            # shed mid-flight work on resume and drop its committed
-            # tokens). The quota is submit()'s only shed, so a bypassed
-            # submit never sheds — no rejection handling needed here.
-            sched.submit(Request(
-                uid=uid, tokens=folded, limit=remaining, temp=temp,
-                eos=eos, tenant=tenant, priority=prio, slo_ms=slo_ms,
-                resumed_from=len(generated), resumed=True),
-                bypass_quota=True)
+                    yield uid, done_out
+
+        def try_reserve(req):
+            # the policy's probe: blocks for ``req`` at this boundary, or None
+            seq = self.state.get_or_create_sequence(req.uid)
+            cached0 = self._admit_capacity(req.uid, seq, req.tokens,
+                                           req.limit, boundary)
+            return None if cached0 is None else (seq, cached0)
+
         while True:
             boundary += 1
             # a poll on an empty server is the wait for the next arrival,
@@ -2514,6 +2242,9 @@ class InferenceEngineV2:
                         exhausted = True
                         batch = None
                     ewma = alpha * len(batch or []) + (1.0 - alpha) * ewma
+                    # validate at ENQUEUE — before any KV reservation is made
+                    # for this round, so a bad request can't strand blocks
+                    # already reserved for earlier items in the same batch
                     for item in (batch or []):
                         uid, toks, limit, temp, eos, tenant, prio, slo_ms, \
                             dl_ms, gen, trace = self._norm_arrival(
@@ -2526,53 +2257,22 @@ class InferenceEngineV2:
                         if gen is not None and limit < want:
                             self._note_resume_truncated(uid, want, limit,
                                                         boundary)
-                        prio = normalize_priority(prio)
-                        tenant = tenant or "default"
-                        self._ledger_add(uid, toks, limit, temp, eos, dl_ms,
-                                         tenant=tenant,
-                                         priority=PRIORITY_NAMES[prio],
-                                         slo_ms=slo_ms,
-                                         resumed_from=len(gen) if gen else 0,
-                                         trace=trace)
-                        self._enqueue_traced(uid, tenant=tenant,
-                                            pclass=PRIORITY_NAMES[prio],
-                                            resumed=bool(gen), trace=trace)
-                        if gen is not None:
-                            # mid-run RESUME arrival (router failover / drain
-                            # migration / handoff): the submit bypasses the tenant
-                            # queue quota — this request was already accepted
-                            # once, and its committed tokens must not be shed
-                            # at a second admission
-                            fold, done_out = self._ingest_resume(
-                                uid, toks, limit, gen, tel)
-                            if done_out is not None:
-                                with tel.phase("yield"):
-                                    yield uid, done_out
-                                continue
-                            folded, remaining = fold
-                            sched.submit(Request(
-                                uid=uid, tokens=folded, limit=remaining,
-                                temp=temp, eos=eos, tenant=tenant,
-                                priority=prio, slo_ms=slo_ms,
-                                resumed_from=len(gen), resumed=True),
-                                bypass_quota=True)
-                            continue
-                        shed = sched.submit(Request(
-                            uid=uid, tokens=toks, limit=limit, temp=temp,
-                            eos=eos, tenant=tenant, priority=prio,
-                            slo_ms=slo_ms))
-                        if shed is not None:
-                            tel.on_shed(uid, shed.tenant, shed.priority,
-                                        shed.reason)
-                            self._ledger.pop(uid, None)
+                        done_out = self._enqueue(
+                            sched, uid, toks, limit, temp, eos, dl_ms, gen,
+                            tenant, prio, slo_ms, trace)
+                        if done_out is not None:
+                            with tel.phase("yield"):
+                                yield uid, done_out
             with tel.phase("admit"):
                 # ---- deadlines: cancel expired work (queued or live) BEFORE
                 # it can be aged, preempted for, or admitted ----
-                self._expire_deadlines(slots, boundary, sched=sched)
-                # ---- SLO control pass: age queues, refill fair-share credit,
-                # recompute pressure, shed best-effort work under critical
-                # pressure (structured reasons land in sched.shed_log) ----
-                for shed in sched.on_boundary(tel.slo_view(),
+                self._expire_deadlines(slots, boundary, sched)
+                # ---- the policy's control pass: age queues, refill fair-share
+                # credit, recompute pressure, shed best-effort work under
+                # critical pressure (structured reasons land in
+                # sched.shed_log). It takes the SLO view itself, if it reads
+                # one ----
+                for shed in sched.on_boundary(tel.slo_view,
                                               live_count=slots.live_count()):
                     tel.on_shed(shed.uid, shed.tenant, shed.priority,
                                 shed.reason)
@@ -2582,7 +2282,6 @@ class InferenceEngineV2:
                     self.state.flush_sequence(shed.uid)
                     self._ledger.pop(shed.uid, None)
                     self._drop_swap(shed.uid)
-                tel.gauges["slo_risk"] = round(sched.risk, 4)
                 # ---- frame-boundary preemption: make room for a queued
                 # interactive arrival by evicting a lower-priority live row
                 # (pointless while draining: nothing will be admitted) ----
@@ -2592,7 +2291,9 @@ class InferenceEngineV2:
                     for uid in sched.pick_victims(
                             committed, free_blocks=self.kv.free_blocks):
                         self._evict_to_queue(uid, slots, sched, boundary)
-                # ---- policy admission (strict priority + fair share) ----
+                # ---- admission, in the policy's order (blocks reserved for
+                # the whole prompt + generation budget up front, so block
+                # tables never grow mid-flight) ----
                 blocks_before = self.kv.free_blocks
                 alloc_blocked = faults is not None \
                     and faults.kv_alloc_blocked(boundary)
@@ -2601,15 +2302,6 @@ class InferenceEngineV2:
                         "kv_alloc_failed", boundary,
                         "injected KV-block allocation failure; admission "
                         "deferred this boundary")
-
-                def try_reserve(req):
-                    seq = self.state.get_or_create_sequence(req.uid)
-                    cached0 = self._admit_capacity(req.uid, seq, req.tokens,
-                                                   req.limit, boundary)
-                    if cached0 is None:
-                        return None
-                    return (seq, cached0)
-
                 admits = []
                 if not alloc_blocked and not self._draining:
                     for req, res in sched.pick(slots.free_slots(), try_reserve,
@@ -2621,6 +2313,14 @@ class InferenceEngineV2:
                                        req.temp, req.eos, cached0))
                         tel.on_admit(req.uid)
                 if sched.queued_count() and not self._draining:
+                    # overload is otherwise invisible: the deferred arrivals
+                    # just wait — count it and warn (rate-limited). admit()
+                    # hasn't executed yet, so subtract this round's admits or
+                    # a full table would be misreported as KV pressure;
+                    # likewise free_blocks already reflects this round's
+                    # reservations, so thread the reserved count through to
+                    # keep standing pressure distinguishable from a busy
+                    # admission round
                     tel.on_defer(
                         queue_depth=sched.queued_count(),
                         frame_steps=tel.serve_view["frame_steps_last"] or steps,
@@ -2644,11 +2344,14 @@ class InferenceEngineV2:
                             queued=sched.queued_count(),
                             free_slots=slots.free_slots(), t=self._clock(),
                             queued_tokens=sched.queued_prompt_tokens())
-                continue
+                continue         # arrival gap: poll the clock again
             with tel.phase("plan"):
-                # ---- frame plan: the scheduler's pressure signal caps the
-                # frame length so admission boundaries come around sooner
-                # while interactive latency is at risk ----
+                # ---- frame plan: wide while any slot prefills, else pure
+                # decode at width 1 (two shape buckets total; width-1 frames
+                # are the speculative draft/verify frames when a draft
+                # rides). The policy's pressure signal caps the frame length
+                # so admission boundaries come around sooner while
+                # interactive latency is at risk ----
                 width = c.prefill_chunk_size if slots.any_prefilling() else 1
                 cur_steps = steps
                 saturated = slots.free_slots() == 0
@@ -2670,13 +2373,19 @@ class InferenceEngineV2:
                 stats_synced = self._sync_frame_stats(
                     slots, width, cur_steps, ewma, sched.queued_count(),
                     stats_synced)
-                repaired = self._handle_nonfinite(slots, boundary, sched=sched)
+                # quarantine BEFORE the host replay: a poisoned row's slot is
+                # freed here, so absorb neither emits its garbage tail nor
+                # retires it as finished (repair-policy rows survive instead
+                # and get their mirrors resynced after the replay)
+                repaired = self._handle_nonfinite(slots, boundary, sched)
                 emissions, finished = slots.absorb(toks, emit, width)
                 if repaired:
                     slots.resync_committed(repaired)
                 for uid, new_toks in emissions.items():
                     seq = self.state.seqs[uid]
                     seq.generated.extend(new_toks)
+                    # the committed watermark, NOT the speculative write cursor:
+                    # rejected draft positions never count as seen
                     seq.seen_tokens = int(
                         slots.committed_h[slots.slot_of_uid[uid]])
                     tel.on_emit(uid, len(new_toks))
@@ -2698,9 +2407,12 @@ class InferenceEngineV2:
                     with tel.phase("yield"):
                         yield uid, out
             if self._handoff_mode:
+                # prefill complete (and not finished outright): publish
+                # the final pages + prefix record and hand the request
+                # back to the router for decode placement
                 with tel.phase("publish"):
                     handoffs = self._collect_handoffs(
-                        slots, boundary, c.prefill_chunk_size, sched=sched)
+                        slots, boundary, c.prefill_chunk_size, sched)
                 for event in handoffs:
                     with tel.phase("yield"):
                         yield event

@@ -1,12 +1,17 @@
-"""SLO-aware request scheduler for the frame serving loop.
+"""Admission policies for the frame serving loop.
 
-The frame loop (``engine_v2.serve``) admits arrivals FIFO-until-full; under
-the multi-tenant, heavy-traffic regime DeepSpeed Inference frames serving as
-a *scheduling* problem, not just a kernel problem — and PR 3's telemetry
-exposes exactly the signals (live TTFT / queue-wait p90, occupancy, KV
-pressure) an admission policy needs. This module is that policy layer: a
-``RequestScheduler`` replaces the inline ``pending`` deque in
-``_serve_loop`` with a policy object owning
+``engine_v2._serve_loop`` is one loop that knows how waiting requests
+queue, and who is admitted next, only through the methods the two classes
+here share: ``begin_serve``, ``new_request``, ``submit``, ``is_queued``,
+``queued_count``, ``queued_uids``, ``queued_prompt_tokens``, ``cancel``,
+``on_boundary``, ``preempt_wanted`` / ``pick_victims`` / ``on_evict`` /
+``requeue_front``, ``pick``, ``frame_steps_cap`` and ``on_retire``.
+``FifoPolicy`` is what ``serve(scheduler=None)`` runs: arrival order,
+FIFO-until-full. Under the multi-tenant, heavy-traffic regime DeepSpeed
+Inference frames serving as a *scheduling* problem, not just a kernel
+problem — and the telemetry exposes exactly the signals (live TTFT /
+queue-wait p90, occupancy, KV pressure) an admission policy needs:
+``RequestScheduler`` is the policy object owning
 
 1. **Priority classes** — ``interactive`` / ``batch`` / ``best_effort``
    with strict-priority dispatch (every effective-interactive admission is
@@ -49,10 +54,11 @@ pressure) an admission policy needs. This module is that policy layer: a
    from scratch — token-identical under greedy decoding, at the cost of
    recomputing the committed prefix.
 
-Everything here runs host-side at frame boundaries: the scheduler adds zero
-device->host transfers inside a frame (pinned by the transfer-guard test),
-and with no scheduler passed ``serve()`` keeps its original FIFO code path
-byte-for-byte.
+Everything here runs host-side at frame boundaries: a policy adds zero
+device->host transfers inside a frame (pinned by the transfer-guard test).
+A default ``RequestScheduler()`` gives tuple arrivals FIFO's outputs in
+FIFO's order (``test_no_scheduler_path_is_fifo_identical``), under the
+labels ``default`` / ``interactive`` and with the SLO pass each boundary.
 """
 
 import dataclasses
@@ -156,8 +162,8 @@ class Request:
     limit: int
     temp: float
     eos: Optional[int]
-    tenant: str = "default"
-    priority: int = INTERACTIVE
+    tenant: Optional[str] = "default"       # None: filed by ``FifoPolicy``
+    priority: Optional[int] = INTERACTIVE   # None: filed by ``FifoPolicy``
     slo_ms: Optional[float] = None
     seq_no: int = 0            # global arrival order (FIFO tie-break)
     round0: int = 0            # boundary index at (re-)enqueue, for aging
@@ -174,6 +180,12 @@ class Request:
     resumed_from: int = 0
     resumed: bool = False
 
+    @property
+    def pclass(self) -> Optional[str]:
+        """The class NAME the ledger, snapshots and metric labels carry."""
+        return None if self.priority is None \
+            else PRIORITY_NAMES[self.priority]
+
 
 @dataclasses.dataclass
 class ShedReason:
@@ -189,6 +201,86 @@ class ShedReason:
     # monotonic shed time: orders shed records against the crash flight
     # recorder's event ring (tracing.py) in a postmortem bundle
     t: Optional[float] = None
+
+
+class FifoPolicy:
+    """What ``serve(scheduler=None)`` runs: one deque in arrival order,
+    admitted head first until the table or the pool is full. It reads no
+    scheduling metadata, so it files every request under no tenant and no
+    class whatever the arrival carried (ledger, snapshots and metric
+    labels carry None), sheds nothing, preempts nothing, and leaves the
+    frame length alone."""
+
+    def begin_serve(self, engine) -> None:
+        self._kv = engine.kv
+        self._queue: deque = deque()
+        self._live: Dict[int, Request] = {}
+
+    def new_request(self, uid, tokens, limit, temp, eos, tenant=None,
+                    priority=None, slo_ms=None) -> Request:
+        return Request(uid=uid, tokens=tokens, limit=limit, temp=temp,
+                       eos=eos, tenant=None, priority=None)
+
+    def queued_count(self) -> int:
+        return len(self._queue)
+
+    def is_queued(self, uid: int) -> bool:
+        return any(r.uid == uid for r in self._queue)
+
+    def queued_uids(self) -> List[int]:
+        return [r.uid for r in self._queue]
+
+    def queued_prompt_tokens(self) -> int:
+        return sum(len(r.tokens) for r in self._queue)
+
+    def submit(self, req: Request, bypass_quota: bool = False) -> None:
+        self._queue.append(req)
+
+    def requeue_front(self, req: Request) -> None:
+        self._queue.appendleft(req)
+
+    def cancel(self, uid: int) -> Optional[Request]:
+        for r in self._queue:
+            if r.uid == uid:
+                self._queue.remove(r)
+                return r
+        return None
+
+    def on_boundary(self, slo_view, live_count: int) -> List[ShedReason]:
+        return []
+
+    def frame_steps_cap(self, max_steps: int) -> int:
+        return max_steps
+
+    def preempt_wanted(self, free_slots: int) -> bool:
+        return False
+
+    def pick_victims(self, committed, free_blocks=None) -> List[int]:
+        return []
+
+    def on_evict(self, uid: int) -> Request:
+        return self._live.pop(uid)
+
+    def pick(self, free_slots: int, try_reserve, live_count: int):
+        """Head of line: stop at the first request the pool cannot hold
+        yet. One that an EMPTY pool cannot hold can never fit."""
+        admits = []
+        while self._queue and len(admits) < free_slots:
+            head = self._queue[0]
+            res = try_reserve(head)
+            if res is None:
+                if live_count == 0 and not admits:
+                    raise RuntimeError(
+                        f"uid={head.uid}: prompt + budget can never fit "
+                        f"the KV pool ({self._kv.free_blocks} blocks free "
+                        "with no live sequences)")
+                break
+            self._live[head.uid] = self._queue.popleft()
+            admits.append((head, res))
+        return admits
+
+    def on_retire(self, uid: int) -> None:
+        self._live.pop(uid, None)
 
 
 class RequestScheduler:
@@ -276,9 +368,6 @@ class RequestScheduler:
         TOKENS to chew through, not request count)."""
         return sum(len(r.tokens) for q in self._queues.values() for r in q)
 
-    def live_request(self, uid: int) -> Optional[Request]:
-        return self._live.get(uid)
-
     def _weight(self, tenant: str) -> float:
         w = self.cfg.tenant_weights.get(tenant, 1.0)
         return max(w, 1e-6)
@@ -313,6 +402,15 @@ class RequestScheduler:
                   if t != tenant and self._tenant_active(t)]
         floor = min(others) if others else self._vclock
         self._vtime[tenant] = max(self._vtime.get(tenant, 0.0), floor)
+
+    def new_request(self, uid, tokens, limit, temp, eos, tenant=None,
+                    priority=None, slo_ms=None) -> Request:
+        """The arrival as this policy files it: no tenant is ``default``,
+        no priority is interactive (an unknown one raises here, before
+        anything is kept of the request)."""
+        return Request(uid=uid, tokens=tokens, limit=limit, temp=temp,
+                       eos=eos, tenant=tenant or "default",
+                       priority=normalize_priority(priority), slo_ms=slo_ms)
 
     def submit(self, req: Request,
                bypass_quota: bool = False) -> Optional[ShedReason]:
@@ -401,12 +499,16 @@ class RequestScheduler:
                     cands.append(r.slo_ms)
         return min(cands) if cands else None
 
-    def on_boundary(self, slo_view: Dict, live_count: int) -> List[ShedReason]:
+    def on_boundary(self, slo_view, live_count: int) -> List[ShedReason]:
         """Advance the boundary clock: age queues, refill fair-share
         credit, recompute SLO risk, and shed queued best-effort work under
         critical pressure. Returns the sheds (the engine reports each to
-        telemetry)."""
+        telemetry). ``slo_view`` is ``telemetry.slo_view()`` or that
+        function itself: the serve loop hands over the function, so the
+        percentiles are taken only for a policy that reads them."""
         cfg = self.cfg
+        if callable(slo_view):
+            slo_view = slo_view()
         self._round += 1
         # admission-lookahead predictor: EWMA of fresh interactive
         # submissions per boundary (updated even when the feature is off,
@@ -426,6 +528,8 @@ class RequestScheduler:
         self.pressure = (2 if target and self.risk >= cfg.slo_shed_threshold
                          else 1 if target and
                          self.risk >= cfg.slo_defer_threshold else 0)
+        if self._telemetry is not None:
+            self._telemetry.gauges["slo_risk"] = round(self.risk, 4)
         sheds: List[ShedReason] = []
         # shed queued best-effort under critical pressure — but only while
         # the machine is actually busy (an idle table should drain its
@@ -545,10 +649,10 @@ class RequestScheduler:
         tenant (FIFO by arrival among equals). ``try_reserve(req)`` returns
         the engine-side descriptor on success or None when the KV pool
         cannot hold the request (that tenant's queue is then blocked for
-        this boundary — head-of-line, like the FIFO path).
+        this boundary — head-of-line, like ``FifoPolicy``).
 
         Raises RuntimeError when the table is empty, nothing could be
-        admitted, and work is queued — the FIFO path's impossible-fit
+        admitted, and work is queued — ``FifoPolicy``'s impossible-fit
         semantics (only capacity can block an empty table)."""
         admits: List[Tuple[Request, object]] = []
         blocked: set = set()
@@ -597,7 +701,7 @@ class RequestScheduler:
                 self.summary["admitted_by_class"][PRIORITY_NAMES[cls]] += 1
                 admits.append((head, seq))
         if live_count == 0 and not admits and self.queued_count():
-            # mirrors the FIFO path: with nothing live, no quota or
+            # mirrors FifoPolicy: with nothing live, no quota or
             # deferral can block (both are gated on live work), so the only
             # blocker is capacity — and capacity that fails an EMPTY pool
             # can never succeed. Name the request whose reservation

@@ -14,7 +14,8 @@ verbatim (submit quotas, SLO sheds, aging, fair share, preemption,
 admission, frame-steps caps), the ``ServingTelemetry`` is the real class
 on the virtual clock (so TTFT/ITL percentiles come out of the same
 histograms the live fleet exports), and the per-boundary sequence below
-mirrors ``engine_v2._serve_loop_sched`` stage for stage — arrival poll,
+mirrors ``engine_v2._serve_loop`` (the one serve loop, here always under
+a ``RequestScheduler``) stage for stage — arrival poll,
 deadline expiry, ``on_boundary`` control pass, preemption, admission,
 idle/exhausted handling, frame plan, emissions, retirement, handoffs,
 boundary event. Arrival normalization reuses the real
@@ -453,7 +454,7 @@ class SimEngine:
         exhausted = False
         boundary = -1
         self._clock.seek(self.local_t)
-        # ---- crash-recovery ingestion (mirrors _serve_loop_sched) ----
+        # ---- crash-recovery ingestion (mirrors engine_v2._enqueue) ----
         for (uid, prompt, limit, temp, eos, dl_ms, generated, tenant, prio,
              slo_ms, trace) in resume:
             seq = self.state.get_or_create_sequence(uid)
@@ -550,7 +551,7 @@ class SimEngine:
                         self._ledger.pop(uid, None)
                         self._emit_event("shed", uid, reason=shed.reason)
             # ---- deadlines, control pass, preemption, admission: the
-            # exact _serve_loop_sched stage order ----
+            # exact _serve_loop stage order ----
             self._expire_deadlines(sched, boundary)
             for shed in sched.on_boundary(tel.slo_view(),
                                           live_count=len(self._rows)):

@@ -12,6 +12,7 @@ import pytest
 
 from deepspeed_tpu.inference.v2.engine_v2 import (InferenceEngineV2,
                                                   RaggedInferenceEngineConfig)
+from deepspeed_tpu.inference.v2.scheduler import RequestScheduler
 from deepspeed_tpu.models import build_model
 
 
@@ -24,6 +25,13 @@ def _mesh(mesh_8dp):
 def tiny_model_params():
     model = build_model("tiny")
     return model, model.init(jax.random.PRNGKey(0))
+
+
+# One serve loop runs whatever the admission policy: what a de-forked
+# helper does is checked under both (None = scheduler.FifoPolicy). A
+# factory, not an instance: a scheduler is bound to one serve at a time.
+POLICIES = pytest.mark.parametrize(
+    "policy", [lambda: None, RequestScheduler], ids=["fifo", "scheduler"])
 
 
 def _engine(model, params, **over):
@@ -201,42 +209,72 @@ def test_frame_loop_recompile_count_bounded(tiny_model_params):
     assert frame_fn._cache_size() <= 6
 
 
-def test_frame_serving_admission_guards(tiny_model_params):
+@POLICIES
+def test_frame_serving_admission_guards(tiny_model_params, policy):
     """A duplicate in-flight uid is a client error (loud, before it can
     corrupt the uid<->slot mapping); an over-context budget is clamped so
-    the slot table never outgrows max_seq_len."""
+    the slot table never outgrows max_seq_len; a request that an EMPTY
+    pool cannot hold can never be admitted, and says so."""
     model, params = tiny_model_params
     rng = np.random.default_rng(12)
     p = rng.integers(0, 200, (8,)).astype(np.int32)
 
     with pytest.raises(ValueError, match="already live"):
         list(_engine(model, params).serve(
-            iter([[(0, p)], [(0, p)]]), max_new_tokens=64))
+            iter([[(0, p)], [(0, p)]]), max_new_tokens=64,
+            scheduler=policy()))
+
+    # a duplicate of a QUEUED uid (one slot: uid 1 waits behind uid 0)
+    with pytest.raises(ValueError, match="already live"):
+        list(_engine(model, params).serve(
+            iter([[(0, p), (1, p)], [(1, p)]]), max_new_tokens=8,
+            frame_slots=1, scheduler=policy()))
 
     # 100-token prompt in a 128-token context: budget 64 -> clamped to 27
     long_p = rng.integers(0, 200, (100,)).astype(np.int32)
     e = _engine(model, params)
-    got = dict(e.serve(iter([[(0, long_p)]]), max_new_tokens=64))
+    got = dict(e.serve(iter([[(0, long_p)]]), max_new_tokens=64,
+                       scheduler=policy()))
     assert len(got[0]) == 128 - 100 - 1
     assert e.kv.free_blocks == e.kv.num_blocks - 1
 
+    # 3 usable blocks of 16 positions against 8 + 64 + 1: the table is
+    # empty and nothing was admitted, so waiting cannot help. The uid the
+    # error names was never admitted and leaves nothing behind
+    small = _engine(model, params, num_kv_blocks=4)
+    with pytest.raises(RuntimeError, match="uid=7: prompt.*can never fit"):
+        list(small.serve(iter([[(7, p)]]), max_new_tokens=64,
+                         scheduler=policy()))
+    assert not small.state.seqs and not small._ledger
+    assert small.kv.free_blocks == small.kv.num_blocks - 1
 
-def test_frame_serving_abandonment_releases_state(tiny_model_params):
+
+@POLICIES
+def test_frame_serving_abandonment_releases_state(tiny_model_params, policy):
     """Breaking out of serve() mid-stream (server shutdown, client error)
-    must release every in-flight sequence: no leaked KV blocks, no stale
-    descriptors that would feed old tokens to a later call reusing a uid."""
+    must release every in-flight sequence, live or still queued (two
+    slots: one waits with a descriptor from its capacity probe): no leaked
+    KV blocks, no stale descriptors that would feed old tokens to a later
+    call reusing a uid."""
     model, params = tiny_model_params
     rng = np.random.default_rng(13)
     prompts = {u: rng.integers(0, 200, (10 + u,)).astype(np.int32)
                for u in range(4)}
     e = _engine(model, params)
     for _uid, _toks in e.serve(iter([[(u, prompts[u]) for u in prompts]]),
-                               max_new_tokens=16):
+                               max_new_tokens=16, scheduler=policy()):
         break                                   # abandon with 3 in flight
     assert not e.state.seqs
     assert e.kv.free_blocks == e.kv.num_blocks - 1
+    for _uid, _toks in e.serve(iter([[(u, prompts[u]) for u in prompts]]),
+                               max_new_tokens=16, frame_slots=2,
+                               scheduler=policy()):
+        break                                   # 1 live, 2 queued
+    assert not e.state.seqs and not e._ledger
+    assert e.kv.free_blocks == e.kv.num_blocks - 1
     # the engine is reusable afterwards, uids included
-    got = dict(e.serve(iter([[(0, prompts[0])]]), max_new_tokens=4))
+    got = dict(e.serve(iter([[(0, prompts[0])]]), max_new_tokens=4,
+                       scheduler=policy()))
     assert len(got[0]) == 4
 
 

@@ -47,6 +47,14 @@ def tiny_model_params():
     return model, model.init(jax.random.PRNGKey(0))
 
 
+# One serve loop runs whatever the admission policy: what a de-forked
+# helper does (expiry, quarantine, deferral, resume ingestion) is checked
+# under both (None = scheduler.FifoPolicy). A factory, not an instance: a
+# scheduler is bound to one serve at a time.
+POLICIES = pytest.mark.parametrize(
+    "policy", [lambda: None, RequestScheduler], ids=["fifo", "scheduler"])
+
+
 def _engine(model, params, **over):
     kw = dict(kv_block_size=16, prefill_chunk_size=16, max_tokens_per_step=256,
               dtype="float32", max_ragged_batch_size=8, frame_steps=4,
@@ -189,8 +197,9 @@ def test_watchdog_flags_slow_frame(served_engine, fault_free_base):
 # ---------------------------------------------------------------------------
 
 
+@POLICIES
 def test_poison_row_quarantined_siblings_unaffected(
-        served_engine, fault_free_base):
+        served_engine, fault_free_base, policy):
     """A row whose logits go non-finite mid-decode is quarantined at the
     frame boundary: evicted, retired with a structured FaultReason carrying
     its committed partial output, never yielded — and every sibling's
@@ -198,7 +207,8 @@ def test_poison_row_quarantined_siblings_unaffected(
     for one request."""
     e = served_engine
     inj = FaultInjector([{"kind": "poison_row", "frame": 1, "uid": 1}])
-    got = dict(e.serve(_arrivals(), max_new_tokens=8, faults=inj))
+    got = dict(e.serve(_arrivals(), max_new_tokens=8, faults=inj,
+                       scheduler=policy()))
     assert 1 not in got                      # quarantined, not yielded
     for u in (0, 2, 3):
         np.testing.assert_array_equal(fault_free_base[u], got[u],
@@ -237,14 +247,16 @@ def test_finite_check_adds_no_in_frame_transfers(served_engine,
 # ---------------------------------------------------------------------------
 
 
+@POLICIES
 def test_kv_alloc_failure_defers_then_recovers(served_engine,
-                                               fault_free_base):
+                                               fault_free_base, policy):
     """Injected allocation failures turn into admission deferrals (the
     graceful path), not crashes: arrivals wait out the fault window and
     complete token-identically."""
     e = served_engine
     inj = FaultInjector([{"kind": "kv_alloc_fail", "frame": 2, "times": 2}])
-    got = dict(e.serve(_arrivals(), max_new_tokens=8, faults=inj))
+    got = dict(e.serve(_arrivals(), max_new_tokens=8, faults=inj,
+                       scheduler=policy()))
     assert set(got) == set(fault_free_base)
     for u in fault_free_base:
         np.testing.assert_array_equal(fault_free_base[u], got[u],
@@ -286,7 +298,8 @@ def test_deadline_expiry_frees_blocks_and_counts(served_engine,
     _assert_clean(e)
 
 
-def test_deadline_expiry_in_queue_before_admission(served_engine):
+@POLICIES
+def test_deadline_expiry_in_queue_before_admission(served_engine, policy):
     """A QUEUED request past its deadline is cancelled before a slot or any
     KV blocks are ever spent on it (zero tokens emitted)."""
     e = served_engine
@@ -298,7 +311,8 @@ def test_deadline_expiry_in_queue_before_admission(served_engine):
         for _ in range(2):
             yield []
 
-    got = dict(e.serve(arr(), max_new_tokens=8, frame_slots=2))
+    got = dict(e.serve(arr(), max_new_tokens=8, frame_slots=2,
+                       scheduler=policy()))
     assert set(got) == {20, 21}
     fr = [f for f in e.fault_log if f.kind == "deadline_expired"][-1]
     assert fr.uid == 22 and "queued" in fr.detail
@@ -338,8 +352,9 @@ def test_deadline_cancelled_before_preemption_or_aging(served_engine):
 # ---------------------------------------------------------------------------
 
 
+@POLICIES
 def test_kill_and_resume_token_identical(tiny_model_params, served_engine,
-                                         fault_free_base):
+                                         fault_free_base, policy):
     """A fatal dispatch failure (retry budget exhausted) surfaces as
     FrameDispatchError AFTER the engine auto-snapshots its request ledger;
     a FRESH engine resuming from the snapshot re-admits the in-flight
@@ -352,7 +367,8 @@ def test_kill_and_resume_token_identical(tiny_model_params, served_engine,
                           "times": 10}])
     collected = {}
     with pytest.raises(FrameDispatchError, match="resume_from"):
-        for uid, toks in e.serve(_arrivals(), max_new_tokens=8, faults=inj):
+        for uid, toks in e.serve(_arrivals(), max_new_tokens=8, faults=inj,
+                                 scheduler=policy()):
             collected[uid] = toks
     assert any(f.kind == "dispatch_failed" for f in e.fault_log)
     _assert_clean(e)                          # crash cleanup left no leaks
@@ -362,7 +378,8 @@ def test_kill_and_resume_token_identical(tiny_model_params, served_engine,
     assert in_flight and in_flight.isdisjoint(collected)
 
     e2 = _engine(model, params)               # the restarted engine
-    rest = dict(e2.serve(iter([[]]), max_new_tokens=8, resume_from=snap))
+    rest = dict(e2.serve(iter([[]]), max_new_tokens=8, resume_from=snap,
+                         scheduler=policy()))
     collected.update(rest)
     assert set(collected) == set(fault_free_base)
     for u in fault_free_base:

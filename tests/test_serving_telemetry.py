@@ -11,7 +11,9 @@ test pins the no-in-frame-transfer invariant; the histogram/Prometheus tests
 pin the fixed-memory bucket math and the exposition format.
 """
 
+import json
 import logging
+import os
 
 import jax
 import numpy as np
@@ -442,6 +444,73 @@ def test_prometheus_render_from_serve(served):
     assert 'ds_serving_e2e_seconds_quantile{quantile="0.99"}' in text
     assert "ds_serving_occupancy" in text
     assert "ds_serving_kv_blocks_in_use" in text
+
+
+# ---------------------------------------------------------------------------
+# what an operator scrapes and snapshots under scheduler=None, pinned to the
+# tree that still had a FIFO loop of its own
+# ---------------------------------------------------------------------------
+
+FIFO_GOLDEN = os.path.join(os.path.dirname(__file__), "fixtures",
+                           "fifo_tuple_serve_golden.json")
+# counters whose value is a clock reading or depends on what this process
+# compiled before the test ran: their series are pinned, not their values
+_UNPINNED = ("_ns_total", "programs_requested_total")
+
+
+def _fifo_tuple_serve_record(e):
+    """Tuple arrivals under ``scheduler=None`` on a scripted schedule, two
+    slots so that requests queue and admissions defer: the retirement
+    order, ``snapshot_serving_state()`` taken mid-serve (every field but
+    the clock's), and ``render_prometheus()`` as the set of series (name
+    and labels) plus every counter's value. The golden file is this
+    function's result at b6ef6c1, the last tree with ``_serve_loop`` for
+    FIFO and ``_serve_loop_sched`` for a scheduler."""
+    rng = np.random.default_rng(5)
+    prompts = {u: rng.integers(0, 200, (n,)).astype(np.int32)
+               for u, n in {0: 7, 1: 24, 2: 33, 3: 5}.items()}
+    schedule = {0: [0, 1], 1: [2], 2: [3]}
+    arrivals = ([(u, prompts[u]) for u in schedule.get(k, [])]
+                for k in range(4))
+    order, snap = [], None
+    for ev in e.serve(arrivals, max_new_tokens=MAX_NEW, frame_slots=2,
+                      yield_boundaries=True):
+        if isinstance(ev, tuple):
+            order.append(int(ev[0]))
+        elif ev.index == 2:
+            snap = e.snapshot_serving_state()
+    for r in snap["requests"]:
+        del r["deadline_remaining_ms"]
+    series, counters = [], {}
+    for line in e.telemetry.render_prometheus().splitlines():
+        if line.startswith("#"):
+            continue
+        key, val = line.rsplit(" ", 1)
+        series.append(key)
+        if key.split("{")[0].endswith("_total") \
+                and not key.split("{")[0].endswith(_UNPINNED):
+            counters[key] = val
+    return {"order": order, "snapshot": snap, "series": series,
+            "counters": counters}
+
+
+def test_fifo_tuple_serve_matches_the_fifo_loop(tiny_model_params):
+    """``serve(scheduler=None)`` resolves to a policy object in the one
+    serve loop; for tuple arrivals nothing an operator reads may show it:
+    no tenant / class in the ledger or a snapshot, no labeled series, the
+    same counters."""
+    model, params = tiny_model_params
+    got = _fifo_tuple_serve_record(_engine(model, params))
+    with open(FIFO_GOLDEN) as f:
+        want = json.load(f)
+    assert got["order"] == want["order"]
+    assert got["snapshot"] == want["snapshot"]
+    assert [r["uid"] for r in got["snapshot"]["requests"]] and all(
+        r["tenant"] is None and r["priority"] is None and r["slo_ms"] is None
+        for r in got["snapshot"]["requests"])
+    assert got["series"] == want["series"]
+    assert got["counters"] == want["counters"]
+    assert got["counters"]["ds_serving_admission_deferrals_total"] != "0"
 
 
 # ---------------------------------------------------------------------------

@@ -220,7 +220,7 @@ def _arrivals(schedule):
 @pytest.mark.parametrize("scheduled", [False, True])
 def test_phases_tile_the_serve_loop(tiny_model_params, scheduled):
     """The phases of a scripted serve() sum to the loop's wall time within
-    2%, in the FIFO loop and in the scheduler's twin; the consumer's time
+    2%, under the FIFO policy and under a scheduler; the consumer's time
     lands in ``yield``."""
     model, params = tiny_model_params
     e = _engine(model, params)
