@@ -1,0 +1,130 @@
+"""The narrow paged-attention kernel alone, on the chip.
+
+    python benchmarks/paged_decode_sweep.py [--root DIR] [--label NAME]
+
+One decode step's attention (C = 1) at the serve configurations' shapes
+(mistral-7b: 32 query / 8 KV heads, window 4096, table width 64;
+OLMoE-1B-7B: 16 / 16, no window, table width 32; 16 slots, pages of 128,
+head_dim 128, bf16) over live slots 1 / 3 / 16 and contexts 256 / 1,024 /
+4,096 / 7,168: microseconds a layer, the pages a layer had to move
+(``live x ceil((cs - lo) / page)``, K and V of every KV head) and their
+bytes over the time as a share of the chip's 819 GB/s. ``live = 0`` is what
+sixteen frozen slots cost. ``--root`` imports ``deepspeed_tpu`` from another
+checkout (a ``git archive`` copy of the parent), so one script times both
+sides. Needs the chip: the kernel's interpret mode times nothing.
+"""
+
+import argparse
+import json
+import os
+import sys
+import time
+
+HBM_BYTES_PER_S = 819e9     # TPU v5e, Google Cloud documentation
+SLOTS, PAGE, D, LAYERS, POOL_PAGES = 16, 128, 128, 4, 416
+CONFIGS = {
+    # name: (query heads, kv heads, window, table width)
+    "mistral-7b": (32, 8, 4096, 64),
+    "olmoe-1b-7b": (16, 16, 0, 32),
+}
+LIVE = (0, 1, 3, 16)
+CONTEXTS = (256, 1024, 4096, 7168)
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--root", default=os.path.join(os.path.dirname(
+        os.path.abspath(__file__)), ".."))
+    ap.add_argument("--iters", type=int, default=40)
+    ap.add_argument("--label", default="")
+    args = ap.parse_args()
+    sys.path.insert(0, os.path.abspath(args.root))
+
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_ragged_attention
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        sys.exit(f"paged_decode_sweep needs the chip, found {dev.platform}")
+
+    def reference(q, kpool, vpool, tables, pos, ck, cv, window):
+        """Layer 0 by gather, in float32: (SLOTS, 1, H, D)."""
+        h, kvh = q.shape[2], kpool.shape[1]
+        f32 = jnp.float32
+        k = kpool[0][:, tables].reshape(kvh, SLOTS, -1, D).astype(f32)
+        v = vpool[0][:, tables].reshape(kvh, SLOTS, -1, D).astype(f32)
+        k = jnp.concatenate([k, ck.astype(f32).transpose(2, 0, 1, 3)], axis=2)
+        v = jnp.concatenate([v, cv.astype(f32).transpose(2, 0, 1, 3)], axis=2)
+        slot = jnp.arange(k.shape[2])[None, :]
+        live = (slot < pos) | (slot == k.shape[2] - 1)
+        if window:
+            live &= (slot > pos - window) | (slot == k.shape[2] - 1)
+        qg = q.astype(f32).reshape(SLOTS, kvh, h // kvh, D)
+        s = jnp.einsum("bhgd,hbkd->bhgk", qg, k, precision="highest") * D ** -0.5
+        s = jnp.where(live[:, None, None, :], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("bhgk,hbkd->bhgd", p, v,
+                          precision="highest").reshape(SLOTS, 1, h, D)
+
+    def bench(pool, h, window, mb, live, ctx):
+        kvh = pool.shape[1]
+        rng = np.random.default_rng(live * 10007 + ctx)
+        q = jnp.asarray(rng.standard_normal((SLOTS, 1, h, D)) * 0.1,
+                        jnp.bfloat16)
+        ck = jnp.asarray(rng.standard_normal((SLOTS, 1, kvh, D)), jnp.bfloat16)
+        pages = -(-ctx // PAGE)
+        tables = np.zeros((SLOTS, mb), np.int32)
+        pos = np.full((SLOTS, 1), -1, np.int32)
+        for s in range(live):
+            # a slot's pages lie scattered through the pool, as in a server
+            tables[s, :pages] = 1 + rng.permutation(POOL_PAGES - 1)[:pages]
+            pos[s, 0] = ctx
+
+        @jax.jit
+        def step(q, kpool, vpool, tables, pos, ck):
+            def layer(i, x):
+                return paged_ragged_attention(
+                    x, kpool, vpool, tables, pos, ck, ck,
+                    layer=i % LAYERS, window=window)
+            return jax.lax.fori_loop(0, args.iters * LAYERS, layer, q)
+
+        a = (q, pool, pool, jnp.asarray(tables), jnp.asarray(pos), ck)
+        step(*a).block_until_ready()
+        gap = None
+        if live:
+            one = paged_ragged_attention(*a, ck, layer=0, window=window)
+            ref = reference(*a, ck, window)
+            gap = float(jnp.max(jnp.abs(one.astype(jnp.float32) - ref)[:live]))
+        times = []
+        for _ in range(5):
+            t = time.perf_counter()
+            step(*a).block_until_ready()
+            times.append(time.perf_counter() - t)
+        us = min(times) / (args.iters * LAYERS) * 1e6
+        lo = max(ctx - window + 1, 0) if window else 0
+        moved = live * (-(-ctx // PAGE) - lo // PAGE)
+        nbytes = moved * 2 * kvh * PAGE * D * 2
+        return {"us_a_layer": round(us, 2), "pages_moved": moved,
+                "max_gap_to_gather": gap,
+                "share_of_hbm_peak_pct": round(
+                    100 * nbytes / (us * 1e-6) / HBM_BYTES_PER_S, 2)}
+
+    for name, (h, kvh, window, mb) in CONFIGS.items():
+        pool = jax.random.normal(jax.random.PRNGKey(kvh),
+                                 (LAYERS, kvh, POOL_PAGES, PAGE, D),
+                                 jnp.bfloat16)
+        for live in LIVE:
+            for ctx in CONTEXTS if live else (0,):
+                if ctx > mb * PAGE:
+                    continue
+                ctx = min(ctx, mb * PAGE - 1)     # the new token needs a slot
+                row = {"label": args.label, "config": name, "live": live,
+                       "context": ctx, "device": dev.device_kind,
+                       **bench(pool, h, window, mb, live, ctx)}
+                print(json.dumps(row), flush=True)
+
+
+if __name__ == "__main__":
+    main()

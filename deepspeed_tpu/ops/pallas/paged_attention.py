@@ -6,23 +6,32 @@ KV lives scattered across fixed-size pages of a global pool; attention reads
 the pages IN PLACE via the block table — the (B, S_max, KVH, D) gathered
 cache the XLA fallback materializes never exists.
 
-TPU mapping: the block table and per-sequence page bounds are
-scalar-prefetched (``pltpu.PrefetchScalarGridSpec``) so the kernel's
-BlockSpec index_map can chase page indices while the pipeline
-double-buffers page fetches. Grid = (batch, kv_head, page); online-softmax
-state (m, l, acc) lives in VMEM scratch carried across the page dimension.
-GQA runs the q-head group of each kv head as rows of one tile.
-
-One kernel covers BOTH decode (C == 1) and chunked prefill (C > 1) — the
+One algorithm covers BOTH decode (C == 1) and chunked prefill (C > 1) — the
 Dynamic-SplitFuse unification: queries are rows of a (C*G, D) tile whose
 per-row absolute positions ride in as an int32 block, so per-row causal
 masking, sliding windows, and ALiBi (reference blocked-flash handles these
-in-kernel too) need no gathered bias tensors. Pages wholly outside
-[min_pos - window, max_pos] are skipped by the grid predicate.
+in-kernel too) need no gathered bias tensors; online-softmax state
+(m, l, acc) lives in VMEM scratch carried across a sequence's pages; the
+block table and per-sequence page bounds are scalar-prefetched
+(``pltpu.PrefetchScalarGridSpec``). GQA runs the q-head group of each kv
+head as rows of one tile.
+
+It wants different tiles at different widths, and ``_tiling`` picks them
+from static shapes against one VMEM budget:
+
+- few query rows (a decode or speculation step): the work is the bytes of
+  the context, so the iteration space is LIVE PAGES x ALL LOCAL KV HEADS
+  (``_live_pages_kernel``). Grid = (batch,); a slot walks its own pages
+  ``[lo, cs)`` in groups, each page copied once across heads — the whole
+  ``(KVH, page, D)`` block ``kv_commit.py`` also moves — by hand into a
+  double buffer while the group before it computes. A frozen slot copies
+  nothing and a step's cost follows the context it reads.
+- many rows (a prefill chunk): one (slot, kv head, page group) a grid step
+  with BlockSpec index maps chasing the page ids (``_head_step_kernel``);
+  a step there is bound by its (R, K*bs) score tile, not by its count.
 """
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -31,11 +40,90 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
+# What one step's tiles may take of a core's VMEM (the compiler's scoped
+# default is 16 MiB on v5e; the rest is its own temporaries).
+_VMEM_BUDGET = 12 << 20
+_MXU_ROWS = 128         # from here on one head's product fills the MXU
+_HEAD_STEP_PAGES = 8    # pages a (slot, head) step groups: 1,024 keys
+_FOLD_PAGES = 4         # pages a folded step groups: 8 timed 2-15% slower at
+# mistral's 8 KV heads (a short context computes the group's dead keys) and
+# does not fit OLMoE's 16 (benchmarks/paged_decode_sweep.py, PERF.md PR 29)
 
-def _paged_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,   # scalar prefetch
-                  q_ref, *rest,                   # K k-pages, K v-pages, ...
-                  page_size, grid_steps, pages_per_step, scale, softcap,
-                  use_alibi):
+
+def _tiling(rows, kvh, mb, page_size, d, itemsize):
+    """(fold all local kv heads into a step?, pages a step). A step folds
+    while a head's rows leave the MXU mostly empty — its cost is then the
+    context's bytes and the count of steps — and its tiles fit the budget."""
+    keys = _FOLD_PAGES * page_size
+    folded = (kvh * rows * keys * (4 + 4 + 2)        # s, exp(s - m), its cast
+              + 2 * 2 * kvh * keys * d * itemsize    # K and V, double-buffered
+              + kvh * rows * d * (4 + 2 * 2 * itemsize))   # acc; q, out x 2
+    fold = rows < _MXU_ROWS and folded <= _VMEM_BUDGET
+    return fold, min(_FOLD_PAGES if fold else _HEAD_STEP_PAGES, mb)
+
+
+def _scores(q, k, key_pos, pos, win, slope, *, scale, softcap):
+    """Masked-score inputs of one key block: ``s`` f32 ([KVH,] R, T) and the
+    (R, T) mask of causality and the window. q ([KVH,] R, D), k ([KVH,] T,
+    D); key_pos (R, T) or (1, T); pos (R, 1); slope ([KVH,] R, 1) or None."""
+    lead = tuple(range(q.ndim - 2))
+    if k.shape[-2] == 1:
+        # a decode step's chunk holds ONE key. Mosaic lowers q @ k.T
+        # with a one-row k through a vector.broadcast that carries the
+        # f32 result type on the bf16 q tile, which its own verifier
+        # refuses (R = GQA group > 1 rows, bf16; v5e, jax 0.9.0). The
+        # same products in f32 on the VPU are exact and cost R*D
+        s = jnp.sum(q.astype(jnp.float32) * k.astype(jnp.float32),
+                    axis=q.ndim - 1, keepdims=True)
+    else:
+        s = jax.lax.dot_general(
+            q, k, (((q.ndim - 1,), (k.ndim - 1,)), (lead, lead)),
+            preferred_element_type=jnp.float32)
+    if scale != 1.0:
+        s = s * scale
+    if slope is not None:
+        rel = (key_pos - pos).astype(jnp.float32)
+        s = s + slope * rel.reshape((1,) * len(lead) + rel.shape)
+    if softcap:
+        s = softcap * jnp.tanh(s / softcap)
+    mask = key_pos <= pos
+    mask = jnp.logical_and(mask,
+                           jnp.logical_or(win <= 0, key_pos > pos - win))
+    return s, mask
+
+
+def _online_update(m_ref, l_ref, acc_ref, s, mask, v):
+    """Fold one key block into the running softmax: s ([KVH,] R, T), mask
+    (R, T), v ([KVH,] T, D)."""
+    lead = tuple(range(s.ndim - 2))
+    s = jnp.where(mask.reshape((1,) * len(lead) + mask.shape), s, NEG_INF)
+    m_prev = m_ref[...]
+    l_prev = l_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=s.ndim - 1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_ref[...] = l_prev * alpha + jnp.sum(p, axis=s.ndim - 1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((s.ndim - 1,), (v.ndim - 2,)), (lead, lead)),
+        preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
+
+
+def _chunk_and_finalize(m_ref, l_ref, acc_ref, q, ck, cv, kpos, pos, win,
+                        slope, *, scale, softcap):
+    """The chunk's own keys as a last virtual page, then acc / l."""
+    s, mask = _scores(q, ck, kpos, pos, win, slope, scale=scale,
+                      softcap=softcap)
+    mask = jnp.logical_and(mask, kpos >= 0)               # pad keys dead
+    _online_update(m_ref, l_ref, acc_ref, s, mask, cv)
+    l_safe = jnp.where(l_ref[...] == 0.0, 1.0, l_ref[...])
+    return acc_ref[...] / l_safe
+
+
+def _head_step_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,   # scalar prefetch
+                      q_ref, *rest,               # K k-pages, K v-pages, ...
+                      page_size, grid_steps, pages_per_step, scale, softcap,
+                      use_alibi):
     K = pages_per_step
     k_refs = rest[0:K]
     v_refs = rest[K:2 * K]
@@ -55,44 +143,8 @@ def _paged_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,   # scalar prefetch
     # window patterns arrive as traced scan elements, so the window cannot
     # be a compile-time constant)
     pos = pos_ref[0, 0].reshape(-1, 1)                    # (R, 1) int32
-
-    def online_update(s, mask, v):
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]
-        l_prev = l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
-
-    def scores(q, k, key_pos):
-        if k.shape[0] == 1:
-            # a decode step's chunk holds ONE key. Mosaic lowers q @ k.T
-            # with a one-row k through a vector.broadcast that carries the
-            # f32 result type on the bf16 q tile, which its own verifier
-            # refuses (R = GQA group > 1 rows, bf16; v5e, jax 0.9.0). The
-            # same products in f32 on the VPU are exact and cost R*D
-            s = jnp.sum(q.astype(jnp.float32) * k.astype(jnp.float32),
-                        axis=1, keepdims=True)
-        else:
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-        if scale != 1.0:
-            s = s * scale
-        if use_alibi:
-            # slope block is already this kv-head's (1, 1, R) slice
-            s = s + slope_ref[0, 0].reshape(-1, 1) * (
-                key_pos - pos).astype(jnp.float32)
-        if softcap:
-            s = softcap * jnp.tanh(s / softcap)
-        mask = key_pos <= pos
-        mask = jnp.logical_and(mask,
-                               jnp.logical_or(win <= 0, key_pos > pos - win))
-        return s, mask
+    # slope block is already this kv-head's (1, 1, R) slice
+    slope = slope_ref[0, 0].reshape(-1, 1) if use_alibi else None
 
     # pool slots >= cs (the current chunk's first position) are stale: the
     # chunk's own KV arrives as separate blocks below, NOT via the pool —
@@ -114,32 +166,113 @@ def _paged_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,   # scalar prefetch
         # staleness mask kills them
         slot = j * K * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (q.shape[0], K * page_size), 1)
-        s, mask = scores(q, k, slot)
+        s, mask = _scores(q, k, slot, pos, win, slope, scale=scale,
+                          softcap=softcap)
         mask = jnp.logical_and(mask, slot < cs_ref[b])
-        online_update(s, mask, v)
+        _online_update(m_ref, l_ref, acc_ref, s, mask, v)
 
     @pl.when(j == grid_steps - 1)
-    def _chunk_and_finalize():
-        q = q_ref[0, 0]
-        ck = ck_ref[0, 0]                                 # (C, D)
-        cv = cv_ref[0, 0]
-        kpos = cpos_ref[0, 0].reshape(1, -1)              # (1, C); -1 = pad
-        s, mask = scores(q, ck, kpos)
-        mask = jnp.logical_and(mask, kpos >= 0)           # pad keys dead
-        online_update(s, mask, cv)
-        l_safe = jnp.where(l_ref[...] == 0.0, 1.0, l_ref[...])
-        o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+    def _finalize():
+        out = _chunk_and_finalize(
+            m_ref, l_ref, acc_ref, q_ref[0, 0], ck_ref[0, 0], cv_ref[0, 0],
+            cpos_ref[0, 0].reshape(1, -1),                # (1, C); -1 = pad
+            pos, win, slope, scale=scale, softcap=softcap)
+        o_ref[0, 0] = out.astype(o_ref.dtype)
+
+
+def _live_pages_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,  # scalar prefetch
+                       q_ref, k_hbm, v_hbm, pos_ref, slope_ref,
+                       ck_ref, cv_ref, cpos_ref,
+                       o_ref,
+                       kbuf, vbuf, sems, m_ref, l_ref, acc_ref,
+                       *, page_size, pages_per_step, scale, softcap,
+                       use_alibi):
+    """One slot a grid step, every local kv head at once: walk the slot's
+    live pages [lo, cs) in groups of K, group g+1's pages on their way into
+    the other half of (kbuf, vbuf) while group g computes."""
+    K = pages_per_step
+    span = K * page_size
+    b = pl.program_id(0)
+    cs = cs_ref[b]
+    lo = lo_ref[b]
+    first = lo // span
+    end = (cs + span - 1) // span          # cs = 0 (a frozen slot): no group
+
+    def page_copies(g, half, start):
+        # a page is copied iff it holds a slot of [lo, cs): whole
+        # (KVH, page, D) blocks, never part of a page. A group's dead tail
+        # keeps what the buffer held before — finite pool data or the zeros
+        # below — under keys the staleness mask kills.
+        for t in range(K):
+            page = g * K + t
+
+            @pl.when(jnp.logical_and(page * page_size < cs,
+                                     (page + 1) * page_size > lo))
+            def _():
+                src = bt_ref[b, page] if start else 0
+                rows = pl.ds(t * page_size, page_size)
+                for hbm, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                    dma = pltpu.make_async_copy(
+                        hbm.at[lyr_ref[0], :, src], buf.at[half, :, rows],
+                        sems.at[which, half])
+                    if start:
+                        dma.start()
+                    else:
+                        dma.wait()
+
+    @pl.when(b == 0)
+    def _clean():
+        # 0 x NaN is NaN: a dead key's probability is exactly 0, so what
+        # sits under it in V must be finite
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    @pl.when(first < end)
+    def _first():
+        page_copies(first, 0, True)
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    win = win_ref[0]
+    q = q_ref[0]                                          # (KVH, R, D)
+    pos = pos_ref[0]                                      # (R, 1) int32
+    slope = slope_ref[...] if use_alibi else None         # (KVH, R, 1)
+
+    def group(g, carry):
+        half = (g - first) % 2
+
+        @pl.when(g + 1 < end)
+        def _next():
+            page_copies(g + 1, 1 - half, True)
+
+        page_copies(g, half, False)
+        slot = g * span + jax.lax.broadcasted_iota(
+            jnp.int32, (q.shape[1], span), 1)
+        s, mask = _scores(q, kbuf[half], slot, pos, win, slope, scale=scale,
+                          softcap=softcap)
+        mask = jnp.logical_and(mask, slot < cs)           # stale pool slots
+        _online_update(m_ref, l_ref, acc_ref, s, mask, vbuf[half])
+        return carry
+
+    jax.lax.fori_loop(first, end, group, 0)
+
+    out = _chunk_and_finalize(
+        m_ref, l_ref, acc_ref, q, ck_ref[0], cv_ref[0],
+        cpos_ref[0, 0].reshape(1, -1), pos, win, slope, scale=scale,
+        softcap=softcap)
+    o_ref[0] = out.astype(o_ref.dtype)
 
 
 def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
                            chunk_k=None, chunk_v=None, *, layer=None,
                            scale=None, window=0, alibi_slopes=None,
-                           softcap=0.0, pages_per_step=None):
+                           softcap=0.0):
     """Unified paged attention for decode AND chunked prefill.
 
     q: (B, C, H, D) — C query tokens per sequence (1 = decode);
     kpool/vpool: the FULL (L, KVH, NB, bs, D) kv-head-major page pools with
-    ``layer`` the (traced) layer index — the kernel's BlockSpec chases
+    ``layer`` the (traced) layer index — the kernel chases
     (layer, head, page) directly, so no per-layer pool slice is ever
     materialized. A 4-D (KVH, NB, bs, D) single-layer pool with
     ``layer=None`` is also accepted. The pools are READ-ONLY here and must
@@ -174,11 +307,16 @@ def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
         window = 0
     softcap = float(softcap or 0.0)
 
+    fold, K = _tiling(rows, kvh, mb, page_size, d, kpool.dtype.itemsize)
+    # a folded step broadcasts per-row vectors against (KVH, R, T) scores:
+    # it takes them as columns, a head step as rows of a (1, R) block
+    col = (rows, 1) if fold else (1, rows)
+
     # (B, C, H, D) → (B, KVH, C*G, D): row r = c*G + g
     qg = q.reshape(b, c, kvh, group, d).transpose(0, 2, 1, 3, 4).reshape(
         b, kvh, rows, d)
-    # per-row positions (B, 1, C*G): row r = c*G + g sits at positions[c]
-    pos_rep = jnp.repeat(positions, group, axis=1).reshape(b, 1, rows)
+    # per-row positions: row r = c*G + g sits at positions[c]
+    pos_rep = jnp.repeat(positions, group, axis=1).reshape(b, *col)
     valid = positions >= 0
     win_arr = jnp.asarray(window, jnp.int32).reshape(1)
     minpos = jnp.min(jnp.where(valid, positions, 1 << 30), axis=1)
@@ -204,77 +342,113 @@ def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
     use_alibi = alibi_slopes is not None
     if use_alibi:
         sl = jnp.asarray(alibi_slopes, jnp.float32).reshape(kvh, group)
-        slopes = jnp.tile(sl, (1, c)).reshape(kvh, 1, rows)
+        slopes = jnp.tile(sl, (1, c)).reshape(kvh, *col)
     else:
-        slopes = jnp.zeros((kvh, 1, rows), jnp.float32)
+        slopes = jnp.zeros((kvh, *col), jnp.float32)
 
-    if pages_per_step is None:
-        pages_per_step = int(os.environ.get("DS_TPU_PAGES_PER_STEP", "8"))
-    K = max(1, min(int(pages_per_step), mb))
-    grid_steps = -(-mb // K)
-    grid = (b, kvh, grid_steps)
+    kernel_args = dict(page_size=page_size, pages_per_step=K, scale=scale,
+                       softcap=softcap, use_alibi=use_alibi)
+    scalars = (lyr, block_tables, chunk_start, lo, win_arr)
+    scratch = [pltpu.VMEM((rows, 1), jnp.float32),
+               pltpu.VMEM((rows, 1), jnp.float32),
+               pltpu.VMEM((rows, d), jnp.float32)]
 
-    def q_map(bi, hi, ji, lyr_, bt, lens, lo_, w_):
-        return (bi, hi, 0, 0)
+    if fold:
+        def slot_map(bi, *_):
+            return (bi, 0, 0, 0)
 
-    def kv_map_t(t):
-        # t-th page of this grid step's K-page group. The page lookup is
-        # clamped into the sequence's LIVE range [lo/bs, ceil(cs/bs)-1]:
-        # steps outside it all map to the same page, and Pallas elides the
-        # DMA when consecutive grid steps index an identical block — dead
-        # pages (beyond the sequence, or below the sliding window) cost no
-        # HBM traffic. Correctness is unaffected: the kernel masks by the
-        # LOGICAL slot (ji*K+t), not the fetched page.
-        def kv_map(bi, hi, ji, lyr_, bt, cs, lo_, w_):
-            last = jnp.maximum((cs[bi] + page_size - 1) // page_size - 1, 0)
-            jt = jnp.clip(ji * K + t, lo_[bi] // page_size, last)
-            return (lyr_[0], hi, bt[bi, jt], 0, 0)
-        return kv_map
+        def row_map(bi, *_):
+            return (bi, 0, 0)
 
-    def pos_map(bi, hi, ji, lyr_, bt, lens, lo_, w_):
-        return (bi, 0, 0)
+        def all_map(bi, *_):
+            return (0, 0, 0)
 
-    def slope_map(bi, hi, ji, lyr_, bt, lens, lo_, w_):
-        return (hi, 0, 0)
+        out = pl.pallas_call(
+            functools.partial(_live_pages_kernel, **kernel_args),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=5,
+                grid=(b,),
+                in_specs=[
+                    pl.BlockSpec((1, kvh, rows, d), slot_map),
+                    pl.BlockSpec(memory_space=pl.ANY),         # k pool
+                    pl.BlockSpec(memory_space=pl.ANY),         # v pool
+                    pl.BlockSpec((1, rows, 1), row_map),
+                    pl.BlockSpec((kvh, rows, 1), all_map),
+                    pl.BlockSpec((1, kvh, c, d), slot_map),
+                    pl.BlockSpec((1, kvh, c, d), slot_map),
+                    pl.BlockSpec((1, 1, c), row_map),
+                ],
+                out_specs=pl.BlockSpec((1, kvh, rows, d), slot_map),
+                scratch_shapes=[
+                    pltpu.VMEM((2, kvh, K * page_size, d), kpool.dtype),
+                    pltpu.VMEM((2, kvh, K * page_size, d), vpool.dtype),
+                    pltpu.SemaphoreType.DMA((2, 2)),
+                    *[pltpu.VMEM((kvh, *s.shape), s.dtype) for s in scratch],
+                ],
+            ),
+            out_shape=jax.ShapeDtypeStruct((b, kvh, rows, d), q.dtype),
+            name=f"paged_attn_c{c}",
+            interpret=jax.default_backend() != "tpu",
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+        )(*scalars, qg, kpool, vpool, pos_rep, slopes, ckg, cvg, cpos)
+    else:
+        grid_steps = -(-mb // K)
 
-    def chunk_map(bi, hi, ji, lyr_, bt, lens, lo_, w_):
-        return (bi, hi, 0, 0)
+        def q_map(bi, hi, ji, lyr_, bt, lens, lo_, w_):
+            return (bi, hi, 0, 0)
 
-    page_spec = [pl.BlockSpec((1, 1, 1, page_size, d), kv_map_t(t))
-                 for t in range(K)]
-    out = pl.pallas_call(
-        functools.partial(_paged_kernel, page_size=page_size,
-                          grid_steps=grid_steps, pages_per_step=K,
-                          scale=scale, softcap=softcap,
-                          use_alibi=use_alibi),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, rows, d), q_map),
-                *page_spec,                                    # K k-pages
-                *page_spec,                                    # K v-pages
-                pl.BlockSpec((1, 1, rows), pos_map),
-                pl.BlockSpec((1, 1, rows), slope_map),
-                pl.BlockSpec((1, 1, c, d), chunk_map),
-                pl.BlockSpec((1, 1, c, d), chunk_map),
-                pl.BlockSpec((1, 1, c), pos_map),
-            ],
-            out_specs=pl.BlockSpec((1, 1, rows, d), q_map),
-            scratch_shapes=[
-                pltpu.VMEM((rows, 1), jnp.float32),
-                pltpu.VMEM((rows, 1), jnp.float32),
-                pltpu.VMEM((rows, d), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, kvh, rows, d), q.dtype),
-        name=f"paged_attn_c{c}",
-        interpret=jax.default_backend() != "tpu",
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-    )(lyr, block_tables, chunk_start, lo, win_arr, qg,
-      *([kpool] * K), *([vpool] * K), pos_rep,
-      slopes, ckg, cvg, cpos)
+        def kv_map_t(t):
+            # t-th page of this grid step's K-page group. The page lookup is
+            # clamped into the sequence's LIVE range [lo/bs, ceil(cs/bs)-1]:
+            # steps outside it all map to the same page, and Pallas elides the
+            # DMA when consecutive grid steps index an identical block — dead
+            # pages (beyond the sequence, or below the sliding window) cost no
+            # HBM traffic. Correctness is unaffected: the kernel masks by the
+            # LOGICAL slot (ji*K+t), not the fetched page.
+            def kv_map(bi, hi, ji, lyr_, bt, cs, lo_, w_):
+                last = jnp.maximum((cs[bi] + page_size - 1) // page_size - 1, 0)
+                jt = jnp.clip(ji * K + t, lo_[bi] // page_size, last)
+                return (lyr_[0], hi, bt[bi, jt], 0, 0)
+            return kv_map
+
+        def pos_map(bi, hi, ji, lyr_, bt, lens, lo_, w_):
+            return (bi, 0, 0)
+
+        def slope_map(bi, hi, ji, lyr_, bt, lens, lo_, w_):
+            return (hi, 0, 0)
+
+        def chunk_map(bi, hi, ji, lyr_, bt, lens, lo_, w_):
+            return (bi, hi, 0, 0)
+
+        page_spec = [pl.BlockSpec((1, 1, 1, page_size, d), kv_map_t(t))
+                     for t in range(K)]
+        out = pl.pallas_call(
+            functools.partial(_head_step_kernel, grid_steps=grid_steps,
+                              **kernel_args),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=5,
+                grid=(b, kvh, grid_steps),
+                in_specs=[
+                    pl.BlockSpec((1, 1, rows, d), q_map),
+                    *page_spec,                                # K k-pages
+                    *page_spec,                                # K v-pages
+                    pl.BlockSpec((1, 1, rows), pos_map),
+                    pl.BlockSpec((1, 1, rows), slope_map),
+                    pl.BlockSpec((1, 1, c, d), chunk_map),
+                    pl.BlockSpec((1, 1, c, d), chunk_map),
+                    pl.BlockSpec((1, 1, c), pos_map),
+                ],
+                out_specs=pl.BlockSpec((1, 1, rows, d), q_map),
+                scratch_shapes=scratch,
+            ),
+            out_shape=jax.ShapeDtypeStruct((b, kvh, rows, d), q.dtype),
+            name=f"paged_attn_c{c}",
+            interpret=jax.default_backend() != "tpu",
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        )(*scalars, qg, *([kpool] * K), *([vpool] * K), pos_rep, slopes,
+          ckg, cvg, cpos)
     # (B, KVH, C*G, D) → (B, C, H, D)
     return out.reshape(b, kvh, c, group, d).transpose(0, 2, 1, 3, 4).reshape(
         b, c, h, d)

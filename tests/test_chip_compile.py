@@ -14,6 +14,7 @@ itself gets no option for this.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else the compiler logs under /tmp
 
@@ -56,26 +57,66 @@ def _compile_text(fn, *shapes):
 H, KVH, D, PAGE, WINDOW = 32, 8, 128, 128, 4096
 
 
+def _paged_step_shapes(sharding, h, kvh, chunk, table, b=16, layers=4,
+                       pages=416):
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    pool = sds((layers, kvh, pages, PAGE, D))
+    return (sds((b, chunk, h, D)), pool, pool, sds((b, table), jnp.int32),
+            sds((b, chunk), jnp.int32), sds((b, chunk, kvh, D)),
+            sds((b, chunk, kvh, D)), sds((), jnp.int32))
+
+
+def _paged_step(q, kpool, vpool, tables, positions, ck, cv, layer):
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_ragged_attention
+    return paged_ragged_attention(q, kpool, vpool, tables, positions, ck, cv,
+                                  layer=layer, window=WINDOW)
+
+
 @pytest.mark.parametrize("chunk", [1, 3, 128],
                          ids=["decode", "spec-verify", "prefill"])
 def test_paged_attention_compiles_at_serve_widths(one_chip, as_tpu, chunk):
-    from deepspeed_tpu.ops.pallas.paged_attention import paged_ragged_attention
-    b, layers, pages, table = 8, 4, 64, 8
-    dt = jnp.bfloat16
-
-    def sds(shape, dtype=dt):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    def step(q, kpool, vpool, tables, positions, ck, cv, layer):
-        return paged_ragged_attention(q, kpool, vpool, tables, positions,
-                                      ck, cv, layer=layer, window=WINDOW)
-
-    pool = sds((layers, KVH, pages, PAGE, D))
-    text = _compile_text(
-        step, sds((b, chunk, H, D)), pool, pool, sds((b, table), jnp.int32),
-        sds((b, chunk), jnp.int32), sds((b, chunk, KVH, D)),
-        sds((b, chunk, KVH, D)), sds((), jnp.int32))
+    text = _compile_text(_paged_step, *_paged_step_shapes(
+        one_chip, H, KVH, chunk, table=8, b=8, pages=64))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("h,kvh,table", [(32, 8, 32), (32, 8, 64),
+                                         (16, 16, 32)],
+                         ids=["mistral-mb32", "mistral-mb64", "olmoe-mb32"])
+def test_narrow_paged_attention_compiles_at_the_cells_shapes(one_chip, as_tpu,
+                                                             h, kvh, table):
+    """The decode step's kernel at the benchmark's shapes (16 slots, 416
+    pages, the table widths the cells' contexts bucket to): every local KV
+    head folded into one grid step a slot — the batched products over heads
+    with 4 (GQA) or 1 (MHA) query rows, the hand-made page copies and the
+    double buffer (4.2 / 8.4 MB) pass Mosaic's verifier and its VMEM."""
+    text = _compile_text(_paged_step, *_paged_step_shapes(
+        one_chip, h, kvh, 1, table))
+    assert len(re.findall(r"%paged_attn_c1\S* = ", text)) == 1
+    assert "tpu_custom_call" in text
+
+
+# sha256 of the traced program (wrapper and kernel, no source locations) of
+# the chunked-prefill attention at the mistral cells' shapes, as PR 28 left
+# it (`git archive 0dbf65d`): the Mosaic module's own text carries file
+# lines and function names, the jaxpr does not
+WIDE_PAGED_JAXPR = \
+    "824ff7285a669774b71d613999687011d075b02455a375935aef19a2de154129"
+
+
+def test_wide_paged_attention_is_the_program_it_was():
+    """PR 29 rebuilt the narrow step's tiling and must not move the wide
+    one: at C = 128 the shape rule keeps one (slot, KV head, 8 pages) a grid
+    step, and what is traced there — index maps, kernel body, operands — is
+    PR 28's to the character. A change that means to move it re-pins."""
+    import hashlib
+    from deepspeed_tpu.ops.pallas.paged_attention import _tiling
+    assert _tiling(128 * (H // KVH), KVH, 64, PAGE, D, 2) == (False, 8)
+    jaxpr = jax.make_jaxpr(_paged_step)(*_paged_step_shapes(
+        None, H, KVH, 128, 64))
+    assert hashlib.sha256(str(jaxpr).encode()).hexdigest() == WIDE_PAGED_JAXPR
 
 
 @pytest.mark.parametrize("chunk", [1, 3, 128],

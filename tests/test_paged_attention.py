@@ -1,5 +1,7 @@
 """Pallas paged decode attention vs the XLA gather reference."""
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -222,3 +224,98 @@ def test_paged_ragged_chunk_beside_pool(c, h, kvh, dtype):
     tol = 2e-2 if dtype == jnp.bfloat16 else 3e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), rtol=tol, atol=tol)
+
+
+# ---- the narrow path: live pages x all KV heads a step --------------------
+
+_NARROW_HEADS = [(32, 8), (16, 16), (4, 1)]
+_NARROW_CASES = [
+    (heads, c, chunk, feature)
+    for heads in _NARROW_HEADS for c in (1, 3) for chunk in (True, False)
+    for feature in ("plain", "window")
+] + [
+    (heads, c, True, feature)
+    for heads in _NARROW_HEADS for c in (1, 3)
+    for feature in ("alibi", "softcap", "layer")
+]
+
+
+@pytest.mark.parametrize(
+    "heads,c,chunk,feature", _NARROW_CASES,
+    ids=[f"h{h}kv{k}-c{c}-{'chunk' if ch else 'pool'}-{f}"
+         for (h, k), c, ch, f in _NARROW_CASES])
+def test_narrow_steps_walk_live_pages(heads, c, chunk, feature):
+    """A decode or speculation step folds every KV head into one grid step
+    a slot and copies the slot's live pages by hand: against the gather
+    reference over slots that are frozen (no context, every row a pad), end
+    exactly on a page, end mid-page (one row a pad at C = 3), span more
+    than one group of pages, and, under a window, start past their first
+    group (``lo > 0``)."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    from deepspeed_tpu.models.layers import alibi_slopes
+    h, kvh = heads
+    d, bs, mb, layers = 32, 16, 8, 3
+    assert pa._tiling(c * h // kvh, kvh, mb, bs, d, 4) == (True, 4)
+    ctx = [0, 2 * bs, bs + 5, 5 * bs + 3, 7 * bs - c]
+    b, nb = len(ctx), 1 + sum(-(-(x + c) // bs) for x in ctx)
+    rng = np.random.default_rng(7)
+
+    def rand(*shape, scale=1.0):
+        return jnp.asarray(rng.standard_normal(shape) * scale, jnp.float32)
+
+    q = rand(b, c, h, d, scale=0.3)
+    kpool, vpool = rand(layers, kvh, nb, bs, d), rand(layers, kvh, nb, bs, d)
+    ck, cv = rand(b, c, kvh, d), rand(b, c, kvh, d)
+    tables = np.zeros((b, mb), np.int32)
+    perm, at = 1 + rng.permutation(nb - 1), 0
+    positions = np.full((b, c), -1, np.int32)
+    for s, x in enumerate(ctx):
+        if not x:
+            continue                                  # frozen: page 0, pads
+        need = -(-(x + c) // bs)
+        tables[s, :need] = perm[at:at + need]
+        at += need
+        positions[s] = x + np.arange(c)
+    if c > 1:
+        positions[2, -1] = -1                         # a decoding row's pad
+    tables, positions = jnp.asarray(tables), jnp.asarray(positions)
+    kw = {"window": 2 * bs + 3} if feature == "window" else {}
+    if feature == "alibi":
+        kw["alibi_slopes"] = alibi_slopes(h)
+    if feature == "softcap":
+        kw.update(softcap=20.0, scale=0.3)
+    lyr = 2 if feature == "layer" else 0
+
+    if feature == "layer":
+        run = jax.jit(lambda i, *a: pa.paged_ragged_attention(*a, layer=i))
+        call = functools.partial(run, jnp.asarray(lyr, jnp.int32))
+    else:
+        call = functools.partial(pa.paged_ragged_attention, layer=lyr, **kw)
+    out = call(q, kpool, vpool, tables, positions,
+               *((ck, cv) if chunk else ()))
+
+    kfull, vfull = kpool[lyr], vpool[lyr]
+    if chunk:                       # the reference reads the chunk from a pool
+        safe = jnp.maximum(positions, 0)
+        blk = jnp.take_along_axis(tables, safe // bs, axis=1)
+        blk = jnp.where(positions >= 0, blk, 0)       # pads land in trash page 0
+        kfull = kfull.at[:, blk, safe % bs].set(ck.transpose(2, 0, 1, 3))
+        vfull = vfull.at[:, blk, safe % bs].set(cv.transpose(2, 0, 1, 3))
+    ref = _ragged_reference(q, kfull, vfull, tables, positions, **kw)
+    valid = np.asarray(positions) >= 0
+    assert valid[0].sum() == 0 and valid[1:].all(axis=1).sum() >= 3
+    np.testing.assert_allclose(np.asarray(out)[valid], np.asarray(ref)[valid],
+                               rtol=3e-5, atol=3e-5)
+
+
+def test_wide_chunks_keep_one_head_a_step():
+    """The tiling follows from shapes: a prefill chunk's rows fill the MXU,
+    so it keeps one (slot, KV head, 8 pages) a grid step at the serve
+    configurations' widths; a decode or speculation step folds its heads,
+    4 pages a group."""
+    from deepspeed_tpu.ops.pallas.paged_attention import _tiling
+    for kvh, group in ((8, 4), (16, 1), (2, 4)):          # mistral, OLMoE, tp=4
+        assert _tiling(128 * group, kvh, 64, 128, 128, 2) == (False, 8)
+        for c in (1, 3, 8):
+            assert _tiling(c * group, kvh, 64, 128, 128, 2) == (True, 4)
+    assert _tiling(4, 8, 2, 128, 128, 2) == (True, 2)     # a table of 2 pages
