@@ -1,12 +1,16 @@
-"""The narrow paged-attention kernel alone, on the chip.
+"""The paged-attention kernels alone, on the chip.
 
     python benchmarks/paged_decode_sweep.py [--root DIR] [--label NAME]
+                                            [--chunk C] [--configs A,B]
 
-One decode step's attention (C = 1) at the serve configurations' shapes
+One step's attention (``--chunk`` 1: the narrow kernel of a decode step;
+128: the wide one of a prefill chunk) at the serve configurations' shapes
 (mistral-7b: 32 query / 8 KV heads, window 4096, table width 64;
-OLMoE-1B-7B: 16 / 16, no window, table width 32; 16 slots, pages of 128,
+OLMoE-1B-7B: 16 / 16, no window, table width 32; Mellum2-12B-A2.5B: 32 / 4,
+its two global layers over a table of 256 and its six windowed ones over a
+ring of 10 pages behind a window of 1,024; 16 slots, pages of 128,
 head_dim 128, bf16) over live slots 1 / 3 / 16 and contexts 256 / 1,024 /
-4,096 / 7,168: microseconds a layer, the pages a layer had to move
+4,096 / 7,168 (Mellum2: to 30,000): microseconds a layer, the pages a layer had to move
 (``live x ceil((cs - lo) / page)``, K and V of every KV head) and their
 bytes over the time as a share of the chip's 819 GB/s. ``live = 0`` is what
 sixteen frozen slots cost. ``--root`` imports ``deepspeed_tpu`` from another
@@ -23,12 +27,14 @@ import time
 HBM_BYTES_PER_S = 819e9     # TPU v5e, Google Cloud documentation
 SLOTS, PAGE, D, LAYERS, POOL_PAGES = 16, 128, 128, 4, 416
 CONFIGS = {
-    # name: (query heads, kv heads, window, table width)
-    "mistral-7b": (32, 8, 4096, 64),
-    "olmoe-1b-7b": (16, 16, 0, 32),
+    # name: (query heads, kv heads, window, table width, ring, pool pages)
+    "mistral-7b": (32, 8, 4096, 64, None, POOL_PAGES),
+    "olmoe-1b-7b": (16, 16, 0, 32, None, POOL_PAGES),
+    "mellum2-full": (32, 4, 0, 256, None, 4097),
+    "mellum2-window": (32, 4, 1024, 256, 10, 161),
 }
 LIVE = (0, 1, 3, 16)
-CONTEXTS = (256, 1024, 4096, 7168)
+CONTEXTS = (256, 1024, 4096, 7168, 16384, 30000)
 
 
 def main():
@@ -37,13 +43,18 @@ def main():
         os.path.abspath(__file__)), ".."))
     ap.add_argument("--iters", type=int, default=40)
     ap.add_argument("--label", default="")
+    ap.add_argument("--chunk", type=int, default=1)
+    ap.add_argument("--configs", default=",".join(CONFIGS))
     args = ap.parse_args()
     sys.path.insert(0, os.path.abspath(args.root))
 
     import numpy as np
     import jax
     import jax.numpy as jnp
+    import inspect
     from deepspeed_tpu.ops.pallas.paged_attention import paged_ragged_attention
+    has_ring = "ring" in inspect.signature(paged_ragged_attention).parameters
+    chunk = args.chunk
 
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -68,34 +79,41 @@ def main():
         return jnp.einsum("bhgk,hbkd->bhgd", p, v,
                           precision="highest").reshape(SLOTS, 1, h, D)
 
-    def bench(pool, h, window, mb, live, ctx):
-        kvh = pool.shape[1]
+    def bench(pool, h, window, mb, live, ctx, ring):
+        kvh, pool_pages = pool.shape[1], pool.shape[2]
         rng = np.random.default_rng(live * 10007 + ctx)
-        q = jnp.asarray(rng.standard_normal((SLOTS, 1, h, D)) * 0.1,
+        q = jnp.asarray(rng.standard_normal((SLOTS, chunk, h, D)) * 0.1,
                         jnp.bfloat16)
-        ck = jnp.asarray(rng.standard_normal((SLOTS, 1, kvh, D)), jnp.bfloat16)
-        pages = -(-ctx // PAGE)
-        tables = np.zeros((SLOTS, mb), np.int32)
-        pos = np.full((SLOTS, 1), -1, np.int32)
+        ck = jnp.asarray(rng.standard_normal((SLOTS, chunk, kvh, D)),
+                         jnp.bfloat16)
+        # a ring holds min(pages, ring) pages whatever the context
+        pages = min(-(-(ctx + chunk) // PAGE), ring or mb)
+        tables = np.zeros((SLOTS, ring or mb), np.int32)
+        pos = np.full((SLOTS, chunk), -1, np.int32)
         for s in range(live):
             # a slot's pages lie scattered through the pool, as in a server
-            tables[s, :pages] = 1 + rng.permutation(POOL_PAGES - 1)[:pages]
-            pos[s, 0] = ctx
+            tables[s, :pages] = 1 + rng.permutation(pool_pages - 1)[:pages]
+            pos[s] = ctx + np.arange(chunk)
+        kw = {"ring": ring} if ring else {}
 
         @jax.jit
         def step(q, kpool, vpool, tables, pos, ck):
             def layer(i, x):
                 return paged_ragged_attention(
                     x, kpool, vpool, tables, pos, ck, ck,
-                    layer=i % LAYERS, window=window)
+                    layer=i % LAYERS, window=window, **kw)
             return jax.lax.fori_loop(0, args.iters * LAYERS, layer, q)
 
         a = (q, pool, pool, jnp.asarray(tables), jnp.asarray(pos), ck)
         step(*a).block_until_ready()
         gap = None
-        if live:
-            one = paged_ragged_attention(*a, ck, layer=0, window=window)
-            ref = reference(*a, ck, window)
+        if live and chunk == 1:
+            one = paged_ragged_attention(*a, ck, layer=0, window=window, **kw)
+            ref_tables = a[3]
+            if ring:
+                # the ring as the table it stands for: page p in slot p mod R
+                ref_tables = ref_tables[:, jnp.arange(mb) % ring]
+            ref = reference(*a[:3], ref_tables, *a[4:], ck, window)
             gap = float(jnp.max(jnp.abs(one.astype(jnp.float32) - ref)[:live]))
         times = []
         for _ in range(5):
@@ -111,18 +129,22 @@ def main():
                 "share_of_hbm_peak_pct": round(
                     100 * nbytes / (us * 1e-6) / HBM_BYTES_PER_S, 2)}
 
-    for name, (h, kvh, window, mb) in CONFIGS.items():
+    for name in args.configs.split(","):
+        h, kvh, window, mb, ring, pool_pages = CONFIGS[name]
+        if ring and not has_ring:
+            continue                  # a checkout older than the rings
         pool = jax.random.normal(jax.random.PRNGKey(kvh),
-                                 (LAYERS, kvh, POOL_PAGES, PAGE, D),
+                                 (LAYERS, kvh, pool_pages, PAGE, D),
                                  jnp.bfloat16)
         for live in LIVE:
             for ctx in CONTEXTS if live else (0,):
                 if ctx > mb * PAGE:
                     continue
-                ctx = min(ctx, mb * PAGE - 1)     # the new token needs a slot
-                row = {"label": args.label, "config": name, "live": live,
-                       "context": ctx, "device": dev.device_kind,
-                       **bench(pool, h, window, mb, live, ctx)}
+                ctx = min(ctx, mb * PAGE - chunk)  # the chunk needs its slots
+                row = {"label": args.label, "config": name, "chunk": chunk,
+                       "live": live, "context": ctx,
+                       "device": dev.device_kind,
+                       **bench(pool, h, window, mb, live, ctx, ring)}
                 print(json.dumps(row), flush=True)
 
 

@@ -31,7 +31,7 @@ from ..config import DeepSpeedInferenceConfig
 from ..sampling import sample_logits
 from .faults import (FaultReason, FrameDispatchError, LedgerEntry,
                      snapshot_ledger)
-from .kv_cache import BlockedKVCache
+from .kv_cache import BlockedKVCache, LayeredKVCache, cache_kinds
 from .model_runner import PagedModelRunner
 from .ragged_manager import DeviceSlotTable, DSStateManager
 from .scheduler import FifoPolicy
@@ -313,13 +313,27 @@ class InferenceEngineV2:
         conc = min(c.expected_concurrency or c.max_ragged_batch_size,
                    c.max_ragged_batch_size)
         num_blocks = c.num_kv_blocks or (conc * per_seq + 1)
-        self.kv = BlockedKVCache(cfg.num_layers, cfg.kv_heads, cfg.dims_per_head,
-                                 num_blocks=num_blocks, block_size=bs,
-                                 dtype=cfg.act_dtype, kv_dtype=c.kv_dtype)
+        # a stack that mixes windowed and global layers keeps a cache a
+        # kind (kv_cache.cache_kinds); None, and everything below as it
+        # always was, for a model whose layers are alike
+        kinds = cache_kinds(cfg.layer_windows(), bs, max_blocks_per_seq,
+                            c.prefill_chunk_size)
+        if kinds is None:
+            self.kv = BlockedKVCache(cfg.num_layers, cfg.kv_heads, cfg.dims_per_head,
+                                     num_blocks=num_blocks, block_size=bs,
+                                     dtype=cfg.act_dtype, kv_dtype=c.kv_dtype)
+        else:
+            from .model_implementations.archs import validate_layered_serving
+            validate_layered_serving(c, draft=draft_model is not None)
+            self.kv = LayeredKVCache(kinds, cfg.kv_heads, cfg.dims_per_head,
+                                     num_blocks=num_blocks,
+                                     slots=c.max_ragged_batch_size,
+                                     block_size=bs, dtype=cfg.act_dtype)
         # block 0 is the trash block for padded writes — never allocate it
         self.kv.reserve_trash_block()
         self.state = DSStateManager(self.kv, c.max_tracked_sequences)
-        self.runner = PagedModelRunner(self.model, bs, max_blocks_per_seq)
+        self.runner = PagedModelRunner(self.model, bs, max_blocks_per_seq,
+                                       kinds=kinds)
         self.max_blocks_per_seq = max_blocks_per_seq
         self._rng = jax.random.PRNGKey(0)
         self.draft_model = None
@@ -429,6 +443,7 @@ class InferenceEngineV2:
         retirement, or bucket growth. ``draft_params=None`` initializes
         fresh draft weights; pass the target's params for a self-draft
         (useful as the 100%-acceptance upper bound in benchmarks)."""
+        self._one_kind_only("a draft model")
         from ...module_inject import as_inference_model
         self.draft_model, converted = as_inference_model(draft_model, None)
         if draft_params is not None:
@@ -511,6 +526,16 @@ class InferenceEngineV2:
                  f"(layers={dcfg.num_layers} gamma={c.speculate_gamma})",
                  ranks=[0])
 
+    def _one_kind_only(self, what: str) -> None:
+        """A model of mixed cache kinds is served without what moves a
+        sequence's pages by one block list or rolls a step back across a
+        ring (``archs.validate_layered_serving``)."""
+        if isinstance(self.kv, LayeredKVCache):
+            raise NotImplementedError(
+                f"{what}: this model mixes windowed and global layers and "
+                "keeps a cache a kind (kv_cache.LayeredKVCache); it is "
+                "served without a draft, a swap tier or a prefix cache")
+
     def attach_kv_tier(self, tier, tag: Optional[str] = None) -> None:
         """Attach an EXTERNAL (typically shared) ``KVSwapTier`` — the
         disaggregated fleet's transport: every replica points at ONE tier
@@ -521,6 +546,7 @@ class InferenceEngineV2:
         spill keys inside the shared tier (defaults to the engine's id —
         unique per process, which is all the per-instance ``kvblk_``
         records need)."""
+        self._one_kind_only("a swap tier")
         self.kv_swap = tier
         if self.prefix_cache is not None:
             self.prefix_cache.swap = tier
@@ -672,18 +698,17 @@ class InferenceEngineV2:
         ids = np.zeros((bp, chunk), np.int32)
         positions = np.full((bp, chunk), -1, np.int32)
         valid = np.zeros((bp,), np.int32)
-        tables = np.zeros((bp, self.max_blocks_per_seq), np.int32)
         for i, s in enumerate(seqs):
             n = take[s.uid]
             toks = s.pending[:n] if s.in_prefill else s.generated[-1:]
             ids[i, :n] = toks
             positions[i, :n] = s.seen_tokens + np.arange(n)
             valid[i] = n
-            tables[i] = self.state.block_table(s, self.max_blocks_per_seq)
 
         logits, self.kv.k, self.kv.v = self.runner.run(
             chunk, self.params, jnp.asarray(ids), jnp.asarray(positions),
-            jnp.asarray(tables), jnp.asarray(valid), self.kv.k, self.kv.v)
+            self._kind_tables(seqs, self.max_blocks_per_seq, rows=bp),
+            jnp.asarray(valid), self.kv.k, self.kv.v)
         self._rng, sub = jax.random.split(self._rng)
         toks = np.asarray(sample_logits(logits, sub, greedy=greedy,
                                         temperature=temperature))
@@ -768,7 +793,7 @@ class InferenceEngineV2:
             self._rng, sub = jax.random.split(self._rng)
             toks, self.kv.k, self.kv.v = self.runner.decode_loop(
                 self.params, jnp.asarray(last_ids), jnp.asarray(lens),
-                jnp.asarray(tables), self.kv.k, self.kv.v, sub,
+                tables, self.kv.k, self.kv.v, sub,
                 jnp.float32(temperature), steps=remaining,
                 greedy=(temperature == 0.0))
             toks = np.asarray(toks)                      # (steps, B)
@@ -788,9 +813,7 @@ class InferenceEngineV2:
             for s in seqs:
                 if len(s.generated) >= max_new_tokens and not s.done:
                     s.done = True
-                    if s.blocks:
-                        self.kv.allocator.free(s.blocks)
-                        s.blocks = []
+                    self.state.release_blocks(s)
             if all(s.done for s in seqs):
                 return
             if not self.step(temperature=temperature):
@@ -809,14 +832,32 @@ class InferenceEngineV2:
         self.flush(uids)
         return outs
 
-    def _block_tables(self, seqs) -> np.ndarray:
+    def _block_tables(self, seqs):
         """Block tables sized to the pages THIS call can touch (padded to a
         power of two to bound recompiles): attention cost per decode token
         scales with table width, so a 1k-ctx model serving 192-token
         requests pays for 4 pages, not 16."""
         need = max(len(s.blocks) for s in seqs)
         mb = BlockedKVCache.bucket_width(need, self.max_blocks_per_seq)
-        return np.stack([self.state.block_table(s, mb) for s in seqs])
+        return self._kind_tables(seqs, mb)
+
+    def _kind_tables(self, seqs, width: int, rows: Optional[int] = None):
+        """``seqs``' block tables as the runner's programs take them:
+        (rows, width) of page ids, rows past the sequences all trash page
+        0; under caches by layer kind a tuple of that, the table kind's,
+        and every ring kind's (rows, ring)."""
+        def stack(width, blocks_of):
+            tables = np.zeros((rows or len(seqs), width), np.int32)
+            for i, s in enumerate(seqs):
+                tables[i] = self.state.block_table(s, width, blocks_of(s))
+            return jnp.asarray(tables)
+
+        tables = stack(width, lambda s: s.blocks)
+        if not self.state.rings:
+            return tables
+        return (tables,) + tuple(
+            stack(ring, lambda s, i=i: s.ring_blocks[i])
+            for i, (_, ring) in enumerate(self.state.rings))
 
     def generate_compiled(self, prompts: List[np.ndarray],
                           max_new_tokens: int = 32, temperature: float = 0.0,
@@ -863,7 +904,7 @@ class InferenceEngineV2:
                 jnp.asarray(prompts_p), jnp.asarray(plens),
                 jnp.full((b,), max_new_tokens, jnp.int32),
                 self.kv.k, self.kv.v, self.draft_kv.k, self.draft_kv.v,
-                jnp.asarray(tables), sub, jnp.float32(temperature),
+                tables, sub, jnp.float32(temperature),
                 chunk=chunk, wide_steps=wide_steps,
                 narrow_steps=max(0, max_new_tokens - 1),
                 greedy=temperature == 0.0, gamma=gamma)
@@ -871,7 +912,7 @@ class InferenceEngineV2:
             toks, emit, self.kv.k, self.kv.v = self.runner.mixed_loop(
                 self.params, jnp.asarray(prompts_p), jnp.asarray(plens),
                 jnp.full((b,), max_new_tokens, jnp.int32), self.kv.k, self.kv.v,
-                jnp.asarray(tables), sub, jnp.float32(temperature),
+                tables, sub, jnp.float32(temperature),
                 chunk=chunk, wide_steps=wide_steps,
                 narrow_steps=max(0, max_new_tokens - 1),
                 greedy=temperature == 0.0)
@@ -1078,7 +1119,8 @@ class InferenceEngineV2:
             raise ValueError(f"speculate needs gamma >= 1, got {gamma}")
         n_slots = frame_slots or c.max_ragged_batch_size
         check_stat_range(n_slots, c.prefill_chunk_size, steps,
-                         self.runner.stat_window or self.max_seq_len)
+                         self.runner.stat_context(self.max_seq_len,
+                                                  c.prefill_chunk_size))
         arrivals = iter(arrivals)
         if rng is None:
             self._rng, frame_rng = jax.random.split(self._rng)
@@ -1090,7 +1132,8 @@ class InferenceEngineV2:
             n_slots, prompt_width=c.prefill_chunk_size,
             table_width=1, rng=frame_rng, tp=self.tp_ctx,
             debug_replicas=c.tp_debug_replica_check,
-            n_stats=self.runner.n_stats)
+            n_stats=self.runner.n_stats,
+            rings=[ring for _, ring in self.state.rings])
         if faults is not None:
             faults.begin_serve()     # rearm the scripted schedule
         if self.prefix_cache is not None:
@@ -1122,7 +1165,8 @@ class InferenceEngineV2:
                                    adaptive=adaptive, n_slots=n_slots,
                                    kv_blocks_total=self.kv.num_blocks,
                                    tp_degree=self._config.tp,
-                                   kv_block_bytes=self.kv.block_bytes)
+                                   kv_block_bytes=self.kv.block_bytes,
+                                   layered=self.runner.kinds is not None)
         sched = FifoPolicy() if scheduler is None else scheduler
         sched.begin_serve(self)
         return self._serve_guarded(slots, arrivals, sched, steps,
@@ -1224,7 +1268,8 @@ class InferenceEngineV2:
                 width=width, steps=cur_steps,
                 live_slots=slots.live_count(),
                 kv_blocks_in_use=self.kv.num_blocks - self.kv.free_blocks,
-                arrival_ewma=ewma, queue_depth=queue_depth)
+                arrival_ewma=ewma, queue_depth=queue_depth,
+                kv_kinds=self.kv.in_use() if self.runner.kinds else None)
             return True
         if tel.enabled:
             # telemetry re-enabled mid-serve: the device vector holds
@@ -2176,9 +2221,7 @@ class InferenceEngineV2:
         # corrupt pages on the decode side's restore)
         seq.tier_blocks = 0
         seq.tier_final = seq.tier_partial = False
-        if seq.blocks:
-            self.kv.allocator.free(seq.blocks)
-            seq.blocks = []
+        self.state.release_blocks(seq)
         sched.requeue_front(req)
         self.telemetry.on_preempt(uid, req.tenant, req.pclass)
 

@@ -10,6 +10,20 @@ the Pallas paged-decode kernel (``ops/pallas/paged_attention.py``) reads each
 concatenation of its blocks; prefill chunks gather pages by block table (XLA
 gather), decode attends in place.
 
+Caches by layer kind (``cache_kinds`` / ``LayeredKVCache``): a stack that
+mixes windowed and global layers (``cfg.layer_windows()``) keeps a cache a
+KIND. The global layers' is the object above, over those layers alone: a
+sequence holds ``blocks_for(tokens)`` pages of it. A windowed kind holds a
+RING of ``R`` pages a sequence whatever its length: position ``p`` lives in
+``table[slot, (p // bs) mod R]``. THE RING'S INVARIANT, stated here once:
+with ``R * bs >= window + chunk + bs``, a step that commits positions
+``[cs, cs + C)``, ``C <= chunk``, overwrites positions ``<= cs + C - 1 -
+R * bs < cs - window``, which no query at ``cs`` or later can see, so the
+ring always holds every key a live query's window admits, and a step that is
+rolled back (``cs`` unchanged) has destroyed nothing it will read again.
+A model of one kind (no pattern: every benchmark model before Mellum2) is
+not grouped and builds ``BlockedKVCache`` as it always did.
+
 Quantized pages (``kv_dtype="int8"``): the pools become int8 with the last
 dim widened to D + 4 *scale lanes* — each (token, head) row stores its D
 quantized values followed by its f32 absmax scale bitcast into 4 int8 lanes
@@ -21,8 +35,9 @@ representation unchanged — spill/restore ships the already-int8 bytes with
 zero conversion, and per-token pool bytes drop from 4D (f32) to D + 4.
 """
 
+import dataclasses
 import functools
-from typing import List, Optional
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -63,6 +78,45 @@ def dequantize_kv_lanes(packed, dtype):
         packed[..., -KV_SCALE_LANES:], jnp.float32)       # lanes collapse
     scale = jnp.where(jnp.isfinite(scale), scale, 0.0)
     return (q.astype(jnp.float32) * scale[..., None]).astype(dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheKind:
+    """Layers that share one cache: their indices in the model (ascending),
+    their window (0: global) and the pages of their ring (None: whole
+    tables)."""
+    name: str
+    layers: Tuple[int, ...]
+    window: int
+    ring: Optional[int]
+
+
+def ring_pages(window: int, chunk: int, block_size: int) -> int:
+    """Pages of a ring behind ``window`` under steps of ``chunk`` positions:
+    the fewest with ``R * bs >= window + chunk + bs`` (the module's
+    invariant), so that 1,024 + 128 over pages of 128 is 10."""
+    return -(-(window + chunk) // block_size) + 1
+
+
+def cache_kinds(windows, block_size: int, max_blocks: int, chunk: int):
+    """The cache kinds of a model whose layers have ``windows`` (per-layer
+    sizes, 0 = global; None = layers alike): the global layers' whole
+    tables, then the windowed layers' ring. None, and one cache as ever,
+    for a model of one kind, for windows that differ among the windowed
+    layers (no model here has them) and where the ring would not be shorter
+    than the table: those mask by the layer's window inside one pool."""
+    sizes = {w for w in windows or () if w}
+    if len(sizes) != 1 or 0 not in windows:
+        return None
+    window, = sizes
+    ring = ring_pages(window, chunk, block_size)
+    if ring >= max_blocks:
+        return None
+    return (CacheKind("full", tuple(i for i, w in enumerate(windows) if not w),
+                      0, None),
+            CacheKind(f"window{window}",
+                      tuple(i for i, w in enumerate(windows) if w),
+                      window, ring))
 
 
 class BlockedKVCache:
@@ -285,3 +339,82 @@ class BlockedKVCache:
         k = k.reshape(l, kvh, b, nb * bs, d).transpose(0, 2, 3, 1, 4)
         v = v.reshape(l, kvh, b, nb * bs, d).transpose(0, 2, 3, 1, 4)
         return (k, v)
+
+
+class LayeredKVCache:
+    """One ``BlockedKVCache`` a cache kind (``cache_kinds``), behind the
+    attributes the serve loop reads of a cache. ``k`` / ``v`` are TUPLES of
+    the kinds' pools, in the kinds' order: the frame programs take and give
+    them back as they take one pool of a model of one kind. Every count
+    without a kind's name (``num_blocks``, ``free_blocks``, ``blocks_for``,
+    ``allocator``) is the table kind's, whose pool is what grows with the
+    context and what ``num_kv_blocks`` sizes; a ring kind's pool follows
+    from the slots: ``slots * ring`` pages and the trash page."""
+
+    def __init__(self, kinds, kv_heads: int, head_dim: int, num_blocks: int,
+                 slots: int, block_size: int = 64, dtype=jnp.bfloat16):
+        self.kinds = tuple(kinds)
+        self.groups = tuple(
+            BlockedKVCache(len(kind.layers), kv_heads, head_dim,
+                           num_blocks if kind.ring is None
+                           else slots * kind.ring + 1,
+                           block_size=block_size, dtype=dtype)
+            for kind in self.kinds)
+        self.block_size = block_size
+
+    @property
+    def k(self):
+        return tuple(g.k for g in self.groups)
+
+    @k.setter
+    def k(self, pools):
+        for g, pool in zip(self.groups, pools):
+            g.k = pool
+
+    @property
+    def v(self):
+        return tuple(g.v for g in self.groups)
+
+    @v.setter
+    def v(self, pools):
+        for g, pool in zip(self.groups, pools):
+            g.v = pool
+
+    @property
+    def rings(self):
+        """(cache, ring) of every ring kind, in the kinds' order."""
+        return tuple((g, kind.ring)
+                     for g, kind in zip(self.groups[1:], self.kinds[1:]))
+
+    # ---- the table kind's, under the names a cache of one kind gives ----
+
+    @property
+    def allocator(self):
+        return self.groups[0].allocator
+
+    @property
+    def num_blocks(self) -> int:
+        return self.groups[0].num_blocks
+
+    @property
+    def free_blocks(self) -> int:
+        return self.groups[0].free_blocks
+
+    @property
+    def block_bytes(self) -> int:
+        return self.groups[0].block_bytes
+
+    def blocks_for(self, num_tokens: int) -> int:
+        return self.groups[0].blocks_for(num_tokens)
+
+    def reserve_trash_block(self) -> None:
+        for g in self.groups:
+            g.reserve_trash_block()
+
+    def in_use(self):
+        """([(kind's name, pages sequences hold, bytes a page)], tokens the
+        table kind's pages hold: what admission reserved for the live
+        sequences). The trash page is no sequence's."""
+        rows = [(kind.name, g.num_blocks - g.free_blocks - 1, g.block_bytes)
+                for kind, g in zip(self.kinds, self.groups)]
+        return rows, rows[0][1] * self.block_size

@@ -203,6 +203,63 @@ class OlmoeContainer(LlamaContainer):
             moe_norm_topk=bool(_get(hf_cfg, "norm_topk_prob", default=False)))
 
 
+class MellumContainer(LlamaContainer):
+    """Mellum2 (JetBrains/Mellum2-12B-A2.5B-Instruct ``config.json``,
+    ``model_type`` "mellum"): GQA with an explicit ``head_dim``, layers of
+    two kinds by ``layer_types`` (sliding window / full attention), RoPE by
+    kind from ``rope_parameters`` (YaRN on the full layers), every MLP
+    routed (``mlp_layer_types`` all "sparse") with experts of
+    ``moe_intermediate_size``, top-k weights renormalised, no shared expert,
+    no q/k norm (the config has no key for one). The published modelling
+    code is not in the installed transformers: the weights' names are taken
+    to be OLMoE's (``mlp.gate``, ``mlp.experts.{x}.*_proj``), which the
+    Qwen-MoE family shares. Served dropless, as the ``mellum2-12b-a2.5b``
+    preset."""
+
+    layer_mapping = {k: v for k, v in OlmoeContainer.layer_mapping.items()
+                     if "_norm" not in k}
+
+    @classmethod
+    def config(cls, hf_cfg):
+        kinds = list(_get(hf_cfg, "layer_types", default=()))
+        window = int(_get(hf_cfg, "sliding_window", default=0) or 0)
+        sparse = _get(hf_cfg, "mlp_layer_types")
+        if sparse and set(sparse) != {"sparse"}:
+            raise NotImplementedError(
+                f"mlp_layer_types {sorted(set(sparse))}: only a stack whose "
+                "every MLP is routed is mapped")
+        rope = _get(hf_cfg, "rope_parameters", default={}) or {}
+        full = rope.get("full_attention", rope)
+        local = rope.get("sliding_attention", full)
+        if local.get("rope_type", "default") != "default" or float(
+                local.get("rope_theta", 1e4)) != float(full.get("rope_theta",
+                                                                 1e4)):
+            raise NotImplementedError(
+                "sliding-attention layers with scaled RoPE, or a theta of "
+                "their own, are not mapped")
+        yarn = None
+        if full.get("rope_type", "default") == "yarn":
+            yarn = (float(full["factor"]),
+                    int(full["original_max_position_embeddings"]),
+                    float(full.get("beta_fast", 32.0)),
+                    float(full.get("beta_slow", 1.0)),
+                    full.get("attention_factor"))
+        elif full.get("rope_type", "default") != "default":
+            raise NotImplementedError(
+                f"rope_type {full['rope_type']!r} is not mapped")
+        mixed = window and "sliding_attention" in kinds
+        return _llama_family_config(
+            hf_cfg, head_dim=int(hf_cfg.head_dim),
+            rope_theta=float(full.get("rope_theta", 1e4)), rope_yarn=yarn,
+            sliding_window=window or None,
+            window_pattern=tuple(window if k == "sliding_attention" else 0
+                                 for k in kinds) if mixed else None,
+            moe_impl="grouped", num_experts=int(hf_cfg.num_experts),
+            num_experts_per_tok=int(hf_cfg.num_experts_per_tok),
+            moe_intermediate_size=int(hf_cfg.moe_intermediate_size),
+            moe_norm_topk=bool(_get(hf_cfg, "norm_topk_prob", default=True)))
+
+
 def _t_phi3_q(w, cfg):
     q = w[: cfg.num_heads * cfg.dims_per_head]
     return q.T.reshape(cfg.hidden_size, cfg.num_heads, cfg.dims_per_head)
@@ -1146,6 +1203,7 @@ ARCH_CONTAINERS: Dict[str, Type[LayerContainer]] = {
     "mixtral": MixtralContainer,
     "qwen2moe": Qwen2MoeContainer,
     "olmoe": OlmoeContainer,
+    "mellum": MellumContainer,
     "qwen2": Qwen2Container,
     "phi3": Phi3Container,
     "phi": PhiContainer,
@@ -1235,6 +1293,34 @@ def build_native(hf_model, dtype: str = None) -> Tuple[CausalLM, Dict]:
     sd = hf_model.state_dict()
     params = container.build_params(sd, cfg)
     return container.model_class(cfg), params
+
+
+def validate_layered_serving(engine_config, draft: bool = False) -> None:
+    """Fail LOUDLY at engine build for what a model of mixed cache kinds
+    (``kv_cache.cache_kinds``: windowed layers on rings of pages, global
+    layers on whole tables) cannot be served with yet. Each moves or reads a
+    sequence's pages by ONE block list, or rolls a step back across pages a
+    ring has already reused (ROADMAP M2's remainder)."""
+    c = engine_config
+    probs = []
+    if c.tp > 1:
+        probs.append(f"tp={c.tp} (the pools of two kinds are not sharded)")
+    if c.prefix_cache:
+        probs.append("prefix_cache (a shared prefix's pages behind a "
+                     "window are gone from the ring)")
+    if c.kv_swap_dir or c.role != "unified":
+        probs.append("the swap tier / prefill-decode handoff (kv_swap_dir, "
+                     "role): a record holds one block list")
+    if c.kv_dtype == "int8":
+        probs.append("kv_dtype='int8' (the gather path has no ring of "
+                     "packed rows)")
+    if draft:
+        probs.append("a draft model (a speculative rollback across a ring "
+                     "is not defined)")
+    if probs:
+        raise NotImplementedError(
+            "a model that mixes windowed and global layers keeps a cache a "
+            "kind and cannot be served with: " + "; ".join(probs))
 
 
 def validate_tp_serving(cfg: TransformerConfig, tp: int,

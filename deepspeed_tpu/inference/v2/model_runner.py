@@ -31,8 +31,9 @@ from ...models.transformer import CausalLM
 from ...ops.attention import decode_attention
 from ..sampling import sample_logits_per_row, speculative_verify_per_row
 from .kv_cache import dequantize_kv_lanes, quantize_kv_lanes
-from .telemetry import (MAX_RUNGS, MOE_STAT_NAMES,   # in-graph counter
-                        n_stats, pack_ladder)        # layout
+from .telemetry import (LAYER_STAT_NAMES, MAX_RUNGS,   # in-graph counter
+                        MOE_STAT_NAMES, n_stats,       # layout
+                        pack_ladder)
 
 
 def _use_pallas_paged() -> bool:
@@ -42,7 +43,8 @@ def _use_pallas_paged() -> bool:
 
 
 class PagedModelRunner:
-    def __init__(self, model: CausalLM, block_size: int, max_blocks_per_seq: int):
+    def __init__(self, model: CausalLM, block_size: int, max_blocks_per_seq: int,
+                 kinds=None):
         if model.cfg.post_norm or model.cfg.mlm_head or not model.cfg.causal:
             raise NotImplementedError(
                 "the paged serving runner executes causal pre-norm decoder "
@@ -52,6 +54,14 @@ class PagedModelRunner:
         self.cfg = model.cfg
         self.block_size = block_size
         self.max_blocks = max_blocks_per_seq
+        # caches by layer kind (``kv_cache.cache_kinds``; None: one kind,
+        # and every program below is what it always was). The pools and the
+        # block tables of the serving loops are then tuples, one a kind
+        self.kinds = kinds
+        if kinds is not None and model._groups is not None:
+            raise NotImplementedError(
+                "a stack of mixed cache kinds whose layers also differ in "
+                "structure (layer_types) is not walked yet")
         self._fns = {}
         # compiled programs that lived in since-evicted entry points (e.g.
         # the spec loops dropped by a draft re-attach): keeps the monotonic
@@ -71,9 +81,26 @@ class PagedModelRunner:
         window, or None (full context; per-layer local/global patterns
         count as full context — an upper bound)."""
         cfg = self.cfg
-        if cfg.sliding_window is None or cfg.local_attention_every is not None:
+        if cfg.sliding_window is None or cfg.layer_windows() is not None:
             return None
         return int(cfg.sliding_window)
+
+    @property
+    def layer_work(self):
+        """What the layered work counters of a model of mixed cache kinds
+        (``telemetry.LAYER_STAT_NAMES``) are computed from: every layer's
+        window (0: a global layer; the others have the ring); None for a
+        model of one kind, whose stat vector has no such lanes."""
+        return None if self.kinds is None else self.cfg.layer_windows()
+
+    def stat_context(self, max_seq_len: int, chunk: int) -> int:
+        """The context ``telemetry.check_stat_range`` bounds a frame's
+        largest work lane by: the uniform window or the longest sequence,
+        and for a model of mixed kinds the layers' own, summed."""
+        if self.kinds is None:
+            return self.stat_window or max_seq_len
+        return sum((w or max_seq_len) + chunk
+                   for w in self.cfg.layer_windows()) - chunk
 
     def pack_ladder(self, b: int, c: int):
         """The rungs a (b, c) step may pack its live tokens into
@@ -102,7 +129,7 @@ class PagedModelRunner:
         """Lanes of the stat vector this model's serving loops carry: a
         model with routed experts counts their work in lanes of its own
         (``telemetry.n_stats``)."""
-        return n_stats(self.cfg.is_moe)
+        return n_stats(self.cfg.is_moe, self.kinds is not None)
 
     def set_tp(self, tp_ctx) -> None:
         """Bind a ``tp.TPContext`` (engine setup, before any serving loop
@@ -140,9 +167,19 @@ class PagedModelRunner:
         issues the explicit Megatron collectives — masked-lookup psum for
         the vocab-sharded embedding, a psum after the attention-output and
         MLP-output (row-parallel) projections, and a logit all-gather at the
-        head. ``tp=None`` traces the exact pre-TP program."""
+        head. ``tp=None`` traces the exact pre-TP program.
+
+        A model of mixed cache kinds (``self.kinds``) takes ``block_tables``,
+        ``kpool`` and ``vpool`` as tuples, one a kind, and gives the pools
+        back so."""
         cfg = self.cfg
         bs = self.block_size
+        kinds = self.kinds
+        for kind in kinds or ():
+            # the ring's invariant (kv_cache.py), for this step's width
+            assert kind.ring is None or \
+                kind.ring * bs >= kind.window + ids.shape[1] + bs, \
+                (kind, ids.shape, bs)
         model = self.model
         dt = cfg.act_dtype
         b = ids.shape[0]
@@ -184,6 +221,7 @@ class PagedModelRunner:
         with jax.named_scope("embed"):
             h = _on_live(pack, embed, ids, positions)
         inv_freq = model._inv_freq
+        rope_layers = model._rope_layers   # RoPE that differs by layer
         # positions < 0 mark padding
         is_pad = positions < 0
         pos_safe = jnp.maximum(positions, 0)
@@ -194,7 +232,8 @@ class PagedModelRunner:
 
         windows = model._layer_windows()   # (L,) for local/global patterns
         uniform_window = None
-        if cfg.sliding_window is not None and cfg.local_attention_every is None \
+        if kinds is None and cfg.sliding_window is not None \
+                and cfg.local_attention_every is None \
                 and cfg.sliding_window < block_tables.shape[1] * bs:
             uniform_window = cfg.sliding_window   # binds within this pool
 
@@ -217,7 +256,7 @@ class PagedModelRunner:
                 return jax.tree.map(lambda a: a[lp[1]], lp[0])
             return lp
 
-        def qkv(lp, h, pos):
+        def qkv(lp, l, h, pos):
             lp = at(lp)
             a_in = L.apply_norm(lp["norm1"], h, cfg)
             # L.dq dequantizes int8 per-channel weight leaves in-graph (a
@@ -236,9 +275,14 @@ class PagedModelRunner:
                 q = L.apply_qk_norm(lp["attn"]["q_norm"], q, cfg)
                 k = L.apply_qk_norm(lp["attn"]["k_norm"], k, cfg)
             if cfg.position == "rope":
-                q = L.apply_rope(q, pos, inv_freq,
+                freq, factor = inv_freq, None
+                if rope_layers is not None:
+                    # a row of a small constant table by the layer's index:
+                    # no branch, whatever walks the layers
+                    freq, factor = rope_layers[0][l], rope_layers[1][l]
+                q = L.apply_rope(q, pos, freq, factor=factor,
                                  interleaved=cfg.rope_interleaved)
-                k = L.apply_rope(k, pos, inv_freq,
+                k = L.apply_rope(k, pos, freq, factor=factor,
                                  interleaved=cfg.rope_interleaved)
             return q, k, v
 
@@ -294,22 +338,27 @@ class PagedModelRunner:
         # rows are unpacked right after the gather, and the commit scatter.
         # Float pools on the chip are read (``paged_ragged_attention``) and
         # written (``kv_commit``) in place, in one layout
-        quantized_kv = kpool.dtype == jnp.int8
+        quantized_kv = (kpool if kinds is None else kpool[0]).dtype == jnp.int8
         in_place = _use_pallas_paged() and not quantized_kv
         if in_place:
             from ...ops.pallas.kv_commit import kv_commit as commit
         else:
             commit = commit_scatter
 
-        def layer(h, xs, tag=None):
+        def layer(h, xs, tag=None, cache=None):
+            """``cache`` (a model of mixed cache kinds): the layer's kind's
+            (k pool, v pool, block tables, ring or None, the layer's index
+            within those pools), all static but the index."""
             lp, l, win = xs
             if win is None:
                 win = uniform_window
+            kp, vp, tables, ring, at_pool = cache or (
+                kpool, vpool, block_tables, None, l)
             if cfg.act_quant_bits:   # QAT models serve with quantized acts
                 from ...compression.compress import fake_quantize_activation
                 h = fake_quantize_activation(h, cfg.act_quant_bits)
             with jax.named_scope("attn_qkv"):
-                q, k, v = _on_live(pack, functools.partial(qkv, lp), h,
+                q, k, v = _on_live(pack, functools.partial(qkv, lp, l), h,
                                    pos_safe)
             # the pools are LOOP-INVARIANT inside the layer scan: this
             # layer's chunk KV rides into the attention as separate blocks
@@ -326,17 +375,24 @@ class PagedModelRunner:
                     from ...ops.pallas.paged_attention import \
                         paged_ragged_attention
                     out = paged_ragged_attention(
-                        q, kpool, vpool, block_tables, positions, k, v, layer=l,
+                        q, kp, vp, tables, positions, k, v, layer=at_pool,
                         scale=cfg.attn_scale, window=win, alibi_slopes=slopes,
-                        softcap=cfg.attn_softcap)
+                        softcap=cfg.attn_softcap, ring=ring)
                 else:
-                    kvh_loc = kpool.shape[1]   # local KV heads (KVH/tp under tp)
-                    lanes = kpool.shape[-1]    # D, or D + scale lanes when int8
-                    kl = jnp.take(kpool, l, axis=0)   # escape hatch: copies 1/L
-                    vl = jnp.take(vpool, l, axis=0)
-                    kpages = kl[:, block_tables].reshape(
+                    kvh_loc = kp.shape[1]   # local KV heads (KVH/tp under tp)
+                    lanes = kp.shape[-1]    # D, or D + scale lanes when int8
+                    kl = jnp.take(kp, at_pool, axis=0)   # escape hatch: copies 1/L
+                    vl = jnp.take(vp, at_pool, axis=0)
+                    if ring is not None:
+                        # the ring as the table it stands for: logical page
+                        # p in ring slot p mod R. A slot holds the newest
+                        # page that maps to it; an older or a later one
+                        # reads that page under positions the window and
+                        # the staleness mask kill
+                        tables = tables[:, jnp.arange(self.max_blocks) % ring]
+                    kpages = kl[:, tables].reshape(
                         kvh_loc, b, -1, lanes).transpose(1, 2, 0, 3)
-                    vpages = vl[:, block_tables].reshape(
+                    vpages = vl[:, tables].reshape(
                         kvh_loc, b, -1, lanes).transpose(1, 2, 0, 3)
                     if quantized_kv:
                         kpages = dequantize_kv_lanes(kpages, dt)
@@ -366,14 +422,18 @@ class PagedModelRunner:
                 if quantized_kv:
                     return h, (quantize_kv_lanes(k), quantize_kv_lanes(v),
                                *work)
-                return h, (k.astype(kpool.dtype), v.astype(vpool.dtype),
-                           *work)
+                return h, (k.astype(kp.dtype), v.astype(vp.dtype), *work)
 
-        h, kpool, vpool, work = self._run_layers(
-            layer, h, params, kpool, vpool, windows,
-            functools.partial(commit, block_tables=block_tables,
-                              positions=positions),
-            stacked=pack is not None or self.experts_from_stack)
+        if kinds is None:
+            h, kpool, vpool, work = self._run_layers(
+                layer, h, params, kpool, vpool, windows,
+                functools.partial(commit, block_tables=block_tables,
+                                  positions=positions),
+                stacked=pack is not None or self.experts_from_stack)
+        else:
+            h, kpool, vpool, work = self._run_layers_by_kind(
+                layer, h, params, kpool, vpool, block_tables,
+                functools.partial(commit, positions=positions))
         with jax.named_scope("lm_head"):
             h = L.apply_norm(params["final_norm"], h, cfg)
             logits = self._head(params, h, valid_counts, all_logits, tp=tp)
@@ -426,6 +486,55 @@ class PagedModelRunner:
         with jax.named_scope("kv_commit"):
             kpool, vpool = commit(kpool, vpool, ck_all, cv_all)
         return h, kpool, vpool, jnp.sum(work[0], axis=0) if work else None
+
+    def _run_layers_by_kind(self, layer, h, params, kpools, vpools, tables,
+                            commit):
+        """``_run_layers`` for a stack of mixed cache kinds: every layer
+        reads its kind's pools through its kind's table and its index
+        within them, and one commit a kind writes that kind's chunk KV
+        after the walk. Which kind a layer is must be static (a pool picked
+        by a traced branch is an operand of a conditional, which XLA
+        materializes), so the walk is a scan over the PERIODS of the
+        kinds' pattern with a period's layers unrolled in its body (Mellum2:
+        three windowed layers and a global one; a pattern without a period
+        is one period). Layers are walked by index and their weights sliced
+        where they are used, as a step that packs does."""
+        kinds = self.kinds
+        n = self.cfg.num_layers
+        kind_of = [ki for _, ki in sorted(
+            (l, ki) for ki, kind in enumerate(kinds) for l in kind.layers)]
+        p = next(p for p in range(1, n + 1) if n % p == 0 and all(
+            kind_of[i] == kind_of[i % p] for i in range(n)))
+        # a period's layers of each kind, and where each stands among them
+        per = [kind_of[:p].count(ki) for ki in range(len(kinds))]
+        rank = [kind_of[:j].count(kind_of[j]) for j in range(p)]
+        layers = params["layers"]
+
+        def period(h, t):
+            ys = [[] for _ in kinds]
+            for j in range(p):
+                l, ki = t * p + j, kind_of[j]
+                kind = kinds[ki]
+                h, y = layer(h, ((layers, l), l, kind.window), cache=(
+                    kpools[ki], vpools[ki], tables[ki], kind.ring,
+                    t * per[ki] + rank[j]))
+                ys[ki].append(y)
+            return h, tuple(jax.tree.map(lambda *z: jnp.stack(z), *y)
+                            for y in ys)
+
+        h, ys = jax.lax.scan(period, h, jnp.arange(n // p, dtype=jnp.int32))
+        # (periods, layers of the kind a period, ...) -> the kind's layers
+        ys = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), ys)
+        kout, vout, work = [], [], []
+        with jax.named_scope("kv_commit"):
+            for ki, kind in enumerate(kinds):
+                ck, cv, *w = ys[ki]
+                k, v = commit(kpools[ki], vpools[ki], ck, cv,
+                              block_tables=tables[ki], ring=kind.ring)
+                kout.append(k)
+                vout.append(v)
+                work += [jnp.sum(w[0], axis=0)] if w else []
+        return h, tuple(kout), tuple(vout), sum(work) if work else None
 
     def _head(self, params, h, valid_counts, all_logits=False, tp=None):
         """Last-valid-token logits (B, V) from normed hidden states — or
@@ -562,7 +671,8 @@ class PagedModelRunner:
                                               no_eos, temps, block_tables,
                                               width, greedy,
                                               window=self.stat_window,
-                                              ladder=self.pack_ladder)
+                                              ladder=self.pack_ladder,
+                                              layers=self.layer_work)
 
                 zero = jnp.zeros((b,), jnp.int32)
                 no = jnp.zeros((b,), bool)
@@ -643,7 +753,8 @@ class PagedModelRunner:
                                           limits, eos_ids, temps, tables,
                                           width, greedy, repair=repair,
                                           window=self.stat_window,
-                                          ladder=self.pack_ladder)
+                                          ladder=self.pack_ladder,
+                                          layers=self.layer_work)
                 carry = (cached, produced, last_tok, done, poison, nonfinite,
                          stats, rng, kpool, vpool)
                 carry, (toks, emit) = jax.lax.scan(body, carry, None,
@@ -842,15 +953,20 @@ class PagedModelRunner:
                 self._evicted_programs += f._cache_size()
 
 
-def commit_scatter(kpool, vpool, chunk_k, chunk_v, block_tables, positions):
+def commit_scatter(kpool, vpool, chunk_k, chunk_v, block_tables, positions,
+                   ring=None):
     """The chunk's (L, B, C, KVH, D) KV into the (L, KVH, NB, bs, D) pools
     at ``positions`` (B, C) through the rows' block tables, as one XLA
-    scatter a pool; a pad (``positions < 0``) goes to trash page 0."""
+    scatter a pool; a pad (``positions < 0``) goes to trash page 0.
+    ``ring``: the (B, ring) tables are rings (``kv_commit``'s)."""
     bs = kpool.shape[3]
     is_pad = positions < 0
     pos_safe = jnp.maximum(positions, 0)
+    page = pos_safe // bs
+    if ring is not None:
+        page = page % ring
     blk = jnp.where(is_pad, 0, jnp.take_along_axis(
-        block_tables, pos_safe // bs, axis=1))              # (B, C)
+        block_tables, page, axis=1))                        # (B, C)
     off = pos_safe % bs
     # the advanced (B, C) indices are contiguous, so the indexed window is
     # (L, KVH, B, C, D)
@@ -931,7 +1047,8 @@ def _on_live(pack, fn, *xs, live=None):
 
 def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                        temps, tables, width, greedy, draft=None,
-                       repair=False, window=None, ladder=pack_ladder):
+                       repair=False, window=None, ladder=pack_ladder,
+                       layers=None):
     """Shared scan-step for ``mixed_loop`` and ``frame_loop`` — the in-graph
     SplitFuse scheduling arithmetic lives in exactly one place.
 
@@ -970,7 +1087,11 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
     wrote sits at/above the unchanged committed watermark, exactly like
     rejected speculation, and the retry overwrites it). The ``nonfinite``
     latch still reports to the host, which counts consecutive latched
-    boundaries and escalates a persistent fault to the quarantine path."""
+    boundaries and escalates a persistent fault to the quarantine path.
+
+    ``layers`` (``PagedModelRunner.layer_work``, a model of mixed cache
+    kinds): the step also counts its attention's work summed over the
+    layers, each under its own window (``_attn_work_by_layer``)."""
     if draft is not None:
         return _spec_scan_body(fwd, params, prompts, prompt_lens, limits,
                                eos_ids, temps, tables, width, greedy, *draft,
@@ -987,6 +1108,8 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
             # the attention's work this step (before a repair zeroes w:
             # the step was computed either way)
             kv_read, attn_pairs = _attn_work(cached, w, window)
+            layer_work = None if layers is None else \
+                _attn_work_by_layer(cached, w, layers)
         logits, kpool, vpool, moe_work = fwd(params, ids, positions, tables,
                                              w, kpool, vpool, moe_work=True)
         with jax.named_scope("sample"):
@@ -1014,7 +1137,8 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                 prefill_toks=jnp.where(prefilling, w, 0),
                 eos=emit & (nxt == eos_ids),
                 target_fwd=active & ~prefilling,
-                kv_read=kv_read, attn_pairs=attn_pairs, moe_work=moe_work)
+                kv_read=kv_read, attn_pairs=attn_pairs, moe_work=moe_work,
+                layer_work=layer_work)
         return ((cached + w, produced + emit.astype(jnp.int32),
                  last_tok, done, poison, nonfinite, stats, rng, kpool,
                  vpool),
@@ -1063,9 +1187,27 @@ def _attn_work(cached, w, window):
     return kv, w * kv
 
 
+def _attn_work_by_layer(cached, w, layer_windows):
+    """``_attn_work`` summed over the rows and over the LAYERS, each layer
+    under its own window (0: none), in the order of
+    ``telemetry.LAYER_STAT_NAMES``: KV positions read, query x key pairs,
+    and the part of the first that the windowed layers (the ring kind)
+    read."""
+    read = pairs = ring = jnp.zeros((), jnp.int32)
+    for win in sorted(set(layer_windows)):
+        n = layer_windows.count(win)
+        kv, qk = _attn_work(cached, w, win or None)
+        read = read + n * jnp.sum(kv)
+        pairs = pairs + n * jnp.sum(qk)
+        if win:
+            ring = ring + n * jnp.sum(kv)
+    return jnp.stack([read, pairs, ring]).astype(jnp.int32)
+
+
 def _stat_delta(positions, ladder, emitted=None, active=None,
                 prefill_toks=None, eos=None, target_fwd=None, drafted=None,
-                accepted=None, kv_read=None, attn_pairs=None, moe_work=None):
+                accepted=None, kv_read=None, attn_pairs=None, moe_work=None,
+                layer_work=None):
     """One step's (N_STATS,) in-graph counter increment. Each keyword is a
     bool mask / int array to sum, or None for zero — the layout is pinned by
     the STAT_* indices in ``telemetry.py`` and the host-mirror replay tests
@@ -1074,7 +1216,9 @@ def _stat_delta(positions, ladder, emitted=None, active=None,
     is the rung the forward chose for it (``_rung_of``, the same
     arithmetic), then one step at that rung. Behind them ``moe_work``, the
     target forward's own count of its routed experts' work
-    (``MOE_STAT_NAMES``), where the model has any (``telemetry.n_stats``)."""
+    (``MOE_STAT_NAMES``), where the model has any (``telemetry.n_stats``),
+    and last ``layer_work`` (``LAYER_STAT_NAMES``), where the model mixes
+    cache kinds."""
     vals = [emitted, active, prefill_toks, eos, target_fwd, drafted, accepted,
             kv_read, attn_pairs]
     z = jnp.zeros((), jnp.int32)
@@ -1085,8 +1229,11 @@ def _stat_delta(positions, ladder, emitted=None, active=None,
     steps = (jnp.arange(MAX_RUNGS) == rung).astype(jnp.int32) \
         * int(len(ladder) > 1)
     out = jnp.concatenate([jnp.stack(out), steps]
-                          + ([] if moe_work is None else [moe_work]))
-    assert out.shape == (n_stats(moe_work is not None),)
+                          + ([] if moe_work is None else [moe_work])
+                          + ([] if layer_work is None else [layer_work]))
+    assert layer_work is None or layer_work.shape == (len(LAYER_STAT_NAMES),)
+    assert out.shape == (n_stats(moe_work is not None,
+                                 layer_work is not None),)
     return out
 
 

@@ -27,6 +27,9 @@ class DSSequenceDescriptor:
     generated: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
     slot: int = -1                  # decode-slot index, -1 = not resident
+    # a model of mixed cache kinds (kv_cache.LayeredKVCache): the pages of
+    # each ring kind's ring, one list a kind; ``blocks`` is the table kind's
+    ring_blocks: List[List[int]] = dataclasses.field(default_factory=list)
     # KV memory hierarchy (kv_hierarchy.py): tokens whose pages are already
     # valid at admission (mapped prefix-cache blocks, or swapped-in pages) —
     # prefill starts here instead of token zero. Reset when the blocks are
@@ -70,6 +73,9 @@ class DSStateManager:
 
     def __init__(self, kv_cache, max_tracked_sequences: int = 2048):
         self.kv_cache = kv_cache
+        # (cache, ring) of every ring kind of a cache by layer kind
+        # (``LayeredKVCache.rings``); a cache of one kind has none
+        self.rings = getattr(kv_cache, "rings", ())
         self.max_tracked = max_tracked_sequences
         self.seqs: Dict[int, DSSequenceDescriptor] = {}
 
@@ -84,33 +90,62 @@ class DSStateManager:
 
     def ensure_capacity(self, seq: DSSequenceDescriptor, new_total_tokens: int) -> bool:
         """Grow the sequence's block list to hold ``new_total_tokens``;
-        returns False if the pool can't satisfy it."""
+        returns False if the pool can't satisfy it. Under caches by layer
+        kind the sequence also takes its ring of every ring kind
+        (``min(blocks_for(tokens), ring)`` pages), all or nothing."""
         need = self.kv_cache.blocks_for(new_total_tokens) - len(seq.blocks)
-        if need <= 0:
-            return True
+        ring_need = []
+        if self.rings:
+            if not seq.ring_blocks:
+                seq.ring_blocks = [[] for _ in self.rings]
+            ring_need = [min(kv.blocks_for(new_total_tokens), ring) - len(held)
+                         for (kv, ring), held in zip(self.rings,
+                                                     seq.ring_blocks)]
+            if any(n > kv.free_blocks
+                   for (kv, _), n in zip(self.rings, ring_need)):
+                return False
         if need > self.kv_cache.allocator.free_blocks:
             return False
-        seq.blocks.extend(self.kv_cache.allocator.allocate(need))
+        if need > 0:
+            seq.blocks.extend(self.kv_cache.allocator.allocate(need))
+        for (kv, _), n, held in zip(self.rings, ring_need, seq.ring_blocks):
+            if n > 0:
+                held.extend(kv.allocator.allocate(n))
         return True
+
+    def release_blocks(self, seq: DSSequenceDescriptor) -> None:
+        """Give the sequence's pages back, of every kind (an eviction: the
+        descriptor lives on and is re-admitted cold)."""
+        if seq.blocks:
+            self.kv_cache.allocator.free(seq.blocks)
+            seq.blocks = []
+        for (kv, _), held in zip(self.rings, seq.ring_blocks):
+            if held:
+                kv.allocator.free(held)
+        seq.ring_blocks = []
 
     def flush_sequence(self, uid: int):
         seq = self.seqs.pop(uid, None)
-        if seq is not None and seq.blocks:
-            self.kv_cache.allocator.free(seq.blocks)
+        if seq is not None:
+            self.release_blocks(seq)
 
     @staticmethod
-    def block_table(seq: DSSequenceDescriptor, max_blocks: int) -> np.ndarray:
+    def block_table(seq: DSSequenceDescriptor, max_blocks: int,
+                    blocks=None) -> np.ndarray:
         """Padded block-table ROW as host numpy. Callers stack rows and ship
         ONE device transfer per step — returning a jnp array here cost a
-        host->device round trip per sequence per call."""
-        if len(seq.blocks) > max_blocks:
+        host->device round trip per sequence per call. ``blocks``: another
+        of the sequence's lists (a ring kind's pages, ``max_blocks`` the
+        ring) in place of ``seq.blocks``."""
+        blocks = seq.blocks if blocks is None else blocks
+        if len(blocks) > max_blocks:
             # never truncate: positions past a truncated table would gather
             # a wrong page and silently overwrite live KV
             raise ValueError(
-                f"uid={seq.uid}: {len(seq.blocks)} blocks exceed the "
+                f"uid={seq.uid}: {len(blocks)} blocks exceed the "
                 f"{max_blocks}-wide table (sequence past max_seq_len?)")
         tbl = np.zeros((max_blocks,), np.int32)
-        tbl[:len(seq.blocks)] = seq.blocks
+        tbl[:len(blocks)] = blocks
         return tbl
 
     @property
@@ -145,7 +180,7 @@ class DeviceSlotTable:
 
     def __init__(self, n_slots: int, prompt_width: int, table_width: int, rng,
                  tp=None, debug_replicas: bool = False,
-                 n_stats: int = N_STATS):
+                 n_stats: int = N_STATS, rings=()):
         self.n_slots = n_slots
         self.n_stats = n_stats     # lanes of the runner's stat vector
         # tensor-parallel serving (tp.TPContext): every slot array is
@@ -166,6 +201,10 @@ class DeviceSlotTable:
         self.eos_ids = self._dev(jnp.full((n_slots,), -1, jnp.int32))
         self.temps = self._dev(jnp.zeros((n_slots,), jnp.float32))
         self.tables = zi(n_slots, max(1, table_width))
+        # caches by layer kind: one (n_slots, ring) table a ring kind, of
+        # the ring's own width from the start; the frame program then takes
+        # (tables, *ring_tables) where it takes ``tables`` of one kind
+        self.ring_tables = tuple(zi(n_slots, ring) for ring in rings)
         self.cached = zi(n_slots)
         self.produced = zi(n_slots)
         self.last_tok = zi(n_slots)
@@ -301,6 +340,12 @@ class DeviceSlotTable:
             self._dev(jnp.asarray(np.stack(p_rows))))
         self.tables = self.tables.at[idx].set(
             self._dev(jnp.asarray(np.stack(t_rows))))
+        self.ring_tables = tuple(
+            table.at[idx].set(self._dev(jnp.asarray(np.stack([
+                DSStateManager.block_table(item[1], table.shape[1],
+                                           item[1].ring_blocks[i])
+                for item in items]))))
+            for i, table in enumerate(self.ring_tables))
         self.prompt_lens = self.prompt_lens.at[idx].set(
             self._dev(jnp.asarray(plens, jnp.int32)))
         self.limits = self.limits.at[idx].set(
@@ -366,11 +411,14 @@ class DeviceSlotTable:
         telemetry counters (``self.stats``) ride the carry too and come back
         as a device array."""
         if draft is None:
+            tables = self.tables
+            if self.ring_tables:
+                tables = (tables,) + self.ring_tables
             (toks, emit, self.cached, self.produced, self.last_tok, self.done,
              self.poison, self.nonfinite, self.stats, self.rng, kv.k,
              kv.v) = runner.frame_loop(
                 params, self.prompts, self.prompt_lens, self.limits,
-                self.eos_ids, self.temps, self.tables, self.cached,
+                self.eos_ids, self.temps, tables, self.cached,
                 self.produced, self.last_tok, self.done, self.poison,
                 self.nonfinite, self.stats, self.rng, kv.k, kv.v,
                 width=width, steps=steps, greedy=greedy, repair=repair)

@@ -129,10 +129,21 @@ SPLIT_STAT_NAMES = ("kv_positions_read", "attn_pairs")
 MOE_STAT_NAMES = ("expert_rows", "experts_touched", "expert_rows_max")
 
 
-def n_stats(routed: bool) -> int:
+#: the attention's work SUMMED OVER LAYERS with each layer's own window,
+#: lanes of a model of mixed cache kinds alone (``kv_cache.cache_kinds``),
+#: behind the routed experts' where it has those too: KV positions read,
+#: query x key pairs scored, and the ring kinds' part of the first. Work,
+#: so split by frame width like SPLIT_STAT_NAMES. (KV_READ and ATTN_PAIRS
+#: count such a model ONE layer at full context, an upper bound.)
+LAYER_STAT_NAMES = ("kv_positions_read_layers", "attn_pairs_layers",
+                    "kv_positions_read_window")
+
+
+def n_stats(routed: bool, layered: bool = False) -> int:
     """Lanes of the stat vector of a model with (or without) routed
-    experts."""
-    return N_STATS + (len(MOE_STAT_NAMES) if routed else 0)
+    experts, and of mixed cache kinds or of one."""
+    return (N_STATS + (len(MOE_STAT_NAMES) if routed else 0)
+            + (len(LAYER_STAT_NAMES) if layered else 0))
 
 
 #: host work between two frames, in loop order; ``dispatch`` and ``fetch``
@@ -531,6 +542,11 @@ class ServingTelemetry:
         # scheduler label surfaces: {metric: {((label, value), ...): count}}
         # — cardinality is classes x tenants, bounded by the tenant set
         self.labeled: Dict[str, Dict[tuple, int]] = {}
+        # gauges by cache kind, of a model of mixed kinds alone:
+        # {gauge: {kind: value}}, beside the gauge of the same name (which
+        # stays the table kind's pool with its trash page)
+        self.kind_gauges: Dict[str, Dict[str, int]] = {}
+        self._layered = False
         # per-class TTFT (the bench/SLO acceptance surface)
         self.class_ttft: Dict[str, LogBucketHistogram] = {}
         # live SLO signal windows (recent samples, seconds)
@@ -558,14 +574,28 @@ class ServingTelemetry:
 
     def begin_serve(self, *, speculate: bool, gamma: int, adaptive: bool,
                     n_slots: int, kv_blocks_total: int,
-                    tp_degree: int = 1, kv_block_bytes: int = 0) -> None:
+                    tp_degree: int = 1, kv_block_bytes: int = 0,
+                    layered: bool = False) -> None:
         """Called by ``serve()`` at generator construction.
         ``kv_block_bytes`` is the pool-resident footprint of one KV block
         across all layers (``BlockedKVCache.block_bytes``) — the
         multiplier that turns block counts into the byte-denominated
         swap/residency series (``ds_serving_kv_swap_bytes_total``,
-        ``ds_serving_kv_resident_bytes``)."""
+        ``ds_serving_kv_resident_bytes``). ``layered``: the model keeps a
+        cache a layer kind (``kv_cache.LayeredKVCache``) and its stat vector
+        ends with LAYER_STAT_NAMES; only then do their counters, and the
+        gauges and sums ``on_frame`` keeps by kind, exist."""
         self.reset()
+        self._layered = layered
+        if layered:
+            for n in LAYER_STAT_NAMES:
+                self.counters[f"{n}_narrow"] = 0
+                self.counters[f"{n}_wide"] = 0
+            # the gauges below, summed over frames: a window's mean is the
+            # ratio of two deltas (kv_bytes_per_context_token)
+            self.counters.update(kv_bytes_in_use_sum=0,
+                                 context_tokens_reserved_sum=0)
+            self.gauges.update(kv_bytes_in_use=0, context_tokens_reserved=0)
         self._gamma = gamma if speculate else 0
         self._kv_block_bytes = kv_block_bytes
         self.serve_view["adaptive_frame_steps"] = adaptive
@@ -1066,18 +1096,25 @@ class ServingTelemetry:
 
     def on_frame(self, *, delta: np.ndarray, width: int, steps: int,
                  live_slots: int, kv_blocks_in_use: int,
-                 arrival_ewma: float, queue_depth: int) -> None:
+                 arrival_ewma: float, queue_depth: int,
+                 kv_kinds=None) -> None:
         """Absorb one frame's device counter DELTA (``(n_stats,)`` int64)
         plus the host-known frame facts, update the serve_stats view, and
         fan out to the attached monitor. When telemetry is disabled the
         engine calls ``frame_view_update`` instead (so even the argument
         gathering is skipped); the guard here is defensive for other
-        callers."""
+        callers. ``kv_kinds`` (a model of mixed cache kinds):
+        ``LayeredKVCache.in_use()`` at this boundary."""
         if not self.enabled:
             self.frame_view_update(width, steps, arrival_ewma)
             return
         for i, name in enumerate(STAT_NAMES):
             self.counters[name] += int(delta[i])
+        # a model of mixed cache kinds ends its vector with the layered work
+        layers = {}
+        if self._layered:
+            delta, tail = np.split(delta, [len(delta) - len(LAYER_STAT_NAMES)])
+            layers = dict(zip(LAYER_STAT_NAMES, map(int, tail)))
         # a dense model's vector ends with the rung lanes
         moe = dict.fromkeys(MOE_STAT_NAMES, 0)
         moe.update(zip(MOE_STAT_NAMES, map(int, delta[STAT_EXPERT_ROWS:])))
@@ -1090,11 +1127,28 @@ class ServingTelemetry:
             with jax.profiler.TraceAnnotation(
                     "serve/frame_work", width=width, steps=steps,
                     **{n: int(delta[i]) for i, n in
-                       enumerate(STAT_NAMES + SPLIT_STAT_NAMES)}, **moe):
+                       enumerate(STAT_NAMES + SPLIT_STAT_NAMES)}, **moe,
+                    **layers):
                 pass
         split = "wide" if width > 1 else "narrow"
         for i, name in enumerate(SPLIT_STAT_NAMES, len(STAT_NAMES)):
             self.counters[f"{name}_{split}"] += int(delta[i])
+        for name, value in layers.items():
+            self.counters[f"{name}_{split}"] += value
+        if kv_kinds is not None:
+            # pages by kind, their bytes over the kinds, and the tokens the
+            # table kind's pages hold
+            rows, reserved = kv_kinds
+            peaks = self.kind_gauges.setdefault("kv_blocks_in_use_peak", {})
+            self.kind_gauges["kv_blocks_in_use"] = {
+                kind: pages for kind, pages, _ in rows}
+            for kind, pages, _ in rows:
+                peaks[kind] = max(peaks.get(kind, 0), pages)
+            nbytes = sum(pages * page_bytes for _, pages, page_bytes in rows)
+            self.gauges["kv_bytes_in_use"] = nbytes
+            self.gauges["context_tokens_reserved"] = reserved
+            self.counters["kv_bytes_in_use_sum"] += nbytes
+            self.counters["context_tokens_reserved_sum"] += reserved
         # what the frame's per-token layers ran, as the chip counted it:
         # the rung each step chose for its live tokens, and how many steps
         # ran at each rung of the frame's ladder (a speculative decode step
@@ -1174,6 +1228,7 @@ class ServingTelemetry:
         out = {
             "counters": dict(self.counters),
             "gauges": dict(self.gauges),
+            "kind_gauges": {n: dict(v) for n, v in self.kind_gauges.items()},
             "histograms": {n: h.summary() for n, h in self.hists.items()},
             "spec": dict(self.serve_view["spec"]),
             "labeled": {
@@ -1262,6 +1317,9 @@ class ServingTelemetry:
             full = f"ds_serving_{name}"
             lines.append(f"# TYPE {full} gauge")
             lines.append(f"{full}{lb()} {fmt(val)}")
+            for kind, kval in sorted(self.kind_gauges.get(name, {}).items()):
+                extra = f'kind="{kind}"'
+                lines.append(f"{full}{lb(extra)} {fmt(kval)}")
         if self.class_ttft:
             full = "ds_serving_class_ttft_p90_seconds"
             lines.append(f"# TYPE {full} gauge")

@@ -32,6 +32,14 @@ class TransformerConfig:
     rope_theta: float = 10000.0
     rotary_pct: float = 1.0             # fraction of head_dim rotated (GPT-NeoX)
     rope_interleaved: bool = False      # GPT-NeoX/GPT-J (cos,sin per pair) layout
+    # YaRN frequency scaling (arXiv 2309.00071; transformers'
+    # ``_compute_yarn_parameters``): (factor, original_max_position,
+    # beta_fast, beta_slow, attention_factor); attention_factor None ->
+    # 0.1 ln(factor) + 1. It scales the layers of full attention: in a stack
+    # that mixes them with windowed ones (``layer_windows()``; Mellum2's
+    # ``rope_parameters`` by layer type) a windowed layer never sees a
+    # distance past its window and keeps the plain frequencies
+    rope_yarn: Optional[tuple] = None
     parallel_block: bool = False        # h + attn(ln1 h) + mlp(ln2 h) (NeoX/Falcon)
     norm_eps: float = 1e-5
     embedding_norm: bool = False        # layernorm right after token embed (BLOOM/BERT)
@@ -55,7 +63,9 @@ class TransformerConfig:
     local_attention_every: Optional[int] = None
     # explicit per-layer window sizes (len == num_layers, 0 = global) for
     # patterns local_attention_every can't express (Gemma-2 windows the
-    # EVEN-indexed layers). Takes precedence over local_attention_every.
+    # EVEN-indexed layers). Takes precedence over local_attention_every. A
+    # pattern shorter than the stack is one period of it (Mellum2: three
+    # windowed layers, then a global one), so a cut in depth keeps it.
     window_pattern: Optional[tuple] = None
     # q/k normalization before rope (HF refs: MPT attn_config.qk_ln,
     # StableLM qk_layernorm, Phi qk_layernorm):
@@ -145,6 +155,23 @@ class TransformerConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    def layer_windows(self) -> Optional[tuple]:
+        """Per-layer window sizes (0 = global) of a stack that mixes
+        windowed and global layers, or None where the layers are alike (a
+        uniform window is ``sliding_window`` alone)."""
+        if self.window_pattern is not None:
+            p = tuple(int(w) for w in self.window_pattern)
+            if self.num_layers % len(p):
+                raise ValueError(
+                    f"window_pattern of {len(p)} layers does not tile "
+                    f"num_layers={self.num_layers}")
+            return p * (self.num_layers // len(p))
+        if self.sliding_window is None or not self.local_attention_every:
+            return None
+        n = self.local_attention_every
+        return tuple(self.sliding_window if i % n == n - 1 else 0
+                     for i in range(self.num_layers))
 
     def layer_type(self, i: int) -> str:
         if self.layer_types is not None:
@@ -237,6 +264,21 @@ PRESETS = {
                                      num_kv_heads=16, intermediate_size=1024, max_seq_len=4096,
                                      num_experts=64, num_experts_per_tok=8, moe_norm_topk=False,
                                      moe_impl="grouped", qk_norm="full", qk_norm_bias=False),
+    # Mellum2-12B-A2.5B (JetBrains/Mellum2-12B-A2.5B-Instruct config.json):
+    # GQA 32/4 with head_dim 128 beside hidden 2304 (H x D = 4096); layers
+    # in periods of three sliding-window (1,024) and one full-attention
+    # layer; RoPE theta 5e5 on both kinds, YaRN (16 x 8,192) on the full
+    # layers only; every layer routes to 8 of 64 experts of width 896,
+    # softmax over all 64, the top-8 weights renormalised, no shared expert
+    # (the published dense intermediate_size 7168 is used by no layer);
+    # untied head. Dropless routing, as OLMoE
+    "mellum2-12b-a2.5b": TransformerConfig(
+        vocab_size=98304, hidden_size=2304, num_layers=28, num_heads=32, num_kv_heads=4,
+        head_dim=128, intermediate_size=7168, moe_intermediate_size=896, max_seq_len=131072,
+        rope_theta=500000.0, rope_yarn=(16.0, 8192, 32.0, 1.0, 1.2772588722239782),
+        sliding_window=1024, window_pattern=(1024, 1024, 1024, 0),
+        norm_eps=1e-6, num_experts=64, num_experts_per_tok=8, moe_norm_topk=True,
+        moe_impl="grouped"),
     # BERT family (post-norm encoder, MLM head; acceptance config 2 trains
     # bert-large under ZeRO-1/2)
     "bert-base": TransformerConfig(vocab_size=30522, hidden_size=768, num_layers=12, num_heads=12,

@@ -80,20 +80,65 @@ def apply_norm(params, x, cfg: TransformerConfig):
 
 # ---- rotary embeddings --------------------------------------------------
 
-def rope_frequencies(cfg: TransformerConfig):
+def _rotary_dims(cfg: TransformerConfig) -> int:
     d = int(cfg.dims_per_head * cfg.rotary_pct)  # partial rotary (GPT-NeoX)
-    d -= d % 2
+    return d - d % 2
+
+
+def rope_frequencies(cfg: TransformerConfig):
+    d = _rotary_dims(cfg)
     inv_freq = 1.0 / (cfg.rope_theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     return inv_freq  # (d/2,)
 
 
-def apply_rope(x, positions, inv_freq, *, interleaved=False):
+def yarn_frequencies(cfg: TransformerConfig):
+    """``cfg.rope_yarn``'s scaled frequencies (d/2,) and the factor its cos
+    and sin are multiplied by, after transformers'
+    ``_compute_yarn_parameters``: a band that turns more than ``beta_fast``
+    times over the original context keeps its frequency, one that turns less
+    than ``beta_slow`` times is divided by ``factor``, a linear ramp
+    between."""
+    factor, original, beta_fast, beta_slow, attention_factor = cfg.rope_yarn
+    d = _rotary_dims(cfg)
+
+    def correction_dim(rotations):
+        return (d * math.log(original / (rotations * 2 * math.pi))
+                / (2 * math.log(cfg.rope_theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), d - 1)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    inv_freq = rope_frequencies(cfg)
+    if attention_factor is None:
+        attention_factor = 0.1 * math.log(factor) + 1.0
+    return (inv_freq * (1.0 - ramp) + inv_freq / factor * ramp,
+            float(attention_factor))
+
+
+def rope_by_layer(cfg: TransformerConfig):
+    """A model whose RoPE differs from the plain one (``rope_yarn``): every
+    layer's (L, d/2) frequencies and (L,) cos/sin factor, which a layer
+    reads by its index: YaRN's on the layers of full attention, the plain
+    ones on the windowed layers of a mixed stack. None for plain RoPE on
+    every layer."""
+    if cfg.position != "rope" or cfg.rope_yarn is None:
+        return None
+    plain = rope_frequencies(cfg)
+    scaled, factor = yarn_frequencies(cfg)
+    windows = cfg.layer_windows() or (0,) * cfg.num_layers
+    return (jnp.stack([plain if w else scaled for w in windows]),
+            jnp.asarray([1.0 if w else factor for w in windows], jnp.float32))
+
+
+def apply_rope(x, positions, inv_freq, *, interleaved=False, factor=None):
     """x: (B, S, H, D); positions: (B, S) int32.
 
     ``inv_freq`` has rd/2 entries where rd <= D is the rotary span (partial
     rotary, GPT-NeoX ``rotary_pct``); dims past rd pass through untouched.
     ``interleaved`` uses the (x0,x1),(x2,x3)... pair layout (GPT-J/NeoX
-    checkpoints) instead of split halves (Llama).
+    checkpoints) instead of split halves (Llama). ``factor`` multiplies cos
+    and sin (YaRN's attention factor: a score carries its square).
     """
     rd = 2 * inv_freq.shape[0]
     rot = x[..., :rd].astype(jnp.float32)
@@ -101,6 +146,8 @@ def apply_rope(x, positions, inv_freq, *, interleaved=False):
               * inv_freq[None, None, :])                     # (B, S, rd/2)
     sin = jnp.sin(angles)[:, :, None, :]
     cos = jnp.cos(angles)[:, :, None, :]
+    if factor is not None:
+        sin, cos = sin * factor, cos * factor
     if interleaved:
         x1 = rot[..., 0::2]
         x2 = rot[..., 1::2]
@@ -222,7 +269,7 @@ def apply_qk_norm(norm_params, x, cfg: TransformerConfig):
 
 def apply_attention(params, x, cfg: TransformerConfig, *, positions=None, inv_freq=None,
                     segment_ids=None, kv_cache=None, cache_len=None, attn_bias=None,
-                    window=None):
+                    window=None, rope_factor=None):
     """x: (B, S, E). Returns (out, new_kv_cache).
 
     Training: kv_cache None. Decode: kv_cache = (k, v) with shape
@@ -232,6 +279,8 @@ def apply_attention(params, x, cfg: TransformerConfig, *, positions=None, inv_fr
     here only as a standalone-call fallback).
     ``window``: sliding-window width for this layer (static int, or traced
     scalar under a scan over mixed local/global layers; <= 0 = global).
+    ``rope_factor``: this layer's cos/sin factor beside its ``inv_freq``
+    (``rope_by_layer``).
     """
     if window is None and cfg.sliding_window is not None and cfg.local_attention_every is None:
         window = cfg.sliding_window   # uniform window (Mistral)
@@ -250,8 +299,10 @@ def apply_attention(params, x, cfg: TransformerConfig, *, positions=None, inv_fr
         if cfg.position == "rope":
             if positions is None:
                 positions = jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
-            q = apply_rope(q, positions, inv_freq, interleaved=cfg.rope_interleaved)
-            k = apply_rope(k, positions, inv_freq, interleaved=cfg.rope_interleaved)
+            q = apply_rope(q, positions, inv_freq, interleaved=cfg.rope_interleaved,
+                           factor=rope_factor)
+            k = apply_rope(k, positions, inv_freq, interleaved=cfg.rope_interleaved,
+                           factor=rope_factor)
 
     new_cache = None
     if kv_cache is not None:

@@ -239,6 +239,10 @@ class CausalLM:
     def __init__(self, cfg: TransformerConfig):
         self.cfg = cfg
         self._inv_freq = L.rope_frequencies(cfg) if cfg.position == "rope" else None
+        # RoPE that differs from the plain one by layer (YaRN on the layers
+        # of full attention): (L, d/2) frequencies and (L,) factors,
+        # walked beside the layers; None for every other model
+        self._rope_layers = L.rope_by_layer(cfg)
         self._plan = layer_plan(cfg)
         self._groups = layer_groups(cfg)
 
@@ -316,18 +320,25 @@ class CausalLM:
         even-layers-windowed via an explicit ``window_pattern``), or None
         when layers are homogeneous (uniform windows flow through
         cfg.sliding_window inside apply_attention)."""
-        cfg = self.cfg
-        if cfg.window_pattern is not None:
-            return jnp.asarray(cfg.window_pattern, jnp.int32)
-        if cfg.sliding_window is None or not cfg.local_attention_every:
-            return None
-        n = cfg.local_attention_every
-        return jnp.asarray([cfg.sliding_window if i % n == n - 1 else 0
-                            for i in range(cfg.num_layers)], jnp.int32)
+        windows = self.cfg.layer_windows()
+        return None if windows is None else jnp.asarray(windows, jnp.int32)
+
+    def _rope_args(self, rope):
+        """``apply_attention``'s RoPE arguments for a layer: the model's
+        plain frequencies, or the layer's own (``_rope_layers``)."""
+        if rope is None:
+            if self._rope_layers is not None:
+                raise NotImplementedError(
+                    "this model's RoPE differs by layer (rope_yarn) and the "
+                    "caller walks its layers without it: only the model's "
+                    "own forward passes and the paged serving runner do")
+            return {"inv_freq": self._inv_freq}
+        return {"inv_freq": rope[0], "rope_factor": rope[1]}
 
     def _layer_fn(self, lp, h, positions, segment_ids, attn_bias=None, window=None,
-                  layer_type=None):
+                  layer_type=None, rope=None):
         cfg = self.cfg
+        rope = self._rope_args(rope)
         is_moe = cfg.is_moe if layer_type is None else layer_type == "moe"
         if cfg.act_quant_bits:
             # QAT activation quantization (compression QuantAct analog):
@@ -340,8 +351,8 @@ class CausalLM:
             with jax.named_scope("attn"):
                 attn_out, _ = L.apply_attention(
                     lp["attn"], h, cfg, positions=positions,
-                    inv_freq=self._inv_freq, segment_ids=segment_ids,
-                    attn_bias=attn_bias, window=window)
+                    segment_ids=segment_ids, attn_bias=attn_bias,
+                    window=window, **rope)
                 h = L.apply_norm(lp["norm1"], h + attn_out, cfg)
             with jax.named_scope("mlp"):
                 mlp_out = L.apply_mlp(lp["mlp"], h, cfg)
@@ -351,8 +362,8 @@ class CausalLM:
             a_in = L.apply_norm(lp["norm1"], h, cfg)
             attn_out, _ = L.apply_attention(
                 lp["attn"], a_in, cfg, positions=positions,
-                inv_freq=self._inv_freq, segment_ids=segment_ids,
-                attn_bias=attn_bias, window=window)
+                segment_ids=segment_ids, attn_bias=attn_bias, window=window,
+                **rope)
             if cfg.sandwich_norm:   # Gemma-2: norm the sublayer OUTPUT pre-residual
                 attn_out = L.apply_norm(lp["norm3"], attn_out, cfg)
         with jax.named_scope("mlp"):
@@ -449,14 +460,16 @@ class CausalLM:
             return (jax.checkpoint(fn, policy=_remat_policy(cfg.remat))
                     if cfg.remat != "none" else fn)
 
-        def body(carry, lp, win, tag):
+        def body(carry, lp, xs_t, tag):
             h, aux_sum = carry
+            win, rope = xs_t
             h, aux = self._layer_fn(lp, h, positions, segment_ids, attn_bias,
-                                    win, layer_type=tag)
+                                    win, layer_type=tag, rope=rope)
             return (constrain(h), aux_sum + aux), None
 
         carry, _ = walk_layer_plan(self._plan, self._groups, params["layers"],
-                                   windows, carry, body, wrap=make_body)
+                                   (windows, self._rope_layers), carry, body,
+                                   wrap=make_body)
         h, aux_total = carry
         if not cfg.post_norm:
             with jax.named_scope("lm_head_loss"):
@@ -516,16 +529,16 @@ class CausalLM:
 
         windows = self._layer_windows()
 
-        def dec_layer(lp, h, ck, cv, win, tag=None):
+        def dec_layer(lp, h, ck, cv, win, tag=None, rope=None):
             is_moe = cfg.is_moe if tag is None else tag == "moe"
             if cfg.act_quant_bits:   # QAT: decode must match the forward
                 from ..compression.compress import fake_quantize_activation
                 h = fake_quantize_activation(h, cfg.act_quant_bits)
             a_in = L.apply_norm(lp["norm1"], h, cfg)
             attn_out, kv = L.apply_attention(lp["attn"], a_in, cfg, positions=positions,
-                                             inv_freq=self._inv_freq,
                                              kv_cache=(ck, cv), cache_len=cache_len,
-                                             attn_bias=attn_bias, window=win)
+                                             attn_bias=attn_bias, window=win,
+                                             **self._rope_args(rope))
             if cfg.sandwich_norm:
                 attn_out = L.apply_norm(lp["norm3"], attn_out, cfg)
             if cfg.parallel_block:
@@ -544,12 +557,12 @@ class CausalLM:
             return h + mlp_out, kv
 
         def body(h, lp, xs_t, tag):
-            ck, cv, win = xs_t
-            return dec_layer(lp, h, ck, cv, win, tag)
+            ck, cv, win, rope = xs_t
+            return dec_layer(lp, h, ck, cv, win, tag, rope)
 
         h, (new_k, new_v) = walk_layer_plan(
             self._plan, self._groups, params["layers"],
-            (cache["k"], cache["v"], windows), h, body)
+            (cache["k"], cache["v"], windows, self._rope_layers), h, body)
         h = L.apply_norm(params["final_norm"], h, cfg)
         w, transpose = self._lm_head_weight(params)
         logits = lm_head_logits(h, w, transpose, dt,

@@ -49,7 +49,7 @@ def _commit_kernel(plan_ref, ck_ref, cv_ref, kin_ref, vin_ref,
                 ).astype(new.dtype)
 
 
-def _plan(positions, block_tables, rows, slots, chunk_rows):
+def _plan(positions, block_tables, rows, slots, chunk_rows, ring=None):
     """(5, B * slots) int32: for each slot its page, whether any live
     position lands in it, the live rows' [lo, hi) in page coordinates, and
     the rotation that brings the chunk's rows under them. Dead slots take
@@ -65,9 +65,11 @@ def _plan(positions, block_tables, rows, slots, chunk_rows):
     lo = p0[:, None] - start                                      # (B, J)
     hi = lo + n[:, None]
     slot_live = jnp.logical_and(n[:, None] > 0, hi > 0).reshape(-1)
-    page = jnp.take_along_axis(
-        block_tables, jnp.clip(start // rows, 0, block_tables.shape[1] - 1),
-        axis=1)
+    if ring is None:
+        at = jnp.clip(start // rows, 0, block_tables.shape[1] - 1)
+    else:
+        at = (start // rows) % ring
+    page = jnp.take_along_axis(block_tables, at, axis=1)
     # block row r takes chunk row r - lo + c0: rotate the chunk up by that
     roll = (lo - c0[:, None]) % chunk_rows
     order = jnp.arange(b * slots, dtype=jnp.int32)
@@ -80,7 +82,8 @@ def _plan(positions, block_tables, rows, slots, chunk_rows):
         roll.reshape(-1)]).astype(jnp.int32)
 
 
-def kv_commit(kpool, vpool, chunk_k, chunk_v, block_tables, positions):
+def kv_commit(kpool, vpool, chunk_k, chunk_v, block_tables, positions,
+              ring=None):
     """Write a step's chunk KV into the page pools in place.
 
     kpool/vpool: (L, KVH, NB, bs, D), donated to the results;
@@ -89,7 +92,9 @@ def kv_commit(kpool, vpool, chunk_k, chunk_v, block_tables, positions):
     chunk position, -1 for a pad. A row's live positions are consecutive
     and ascending at consecutive chunk indices (every serving loop plans
     them so); pads may lie ahead of them or behind. Pads and wholly dead
-    rows write nothing. Returns (kpool, vpool)."""
+    rows write nothing. ``ring`` (static): the (B, ring) table is a ring,
+    position ``p`` lands in page ``table[slot, (p // bs) mod ring]``; the
+    kernel is then named ``kv_commit_ring_c<C>``. Returns (kpool, vpool)."""
     layers, kvh, _, page_size, d = kpool.shape
     b, c = positions.shape
     # a slot is a whole page: blocks of 16 rows in a decode step moved an
@@ -122,9 +127,9 @@ def kv_commit(kpool, vpool, chunk_k, chunk_v, block_tables, positions):
         out_shape=[jax.ShapeDtypeStruct(kpool.shape, kpool.dtype),
                    jax.ShapeDtypeStruct(vpool.shape, vpool.dtype)],
         input_output_aliases={3: 0, 4: 1},   # the pools, written in place
-        name=f"kv_commit_c{c}",
+        name=f"kv_commit_c{c}" if ring is None else f"kv_commit_ring_c{c}",
         interpret=jax.default_backend() != "tpu",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
-    )(_plan(positions, block_tables, rows, slots, chunk_rows),
+    )(_plan(positions, block_tables, rows, slots, chunk_rows, ring),
       chunk_blocks(chunk_k), chunk_blocks(chunk_v), kpool, vpool)

@@ -123,7 +123,7 @@ def _chunk_and_finalize(m_ref, l_ref, acc_ref, q, ck, cv, kpos, pos, win,
 def _head_step_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,   # scalar prefetch
                       q_ref, *rest,               # K k-pages, K v-pages, ...
                       page_size, grid_steps, pages_per_step, scale, softcap,
-                      use_alibi):
+                      use_alibi, ring=None):
     K = pages_per_step
     k_refs = rest[0:K]
     v_refs = rest[K:2 * K]
@@ -153,8 +153,14 @@ def _head_step_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,   # scalar prefe
     # One grid step covers K pages fused into ONE (R, K*bs) score matmul —
     # per-step overhead (DMA latency, semaphores) amortizes over K pages and
     # the MXU tile is K× wider.
-    active = jnp.logical_and(j * K * page_size < cs_ref[b],
-                             (j * K + K) * page_size > lo_ref[b])
+    if ring is None:
+        active = jnp.logical_and(j * K * page_size < cs_ref[b],
+                                 (j * K + K) * page_size > lo_ref[b])
+    else:
+        # a ring's steps count pages from the first one the window reaches
+        first = lo_ref[b] // page_size + j * K
+        active = jnp.logical_and(first * page_size < cs_ref[b],
+                                 (first + K) * page_size > lo_ref[b])
 
     @pl.when(active)
     def _pages():
@@ -164,8 +170,9 @@ def _head_step_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,   # scalar prefe
         # logical slot of each fetched key: pages past the table's end are
         # fetched clamped but their logical slots are >= MB*bs >= cs → the
         # staleness mask kills them
-        slot = j * K * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (q.shape[0], K * page_size), 1)
+        slot = (j * K if ring is None else first) * page_size \
+            + jax.lax.broadcasted_iota(
+                jnp.int32, (q.shape[0], K * page_size), 1)
         s, mask = _scores(q, k, slot, pos, win, slope, scale=scale,
                           softcap=softcap)
         mask = jnp.logical_and(mask, slot < cs_ref[b])
@@ -186,7 +193,7 @@ def _live_pages_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,  # scalar prefe
                        o_ref,
                        kbuf, vbuf, sems, m_ref, l_ref, acc_ref,
                        *, page_size, pages_per_step, scale, softcap,
-                       use_alibi):
+                       use_alibi, ring=None):
     """One slot a grid step, every local kv head at once: walk the slot's
     live pages [lo, cs) in groups of K, group g+1's pages on their way into
     the other half of (kbuf, vbuf) while group g computes."""
@@ -209,7 +216,12 @@ def _live_pages_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,  # scalar prefe
             @pl.when(jnp.logical_and(page * page_size < cs,
                                      (page + 1) * page_size > lo))
             def _():
-                src = bt_ref[b, page] if start else 0
+                if not start:
+                    src = 0
+                elif ring is None:
+                    src = bt_ref[b, page]
+                else:
+                    src = bt_ref[b, page % ring]
                 rows = pl.ds(t * page_size, page_size)
                 for hbm, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
                     dma = pltpu.make_async_copy(
@@ -267,7 +279,7 @@ def _live_pages_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,  # scalar prefe
 def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
                            chunk_k=None, chunk_v=None, *, layer=None,
                            scale=None, window=0, alibi_slopes=None,
-                           softcap=0.0):
+                           softcap=0.0, ring=None):
     """Unified paged attention for decode AND chunked prefill.
 
     q: (B, C, H, D) — C query tokens per sequence (1 = decode);
@@ -291,7 +303,18 @@ def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
     within (p - window, p] when ``window`` > 0; ``alibi_slopes``: (H,)
     per-head slopes applied in-kernel; ``softcap``: Gemma-2 attention-logit
     tanh cap. Returns (B, C, H, D).
+
+    ``ring`` (static): the pages are a ring behind a static ``window`` —
+    ``block_tables`` is (B, ring) and position ``p`` lives in page
+    ``table[slot, (p // bs) mod ring]`` (``kv_cache.CacheKind``: the ring is
+    long enough that every key the window admits is still in it). The
+    many-rows kernel then counts its steps from the window's first page, so
+    it takes ``ceil(ring / K)`` of them whatever the context. The kernel is
+    named ``paged_attn_ring_c<C>``.
     """
+    if ring is not None:
+        assert block_tables.shape[1] == ring and isinstance(window, int) \
+            and 0 < window <= (ring - 1) * kpool.shape[-2], (ring, window)
     if kpool.ndim == 4:
         kpool = kpool[None]
         vpool = vpool[None]
@@ -347,7 +370,8 @@ def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
         slopes = jnp.zeros((kvh, *col), jnp.float32)
 
     kernel_args = dict(page_size=page_size, pages_per_step=K, scale=scale,
-                       softcap=softcap, use_alibi=use_alibi)
+                       softcap=softcap, use_alibi=use_alibi, ring=ring)
+    name = f"paged_attn_c{c}" if ring is None else f"paged_attn_ring_c{c}"
     scalars = (lyr, block_tables, chunk_start, lo, win_arr)
     scratch = [pltpu.VMEM((rows, 1), jnp.float32),
                pltpu.VMEM((rows, 1), jnp.float32),
@@ -387,13 +411,13 @@ def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
                 ],
             ),
             out_shape=jax.ShapeDtypeStruct((b, kvh, rows, d), q.dtype),
-            name=f"paged_attn_c{c}",
+            name=name,
             interpret=jax.default_backend() != "tpu",
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
         )(*scalars, qg, kpool, vpool, pos_rep, slopes, ckg, cvg, cpos)
     else:
-        grid_steps = -(-mb // K)
+        grid_steps = -(-mb // K)      # a ring's mb is the ring
 
         def q_map(bi, hi, ji, lyr_, bt, lens, lo_, w_):
             return (bi, hi, 0, 0)
@@ -410,7 +434,13 @@ def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
                 last = jnp.maximum((cs[bi] + page_size - 1) // page_size - 1, 0)
                 jt = jnp.clip(ji * K + t, lo_[bi] // page_size, last)
                 return (lyr_[0], hi, bt[bi, jt], 0, 0)
-            return kv_map
+
+            def ring_map(bi, hi, ji, lyr_, bt, cs, lo_, w_):
+                last = jnp.maximum((cs[bi] + page_size - 1) // page_size - 1, 0)
+                first = lo_[bi] // page_size
+                jt = jnp.clip(first + ji * K + t, first, last)
+                return (lyr_[0], hi, bt[bi, jt % ring], 0, 0)
+            return kv_map if ring is None else ring_map
 
         def pos_map(bi, hi, ji, lyr_, bt, lens, lo_, w_):
             return (bi, 0, 0)
@@ -443,7 +473,7 @@ def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
                 scratch_shapes=scratch,
             ),
             out_shape=jax.ShapeDtypeStruct((b, kvh, rows, d), q.dtype),
-            name=f"paged_attn_c{c}",
+            name=name,
             interpret=jax.default_backend() != "tpu",
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary")),

@@ -302,6 +302,81 @@ def test_olmoe_frame_programs_fit_the_chip(one_chip, as_tpu, width,
     assert total < 15.75e9, total
 
 
+@pytest.mark.parametrize("width", [1, 128], ids=["narrow", "wide"])
+def test_mellum2_frame_programs_fit_the_chip(one_chip, as_tpu, width):
+    """The benchmark's Mellum2-12B-A2.5B configuration (published widths, 8
+    of 28 layers ``S, S, S, F`` twice, bf16; 16 slots, 8 steps, sequences to
+    32,768: tables of 256 pages over a pool of 4,097 for the two global
+    layers, rings of 10 pages over a pool of 161 for the six windowed ones):
+    both frame programs compile with the chip's compiler from shapes alone.
+    The walk is a scan over the two periods with a period's four layers
+    unrolled: three ring kernels and one over whole tables, one commit a
+    kind, in place (no value shaped like either kind's pool that XLA made),
+    XLA's grouped-product kernel three times a layer and rung, NO buffer
+    shaped like one layer's stack of experts, and arguments and temporaries
+    under the chip's 15.75 GB."""
+    import re
+    from deepspeed_tpu.inference.v2.kv_cache import cache_kinds
+    from deepspeed_tpu.inference.v2.model_runner import PagedModelRunner
+    from deepspeed_tpu.inference.v2.telemetry import pack_ladder
+    from deepspeed_tpu.models import build_model, get_config
+    slots, steps, pages, seq, chunk = 16, 8, 4097, 32768, 128
+    cfg = get_config("mellum2-12b-a2.5b", num_layers=8)
+    assert cfg.dtype == "bfloat16"
+    model = build_model(cfg.replace(param_dtype=cfg.dtype))
+    kinds = cache_kinds(cfg.layer_windows(), PAGE, seq // PAGE, chunk)
+    assert [(k.layers, k.ring) for k in kinds] == [
+        ((3, 7), None), ((0, 1, 2, 4, 5, 6), 10)]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                          model.abstract_params())
+    i32, flag = jnp.int32, jnp.bool_
+    row = sds((slots,), i32)
+    pools = tuple(
+        sds((len(k.layers), cfg.kv_heads,
+             pages if k.ring is None else slots * k.ring + 1, PAGE,
+             cfg.dims_per_head), jnp.bfloat16) for k in kinds)
+    tables = tuple(sds((slots, k.ring or seq // PAGE), i32) for k in kinds)
+    key = jax.random.PRNGKey(0)
+    runner = PagedModelRunner(model, PAGE, seq // PAGE, kinds=kinds)
+    compiled = runner._build_frame_loop().lower(
+        params, sds((slots, seq), i32), row, row, row,
+        sds((slots,), jnp.float32), tables, row, row,
+        row, sds((slots,), flag), sds((slots,), flag), sds((slots,), flag),
+        sds((runner.n_stats,), i32), sds(key.shape, key.dtype), pools, pools,
+        width=width, steps=steps, greedy=True).compile()
+    text = compiled.as_text()
+    rungs = len(pack_ladder(slots, width))
+    assert len(re.findall(r"%paged_attn_c\d+\S* = ", text)) == 1
+    assert len(re.findall(r"%paged_attn_ring_c\d+\S* = ", text)) == 3
+    assert len(re.findall(r"%kv_commit_c\d+\S* = ", text)) == 1
+    assert len(re.findall(r"%kv_commit_ring_c\d+\S* = ", text)) == 1
+    # one conditional for the embedding, two a layer (q/k/v; output
+    # projection + experts), a period's four layers unrolled
+    assert len(re.findall(r" conditional\(", text)) == \
+        (1 + 2 * 4 if rungs > 1 else 0)
+    assert len(re.findall(r"%ragged-dot-none\S* = ", text)) == 3 * rungs * 4
+    for pool in pools:
+        shape = ",".join(map(str, pool.shape))
+        made = re.findall(
+            rf"= bf16\[{shape}\]\S* (copy|copy-start|fusion|scatter|"
+            rf"transpose|dynamic-update-slice)\(", text)
+        assert not made, made
+    one_layer = re.findall(
+        r"= bf16\[(?:1,)?64,(?:2304,896|896,2304)\]\S* (\w[\w-]*)\(", text)
+    assert not one_layer, one_layer
+    m = compiled.memory_analysis()
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes
+    print(f"mellum2 frame program, width {width}: args "
+          f"{m.argument_size_in_bytes / 1e9:.3f} GB + temp "
+          f"{m.temp_size_in_bytes / 1e9:.3f} GB")
+    assert 9.9e9 < m.argument_size_in_bytes < 10.1e9
+    assert total < 15.75e9, total
+
+
 def test_chip_smoke_fails_without_a_chip():
     """The suite runs on the CPU: ``chip_smoke.py`` must exit nonzero there
     and never print its success line (the children stop before any phase)."""

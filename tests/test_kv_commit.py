@@ -82,6 +82,63 @@ def test_commit_kernel_equals_the_scatter(name):
                                           _bits(pool)[:, :, 1:])
 
 
+# a ring of pages (caches by layer kind): position p lands in page
+# ``table[row, (p // page) mod ring]``; rows as in CASES
+RING_CASES = {
+    # C, KVH, page, D, dtype, ring, rows
+    "decode-shorter-than-the-ring": (1, 4, 128, 128, jnp.bfloat16, 10,
+                                     [(5, 1, 0), (1279, 1, 0), (0, 0, 0)]),
+    "decode-exactly-the-ring-and-wrapped": (1, 4, 128, 128, jnp.bfloat16, 10,
+                                            [(1280, 1, 0), (12800, 1, 0),
+                                             (31000, 1, 0)]),
+    "prefill-crosses-the-wrap": (128, 4, 128, 128, jnp.bfloat16, 10,
+                                 [(1200, 128, 0), (10 * 128 * 7 - 1, 128, 0),
+                                  (2560, 128, 0)]),
+    "prefill-wrapped-pads-behind": (128, 4, 128, 128, jnp.bfloat16, 10,
+                                    [(20000, 37, 0), (1270, 29, 0),
+                                     (0, 0, 0)]),
+    "spec-verify-crosses-the-wrap-f32": (3, 2, 16, 16, jnp.float32, 4,
+                                         [(62, 3, 0), (63, 2, 1),
+                                          (127, 1, 0)]),
+}
+
+
+@pytest.mark.parametrize("name", list(RING_CASES))
+def test_ring_commit_kernel_equals_the_scatter(name):
+    """``ring=R``: the kernel's page map goes through ``mod R`` as the
+    scatter's does, bit for bit, and it carries a name of its own."""
+    c, kvh, page, d, dtype, ring, rows = RING_CASES[name]
+    b, layers = len(rows), 2
+    nb = 1 + b * ring
+    rng = np.random.default_rng(1)
+
+    def rand(*shape):
+        return jnp.asarray(rng.standard_normal(shape), dtype)
+
+    kpool, vpool = rand(layers, kvh, nb, page, d), rand(layers, kvh, nb, page, d)
+    ck, cv = rand(layers, b, c, kvh, d), rand(layers, b, c, kvh, d)
+    tables = jnp.asarray(1 + rng.permutation(nb - 1)[: b * ring].reshape(
+        b, ring), jnp.int32)
+    positions = np.full((b, c), -1, np.int32)
+    for i, (p0, n, ahead) in enumerate(rows):
+        positions[i, ahead:ahead + n] = p0 + np.arange(n)
+    args = (kpool, vpool, ck, cv, tables, jnp.asarray(positions))
+    got = jax.jit(kv_commit, static_argnames="ring")(*args, ring=ring)
+    want = commit_scatter(*args, ring=ring)
+    changed = False
+    for g, w, pool in zip(got, want, args[:2]):
+        np.testing.assert_array_equal(_bits(g)[:, :, 1:], _bits(w)[:, :, 1:])
+        changed |= bool(np.any(_bits(g)[:, :, 1:] != _bits(pool)[:, :, 1:]))
+    assert changed
+    # the first live position of row 0 sits where the ring says
+    p0 = rows[0][0]
+    np.testing.assert_array_equal(
+        _bits(got[0])[:, :, int(tables[0, p0 // page % ring]), p0 % page],
+        _bits(ck)[:, 0, rows[0][2]])
+    text = str(jax.make_jaxpr(lambda *a: kv_commit(*a, ring=ring))(*args))
+    assert f"kv_commit_ring_c{c}" in text and f"kv_commit_c{c}" not in text
+
+
 def test_commit_kernel_in_the_serving_forward(monkeypatch, mesh_8dp):
     """The runner with the chip's predicate on (both paged kernels, in
     interpret mode here) serves the tokens of the scatter path through

@@ -319,3 +319,92 @@ def test_wide_chunks_keep_one_head_a_step():
         for c in (1, 3, 8):
             assert _tiling(c * group, kvh, 64, 128, 128, 2) == (True, 4)
     assert _tiling(4, 8, 2, 128, 128, 2) == (True, 2)     # a table of 2 pages
+
+
+# ---- a ring of pages behind the window (caches by layer kind) -------------
+
+_RING_CASES = [(c, ctx) for c in (1, 3, 16) for ctx in (
+    "shorter-than-the-ring", "exactly-the-ring", "chunk-crosses-the-wrap",
+    "wrapped-many-times")]
+
+
+@pytest.mark.parametrize("c,ctx", _RING_CASES,
+                         ids=[f"c{c}-{ctx}" for c, ctx in _RING_CASES])
+def test_ring_of_pages_matches_the_linear_context(c, ctx):
+    """``ring=R``: position p lives in ``table[slot, (p // bs) mod R]``. The
+    pool is filled the way a run fills it (page after page through the
+    ring, later pages over earlier ones) and the kernel — the narrow one at
+    C = 1 and 3, the wide one at C = 16 (128 rows a KV head) — must equal
+    windowed attention over the LINEAR context, which knows no pages:
+    beside a frozen slot, contexts shorter than the ring, ending exactly on
+    it, with the chunk across the wrap, and wrapped many times with the
+    window's first page far from 0 (``lo > 0``)."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    h, kvh, d, bs, ring, window, layers = 16, 2, 32, 16, 5, 40, 2
+    assert ring * bs >= window + c + bs              # the ring's invariant
+    assert pa._tiling(c * h // kvh, kvh, ring, bs, d, 4)[0] == (c < 16)
+    start = {"shorter-than-the-ring": 23, "exactly-the-ring": ring * bs,
+             "chunk-crosses-the-wrap": 2 * ring * bs - min(c, 5) + 1
+             if c > 1 else 2 * ring * bs - 1,
+             "wrapped-many-times": 333}[ctx]
+    starts = [0, start, start + 7]                   # slot 0 is frozen
+    b, nb = len(starts), 1 + len(starts) * ring
+    rng = np.random.default_rng(11)
+
+    def rand(*shape, scale=1.0):
+        return jnp.asarray(rng.standard_normal(shape) * scale, jnp.float32)
+
+    total = max(starts) + c
+    lin_k, lin_v = rand(b, total, kvh, d), rand(b, total, kvh, d)
+    q = rand(b, c, h, d, scale=0.3)
+    kpool = np.array(rand(layers, kvh, nb, bs, d))
+    vpool = np.array(rand(layers, kvh, nb, bs, d))
+    tables = (1 + rng.permutation(nb - 1)[:b * ring]).reshape(b, ring)
+    positions = np.full((b, c), -1, np.int32)
+    lyr = 1
+    for s, cs in enumerate(starts):
+        if s == 0:
+            continue
+        positions[s] = cs + np.arange(c)
+        for page in range(-(-cs // bs)):             # what a run has written
+            rows = slice(page * bs, min((page + 1) * bs, total))
+            n = rows.stop - rows.start
+            for pool, lin in ((kpool, lin_k), (vpool, lin_v)):
+                pool[lyr, :, tables[s, page % ring], :n] = \
+                    np.asarray(lin[s, rows]).transpose(1, 0, 2)
+    ck = jnp.stack([lin_k[s, cs:cs + c] for s, cs in enumerate(starts)])
+    cv = jnp.stack([lin_v[s, cs:cs + c] for s, cs in enumerate(starts)])
+    out = pa.paged_ragged_attention(
+        q, jnp.asarray(kpool), jnp.asarray(vpool),
+        jnp.asarray(tables, jnp.int32), jnp.asarray(positions), ck, cv,
+        layer=lyr, window=window, ring=ring)
+    # windowed causal attention over the linear context
+    group = h // kvh
+    kk = jnp.repeat(lin_k, group, axis=2)
+    vv = jnp.repeat(lin_v, group, axis=2)
+    s_ = jnp.einsum("bchd,bkhd->bhck", q, kk) * d ** -0.5
+    key = jnp.arange(total)[None, None, None, :]
+    pos = jnp.asarray(positions)[:, None, :, None]
+    mask = (key <= pos) & (key > pos - window)
+    p = jax.nn.softmax(jnp.where(mask, s_, -1e30), axis=-1)
+    ref = jnp.einsum("bhck,bkhd->bchd", p, vv)
+    np.testing.assert_allclose(np.asarray(out)[1:], np.asarray(ref)[1:],
+                               rtol=3e-5, atol=3e-5)
+
+
+def test_ring_kernels_carry_their_own_names():
+    """The trace tells the kinds apart: a kernel that reads a ring is
+    ``paged_attn_ring_c<C>``, and the accepted readers' ``^paged_attn_c\\d+$``
+    keeps meaning the kernel over whole tables."""
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_ragged_attention
+    b, c, h, kvh, d, bs, ring = 2, 1, 4, 2, 32, 16, 4
+    args = (jnp.zeros((b, c, h, d)), jnp.zeros((1, kvh, 9, bs, d)),
+            jnp.zeros((1, kvh, 9, bs, d)), jnp.zeros((b, ring), jnp.int32),
+            jnp.zeros((b, c), jnp.int32), jnp.zeros((b, c, kvh, d)),
+            jnp.zeros((b, c, kvh, d)))
+    ringed = str(jax.make_jaxpr(lambda *a: paged_ragged_attention(
+        *a, layer=0, window=20, ring=ring))(*args))
+    plain = str(jax.make_jaxpr(lambda *a: paged_ragged_attention(
+        *a, layer=0, window=20))(*args))
+    assert "paged_attn_ring_c1" in ringed and "paged_attn_c1" not in ringed
+    assert "paged_attn_c1" in plain and "ring" not in plain

@@ -633,3 +633,98 @@ def test_wall_clock_latency_values_plausible(tiny_model_params):
     lat = e.telemetry.latency_ms()
     assert lat["ttft"]["p50"] > 0
     assert lat["e2e"]["p50"] >= lat["ttft"]["p50"]
+
+
+# ---------------------------------------------------------------------------
+# caches by layer kind: the layered lanes, counters and gauges exist for a
+# model that mixes windowed and global layers, and for no other
+# ---------------------------------------------------------------------------
+
+LAYERED_SERIES = (
+    "ds_serving_kv_positions_read_layers_narrow_total",
+    "ds_serving_kv_positions_read_layers_wide_total",
+    "ds_serving_attn_pairs_layers_narrow_total",
+    "ds_serving_attn_pairs_layers_wide_total",
+    "ds_serving_kv_positions_read_window_narrow_total",
+    "ds_serving_kv_positions_read_window_wide_total",
+    "ds_serving_kv_bytes_in_use_sum_total",
+    "ds_serving_context_tokens_reserved_sum_total",
+    "ds_serving_kv_bytes_in_use",
+    "ds_serving_context_tokens_reserved",
+)
+
+
+def _mixed_engine():
+    """8 layers ``S, S, S, F`` twice, window 16 over pages of 8: rings of 4
+    pages under tables of 16."""
+    from deepspeed_tpu.models import get_config
+    cfg = get_config(
+        "mellum2-12b-a2.5b", vocab_size=256, hidden_size=32, num_layers=8,
+        num_heads=4, num_kv_heads=2, head_dim=8, intermediate_size=32,
+        moe_intermediate_size=16, num_experts=4, num_experts_per_tok=2,
+        sliding_window=16, window_pattern=(16, 16, 16, 0),
+        rope_yarn=(4.0, 32, 32.0, 1.0, None), max_seq_len=128,
+        dtype="float32")
+    model = build_model(cfg)
+    return InferenceEngineV2(
+        model, RaggedInferenceEngineConfig(
+            dtype="float32", max_ragged_batch_size=4, prefill_chunk_size=8,
+            kv_block_size=8, max_tokens_per_step=64, frame_steps=2),
+        params=model.init(jax.random.PRNGKey(0)), max_seq_len=128)
+
+
+def test_layered_lanes_and_gauges_exist_for_a_model_of_mixed_kinds():
+    from deepspeed_tpu.inference.v2.telemetry import (LAYER_STAT_NAMES,
+                                                      MOE_STAT_NAMES, N_STATS,
+                                                      n_stats)
+    e = _mixed_engine()
+    assert e.runner.n_stats == N_STATS + len(MOE_STAT_NAMES) \
+        + len(LAYER_STAT_NAMES) == n_stats(True, True)
+    rng = np.random.default_rng(3)
+    prompts = {u: rng.integers(0, 256, n).astype(np.int32)
+               for u, n in ((0, 45), (1, 9))}
+    outs = dict(e.serve(iter([[(u, p) for u, p in prompts.items()]]),
+                        max_new_tokens=5))
+    assert {len(v) for v in outs.values()} == {5}
+    text = e.telemetry.render_prometheus()
+    for series in LAYERED_SERIES:
+        assert f"# TYPE {series} " in text, series
+    # pages by kind beside the gauge without a label, which stays the table
+    # kind's pool (what kv_pool_peak_share divides by kv_blocks)
+    # (sampled where kv_blocks_in_use is: after a frame, before it retires)
+    assert 'ds_serving_kv_blocks_in_use{kind="full"} ' in text
+    assert 'ds_serving_kv_blocks_in_use{kind="window16"} ' in text
+    # (45 + 5 + 1) and (9 + 5 + 1) tokens: 7 + 2 pages of the table kind,
+    # the ring's 4 and 2 of the window kind
+    assert 'ds_serving_kv_blocks_in_use_peak{kind="full"} 9' in text
+    assert 'ds_serving_kv_blocks_in_use_peak{kind="window16"} 6' in text
+    assert "ds_serving_kv_blocks_in_use_peak 10" in text     # + trash page
+    snap = e.telemetry.snapshot()
+    assert snap["kind_gauges"]["kv_blocks_in_use_peak"] == {
+        "full": 9, "window16": 6}
+    c = snap["counters"]
+    assert 0 < c["kv_positions_read_window_narrow"] \
+        < c["kv_positions_read_layers_narrow"]
+    # the layered reads are the old lane's (ONE layer at full context:
+    # documented as an upper bound a layer) bounded above by 8 layers of it
+    one = c["kv_positions_read_narrow"] + c["kv_positions_read_wide"]
+    layered = c["kv_positions_read_layers_narrow"] \
+        + c["kv_positions_read_layers_wide"]
+    assert 2 * one < layered < 8 * one
+
+
+def test_layered_lanes_and_gauges_are_absent_for_a_model_of_one_kind(
+        served):
+    """A model whose layers are alike has no trace of them: not in its
+    stats vector, its counters, its gauges or ``/metrics``."""
+    from deepspeed_tpu.inference.v2.telemetry import N_STATS
+    e, _prompts, _outs, snap = served
+    assert e.runner.kinds is None and e.runner.layer_work is None
+    assert e.runner.n_stats == N_STATS
+    assert "kind=" not in snap["prom"]
+    for series in LAYERED_SERIES:
+        assert series not in snap["prom"], series
+    assert not snap["snapshot"]["kind_gauges"]
+    assert not [k for k in snap["snapshot"]["counters"]
+                if "_layers_" in k or "_window_" in k or k.endswith("_sum")]
+    assert "kv_bytes_in_use" not in snap["snapshot"]["gauges"]

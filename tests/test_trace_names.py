@@ -78,6 +78,44 @@ def test_frame_loop_lowering_names_scopes_and_kernel(width, monkeypatch):
     assert {f"paged_attn_c{width}", f"kv_commit_c{width}"} <= parts
 
 
+@pytest.mark.parametrize("width", [8, 1])
+def test_mixed_kinds_frame_lowering_names_the_ring_kernels(width,
+                                                           monkeypatch):
+    """A model that mixes windowed and global layers (8 layers ``S, S, S,
+    F`` twice, window 16 over pages of 8: rings of 4) names its kernels by
+    cache kind under the same scopes: ``paged_attn_ring_c<C>`` and
+    ``kv_commit_ring_c<C>`` beside the kernels over whole tables, so the
+    accepted readers' ``^paged_attn_c\\d+$`` keeps meaning what it meant."""
+    from deepspeed_tpu.models import get_config
+    monkeypatch.setattr(model_runner, "_use_pallas_paged", lambda: True)
+    cfg = get_config(
+        "mellum2-12b-a2.5b", vocab_size=128, hidden_size=32, num_layers=8,
+        num_heads=4, num_kv_heads=2, head_dim=8, intermediate_size=32,
+        moe_intermediate_size=16, num_experts=4, num_experts_per_tok=2,
+        sliding_window=16, window_pattern=(16, 16, 16, 0),
+        rope_yarn=(4.0, 32, 32.0, 1.0, None), max_seq_len=128,
+        dtype="float32")
+    model = build_model(cfg)
+    eng = InferenceEngineV2(
+        model, RaggedInferenceEngineConfig(
+            dtype="float32", max_ragged_batch_size=4, prefill_chunk_size=8,
+            kv_block_size=8, max_tokens_per_step=64, frame_steps=2),
+        params=model.init(jax.random.PRNGKey(0)), max_seq_len=128)
+    slots = DeviceSlotTable(4, prompt_width=8, table_width=16,
+                            rng=jax.random.PRNGKey(0),
+                            n_stats=eng.runner.n_stats, rings=[4])
+    lowered = eng.runner._build_frame_loop().lower(
+        eng.params, slots.prompts, slots.prompt_lens, slots.limits,
+        slots.eos_ids, slots.temps, (slots.tables,) + slots.ring_tables,
+        slots.cached, slots.produced, slots.last_tok, slots.done,
+        slots.poison, slots.nonfinite, slots.stats, slots.rng, eng.kv.k,
+        eng.kv.v, width=width, steps=2, greedy=True)
+    parts = _scope_components(lowered)
+    assert set(SERVE_SCOPES) <= parts, set(SERVE_SCOPES) - parts
+    assert {f"paged_attn_c{width}", f"paged_attn_ring_c{width}",
+            f"kv_commit_c{width}", f"kv_commit_ring_c{width}"} <= parts
+
+
 def test_train_step_lowering_names_scopes_and_flash_kernels():
     """A tiny training step names its layer scopes, the optimizer, and the
     three flash kernels (interpret mode off the chip)."""

@@ -554,3 +554,43 @@ def test_auto_container_refuses_non_llama_layout():
     cfg = AutoContainer.config(m.config)
     with pytest.raises(NotImplementedError, match="q_norm"):
         AutoContainer.build_params(sd, cfg)
+
+
+def test_container_mellum2_config_mapping():
+    """Mellum2's published ``config.json`` (the benchmark's file holds its
+    keys) maps onto the ``mellum2-12b-a2.5b`` preset field for field: layer
+    kinds -> window pattern, ``rope_parameters`` -> theta and YaRN on the
+    global layers, the experts' own width, renormalised top-k, dropless."""
+    import json
+    import os
+    import types
+    from deepspeed_tpu.inference.v2.model_implementations import resolve_container
+    from deepspeed_tpu.inference.v2.model_implementations.archs import MellumContainer
+    from deepspeed_tpu.models import get_config
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perfbench", "configs",
+                           "mellum2-12b-a2.5b-l8-serve.json")) as fh:
+        published = json.load(fh)
+    published["num_hidden_layers"] = 28          # the file's one cut
+    hf = types.SimpleNamespace(architectures=["MellumForCausalLM"],
+                               **published)
+    container = resolve_container(hf)
+    assert issubclass(container, MellumContainer)
+    cfg = container.config(hf)
+    preset = get_config("mellum2-12b-a2.5b")
+    assert cfg.layer_windows() == preset.layer_windows() \
+        == (1024, 1024, 1024, 0) * 7
+    for field in ("vocab_size", "hidden_size", "num_layers", "num_heads",
+                  "num_kv_heads", "head_dim", "intermediate_size",
+                  "moe_intermediate_size", "max_seq_len", "rope_theta",
+                  "rope_yarn", "sliding_window",
+                  "norm_eps", "num_experts", "num_experts_per_tok",
+                  "moe_norm_topk", "moe_impl", "qk_norm", "tie_embeddings"):
+        assert getattr(cfg, field) == getattr(preset, field), field
+    assert "attn.q_norm.scale" not in container.layer_mapping
+    assert "mlp.router" in container.layer_mapping
+    # a dense MLP layer in the stack, or scaled RoPE on the sliding layers,
+    # is refused rather than mapped wrong
+    hf.mlp_layer_types = ["dense"] + ["sparse"] * 27
+    with pytest.raises(NotImplementedError, match="mlp_layer_types"):
+        container.config(hf)
