@@ -2,6 +2,7 @@
 
     python benchmarks/paged_decode_sweep.py [--root DIR] [--label NAME]
                                             [--chunk C] [--configs A,B]
+                                            [--live N,M] [--contexts N,M]
 
 One step's attention (``--chunk`` 1: the narrow kernel of a decode step;
 128: the wide one of a prefill chunk) at the serve configurations' shapes
@@ -11,9 +12,11 @@ its two global layers over a table of 256 and its six windowed ones over a
 ring of 10 pages behind a window of 1,024; 16 slots, pages of 128,
 head_dim 128, bf16) over live slots 1 / 3 / 16 and contexts 256 / 1,024 /
 4,096 / 7,168 (Mellum2: to 30,000): microseconds a layer, the pages a layer had to move
-(``live x ceil((cs - lo) / page)``, K and V of every KV head) and their
-bytes over the time as a share of the chip's 819 GB/s. ``live = 0`` is what
-sixteen frozen slots cost. ``--root`` imports ``deepspeed_tpu`` from another
+(``live x ceil((cs - lo) / page)``, K and V of every KV head), their
+bytes over the time as a share of the chip's 819 GB/s, and the largest gap
+of a live row's output to a float32 gather reference (at either width: a
+chunk's rows attend the pool below the chunk and the chunk's own keys
+causally). ``live = 0`` is what sixteen frozen slots cost. ``--root`` imports ``deepspeed_tpu`` from another
 checkout (a ``git archive`` copy of the parent), so one script times both
 sides. Needs the chip: the kernel's interpret mode times nothing.
 """
@@ -45,6 +48,8 @@ def main():
     ap.add_argument("--label", default="")
     ap.add_argument("--chunk", type=int, default=1)
     ap.add_argument("--configs", default=",".join(CONFIGS))
+    ap.add_argument("--live", default=",".join(map(str, LIVE)))
+    ap.add_argument("--contexts", default=",".join(map(str, CONTEXTS)))
     args = ap.parse_args()
     sys.path.insert(0, os.path.abspath(args.root))
 
@@ -60,24 +65,29 @@ def main():
     if dev.platform != "tpu":
         sys.exit(f"paged_decode_sweep needs the chip, found {dev.platform}")
 
-    def reference(q, kpool, vpool, tables, pos, ck, cv, window):
-        """Layer 0 by gather, in float32: (SLOTS, 1, H, D)."""
-        h, kvh = q.shape[2], kpool.shape[1]
+    @jax.jit
+    def reference(q, kpool, vpool, table, pos, ck, cv, window):
+        """ONE slot of layer 0 by gather, in float32: q (C, H, D), table
+        (MB,), pos (C,), ck / cv (C, KVH, D) -> (C, H, D)."""
+        h, kvh = q.shape[1], kpool.shape[1]
         f32 = jnp.float32
-        k = kpool[0][:, tables].reshape(kvh, SLOTS, -1, D).astype(f32)
-        v = vpool[0][:, tables].reshape(kvh, SLOTS, -1, D).astype(f32)
-        k = jnp.concatenate([k, ck.astype(f32).transpose(2, 0, 1, 3)], axis=2)
-        v = jnp.concatenate([v, cv.astype(f32).transpose(2, 0, 1, 3)], axis=2)
-        slot = jnp.arange(k.shape[2])[None, :]
-        live = (slot < pos) | (slot == k.shape[2] - 1)
-        if window:
-            live &= (slot > pos - window) | (slot == k.shape[2] - 1)
-        qg = q.astype(f32).reshape(SLOTS, kvh, h // kvh, D)
-        s = jnp.einsum("bhgd,hbkd->bhgk", qg, k, precision="highest") * D ** -0.5
-        s = jnp.where(live[:, None, None, :], s, -1e30)
+        k = kpool[0][:, table].reshape(kvh, -1, D).astype(f32)
+        v = vpool[0][:, table].reshape(kvh, -1, D).astype(f32)
+        held = k.shape[1]
+        k = jnp.concatenate([k, ck.astype(f32).transpose(1, 0, 2)], axis=1)
+        v = jnp.concatenate([v, cv.astype(f32).transpose(1, 0, 2)], axis=1)
+        # the pool is good below the chunk's first position; the chunk's
+        # own keys sit at the chunk's positions
+        key = jnp.concatenate([jnp.arange(held), pos])[None, :]
+        live = key <= pos[:, None]
+        live &= jnp.concatenate([jnp.arange(held) < pos[0], pos >= 0])[None, :]
+        live &= (key > pos[:, None] - window) | (window <= 0)
+        qg = q.astype(f32).reshape(-1, kvh, h // kvh, D)
+        s = jnp.einsum("chgd,hkd->hgck", qg, k, precision="highest") * D ** -0.5
+        s = jnp.where(live[None, None], s, -1e30)
         p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("bhgk,hbkd->bhgd", p, v,
-                          precision="highest").reshape(SLOTS, 1, h, D)
+        return jnp.einsum("hgck,hkd->chgd", p, v,
+                          precision="highest").reshape(-1, h, D)
 
     def bench(pool, h, window, mb, live, ctx, ring):
         kvh, pool_pages = pool.shape[1], pool.shape[2]
@@ -107,14 +117,19 @@ def main():
         a = (q, pool, pool, jnp.asarray(tables), jnp.asarray(pos), ck)
         step(*a).block_until_ready()
         gap = None
-        if live and chunk == 1:
-            one = paged_ragged_attention(*a, ck, layer=0, window=window, **kw)
+        if live:
+            # jitted: the pool is ONE argument however many operands of the
+            # kernel it becomes
+            one = jax.jit(lambda *a: paged_ragged_attention(
+                *a, a[-1], layer=0, window=window, **kw))(*a)
             ref_tables = a[3]
             if ring:
                 # the ring as the table it stands for: page p in slot p mod R
                 ref_tables = ref_tables[:, jnp.arange(mb) % ring]
-            ref = reference(*a[:3], ref_tables, *a[4:], ck, window)
-            gap = float(jnp.max(jnp.abs(one.astype(jnp.float32) - ref)[:live]))
+            gap = max(float(jnp.max(jnp.abs(
+                one[s].astype(jnp.float32) - reference(
+                    q[s], pool, pool, ref_tables[s], a[4][s], ck[s], ck[s],
+                    window)))) for s in range(live))
         times = []
         for _ in range(5):
             t = time.perf_counter()
@@ -136,8 +151,8 @@ def main():
         pool = jax.random.normal(jax.random.PRNGKey(kvh),
                                  (LAYERS, kvh, pool_pages, PAGE, D),
                                  jnp.bfloat16)
-        for live in LIVE:
-            for ctx in CONTEXTS if live else (0,):
+        for live in map(int, args.live.split(",")):
+            for ctx in map(int, args.contexts.split(",")) if live else (0,):
                 if ctx > mb * PAGE:
                     continue
                 ctx = min(ctx, mb * PAGE - chunk)  # the chunk needs its slots

@@ -17,18 +17,26 @@ block table and per-sequence page bounds are scalar-prefetched
 head as rows of one tile.
 
 It wants different tiles at different widths, and ``_tiling`` picks them
-from static shapes against one VMEM budget:
+from static shapes against one VMEM budget, but the iteration space is the
+same at every width: a slot's LIVE PAGES ``[lo, cs)``, never the table's
+width (``_live_pages_kernel``, the one page walk). A grid step walks its
+slot's pages in groups, a group's pages copied by hand into one half of a
+double buffer while the group before it computes; a slot with no context
+copies nothing and goes straight to the chunk's own keys, a window moves
+``lo`` and the walk starts there, a ring is the same walk through
+``page mod ring``. So a step's cost follows the context it reads:
 
 - few query rows (a decode or speculation step): the work is the bytes of
-  the context, so the iteration space is LIVE PAGES x ALL LOCAL KV HEADS
-  (``_live_pages_kernel``). Grid = (batch,); a slot walks its own pages
-  ``[lo, cs)`` in groups, each page copied once across heads — the whole
-  ``(KVH, page, D)`` block ``kv_commit.py`` also moves — by hand into a
-  double buffer while the group before it computes. A frozen slot copies
-  nothing and a step's cost follows the context it reads.
-- many rows (a prefill chunk): one (slot, kv head, page group) a grid step
-  with BlockSpec index maps chasing the page ids (``_head_step_kernel``);
-  a step there is bound by its (R, K*bs) score tile, not by its count.
+  the context, so a step takes ALL LOCAL KV HEADS. Grid = (batch,); a page
+  is copied once across heads — the whole ``(KVH, page, D)`` block
+  ``kv_commit.py`` also moves — 4 pages a group.
+- from 128 rows a KV head (a prefill chunk): a head's rows fill the MXU and
+  its (R, K*bs) f32 score tile fills VMEM, so a step takes as many KV heads
+  as fit the budget beside a group of 8 pages — ONE at mistral's 512 rows,
+  four at OLMoE's 128 (a step there is bound by its count, and four heads
+  a step timed 41% under one) — on grid (batch, KV heads / heads a step);
+  where one head's tile of 8 pages does not fit (Mellum2's 1,024 rows) the
+  group is 4 pages. A head's page is one contiguous ``(page, D)`` block.
 """
 
 import functools
@@ -44,22 +52,38 @@ NEG_INF = -1e30
 # default is 16 MiB on v5e; the rest is its own temporaries).
 _VMEM_BUDGET = 12 << 20
 _MXU_ROWS = 128         # from here on one head's product fills the MXU
-_HEAD_STEP_PAGES = 8    # pages a (slot, head) step groups: 1,024 keys
+_HEAD_PAGES = 8         # most pages a many-rows step groups: 1,024 keys
 _FOLD_PAGES = 4         # pages a folded step groups: 8 timed 2-15% slower at
 # mistral's 8 KV heads (a short context computes the group's dead keys) and
 # does not fit OLMoE's 16 (benchmarks/paged_decode_sweep.py, PERF.md PR 29)
 
 
+def _step_bytes(heads, rows, keys, d, itemsize):
+    """VMEM a step's tiles take with ``heads`` kv heads of ``rows`` query
+    rows against groups of ``keys`` keys."""
+    return (heads * rows * keys * (4 + 4 + 2)        # s, exp(s - m), its cast
+            + 2 * 2 * heads * keys * d * itemsize    # K and V, double-buffered
+            + heads * rows * d * (4 + 2 * 2 * itemsize))   # acc; q, out x 2
+
+
 def _tiling(rows, kvh, mb, page_size, d, itemsize):
-    """(fold all local kv heads into a step?, pages a step). A step folds
+    """(kv heads a step, pages a group). A step takes every local kv head
     while a head's rows leave the MXU mostly empty — its cost is then the
-    context's bytes and the count of steps — and its tiles fit the budget."""
-    keys = _FOLD_PAGES * page_size
-    folded = (kvh * rows * keys * (4 + 4 + 2)        # s, exp(s - m), its cast
-              + 2 * 2 * kvh * keys * d * itemsize    # K and V, double-buffered
-              + kvh * rows * d * (4 + 2 * 2 * itemsize))   # acc; q, out x 2
-    fold = rows < _MXU_ROWS and folded <= _VMEM_BUDGET
-    return fold, min(_FOLD_PAGES if fold else _HEAD_STEP_PAGES, mb)
+    context's bytes and the count of steps — and its tiles fit the budget;
+    else the widest group of pages whose score tile fits for one head, and
+    as many heads (a divisor of the local ones) as fit beside it."""
+    def fits(heads, pages):
+        return _step_bytes(heads, rows, pages * page_size, d,
+                           itemsize) <= _VMEM_BUDGET
+
+    if rows < _MXU_ROWS and fits(kvh, _FOLD_PAGES):
+        return kvh, min(_FOLD_PAGES, mb)
+    pages = _HEAD_PAGES
+    while pages > 1 and not fits(1, pages):
+        pages //= 2
+    heads = max(h for h in range(1, kvh + 1)
+                if kvh % h == 0 and (h == 1 or fits(h, pages)))
+    return heads, min(pages, mb)
 
 
 def _scores(q, k, key_pos, pos, win, slope, *, scale, softcap):
@@ -120,73 +144,6 @@ def _chunk_and_finalize(m_ref, l_ref, acc_ref, q, ck, cv, kpos, pos, win,
     return acc_ref[...] / l_safe
 
 
-def _head_step_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,   # scalar prefetch
-                      q_ref, *rest,               # K k-pages, K v-pages, ...
-                      page_size, grid_steps, pages_per_step, scale, softcap,
-                      use_alibi, ring=None):
-    K = pages_per_step
-    k_refs = rest[0:K]
-    v_refs = rest[K:2 * K]
-    (pos_ref, slope_ref, ck_ref, cv_ref, cpos_ref,   # chunk KV blocks
-     o_ref,                                          # output
-     m_ref, l_ref, acc_ref) = rest[2 * K:]           # VMEM scratch
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    win = win_ref[0]          # runtime: 0/negative = global (per-layer
-    # window patterns arrive as traced scan elements, so the window cannot
-    # be a compile-time constant)
-    pos = pos_ref[0, 0].reshape(-1, 1)                    # (R, 1) int32
-    # slope block is already this kv-head's (1, 1, R) slice
-    slope = slope_ref[0, 0].reshape(-1, 1) if use_alibi else None
-
-    # pool slots >= cs (the current chunk's first position) are stale: the
-    # chunk's own KV arrives as separate blocks below, NOT via the pool —
-    # keeping the pool read-only inside the layer scan is what lets XLA
-    # leave it in place; ``kv_commit.py`` writes the chunk in afterwards.
-    # One grid step covers K pages fused into ONE (R, K*bs) score matmul —
-    # per-step overhead (DMA latency, semaphores) amortizes over K pages and
-    # the MXU tile is K× wider.
-    if ring is None:
-        active = jnp.logical_and(j * K * page_size < cs_ref[b],
-                                 (j * K + K) * page_size > lo_ref[b])
-    else:
-        # a ring's steps count pages from the first one the window reaches
-        first = lo_ref[b] // page_size + j * K
-        active = jnp.logical_and(first * page_size < cs_ref[b],
-                                 (first + K) * page_size > lo_ref[b])
-
-    @pl.when(active)
-    def _pages():
-        q = q_ref[0, 0]                                   # (R, D) R = C*G
-        k = jnp.concatenate([r[0, 0, 0] for r in k_refs], axis=0)  # (K*bs, D)
-        v = jnp.concatenate([r[0, 0, 0] for r in v_refs], axis=0)
-        # logical slot of each fetched key: pages past the table's end are
-        # fetched clamped but their logical slots are >= MB*bs >= cs → the
-        # staleness mask kills them
-        slot = (j * K if ring is None else first) * page_size \
-            + jax.lax.broadcasted_iota(
-                jnp.int32, (q.shape[0], K * page_size), 1)
-        s, mask = _scores(q, k, slot, pos, win, slope, scale=scale,
-                          softcap=softcap)
-        mask = jnp.logical_and(mask, slot < cs_ref[b])
-        _online_update(m_ref, l_ref, acc_ref, s, mask, v)
-
-    @pl.when(j == grid_steps - 1)
-    def _finalize():
-        out = _chunk_and_finalize(
-            m_ref, l_ref, acc_ref, q_ref[0, 0], ck_ref[0, 0], cv_ref[0, 0],
-            cpos_ref[0, 0].reshape(1, -1),                # (1, C); -1 = pad
-            pos, win, slope, scale=scale, softcap=softcap)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
-
-
 def _live_pages_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,  # scalar prefetch
                        q_ref, k_hbm, v_hbm, pos_ref, slope_ref,
                        ck_ref, cv_ref, cpos_ref,
@@ -194,12 +151,16 @@ def _live_pages_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,  # scalar prefe
                        kbuf, vbuf, sems, m_ref, l_ref, acc_ref,
                        *, page_size, pages_per_step, scale, softcap,
                        use_alibi, ring=None):
-    """One slot a grid step, every local kv head at once: walk the slot's
+    """One slot a grid step, with every local kv head at once or, where the
+    grid has a second axis, the kv heads that axis names: walk the slot's
     live pages [lo, cs) in groups of K, group g+1's pages on their way into
     the other half of (kbuf, vbuf) while group g computes."""
     K = pages_per_step
     span = K * page_size
     b = pl.program_id(0)
+    hs = q_ref.shape[1]                    # kv heads this step takes
+    heads = slice(None) if hs == k_hbm.shape[1] \
+        else pl.ds(pl.program_id(1) * hs, hs)
     cs = cs_ref[b]
     lo = lo_ref[b]
     first = lo // span
@@ -207,7 +168,7 @@ def _live_pages_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,  # scalar prefe
 
     def page_copies(g, half, start):
         # a page is copied iff it holds a slot of [lo, cs): whole
-        # (KVH, page, D) blocks, never part of a page. A group's dead tail
+        # (heads, page, D) blocks, never part of a page. A group's dead tail
         # keeps what the buffer held before — finite pool data or the zeros
         # below — under keys the staleness mask kills.
         for t in range(K):
@@ -225,7 +186,7 @@ def _live_pages_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,  # scalar prefe
                 rows = pl.ds(t * page_size, page_size)
                 for hbm, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
                     dma = pltpu.make_async_copy(
-                        hbm.at[lyr_ref[0], :, src], buf.at[half, :, rows],
+                        hbm.at[lyr_ref[0], heads, src], buf.at[half, :, rows],
                         sems.at[which, half])
                     if start:
                         dma.start()
@@ -247,9 +208,9 @@ def _live_pages_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,  # scalar prefe
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
     win = win_ref[0]
-    q = q_ref[0]                                          # (KVH, R, D)
+    q = q_ref[0]                                          # (heads, R, D)
     pos = pos_ref[0]                                      # (R, 1) int32
-    slope = slope_ref[...] if use_alibi else None         # (KVH, R, 1)
+    slope = slope_ref[...] if use_alibi else None         # (heads, R, 1)
 
     def group(g, carry):
         half = (g - first) % 2
@@ -307,9 +268,9 @@ def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
     ``ring`` (static): the pages are a ring behind a static ``window`` —
     ``block_tables`` is (B, ring) and position ``p`` lives in page
     ``table[slot, (p // bs) mod ring]`` (``kv_cache.CacheKind``: the ring is
-    long enough that every key the window admits is still in it). The
-    many-rows kernel then counts its steps from the window's first page, so
-    it takes ``ceil(ring / K)`` of them whatever the context. The kernel is
+    long enough that every key the window admits is still in it). The walk
+    is the same at every width: the window's pages ``[lo, cs)``, each
+    through ``mod ring``, whatever the context's length. The kernel is
     named ``paged_attn_ring_c<C>``.
     """
     if ring is not None:
@@ -330,16 +291,16 @@ def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
         window = 0
     softcap = float(softcap or 0.0)
 
-    fold, K = _tiling(rows, kvh, mb, page_size, d, kpool.dtype.itemsize)
-    # a folded step broadcasts per-row vectors against (KVH, R, T) scores:
-    # it takes them as columns, a head step as rows of a (1, R) block
-    col = (rows, 1) if fold else (1, rows)
+    hs, K = _tiling(rows, kvh, mb, page_size, d, kpool.dtype.itemsize)
+    # a step takes ``hs`` kv heads: all of them on grid (slots,), or a
+    # share on grid (slots, kv heads / hs)
+    split = hs < kvh
 
     # (B, C, H, D) → (B, KVH, C*G, D): row r = c*G + g
     qg = q.reshape(b, c, kvh, group, d).transpose(0, 2, 1, 3, 4).reshape(
         b, kvh, rows, d)
     # per-row positions: row r = c*G + g sits at positions[c]
-    pos_rep = jnp.repeat(positions, group, axis=1).reshape(b, *col)
+    pos_rep = jnp.repeat(positions, group, axis=1).reshape(b, rows, 1)
     valid = positions >= 0
     win_arr = jnp.asarray(window, jnp.int32).reshape(1)
     minpos = jnp.min(jnp.where(valid, positions, 1 << 30), axis=1)
@@ -365,120 +326,56 @@ def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
     use_alibi = alibi_slopes is not None
     if use_alibi:
         sl = jnp.asarray(alibi_slopes, jnp.float32).reshape(kvh, group)
-        slopes = jnp.tile(sl, (1, c)).reshape(kvh, *col)
+        slopes = jnp.tile(sl, (1, c)).reshape(kvh, rows, 1)
     else:
-        slopes = jnp.zeros((kvh, *col), jnp.float32)
+        slopes = jnp.zeros((kvh, rows, 1), jnp.float32)
 
-    kernel_args = dict(page_size=page_size, pages_per_step=K, scale=scale,
-                       softcap=softcap, use_alibi=use_alibi, ring=ring)
-    name = f"paged_attn_c{c}" if ring is None else f"paged_attn_ring_c{c}"
-    scalars = (lyr, block_tables, chunk_start, lo, win_arr)
-    scratch = [pltpu.VMEM((rows, 1), jnp.float32),
-               pltpu.VMEM((rows, 1), jnp.float32),
-               pltpu.VMEM((rows, d), jnp.float32)]
+    def head_of(idx):
+        return idx[0] if split else 0
 
-    if fold:
-        def slot_map(bi, *_):
-            return (bi, 0, 0, 0)
+    def slot_map(bi, *idx):
+        return (bi, head_of(idx), 0, 0)
 
-        def row_map(bi, *_):
-            return (bi, 0, 0)
+    def row_map(bi, *_):
+        return (bi, 0, 0)
 
-        def all_map(bi, *_):
-            return (0, 0, 0)
+    def head_map(bi, *idx):
+        return (head_of(idx), 0, 0)
 
-        out = pl.pallas_call(
-            functools.partial(_live_pages_kernel, **kernel_args),
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=5,
-                grid=(b,),
-                in_specs=[
-                    pl.BlockSpec((1, kvh, rows, d), slot_map),
-                    pl.BlockSpec(memory_space=pl.ANY),         # k pool
-                    pl.BlockSpec(memory_space=pl.ANY),         # v pool
-                    pl.BlockSpec((1, rows, 1), row_map),
-                    pl.BlockSpec((kvh, rows, 1), all_map),
-                    pl.BlockSpec((1, kvh, c, d), slot_map),
-                    pl.BlockSpec((1, kvh, c, d), slot_map),
-                    pl.BlockSpec((1, 1, c), row_map),
-                ],
-                out_specs=pl.BlockSpec((1, kvh, rows, d), slot_map),
-                scratch_shapes=[
-                    pltpu.VMEM((2, kvh, K * page_size, d), kpool.dtype),
-                    pltpu.VMEM((2, kvh, K * page_size, d), vpool.dtype),
-                    pltpu.SemaphoreType.DMA((2, 2)),
-                    *[pltpu.VMEM((kvh, *s.shape), s.dtype) for s in scratch],
-                ],
-            ),
-            out_shape=jax.ShapeDtypeStruct((b, kvh, rows, d), q.dtype),
-            name=name,
-            interpret=jax.default_backend() != "tpu",
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary",)),
-        )(*scalars, qg, kpool, vpool, pos_rep, slopes, ckg, cvg, cpos)
-    else:
-        grid_steps = -(-mb // K)      # a ring's mb is the ring
-
-        def q_map(bi, hi, ji, lyr_, bt, lens, lo_, w_):
-            return (bi, hi, 0, 0)
-
-        def kv_map_t(t):
-            # t-th page of this grid step's K-page group. The page lookup is
-            # clamped into the sequence's LIVE range [lo/bs, ceil(cs/bs)-1]:
-            # steps outside it all map to the same page, and Pallas elides the
-            # DMA when consecutive grid steps index an identical block — dead
-            # pages (beyond the sequence, or below the sliding window) cost no
-            # HBM traffic. Correctness is unaffected: the kernel masks by the
-            # LOGICAL slot (ji*K+t), not the fetched page.
-            def kv_map(bi, hi, ji, lyr_, bt, cs, lo_, w_):
-                last = jnp.maximum((cs[bi] + page_size - 1) // page_size - 1, 0)
-                jt = jnp.clip(ji * K + t, lo_[bi] // page_size, last)
-                return (lyr_[0], hi, bt[bi, jt], 0, 0)
-
-            def ring_map(bi, hi, ji, lyr_, bt, cs, lo_, w_):
-                last = jnp.maximum((cs[bi] + page_size - 1) // page_size - 1, 0)
-                first = lo_[bi] // page_size
-                jt = jnp.clip(first + ji * K + t, first, last)
-                return (lyr_[0], hi, bt[bi, jt % ring], 0, 0)
-            return kv_map if ring is None else ring_map
-
-        def pos_map(bi, hi, ji, lyr_, bt, lens, lo_, w_):
-            return (bi, 0, 0)
-
-        def slope_map(bi, hi, ji, lyr_, bt, lens, lo_, w_):
-            return (hi, 0, 0)
-
-        def chunk_map(bi, hi, ji, lyr_, bt, lens, lo_, w_):
-            return (bi, hi, 0, 0)
-
-        page_spec = [pl.BlockSpec((1, 1, 1, page_size, d), kv_map_t(t))
-                     for t in range(K)]
-        out = pl.pallas_call(
-            functools.partial(_head_step_kernel, grid_steps=grid_steps,
-                              **kernel_args),
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=5,
-                grid=(b, kvh, grid_steps),
-                in_specs=[
-                    pl.BlockSpec((1, 1, rows, d), q_map),
-                    *page_spec,                                # K k-pages
-                    *page_spec,                                # K v-pages
-                    pl.BlockSpec((1, 1, rows), pos_map),
-                    pl.BlockSpec((1, 1, rows), slope_map),
-                    pl.BlockSpec((1, 1, c, d), chunk_map),
-                    pl.BlockSpec((1, 1, c, d), chunk_map),
-                    pl.BlockSpec((1, 1, c), pos_map),
-                ],
-                out_specs=pl.BlockSpec((1, 1, rows, d), q_map),
-                scratch_shapes=scratch,
-            ),
-            out_shape=jax.ShapeDtypeStruct((b, kvh, rows, d), q.dtype),
-            name=name,
-            interpret=jax.default_backend() != "tpu",
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-        )(*scalars, qg, *([kpool] * K), *([vpool] * K), pos_rep, slopes,
-          ckg, cvg, cpos)
+    out = pl.pallas_call(
+        functools.partial(
+            _live_pages_kernel, page_size=page_size, pages_per_step=K,
+            scale=scale, softcap=softcap, use_alibi=use_alibi, ring=ring),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(b, kvh // hs) if split else (b,),
+            in_specs=[
+                pl.BlockSpec((1, hs, rows, d), slot_map),
+                pl.BlockSpec(memory_space=pl.ANY),             # k pool
+                pl.BlockSpec(memory_space=pl.ANY),             # v pool
+                pl.BlockSpec((1, rows, 1), row_map),
+                pl.BlockSpec((hs, rows, 1), head_map),
+                pl.BlockSpec((1, hs, c, d), slot_map),
+                pl.BlockSpec((1, hs, c, d), slot_map),
+                pl.BlockSpec((1, 1, c), row_map),
+            ],
+            out_specs=pl.BlockSpec((1, hs, rows, d), slot_map),
+            scratch_shapes=[
+                pltpu.VMEM((2, hs, K * page_size, d), kpool.dtype),
+                pltpu.VMEM((2, hs, K * page_size, d), vpool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((hs, rows, 1), jnp.float32),        # m
+                pltpu.VMEM((hs, rows, 1), jnp.float32),        # l
+                pltpu.VMEM((hs, rows, d), jnp.float32),        # acc
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, rows, d), q.dtype),
+        name=f"paged_attn_c{c}" if ring is None else f"paged_attn_ring_c{c}",
+        interpret=jax.default_backend() != "tpu",
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * (1 + split)),
+    )(lyr, block_tables, chunk_start, lo, win_arr,
+      qg, kpool, vpool, pos_rep, slopes, ckg, cvg, cpos)
     # (B, KVH, C*G, D) → (B, C, H, D)
     return out.reshape(b, kvh, c, group, d).transpose(0, 2, 1, 3, 4).reshape(
         b, c, h, d)

@@ -98,25 +98,89 @@ def test_narrow_paged_attention_compiles_at_the_cells_shapes(one_chip, as_tpu,
     assert "tpu_custom_call" in text
 
 
-# sha256 of the traced program (wrapper and kernel, no source locations) of
-# the chunked-prefill attention at the mistral cells' shapes, as PR 28 left
-# it (`git archive 0dbf65d`): the Mosaic module's own text carries file
-# lines and function names, the jaxpr does not
-WIDE_PAGED_JAXPR = \
-    "824ff7285a669774b71d613999687011d075b02455a375935aef19a2de154129"
+# the serve cells' attention shapes: (query heads, kv heads, table width,
+# window, ring, pool pages); 16 slots, pages of 128, head_dim 128, bf16
+CELL_SHAPES = {
+    "mistral": (32, 8, 64, WINDOW, None, 416),
+    "olmoe": (16, 16, 32, 0, None, 416),
+    "mellum2-full": (32, 4, 256, 0, None, 4097),
+    "mellum2-ring": (32, 4, 10, 1024, 10, 161),
+}
 
 
-def test_wide_paged_attention_is_the_program_it_was():
-    """PR 29 rebuilt the narrow step's tiling and must not move the wide
-    one: at C = 128 the shape rule keeps one (slot, KV head, 8 pages) a grid
-    step, and what is traced there — index maps, kernel body, operands — is
-    PR 28's to the character. A change that means to move it re-pins."""
+def _cell_step(name, chunk, sharding):
+    """(the attention of one layer at a cell's shapes, its argument shapes)."""
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_ragged_attention
+    h, kvh, table, window, ring, pages = CELL_SHAPES[name]
+    kw = {"ring": ring} if ring else {}
+
+    def step(q, kpool, vpool, tables, positions, ck, cv, layer):
+        return paged_ragged_attention(q, kpool, vpool, tables, positions, ck,
+                                      cv, layer=layer, window=window, **kw)
+
+    return step, _paged_step_shapes(sharding, h, kvh, chunk, table, pages=pages)
+
+
+@pytest.mark.parametrize("cell", list(CELL_SHAPES))
+def test_wide_paged_attention_compiles_at_the_cells_shapes(one_chip, as_tpu,
+                                                           cell):
+    """A prefill chunk's kernel at the benchmark's shapes (C = 128: 512 rows
+    a KV head in mistral, 128 in OLMoE, 1,024 in Mellum2 over its table of
+    256 and its ring of 10): ONE Mosaic call a layer, named for the readers
+    of the trace, one KV head a grid step (four at 128 rows); the hand-made
+    page copies of those heads, the double buffer and the (R, K x 128) f32
+    score tile (8 pages a group, 4 at 1,024 rows) pass Mosaic's verifier
+    inside the VMEM budget ``_tiling`` reckons with, under the compiler's
+    scoped default."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    h, kvh, table, _, ring, _ = CELL_SHAPES[cell]
+    rows = 128 * h // kvh
+    heads, pages = pa._tiling(rows, kvh, table, PAGE, D, 2)
+    assert (heads, pages) == {128: (4, 8), 512: (1, 8), 1024: (1, 4)}[rows]
+    assert pa._step_bytes(heads, rows, pages * PAGE, D, 2) <= pa._VMEM_BUDGET
+    assert pa._VMEM_BUDGET < 16 << 20          # v5e's scoped default
+    step, shapes = _cell_step(cell, 128, one_chip)
+    text = _compile_text(step, *shapes)
+    name = "paged_attn_ring_c128" if ring else "paged_attn_c128"
+    assert len(re.findall(rf"%{name}\S* = ", text)) == 1
+    assert len(re.findall(r"%paged_attn_\S* = ", text)) == 1
+    assert "tpu_custom_call" in text
+
+
+# sha256 of the traced programs (wrapper and kernel, no source locations:
+# the Mosaic module's own text carries file lines and function names, the
+# jaxpr does not) of the narrow step's and the verify step's attention at
+# the cells' shapes, as PR 31 left them (`git archive ce6a163`)
+NARROW_PAGED_JAXPRS = {
+    ("mistral", 1):
+        "482457c4dc95d38d744dd6c1db36af410f589fab3bdb850673d409370d348506",
+    ("mistral", 3):
+        "de2fe1ccec61abac1fbacd119bfc3473707820d07d6086414ecccdfc915c1bc4",
+    ("olmoe", 1):
+        "07318bfce0969fccf9fc0a1b9b4c3ad12dc5640f60514f1a98a1884570536dc2",
+    ("olmoe", 3):
+        "76a3d1d997934f040a901e4327aca6e7e8f86198662d9374bdc90319ddeb71f6",
+    ("mellum2-full", 1):
+        "566b1d7bde3318cbc8493e370ec0466f0fd81312318afed223803223f2d735d9",
+    ("mellum2-ring", 1):
+        "2e9ed8400371d87ed877cf5a53737eaa1c245fdd1bd62c92c035e094045ab124",
+    ("mellum2-ring", 3):
+        "87eb0914d0f80b03926ea53bbdf4eccdc52f2651445b20f4d1a6713bb2f2238f",
+}
+
+
+@pytest.mark.parametrize("cell,chunk", list(NARROW_PAGED_JAXPRS),
+                         ids=[f"{n}-c{c}" for n, c in NARROW_PAGED_JAXPRS])
+def test_narrow_paged_attention_is_the_program_it_was(cell, chunk):
+    """PR 33 gave the wide tiling the narrow step's walk and must not move
+    the narrow step: at C = 1 and C = 3 (speculation's verify step) what is
+    traced — index maps, kernel body, operands — is PR 31's to the
+    character. A change that means to move it re-pins."""
     import hashlib
-    from deepspeed_tpu.ops.pallas.paged_attention import _tiling
-    assert _tiling(128 * (H // KVH), KVH, 64, PAGE, D, 2) == (False, 8)
-    jaxpr = jax.make_jaxpr(_paged_step)(*_paged_step_shapes(
-        None, H, KVH, 128, 64))
-    assert hashlib.sha256(str(jaxpr).encode()).hexdigest() == WIDE_PAGED_JAXPR
+    step, shapes = _cell_step(cell, chunk, None)
+    jaxpr = jax.make_jaxpr(step)(*shapes)
+    assert hashlib.sha256(str(jaxpr).encode()).hexdigest() == \
+        NARROW_PAGED_JAXPRS[cell, chunk]
 
 
 @pytest.mark.parametrize("chunk", [1, 3, 128],

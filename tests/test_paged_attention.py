@@ -255,7 +255,7 @@ def test_narrow_steps_walk_live_pages(heads, c, chunk, feature):
     from deepspeed_tpu.models.layers import alibi_slopes
     h, kvh = heads
     d, bs, mb, layers = 32, 16, 8, 3
-    assert pa._tiling(c * h // kvh, kvh, mb, bs, d, 4) == (True, 4)
+    assert pa._tiling(c * h // kvh, kvh, mb, bs, d, 4) == (kvh, 4)
     ctx = [0, 2 * bs, bs + 5, 5 * bs + 3, 7 * bs - c]
     b, nb = len(ctx), 1 + sum(-(-(x + c) // bs) for x in ctx)
     rng = np.random.default_rng(7)
@@ -308,17 +308,174 @@ def test_narrow_steps_walk_live_pages(heads, c, chunk, feature):
                                rtol=3e-5, atol=3e-5)
 
 
-def test_wide_chunks_keep_one_head_a_step():
-    """The tiling follows from shapes: a prefill chunk's rows fill the MXU,
-    so it keeps one (slot, KV head, 8 pages) a grid step at the serve
-    configurations' widths; a decode or speculation step folds its heads,
-    4 pages a group."""
+def test_tiles_follow_from_shapes():
+    """(kv heads a step, pages a group) from static shapes against the one
+    VMEM budget: a decode or speculation step takes every local kv head, 4
+    pages a group; a prefill chunk's rows fill the MXU, so it takes the
+    widest group whose f32 score tile fits for one head and as many heads
+    as fit beside it: one head and 8 pages at mistral's 512 rows, four heads
+    at OLMoE's 128, one head and 4 pages at Mellum2's 1,024."""
     from deepspeed_tpu.ops.pallas.paged_attention import _tiling
-    for kvh, group in ((8, 4), (16, 1), (2, 4)):          # mistral, OLMoE, tp=4
-        assert _tiling(128 * group, kvh, 64, 128, 128, 2) == (False, 8)
+    for kvh, group, heads in ((8, 4, 1), (16, 1, 4), (2, 4, 1)):
+        # mistral, OLMoE, mistral at tp=4
+        assert _tiling(128 * group, kvh, 64, 128, 128, 2) == (heads, 8)
         for c in (1, 3, 8):
-            assert _tiling(c * group, kvh, 64, 128, 128, 2) == (True, 4)
-    assert _tiling(4, 8, 2, 128, 128, 2) == (True, 2)     # a table of 2 pages
+            assert _tiling(c * group, kvh, 64, 128, 128, 2) == (kvh, 4)
+    assert _tiling(128 * 8, 4, 256, 128, 128, 2) == (1, 4)    # Mellum2, full
+    assert _tiling(128 * 8, 4, 10, 128, 128, 2) == (1, 4)     # ... its ring
+    assert _tiling(4, 8, 2, 128, 128, 2) == (8, 2)        # a table of 2 pages
+    assert _tiling(512, 8, 2, 128, 128, 2) == (1, 2)
+    assert _tiling(512, 1, 64, 128, 128, 2) == (1, 8)     # MQA: grid (slots,)
+    assert _tiling(128, 3, 64, 128, 128, 2) == (3, 8)     # heads divide evenly
+    assert _tiling(128, 6, 64, 128, 128, 2) == (3, 8)
+
+
+# ---- the wide path: live pages x the KV heads that fit a step -------------
+
+def _linear_reference(q, lin_k, lin_v, positions, *, window=0, scale=None,
+                      alibi_slopes=None, softcap=0.0):
+    """Causal (windowed) attention over a LINEAR context, which knows no
+    pages: q (B, C, H, D), lin_k / lin_v (B, T, KVH, D), positions (B, C)."""
+    group = q.shape[2] // lin_k.shape[2]
+    kk = jnp.repeat(lin_k, group, axis=2)
+    vv = jnp.repeat(lin_v, group, axis=2)
+    s = jnp.einsum("bchd,bkhd->bhck", q, kk) * (
+        scale if scale is not None else q.shape[-1] ** -0.5)
+    key = jnp.arange(lin_k.shape[1])[None, None, None, :]
+    pos = jnp.asarray(positions)[:, None, :, None]
+    if alibi_slopes is not None:
+        s = s + jnp.asarray(alibi_slopes, jnp.float32)[
+            None, :, None, None] * (key - pos)
+    if softcap:
+        s = softcap * jnp.tanh(s / softcap)
+    mask = key <= pos
+    if window:
+        mask &= key > pos - window
+    p = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
+    return jnp.einsum("bhck,bkhd->bchd", p, vv)
+
+
+# (query heads, kv heads, chunk, kv heads a step): GQA 4, MHA, G = 8 (MQA: the
+# one head on grid (slots,)), and two heads a step of four
+_WIDE_HEADS = [(8, 2, 32, 1), (2, 2, 128, 1), (8, 1, 16, 1), (16, 4, 32, 2)]
+_WIDE_FEATURES = ("plain", "window", "ring", "alibi", "softcap", "pool",
+                  "layer")
+
+
+def _wide_case(monkeypatch, h, kvh, c, heads, feature):
+    """A prefill chunk of 128 rows a KV head, ``heads`` KV heads a grid step
+    (the budget is set so that no more fit: at these sizes the real one
+    holds them all), over a pool filled the way a run fills it (page after
+    page through the table or the ring, the last page's tail stale), against
+    attention over the LINEAR context, which knows no pages. Slots: frozen (every row a pad); a context of whole
+    pages; a decoding row riding the chunk (one live row); a context ending
+    mid-page in the walk's second group; one three or more groups long
+    whose window starts inside a page of a later group."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    from deepspeed_tpu.models.layers import alibi_slopes
+    d, bs, layers, lyr = 32, 16, 3, 2 if feature == "layer" else 0
+    group = h // kvh
+    window = {"window": 8 * bs + 2 * bs + 3, "ring": 40}.get(feature, 0)
+    ring = -(-(window + c) // bs) + 1 if feature == "ring" else None
+    span = 8 * bs
+    ctx = [0, 2 * bs, 3 * bs + 5, span + 3 * bs + 5, 3 * span + 7]
+    if ring:
+        ctx[3:] = [ring * bs, 5 * ring * bs + 37]         # wrapped five times
+    mb = ring or -(-(max(ctx) + c) // bs)
+    monkeypatch.setattr(pa, "_VMEM_BUDGET",
+                        pa._step_bytes(heads, c * group, span, d, 4))
+    assert pa._tiling(c * group, kvh, mb, bs, d, 4) == (heads, min(8, mb))
+    b, nb = len(ctx), 1 + len(ctx) * mb
+    rng = np.random.default_rng(13)
+
+    def rand(*shape, scale=1.0):
+        return jnp.asarray(rng.standard_normal(shape) * scale, jnp.float32)
+
+    total = max(ctx) + c
+    lin_k, lin_v = rand(b, total, kvh, d), rand(b, total, kvh, d)
+    q = rand(b, c, h, d, scale=0.3)
+    kpool = np.array(rand(layers, kvh, nb, bs, d))        # stale everywhere
+    vpool = np.array(rand(layers, kvh, nb, bs, d))
+    tables = np.zeros((b, mb), np.int32)
+    tables[1:] = (1 + rng.permutation(nb - 1)[:(b - 1) * mb]).reshape(b - 1, mb)
+    positions = np.full((b, c), -1, np.int32)
+    for s, cs in enumerate(ctx):
+        if not cs:
+            continue                                      # frozen: pads
+        live = 1 if s == 2 else c                         # slot 2 decodes
+        positions[s, :live] = cs + np.arange(live)
+        held = cs + live if feature == "pool" else cs     # what a run wrote
+        for page in range(-(-held // bs)):
+            rows = slice(page * bs, min((page + 1) * bs, held))
+            n = rows.stop - rows.start
+            for pool, lin in ((kpool, lin_k), (vpool, lin_v)):
+                pool[lyr, :, tables[s, page % mb], :n] = \
+                    np.asarray(lin[s, rows]).transpose(1, 0, 2)
+    chunk = ()
+    if feature != "pool":
+        chunk = tuple(jnp.stack([lin[s, cs:cs + c] for s, cs in enumerate(ctx)])
+                      for lin in (lin_k, lin_v))
+    kw = {"window": window} if window else {}
+    if ring:
+        kw["ring"] = ring
+    if feature == "alibi":
+        kw["alibi_slopes"] = alibi_slopes(h)
+    if feature == "softcap":
+        kw.update(softcap=20.0, scale=0.3)
+    args = (q, jnp.asarray(kpool), jnp.asarray(vpool), jnp.asarray(tables),
+            jnp.asarray(positions), *chunk)
+    if feature == "layer":
+        out = jax.jit(lambda i, *a: pa.paged_ragged_attention(*a, layer=i))(
+            jnp.asarray(lyr, jnp.int32), *args)
+    else:
+        out = pa.paged_ragged_attention(*args, layer=lyr, **kw)
+
+    ref = _linear_reference(q, lin_k, lin_v, positions,
+                            **{k: v for k, v in kw.items() if k != "ring"})
+    valid = positions >= 0
+    assert valid[0].sum() == 0 and valid[2].sum() == 1 and valid[3:].all()
+    np.testing.assert_allclose(np.asarray(out)[valid], np.asarray(ref)[valid],
+                               rtol=3e-5, atol=3e-5)
+
+
+@pytest.mark.parametrize(
+    "heads,feature", [(hd, f) for hd in _WIDE_HEADS for f in _WIDE_FEATURES],
+    ids=[f"h{h}kv{k}-c{c}-{n}-a-step-{f}" for h, k, c, n in _WIDE_HEADS
+         for f in _WIDE_FEATURES])
+def test_wide_steps_walk_live_pages(monkeypatch, heads, feature):
+    """A prefill chunk takes one KV head a grid step, or the few that fit,
+    and walks the slot's live pages as the narrow step does: see
+    ``_wide_case``."""
+    _wide_case(monkeypatch, *heads, feature)
+
+
+class _Off:
+    """A scalar-prefetch ref that reads ``by`` off the truth."""
+
+    def __init__(self, ref, by):
+        self.ref, self.by = ref, by
+
+    def __getitem__(self, i):
+        return jnp.maximum(self.ref[i] + self.by, 0)
+
+
+@pytest.mark.parametrize("fault", ["last-page-skipped", "lo-a-page-late"])
+def test_wide_walk_comparison_sees_a_planted_fault(monkeypatch, fault):
+    """The comparison above is sharp enough: a walk that stops a page short
+    of ``cs``, or starts a page past ``lo``, fails it."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    sound = pa._live_pages_kernel
+
+    def faulty(lyr, bt, cs, lo, win, *rest, page_size, **kw):
+        if fault == "last-page-skipped":
+            cs = _Off(cs, -page_size)
+        else:
+            lo = _Off(lo, page_size)
+        return sound(lyr, bt, cs, lo, win, *rest, page_size=page_size, **kw)
+
+    monkeypatch.setattr(pa, "_live_pages_kernel", faulty)
+    with pytest.raises(AssertionError, match="Not equal to tolerance"):
+        _wide_case(monkeypatch, 8, 2, 32, 1, "window")
 
 
 # ---- a ring of pages behind the window (caches by layer kind) -------------
@@ -333,7 +490,7 @@ _RING_CASES = [(c, ctx) for c in (1, 3, 16) for ctx in (
 def test_ring_of_pages_matches_the_linear_context(c, ctx):
     """``ring=R``: position p lives in ``table[slot, (p // bs) mod R]``. The
     pool is filled the way a run fills it (page after page through the
-    ring, later pages over earlier ones) and the kernel — the narrow one at
+    ring, later pages over earlier ones) and the kernel — the narrow tiling at
     C = 1 and 3, the wide one at C = 16 (128 rows a KV head) — must equal
     windowed attention over the LINEAR context, which knows no pages:
     beside a frozen slot, contexts shorter than the ring, ending exactly on
@@ -342,7 +499,8 @@ def test_ring_of_pages_matches_the_linear_context(c, ctx):
     from deepspeed_tpu.ops.pallas import paged_attention as pa
     h, kvh, d, bs, ring, window, layers = 16, 2, 32, 16, 5, 40, 2
     assert ring * bs >= window + c + bs              # the ring's invariant
-    assert pa._tiling(c * h // kvh, kvh, ring, bs, d, 4)[0] == (c < 16)
+    assert pa._tiling(c * h // kvh, kvh, ring, bs, d, 4) == (
+        kvh, 4 if c < 16 else 5)     # at this size both heads fit a step
     start = {"shorter-than-the-ring": 23, "exactly-the-ring": ring * bs,
              "chunk-crosses-the-wrap": 2 * ring * bs - min(c, 5) + 1
              if c > 1 else 2 * ring * bs - 1,
@@ -378,16 +536,7 @@ def test_ring_of_pages_matches_the_linear_context(c, ctx):
         q, jnp.asarray(kpool), jnp.asarray(vpool),
         jnp.asarray(tables, jnp.int32), jnp.asarray(positions), ck, cv,
         layer=lyr, window=window, ring=ring)
-    # windowed causal attention over the linear context
-    group = h // kvh
-    kk = jnp.repeat(lin_k, group, axis=2)
-    vv = jnp.repeat(lin_v, group, axis=2)
-    s_ = jnp.einsum("bchd,bkhd->bhck", q, kk) * d ** -0.5
-    key = jnp.arange(total)[None, None, None, :]
-    pos = jnp.asarray(positions)[:, None, :, None]
-    mask = (key <= pos) & (key > pos - window)
-    p = jax.nn.softmax(jnp.where(mask, s_, -1e30), axis=-1)
-    ref = jnp.einsum("bhck,bkhd->bchd", p, vv)
+    ref = _linear_reference(q, lin_k, lin_v, positions, window=window)
     np.testing.assert_allclose(np.asarray(out)[1:], np.asarray(ref)[1:],
                                rtol=3e-5, atol=3e-5)
 
