@@ -9,7 +9,9 @@ One step's attention (``--chunk`` 1: the narrow kernel of a decode step;
 (mistral-7b: 32 query / 8 KV heads, window 4096, table width 64;
 OLMoE-1B-7B: 16 / 16, no window, table width 32; Mellum2-12B-A2.5B: 32 / 4,
 its two global layers over a table of 256 and its six windowed ones over a
-ring of 10 pages behind a window of 1,024; 16 slots, pages of 128,
+ring of 10 pages behind a window of 1,024; LongCat-Flash-Omni: 64 query
+heads on the ONE latent head of a pool of 640-lane rows, values the first
+512 lanes, scale 1 / sqrt(192), table width 128; 16 slots, pages of 128,
 head_dim 128, bf16) over live slots 1 / 3 / 16 and contexts 256 / 1,024 /
 4,096 / 7,168 (Mellum2: to 30,000): microseconds a layer, the pages a layer had to move
 (``live x ceil((cs - lo) / page)``, K and V of every KV head), their
@@ -22,6 +24,7 @@ sides. Needs the chip: the kernel's interpret mode times nothing.
 """
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -35,7 +38,11 @@ CONFIGS = {
     "olmoe-1b-7b": (16, 16, 0, 32, None, POOL_PAGES),
     "mellum2-full": (32, 4, 0, 256, None, 4097),
     "mellum2-window": (32, 4, 1024, 256, 10, 161),
+    "longcat-latent": (64, 1, 0, 128, None, 2049),
 }
+# the latent format: (a row's lanes, its leading lanes that are the value,
+# the scores' scale); one pool, no second
+LATENT = {"longcat-latent": (640, 512, 192 ** -0.5)}
 LIVE = (0, 1, 3, 16)
 CONTEXTS = (256, 1024, 4096, 7168, 16384, 30000)
 
@@ -65,17 +72,24 @@ def main():
     if dev.platform != "tpu":
         sys.exit(f"paged_decode_sweep needs the chip, found {dev.platform}")
 
-    @jax.jit
-    def reference(q, kpool, vpool, table, pos, ck, cv, window):
+    @functools.partial(jax.jit, static_argnames=("dv", "scale"))
+    def reference(q, kpool, vpool, table, pos, ck, cv, window, dv=None,
+                  scale=None):
         """ONE slot of layer 0 by gather, in float32: q (C, H, D), table
-        (MB,), pos (C,), ck / cv (C, KVH, D) -> (C, H, D)."""
+        (MB,), pos (C,), ck / cv (C, KVH, D) -> (C, H, D). The latent
+        format (``dv``): a value is its key's first ``dv`` lanes."""
         h, kvh = q.shape[1], kpool.shape[1]
+        D = q.shape[-1]
         f32 = jnp.float32
         k = kpool[0][:, table].reshape(kvh, -1, D).astype(f32)
-        v = vpool[0][:, table].reshape(kvh, -1, D).astype(f32)
-        held = k.shape[1]
         k = jnp.concatenate([k, ck.astype(f32).transpose(1, 0, 2)], axis=1)
-        v = jnp.concatenate([v, cv.astype(f32).transpose(1, 0, 2)], axis=1)
+        held = k.shape[1] - ck.shape[0]
+        if dv:
+            v = k[..., :dv]
+        else:
+            v = vpool[0][:, table].reshape(kvh, -1, D).astype(f32)
+            v = jnp.concatenate([v, cv.astype(f32).transpose(1, 0, 2)],
+                                axis=1)
         # the pool is good below the chunk's first position; the chunk's
         # own keys sit at the chunk's positions
         key = jnp.concatenate([jnp.arange(held), pos])[None, :]
@@ -83,14 +97,16 @@ def main():
         live &= jnp.concatenate([jnp.arange(held) < pos[0], pos >= 0])[None, :]
         live &= (key > pos[:, None] - window) | (window <= 0)
         qg = q.astype(f32).reshape(-1, kvh, h // kvh, D)
-        s = jnp.einsum("chgd,hkd->hgck", qg, k, precision="highest") * D ** -0.5
+        s = jnp.einsum("chgd,hkd->hgck", qg, k, precision="highest") * (
+            scale or D ** -0.5)
         s = jnp.where(live[None, None], s, -1e30)
         p = jax.nn.softmax(s, axis=-1)
         return jnp.einsum("hgck,hkd->chgd", p, v,
-                          precision="highest").reshape(-1, h, D)
+                          precision="highest").reshape(-1, h, v.shape[-1])
 
-    def bench(pool, h, window, mb, live, ctx, ring):
-        kvh, pool_pages = pool.shape[1], pool.shape[2]
+    def bench(pool, h, window, mb, live, ctx, ring, dv=None, scale=None):
+        kvh, pool_pages, D = pool.shape[1], pool.shape[2], pool.shape[-1]
+        vpool = None if dv else pool
         rng = np.random.default_rng(live * 10007 + ctx)
         q = jnp.asarray(rng.standard_normal((SLOTS, chunk, h, D)) * 0.1,
                         jnp.bfloat16)
@@ -105,23 +121,28 @@ def main():
             tables[s, :pages] = 1 + rng.permutation(pool_pages - 1)[:pages]
             pos[s] = ctx + np.arange(chunk)
         kw = {"ring": ring} if ring else {}
+        if dv:
+            kw.update(value_lanes=dv, scale=scale)
 
         @jax.jit
         def step(q, kpool, vpool, tables, pos, ck):
             def layer(i, x):
-                return paged_ragged_attention(
-                    x, kpool, vpool, tables, pos, ck, ck,
+                out = paged_ragged_attention(
+                    x, kpool, vpool, tables, pos, ck, None if dv else ck,
                     layer=i % LAYERS, window=window, **kw)
+                # a latent step's output is its value lanes wide
+                return x.at[..., :out.shape[-1]].set(out) if dv else out
             return jax.lax.fori_loop(0, args.iters * LAYERS, layer, q)
 
-        a = (q, pool, pool, jnp.asarray(tables), jnp.asarray(pos), ck)
+        a = (q, pool, vpool, jnp.asarray(tables), jnp.asarray(pos), ck)
         step(*a).block_until_ready()
         gap = None
         if live:
             # jitted: the pool is ONE argument however many operands of the
             # kernel it becomes
             one = jax.jit(lambda *a: paged_ragged_attention(
-                *a, a[-1], layer=0, window=window, **kw))(*a)
+                *a, None if dv else a[-1], layer=0, window=window,
+                **kw))(*a)
             ref_tables = a[3]
             if ring:
                 # the ring as the table it stands for: page p in slot p mod R
@@ -129,7 +150,7 @@ def main():
             gap = max(float(jnp.max(jnp.abs(
                 one[s].astype(jnp.float32) - reference(
                     q[s], pool, pool, ref_tables[s], a[4][s], ck[s], ck[s],
-                    window)))) for s in range(live))
+                    window, dv=dv, scale=scale)))) for s in range(live))
         times = []
         for _ in range(5):
             t = time.perf_counter()
@@ -138,7 +159,7 @@ def main():
         us = min(times) / (args.iters * LAYERS) * 1e6
         lo = max(ctx - window + 1, 0) if window else 0
         moved = live * (-(-ctx // PAGE) - lo // PAGE)
-        nbytes = moved * 2 * kvh * PAGE * D * 2
+        nbytes = moved * (1 if dv else 2) * kvh * PAGE * D * 2
         return {"us_a_layer": round(us, 2), "pages_moved": moved,
                 "max_gap_to_gather": gap,
                 "share_of_hbm_peak_pct": round(
@@ -148,8 +169,12 @@ def main():
         h, kvh, window, mb, ring, pool_pages = CONFIGS[name]
         if ring and not has_ring:
             continue                  # a checkout older than the rings
+        lanes, dv, scale = LATENT.get(name, (D, None, None))
+        if dv and "value_lanes" not in inspect.signature(
+                paged_ragged_attention).parameters:
+            continue                  # a checkout older than the format
         pool = jax.random.normal(jax.random.PRNGKey(kvh),
-                                 (LAYERS, kvh, pool_pages, PAGE, D),
+                                 (LAYERS, kvh, pool_pages, PAGE, lanes),
                                  jnp.bfloat16)
         for live in map(int, args.live.split(",")):
             for ctx in map(int, args.contexts.split(",")) if live else (0,):
@@ -159,7 +184,8 @@ def main():
                 row = {"label": args.label, "config": name, "chunk": chunk,
                        "live": live, "context": ctx,
                        "device": dev.device_kind,
-                       **bench(pool, h, window, mb, live, ctx, ring)}
+                       **bench(pool, h, window, mb, live, ctx, ring, dv,
+                               scale)}
                 print(json.dumps(row), flush=True)
 
 

@@ -318,7 +318,14 @@ class InferenceEngineV2:
         # always was, for a model whose layers are alike
         kinds = cache_kinds(cfg.layer_windows(), bs, max_blocks_per_seq,
                             c.prefill_chunk_size)
-        if kinds is None:
+        if cfg.latent_lanes:
+            # latent attention: one row a token and attention layer
+            from .model_implementations.archs import validate_latent_serving
+            validate_latent_serving(c, cfg, draft=draft_model is not None)
+            self.kv = BlockedKVCache(cfg.attn_layers, 1, cfg.latent_lanes,
+                                     num_blocks=num_blocks, block_size=bs,
+                                     dtype=cfg.act_dtype, latent=True)
+        elif kinds is None:
             self.kv = BlockedKVCache(cfg.num_layers, cfg.kv_heads, cfg.dims_per_head,
                                      num_blocks=num_blocks, block_size=bs,
                                      dtype=cfg.act_dtype, kv_dtype=c.kv_dtype)
@@ -535,6 +542,11 @@ class InferenceEngineV2:
                 f"{what}: this model mixes windowed and global layers and "
                 "keeps a cache a kind (kv_cache.LayeredKVCache); it is "
                 "served without a draft, a swap tier or a prefix cache")
+        if self.kv.latent:
+            raise NotImplementedError(
+                f"{what}: this model keeps one pool of latent rows "
+                "(kv_cache.BlockedKVCache(latent=True)); it is served "
+                "without a draft, a swap tier or a prefix cache")
 
     def attach_kv_tier(self, tier, tag: Optional[str] = None) -> None:
         """Attach an EXTERNAL (typically shared) ``KVSwapTier`` — the
@@ -1166,7 +1178,9 @@ class InferenceEngineV2:
                                    kv_blocks_total=self.kv.num_blocks,
                                    tp_degree=self._config.tp,
                                    kv_block_bytes=self.kv.block_bytes,
-                                   layered=self.runner.kinds is not None)
+                                   layered=self.runner.kinds is not None,
+                                   latent=bool(self.model.cfg.latent_lanes),
+                                   share=self.model.cfg.moe_is_share)
         sched = FifoPolicy() if scheduler is None else scheduler
         sched.begin_serve(self)
         return self._serve_guarded(slots, arrivals, sched, steps,
@@ -1269,7 +1283,8 @@ class InferenceEngineV2:
                 live_slots=slots.live_count(),
                 kv_blocks_in_use=self.kv.num_blocks - self.kv.free_blocks,
                 arrival_ewma=ewma, queue_depth=queue_depth,
-                kv_kinds=self.kv.in_use() if self.runner.kinds else None)
+                kv_kinds=self.kv.in_use() if self.runner.kinds
+                or self.kv.latent else None)
             return True
         if tel.enabled:
             # telemetry re-enabled mid-serve: the device vector holds

@@ -24,6 +24,13 @@ rolled back (``cs`` unchanged) has destroyed nothing it will read again.
 A model of one kind (no pattern: every benchmark model before Mellum2) is
 not grouped and builds ``BlockedKVCache`` as it always did.
 
+The latent format (``BlockedKVCache(latent=True)``; a model with latent
+attention, ``cfg.latent_lanes``): ONE pool ``(attention layers, 1, pages,
+block_size, lanes)`` and no second: a token's row is its latent and its
+shared RoPE key, the key of every query head, and its first
+``kv_lora_rank`` lanes are the value. ``v`` is None; allocator, tables,
+``blocks_for``, admission and the slot table are as they are.
+
 Quantized pages (``kv_dtype="int8"``): the pools become int8 with the last
 dim widened to D + 4 *scale lanes* — each (token, head) row stores its D
 quantized values followed by its f32 absmax scale bitcast into 4 int8 lanes
@@ -122,7 +129,7 @@ def cache_kinds(windows, block_size: int, max_blocks: int, chunk: int):
 class BlockedKVCache:
     def __init__(self, num_layers: int, kv_heads: int, head_dim: int,
                  num_blocks: int, block_size: int = 64, dtype=jnp.bfloat16,
-                 kv_dtype: Optional[str] = None):
+                 kv_dtype: Optional[str] = None, latent: bool = False):
         self.num_layers = num_layers
         self.kv_heads = kv_heads
         self.head_dim = head_dim
@@ -136,17 +143,27 @@ class BlockedKVCache:
         self.lanes = head_dim + KV_SCALE_LANES if self.quantized else head_dim
         pool_dtype = jnp.int8 if self.quantized else dtype
         shape = (num_layers, kv_heads, num_blocks, block_size, self.lanes)
+        self.latent = latent
         self.k = jnp.zeros(shape, pool_dtype)
-        self.v = jnp.zeros(shape, pool_dtype)
+        self.v = None if latent else jnp.zeros(shape, pool_dtype)
         self.allocator = BlockedAllocator(num_blocks)
         self._sharding = None       # set by shard(); places swap-in updates
 
     @property
     def block_bytes(self) -> int:
-        """Resident HBM bytes per block across BOTH pools — the unit the
-        byte-accounting telemetry multiplies block counts by."""
+        """Resident HBM bytes per block across BOTH pools (the latent
+        format's one) — the unit the byte-accounting telemetry multiplies
+        block counts by."""
         per_row = self.lanes * self.k.dtype.itemsize
-        return 2 * self.num_layers * self.kv_heads * self.block_size * per_row
+        pools = 1 if self.latent else 2
+        return (pools * self.num_layers * self.kv_heads * self.block_size
+                * per_row)
+
+    def in_use(self):
+        """``LayeredKVCache.in_use`` of the latent format's one kind."""
+        pages = self.num_blocks - self.free_blocks - 1
+        return ([("latent", pages, self.block_bytes)],
+                pages * self.block_size)
 
     def blocks_for(self, num_tokens: int) -> int:
         return (num_tokens + self.block_size - 1) // self.block_size
@@ -350,6 +367,8 @@ class LayeredKVCache:
     ``allocator``) is the table kind's, whose pool is what grows with the
     context and what ``num_kv_blocks`` sizes; a ring kind's pool follows
     from the slots: ``slots * ring`` pages and the trash page."""
+
+    latent = False      # every kind's pools are K and V by head
 
     def __init__(self, kinds, kv_heads: int, head_dim: int, num_blocks: int,
                  slots: int, block_size: int = 64, dtype=jnp.bfloat16):

@@ -203,6 +203,109 @@ class OlmoeContainer(LlamaContainer):
             moe_norm_topk=bool(_get(hf_cfg, "norm_topk_prob", default=False)))
 
 
+def _pair(transform=t_identity):
+    """A leaf stacked over a shortcut-connected layer's pair: the two
+    sources each through ``transform``."""
+    return lambda ws, cfg: np.stack([transform(w, cfg) for w in ws])
+
+
+def _both(template):
+    return [template.format(l="{l}", j=j) for j in (0, 1)]
+
+
+class LongcatFlashContainer(LayerContainer):
+    """LongCat-Flash (``modeling_longcat_flash.py``): a layer holds two
+    latent attentions (``self_attn.{0,1}``), two dense MLPs (``mlps.{0,1}``),
+    their four norms and one routed block ``mlp`` (router ``classifier``
+    over experts and zero experts, ``e_score_correction_bias``). The native
+    tree stacks a pair's leaves behind the layer axis
+    (``CausalLM._init_double_layer``). Every expert of the checkpoint is
+    held; a chip's share is a cut of the expert axis afterwards
+    (``num_experts`` / ``moe_router_experts``). Served path only."""
+
+    layer_mapping = {
+        "attn.wq_a": Param(_both("model.layers.{l}.self_attn.{j}.q_a_proj.weight"),
+                           _pair(t_linear)),
+        "attn.q_norm.scale": Param(
+            _both("model.layers.{l}.self_attn.{j}.q_a_layernorm.weight"), _pair()),
+        "attn.wq_b": Param(
+            _both("model.layers.{l}.self_attn.{j}.q_b_proj.weight"),
+            _pair(lambda w, cfg: w.T.reshape(
+                cfg.q_lora_rank, cfg.num_heads, -1))),
+        "attn.wkv_a": Param(
+            _both("model.layers.{l}.self_attn.{j}.kv_a_proj_with_mqa.weight"),
+            _pair(t_linear)),
+        "attn.kv_norm.scale": Param(
+            _both("model.layers.{l}.self_attn.{j}.kv_a_layernorm.weight"),
+            _pair()),
+        "attn.wkv_b": Param(
+            _both("model.layers.{l}.self_attn.{j}.kv_b_proj.weight"),
+            _pair(lambda w, cfg: w.T.reshape(
+                cfg.kv_lora_rank, cfg.num_heads, -1))),
+        "attn.wo": Param(
+            _both("model.layers.{l}.self_attn.{j}.o_proj.weight"),
+            _pair(lambda w, cfg: w.T.reshape(
+                cfg.num_heads, cfg.v_head_dim, cfg.hidden_size))),
+        "norm1.scale": Param(
+            _both("model.layers.{l}.input_layernorm.{j}.weight"), _pair()),
+        "norm2.scale": Param(
+            _both("model.layers.{l}.post_attention_layernorm.{j}.weight"),
+            _pair()),
+        "mlp.wi_gate": Param(_both("model.layers.{l}.mlps.{j}.gate_proj.weight"),
+                             _pair(t_linear)),
+        "mlp.wi_up": Param(_both("model.layers.{l}.mlps.{j}.up_proj.weight"),
+                           _pair(t_linear)),
+        "mlp.wo": Param(_both("model.layers.{l}.mlps.{j}.down_proj.weight"),
+                        _pair(t_linear)),
+        "moe.router": Param("model.layers.{l}.mlp.router.classifier.weight",
+                            t_linear),
+        "moe.router_bias": Param(
+            "model.layers.{l}.mlp.router.e_score_correction_bias"),
+        "moe.wi_gate": Param(
+            "model.layers.{l}.mlp.experts.{x}.gate_proj.weight", t_linear),
+        "moe.wi_up": Param(
+            "model.layers.{l}.mlp.experts.{x}.up_proj.weight", t_linear),
+        "moe.wo": Param(
+            "model.layers.{l}.mlp.experts.{x}.down_proj.weight", t_linear),
+    }
+    non_layer_mapping = {
+        "embed.tok": Param("model.embed_tokens.weight"),
+        "embed.lm_head": Param("lm_head.weight", t_linear),
+        "final_norm.scale": Param("model.norm.weight"),
+    }
+
+    @classmethod
+    def config(cls, hf_cfg):
+        if _get(hf_cfg, "rope_scaling") is not None:
+            raise NotImplementedError(
+                "scaled RoPE on a latent attention's rope part is not mapped")
+        return TransformerConfig(
+            vocab_size=hf_cfg.vocab_size, hidden_size=hf_cfg.hidden_size,
+            num_layers=int(hf_cfg.num_layers),
+            num_heads=hf_cfg.num_attention_heads,
+            intermediate_size=int(hf_cfg.ffn_hidden_size),
+            moe_intermediate_size=int(hf_cfg.expert_ffn_hidden_size),
+            max_seq_len=hf_cfg.max_position_embeddings,
+            rope_theta=float(hf_cfg.rope_theta), rope_interleaved=True,
+            norm_eps=float(hf_cfg.rms_norm_eps),
+            num_experts=int(hf_cfg.n_routed_experts),
+            moe_zero_experts=int(_get(hf_cfg, "zero_expert_num", default=0)
+                                 or 0),
+            num_experts_per_tok=int(hf_cfg.moe_topk), moe_norm_topk=False,
+            moe_router_bias=True,
+            moe_routed_scale=float(hf_cfg.routed_scaling_factor),
+            moe_impl="grouped", kv_lora_rank=int(hf_cfg.kv_lora_rank),
+            q_lora_rank=int(hf_cfg.q_lora_rank),
+            qk_nope_head_dim=int(hf_cfg.qk_nope_head_dim),
+            qk_rope_head_dim=int(hf_cfg.qk_rope_head_dim),
+            v_head_dim=int(hf_cfg.v_head_dim),
+            mla_scale_q_lora=bool(_get(hf_cfg, "mla_scale_q_lora",
+                                       default=True)),
+            mla_scale_kv_lora=bool(_get(hf_cfg, "mla_scale_kv_lora",
+                                        default=True)),
+            shortcut_moe=True, tie_embeddings=False)
+
+
 class MellumContainer(LlamaContainer):
     """Mellum2 (JetBrains/Mellum2-12B-A2.5B-Instruct ``config.json``,
     ``model_type`` "mellum"): GQA with an explicit ``head_dim``, layers of
@@ -1204,6 +1307,7 @@ ARCH_CONTAINERS: Dict[str, Type[LayerContainer]] = {
     "qwen2moe": Qwen2MoeContainer,
     "olmoe": OlmoeContainer,
     "mellum": MellumContainer,
+    "longcatflash": LongcatFlashContainer,
     "qwen2": Qwen2Container,
     "phi3": Phi3Container,
     "phi": PhiContainer,
@@ -1321,6 +1425,37 @@ def validate_layered_serving(engine_config, draft: bool = False) -> None:
         raise NotImplementedError(
             "a model that mixes windowed and global layers keeps a cache a "
             "kind and cannot be served with: " + "; ".join(probs))
+
+
+def validate_latent_serving(engine_config, cfg: TransformerConfig,
+                            draft: bool = False) -> None:
+    """Fail LOUDLY at engine build for what a model with a latent (MLA)
+    cache, or with a share of its layer's experts, cannot be served with
+    yet. The first group moves, shares or packs pages as a K pool and a V
+    pool of equal shape; the second would need the experts' exchange
+    (ROADMAP M1 / M3's remainder)."""
+    c = engine_config
+    probs = []
+    if c.tp > 1:
+        probs.append(f"tp={c.tp} (one latent head does not split by head; "
+                     "no exchange for a share of the experts)")
+    if c.kv_dtype == "int8":
+        probs.append("kv_dtype='int8' (no packed latent row)")
+    if c.prefix_cache:
+        probs.append("prefix_cache (its page copies move two pools)")
+    if c.kv_swap_dir or c.role != "unified":
+        probs.append("the swap tier / prefill-decode handoff (kv_swap_dir, "
+                     "role): a record holds a K and a V payload")
+    if draft:
+        probs.append("a draft model (the speculative loops carry four pools)")
+    if cfg.moe_is_share and cfg.moe_impl != "grouped":
+        probs.append(f"moe_impl={cfg.moe_impl!r} with a router wider than "
+                     "the experts held (only the dropless path knows which "
+                     "experts it holds)")
+    if probs:
+        raise NotImplementedError(
+            "a model with a latent cache cannot be served with: "
+            + "; ".join(probs))
 
 
 def validate_tp_serving(cfg: TransformerConfig, tp: int,

@@ -31,9 +31,9 @@ from ...models.transformer import CausalLM
 from ...ops.attention import decode_attention
 from ..sampling import sample_logits_per_row, speculative_verify_per_row
 from .kv_cache import dequantize_kv_lanes, quantize_kv_lanes
-from .telemetry import (LAYER_STAT_NAMES, MAX_RUNGS,   # in-graph counter
-                        MOE_STAT_NAMES, n_stats,       # layout
-                        pack_ladder)
+from .telemetry import (LATENT_STAT_NAMES, LAYER_STAT_NAMES,   # in-graph
+                        MAX_RUNGS, MOE_STAT_NAMES, n_stats,    # counter
+                        pack_ladder)                           # layout
 
 
 def _use_pallas_paged() -> bool:
@@ -93,10 +93,19 @@ class PagedModelRunner:
         model of one kind, whose stat vector has no such lanes."""
         return None if self.kinds is None else self.cfg.layer_windows()
 
+    @property
+    def latent_layers(self):
+        """Attention layers that read latent rows (``LATENT_STAT_NAMES``
+        count their work summed over them), or None for a model that caches
+        K and V by head, whose stat vector has no such lanes."""
+        return self.cfg.attn_layers if self.cfg.latent_lanes else None
+
     def stat_context(self, max_seq_len: int, chunk: int) -> int:
         """The context ``telemetry.check_stat_range`` bounds a frame's
         largest work lane by: the uniform window or the longest sequence,
         and for a model of mixed kinds the layers' own, summed."""
+        if self.latent_layers:
+            return self.latent_layers * max_seq_len
         if self.kinds is None:
             return self.stat_window or max_seq_len
         return sum((w or max_seq_len) + chunk
@@ -129,7 +138,9 @@ class PagedModelRunner:
         """Lanes of the stat vector this model's serving loops carry: a
         model with routed experts counts their work in lanes of its own
         (``telemetry.n_stats``)."""
-        return n_stats(self.cfg.is_moe, self.kinds is not None)
+        return n_stats(self.cfg.is_moe, self.kinds is not None,
+                       share=self.cfg.moe_is_share,
+                       latent=bool(self.cfg.latent_lanes))
 
     def set_tp(self, tp_ctx) -> None:
         """Bind a ``tp.TPContext`` (engine setup, before any serving loop
@@ -171,7 +182,8 @@ class PagedModelRunner:
 
         A model of mixed cache kinds (``self.kinds``) takes ``block_tables``,
         ``kpool`` and ``vpool`` as tuples, one a kind, and gives the pools
-        back so."""
+        back so. A model with latent attention (``cfg.latent_lanes``) takes
+        its one pool of rows as ``kpool`` and None as ``vpool``."""
         cfg = self.cfg
         bs = self.block_size
         kinds = self.kinds
@@ -300,6 +312,24 @@ class PagedModelRunner:
                 y = L.apply_norm(lp["norm3"], y, cfg)
             return y
 
+        def routed_block(stack, experts, m_in, live):
+            """The routed block over ``m_in`` and its work in the order of
+            the stat vector's expert lanes. ``stack``: the layer as the
+            walk handed it; ``experts``: the layer's routed block."""
+            at_layer = None
+            if isinstance(stack, tuple):
+                # the grouped product is a kernel: it takes the stacked
+                # experts whole and the layer's index, never a slice
+                at_layer = stack[1]
+                whole = stack[0]["moe" if cfg.shortcut_moe else "mlp"]
+                experts = {**experts, **{n: whole[n]
+                                         for n in L.EXPERT_MATRICES}}
+            out, _, groups, *picks = L.apply_moe_mlp(
+                experts, m_in, cfg, live=live, layer=at_layer)
+            work = jnp.stack([jnp.sum(groups), jnp.sum(groups > 0),
+                              jnp.max(groups)]).astype(jnp.int32)
+            return out, jnp.concatenate([work] + picks) if picks else work
+
         def mlp(lp, h, y, moe, live=None):
             """The rest of the layer after attention and, in a model with
             routed experts (``routed``), their work in this layer
@@ -313,17 +343,7 @@ class PagedModelRunner:
                 h = h + y
                 m_in = L.apply_norm(lp["norm2"], h, cfg)
             if moe:
-                experts, at_layer = lp["mlp"], None
-                if isinstance(stack, tuple):
-                    # the grouped product is a kernel: it takes the stacked
-                    # experts whole and the layer's index, never a slice
-                    at_layer = stack[1]
-                    experts = {**experts, **{n: stack[0]["mlp"][n]
-                                             for n in L.EXPERT_MATRICES}}
-                mlp_out, _, groups = L.apply_moe_mlp(
-                    experts, m_in, cfg, live=live, layer=at_layer)
-                work = jnp.stack([jnp.sum(groups), jnp.sum(groups > 0),
-                                  jnp.max(groups)]).astype(jnp.int32)
+                mlp_out, work = routed_block(stack, lp["mlp"], m_in, live)
             else:
                 mlp_out = L.apply_mlp(
                     lp["mlp"], m_in, cfg,
@@ -344,6 +364,59 @@ class PagedModelRunner:
             from ...ops.pallas.kv_commit import kv_commit as commit
         else:
             commit = commit_scatter
+
+        def attend(q, k, v, kp, vp, tables, ring, at_pool, win):
+            """The chunk's queries over the row's pages of layer ``at_pool``
+            of the pools and over the chunk's own keys. The latent format:
+            ``vp`` and ``v`` None, a value is its key's first lanes."""
+            rkv = cfg.kv_lora_rank or None
+            scale = cfg.attn_scale
+            if rkv and scale is None:
+                scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+            if in_place:
+                # decode AND chunked prefill read pages in place (no
+                # gather); causal masking, sliding windows (uniform or
+                # per-layer traced), ALiBi, and attention softcapping all
+                # run in-kernel (the FastGen blocked-flash surface); the
+                # kernel indexes (layer, head, page) in the full pool
+                from ...ops.pallas.paged_attention import \
+                    paged_ragged_attention
+                return paged_ragged_attention(
+                    q, kp, vp, tables, positions, k, v, layer=at_pool,
+                    scale=scale, window=win, alibi_slopes=slopes,
+                    softcap=cfg.attn_softcap, ring=ring,
+                    **({"value_lanes": rkv} if rkv else {}))
+            kvh_loc = kp.shape[1]   # local KV heads (KVH/tp under tp)
+            lanes = kp.shape[-1]    # D, or D + scale lanes when int8
+            kl = jnp.take(kp, at_pool, axis=0)   # escape hatch: copies 1/L
+            if ring is not None:
+                # the ring as the table it stands for: logical page
+                # p in ring slot p mod R. A slot holds the newest
+                # page that maps to it; an older or a later one
+                # reads that page under positions the window and
+                # the staleness mask kill
+                tables = tables[:, jnp.arange(self.max_blocks) % ring]
+            kpages = kl[:, tables].reshape(
+                kvh_loc, b, -1, lanes).transpose(1, 2, 0, 3)
+            if rkv:
+                return _paged_attention(
+                    q, kpages, kpages[..., :rkv], positions, cfg,
+                    chunk_k=k, chunk_v=k[..., :rkv],
+                    chunk_start=chunk_start, scale=scale)
+            vl = jnp.take(vp, at_pool, axis=0)
+            vpages = vl[:, tables].reshape(
+                kvh_loc, b, -1, lanes).transpose(1, 2, 0, 3)
+            if quantized_kv:
+                kpages = dequantize_kv_lanes(kpages, dt)
+                vpages = dequantize_kv_lanes(vpages, dt)
+            # per-query causal mask via positions: query at position p
+            # sees cache slots [0, p]; masks by slot index. The chunk's
+            # own k/v ride in raw (pre-quantization) — only pool pages
+            # pay the quantize/dequantize round-trip.
+            return _paged_attention(q, kpages, vpages, positions, cfg,
+                                    window=win, chunk_k=k, chunk_v=v,
+                                    chunk_start=chunk_start,
+                                    alibi_slopes=slopes)
 
         def layer(h, xs, tag=None, cache=None):
             """``cache`` (a model of mixed cache kinds): the layer's kind's
@@ -366,45 +439,7 @@ class PagedModelRunner:
             # writes every layer's at once (``_run_layers``). Scanning
             # per-layer pool slices as xs/ys restacks the pools every step.
             with jax.named_scope("paged_attn"):
-                if in_place:
-                    # decode AND chunked prefill read pages in place (no
-                    # gather); causal masking, sliding windows (uniform or
-                    # per-layer traced), ALiBi, and attention softcapping all
-                    # run in-kernel (the FastGen blocked-flash surface); the
-                    # kernel indexes (layer, head, page) in the full pool
-                    from ...ops.pallas.paged_attention import \
-                        paged_ragged_attention
-                    out = paged_ragged_attention(
-                        q, kp, vp, tables, positions, k, v, layer=at_pool,
-                        scale=cfg.attn_scale, window=win, alibi_slopes=slopes,
-                        softcap=cfg.attn_softcap, ring=ring)
-                else:
-                    kvh_loc = kp.shape[1]   # local KV heads (KVH/tp under tp)
-                    lanes = kp.shape[-1]    # D, or D + scale lanes when int8
-                    kl = jnp.take(kp, at_pool, axis=0)   # escape hatch: copies 1/L
-                    vl = jnp.take(vp, at_pool, axis=0)
-                    if ring is not None:
-                        # the ring as the table it stands for: logical page
-                        # p in ring slot p mod R. A slot holds the newest
-                        # page that maps to it; an older or a later one
-                        # reads that page under positions the window and
-                        # the staleness mask kill
-                        tables = tables[:, jnp.arange(self.max_blocks) % ring]
-                    kpages = kl[:, tables].reshape(
-                        kvh_loc, b, -1, lanes).transpose(1, 2, 0, 3)
-                    vpages = vl[:, tables].reshape(
-                        kvh_loc, b, -1, lanes).transpose(1, 2, 0, 3)
-                    if quantized_kv:
-                        kpages = dequantize_kv_lanes(kpages, dt)
-                        vpages = dequantize_kv_lanes(vpages, dt)
-                    # per-query causal mask via positions: query at position p
-                    # sees cache slots [0, p]; masks by slot index. The chunk's
-                    # own k/v ride in raw (pre-quantization) — only pool pages
-                    # pay the quantize/dequantize round-trip.
-                    out = _paged_attention(q, kpages, vpages, positions, cfg,
-                                           window=win, chunk_k=k, chunk_v=v,
-                                           chunk_start=chunk_start,
-                                           alibi_slopes=slopes)
+                out = attend(q, k, v, kp, vp, tables, ring, at_pool, win)
             def dense_out(h, out, live=None):
                 with jax.named_scope("attn_out"):
                     y = attn_out(lp, out)
@@ -424,6 +459,64 @@ class PagedModelRunner:
                                *work)
                 return h, (k.astype(kp.dtype), v.astype(vp.dtype), *work)
 
+        def sub(lp, j):
+            """Attention, dense MLP and norms ``j`` of a double layer: one
+            slice of the stack (a layer's pair sliced first is a copy of
+            both)."""
+            stack, at_j = (lp[0], (lp[1], j)) if isinstance(lp, tuple) \
+                else (lp, j)
+            return {n: jax.tree.map(lambda a: a[at_j], stack[n])
+                    for n in ("attn", "mlp", "norm1", "norm2")}
+
+        def latent_qkv(lp, j, h, pos):
+            p = sub(lp, j)
+            return L.mla_query_and_row(
+                p["attn"], L.apply_norm(p["norm1"], h, cfg), pos, cfg,
+                inv_freq)
+
+        def half(lp, j, h, out, s):
+            """A double layer's half after its attention: the output
+            projection, the dense MLP and, in the first half, the routed
+            block, whose output ``s`` joins the stream at the end of the
+            second. ``s``: the first half's mask of live positions, the
+            second's routed output."""
+            p = sub(lp, j)
+            with jax.named_scope("attn_out"):
+                h = h + L.mla_output(p["attn"], out, cfg)
+            m_in = L.apply_norm(p["norm2"], h, cfg)
+            if j == 0:
+                s, work = routed_block(lp, at(lp)["moe"], m_in, s)
+            h = h + L.apply_mlp(p["mlp"], m_in, cfg)
+            return ((h, s), work) if j == 0 else h + s
+
+        def double_layer(h, xs, tag=None):
+            """A shortcut-connected layer (``cfg.shortcut_moe``): latent
+            attention and a dense MLP twice over cache layers 2 l and
+            2 l + 1, the routed block beside the second pair."""
+            lp, l, _ = xs
+            rows, s = [], ~is_pad
+            for j in (0, 1):
+                with jax.named_scope("attn_qkv"):
+                    q, row = _on_live(
+                        pack, functools.partial(latent_qkv, lp, j), h,
+                        pos_safe)
+                with jax.named_scope("paged_attn"):
+                    out = attend(q, row, None, kpool, None, block_tables,
+                                 None, 2 * l + j, None)
+                with jax.named_scope("mlp"):
+                    if j == 0:
+                        (h, s), work = _on_live(
+                            pack, functools.partial(half, lp, j), h, out,
+                            live=s)
+                    else:
+                        h = _on_live(pack, functools.partial(half, lp, j), h,
+                                     out, s)
+                rows.append(row.astype(kpool.dtype))
+            with jax.named_scope("kv_commit"):
+                return h, (jnp.stack(rows), None, work)
+
+        if cfg.shortcut_moe:
+            layer = double_layer
         if kinds is None:
             h, kpool, vpool, work = self._run_layers(
                 layer, h, params, kpool, vpool, windows,
@@ -483,6 +576,9 @@ class PagedModelRunner:
         h, (ck_all, cv_all, *work) = walk_layer_plan(
             model._plan, model._groups, walked,
             (layer_ids, windows), h, body)
+        if self.cfg.shortcut_moe:
+            # (layers, 2, ...) -> the attention layers' rows, in order
+            ck_all = ck_all.reshape((-1,) + ck_all.shape[2:])
         with jax.named_scope("kv_commit"):
             kpool, vpool = commit(kpool, vpool, ck_all, cv_all)
         return h, kpool, vpool, jnp.sum(work[0], axis=0) if work else None
@@ -672,7 +768,8 @@ class PagedModelRunner:
                                               width, greedy,
                                               window=self.stat_window,
                                               ladder=self.pack_ladder,
-                                              layers=self.layer_work)
+                                              layers=self.layer_work,
+                                              latent=self.latent_layers)
 
                 zero = jnp.zeros((b,), jnp.int32)
                 no = jnp.zeros((b,), bool)
@@ -754,7 +851,8 @@ class PagedModelRunner:
                                           width, greedy, repair=repair,
                                           window=self.stat_window,
                                           ladder=self.pack_ladder,
-                                          layers=self.layer_work)
+                                          layers=self.layer_work,
+                                          latent=self.latent_layers)
                 carry = (cached, produced, last_tok, done, poison, nonfinite,
                          stats, rng, kpool, vpool)
                 carry, (toks, emit) = jax.lax.scan(body, carry, None,
@@ -969,8 +1067,9 @@ def commit_scatter(kpool, vpool, chunk_k, chunk_v, block_tables, positions,
         block_tables, page, axis=1))                        # (B, C)
     off = pos_safe % bs
     # the advanced (B, C) indices are contiguous, so the indexed window is
-    # (L, KVH, B, C, D)
+    # (L, KVH, B, C, D); the latent format has one pool
     return (kpool.at[:, :, blk, off].set(chunk_k.transpose(0, 3, 1, 2, 4)),
+            None if vpool is None else
             vpool.at[:, :, blk, off].set(chunk_v.transpose(0, 3, 1, 2, 4)))
 
 
@@ -1048,7 +1147,7 @@ def _on_live(pack, fn, *xs, live=None):
 def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                        temps, tables, width, greedy, draft=None,
                        repair=False, window=None, ladder=pack_ladder,
-                       layers=None):
+                       layers=None, latent=None):
     """Shared scan-step for ``mixed_loop`` and ``frame_loop`` — the in-graph
     SplitFuse scheduling arithmetic lives in exactly one place.
 
@@ -1091,7 +1190,10 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
 
     ``layers`` (``PagedModelRunner.layer_work``, a model of mixed cache
     kinds): the step also counts its attention's work summed over the
-    layers, each under its own window (``_attn_work_by_layer``)."""
+    layers, each under its own window (``_attn_work_by_layer``).
+    ``latent`` (``PagedModelRunner.latent_layers``, a model with latent
+    attention): the step counts the latent rows its attention layers read
+    and the pairs they score (``LATENT_STAT_NAMES``)."""
     if draft is not None:
         return _spec_scan_body(fwd, params, prompts, prompt_lens, limits,
                                eos_ids, temps, tables, width, greedy, *draft,
@@ -1110,6 +1212,9 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
             kv_read, attn_pairs = _attn_work(cached, w, window)
             layer_work = None if layers is None else \
                 _attn_work_by_layer(cached, w, layers)
+            if latent:
+                layer_work = latent * jnp.stack(
+                    [jnp.sum(kv_read), jnp.sum(attn_pairs)]).astype(jnp.int32)
         logits, kpool, vpool, moe_work = fwd(params, ids, positions, tables,
                                              w, kpool, vpool, moe_work=True)
         with jax.named_scope("sample"):
@@ -1216,9 +1321,11 @@ def _stat_delta(positions, ladder, emitted=None, active=None,
     is the rung the forward chose for it (``_rung_of``, the same
     arithmetic), then one step at that rung. Behind them ``moe_work``, the
     target forward's own count of its routed experts' work
-    (``MOE_STAT_NAMES``), where the model has any (``telemetry.n_stats``),
-    and last ``layer_work`` (``LAYER_STAT_NAMES``), where the model mixes
-    cache kinds."""
+    (``MOE_STAT_NAMES``, and ``SHARE_STAT_NAMES`` behind them where its
+    router is wider than the experts held), where the model has any
+    (``telemetry.n_stats``), and last ``layer_work``: ``LAYER_STAT_NAMES``
+    where the model mixes cache kinds, ``LATENT_STAT_NAMES`` where its
+    attention is latent."""
     vals = [emitted, active, prefill_toks, eos, target_fwd, drafted, accepted,
             kv_read, attn_pairs]
     z = jnp.zeros((), jnp.int32)
@@ -1231,9 +1338,8 @@ def _stat_delta(positions, ladder, emitted=None, active=None,
     out = jnp.concatenate([jnp.stack(out), steps]
                           + ([] if moe_work is None else [moe_work])
                           + ([] if layer_work is None else [layer_work]))
-    assert layer_work is None or layer_work.shape == (len(LAYER_STAT_NAMES),)
-    assert out.shape == (n_stats(moe_work is not None,
-                                 layer_work is not None),)
+    assert layer_work is None or layer_work.shape[0] in (
+        len(LAYER_STAT_NAMES), len(LATENT_STAT_NAMES))
     return out
 
 
@@ -1468,7 +1574,7 @@ def _spec_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
 
 def _paged_attention(q, kpages, vpages, positions, cfg, window=None,
                      chunk_k=None, chunk_v=None, chunk_start=None,
-                     alibi_slopes=None):
+                     alibi_slopes=None, scale=None):
     """q: (B, C, H, D); kpages/vpages: (B, S_pad, KVH, D); positions: (B, C)
     absolute slot of each query (−1 = pad). Query at slot p attends slots ≤ p.
     ``window``: sliding-window width (may be traced; <= 0 = global).
@@ -1493,7 +1599,8 @@ def _paged_attention(q, kpages, vpages, positions, cfg, window=None,
         kpages = jnp.repeat(kpages, rep, axis=2)
         vpages = jnp.repeat(vpages, rep, axis=2)
     d = q.shape[-1]
-    scale = cfg.attn_scale if cfg.attn_scale is not None else d ** -0.5
+    if scale is None:
+        scale = cfg.attn_scale if cfg.attn_scale is not None else d ** -0.5
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, kpages,
                         preferred_element_type=jnp.float32) * scale
     if alibi_slopes is not None:

@@ -139,11 +139,29 @@ LAYER_STAT_NAMES = ("kv_positions_read_layers", "attn_pairs_layers",
                     "kv_positions_read_window")
 
 
-def n_stats(routed: bool, layered: bool = False) -> int:
+#: a router wider than the experts held (``cfg.moe_is_share``: zero
+#: experts, or one chip's share of a layer's experts), lanes behind
+#: MOE_STAT_NAMES, which then count the HELD experts' work: the live
+#: tokens' selections (tokens x k, summed over the layers), those that
+#: chose a zero expert, and those that chose an expert held elsewhere
+SHARE_STAT_NAMES = ("expert_selections", "zero_expert_selections",
+                    "absent_expert_selections")
+#: a model with latent attention, last lanes of its vector: latent rows its
+#: attention layers read and query x row pairs they score, summed over the
+#: attention layers (two a shortcut-connected layer). Work, so split by
+#: frame width like SPLIT_STAT_NAMES
+LATENT_STAT_NAMES = ("latent_positions_read", "latent_pairs")
+
+
+def n_stats(routed: bool, layered: bool = False, share: bool = False,
+            latent: bool = False) -> int:
     """Lanes of the stat vector of a model with (or without) routed
-    experts, and of mixed cache kinds or of one."""
+    experts (all of them held, or a share), of mixed cache kinds or of
+    one, with latent attention or without."""
     return (N_STATS + (len(MOE_STAT_NAMES) if routed else 0)
-            + (len(LAYER_STAT_NAMES) if layered else 0))
+            + (len(SHARE_STAT_NAMES) if share else 0)
+            + (len(LAYER_STAT_NAMES) if layered else 0)
+            + (len(LATENT_STAT_NAMES) if latent else 0))
 
 
 #: host work between two frames, in loop order; ``dispatch`` and ``fetch``
@@ -546,7 +564,7 @@ class ServingTelemetry:
         # {gauge: {kind: value}}, beside the gauge of the same name (which
         # stays the table kind's pool with its trash page)
         self.kind_gauges: Dict[str, Dict[str, int]] = {}
-        self._layered = False
+        self._tail_names, self._share = (), False
         # per-class TTFT (the bench/SLO acceptance surface)
         self.class_ttft: Dict[str, LogBucketHistogram] = {}
         # live SLO signal windows (recent samples, seconds)
@@ -575,7 +593,8 @@ class ServingTelemetry:
     def begin_serve(self, *, speculate: bool, gamma: int, adaptive: bool,
                     n_slots: int, kv_blocks_total: int,
                     tp_degree: int = 1, kv_block_bytes: int = 0,
-                    layered: bool = False) -> None:
+                    layered: bool = False, latent: bool = False,
+                    share: bool = False) -> None:
         """Called by ``serve()`` at generator construction.
         ``kv_block_bytes`` is the pool-resident footprint of one KV block
         across all layers (``BlockedKVCache.block_bytes``) — the
@@ -584,11 +603,19 @@ class ServingTelemetry:
         ``ds_serving_kv_resident_bytes``). ``layered``: the model keeps a
         cache a layer kind (``kv_cache.LayeredKVCache``) and its stat vector
         ends with LAYER_STAT_NAMES; only then do their counters, and the
-        gauges and sums ``on_frame`` keeps by kind, exist."""
+        gauges and sums ``on_frame`` keeps by kind, exist. ``latent``: the
+        model's attention is latent, its cache one pool of rows and its
+        vector's last lanes LATENT_STAT_NAMES, with the same gauges and
+        sums. ``share``: its router is wider than the experts it holds
+        (SHARE_STAT_NAMES behind the experts' lanes)."""
         self.reset()
-        self._layered = layered
-        if layered:
-            for n in LAYER_STAT_NAMES:
+        self._share = share
+        self._tail_names = (LAYER_STAT_NAMES if layered else
+                            LATENT_STAT_NAMES if latent else ())
+        for n in SHARE_STAT_NAMES if share else ():
+            self.counters[n] = 0
+        if self._tail_names:
+            for n in self._tail_names:
                 self.counters[f"{n}_narrow"] = 0
                 self.counters[f"{n}_wide"] = 0
             # the gauges below, summed over frames: a window's mean is the
@@ -1110,14 +1137,16 @@ class ServingTelemetry:
             return
         for i, name in enumerate(STAT_NAMES):
             self.counters[name] += int(delta[i])
-        # a model of mixed cache kinds ends its vector with the layered work
+        # a model of mixed cache kinds ends its vector with the layered
+        # work, one with latent attention with the latent rows' work
         layers = {}
-        if self._layered:
-            delta, tail = np.split(delta, [len(delta) - len(LAYER_STAT_NAMES)])
-            layers = dict(zip(LAYER_STAT_NAMES, map(int, tail)))
+        if self._tail_names:
+            delta, tail = np.split(delta, [len(delta) - len(self._tail_names)])
+            layers = dict(zip(self._tail_names, map(int, tail)))
         # a dense model's vector ends with the rung lanes
-        moe = dict.fromkeys(MOE_STAT_NAMES, 0)
-        moe.update(zip(MOE_STAT_NAMES, map(int, delta[STAT_EXPERT_ROWS:])))
+        moe_names = MOE_STAT_NAMES + (SHARE_STAT_NAMES if self._share else ())
+        moe = dict.fromkeys(moe_names, 0)
+        moe.update(zip(moe_names, map(int, delta[STAT_EXPERT_ROWS:])))
         for name, value in moe.items():
             self.counters[name] += value
         if self.trace:

@@ -102,6 +102,34 @@ class TransformerConfig:
     # routed-expert FFN width when it differs from the dense-MLP width
     # (Qwen2-MoE: moe_intermediate_size vs intermediate_size); None → ffn_size
     moe_intermediate_size: Optional[int] = None
+    # routed experts beyond the ones held (LongCat-Flash): the router scores
+    # ``moe_router_experts`` experts with weights (None: ``num_experts``,
+    # every expert held) and ``moe_zero_experts`` without (identity: the
+    # token itself, times its weight). ``num_experts`` stays the experts
+    # whose weights THIS model holds, ``moe_expert_first`` and on of the
+    # published ones: one chip's share of an expert-parallel layer. What the absent experts
+    # would have added is left out, no code stands in for the exchange.
+    moe_router_experts: Optional[int] = None
+    moe_expert_first: int = 0
+    moe_zero_experts: int = 0
+    moe_router_bias: bool = False       # choose by score + bias, weigh by score
+    moe_routed_scale: float = 1.0       # routed_scaling_factor on the weights
+    # latent attention (MLA, DeepSeek-V2; LongCat-Flash's layout): a
+    # low-rank query (q_lora_rank), ONE cached row a token and layer of
+    # kv_lora_rank values and a shared RoPE key of qk_rope_head_dim; heads
+    # of qk_nope_head_dim + qk_rope_head_dim for scores and v_head_dim for
+    # values. 0: the attention every other model has
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    mla_scale_q_lora: bool = False      # q x sqrt(hidden / q_lora_rank)
+    mla_scale_kv_lora: bool = False     # latent x sqrt(hidden / kv_lora_rank)
+    # shortcut-connected double layer (LongCat-Flash): a layer is two
+    # attentions and two dense MLPs, and ONE routed block that reads the
+    # stream after the first attention and joins at the layer's end
+    shortcut_moe: bool = False
     # numerics
     dtype: str = "bfloat16"             # activation dtype
     param_dtype: str = "float32"        # stored parameter dtype
@@ -155,6 +183,34 @@ class TransformerConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def moe_router_width(self) -> int:
+        """Outputs of the router: the published experts with weights, held
+        here or not, and the zero experts behind them."""
+        return (self.moe_router_experts or self.num_experts) \
+            + self.moe_zero_experts
+
+    @property
+    def moe_is_share(self) -> bool:
+        """The router scores more than the experts held: some selections
+        land on zero experts or on experts of other chips."""
+        return self.moe_router_width != self.num_experts
+
+    @property
+    def latent_lanes(self) -> int:
+        """Lanes of a latent cache row: the latent and the RoPE key, padded
+        so both parts sit on lane tiles (512 + 64 -> 640); 0 for a model
+        that caches K and V by head."""
+        if not self.kv_lora_rank:
+            return 0
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def attn_layers(self) -> int:
+        """Attention layers, each with its cache layer: two a layer in a
+        shortcut-connected stack."""
+        return self.num_layers * (2 if self.shortcut_moe else 1)
 
     def layer_windows(self) -> Optional[tuple]:
         """Per-layer window sizes (0 = global) of a stack that mixes
@@ -279,6 +335,23 @@ PRESETS = {
         sliding_window=1024, window_pattern=(1024, 1024, 1024, 0),
         norm_eps=1e-6, num_experts=64, num_experts_per_tok=8, moe_norm_topk=True,
         moe_impl="grouped"),
+    # LongCat-Flash-Omni's language model (meituan-longcat/LongCat-Flash-Omni
+    # config.json): 28 shortcut-connected double layers; MLA with ranks
+    # 1536 / 512, 64 heads of 128 + 64 (scores) and 128 (values), both
+    # low-rank paths rescaled; dense FFNs 12288 wide; a router over 512
+    # experts of width 2048 and 256 identity experts, top 12 chosen by score
+    # + bias, weighed by the score x 6, not renormalised; untied head.
+    # ``num_experts`` is what a chip holds: the whole 512 here, 16 of them
+    # (``--set num_experts=16 moe_router_experts=512``) on one chip of 32
+    "longcat-flash-omni": TransformerConfig(
+        vocab_size=131072, hidden_size=6144, num_layers=28, num_heads=64,
+        intermediate_size=12288, moe_intermediate_size=2048, max_seq_len=131072,
+        rope_theta=1e7, rope_interleaved=True, norm_eps=1e-5,
+        num_experts=512, moe_zero_experts=256, num_experts_per_tok=12,
+        moe_norm_topk=False, moe_router_bias=True, moe_routed_scale=6.0,
+        moe_impl="grouped", kv_lora_rank=512, q_lora_rank=1536,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        mla_scale_q_lora=True, mla_scale_kv_lora=True, shortcut_moe=True),
     # BERT family (post-norm encoder, MLM head; acceptance config 2 trains
     # bert-large under ZeRO-1/2)
     "bert-base": TransformerConfig(vocab_size=30522, hidden_size=768, num_layers=12, num_heads=12,

@@ -81,6 +81,8 @@ def apply_norm(params, x, cfg: TransformerConfig):
 # ---- rotary embeddings --------------------------------------------------
 
 def _rotary_dims(cfg: TransformerConfig) -> int:
+    if cfg.kv_lora_rank:        # latent attention rotates its RoPE part whole
+        return cfg.qk_rope_head_dim
     d = int(cfg.dims_per_head * cfg.rotary_pct)  # partial rotary (GPT-NeoX)
     return d - d % 2
 
@@ -239,6 +241,97 @@ def init_attention(rng, cfg: TransformerConfig):
             params[nm] = grp
             axes[nm] = grp_axes
     return params, axes
+
+
+# ---- latent attention (MLA) ----------------------------------------------
+
+def init_mla(rng, cfg: TransformerConfig):
+    """Latent attention's weights (DeepSeek-V2's names, heads kept as an
+    axis): ``wq_a`` / ``q_norm`` / ``wq_b`` the low-rank query, ``wkv_a`` the
+    cached row's projection (latent | RoPE key), ``kv_norm`` the latent's
+    norm, ``wkv_b`` (latent, heads, nope | value) what expands a latent to a
+    head's key and value, or is absorbed into the query and the output."""
+    e, h = cfg.hidden_size, cfg.num_heads
+    rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    r = jax.random.split(rng, 5)
+    std = 0.02
+    params = {
+        "wq_a": _normal(r[0], (e, rq), cfg.p_dtype, std),
+        "q_norm": {"scale": _ones((rq,), cfg.p_dtype)},
+        "wq_b": _normal(r[1], (rq, h, dn + dr), cfg.p_dtype, std),
+        "wkv_a": _normal(r[2], (e, rkv + dr), cfg.p_dtype, std),
+        "kv_norm": {"scale": _ones((rkv,), cfg.p_dtype)},
+        "wkv_b": _normal(r[3], (rkv, h, dn + dv), cfg.p_dtype, std),
+        "wo": _normal(r[4], (h, dv, e), cfg.p_dtype,
+                      std / math.sqrt(2 * cfg.attn_layers)),
+    }
+    axes = {
+        "wq_a": ("embed", "unmodeled"), "q_norm": {"scale": ("unmodeled",)},
+        "wq_b": ("unmodeled", "heads", "head_dim"),
+        "wkv_a": ("embed", "unmodeled"), "kv_norm": {"scale": ("unmodeled",)},
+        "wkv_b": ("unmodeled", "heads", "head_dim"),
+        "wo": ("heads", "head_dim", "embed"),
+    }
+    return params, axes
+
+
+def mla_scales(cfg: TransformerConfig):
+    """(s_q, s_kv): what ``mla_scale_q_lora`` / ``mla_scale_kv_lora`` put on
+    the query and on the latent, sqrt(hidden / rank)."""
+    return (math.sqrt(cfg.hidden_size / cfg.q_lora_rank)
+            if cfg.mla_scale_q_lora else 1.0,
+            math.sqrt(cfg.hidden_size / cfg.kv_lora_rank)
+            if cfg.mla_scale_kv_lora else 1.0)
+
+
+def mla_query_and_row(params, a, positions, cfg: TransformerConfig, inv_freq):
+    """The ABSORBED query and the cached row of normalised input ``a``
+    (B, S, E) at ``positions`` (B, S), both ``cfg.latent_lanes`` wide:
+    q (B, S, H, lanes) = [q_nope W_UK^T | RoPE(q_rope) | 0], row (B, S, 1,
+    lanes) = [s_kv RMSNorm(u) | RoPE(r) | 0], so that q . row is the
+    expanded form's q_nope . k_nope + q_rope . k_rope, and the row's first
+    ``kv_lora_rank`` lanes are the value before W_UV."""
+    dt = cfg.act_dtype
+    s_q, s_kv = mla_scales(cfg)
+    dn, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    pad = cfg.latent_lanes - rkv - cfg.qk_rope_head_dim
+
+    def lanes(*parts):
+        """``parts`` side by side, zeros behind them up to the row's lanes."""
+        zeros = [jnp.zeros(parts[0].shape[:-1] + (pad,), dt)] if pad else []
+        return jnp.concatenate(list(parts) + zeros, axis=-1)
+
+    with jax.named_scope("mla_q"):
+        cq = apply_norm(params["q_norm"], jnp.einsum(
+            "bse,er->bsr", a, dq(params["wq_a"], dt)), cfg)
+        q = jnp.einsum("bsr,rhd->bshd", cq, dq(params["wq_b"], dt))
+        if s_q != 1.0:
+            q = q * jnp.asarray(s_q, dt)
+        q_rope = apply_rope(q[..., dn:], positions, inv_freq,
+                            interleaved=cfg.rope_interleaved)
+    with jax.named_scope("mla_kv"):
+        kv = jnp.einsum("bse,er->bsr", a, dq(params["wkv_a"], dt))
+        c = apply_norm(params["kv_norm"], kv[..., :rkv], cfg)
+        if s_kv != 1.0:
+            c = c * jnp.asarray(s_kv, dt)
+        k_rope = apply_rope(kv[..., None, rkv:], positions, inv_freq,
+                            interleaved=cfg.rope_interleaved)
+        row = lanes(c[:, :, None], k_rope)
+    with jax.named_scope("mla_absorb"):
+        q_lat = jnp.einsum("bshn,rhn->bshr", q[..., :dn],
+                           dq(params["wkv_b"], dt)[..., :dn])
+    return lanes(q_lat, q_rope), row
+
+
+def mla_output(params, o_lat, cfg: TransformerConfig):
+    """(B, S, H, >= kv_lora_rank) attention output over latents -> (B, S,
+    E): W_UV a head, then the output projection."""
+    dt = cfg.act_dtype
+    with jax.named_scope("mla_absorb"):
+        o = jnp.einsum("bshr,rhv->bshv", o_lat[..., :cfg.kv_lora_rank],
+                       dq(params["wkv_b"], dt)[..., cfg.qk_nope_head_dim:])
+    return jnp.einsum("bshv,hve->bse", o, dq(params["wo"], dt))
 
 
 def apply_qk_norm(norm_params, x, cfg: TransformerConfig):
@@ -414,7 +507,7 @@ def init_moe_mlp(rng, cfg: TransformerConfig):
     r = jax.random.split(rng, 8)
     std = 0.02
     params = {
-        "router": _normal(r[0], (e, x), cfg.p_dtype, std),
+        "router": _normal(r[0], (e, cfg.moe_router_width), cfg.p_dtype, std),
         "wi_gate": _normal(r[1], (x, e, f), cfg.p_dtype, std),
         "wi_up": _normal(r[2], (x, e, f), cfg.p_dtype, std),
         "wo": _normal(r[3], (x, f, e), cfg.p_dtype, std / math.sqrt(2 * cfg.num_layers)),
@@ -425,6 +518,14 @@ def init_moe_mlp(rng, cfg: TransformerConfig):
         "wi_up": ("expert", "embed", "mlp"),
         "wo": ("expert", "mlp", "embed"),
     }
+    if cfg.moe_router_bias:
+        # the score correction that chooses the experts (a trained
+        # parameter): drawn small beside the top scores (~4 / width), so it
+        # changes some of a token's picks and not most
+        params["router_bias"] = _normal(
+            jax.random.fold_in(rng, 8), (cfg.moe_router_width,), jnp.float32,
+            1.0 / cfg.moe_router_width)
+        axes["router_bias"] = ("unmodeled",)
     if cfg.moe_shared_expert_size:
         s = cfg.moe_shared_expert_size
         params.update(
@@ -483,10 +584,19 @@ def apply_moe_grouped(params, x, cfg: TransformerConfig, live=None,
         logits = jnp.einsum("te,ex->tx", tokens.astype(jnp.float32),
                             params["router"].astype(jnp.float32))
         topk_idx, w, aux_loss = topk_gating_grouped(
-            logits, k=k, normalize=cfg.moe_norm_topk)
+            logits, k=k, normalize=cfg.moe_norm_topk,
+            bias=params.get("router_bias"), scale=cfg.moe_routed_scale)
 
+    share = cfg.moe_is_share
     with jax.named_scope("moe_dispatch"):
         expert_of_row = topk_idx.reshape(-1)                  # (T*k,)
+        if share:
+            # held: [first, first + n_exp) of the router's outputs; the
+            # zero experts are its last
+            local = topk_idx - cfg.moe_expert_first
+            held = (local >= 0) & (local < n_exp)
+            is_zero = topk_idx >= cfg.moe_router_width - cfg.moe_zero_experts
+            expert_of_row = jnp.where(held, local, n_exp).reshape(-1)
         if live is not None:
             expert_of_row = jnp.where(jnp.repeat(live.reshape(-1), k),
                                       expert_of_row, n_exp)
@@ -502,16 +612,26 @@ def apply_moe_grouped(params, x, cfg: TransformerConfig, live=None,
                               params["wi_up"], params["wo"], group_sizes,
                               layer)
     with jax.named_scope("moe_combine"):
-        if live is not None:
+        if live is not None or share:
             in_group = jnp.arange(t * k) < jnp.sum(group_sizes)
             rows = jnp.where(in_group[:, None], rows, jnp.zeros((), dt))
         w_sorted = jnp.take(w.reshape(-1), order, axis=0).astype(dt)
         out = jnp.zeros((t, e), dt).at[tok_of_sorted].add(
             rows * w_sorted[:, None])
+        if cfg.moe_zero_experts:
+            out = out + tokens.astype(dt) * jnp.sum(
+                jnp.where(is_zero, w, 0.0), axis=-1, keepdims=True).astype(dt)
     if cfg.moe_shared_expert_size:
         out = out + _apply_shared_expert(params, tokens.astype(dt), cfg)
     out = out.reshape(b, s, e)
-    return (out, aux_loss) if live is None else (out, aux_loss, group_sizes)
+    if live is None:
+        return out, aux_loss
+    if not share:
+        return out, aux_loss, group_sizes
+    picked = live.reshape(-1, 1) & jnp.ones_like(topk_idx, bool)
+    return out, aux_loss, group_sizes, jnp.stack(
+        [jnp.sum(picked), jnp.sum(picked & is_zero),
+         jnp.sum(picked & ~held & ~is_zero)]).astype(jnp.int32)
 
 
 def apply_moe_grouped_ep(params, x, cfg: TransformerConfig, mesh):

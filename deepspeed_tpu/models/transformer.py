@@ -250,6 +250,8 @@ class CausalLM:
 
     def _init_layer(self, rng, layer_type=None):
         cfg = self.cfg
+        if cfg.shortcut_moe:
+            return self._init_double_layer(rng)
         r_attn, r_mlp = jax.random.split(rng)
         attn, attn_axes = L.init_attention(r_attn, cfg)
         if (cfg.is_moe if layer_type is None else layer_type == "moe"):
@@ -264,6 +266,31 @@ class CausalLM:
             for nm in ("norm3", "norm4"):
                 params[nm], axes[nm] = L.init_norm(cfg)
         return params, axes
+
+    def _init_double_layer(self, rng):
+        """A shortcut-connected layer (LongCat-Flash): two latent
+        attentions, two dense MLPs and their four norms, each leaf stacked
+        over the pair (axis 0), and one routed block under ``moe``."""
+        cfg = self.cfg
+        r_attn, r_mlp, r_moe = jax.random.split(rng, 3)
+
+        def pair(init, rng=None):
+            if rng is None:
+                params, axes = init(cfg)
+                params = jax.tree.map(lambda a: jnp.stack([a, a]), params)
+            else:
+                axes = init(rng, cfg)[1]
+                params = jax.vmap(lambda r: init(r, cfg)[0])(
+                    jax.random.split(rng))
+            return params, jax.tree.map(lambda a: ("unmodeled",) + a, axes,
+                                        is_leaf=_is_axes_leaf)
+
+        parts = {"attn": pair(L.init_mla, r_attn),
+                 "mlp": pair(L.init_mlp, r_mlp),
+                 "norm1": pair(L.init_norm), "norm2": pair(L.init_norm),
+                 "moe": L.init_moe_mlp(r_moe, cfg)}
+        return ({n: p for n, (p, _) in parts.items()},
+                {n: a for n, (_, a) in parts.items()})
 
     def init(self, rng):
         cfg = self.cfg
@@ -338,6 +365,11 @@ class CausalLM:
     def _layer_fn(self, lp, h, positions, segment_ids, attn_bias=None, window=None,
                   layer_type=None, rope=None):
         cfg = self.cfg
+        if cfg.kv_lora_rank or cfg.shortcut_moe:
+            raise NotImplementedError(
+                "latent attention and shortcut-connected layers run on the "
+                "paged serving path (inference/v2) only: no training or "
+                "cache-less forward is written for them")
         rope = self._rope_args(rope)
         is_moe = cfg.is_moe if layer_type is None else layer_type == "moe"
         if cfg.act_quant_bits:

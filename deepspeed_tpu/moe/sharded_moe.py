@@ -83,8 +83,12 @@ def topk_gating_einsum(logits, k: int = 2, capacity_factor: float = 1.25,
     return combine, dispatch, aux
 
 
-def topk_gating_grouped(logits, k: int = 2, normalize: bool = True):
+def topk_gating_grouped(logits, k: int = 2, normalize: bool = True,
+                        bias=None, scale: float = 1.0):
     """Top-k gating for the grouped (megablox-style) dropless path.
+    ``bias`` (X,): the k experts are the largest of score + bias, their
+    weights the scores alone (DeepSeek-V3's / LongCat's correction bias);
+    ``scale`` multiplies the weights.
 
     Returns (topk_idx (T, k) int32, weights (T, k) fp32 normalized over the
     k choices, aux_loss). No capacity buffers: every token reaches its
@@ -93,12 +97,18 @@ def topk_gating_grouped(logits, k: int = 2, normalize: bool = True):
     """
     x = logits.shape[1]
     gates = jax.nn.softmax(logits, axis=-1)
-    topk_vals, topk_idx = jax.lax.top_k(gates, k)
+    if bias is None:
+        topk_vals, topk_idx = jax.lax.top_k(gates, k)
+    else:
+        _, topk_idx = jax.lax.top_k(gates + bias.astype(gates.dtype)[None], k)
+        topk_vals = jnp.take_along_axis(gates, topk_idx, axis=-1)
     if normalize:
         denom = jnp.sum(topk_vals, axis=-1, keepdims=True)
         w = topk_vals / jnp.maximum(denom, 1e-9)
     else:
         w = topk_vals
+    if scale != 1.0:
+        w = w * scale
     mask_tx = jnp.sum(jax.nn.one_hot(topk_idx, x, dtype=jnp.float32), axis=1)
     aux = load_balancing_loss(gates, mask_tx)
     return topk_idx.astype(jnp.int32), w.astype(jnp.float32), aux

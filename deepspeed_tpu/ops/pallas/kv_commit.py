@@ -30,8 +30,11 @@ from jax.experimental.pallas import tpu as pltpu
 _PAGE, _LIVE, _LO, _HI, _ROLL = range(5)
 
 
-def _commit_kernel(plan_ref, ck_ref, cv_ref, kin_ref, vin_ref,
-                   kout_ref, vout_ref):
+def _commit_kernel(plan_ref, *refs):
+    """``refs``: the chunks, the pools and the results, one each a pool
+    (K and V, or the latent format's one)."""
+    n = len(refs) // 3
+    kout_ref = refs[2 * n]
     s = pl.program_id(1)
 
     @pl.when(plan_ref[_LIVE, s] == 1)
@@ -40,8 +43,7 @@ def _commit_kernel(plan_ref, ck_ref, cv_ref, kin_ref, vin_ref,
         r = jax.lax.broadcasted_iota(jnp.int32, (rows, d), 0)
         mask = jnp.logical_and(r >= plan_ref[_LO, s], r < plan_ref[_HI, s])
         roll = plan_ref[_ROLL, s]
-        for src, old, new in ((ck_ref, kin_ref, kout_ref),
-                              (cv_ref, vin_ref, vout_ref)):
+        for src, old, new in zip(refs[:n], refs[n:2 * n], refs[2 * n:]):
             for h in range(kvh):
                 x = pltpu.roll(src[0, 0, h].astype(jnp.float32), roll, 0)
                 new[0, h, 0] = jnp.where(
@@ -94,7 +96,10 @@ def kv_commit(kpool, vpool, chunk_k, chunk_v, block_tables, positions,
     them so); pads may lie ahead of them or behind. Pads and wholly dead
     rows write nothing. ``ring`` (static): the (B, ring) table is a ring,
     position ``p`` lands in page ``table[slot, (p // bs) mod ring]``; the
-    kernel is then named ``kv_commit_ring_c<C>``. Returns (kpool, vpool)."""
+    kernel is then named ``kv_commit_ring_c<C>``. The latent format
+    (``kv_cache.py``): ``vpool`` and ``chunk_v`` are None, the one pool's
+    rows are committed alone and the kernel is named
+    ``kv_commit_mla_c<C>``. Returns (kpool, vpool)."""
     layers, kvh, _, page_size, d = kpool.shape
     b, c = positions.shape
     # a slot is a whole page: blocks of 16 rows in a decode step moved an
@@ -116,20 +121,26 @@ def kv_commit(kpool, vpool, chunk_k, chunk_v, block_tables, positions,
 
     chunk_spec = pl.BlockSpec((1, 1, kvh, chunk_rows, d), chunk_map)
     pool_spec = pl.BlockSpec((1, kvh, 1, rows, d), pool_map)
-    return pl.pallas_call(
+    pools = [kpool] if vpool is None else [kpool, vpool]
+    chunks = [chunk_k] if vpool is None else [chunk_k, chunk_v]
+    n = len(pools)
+    name = "kv_commit_mla" if vpool is None else \
+        "kv_commit" if ring is None else "kv_commit_ring"
+    out = pl.pallas_call(
         _commit_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(layers, b * slots),
-            in_specs=[chunk_spec, chunk_spec, pool_spec, pool_spec],
-            out_specs=[pool_spec, pool_spec],
+            in_specs=[chunk_spec] * n + [pool_spec] * n,
+            out_specs=[pool_spec] * n,
         ),
-        out_shape=[jax.ShapeDtypeStruct(kpool.shape, kpool.dtype),
-                   jax.ShapeDtypeStruct(vpool.shape, vpool.dtype)],
-        input_output_aliases={3: 0, 4: 1},   # the pools, written in place
-        name=f"kv_commit_c{c}" if ring is None else f"kv_commit_ring_c{c}",
+        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
+        # the pools, written in place
+        input_output_aliases={1 + n + i: i for i in range(n)},
+        name=f"{name}_c{c}",
         interpret=jax.default_backend() != "tpu",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
     )(_plan(positions, block_tables, rows, slots, chunk_rows, ring),
-      chunk_blocks(chunk_k), chunk_blocks(chunk_v), kpool, vpool)
+      *map(chunk_blocks, chunks), *pools)
+    return (out[0], None) if vpool is None else tuple(out)

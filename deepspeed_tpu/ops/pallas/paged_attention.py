@@ -37,6 +37,17 @@ copies nothing and goes straight to the chunk's own keys, a window moves
   a step timed 41% under one) — on grid (batch, KV heads / heads a step);
   where one head's tile of 8 pages does not fit (Mellum2's 1,024 rows) the
   group is 4 pages. A head's page is one contiguous ``(page, D)`` block.
+
+The latent format (``vpool=None``; ``kv_cache.py``): ONE pool of one "head"
+whose row is the key of every query head and whose first ``value_lanes``
+lanes are the value. The same walk, with one buffer and one copy a page;
+a prefill chunk's query rows (64 heads x 128 positions) are far more than
+a step's tiles hold, so the chunk's POSITIONS are tiled over the grid's
+second axis, every query head of a few positions a tile
+(``_latent_tiling``): the (C, H) order of the queries is the tiles' own, so
+nothing is transposed, and a tile none of whose positions is live (all but
+the first of a decoding slot riding a wide step, a frozen slot's every one)
+walks nothing and writes zeros (``_latent_kernel``).
 """
 
 import functools
@@ -58,12 +69,14 @@ _FOLD_PAGES = 4         # pages a folded step groups: 8 timed 2-15% slower at
 # does not fit OLMoE's 16 (benchmarks/paged_decode_sweep.py, PERF.md PR 29)
 
 
-def _step_bytes(heads, rows, keys, d, itemsize):
+def _step_bytes(heads, rows, keys, d, itemsize, value_lanes=None):
     """VMEM a step's tiles take with ``heads`` kv heads of ``rows`` query
-    rows against groups of ``keys`` keys."""
+    rows against groups of ``keys`` keys. ``value_lanes``: the latent
+    format, whose values are lanes of the one buffered row."""
+    dv, bufs = (d, 2 * d) if value_lanes is None else (value_lanes, d)
     return (heads * rows * keys * (4 + 4 + 2)        # s, exp(s - m), its cast
-            + 2 * 2 * heads * keys * d * itemsize    # K and V, double-buffered
-            + heads * rows * d * (4 + 2 * 2 * itemsize))   # acc; q, out x 2
+            + 2 * heads * keys * bufs * itemsize     # K and V, double-buffered
+            + heads * rows * (4 * dv + 2 * itemsize * (d + dv)))  # acc; q, out x 2
 
 
 def _tiling(rows, kvh, mb, page_size, d, itemsize):
@@ -84,6 +97,24 @@ def _tiling(rows, kvh, mb, page_size, d, itemsize):
     heads = max(h for h in range(1, kvh + 1)
                 if kvh % h == 0 and (h == 1 or fits(h, pages)))
     return heads, min(pages, mb)
+
+
+def _latent_tiling(c, h, mb, page_size, d, value_lanes, itemsize):
+    """(tiles of chunk positions, pages a group) of the latent format:
+    every query head reads the one pool head, so a step takes every head of
+    as many positions as fit the budget beside a group of ``_FOLD_PAGES``
+    pages (the one position of a decode step; 8 of a chunk of 128 at 64
+    heads: 512 rows), then the widest group that still fits."""
+    def fits(rows, pages):
+        return _step_bytes(1, rows, pages * page_size, d, itemsize,
+                           value_lanes) <= _VMEM_BUDGET
+
+    tiles = next(t for t in range(1, c + 1)
+                 if c % t == 0 and (t == c or fits(c * h // t, _FOLD_PAGES)))
+    pages = _HEAD_PAGES if c * h // tiles >= _MXU_ROWS else _FOLD_PAGES
+    while pages > _FOLD_PAGES and not fits(c * h // tiles, pages):
+        pages //= 2
+    return tiles, min(pages, mb)
 
 
 def _scores(q, k, key_pos, pos, win, slope, *, scale, softcap):
@@ -150,14 +181,20 @@ def _live_pages_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,  # scalar prefe
                        o_ref,
                        kbuf, vbuf, sems, m_ref, l_ref, acc_ref,
                        *, page_size, pages_per_step, scale, softcap,
-                       use_alibi, ring=None):
+                       use_alibi, ring=None, value_lanes=None, slot=None):
     """One slot a grid step, with every local kv head at once or, where the
     grid has a second axis, the kv heads that axis names: walk the slot's
     live pages [lo, cs) in groups of K, group g+1's pages on their way into
-    the other half of (kbuf, vbuf) while group g computes."""
+    the other half of (kbuf, vbuf) while group g computes. The latent format
+    (``_latent_kernel``) has no ``v_hbm``, ``cv_ref`` or ``vbuf``: a value
+    is the first ``value_lanes`` lanes of its key's row, and the grid's
+    slot index comes as ``slot`` (read outside the branch this runs in)."""
+    pools = ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1))
+    if v_hbm is None:
+        pools, vbuf = pools[:1], kbuf
     K = pages_per_step
     span = K * page_size
-    b = pl.program_id(0)
+    b = pl.program_id(0) if slot is None else slot
     hs = q_ref.shape[1]                    # kv heads this step takes
     heads = slice(None) if hs == k_hbm.shape[1] \
         else pl.ds(pl.program_id(1) * hs, hs)
@@ -184,7 +221,7 @@ def _live_pages_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,  # scalar prefe
                 else:
                     src = bt_ref[b, page % ring]
                 rows = pl.ds(t * page_size, page_size)
-                for hbm, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                for hbm, buf, which in pools:
                     dma = pltpu.make_async_copy(
                         hbm.at[lyr_ref[0], heads, src], buf.at[half, :, rows],
                         sems.at[which, half])
@@ -193,11 +230,12 @@ def _live_pages_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,  # scalar prefe
                     else:
                         dma.wait()
 
-    @pl.when(b == 0)
-    def _clean():
-        # 0 x NaN is NaN: a dead key's probability is exactly 0, so what
-        # sits under it in V must be finite
-        vbuf[...] = jnp.zeros_like(vbuf)
+    if slot is None:            # the latent kernel cleans outside its branch
+        @pl.when(b == 0)
+        def _clean():
+            # 0 x NaN is NaN: a dead key's probability is exactly 0, so
+            # what sits under it in V must be finite
+            vbuf[...] = jnp.zeros_like(vbuf)
 
     @pl.when(first < end)
     def _first():
@@ -225,22 +263,56 @@ def _live_pages_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,  # scalar prefe
         s, mask = _scores(q, kbuf[half], slot, pos, win, slope, scale=scale,
                           softcap=softcap)
         mask = jnp.logical_and(mask, slot < cs)           # stale pool slots
-        _online_update(m_ref, l_ref, acc_ref, s, mask, vbuf[half])
+        v = vbuf[half]
+        _online_update(m_ref, l_ref, acc_ref, s, mask,
+                       v if value_lanes is None else v[..., :value_lanes])
         return carry
 
     jax.lax.fori_loop(first, end, group, 0)
 
     out = _chunk_and_finalize(
-        m_ref, l_ref, acc_ref, q, ck_ref[0], cv_ref[0],
+        m_ref, l_ref, acc_ref, q, ck_ref[0],
+        cv_ref[0] if value_lanes is None else ck_ref[0][..., :value_lanes],
         cpos_ref[0, 0].reshape(1, -1), pos, win, slope, scale=scale,
         softcap=softcap)
     o_ref[0] = out.astype(o_ref.dtype)
 
 
+def _latent_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref, live_ref,
+                   q_ref, k_hbm, pos_ref, slope_ref, ck_ref, cpos_ref, o_ref,
+                   kbuf, sems, m_ref, l_ref, acc_ref, *, tile_positions,
+                   tiles, **kw):
+    """``_live_pages_kernel`` over the latent format's operands, for a tile
+    of ``tile_positions`` chunk positions some of which are live
+    (``live_ref`` (2, B): the slot's first live chunk index and the one
+    past its last); any other tile walks nothing and writes zeros."""
+    b = pl.program_id(0)
+    first = pl.program_id(1) * tile_positions if tiles > 1 else 0
+    live = jnp.logical_and(first < live_ref[1, b],
+                           first + tile_positions > live_ref[0, b])
+
+    @pl.when(jnp.logical_and(b == 0, first == 0))
+    def _clean():
+        # the walk's own cleaning would sit behind ``live``: a value is a
+        # lane of the key's buffer, which must be finite under a dead key
+        kbuf[...] = jnp.zeros_like(kbuf)
+
+    @pl.when(live)
+    def _walk():
+        _live_pages_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref, q_ref,
+                           k_hbm, None, pos_ref, slope_ref, ck_ref, None,
+                           cpos_ref, o_ref, kbuf, None, sems, m_ref, l_ref,
+                           acc_ref, slot=b, **kw)
+
+    @pl.when(jnp.logical_not(live))
+    def _dead():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
 def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
                            chunk_k=None, chunk_v=None, *, layer=None,
                            scale=None, window=0, alibi_slopes=None,
-                           softcap=0.0, ring=None):
+                           softcap=0.0, ring=None, value_lanes=None):
     """Unified paged attention for decode AND chunked prefill.
 
     q: (B, C, H, D) — C query tokens per sequence (1 = decode);
@@ -272,35 +344,59 @@ def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
     is the same at every width: the window's pages ``[lo, cs)``, each
     through ``mod ring``, whatever the context's length. The kernel is
     named ``paged_attn_ring_c<C>``.
+
+    The latent format: ``vpool`` and ``chunk_v`` None, ``kpool`` (L, 1, NB,
+    bs, lanes) and ``chunk_k`` (B, C, 1, lanes) the rows, q (B, C, H, lanes)
+    the absorbed queries, ``value_lanes`` (static) the row's leading lanes
+    that are its value. Returns (B, C, H, value_lanes); the kernel is named
+    ``paged_attn_mla_c<C>``.
     """
+    latent = vpool is None
+    assert latent == (value_lanes is not None) and not (latent and ring)
     if ring is not None:
         assert block_tables.shape[1] == ring and isinstance(window, int) \
             and 0 < window <= (ring - 1) * kpool.shape[-2], (ring, window)
     if kpool.ndim == 4:
         kpool = kpool[None]
-        vpool = vpool[None]
+        vpool = None if latent else vpool[None]
         layer = 0
     b, c, h, d = q.shape
     _, kvh, nb, page_size, _ = kpool.shape
     lyr = jnp.asarray(layer, jnp.int32).reshape(1)
     mb = block_tables.shape[1]
-    group = h // kvh
-    rows = c * group
+    pool_heads, dv = kvh, d
+    if latent:
+        # tiles of chunk positions stand where the kv heads stand below,
+        # each reading the pool's one head
+        kvh, K = _latent_tiling(c, h, mb, page_size, d, value_lanes,
+                                kpool.dtype.itemsize)
+        hs, dv, rows = 1, value_lanes, c * h // kvh
+        assert alibi_slopes is None
+    else:
+        group = h // kvh
+        rows = c * group
     scale = float(scale if scale is not None else d ** -0.5)
     if window is None:
         window = 0
     softcap = float(softcap or 0.0)
 
-    hs, K = _tiling(rows, kvh, mb, page_size, d, kpool.dtype.itemsize)
+    if not latent:
+        hs, K = _tiling(rows, kvh, mb, page_size, d, kpool.dtype.itemsize)
     # a step takes ``hs`` kv heads: all of them on grid (slots,), or a
     # share on grid (slots, kv heads / hs)
     split = hs < kvh
 
-    # (B, C, H, D) → (B, KVH, C*G, D): row r = c*G + g
-    qg = q.reshape(b, c, kvh, group, d).transpose(0, 2, 1, 3, 4).reshape(
-        b, kvh, rows, d)
-    # per-row positions: row r = c*G + g sits at positions[c]
-    pos_rep = jnp.repeat(positions, group, axis=1).reshape(b, rows, 1)
+    if latent:
+        # (B, C, H, D) as it lies: tile t holds positions [t C/T, (t+1) C/T)
+        # with every head, row r = c*H + h of the tile
+        qg = q.reshape(b, kvh, rows, d)
+        pos_rep = jnp.repeat(positions, h, axis=1).reshape(b, kvh * rows, 1)
+    else:
+        # (B, C, H, D) → (B, KVH, C*G, D): row r = c*G + g
+        qg = q.reshape(b, c, kvh, group, d).transpose(0, 2, 1, 3, 4).reshape(
+            b, kvh, rows, d)
+        # per-row positions: row r = c*G + g sits at positions[c]
+        pos_rep = jnp.repeat(positions, group, axis=1).reshape(b, rows, 1)
     valid = positions >= 0
     win_arr = jnp.asarray(window, jnp.int32).reshape(1)
     minpos = jnp.min(jnp.where(valid, positions, 1 << 30), axis=1)
@@ -308,14 +404,14 @@ def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
         # chunk KV → (B, KVH, C, D) blocks + (B, 1, C) key positions;
         # pool is valid only BELOW the chunk's first position
         ckg = chunk_k.astype(q.dtype).transpose(0, 2, 1, 3)
-        cvg = chunk_v.astype(q.dtype).transpose(0, 2, 1, 3)
+        cvg = None if latent else chunk_v.astype(q.dtype).transpose(0, 2, 1, 3)
         cpos = positions.reshape(b, 1, c)
         # fully-padded rows have no valid positions: zero pages, not 2^30
         chunk_start = jnp.where(minpos == 1 << 30, 0, minpos).astype(jnp.int32)
     else:
         # pool already holds every slot <= pos; dead chunk blocks
-        ckg = jnp.zeros((b, kvh, c, d), q.dtype)
-        cvg = ckg
+        ckg = jnp.zeros((b, pool_heads, c, d), q.dtype)
+        cvg = None if latent else ckg
         cpos = jnp.full((b, 1, c), -1, jnp.int32)
         chunk_start = (jnp.max(jnp.where(valid, positions, -1), axis=1)
                        + 1).astype(jnp.int32)
@@ -339,46 +435,77 @@ def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
     def row_map(bi, *_):
         return (bi, 0, 0)
 
+    def row_map_4(bi, *_):
+        return (bi, 0, 0, 0)
+
     def head_map(bi, *idx):
         return (head_of(idx), 0, 0)
 
+    def tile_map(bi, *idx):
+        return (bi, head_of(idx), 0)
+
+    # the latent format's every tile reads the chunk's one head of rows and
+    # has its own positions
+    chunk_map = row_map_4 if latent else slot_map
+    scalars = (lyr, block_tables, chunk_start, lo, win_arr)
+    if latent:
+        # the live chunk indices [first, end) of each slot (consecutive:
+        # ``kv_commit``'s contract too)
+        n_live = jnp.sum(valid, axis=1, dtype=jnp.int32)
+        first = jnp.argmax(valid, axis=1).astype(jnp.int32)
+        scalars += (jnp.stack([first, first + n_live]),)
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    chunk_spec = pl.BlockSpec((1, hs, c, d), chunk_map)
+    buffer = pltpu.VMEM((2, hs, K * page_size, d), kpool.dtype)
+    kernel, name, both = _live_pages_kernel, "paged_attn", [True, True]
+    if latent:
+        kernel, name, both = functools.partial(
+            _latent_kernel, tile_positions=c // kvh, tiles=kvh), \
+            "paged_attn_mla", [True, False]
+    elif ring is not None:
+        name = "paged_attn_ring"
+
+    def pools(k, v):
+        """``k`` and, but for the latent format, ``v`` behind it."""
+        return [x for x, keep in zip((k, v), both) if keep]
+
     out = pl.pallas_call(
         functools.partial(
-            _live_pages_kernel, page_size=page_size, pages_per_step=K,
-            scale=scale, softcap=softcap, use_alibi=use_alibi, ring=ring),
+            kernel, page_size=page_size, pages_per_step=K,
+            scale=scale, softcap=softcap, use_alibi=use_alibi, ring=ring,
+            **({"value_lanes": value_lanes} if latent else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
+            num_scalar_prefetch=len(scalars),
             grid=(b, kvh // hs) if split else (b,),
             in_specs=[
                 pl.BlockSpec((1, hs, rows, d), slot_map),
-                pl.BlockSpec(memory_space=pl.ANY),             # k pool
-                pl.BlockSpec(memory_space=pl.ANY),             # v pool
-                pl.BlockSpec((1, rows, 1), row_map),
+                *pools(pool_spec, pool_spec),
+                pl.BlockSpec((1, rows, 1), tile_map if latent else row_map),
                 pl.BlockSpec((hs, rows, 1), head_map),
-                pl.BlockSpec((1, hs, c, d), slot_map),
-                pl.BlockSpec((1, hs, c, d), slot_map),
+                *pools(chunk_spec, chunk_spec),
                 pl.BlockSpec((1, 1, c), row_map),
             ],
-            out_specs=pl.BlockSpec((1, hs, rows, d), slot_map),
+            out_specs=pl.BlockSpec((1, hs, rows, dv), slot_map),
             scratch_shapes=[
-                pltpu.VMEM((2, hs, K * page_size, d), kpool.dtype),
-                pltpu.VMEM((2, hs, K * page_size, d), vpool.dtype),
+                *pools(buffer, buffer),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.VMEM((hs, rows, 1), jnp.float32),        # m
                 pltpu.VMEM((hs, rows, 1), jnp.float32),        # l
-                pltpu.VMEM((hs, rows, d), jnp.float32),        # acc
+                pltpu.VMEM((hs, rows, dv), jnp.float32),       # acc
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, kvh, rows, d), q.dtype),
-        name=f"paged_attn_c{c}" if ring is None else f"paged_attn_ring_c{c}",
+        out_shape=jax.ShapeDtypeStruct((b, kvh, rows, dv), q.dtype),
+        name=f"{name}_c{c}",
         interpret=jax.default_backend() != "tpu",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * (1 + split)),
-    )(lyr, block_tables, chunk_start, lo, win_arr,
-      qg, kpool, vpool, pos_rep, slopes, ckg, cvg, cpos)
+    )(*scalars,
+      qg, *pools(kpool, vpool), pos_rep, slopes, *pools(ckg, cvg), cpos)
+    if latent:
+        return out.reshape(b, c, h, dv)
     # (B, KVH, C*G, D) → (B, C, H, D)
-    return out.reshape(b, kvh, c, group, d).transpose(0, 2, 1, 3, 4).reshape(
-        b, c, h, d)
+    return out.reshape(b, kvh, c, group, dv).transpose(0, 2, 1, 3, 4).reshape(
+        b, c, h, dv)
 
 
 def paged_decode_attention(q, kpool, vpool, block_tables, seq_lens, *,

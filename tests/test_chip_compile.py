@@ -441,6 +441,74 @@ def test_mellum2_frame_programs_fit_the_chip(one_chip, as_tpu, width):
     assert total < 15.75e9, total
 
 
+LONGCAT_CUT = dict(num_layers=4, num_experts=16, moe_router_experts=512,
+                   vocab_size=16384)
+
+
+@pytest.mark.parametrize("width", [1, 128], ids=["narrow", "wide"])
+def test_longcat_frame_programs_fit_the_chip(one_chip, as_tpu, width):
+    """The benchmark's LongCat-Flash-Omni configuration (published widths; 4
+    of 28 double layers, experts 0..15 of 512 beside the whole router, an
+    eighth of the vocabulary, bf16; 16 slots, 8 steps, sequences to 16,384:
+    tables of 128 pages over ONE pool of 2,049 pages of 640-lane rows for
+    the 8 attention layers): both frame programs compile with the chip's
+    compiler from shapes alone. A scan step is one double layer: the latent
+    kernel twice, one commit of the one pool in place (no value shaped like
+    it that XLA made), XLA's grouped-product kernel three times a layer and
+    rung, NO buffer shaped like one layer's held experts or a layer's pair
+    of dense matrices, and arguments and temporaries under 15.75 GB."""
+    import re
+    from deepspeed_tpu.inference.v2.model_runner import PagedModelRunner
+    from deepspeed_tpu.inference.v2.telemetry import pack_ladder
+    from deepspeed_tpu.models import build_model, get_config
+    slots, steps, pages, seq = 16, 8, 2049, 16384
+    cfg = get_config("longcat-flash-omni", **LONGCAT_CUT)
+    assert cfg.dtype == "bfloat16" and cfg.latent_lanes == 640
+    model = build_model(cfg.replace(param_dtype=cfg.dtype))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                          model.abstract_params())
+    i32, flag = jnp.int32, jnp.bool_
+    row = sds((slots,), i32)
+    pool = sds((cfg.attn_layers, 1, pages, PAGE, cfg.latent_lanes),
+               jnp.bfloat16)
+    key = jax.random.PRNGKey(0)
+    runner = PagedModelRunner(model, PAGE, seq // PAGE)
+    assert runner.n_stats == 16 + 3 + 3 + 2
+    compiled = runner._build_frame_loop().lower(
+        params, sds((slots, seq), i32), row, row, row,
+        sds((slots,), jnp.float32), sds((slots, seq // PAGE), i32), row, row,
+        row, sds((slots,), flag), sds((slots,), flag), sds((slots,), flag),
+        sds((runner.n_stats,), i32), sds(key.shape, key.dtype), pool, None,
+        width=width, steps=steps, greedy=True).compile()
+    text = compiled.as_text()
+    rungs = len(pack_ladder(slots, width))
+    assert len(re.findall(rf"%paged_attn_mla_c{width}\S* = ", text)) == 2
+    assert len(re.findall(r"%paged_attn_\S* = ", text)) == 2
+    assert len(re.findall(rf"%kv_commit_mla_c{width}\S* = ", text)) == 1
+    assert len(re.findall(r"%kv_commit_\S* = ", text)) == 1
+    assert len(re.findall(r"%ragged-dot-none\S* = ", text)) == 3 * rungs
+    shape = ",".join(map(str, pool.shape))
+    made = re.findall(
+        rf"= bf16\[{shape}\]\S* (copy|copy-start|fusion|scatter|transpose|"
+        rf"dynamic-update-slice)\(", text)
+    assert not made, made
+    stacks = re.findall(
+        r"= bf16\[(?:1,)?(?:16,(?:6144,2048|2048,6144)|2,(?:6144,12288|"
+        r"12288,6144))\]\S* (?!parameter|bitcast)(\w[\w-]*)\(", text)
+    assert not stacks, stacks
+    m = compiled.memory_analysis()
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes
+    print(f"longcat frame program, width {width}: args "
+          f"{m.argument_size_in_bytes / 1e9:.3f} GB + temp "
+          f"{m.temp_size_in_bytes / 1e9:.3f} GB")
+    assert 12.9e9 < m.argument_size_in_bytes < 13.1e9
+    assert total < 15.75e9, total
+
+
 def test_chip_smoke_fails_without_a_chip():
     """The suite runs on the CPU: ``chip_smoke.py`` must exit nonzero there
     and never print its success line (the children stop before any phase)."""

@@ -229,6 +229,9 @@ def test_paged_ragged_chunk_beside_pool(c, h, kvh, dtype):
 # ---- the narrow path: live pages x all KV heads a step --------------------
 
 _NARROW_HEADS = [(32, 8), (16, 16), (4, 1)]
+# the latent format, marked by 0 kv heads: G = 64 query heads and a small G
+# on the one pool's one head, values the row's first lanes
+_LATENT_HEADS = [(64, 0), (4, 0)]
 _NARROW_CASES = [
     (heads, c, chunk, feature)
     for heads in _NARROW_HEADS for c in (1, 3) for chunk in (True, False)
@@ -237,7 +240,10 @@ _NARROW_CASES = [
     (heads, c, True, feature)
     for heads in _NARROW_HEADS for c in (1, 3)
     for feature in ("alibi", "softcap", "layer")
-]
+] + [
+    (heads, c, chunk, "plain")
+    for heads in _LATENT_HEADS for c in (1, 3) for chunk in (True, False)
+] + [(heads, 1, True, "layer") for heads in _LATENT_HEADS]
 
 
 @pytest.mark.parametrize(
@@ -255,7 +261,12 @@ def test_narrow_steps_walk_live_pages(heads, c, chunk, feature):
     from deepspeed_tpu.models.layers import alibi_slopes
     h, kvh = heads
     d, bs, mb, layers = 32, 16, 8, 3
-    assert pa._tiling(c * h // kvh, kvh, mb, bs, d, 4) == (kvh, 4)
+    latent, dv = kvh == 0, 24
+    if latent:
+        kvh = 1
+        assert pa._latent_tiling(c, h, mb, bs, d, dv, 4)[0] == 1
+    else:
+        assert pa._tiling(c * h // kvh, kvh, mb, bs, d, 4) == (kvh, 4)
     ctx = [0, 2 * bs, bs + 5, 5 * bs + 3, 7 * bs - c]
     b, nb = len(ctx), 1 + sum(-(-(x + c) // bs) for x in ctx)
     rng = np.random.default_rng(7)
@@ -284,15 +295,24 @@ def test_narrow_steps_walk_live_pages(heads, c, chunk, feature):
         kw["alibi_slopes"] = alibi_slopes(h)
     if feature == "softcap":
         kw.update(softcap=20.0, scale=0.3)
+    if latent:
+        kw.update(value_lanes=dv, scale=0.2)
     lyr = 2 if feature == "layer" else 0
 
     if feature == "layer":
-        run = jax.jit(lambda i, *a: pa.paged_ragged_attention(*a, layer=i))
+        run = jax.jit(lambda i, *a: pa.paged_ragged_attention(
+            *a, layer=i, **kw))
         call = functools.partial(run, jnp.asarray(lyr, jnp.int32))
     else:
         call = functools.partial(pa.paged_ragged_attention, layer=lyr, **kw)
-    out = call(q, kpool, vpool, tables, positions,
-               *((ck, cv) if chunk else ()))
+    if latent:
+        out = call(q, kpool, None, tables, positions,
+                   *((ck, None) if chunk else ()))
+        assert out.shape == (b, c, h, dv)
+        vpool, cv = kpool, ck       # the reference's values: the row whole
+    else:
+        out = call(q, kpool, vpool, tables, positions,
+                   *((ck, cv) if chunk else ()))
 
     kfull, vfull = kpool[lyr], vpool[lyr]
     if chunk:                       # the reference reads the chunk from a pool
@@ -301,7 +321,10 @@ def test_narrow_steps_walk_live_pages(heads, c, chunk, feature):
         blk = jnp.where(positions >= 0, blk, 0)       # pads land in trash page 0
         kfull = kfull.at[:, blk, safe % bs].set(ck.transpose(2, 0, 1, 3))
         vfull = vfull.at[:, blk, safe % bs].set(cv.transpose(2, 0, 1, 3))
+    kw.pop("value_lanes", None)
     ref = _ragged_reference(q, kfull, vfull, tables, positions, **kw)
+    if latent:
+        ref = ref[..., :dv]
     valid = np.asarray(positions) >= 0
     assert valid[0].sum() == 0 and valid[1:].all(axis=1).sum() >= 3
     np.testing.assert_allclose(np.asarray(out)[valid], np.asarray(ref)[valid],
@@ -360,6 +383,11 @@ def _linear_reference(q, lin_k, lin_v, positions, *, window=0, scale=None,
 _WIDE_HEADS = [(8, 2, 32, 1), (2, 2, 128, 1), (8, 1, 16, 1), (16, 4, 32, 2)]
 _WIDE_FEATURES = ("plain", "window", "ring", "alibi", "softcap", "pool",
                   "layer")
+# the latent format (0 kv heads; the last number is the TILES of chunk
+# positions on the grid's second axis): 8 heads x 32 positions in 2 tiles,
+# G = 64 x 16 positions in 8 (slot 2's one live position: 7 dead tiles)
+_WIDE_LATENT = [(8, 0, 32, 2), (64, 0, 16, 8)]
+_WIDE_LATENT_FEATURES = ("plain", "window", "pool", "layer")
 
 
 def _wide_case(monkeypatch, h, kvh, c, heads, feature):
@@ -374,6 +402,9 @@ def _wide_case(monkeypatch, h, kvh, c, heads, feature):
     from deepspeed_tpu.ops.pallas import paged_attention as pa
     from deepspeed_tpu.models.layers import alibi_slopes
     d, bs, layers, lyr = 32, 16, 3, 2 if feature == "layer" else 0
+    latent, dv = kvh == 0, 24
+    if latent:
+        kvh, tiles = 1, heads
     group = h // kvh
     window = {"window": 8 * bs + 2 * bs + 3, "ring": 40}.get(feature, 0)
     ring = -(-(window + c) // bs) + 1 if feature == "ring" else None
@@ -382,9 +413,14 @@ def _wide_case(monkeypatch, h, kvh, c, heads, feature):
     if ring:
         ctx[3:] = [ring * bs, 5 * ring * bs + 37]         # wrapped five times
     mb = ring or -(-(max(ctx) + c) // bs)
-    monkeypatch.setattr(pa, "_VMEM_BUDGET",
-                        pa._step_bytes(heads, c * group, span, d, 4))
-    assert pa._tiling(c * group, kvh, mb, bs, d, 4) == (heads, min(8, mb))
+    if latent:
+        monkeypatch.setattr(pa, "_VMEM_BUDGET", pa._step_bytes(
+            1, c * h // tiles, span, d, 4, dv))
+        assert pa._latent_tiling(c, h, mb, bs, d, dv, 4) == (tiles, min(8, mb))
+    else:
+        monkeypatch.setattr(pa, "_VMEM_BUDGET",
+                            pa._step_bytes(heads, c * group, span, d, 4))
+        assert pa._tiling(c * group, kvh, mb, bs, d, 4) == (heads, min(8, mb))
     b, nb = len(ctx), 1 + len(ctx) * mb
     rng = np.random.default_rng(13)
 
@@ -393,6 +429,8 @@ def _wide_case(monkeypatch, h, kvh, c, heads, feature):
 
     total = max(ctx) + c
     lin_k, lin_v = rand(b, total, kvh, d), rand(b, total, kvh, d)
+    if latent:
+        lin_v = lin_k               # a value is its key's first lanes
     q = rand(b, c, h, d, scale=0.3)
     kpool = np.array(rand(layers, kvh, nb, bs, d))        # stale everywhere
     vpool = np.array(rand(layers, kvh, nb, bs, d))
@@ -424,14 +462,22 @@ def _wide_case(monkeypatch, h, kvh, c, heads, feature):
         kw.update(softcap=20.0, scale=0.3)
     args = (q, jnp.asarray(kpool), jnp.asarray(vpool), jnp.asarray(tables),
             jnp.asarray(positions), *chunk)
+    if latent:
+        kw.update(value_lanes=dv, scale=0.2)
+        args = (q, args[1], None, *args[3:5], *((chunk[0], None) if chunk
+                                                else ()))
     if feature == "layer":
-        out = jax.jit(lambda i, *a: pa.paged_ragged_attention(*a, layer=i))(
-            jnp.asarray(lyr, jnp.int32), *args)
+        out = jax.jit(lambda i, *a: pa.paged_ragged_attention(
+            *a, layer=i, **kw))(jnp.asarray(lyr, jnp.int32), *args)
     else:
         out = pa.paged_ragged_attention(*args, layer=lyr, **kw)
 
     ref = _linear_reference(q, lin_k, lin_v, positions,
-                            **{k: v for k, v in kw.items() if k != "ring"})
+                            **{k: v for k, v in kw.items()
+                               if k not in ("ring", "value_lanes")})
+    if latent:
+        assert out.shape == ref.shape[:3] + (dv,)
+        ref = ref[..., :dv]
     valid = positions >= 0
     assert valid[0].sum() == 0 and valid[2].sum() == 1 and valid[3:].all()
     np.testing.assert_allclose(np.asarray(out)[valid], np.asarray(ref)[valid],
@@ -439,9 +485,12 @@ def _wide_case(monkeypatch, h, kvh, c, heads, feature):
 
 
 @pytest.mark.parametrize(
-    "heads,feature", [(hd, f) for hd in _WIDE_HEADS for f in _WIDE_FEATURES],
+    "heads,feature", [(hd, f) for hd in _WIDE_HEADS for f in _WIDE_FEATURES]
+    + [(hd, f) for hd in _WIDE_LATENT for f in _WIDE_LATENT_FEATURES],
     ids=[f"h{h}kv{k}-c{c}-{n}-a-step-{f}" for h, k, c, n in _WIDE_HEADS
-         for f in _WIDE_FEATURES])
+         for f in _WIDE_FEATURES]
+    + [f"h{h}latent-c{c}-{n}-tiles-{f}" for h, _, c, n in _WIDE_LATENT
+       for f in _WIDE_LATENT_FEATURES])
 def test_wide_steps_walk_live_pages(monkeypatch, heads, feature):
     """A prefill chunk takes one KV head a grid step, or the few that fit,
     and walks the slot's live pages as the narrow step does: see

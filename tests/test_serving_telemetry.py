@@ -728,3 +728,104 @@ def test_layered_lanes_and_gauges_are_absent_for_a_model_of_one_kind(
     assert not [k for k in snap["snapshot"]["counters"]
                 if "_layers_" in k or "_window_" in k or k.endswith("_sum")]
     assert "kv_bytes_in_use" not in snap["snapshot"]["gauges"]
+
+
+# ---------------------------------------------------------------------------
+# latent attention and a share of the experts: their lanes, counters and
+# gauges exist for such a model, and for no other
+# ---------------------------------------------------------------------------
+
+LATENT_SERIES = (
+    "ds_serving_latent_positions_read_narrow_total",
+    "ds_serving_latent_positions_read_wide_total",
+    "ds_serving_latent_pairs_narrow_total",
+    "ds_serving_latent_pairs_wide_total",
+    "ds_serving_expert_selections_total",
+    "ds_serving_zero_expert_selections_total",
+    "ds_serving_absent_expert_selections_total",
+)
+
+
+def _latent_engine():
+    """2 double layers of latent attention (rows of 20 values in 128
+    lanes), a router over 8 experts of which 4 are held and 4 zero ones."""
+    from deepspeed_tpu.models import get_config
+    cfg = get_config(
+        "longcat-flash-omni", vocab_size=256, hidden_size=32, num_layers=2,
+        num_heads=4, intermediate_size=48, moe_intermediate_size=16,
+        q_lora_rank=12, kv_lora_rank=16, qk_nope_head_dim=8,
+        qk_rope_head_dim=4, v_head_dim=8, num_experts=4,
+        moe_router_experts=8, moe_zero_experts=4, num_experts_per_tok=2,
+        max_seq_len=128, dtype="float32")
+    model = build_model(cfg)
+    return InferenceEngineV2(
+        model, RaggedInferenceEngineConfig(
+            dtype="float32", max_ragged_batch_size=4, prefill_chunk_size=8,
+            kv_block_size=8, max_tokens_per_step=64, frame_steps=2),
+        params=model.init(jax.random.PRNGKey(0)), max_seq_len=128)
+
+
+def test_latent_and_share_lanes_exist_for_such_a_model():
+    from deepspeed_tpu.inference.v2.telemetry import (LATENT_STAT_NAMES,
+                                                      MOE_STAT_NAMES, N_STATS,
+                                                      SHARE_STAT_NAMES,
+                                                      n_stats)
+    e = _latent_engine()
+    assert e.runner.n_stats == N_STATS + len(MOE_STAT_NAMES) \
+        + len(SHARE_STAT_NAMES) + len(LATENT_STAT_NAMES) \
+        == n_stats(True, share=True, latent=True)
+    assert e.runner.latent_layers == 4 and e.kv.v is None
+    rng = np.random.default_rng(3)
+    prompts = {u: rng.integers(0, 256, n).astype(np.int32)
+               for u, n in ((0, 45), (1, 9))}
+    outs = dict(e.serve(iter([[(u, p) for u, p in prompts.items()]]),
+                        max_new_tokens=5))
+    assert {len(v) for v in outs.values()} == {5}
+    text = e.telemetry.render_prometheus()
+    for series in LATENT_SERIES + LAYERED_SERIES[-4:]:
+        assert f"# TYPE {series} " in text, series
+    assert 'ds_serving_kv_blocks_in_use{kind="latent"} ' in text
+    # (45 + 5 + 1) and (9 + 5 + 1) tokens: 7 + 2 pages of the one pool
+    assert 'ds_serving_kv_blocks_in_use_peak{kind="latent"} 9' in text
+    c = e.telemetry.snapshot()["counters"]
+    live = c["prefill_tokens"] + c["target_forwards"]
+    assert c["expert_selections"] == live * 2 * 2       # k x layers
+    assert c["expert_rows"] + c["zero_expert_selections"] \
+        + c["absent_expert_selections"] == c["expert_selections"]
+    assert min(c["expert_rows"], c["zero_expert_selections"],
+               c["absent_expert_selections"]) > 0
+    # 4 attention layers read what the one-layer lane counts
+    for split in ("narrow", "wide"):
+        assert c[f"latent_positions_read_{split}"] == \
+            4 * c[f"kv_positions_read_{split}"] > 0
+        assert c[f"latent_pairs_{split}"] == 4 * c[f"attn_pairs_{split}"]
+    # one pool: a page is 4 layers x 8 rows x 128 lanes of float32
+    assert c["kv_bytes_in_use_sum"] == \
+        c["context_tokens_reserved_sum"] * 4 * 128 * 4
+    assert e.kv.free_blocks == e.kv.num_blocks - 1
+
+
+@pytest.mark.parametrize("other", ["dense", "mixed-kinds-routed"])
+def test_latent_and_share_lanes_are_absent_for_every_other_model(
+        served, other):
+    """A dense model, and a routed model of mixed cache kinds that holds
+    every expert its router scores, have no trace of them: not in the stats
+    vector, the counters or ``/metrics``."""
+    from deepspeed_tpu.inference.v2.telemetry import n_stats
+    if other == "dense":
+        e, _prompts, _outs, snap = served
+        text, counters = snap["prom"], snap["snapshot"]["counters"]
+        assert e.runner.n_stats == n_stats(False)
+    else:
+        e = _mixed_engine()
+        dict(e.serve(iter([[(0, np.arange(20, dtype=np.int32))]]),
+                     max_new_tokens=3))
+        text = e.telemetry.render_prometheus()
+        counters = e.telemetry.snapshot()["counters"]
+        assert e.runner.n_stats == n_stats(True, True)
+    assert e.runner.latent_layers is None and e.kv.v is not None
+    assert 'kind="latent"' not in text
+    for series in LATENT_SERIES:
+        assert series not in text, series
+    assert not [k for k in counters
+                if "latent" in k or k.endswith("_selections")]

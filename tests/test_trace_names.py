@@ -116,6 +116,54 @@ def test_mixed_kinds_frame_lowering_names_the_ring_kernels(width,
             f"kv_commit_c{width}", f"kv_commit_ring_c{width}"} <= parts
 
 
+def _tiny_longcat_engine():
+    from deepspeed_tpu.models import get_config
+    cfg = get_config(
+        "longcat-flash-omni", vocab_size=128, hidden_size=32, num_layers=2,
+        num_heads=4, intermediate_size=48, moe_intermediate_size=16,
+        q_lora_rank=12, kv_lora_rank=16, qk_nope_head_dim=8,
+        qk_rope_head_dim=4, v_head_dim=8, num_experts=4,
+        moe_router_experts=8, moe_zero_experts=4, num_experts_per_tok=2,
+        max_seq_len=128, dtype="float32")
+    model = build_model(cfg)
+    return InferenceEngineV2(
+        model, RaggedInferenceEngineConfig(
+            dtype="float32", max_ragged_batch_size=4, prefill_chunk_size=8,
+            kv_block_size=8, max_tokens_per_step=64, frame_steps=2),
+        params=model.init(jax.random.PRNGKey(0)), max_seq_len=128)
+
+
+@pytest.mark.parametrize("width", [8, 1])
+def test_latent_frame_lowering_names_the_mla_kernels_and_scopes(width,
+                                                                monkeypatch):
+    """A model with latent attention names its two kernels
+    ``paged_attn_mla_c<C>`` and ``kv_commit_mla_c<C>`` under the scopes every
+    model has (so ``^paged_attn(?:_ring)?_c\\d+$`` keeps meaning K and V by
+    head), and the latent projections ``mla_q`` / ``mla_kv`` / ``mla_absorb``
+    inside ``attn_qkv`` and ``attn_out``; the routed block's scopes are the
+    ones a routed model has. None of the other models' kernels is in it."""
+    monkeypatch.setattr(model_runner, "_use_pallas_paged", lambda: True)
+    eng = _tiny_longcat_engine()
+    slots = DeviceSlotTable(4, prompt_width=8, table_width=16,
+                            rng=jax.random.PRNGKey(0),
+                            n_stats=eng.runner.n_stats)
+    lowered = eng.runner._build_frame_loop().lower(
+        eng.params, slots.prompts, slots.prompt_lens, slots.limits,
+        slots.eos_ids, slots.temps, slots.tables, slots.cached,
+        slots.produced, slots.last_tok, slots.done, slots.poison,
+        slots.nonfinite, slots.stats, slots.rng, eng.kv.k, eng.kv.v,
+        width=width, steps=2, greedy=True)
+    parts = _scope_components(lowered)
+    assert set(SERVE_SCOPES) <= parts, set(SERVE_SCOPES) - parts
+    assert {f"paged_attn_mla_c{width}", f"kv_commit_mla_c{width}", "mla_q",
+            "mla_kv", "mla_absorb", "moe_mlp", "moe_route", "moe_dispatch",
+            "moe_experts", "moe_combine"} <= parts
+    assert not {f"paged_attn_c{width}", f"kv_commit_c{width}"} & parts
+    text = lowered.as_text(dialect="hlo", debug_info=True)
+    assert "attn_qkv/mla_q" in text and "attn_qkv/mla_kv" in text
+    assert "attn_qkv/mla_absorb" in text and "attn_out/mla_absorb" in text
+
+
 def test_train_step_lowering_names_scopes_and_flash_kernels():
     """A tiny training step names its layer scopes, the optimizer, and the
     three flash kernels (interpret mode off the chip)."""
