@@ -11,8 +11,17 @@ virtual 8-device mesh and its throughput character is the local ragged_dot
 plus two all-to-alls over ICI.
 
 Prints one JSON line; run with the repo root on sys.path.
+
+``--grouped-sweep`` times the routed experts' grouped product ALONE, on the
+chip: ``jax.lax.ragged_dot`` (over the stack as L x X groups, what every cell
+ran before the kernel) against ``ops/pallas/grouped_gemm.py``'s kernel, us a
+product, at the rows the cells' rungs give (128 / 1,152 / 2,176 / 4,224 /
+8,320 / 16,384 selection rows) and the three served models' (K, N, groups),
+over even groups and groups drawn at the cell's ``expert_load_max_over_mean``;
+one JSON line a row, the bytes and FLOPs floors beside the times.
 """
 
+import functools
 import json
 import os
 import sys
@@ -102,12 +111,150 @@ def bench_ep_virtual(tokens, hidden, ffn, experts, k, iters=5):
     return out
 
 
+# (hidden, expert width, experts held, the cell's expert_load_max_over_mean,
+# the share of a step's selection rows that land on a held expert, the
+# layers the cell stacks): the benchmark's three routed configurations
+# (ledger, PR 34)
+SWEEP_MODELS = {
+    "olmoe-1b-7b": (2048, 1024, 64, 5.0, 1.0, 8),
+    "mellum2-12b-a2.5b": (2304, 896, 64, 4.0, 1.0, 8),
+    # top 12 of 768 outputs, 16 of them held: 0.25 rows a token of 12
+    "longcat-flash-omni": (6144, 2048, 16, 1.76, 0.25 / 12, 4),
+}
+SWEEP_ROWS = (128, 1152, 2176, 4224, 8320, 16384)
+HBM_BYTES_PER_S, BF16_FLOPS_PER_S = 819e9, 197e12   # TPU v5e, Google Cloud documentation
+
+
+def draw_groups(rng, rows, groups, max_over_mean):
+    """Group sizes that sum to ``rows``: even, or (``max_over_mean`` > 1)
+    shares that fall off geometrically so that the largest is that many
+    times the mean, in a random order."""
+    if max_over_mean <= 1:
+        share = np.ones(groups)
+    else:
+        lo, hi = 0.0, 50.0
+        for _ in range(60):
+            a = (lo + hi) / 2
+            share = np.exp(-a * np.arange(groups) / groups)
+            lo, hi = (a, hi) if share[0] * groups / share.sum() \
+                < max_over_mean else (lo, a)
+        share = rng.permutation(share)
+    sizes = np.floor(share / share.sum() * rows).astype(np.int64)
+    sizes[np.argsort(-share)[:rows - sizes.sum()]] += 1
+    return sizes.astype(np.int32)
+
+
+def grouped_sweep(args):
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.ops.pallas import grouped_gemm as G
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        sys.exit(f"--grouped-sweep needs the chip, found {dev.platform}")
+
+    def ragged(tokens, stack, sizes, layer):
+        n_layers, n_exp = stack.shape[:2]
+        padded = jax.lax.dynamic_update_slice(
+            jnp.zeros((n_layers * n_exp,), sizes.dtype), sizes,
+            (layer * n_exp,))
+        return jax.lax.ragged_dot(
+            tokens, stack.reshape((n_layers * n_exp,) + stack.shape[2:]),
+            padded)
+
+    def timed(product, tokens, stack, sizes):
+        """us a product: ``iters`` of them in one program, each on the next
+        layer of the stack, each result folded into the carry."""
+        @jax.jit
+        def loop(tokens, stack, sizes):
+            def body(i, acc):
+                out = product(tokens, stack, sizes, i % stack.shape[0])
+                return acc + out[:8].astype(jnp.float32)
+            return jax.lax.fori_loop(
+                0, args.iters, body,
+                jnp.zeros((8, stack.shape[-1]), jnp.float32))
+        loop(tokens, stack, sizes).block_until_ready()
+        best = float("inf")
+        for _ in range(3):
+            t = time.perf_counter()
+            loop(tokens, stack, sizes).block_until_ready()
+            best = min(best, time.perf_counter() - t)
+        return best / args.iters * 1e6
+
+    rng = np.random.default_rng(args.seed)
+    # "256": that row tile with the rule's tk, tn; "128x6144x1024": all three
+    tilings = [None] + [tuple(map(int, t.split("x")))
+                        for t in args.tilings.split(",") if t]
+    for name in args.models.split(","):
+        hidden, width, groups, skew, held, layers = SWEEP_MODELS[name]
+        for k, n in ((hidden, width), (width, hidden)):
+            # made on the device: a cell's stack is 1-2 GB
+            stack = jax.random.normal(
+                jax.random.PRNGKey(args.seed), (layers, groups, k, n),
+                jnp.bfloat16) * k ** -0.5
+            for rows in map(int, args.rows.split(",")):
+                # LongCat's 12 selections a token where the others have 8
+                rows = rows * 3 // 2 if held < 1 else rows
+                tokens = jnp.asarray(rng.normal(size=(rows, k)), jnp.bfloat16)
+                for draw, ratio in (("even", 1.0), ("skewed", skew)):
+                    sizes_np = draw_groups(rng, max(1, round(rows * held)),
+                                           groups, ratio)
+                    sizes = jnp.asarray(sizes_np)
+                    in_groups = int(sizes_np.sum())
+                    row = {
+                        "model": name, "k": k, "n": n, "groups": groups,
+                        "rows": rows, "rows_in_groups": in_groups,
+                        "draw": draw,
+                        "max_over_mean": round(
+                            float(sizes_np.max() * groups / in_groups), 2),
+                        "bytes_floor_us": round(
+                            int((sizes_np > 0).sum()) * k * n * 2
+                            / HBM_BYTES_PER_S * 1e6, 1),
+                        "flops_floor_us": round(
+                            in_groups * k * n * 2 / BF16_FLOPS_PER_S * 1e6, 1),
+                        "ragged_dot_us": round(
+                            timed(ragged, tokens, stack, sizes), 1),
+                    }
+                    want = np.asarray(ragged(tokens, stack, sizes, 1)
+                                      [:in_groups], np.float32)
+                    rule = G.tiles(rows, k, n)
+                    for tiling in tilings:
+                        if tiling is not None:
+                            tiling = tiling + rule[len(tiling):]
+                            if k % tiling[1] or n % tiling[2]:
+                                continue
+                        product = functools.partial(G.grouped_mm,
+                                                    tiling=tiling)
+                        label = "x".join(map(str, tiling or rule))
+                        if tiling is None:
+                            row["tiles"] = label
+                        tag = "" if tiling is None else f"_{label}"
+                        got = np.asarray(product(tokens, stack, sizes, 1)
+                                         [:in_groups], np.float32)
+                        row[f"kernel_us{tag}"] = round(
+                            timed(product, tokens, stack, sizes), 1)
+                        row[f"gap{tag}"] = float(np.abs(got - want).max())
+                    row["device"] = dev.device_kind
+                    print(json.dumps(row), flush=True)
+
+
 def main():
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--ep-virtual", action="store_true",
                     help="run the EP-ring row on a forced CPU mesh")
+    ap.add_argument("--grouped-sweep", action="store_true",
+                    help="the grouped product alone: ragged_dot against the "
+                         "kernel, us a product (needs the chip)")
+    ap.add_argument("--models", default=",".join(SWEEP_MODELS))
+    ap.add_argument("--rows", default=",".join(map(str, SWEEP_ROWS)))
+    ap.add_argument("--tilings", default="",
+                    help="tilings to time beside the rule's: TM or TMxTKxTN, "
+                         "comma-separated")
+    ap.add_argument("--iters", type=int, default=24)
+    ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    if args.grouped_sweep:
+        return grouped_sweep(args)
     if args.ep_virtual:
         print(json.dumps(bench_ep_virtual(tokens=2048, hidden=256, ffn=512,
                                           experts=8, k=2)))

@@ -555,15 +555,17 @@ def apply_moe_grouped(params, x, cfg: TransformerConfig, live=None,
                       layer=None):
     """Dropless grouped-GEMM MoE (megablox pattern; reference analog:
     ``inference/v2/kernels/cutlass_ops/moe_gemm``): tokens are sorted by
-    assigned expert and each expert's contiguous row group hits one MXU-tiled
-    ``ragged_dot`` — no capacity buffers, no dense (T, X, C) dispatch
+    assigned expert and each expert's contiguous row group is multiplied by
+    that expert's matrices in ``ops/pallas/grouped_gemm.py`` (on the chip
+    its own kernel, which reads an expert once a product; ``ragged_dot``
+    elsewhere) — no capacity buffers, no dense (T, X, C) dispatch
     einsums, no token dropping. Selected by ``moe_impl: "grouped"``;
     requires an unsharded expert axis (EP uses the einsum/all-to-all path).
 
     ``live`` (B, S) bool, serving: the positions that hold a token. A dead
     position (an idle slot, a packed buffer's padding) reaches no expert:
-    its rows sort behind the last expert and belong to no group, so
-    ``ragged_dot`` skips them, they read no expert's weights and a live
+    its rows sort behind the last expert and belong to no group, so the
+    product skips them, they read no expert's weights and a live
     token's output does not depend on them. Rows past the groups are not
     written (zero on the CPU, whatever the buffer held on the chip), so the
     combine zeroes them. With ``live`` the group sizes come back as a third
@@ -642,10 +644,12 @@ def apply_moe_grouped_ep(params, x, cfg: TransformerConfig, mesh):
     A shard_map manual over the token-carrying axes + ``expert``:
     each device routes its local tokens, lays rows destined to expert-shard
     ``s`` into slot block ``s`` of a static (ep, R, E) buffer, all-to-all
-    over the expert axis, runs ONE local ``ragged_dot`` over the received
-    rows sorted by local expert (ragged_dot zero-fills and skips the empty
-    tail, so compute scales with the rows actually routed here), and
-    all-to-alls results back for the weighted combine. R = T_local * k — the
+    over the expert axis, runs ONE local grouped product
+    (``ops/pallas/grouped_gemm.py``) over the received rows sorted by local
+    expert (the empty tail belongs to no group: it is skipped and, on the
+    chip, left unwritten; nothing gathers it, so compute scales with the
+    rows actually routed here), and all-to-alls results back for the
+    weighted combine. R = T_local * k — the
     worst case, so NO token is ever dropped regardless of routing imbalance
     (the capacity-einsum path drops at C); memory is over-provisioned
     instead, the standard static-shape tradeoff on XLA.
@@ -666,7 +670,14 @@ def apply_moe_grouped_ep(params, x, cfg: TransformerConfig, mesh):
     batch_axes = tuple(a for a in _groups.BATCH_AXES
                        if mesh.shape.get(a, 1) > 1)
     seq_axis = "seq" if mesh.shape.get("seq", 1) > 1 else None
-    manual = set(batch_axes) | {"expert"} | ({seq_axis} if seq_axis else set())
+    token_axes = (set(batch_axes) | {"expert"}
+                  | ({seq_axis} if seq_axis else set()))
+    # Mosaic lowers a call (the grouped product's kernel on the chip) only
+    # where EVERY mesh axis is manual: name the rest too where they hold one
+    # device each, which changes nothing else
+    manual = (set(mesh.shape) if all(
+        mesh.shape[a] == 1 for a in mesh.shape if a not in token_axes)
+        else token_axes)
 
     def body(router, wi_gate, wi_up, wo, x):
         b, s, e = x.shape
@@ -686,7 +697,7 @@ def apply_moe_grouped_ep(params, x, cfg: TransformerConfig, mesh):
                           axis=1)
         stats = jax.lax.pmean(
             jnp.stack([jnp.mean(gates, axis=0), jnp.mean(mask_tx, axis=0)]),
-            tuple(sorted(manual)))
+            tuple(sorted(token_axes)))
         aux = n_exp * jnp.sum(stats[0] * stats[1])
         er = topk_idx.reshape(-1)                       # (T*k,) global expert
         ts = er // n_local                              # target expert shard

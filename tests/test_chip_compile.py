@@ -25,7 +25,7 @@ from jax.sharding import SingleDeviceSharding
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def four_chips():
     from jax.experimental import topologies
     try:
         topo = topologies.get_topology_desc(platform="tpu",
@@ -38,9 +38,14 @@ def one_chip():
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", was_on)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(four_chips):
+    return SingleDeviceSharding(four_chips[0])
 
 
 @pytest.fixture
@@ -237,6 +242,119 @@ def test_fused_adam_compiles(one_chip, as_tpu):
     assert "tpu_custom_call" in text
 
 
+# the routed cells' experts: (hidden, expert width, experts held, layers
+# stacked, selections a token); 16 slots, so 16 tokens the narrowest step
+# and 2,048 the widest rung
+EXPERT_SHAPES = {"olmoe": (2048, 1024, 64, 8, 8),
+                 "mellum2": (2304, 896, 64, 8, 8),
+                 "longcat": (6144, 2048, 16, 4, 12)}
+
+
+@pytest.mark.parametrize("tokens", [16, 2048], ids=["narrowest", "widest"])
+@pytest.mark.parametrize("cell", list(EXPERT_SHAPES))
+def test_grouped_product_compiles_at_the_cells_shapes(one_chip, as_tpu, cell,
+                                                      tokens):
+    """The routed experts' SwiGLU at the benchmark's shapes, the stacked
+    weights whole and the layer an index: three Mosaic calls named for the
+    readers of the trace (a row tile of 128 against a WHOLE expert matrix,
+    LongCat's two buffers of 25 MB inside the limit the call asks for), no
+    ``ragged_dot``, and no buffer shaped like one layer's experts."""
+    from deepspeed_tpu.ops.pallas.grouped_gemm import moe_expert_ffn
+    hidden, width, experts, layers, top = EXPERT_SHAPES[cell]
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    up, down = (layers, experts, hidden, width), (layers, experts, width,
+                                                  hidden)
+    text = _compile_text(
+        moe_expert_ffn, sds((tokens * top, hidden)), sds(up), sds(up), sds(down),
+        sds((experts,), jnp.int32), sds((), jnp.int32))
+    assert len(re.findall(r"%grouped_mm_m128\S* = ", text)) == 3
+    assert text.count("tpu_custom_call") >= 3 and "ragged-dot" not in text
+    one_layer = re.findall(
+        rf"= bf16\[(?:1,)?{experts},(?:{hidden},{width}|{width},{hidden})\]"
+        rf"\S* (\w[\w-]*)\(", text)
+    assert not one_layer, one_layer
+
+
+def test_grouped_product_gradient_slices_no_layer(one_chip, as_tpu):
+    """``jax.grad`` through the routed experts with the stacked weights and a
+    layer index, at OLMoE's shapes: the kernel forward, ``ragged_dot``'s own
+    transposes backward over the stack's L * X groups, and, as in the
+    forward, no buffer shaped like one layer's experts (the derivative of
+    the stack is the stack's shape, and an argument's)."""
+    from deepspeed_tpu.ops.pallas.grouped_gemm import moe_expert_ffn
+    hidden, width, experts, layers, top = EXPERT_SHAPES["olmoe"]
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(tokens, gate, up, down, sizes, layer):
+        out = moe_expert_ffn(tokens, gate, up, down, sizes, layer)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    up, down = (layers, experts, hidden, width), (layers, experts, width,
+                                                  hidden)
+    text = _compile_text(
+        jax.grad(loss, argnums=(0, 1, 2, 3)), sds((144 * top, hidden)),
+        sds(up), sds(up), sds(down), sds((experts,), jnp.int32),
+        sds((), jnp.int32))
+    assert len(re.findall(r"%(?:jvp_)?grouped_mm_m128\S* = ", text)) == 3
+    assert len(re.findall(r"%ragged-dot-none\S* = ", text)) == 6
+    one_layer = re.findall(
+        rf"= (?:bf16|f32)\[(?:1,)?{experts},(?:{hidden},{width}|{width},{hidden})\]"
+        rf"\S* (\w[\w-]*)\(", text)
+    assert not one_layer, one_layer
+
+
+@pytest.mark.parametrize("axes,kernels", [
+    (dict(expert=2, data=2), 3), (dict(data=4), 0)],
+    ids=["expert-parallel", "data-parallel"])
+def test_routed_block_compiles_for_four_chips(four_chips, as_tpu, axes,
+                                              kernels):
+    """The dropless routed block and its gradient, compiled for the 2 x 2
+    mesh. Under a sharded expert axis the local product inside
+    ``apply_moe_grouped_ep``'s ``shard_map`` is the kernel (the region is
+    manual over every mesh axis and checks how values vary, so the call
+    states it: Mosaic lowers nothing less) with ``ragged_dot``'s transposes
+    behind it; under data parallelism alone XLA's SPMD pass partitions the
+    block, cannot partition a Mosaic call, and the product stays
+    ``ragged_dot``."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from deepspeed_tpu.models import layers as L
+    from deepspeed_tpu.models.config import TransformerConfig
+    from deepspeed_tpu.utils import groups
+    groups.reset_mesh()
+    mesh = groups.set_mesh(groups.build_mesh(devices=four_chips, **axes))
+    try:
+        cfg = TransformerConfig(
+            vocab_size=256, hidden_size=256, num_layers=1, num_heads=4,
+            intermediate_size=512, moe_intermediate_size=512, num_experts=8,
+            num_experts_per_tok=2, moe_impl="grouped", max_seq_len=128,
+            dtype="bfloat16")
+        params, _ = L.init_moe_mlp(jax.random.PRNGKey(0), cfg)
+
+        def loss(params, x):
+            out, aux = L.apply_moe_mlp(params, x, cfg)
+            return jnp.sum(out.astype(jnp.float32) ** 2) + aux
+
+        def sds(shape, dtype, spec):
+            return jax.ShapeDtypeStruct(shape, dtype,
+                                        sharding=NamedSharding(mesh, spec))
+
+        held = P("expert") if "expert" in axes else P()
+        shapes = jax.tree.map(
+            lambda a: sds(a.shape, a.dtype, held if a.ndim == 3 else P()),
+            params)
+        x = sds((8, 128, 256), jnp.bfloat16, P(tuple(axes)))
+        text = _compile_text(jax.value_and_grad(loss), shapes, x)
+    finally:
+        groups.reset_mesh()
+    assert len(re.findall(r"%(?:jvp_)?grouped_mm_m128\S* = ", text)) == kernels
+    assert len(re.findall(r"%ragged-dot\S* = ", text)) == (6 if kernels else 9)
+
+
 def _assert_commits_in_place(compiled, text, pool, scatter_temp_gb):
     """A frame program writes the step's KV into the pools in place: the
     commit kernel once, no pool-shaped value that XLA made (a relaid or
@@ -315,9 +433,9 @@ def test_olmoe_frame_programs_fit_the_chip(one_chip, as_tpu, width,
     """The benchmark's OLMoE-1B-7B configuration (published widths, 8 of 16
     layers, bf16; 16 slots, 8 steps, 416 pages of 128, sequences to 4,096):
     both frame programs compile with the chip's compiler from shapes alone,
-    keep the paged kernel, commit in place (MHA: 16 KV heads a block), keep
-    XLA's grouped-product kernel (three products
-    a routed layer, at every rung), hold NO buffer shaped like one layer's
+    keep the paged kernel, commit in place (MHA: 16 KV heads a block), hold
+    the grouped-product kernel (``grouped_mm_m128``: three products a routed
+    layer, at every rung, and ``ragged_dot`` nowhere), hold NO buffer shaped like one layer's
     stack of experts (the products read the stacked weights whole: a
     layer's slice handed to a kernel is 805 MB copied a layer a step), and
     their arguments and temporaries stay under the chip's 15.75 GB."""
@@ -352,7 +470,8 @@ def test_olmoe_frame_programs_fit_the_chip(one_chip, as_tpu, width,
     assert len(re.findall(r" conditional\(", text)) == (3 if rungs > 1 else 0)
     assert len(re.findall(r"%paged_attn_c\d+\S* = ", text)) == 1
     _assert_commits_in_place(compiled, text, pool, scatter_temp_gb)
-    assert len(re.findall(r"%ragged-dot-none\S* = ", text)) == 3 * rungs
+    assert len(re.findall(r"%grouped_mm_m128\S* = ", text)) == 3 * rungs
+    assert "ragged-dot" not in text
     # one layer's experts: defined nowhere, in the loop, a conditional or
     # the entry (the stack itself is a parameter, [8,64,...])
     one_layer = re.findall(
@@ -376,7 +495,7 @@ def test_mellum2_frame_programs_fit_the_chip(one_chip, as_tpu, width):
     The walk is a scan over the two periods with a period's four layers
     unrolled: three ring kernels and one over whole tables, one commit a
     kind, in place (no value shaped like either kind's pool that XLA made),
-    XLA's grouped-product kernel three times a layer and rung, NO buffer
+    the grouped-product kernel three times a layer and rung, NO buffer
     shaped like one layer's stack of experts, and arguments and temporaries
     under the chip's 15.75 GB."""
     import re
@@ -422,7 +541,8 @@ def test_mellum2_frame_programs_fit_the_chip(one_chip, as_tpu, width):
     # projection + experts), a period's four layers unrolled
     assert len(re.findall(r" conditional\(", text)) == \
         (1 + 2 * 4 if rungs > 1 else 0)
-    assert len(re.findall(r"%ragged-dot-none\S* = ", text)) == 3 * rungs * 4
+    assert len(re.findall(r"%grouped_mm_m128\S* = ", text)) == 3 * rungs * 4
+    assert "ragged-dot" not in text
     for pool in pools:
         shape = ",".join(map(str, pool.shape))
         made = re.findall(
@@ -454,7 +574,7 @@ def test_longcat_frame_programs_fit_the_chip(one_chip, as_tpu, width):
     the 8 attention layers): both frame programs compile with the chip's
     compiler from shapes alone. A scan step is one double layer: the latent
     kernel twice, one commit of the one pool in place (no value shaped like
-    it that XLA made), XLA's grouped-product kernel three times a layer and
+    it that XLA made), the grouped-product kernel three times a layer and
     rung, NO buffer shaped like one layer's held experts or a layer's pair
     of dense matrices, and arguments and temporaries under 15.75 GB."""
     import re
@@ -490,7 +610,8 @@ def test_longcat_frame_programs_fit_the_chip(one_chip, as_tpu, width):
     assert len(re.findall(r"%paged_attn_\S* = ", text)) == 2
     assert len(re.findall(rf"%kv_commit_mla_c{width}\S* = ", text)) == 1
     assert len(re.findall(r"%kv_commit_\S* = ", text)) == 1
-    assert len(re.findall(r"%ragged-dot-none\S* = ", text)) == 3 * rungs
+    assert len(re.findall(r"%grouped_mm_m128\S* = ", text)) == 3 * rungs
+    assert "ragged-dot" not in text
     shape = ",".join(map(str, pool.shape))
     made = re.findall(
         rf"= bf16\[{shape}\]\S* (copy|copy-start|fusion|scatter|transpose|"

@@ -1,0 +1,263 @@
+"""The grouped-product kernel (``ops/pallas/grouped_gemm.py``) in interpret
+mode on the CPU, against ``jax.lax.ragged_dot``: the cells' (K, N) with the
+rows cut, groups that are empty, one that holds half the rows, group edges off
+every tile edge, rows past the groups, the stacked ``layer=`` form, the
+derivative through ``moe_expert_ffn`` (one layer's experts and the stack),
+the expert-parallel path inside its ``shard_map``, where the kernel runs and
+where ``ragged_dot`` stays, and the tile rule at every shape the three routed
+cells run."""
+
+
+import numpy as np
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deepspeed_tpu.inference.v2.telemetry import pack_ladder
+from deepspeed_tpu.ops.pallas import grouped_gemm as G
+
+
+@pytest.fixture
+def kernel_path(monkeypatch):
+    """The chip's path on the CPU, through the file's one seam: ``_on_chip``
+    says yes and ``grouped_mm`` is interpreted. Returns the list the calls
+    are counted in."""
+    from deepspeed_tpu.utils import groups
+    groups.reset_mesh()
+    calls, real = [], G.grouped_mm
+    monkeypatch.setattr(G, "_on_chip", lambda: True)
+    monkeypatch.setattr(G, "grouped_mm", lambda *a, **kw: (
+        calls.append(1), real(*a, interpret=True, **kw))[1])
+    yield calls
+    groups.reset_mesh()
+
+
+def _sizes(rng, rows, groups, draw):
+    """Group sizes summing to ``rows``, no edge on a multiple of 8."""
+    if draw == "half":          # one group holds half the rows: max / mean 5
+        groups = 10
+        rest = rng.multinomial(rows - rows // 2 - (groups - 1),
+                               np.ones(groups - 1) / (groups - 1)) + 1
+        sizes = np.insert(rest, 3, rows // 2)
+    elif draw == "empty":       # every other group empty, the first and last too
+        live = rng.multinomial(rows - groups // 2,
+                               np.ones(groups // 2) / (groups // 2)) + 1
+        sizes = np.zeros(groups, np.int64)
+        sizes[1:-1:2] = live[:len(sizes[1:-1:2])]
+        sizes[1] += rows - sizes.sum()
+    else:                       # "odd": every group an odd size
+        sizes = 2 * rng.multinomial((rows - groups) // 2,
+                                    np.ones(groups) / groups) + 1
+        sizes[0] += rows - sizes.sum()
+    assert sizes.sum() == rows and (sizes >= 0).all()
+    return sizes.astype(np.int32)
+
+
+def _operands(rng, rows, k, n, groups, layers=None):
+    tokens = jnp.asarray(rng.normal(size=(rows, k)), jnp.bfloat16)
+    shape = (groups, k, n) if layers is None else (layers, groups, k, n)
+    weights = jnp.asarray(rng.normal(size=shape) / np.sqrt(k), jnp.bfloat16)
+    return tokens, weights
+
+
+def _gap(got, want, rows):
+    """The largest gap over the rows in groups, in units of the result's
+    largest value."""
+    got = np.asarray(got[:rows], np.float32)
+    want = np.asarray(want[:rows], np.float32)
+    return np.abs(got - want).max() / np.abs(want).max()
+
+
+# the cells' (K, N): Mellum2's gate / up and down, OLMoE's, LongCat's whose
+# 25 MB matrix is tiled in K and N here (the budget is cut so the rule must)
+@pytest.mark.parametrize("k,n,groups,budget", [
+    (2304, 896, 8, None), (896, 2304, 8, None), (2048, 1024, 8, None),
+    (6144, 2048, 4, 6 << 20)], ids=["mellum2-up", "mellum2-down", "olmoe-up",
+                                    "longcat-up-tiled"])
+@pytest.mark.parametrize("draw", ["odd", "half", "empty"])
+def test_kernel_matches_ragged_dot(monkeypatch, k, n, groups, budget, draw):
+    rng = np.random.default_rng(k + len(draw))
+    rows, past = 296, 88            # 296 + 88 = 3 tiles of 128: one is never visited
+    sizes = _sizes(rng, rows, groups, draw)
+    if draw == "half":
+        assert sizes.max() * len(sizes) / sizes.sum() == pytest.approx(5, abs=.1)
+    if budget:
+        monkeypatch.setattr(G, "_VMEM_BUDGET", budget)
+        tm, tk, tn = G.tiles(rows + past, k, n)
+        assert tk < k and tn < n, (tk, tn)
+        monkeypatch.setattr(G, "_VMEM_BUDGET", 1 << 16)
+        with pytest.raises(ValueError, match="fits"):
+            G.tiles(rows + past, k, n)
+        monkeypatch.setattr(G, "_VMEM_BUDGET", budget)
+    tokens, weights = _operands(rng, rows + past, k, n, len(sizes))
+    got = G.grouped_mm(tokens, weights, jnp.asarray(sizes), interpret=True)
+    want = jax.lax.ragged_dot(tokens, weights, jnp.asarray(sizes))
+    assert got.dtype == jnp.bfloat16 and got.shape == want.shape
+    # bf16 results of float32 sums: an ulp where the order of the sum differs
+    assert _gap(got, want, rows) < 2 ** -7
+    # a planted fault shows: the walk one group short
+    short = G.grouped_mm(tokens, weights,
+                         jnp.asarray(sizes).at[np.flatnonzero(sizes)[-1]].set(0),
+                         interpret=True)
+    assert _gap(short, want, rows) > 0.1 or not np.isfinite(_gap(short, want, rows))
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2], ids=["first", "middle", "last"])
+def test_stacked_weights_pick_the_layer(layer):
+    rng = np.random.default_rng(layer)
+    rows, k, n, groups = 200, 256, 384, 6
+    sizes = jnp.asarray(_sizes(rng, rows - 40, groups, "odd"))
+    tokens, stack = _operands(rng, rows, k, n, groups, layers=3)
+    got = jax.jit(lambda l: G.grouped_mm(tokens, stack, sizes, l,
+                                         tiling=(64, 128, 128),
+                                         interpret=True))(layer)
+    want = jax.lax.ragged_dot(tokens, stack[layer], sizes)
+    assert _gap(got, want, rows - 40) < 2 ** -7
+    others = [_gap(got, jax.lax.ragged_dot(tokens, stack[l], sizes), rows - 40)
+              for l in range(3) if l != layer]
+    assert min(others) > 0.1
+
+
+def test_no_group_has_rows():
+    """No visit at all (a step whose tokens all chose experts of other
+    chips): the kernel runs to its end and nothing is claimed of the rows."""
+    rng = np.random.default_rng(0)
+    tokens, weights = _operands(rng, 144, 128, 128, 4)
+    out = G.grouped_mm(tokens, weights, jnp.zeros((4,), jnp.int32),
+                       interpret=True)
+    assert out.shape == (144, 128)
+
+
+@pytest.mark.parametrize("layer", [None, 1], ids=["one-layer", "stacked"])
+def test_moe_ffn_and_its_gradient_match_the_ragged_dot_path(monkeypatch,
+                                                            request, layer):
+    """``moe_expert_ffn`` through the kernel against the path the CPU takes,
+    values and ``jax.grad`` for the rows and all three matrices; with
+    ``layer=`` the matrices are a stack of three layers and the other
+    layers' derivative is zero."""
+    rng = np.random.default_rng(1)
+    rows, e, f, groups = 160, 128, 256, 5
+    sizes = jnp.asarray(_sizes(rng, rows, groups, "odd"))
+    tokens = jnp.asarray(rng.normal(size=(rows, e)), jnp.float32)
+    stack = () if layer is None else (3,)
+    mats = [jnp.asarray(rng.normal(size=stack + s) / np.sqrt(s[1]), jnp.float32)
+            for s in ((groups, e, f), (groups, e, f), (groups, f, e))]
+
+    def loss(tokens, *mats):
+        return jnp.sum(G.moe_expert_ffn(tokens, *mats, sizes, layer) ** 2)
+
+    want = jax.value_and_grad(loss, argnums=(0, 1, 2, 3))(tokens, *mats)
+    calls = request.getfixturevalue("kernel_path")
+    got = jax.value_and_grad(loss, argnums=(0, 1, 2, 3))(tokens, *mats)
+    assert len(calls) == 3
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4 * float(
+            jnp.abs(b).max()))
+    if layer is not None:
+        assert all(np.asarray(g)[layer].any() and not np.asarray(g)[0].any()
+                   for g in got[1][1:])
+
+
+def test_dead_positions_rows_are_left_alone(request):
+    """``apply_moe_grouped`` with dead positions, whose rows no group holds:
+    the kernel leaves them alone and the combine's zeroing keeps the
+    result."""
+    from deepspeed_tpu.models import layers as L
+    from deepspeed_tpu.models.config import TransformerConfig
+    cfg = TransformerConfig(
+        vocab_size=64, hidden_size=128, num_layers=1, num_heads=4,
+        intermediate_size=128, moe_intermediate_size=128, num_experts=4,
+        num_experts_per_tok=2, moe_impl="grouped", max_seq_len=64,
+        dtype="float32")
+    params, _ = L.init_moe_mlp(jax.random.PRNGKey(0), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 40, 128), jnp.float32)
+    live = jnp.arange(80).reshape(2, 40) % 3 != 1
+    want = L.apply_moe_grouped(params, x, cfg, live=live)
+    calls = request.getfixturevalue("kernel_path")
+    got = L.apply_moe_grouped(params, x, cfg, live=live)
+    assert len(calls) == 3
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-4, atol=1e-5)
+    np.testing.assert_array_equal(got[2], want[2])
+    assert not np.asarray(got[0])[~np.asarray(live)].any()
+
+
+def _tiny_moe_pair():
+    from deepspeed_tpu.models import build_model, get_config
+    cfg = get_config("tiny-moe").replace(moe_capacity_factor=8.0)
+    me, mg = build_model(cfg), build_model(cfg.replace(moe_impl="grouped"))
+    params = jax.jit(me.init)(jax.random.PRNGKey(0))
+    ids = jnp.asarray(np.random.default_rng(0).integers(0, 256, (8, 32)))
+    return me, mg, params, {"input_ids": ids, "labels": ids}
+
+
+def test_expert_parallel_path_runs_the_kernel_in_its_shard_map(monkeypatch,
+                                                               kernel_path):
+    """``apply_moe_grouped_ep`` on a data x expert mesh with the kernel as
+    its local product: the loss and every gradient of the capacity-einsum
+    dispatch (nothing drops at this capacity). The region is manual over
+    EVERY mesh axis, which is what lets Mosaic lower the call on the chip.
+    The interpreter reads the prefetched scalars in a way ``check_vma``
+    refuses, so the check is off HERE; with it on, the chip's own lowering
+    is compiled in ``tests/test_chip_compile.py``."""
+    from deepspeed_tpu.utils import groups
+    mesh = groups.set_mesh(groups.build_mesh(expert=2, data=4))
+    regions = []
+    real = jax.shard_map
+
+    def shard_map(f, **kw):
+        regions.append(set(kw["axis_names"]))
+        return real(f, **{**kw, "check_vma": False})
+
+    monkeypatch.setattr(jax, "shard_map", shard_map)
+    me, mg, params, batch = _tiny_moe_pair()
+    le, ge = jax.jit(jax.value_and_grad(me.loss))(params, batch)
+    lg, gg = jax.jit(jax.value_and_grad(mg.loss))(params, batch)
+    assert len(kernel_path) == 3 * 2        # three products a layer
+    assert regions and all(r == set(mesh.axis_names) for r in regions)
+    np.testing.assert_allclose(float(le), float(lg), rtol=2e-5)
+    for a, b in zip(jax.tree.leaves(ge), jax.tree.leaves(gg)):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   rtol=5e-3, atol=5e-4)
+
+
+@pytest.mark.parametrize("axes", [dict(data=8), dict(expert=2, tensor=2,
+                                                     data=2)],
+                         ids=["data-parallel", "expert-and-tensor"])
+def test_a_mesh_that_xla_partitions_keeps_ragged_dot(kernel_path, axes):
+    """XLA's SPMD pass cannot partition a Mosaic call: where it partitions
+    the routed block (data parallelism with the experts whole; an expert
+    axis beside a tensor axis, whose ``shard_map`` is manual over part of
+    the mesh) the product is ``ragged_dot`` as it always was, and the
+    kernel is not called."""
+    from deepspeed_tpu.utils import groups
+    groups.set_mesh(groups.build_mesh(**axes))
+    me, mg, params, batch = _tiny_moe_pair()
+    le = jax.jit(me.loss)(params, batch)
+    lg = jax.jit(mg.loss)(params, batch)
+    assert not kernel_path
+    np.testing.assert_allclose(float(le), float(lg), rtol=2e-5)
+
+
+# (hidden, expert width, experts held, selections a token): the three routed
+# cells; every cell serves 16 slots, narrow steps and chunks of 128
+CELLS = {"olmoe": (2048, 1024, 64, 8), "mellum2": (2304, 896, 64, 8),
+         "longcat": (6144, 2048, 16, 12)}
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_the_tile_rule_fits_every_shape_the_cells_run(cell):
+    hidden, width, groups, top = CELLS[cell]
+    rungs = sorted({t for w in (1, 128) for t in pack_ladder(16, w)})
+    assert rungs == [16, 144, 272, 528, 1040, 2048]
+    for tokens in rungs:
+        rows = tokens * top
+        for k, n in ((hidden, width), (width, hidden)):
+            tm, tk, tn = G.tiles(rows, k, n)
+            assert G._vmem_bytes(tm, tk, tn, k, 2) <= G._VMEM_BUDGET
+            assert k % tk == 0 and n % tn == 0
+            assert tk == k or tk % 128 == 0
+            assert tn == n or tn % 128 == 0
+            assert tm % 16 == 0 and tm <= rows
+            # the whole expert matrix: a product reads an expert once
+            assert (tk, tn) == (k, n)
