@@ -1364,7 +1364,8 @@ class InferenceEngineV2:
                          tenant=req.tenant, priority=req.pclass,
                          slo_ms=req.slo_ms, resumed_from=n_gen, trace=trace)
         self._enqueue_traced(uid, tenant=req.tenant, pclass=req.pclass,
-                             resumed=n_gen > 0, trace=trace)
+                             resumed=n_gen > 0, trace=trace,
+                             prompt_tokens=len(toks))
         if gen is None:
             shed = sched.submit(req)
             if shed is not None:
@@ -2393,6 +2394,7 @@ class InferenceEngineV2:
                     slots.admit(admits)
                 self._note_recovery_progress(slots, resume_t0, n_resumed)
             if slots.live_count() == 0:
+                tel.on_idle_boundary()
                 if exhausted and not sched.queued_count():
                     return
                 if boundaries:

@@ -659,19 +659,24 @@ class EngineRouter:
             self._unplaced.append((item, exclude))
             return False
         r = self._replicas[name]
+        tr = self._trace_of(item)
+        if self.tracer is not None and tr:
+            # stamped BEFORE the append: the replica's serve loop may take
+            # the item from its feed at once, and its wait there (the
+            # span ``engine.feed``) begins at this instant
+            placed = self._clock()
+            self.tracer.note_placed(tr["id"], placed)
+            self.tracer.instant(
+                tr["id"], "router.place", placed,
+                parent=tr.get("parent"), replica="router",
+                attrs={"uid": uid, "replica": name,
+                       "resumed": bool(isinstance(item, dict)
+                                       and item.get("generated"))})
         r.feed.append(item)
         self._assignment[uid] = name
         self.counters["placements"] += 1
         self.placements_by_engine[name] = \
             self.placements_by_engine.get(name, 0) + 1
-        tr = self._trace_of(item)
-        if self.tracer is not None and tr:
-            self.tracer.instant(
-                tr["id"], "router.place", self._clock(),
-                parent=tr.get("parent"), replica="router",
-                attrs={"uid": uid, "replica": name,
-                       "resumed": bool(isinstance(item, dict)
-                                       and item.get("generated"))})
         self._flight_note("placement", replica=name, uid=uid,
                           tick=self._tick,
                           trace=tr.get("id") if tr else None)

@@ -232,23 +232,27 @@ class ServiceEdge:
     # distributed-trace plumbing (no-ops when tracing is off)
     # ------------------------------------------------------------------
 
-    def _trace_instant(self, uid: int, name: str,
-                       attrs: Optional[Dict] = None) -> None:
-        if self.tracer is None:
+    def _note_sse_write(self, uid: int, n_tokens: int) -> None:
+        """A ``token`` event was written and flushed. The request's first
+        ends its time to first token (the stage ``egress``); every one is
+        an ``sse.write`` instant of its trace."""
+        tid = self._traces.get(uid) if self.tracer is not None else None
+        if tid is None:
             return
-        tid = self._traces.get(uid)
-        if tid is not None:
-            if name == "sse.write":
-                # cap per request, like the engine's emit instants: a
-                # long stream must not spend the trace's span budget on
-                # write markers before its terminal spans land
-                n = self._sse_spans.get(uid, 0)
-                if n >= 64:
-                    return
-                self._sse_spans[uid] = n + 1
-            # the root span id is "s0" by mint() construction
-            self.tracer.instant(tid, name, parent="s0", replica="edge",
-                                attrs={"uid": uid, **(attrs or {})})
+        # cap per request, like the engine's emit instants: a long stream
+        # must not spend the trace's span budget on write markers before
+        # its terminal spans land
+        seen = self._sse_spans.get(uid, 0)
+        if seen >= 64:
+            return
+        self._sse_spans[uid] = seen + 1
+        now = self.tracer.clock()
+        if not seen:
+            self.tracer.note_first_write(tid, now)
+        # the root span id is "s0" by mint() construction
+        self.tracer.instant(tid, "sse.write", now, parent="s0",
+                            replica="edge",
+                            attrs={"uid": uid, "n": n_tokens})
 
     def _trace_close(self, uid: int, outcome: str,
                      mark: Optional[str] = None) -> None:
@@ -268,17 +272,24 @@ class ServiceEdge:
                                                    "error"):
             self.flight.record("edge_" + outcome, uid=uid, trace=tid)
 
-    def handle_generate(self, body: Dict):
+    def handle_generate(self, body: Dict, recv_t: Optional[float] = None):
         """Shared core of the POST handler (unit-testable without
         sockets): returns ``("shed", verdict)`` or
         ``("stream", uid, events_queue)``. The caller owns consuming the
-        queue and cancelling on disconnect."""
+        queue and cancelling on disconnect. ``recv_t``: when the handler
+        had the request's bytes, on the tracer's clock (now, if the
+        caller took no stamp before it parsed them)."""
+        if recv_t is None and self.tracer is not None:
+            recv_t = self.tracer.clock()
         item = self._parse_request(body)
         uid = item["uid"]
         tid = None
         if self.tracer is not None:
-            # the trace starts the moment the edge accepted the bytes —
-            # fleet TTFT/E2E are measured from HERE, the client's view.
+            # the trace starts the moment the edge had the bytes (before
+            # they were parsed and validated: a long prompt's parse is
+            # part of its ``ingress``) — fleet TTFT/E2E are measured from
+            # THERE, the client's view. A stream's TTFT ends at its first
+            # write; a response sent whole at the end has none to wait for.
             # The root span carries the request's WORKLOAD identity
             # (prompt length, budget, scheduling metadata) so a trace
             # export is a replayable arrival trace — the
@@ -288,8 +299,9 @@ class ServiceEdge:
                       "session", "deadline_ms"):
                 if item.get(k) is not None:
                     attrs[k] = item[k]
-            tid, root = self.tracer.mint("edge.recv", replica="edge",
-                                         attrs=attrs)
+            tid, root = self.tracer.mint(
+                "edge.recv", replica="edge", t=recv_t, attrs=attrs,
+                awaits_write=bool(body.get("stream", True)))
             item["trace"] = {"id": tid, "parent": root}
             with self._lock:
                 self._traces[uid] = tid
@@ -412,9 +424,11 @@ class ServiceEdge:
                     n = int(self.headers.get("Content-Length", 0))
                     if n <= 0 or n > edge.cfg.max_body_bytes:
                         raise ValueError(f"body size {n} out of range")
-                    body = json.loads(self.rfile.read(n))
+                    raw = self.rfile.read(n)
+                    recv_t = edge.tracer.clock() if edge.tracer else None
+                    body = json.loads(raw)
                     stream = bool(body.get("stream", True))
-                    out = edge.handle_generate(body)
+                    out = edge.handle_generate(body, recv_t)
                 except (ValueError, KeyError, TypeError,
                         json.JSONDecodeError) as e:
                     edge._inc("errors")
@@ -490,8 +504,7 @@ class ServiceEdge:
                             "uid": uid, "tokens": ev["tokens"],
                             "index": n_sent})
                         n_sent += len(ev["tokens"])
-                        edge._trace_instant(uid, "sse.write",
-                                            {"n": len(ev["tokens"])})
+                        edge._note_sse_write(uid, len(ev["tokens"]))
                     elif ev["type"] == "done":
                         self._sse_event("done", {
                             "uid": uid, "tokens": ev["tokens"],

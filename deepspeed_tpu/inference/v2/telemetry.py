@@ -173,6 +173,17 @@ PHASES = ("idle", "poll", "admit", "plan", "dispatch", "fetch", "absorb",
           "publish", "retire", "yield")
 
 
+#: a request's time from the edge to its first token on the wire, cut where
+#: it is handed over (README "Time to first token, stage by stage"): each
+#: stage ends at the stamp of the thread that takes the request on
+TTFT_STAGES = ("ingress", "feed", "queue", "prefill", "egress")
+#: what ``tracing.TraceCollector`` folds a first token into, for the
+#: replica that emitted it; that replica's serve loop mirrors them into
+#: ``counters`` at its frame boundaries (``_sync_ttft``)
+TTFT_COUNTERS = ("ttft_requests", "ttft_total_ns") + tuple(
+    f"ttft_{s}_ns" for s in TTFT_STAGES) + ("ttft_prefill_frames",)
+
+
 class _CompileLog:
     """Every program this process asks XLA for, by the thread that asked:
     JAX's backend-compile event (it wraps a load from the persistent cache
@@ -390,13 +401,16 @@ class LogBucketHistogram:
 class _Span:
     __slots__ = ("uid", "enqueue_t", "admit_t", "first_token_t",
                  "last_emit_t", "tokens", "emit_spans", "tenant", "pclass",
-                 "resumed", "trace", "parent")
+                 "resumed", "trace", "parent", "prompt_tokens", "work0")
 
     def __init__(self, uid: int, enqueue_t: float,
                  tenant: Optional[str] = None, pclass: Optional[str] = None,
-                 resumed: bool = False):
+                 resumed: bool = False, prompt_tokens: int = 0):
         self.uid = uid
         self.enqueue_t = enqueue_t
+        self.prompt_tokens = prompt_tokens
+        # (frames, steps) the loop had dispatched when this was admitted
+        self.work0 = (0, 0)
         self.admit_t: Optional[float] = None
         self.first_token_t: Optional[float] = None
         self.last_emit_t: Optional[float] = None
@@ -503,6 +517,14 @@ class ServingTelemetry:
         # first phase (frame programs AND the small ones admission
         # dispatches), and the time it waited for them
         self.counters.update(programs_requested=0, compile_wait_ns=0)
+        # time to first token by stage, of the first tokens this replica
+        # emitted: mirrored from the tracer (_sync_ttft), which counts for
+        # its own lifetime, so from what it held when this run began
+        self.counters.update(dict.fromkeys(TTFT_COUNTERS, 0))
+        self._ttft_base = self._tracer_ttft()
+        # (width, steps) of the frame that ended before this boundary's
+        # poll; (0, 0) after a boundary that dispatched none
+        self.last_frame = (0, 0)
         self.counters.update(requests_enqueued=0, requests_admitted=0,
                              requests_retired=0, admission_deferrals=0,
                              requests_shed=0, requests_preempted=0,
@@ -663,6 +685,35 @@ class ServingTelemetry:
         self.tracer = tracer
         if replica is not None:
             self.trace_replica = replica
+        self._ttft_base = self._tracer_ttft()
+
+    def _tracer_ttft(self) -> Dict[str, int]:
+        if self.tracer is None:
+            return dict.fromkeys(TTFT_COUNTERS, 0)
+        return self.tracer.ttft_totals(self.trace_replica)
+
+    def _sync_ttft(self) -> None:
+        """Mirror the tracer's TTFT_COUNTERS for this replica into
+        ``counters``, as ``_sync_compiles`` mirrors the compile log: the
+        last stamp of a request (the edge's first write) comes from
+        another thread than the serve loop's, which owns ``counters``, so
+        the tracer folds under its lock and the loop copies at a frame
+        boundary. What an edge wrote during a frame shows after it."""
+        if self.tracer is not None:
+            for name, total in self._tracer_ttft().items():
+                self.counters[name] = total - self._ttft_base[name]
+
+    def on_idle_boundary(self) -> None:
+        """A boundary with nothing live: no frame follows it."""
+        if self.enabled:
+            self.last_frame = (0, 0)
+            self._sync_ttft()
+
+    def _work_done(self) -> tuple:
+        """(frames, steps) the loop has dispatched this run."""
+        v = self.serve_view
+        return v["frames"], sum(
+            steps * n for steps, n in v["frame_steps_hist"].items())
 
     def _trace_span(self, span, name: str, t0: float, t1=None,
                     status: Optional[str] = None,
@@ -708,8 +759,14 @@ class ServingTelemetry:
     def on_enqueue(self, uid: int, tenant: Optional[str] = None,
                    pclass: Optional[str] = None,
                    resumed: bool = False,
-                   trace: Optional[Dict] = None) -> Optional[Dict]:
-        """``trace`` is the distributed-trace context the arrival carried
+                   trace: Optional[Dict] = None,
+                   prompt_tokens: int = 0) -> Optional[Dict]:
+        """The serve loop's poll took ``uid`` from its arrivals. Where a
+        router fed them, the wait since it placed the request there (the
+        rest of the frame that was in flight) is the span ``engine.feed``,
+        which says what frame that was; ``engine.queue`` begins here.
+
+        ``trace`` is the distributed-trace context the arrival carried
         (``{"id", "parent"}``, minted at the edge/router); with a tracer
         attached and no context, a trace is minted HERE — a bare engine
         (tuple arrivals) still yields one connected tree per request.
@@ -719,7 +776,8 @@ class ServingTelemetry:
         if not self.enabled:
             return trace
         self.counters["requests_enqueued"] += 1
-        span = _Span(uid, self.clock(), tenant, pclass, resumed=resumed)
+        span = _Span(uid, self.clock(), tenant, pclass, resumed=resumed,
+                     prompt_tokens=prompt_tokens)
         if self.tracer is not None:
             if not trace:
                 tid, root = self.tracer.mint(
@@ -728,6 +786,13 @@ class ServingTelemetry:
                 trace = {"id": tid, "parent": root}
             span.trace = trace.get("id")
             span.parent = trace.get("parent")
+            placed = self.tracer.placed_at(span.trace)
+            if placed is not None:
+                width, steps = self.last_frame
+                self._trace_span(
+                    span, "engine.feed", placed,
+                    max(placed, span.enqueue_t),
+                    attrs={"after_width": width, "after_steps": steps})
         self._open_spans[uid] = span
         return trace
 
@@ -744,6 +809,7 @@ class ServingTelemetry:
             # signal the scheduler sheds on. A request admits once.
             return
         span.admit_t = self.clock()
+        span.work0 = self._work_done()
         self.counters["requests_admitted"] += 1
         wait = span.admit_t - span.enqueue_t
         self.hists["queue_wait"].record(wait)
@@ -774,11 +840,28 @@ class ServingTelemetry:
             # The collector keys fleet TTFT by TRACE id — only the first
             # replica to emit records a sample, so a handed-off/failed-
             # over request gets exactly one true first-token time.
+            frames, steps = (a - b for a, b in
+                             zip(self._work_done(), span.work0))
             self._trace_span(
                 span, "engine.restore" if span.resumed else
-                "engine.prefill", span.admit_t or span.enqueue_t, now)
+                "engine.prefill", span.admit_t or span.enqueue_t, now,
+                attrs={"frames": frames, "steps": steps})
+            stages = None
             if self.tracer is not None and span.trace is not None:
-                self.tracer.note_first_token(span.trace, now)
+                stages = self.tracer.note_first_token(
+                    span.trace, now, replica=self.trace_replica,
+                    poll_t=span.enqueue_t, admit_t=span.admit_t,
+                    frames=frames)
+            if self.trace and not span.resumed:
+                # the request's stages on the profiler's clock, beside
+                # the frame that ended them; ``mono_ns`` is this instant
+                # on the spans' clock, the offset between the two
+                with jax.profiler.TraceAnnotation(
+                        "serve/first_token", uid=span.uid,
+                        prompt_tokens=span.prompt_tokens, frames=frames,
+                        steps=steps, mono_ns=time.monotonic_ns(),
+                        **{f"{k}_ns": v for k, v in (stages or {}).items()}):
+                    pass
         else:
             gap = max(0.0, now - span.last_emit_t)
             self.hists["itl"].record(gap / n_tokens, count=n_tokens)
@@ -1211,6 +1294,7 @@ class ServingTelemetry:
             self.gauges["kv_blocks_in_use_peak"], kv_blocks_in_use)
         self.gauges["queue_depth"] = queue_depth
         self._sync_compiles()
+        self._sync_ttft()
         if self.gauges["slot_count"]:
             self.gauges["occupancy"] = round(
                 int(delta[STAT_ACTIVE_STEPS])
@@ -1243,6 +1327,7 @@ class ServingTelemetry:
         per-frame work that runs when telemetry is disabled."""
         v = self.serve_view
         v["telemetry_enabled"] = self.enabled   # stays live across toggles
+        self.last_frame = (width, steps)
         v["frames"] += 1
         v["frame_steps_last"] = steps
         v["frame_steps_hist"][steps] = v["frame_steps_hist"].get(steps, 0) + 1

@@ -38,6 +38,16 @@ DeepSpeed Inference, arXiv 2207.00032), in three pieces:
    record nothing locally). Histogram recording is independent of span
    sampling — an unsampled trace still counts.
 
+   With that one sample goes the request's TIME TO FIRST TOKEN BY STAGE
+   (``telemetry.TTFT_STAGES``; README "Time to first token, stage by
+   stage"): ``note_first_token`` cuts mint -> ``note_placed`` -> the
+   serve loop's poll -> admit -> first token -> ``note_first_write`` into
+   nanoseconds that tile the total, and sums them per replica
+   (``ttft_totals``); each replica's serve loop mirrors its own into
+   ``telemetry.counters``, so they ride ``/metrics``. The serve loop's
+   own histograms start at its poll and cannot see a request wait in
+   the replica's feed for the frame in flight.
+
 3. **FlightRecorder** — a bounded ring of structured fleet events
    (placements, heartbeats, faults, kills, tier commits, autoscale
    actions) plus a postmortem dump: on replica DEAD, on an engine crash
@@ -62,7 +72,7 @@ import time
 from typing import Dict, List, Optional, Tuple
 
 from ...utils.logging import logger
-from .telemetry import LogBucketHistogram
+from .telemetry import TTFT_COUNTERS, TTFT_STAGES, LogBucketHistogram
 
 #: marks that force retention regardless of ``sample_rate`` — the
 #: always-sample set the ISSUE pins (plus cancel/preempt, which are the
@@ -72,6 +82,10 @@ IMPORTANT_MARKS = ("fault", "shed", "handoff", "failover", "cancelled",
 
 #: flight-recorder event kinds that trigger an automatic postmortem dump
 AUTO_DUMP_KINDS = ("replica_dead", "engine_crash")
+
+
+def _ns(t: float) -> int:
+    return int(round(t * 1e9))
 
 
 def _frac_of(trace_id: str) -> float:
@@ -137,18 +151,31 @@ class TraceCollector:
             traces_minted=0, traces_retained=0, traces_dropped=0,
             spans_recorded=0, spans_truncated=0, ttft_samples=0,
             e2e_samples=0)
+        # TTFT by stage (TTFT_COUNTERS), summed per replica that emitted
+        # the first token; like the histograms, independent of sampling.
+        # A trace whose edge streams waits here, from its first token to
+        # its first write, outside the trace's own record: an unsampled
+        # trace is dropped when its engine retires it, which a short
+        # request does before the edge has written anything
+        self._ttft_totals: Dict[Optional[str], Dict[str, int]] = {}
+        self._ttft_pending: "collections.OrderedDict[str, Dict]" = \
+            collections.OrderedDict()
 
     # ------------------------------------------------------------------
     # span production
     # ------------------------------------------------------------------
 
     def mint(self, name: str = "request", replica: str = "edge",
-             t: Optional[float] = None,
-             attrs: Optional[Dict] = None) -> Tuple[str, str]:
+             t: Optional[float] = None, attrs: Optional[Dict] = None,
+             awaits_write: bool = False) -> Tuple[str, str]:
         """Create a new trace with its root span open; returns
         ``(trace_id, root_span_id)`` — the ``{"id", "parent"}`` context
         the arrival dict carries from here on. The root span id is
-        always ``"s0"`` (per-trace span ids are sequential)."""
+        always ``"s0"`` (per-trace span ids are sequential).
+        ``awaits_write``: whoever mints it will report the request's
+        first write to the client (``note_first_write``), so its TTFT
+        sample waits for that; otherwise the sample ends at the first
+        token's emission."""
         with self._lock:
             self._seq += 1
             tid = f"t{self._seq:08x}"
@@ -160,6 +187,7 @@ class TraceCollector:
                 "id": tid, "t0": t, "t_last": t, "nspans": 1, "seq": 1,
                 "spans": [root], "marks": [], "status": None,
                 "uid": (attrs or {}).get("uid"),
+                "awaits_write": awaits_write, "place_t": None,
             }
             self.counters["traces_minted"] += 1
             # bound the open set: a leaked/abandoned trace must not grow
@@ -218,18 +246,91 @@ class TraceCollector:
             if tr is not None and mark not in tr["marks"]:
                 tr["marks"].append(mark)
 
-    def note_first_token(self, trace_id: str, t: float) -> None:
+    def note_placed(self, trace_id: str, t: float) -> None:
+        """The router is about to append the request to a replica's feed
+        (the ``router.place`` instant): where ``ingress`` ends and
+        ``feed`` begins. A re-placement (failover, handoff, drain)
+        overwrites it: the time lost to the first try is ``ingress``."""
+        with self._lock:
+            tr = self._trace(trace_id)
+            if tr is not None:
+                tr["place_t"] = t
+
+    def placed_at(self, trace_id: str) -> Optional[float]:
+        with self._lock:
+            tr = self._trace(trace_id)
+            return None if tr is None else tr["place_t"]
+
+    def note_first_token(self, trace_id: str, t: float, *,
+                         replica: Optional[str] = None,
+                         poll_t: Optional[float] = None,
+                         admit_t: Optional[float] = None,
+                         frames: int = 0) -> Optional[Dict[str, int]]:
         """Record the trace's FIRST first-token time — exactly one
         fleet-TTFT sample per trace id, whichever replica got there
         first (handoff: the prefill replica; failover: the original
-        unless it died before emitting). Independent of span sampling."""
+        unless it died before emitting). Independent of span sampling.
+
+        This is also where the request's time is cut into TTFT_STAGES,
+        the one place that computes them: mint -> ``note_placed`` ->
+        ``poll_t`` (the serve loop took it from its feed) -> ``admit_t``
+        -> ``t`` -> ``note_first_write``, in whole nanoseconds so that
+        the stages tile the total exactly. A stamp nobody gave (a bare
+        engine has no router, a unit test no serve loop) falls on the
+        one before it: that stage is 0. Returns the stages known now
+        (all but ``egress``), or None if the trace had its sample."""
         with self._lock:
             tr = self._trace(trace_id)
             if tr is None or trace_id in self._ttft_done:
-                return
+                return None
             self._ttft_done.add(trace_id)
             self.fleet_ttft.record(max(0.0, t - tr["t0"]))
             self.counters["ttft_samples"] += 1
+            at = _ns(tr["t0"])
+            stages = {}
+            # four stamps, so four stages: ``egress`` ends at the write
+            for stage, stamp in zip(TTFT_STAGES, (tr["place_t"], poll_t,
+                                                  admit_t, t)):
+                end = at if stamp is None else max(at, _ns(stamp))
+                stages[stage], at = end - at, end
+            rec = {"replica": replica, "first_ns": at, "frames": frames,
+                   "stages": stages}
+            if tr["awaits_write"]:
+                self._ttft_pending[trace_id] = rec
+                # a stream that never wrote (a client gone before its
+                # first token) leaves its record here: bound them
+                while len(self._ttft_pending) > 4 * self.max_traces:
+                    self._ttft_pending.popitem(last=False)
+            else:
+                self._fold_ttft(rec, at)
+            return dict(stages)
+
+    def note_first_write(self, trace_id: str, t: float) -> None:
+        """The edge has written and flushed the request's first ``token``
+        event: ``egress`` ends, and the request's stages reach the
+        totals of the replica that emitted the token."""
+        with self._lock:
+            rec = self._ttft_pending.pop(trace_id, None)
+            if rec is not None:
+                self._fold_ttft(rec, max(rec["first_ns"], _ns(t)))
+
+    def _fold_ttft(self, rec: Dict, end_ns: int) -> None:
+        tot = self._ttft_totals.setdefault(
+            rec["replica"], dict.fromkeys(TTFT_COUNTERS, 0))
+        stages = dict(rec["stages"], egress=end_ns - rec["first_ns"])
+        tot["ttft_requests"] += 1
+        tot["ttft_total_ns"] += sum(stages.values())
+        for stage, ns in stages.items():
+            tot[f"ttft_{stage}_ns"] += ns
+        tot["ttft_prefill_frames"] += rec["frames"]
+
+    def ttft_totals(self, replica: Optional[str] = None) -> Dict[str, int]:
+        """TTFT_COUNTERS of the first tokens ``replica`` emitted, since
+        this collector was made (a copy: the replica's serve loop mirrors
+        it into its own counters at a frame boundary)."""
+        with self._lock:
+            return dict(self._ttft_totals.get(replica)
+                        or dict.fromkeys(TTFT_COUNTERS, 0))
 
     def note_done(self, trace_id: str, t: float) -> None:
         """One fleet end-to-end sample per trace id (mint -> retire)."""

@@ -22,7 +22,8 @@ import pytest
 from deepspeed_tpu.inference.v2.engine_v2 import (InferenceEngineV2,
                                                   RaggedInferenceEngineConfig)
 from deepspeed_tpu.inference.v2.ragged_manager import DeviceSlotTable
-from deepspeed_tpu.inference.v2.telemetry import (LogBucketHistogram,
+from deepspeed_tpu.inference.v2.telemetry import (TTFT_COUNTERS,
+                                                  LogBucketHistogram,
                                                   ServingTelemetry)
 from deepspeed_tpu.models import build_model
 from deepspeed_tpu.utils.logging import logger as ds_logger
@@ -508,8 +509,13 @@ def test_fifo_tuple_serve_matches_the_fifo_loop(tiny_model_params):
     assert [r["uid"] for r in got["snapshot"]["requests"]] and all(
         r["tenant"] is None and r["priority"] is None and r["slo_ms"] is None
         for r in got["snapshot"]["requests"])
-    assert got["series"] == want["series"]
-    assert got["counters"] == want["counters"]
+    # series younger than the golden tree: the TTFT stage counters, which
+    # stay 0 on an engine that no trace collector is attached to
+    younger = {f"ds_serving_{n}_total": "0" for n in TTFT_COUNTERS}
+    assert [s for s in got["series"] if s not in younger] == want["series"]
+    assert younger.keys() <= set(got["series"])
+    assert got["counters"] == {**want["counters"], **{
+        k: v for k, v in younger.items() if not k.endswith(_UNPINNED)}}
     assert got["counters"]["ds_serving_admission_deferrals_total"] != "0"
 
 

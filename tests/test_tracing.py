@@ -80,6 +80,11 @@ def _replicas_of(trace):
     return {s["replica"] for s in trace["spans"]} - {"router", "edge"}
 
 
+def _ttft_samples(col, *replicas):
+    """First tokens folded into the TTFT stage counters, by replica."""
+    return [col.ttft_totals(r)["ttft_requests"] for r in replicas]
+
+
 # ---------------------------------------------------------------------------
 # collector units (no engines)
 # ---------------------------------------------------------------------------
@@ -242,6 +247,9 @@ def test_cancel_and_shed_traces_always_sampled(tiny_model_params):
     # faulted/shed requests record no fleet E2E sample (mirrors the
     # per-replica histogram semantics)
     assert col.snapshot()["counters"]["e2e_samples"] == 1
+    # and no stage sample: only uid 0 ever had a first token
+    assert _ttft_samples(col, "solo") == [1]
+    assert eng.telemetry.counters["ttft_requests"] == 1
 
 
 # ---------------------------------------------------------------------------
@@ -287,6 +295,18 @@ def test_failover_one_connected_trace(tiny_model_params, tmp_path):
     snap = col.snapshot()
     assert snap["counters"]["ttft_samples"] == 6
     assert snap["counters"]["e2e_samples"] == 6
+    # and exactly one stage sample, on the replica that emitted the first
+    # token: a failed-over request is not counted again by its second
+    by_replica = _ttft_samples(col, "a", "b")
+    assert sum(by_replica) == 6 and all(by_replica), by_replica
+    for name in "ab":
+        tot = col.ttft_totals(name)
+        assert sum(tot[f"ttft_{s}_ns"] for s in (
+            "ingress", "feed", "queue", "prefill", "egress")) \
+            == tot["ttft_total_ns"]
+    # the survivor's serve loop mirrored every one of its own
+    assert router._replicas["b"].engine.telemetry.counters[
+        "ttft_requests"] == by_replica[1]
     # per-replica TTFT stays resumed-blind: total per-replica samples
     # equal fresh enqueues only (the failed-over request sampled once,
     # on its FIRST replica)
@@ -348,6 +368,11 @@ def test_handoff_one_connected_trace(tiny_model_params, tmp_path):
     snap = col.snapshot()
     assert snap["counters"]["ttft_samples"] == 2
     assert snap["counters"]["e2e_samples"] == 2
+    # one stage sample a trace too, the handed-off one on the PREFILL
+    # replica; its trace shows the wait in each replica's feed
+    assert sum(_ttft_samples(col, "prefill0", "decode0")) == 2
+    assert _ttft_samples(col, "prefill0")[0] >= 1
+    assert names.count("engine.feed") == 2
     # tier commits reached the flight ring
     assert any(e["kind"] == "tier_commit" for e in fr.events)
     assert any(e["kind"] == "handoff" for e in fr.events)
@@ -596,3 +621,49 @@ def test_trace_context_survives_snapshot_split(tiny_model_params):
     tr = items[0]["trace"]
     assert tr is not None and tr["id"] in {t["id"] for t in col.traces()}
     assert tr["parent"] == "s0"
+
+
+# ---------------------------------------------------------------------------
+# TTFT stage samples: none without a first token
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("fate", ["shed", "deadline_expired", "poison_row",
+                                  "cancelled"])
+def test_no_ttft_stage_sample_without_a_first_token(tiny_model_params, fate):
+    """A request shed, expired, quarantined or cancelled BEFORE its first
+    token adds nothing to the stage counters; its neighbour adds one."""
+    from deepspeed_tpu.inference.v2.faults import FaultInjector
+    from deepspeed_tpu.inference.v2.scheduler import (RequestScheduler,
+                                                      SchedulerConfig)
+    model, params = tiny_model_params
+    eng = _engine(model, params)
+    col = TraceCollector(sample_rate=0.0)
+    eng.telemetry.set_tracer(col, replica="solo")
+    # 40 tokens at 8 a step, 2 steps a frame: no first token before frame 3
+    victim = {"uid": 1, "tokens": RNG.integers(0, 200, (40,)).astype(np.int32),
+              "tenant": "t0"}
+    kw = {}
+    if fate == "shed":
+        kw["scheduler"] = RequestScheduler(SchedulerConfig(tenant_max_queued=1))
+    elif fate == "deadline_expired":
+        victim["deadline_ms"] = 1e-6
+    elif fate == "poison_row":
+        kw["faults"] = FaultInjector(
+            [{"kind": "poison_row", "frame": 0, "uid": 1}])
+    arrivals = iter([[{"uid": 0, "tokens": PROMPTS[0], "tenant": "t0"},
+                      victim]])
+    out = {}
+    for ev in eng.serve(arrivals, max_new_tokens=MAX_NEW,
+                        yield_boundaries=True, **kw):
+        if isinstance(ev, tuple):
+            out[ev[0]] = ev[1]
+        elif fate == "cancelled" and ev.dispatched and ev.index == 0:
+            assert eng.cancel_request(1)
+    assert set(out) == {0}
+    assert _ttft_samples(col, "solo") == [1]
+    assert eng.telemetry.counters["ttft_requests"] == 1
+    assert not col._ttft_pending
+    status = {t["uid"]: t["status"] for t in col.traces()}[1]
+    assert status.startswith("shed:") if fate == "shed" else \
+        status in (fate, "cancelled"), status
