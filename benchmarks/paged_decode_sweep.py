@@ -3,6 +3,7 @@
     python benchmarks/paged_decode_sweep.py [--root DIR] [--label NAME]
                                             [--chunk C] [--configs A,B]
                                             [--live N,M] [--contexts N,M]
+                                            [--riders N,M] [--tiling H,P,T]
 
 One step's attention (``--chunk`` 1: the narrow kernel of a decode step;
 128: the wide one of a prefill chunk) at the serve configurations' shapes
@@ -18,7 +19,12 @@ head_dim 128, bf16) over live slots 1 / 3 / 16 and contexts 256 / 1,024 /
 bytes over the time as a share of the chip's 819 GB/s, and the largest gap
 of a live row's output to a float32 gather reference (at either width: a
 chunk's rows attend the pool below the chunk and the chunk's own keys
-causally). ``live = 0`` is what sixteen frozen slots cost. ``--root`` imports ``deepspeed_tpu`` from another
+causally). ``live = 0`` is what sixteen frozen slots cost. ``--riders N``
+(with ``--chunk`` > 1): N of the live slots are DECODING rows riding the
+wide step, ONE live position at the context, the other live slots a full
+chunk there. ``--tiling H,P,T`` times another tiling than ``_tiling``'s own
+(kv heads a step, pages a group, rows a row tile) before the rule is
+changed. ``--root`` imports ``deepspeed_tpu`` from another
 checkout (a ``git archive`` copy of the parent), so one script times both
 sides. Needs the chip: the kernel's interpret mode times nothing.
 """
@@ -57,6 +63,8 @@ def main():
     ap.add_argument("--configs", default=",".join(CONFIGS))
     ap.add_argument("--live", default=",".join(map(str, LIVE)))
     ap.add_argument("--contexts", default=",".join(map(str, CONTEXTS)))
+    ap.add_argument("--riders", default="0")
+    ap.add_argument("--tiling", default="")
     args = ap.parse_args()
     sys.path.insert(0, os.path.abspath(args.root))
 
@@ -64,7 +72,12 @@ def main():
     import jax
     import jax.numpy as jnp
     import inspect
+    from deepspeed_tpu.ops.pallas import paged_attention
     from deepspeed_tpu.ops.pallas.paged_attention import paged_ragged_attention
+    if args.tiling:
+        forced = tuple(map(int, args.tiling.split(",")))
+        paged_attention._tiling = lambda rows, kvh, mb, *_: (
+            forced[0], min(forced[1], mb), *forced[2:])
     has_ring = "ring" in inspect.signature(paged_ragged_attention).parameters
     chunk = args.chunk
 
@@ -104,7 +117,8 @@ def main():
         return jnp.einsum("hgck,hkd->chgd", p, v,
                           precision="highest").reshape(-1, h, v.shape[-1])
 
-    def bench(pool, h, window, mb, live, ctx, ring, dv=None, scale=None):
+    def bench(pool, h, window, mb, live, ctx, ring, dv=None, scale=None,
+              riders=0):
         kvh, pool_pages, D = pool.shape[1], pool.shape[2], pool.shape[-1]
         vpool = None if dv else pool
         rng = np.random.default_rng(live * 10007 + ctx)
@@ -119,7 +133,8 @@ def main():
         for s in range(live):
             # a slot's pages lie scattered through the pool, as in a server
             tables[s, :pages] = 1 + rng.permutation(pool_pages - 1)[:pages]
-            pos[s] = ctx + np.arange(chunk)
+            rows = 1 if s < riders else chunk
+            pos[s, :rows] = ctx + np.arange(rows)
         kw = {"ring": ring} if ring else {}
         if dv:
             kw.update(value_lanes=dv, scale=scale)
@@ -147,10 +162,12 @@ def main():
             if ring:
                 # the ring as the table it stands for: page p in slot p mod R
                 ref_tables = ref_tables[:, jnp.arange(mb) % ring]
+            # over the live rows: a dead row's output is nobody's
             gap = max(float(jnp.max(jnp.abs(
                 one[s].astype(jnp.float32) - reference(
                     q[s], pool, pool, ref_tables[s], a[4][s], ck[s], ck[s],
-                    window, dv=dv, scale=scale)))) for s in range(live))
+                    window, dv=dv, scale=scale))[pos[s] >= 0]))
+                for s in range(live))
         times = []
         for _ in range(5):
             t = time.perf_counter()
@@ -181,12 +198,15 @@ def main():
                 if ctx > mb * PAGE:
                     continue
                 ctx = min(ctx, mb * PAGE - chunk)  # the chunk needs its slots
-                row = {"label": args.label, "config": name, "chunk": chunk,
-                       "live": live, "context": ctx,
-                       "device": dev.device_kind,
-                       **bench(pool, h, window, mb, live, ctx, ring, dv,
-                               scale)}
-                print(json.dumps(row), flush=True)
+                for riders in map(int, args.riders.split(",")):
+                    if riders > live or riders and (chunk == 1 or not live):
+                        continue
+                    row = {"label": args.label, "config": name,
+                           "chunk": chunk, "live": live, "riders": riders,
+                           "context": ctx, "device": dev.device_kind,
+                           **bench(pool, h, window, mb, live, ctx, ring, dv,
+                                   scale, riders)}
+                    print(json.dumps(row), flush=True)
 
 
 if __name__ == "__main__":

@@ -100,6 +100,17 @@ class PagedModelRunner:
         K and V by head, whose stat vector has no such lanes."""
         return self.cfg.attn_layers if self.cfg.latent_lanes else None
 
+    @property
+    def row_heads(self):
+        """(kv heads, query rows a position a kv head) the row-tile counters
+        (``telemetry.TILE_STAT_NAMES``) cut a wide step's chunk by; None for
+        the latent format, whose kernel tiles the chunk's positions itself
+        (the counters stay 0)."""
+        cfg = self.cfg
+        if cfg.latent_lanes:
+            return None
+        return cfg.kv_heads, cfg.num_heads // cfg.kv_heads
+
     def stat_context(self, max_seq_len: int, chunk: int) -> int:
         """The context ``telemetry.check_stat_range`` bounds a frame's
         largest work lane by: the uniform window or the longest sequence,
@@ -769,7 +780,8 @@ class PagedModelRunner:
                                               window=self.stat_window,
                                               ladder=self.pack_ladder,
                                               layers=self.layer_work,
-                                              latent=self.latent_layers)
+                                              latent=self.latent_layers,
+                                              heads=self.row_heads)
 
                 zero = jnp.zeros((b,), jnp.int32)
                 no = jnp.zeros((b,), bool)
@@ -852,7 +864,8 @@ class PagedModelRunner:
                                           window=self.stat_window,
                                           ladder=self.pack_ladder,
                                           layers=self.layer_work,
-                                          latent=self.latent_layers)
+                                          latent=self.latent_layers,
+                                          heads=self.row_heads)
                 carry = (cached, produced, last_tok, done, poison, nonfinite,
                          stats, rng, kpool, vpool)
                 carry, (toks, emit) = jax.lax.scan(body, carry, None,
@@ -915,7 +928,7 @@ class PagedModelRunner:
                     temps, tables, width, greedy,
                     draft=(draft_fwd, draft_params, gamma), repair=repair,
                     window=self.stat_window,
-                    ladder=self.pack_ladder)
+                    ladder=self.pack_ladder, heads=self.row_heads)
                 carry = (cached, produced, last_tok, penult, done, poison,
                          nonfinite, stats, rng, kpool, vpool, dkpool, dvpool)
                 carry, (toks, emit) = jax.lax.scan(body, carry, None,
@@ -974,7 +987,8 @@ class PagedModelRunner:
                                               draft=(draft_fwd, draft_params,
                                                      gamma),
                                               window=self.stat_window,
-                                              ladder=self.pack_ladder)
+                                              ladder=self.pack_ladder,
+                                              heads=self.row_heads)
 
                 zero = jnp.zeros((b,), jnp.int32)
                 no = jnp.zeros((b,), bool)
@@ -1147,7 +1161,7 @@ def _on_live(pack, fn, *xs, live=None):
 def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                        temps, tables, width, greedy, draft=None,
                        repair=False, window=None, ladder=pack_ladder,
-                       layers=None, latent=None):
+                       layers=None, latent=None, heads=None):
     """Shared scan-step for ``mixed_loop`` and ``frame_loop`` — the in-graph
     SplitFuse scheduling arithmetic lives in exactly one place.
 
@@ -1193,11 +1207,14 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
     layers, each under its own window (``_attn_work_by_layer``).
     ``latent`` (``PagedModelRunner.latent_layers``, a model with latent
     attention): the step counts the latent rows its attention layers read
-    and the pairs they score (``LATENT_STAT_NAMES``)."""
+    and the pairs they score (``LATENT_STAT_NAMES``). ``heads``
+    (``PagedModelRunner.row_heads``): a wide step counts its row tiles
+    (``_row_tile_work``)."""
     if draft is not None:
         return _spec_scan_body(fwd, params, prompts, prompt_lens, limits,
                                eos_ids, temps, tables, width, greedy, *draft,
-                               repair=repair, window=window, ladder=ladder)
+                               repair=repair, window=window, ladder=ladder,
+                               heads=heads)
 
     def body(carry, _):
         (cached, produced, last_tok, done, poison, nonfinite, stats, rng,
@@ -1210,6 +1227,7 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
             # the attention's work this step (before a repair zeroes w:
             # the step was computed either way)
             kv_read, attn_pairs = _attn_work(cached, w, window)
+            row_tiles = _row_tile_work(w, width, heads)
             layer_work = None if layers is None else \
                 _attn_work_by_layer(cached, w, layers)
             if latent:
@@ -1242,8 +1260,8 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                 prefill_toks=jnp.where(prefilling, w, 0),
                 eos=emit & (nxt == eos_ids),
                 target_fwd=active & ~prefilling,
-                kv_read=kv_read, attn_pairs=attn_pairs, moe_work=moe_work,
-                layer_work=layer_work)
+                kv_read=kv_read, attn_pairs=attn_pairs, row_tiles=row_tiles,
+                moe_work=moe_work, layer_work=layer_work)
         return ((cached + w, produced + emit.astype(jnp.int32),
                  last_tok, done, poison, nonfinite, stats, rng, kpool,
                  vpool),
@@ -1292,6 +1310,23 @@ def _attn_work(cached, w, window):
     return kv, w * kv
 
 
+def _row_tile_work(w, width, heads):
+    """(row tiles, those that hold a live row) of ONE layer's wide step in
+    which each row consumes ``w`` positions of a chunk of ``width``: over
+    slots and ``heads[0]`` kv heads, a head's ``width x heads[1]`` query
+    rows cut as the paged kernel cuts them
+    (``paged_attention.row_tile``), a row's live ones the first
+    ``w x heads[1]``. None for a step whose rows are not cut, or where
+    ``heads`` is."""
+    from ...ops.pallas.paged_attention import row_tile
+    tile = None if heads is None else row_tile(width * heads[1])
+    if tile is None:
+        return None
+    kvh, group = heads
+    return (jnp.asarray(kvh * w.shape[0] * (width * group // tile)),
+            kvh * ((w * group + tile - 1) // tile))
+
+
 def _attn_work_by_layer(cached, w, layer_windows):
     """``_attn_work`` summed over the rows and over the LAYERS, each layer
     under its own window (0: none), in the order of
@@ -1311,15 +1346,16 @@ def _attn_work_by_layer(cached, w, layer_windows):
 
 def _stat_delta(positions, ladder, emitted=None, active=None,
                 prefill_toks=None, eos=None, target_fwd=None, drafted=None,
-                accepted=None, kv_read=None, attn_pairs=None, moe_work=None,
-                layer_work=None):
+                accepted=None, kv_read=None, attn_pairs=None, row_tiles=None,
+                moe_work=None, layer_work=None):
     """One step's (N_STATS,) in-graph counter increment. Each keyword is a
     bool mask / int array to sum, or None for zero — the layout is pinned by
     the STAT_* indices in ``telemetry.py`` and the host-mirror replay tests
     assert the resulting totals exactly. ``positions`` (B, C) is the chunk
     the target forwarded and ``ladder`` its rungs: the lane after the sums
     is the rung the forward chose for it (``_rung_of``, the same
-    arithmetic), then one step at that rung. Behind them ``moe_work``, the
+    arithmetic), then one step at that rung; ``row_tiles`` is
+    ``_row_tile_work``'s pair. Behind them ``moe_work``, the
     target forward's own count of its routed experts' work
     (``MOE_STAT_NAMES``, and ``SHARE_STAT_NAMES`` behind them where its
     router is wider than the experts held), where the model has any
@@ -1327,7 +1363,7 @@ def _stat_delta(positions, ladder, emitted=None, active=None,
     where the model mixes cache kinds, ``LATENT_STAT_NAMES`` where its
     attention is latent."""
     vals = [emitted, active, prefill_toks, eos, target_fwd, drafted, accepted,
-            kv_read, attn_pairs]
+            kv_read, attn_pairs, *(row_tiles or (None, None))]
     z = jnp.zeros((), jnp.int32)
     out = [z if v is None else jnp.sum(v.astype(jnp.int32)) for v in vals]
     rung = _rung_of(positions, ladder)
@@ -1383,7 +1419,8 @@ def _wide_emit(active, prefilling, cached, w, prompt_lens, eos_ids, nxt,
 
 def _spec_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                     temps, tables, width, greedy, draft_fwd, draft_params,
-                    gamma, repair=False, window=None, ladder=pack_ladder):
+                    gamma, repair=False, window=None, ladder=pack_ladder,
+                    heads=None):
     """Speculative variant of the serving scan step (see
     ``_serving_scan_body``). Carry: (cached, produced, last_tok, penult,
     done, poison, nonfinite, stats, rng, kpool, vpool, dkpool, dvpool);
@@ -1416,6 +1453,7 @@ def _spec_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                     prompts, prompt_lens, limits, width, cached, produced,
                     last_tok, done)
                 kv_read, attn_pairs = _attn_work(cached, w, window)
+                row_tiles = _row_tile_work(w, width, heads)
             logits, kpool, vpool, moe_work = fwd(
                 params, ids, positions, tables, w, kpool, vpool,
                 moe_work=True)
@@ -1461,7 +1499,8 @@ def _spec_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                 emitted=emit, active=active,
                 prefill_toks=jnp.where(prefilling, w, 0),
                 eos=emit & (nxt == eos_ids),
-                kv_read=kv_read, attn_pairs=attn_pairs, moe_work=moe_work)
+                kv_read=kv_read, attn_pairs=attn_pairs, row_tiles=row_tiles,
+                moe_work=moe_work)
             return ((cached + w, produced + emit.astype(jnp.int32), last_tok,
                      penult, done, poison, nonfinite, stats, rng, kpool,
                      vpool, dkpool, dvpool),

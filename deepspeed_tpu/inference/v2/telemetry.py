@@ -81,6 +81,12 @@ from ...utils.logging import logger
 #                  rows: min(cached + w, sliding_window + w), or cached + w
 #                  without a (uniform) window — w is the row's chunk width
 #   ATTN_PAIRS     query x key pairs that attention must score: w * KV_READ
+#   ROW_TILES      row tiles of a WIDE step's query rows, one layer's: over
+#                  slots and kv heads, a head's width x G rows a slot cut as
+#                  the paged kernel cuts them (``paged_attention.row_tile``)
+#   ROW_TILES_LIVE those of them that hold a live row, ceil(w x G / tile):
+#                  the tiles the kernel computes (0 for a frozen slot, 1 for
+#                  a decoding row riding the step)
 #   POSITIONS      token positions the step's per-token layers (embedding,
 #                  projections, MLP) ran: the rung of ``pack_ladder`` the
 #                  step chose in the graph, or slots x width where the
@@ -109,8 +115,10 @@ STAT_DRAFTED = 5
 STAT_ACCEPTED = 6
 STAT_KV_READ = 7
 STAT_ATTN_PAIRS = 8
-STAT_POSITIONS = 9
-STAT_RUNG0 = 10
+STAT_ROW_TILES = 9
+STAT_ROW_TILES_LIVE = 10
+STAT_POSITIONS = 11
+STAT_RUNG0 = 12
 #: rungs a ladder may have (``pack_ladder``), one lane each
 MAX_RUNGS = 6
 N_STATS = STAT_RUNG0 + MAX_RUNGS
@@ -125,6 +133,8 @@ STAT_NAMES = ("tokens_emitted", "active_row_steps", "prefill_tokens",
               "accepted_draft_tokens")
 #: lanes after STAT_NAMES, split by frame width at absorption
 SPLIT_STAT_NAMES = ("kv_positions_read", "attn_pairs")
+#: lanes after those: the wide steps' row tiles and the ones computed
+TILE_STAT_NAMES = ("attn_row_tiles", "attn_row_tiles_live")
 #: the routed experts' work, lanes STAT_EXPERT_ROWS..: counters of their own
 MOE_STAT_NAMES = ("expert_rows", "experts_touched", "expert_rows_max")
 
@@ -508,7 +518,7 @@ class ServingTelemetry:
         for n in SPLIT_STAT_NAMES:
             self.counters[f"{n}_narrow"] = 0
             self.counters[f"{n}_wide"] = 0
-        for n in MOE_STAT_NAMES:
+        for n in TILE_STAT_NAMES + MOE_STAT_NAMES:
             self.counters[n] = 0
         # exclusive host time of each boundary phase (phase())
         for n in PHASES:
@@ -1239,7 +1249,8 @@ class ServingTelemetry:
             with jax.profiler.TraceAnnotation(
                     "serve/frame_work", width=width, steps=steps,
                     **{n: int(delta[i]) for i, n in
-                       enumerate(STAT_NAMES + SPLIT_STAT_NAMES)}, **moe,
+                       enumerate(STAT_NAMES + SPLIT_STAT_NAMES
+                                 + TILE_STAT_NAMES)}, **moe,
                     **layers):
                 pass
         split = "wide" if width > 1 else "narrow"
@@ -1247,6 +1258,8 @@ class ServingTelemetry:
             self.counters[f"{name}_{split}"] += int(delta[i])
         for name, value in layers.items():
             self.counters[f"{name}_{split}"] += value
+        for i, name in enumerate(TILE_STAT_NAMES, STAT_ROW_TILES):
+            self.counters[name] += int(delta[i])
         if kv_kinds is not None:
             # pages by kind, their bytes over the kinds, and the tokens the
             # table kind's pages hold

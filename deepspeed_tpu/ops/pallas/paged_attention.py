@@ -30,13 +30,16 @@ copies nothing and goes straight to the chunk's own keys, a window moves
   the context, so a step takes ALL LOCAL KV HEADS. Grid = (batch,); a page
   is copied once across heads — the whole ``(KVH, page, D)`` block
   ``kv_commit.py`` also moves — 4 pages a group.
-- from 128 rows a KV head (a prefill chunk): a head's rows fill the MXU and
-  its (R, K*bs) f32 score tile fills VMEM, so a step takes as many KV heads
-  as fit the budget beside a group of 8 pages — ONE at mistral's 512 rows,
-  four at OLMoE's 128 (a step there is bound by its count, and four heads
-  a step timed 41% under one) — on grid (batch, KV heads / heads a step);
-  where one head's tile of 8 pages does not fit (Mellum2's 1,024 rows) the
-  group is 4 pages. A head's page is one contiguous ``(page, D)`` block.
+- from 128 rows a KV head (a prefill chunk): a head's rows fill the MXU, so
+  they are cut into ROW TILES of 128 (``row_tile``) and a step computes,
+  against each group of pages and against the chunk's own keys, only the
+  tiles that hold a live row: a decoding row riding the wide step pays for
+  the one tile its G rows sit in, a prompt's last partial chunk for the
+  tiles it reaches, and a slot with no live row copies no page, reads no
+  block and writes zeros. A tile's (128, K*bs) f32 scores are what a step
+  holds beside its pages, so the group is 8 pages and a step takes as many
+  KV heads as fit the budget beside it, on grid (batch, KV heads / heads a
+  step). A head's page is one contiguous ``(page, D)`` block.
 
 The latent format (``vpool=None``; ``kv_cache.py``): ONE pool of one "head"
 whose row is the key of every query head and whose first ``value_lanes``
@@ -69,34 +72,47 @@ _FOLD_PAGES = 4         # pages a folded step groups: 8 timed 2-15% slower at
 # does not fit OLMoE's 16 (benchmarks/paged_decode_sweep.py, PERF.md PR 29)
 
 
-def _step_bytes(heads, rows, keys, d, itemsize, value_lanes=None):
+def _step_bytes(heads, rows, keys, d, itemsize, value_lanes=None, tile=None):
     """VMEM a step's tiles take with ``heads`` kv heads of ``rows`` query
-    rows against groups of ``keys`` keys. ``value_lanes``: the latent
-    format, whose values are lanes of the one buffered row."""
+    rows against groups of ``keys`` keys, the scores computed ``tile`` rows
+    a head at a time (every row at once where it is None). ``value_lanes``:
+    the latent format, whose values are lanes of the one buffered row."""
     dv, bufs = (d, 2 * d) if value_lanes is None else (value_lanes, d)
-    return (heads * rows * keys * (4 + 4 + 2)        # s, exp(s - m), its cast
+    return (heads * (tile or rows) * keys * (4 + 4 + 2)  # s, exp(s - m), its cast
             + 2 * heads * keys * bufs * itemsize     # K and V, double-buffered
             + heads * rows * (4 * dv + 2 * itemsize * (d + dv)))  # acc; q, out x 2
 
 
+def row_tile(rows):
+    """Rows of the tiles a step cuts a kv head's ``rows`` query rows into,
+    to compute only the tiles that hold a live row: ``_MXU_ROWS``, all of
+    them where that does not divide them, and None (not cut) while they
+    leave the MXU mostly empty."""
+    if rows < _MXU_ROWS:
+        return None
+    return _MXU_ROWS if rows % _MXU_ROWS == 0 else rows
+
+
 def _tiling(rows, kvh, mb, page_size, d, itemsize):
-    """(kv heads a step, pages a group). A step takes every local kv head
-    while a head's rows leave the MXU mostly empty — its cost is then the
-    context's bytes and the count of steps — and its tiles fit the budget;
-    else the widest group of pages whose score tile fits for one head, and
-    as many heads (a divisor of the local ones) as fit beside it."""
-    def fits(heads, pages):
-        return _step_bytes(heads, rows, pages * page_size, d,
-                           itemsize) <= _VMEM_BUDGET
+    """(kv heads a step, pages a group, rows a row tile). A step takes every
+    local kv head while a head's rows leave the MXU mostly empty — its cost
+    is then the context's bytes and the count of steps — and its tiles fit
+    the budget; else the widest group of pages whose score tile (a row
+    tile's, ``row_tile``) fits for one head, and as many heads (a divisor
+    of the local ones) as fit beside it."""
+    def fits(heads, pages, tile=None):
+        return _step_bytes(heads, rows, pages * page_size, d, itemsize,
+                           tile=tile) <= _VMEM_BUDGET
 
     if rows < _MXU_ROWS and fits(kvh, _FOLD_PAGES):
-        return kvh, min(_FOLD_PAGES, mb)
+        return kvh, min(_FOLD_PAGES, mb), None
+    tile = row_tile(rows)
     pages = _HEAD_PAGES
-    while pages > 1 and not fits(1, pages):
+    while pages > 1 and not fits(1, pages, tile):
         pages //= 2
     heads = max(h for h in range(1, kvh + 1)
-                if kvh % h == 0 and (h == 1 or fits(h, pages)))
-    return heads, min(pages, mb)
+                if kvh % h == 0 and (h == 1 or fits(h, pages, tile)))
+    return heads, min(pages, mb), tile
 
 
 def _latent_tiling(c, h, mb, page_size, d, value_lanes, itemsize):
@@ -181,14 +197,21 @@ def _live_pages_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,  # scalar prefe
                        o_ref,
                        kbuf, vbuf, sems, m_ref, l_ref, acc_ref,
                        *, page_size, pages_per_step, scale, softcap,
-                       use_alibi, ring=None, value_lanes=None, slot=None):
+                       use_alibi, ring=None, value_lanes=None, slot=None,
+                       live_ref=None, row_tile=None):
     """One slot a grid step, with every local kv head at once or, where the
     grid has a second axis, the kv heads that axis names: walk the slot's
     live pages [lo, cs) in groups of K, group g+1's pages on their way into
     the other half of (kbuf, vbuf) while group g computes. The latent format
     (``_latent_kernel``) has no ``v_hbm``, ``cv_ref`` or ``vbuf``: a value
     is the first ``value_lanes`` lanes of its key's row, and the grid's
-    slot index comes as ``slot`` (read outside the branch this runs in)."""
+    slot index comes as ``slot`` (read outside the branch this runs in).
+
+    A many-rows step (``_row_tiles_kernel``: ``live_ref`` (2, B), the
+    slot's live query rows [first, end), and ``row_tile``) computes, against
+    each group and against the chunk's own keys, only the row tiles that
+    hold a live row; the other rows of its output are zeros, and a slot
+    with no live row copies no page at all."""
     pools = ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1))
     if v_hbm is None:
         pools, vbuf = pools[:1], kbuf
@@ -202,6 +225,10 @@ def _live_pages_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,  # scalar prefe
     lo = lo_ref[b]
     first = lo // span
     end = (cs + span - 1) // span          # cs = 0 (a frozen slot): no group
+    if live_ref is not None:
+        tiles = (live_ref[0, b] // row_tile,
+                 (live_ref[1, b] + row_tile - 1) // row_tile)
+        end = jnp.where(tiles[0] < tiles[1], end, first)
 
     def page_copies(g, half, start):
         # a page is copied iff it holds a slot of [lo, cs): whole
@@ -241,14 +268,40 @@ def _live_pages_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,  # scalar prefe
     def _first():
         page_copies(first, 0, True)
 
-    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
+    if live_ref is None:
+        def on_live_rows(fn):
+            fn(q, pos, slope, m_ref, l_ref, acc_ref, None)
+
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+    else:
+        def on_live_rows(fn):
+            """``fn`` on the operands of each row tile that holds a live
+            row, the running softmax's as views of the scratch."""
+            def tile(t, carry):
+                rows = pl.ds(pl.multiple_of(t * row_tile, row_tile), row_tile)
+                fn(q_ref[0, :, rows], pos_ref[0, rows],
+                   slope_ref[:, rows] if use_alibi else None,
+                   m_ref.at[:, rows], l_ref.at[:, rows], acc_ref.at[:, rows],
+                   rows)
+                return carry
+
+            jax.lax.fori_loop(*tiles, tile, 0)
+
+        def fresh(q, pos, slope, m, l, acc, rows):
+            m[...] = jnp.full(m.shape, NEG_INF, m.dtype)
+            l[...] = jnp.zeros(l.shape, l.dtype)
+            acc[...] = jnp.zeros(acc.shape, acc.dtype)
+
+        o_ref[...] = jnp.zeros_like(o_ref)
+        on_live_rows(fresh)
 
     win = win_ref[0]
-    q = q_ref[0]                                          # (heads, R, D)
-    pos = pos_ref[0]                                      # (R, 1) int32
-    slope = slope_ref[...] if use_alibi else None         # (heads, R, 1)
+    if live_ref is None:
+        q = q_ref[0]                                      # (heads, R, D)
+        pos = pos_ref[0]                                  # (R, 1) int32
+        slope = slope_ref[...] if use_alibi else None     # (heads, R, 1)
 
     def group(g, carry):
         half = (g - first) % 2
@@ -258,24 +311,42 @@ def _live_pages_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref,  # scalar prefe
             page_copies(g + 1, 1 - half, True)
 
         page_copies(g, half, False)
-        slot = g * span + jax.lax.broadcasted_iota(
-            jnp.int32, (q.shape[1], span), 1)
-        s, mask = _scores(q, kbuf[half], slot, pos, win, slope, scale=scale,
-                          softcap=softcap)
-        mask = jnp.logical_and(mask, slot < cs)           # stale pool slots
-        v = vbuf[half]
-        _online_update(m_ref, l_ref, acc_ref, s, mask,
-                       v if value_lanes is None else v[..., :value_lanes])
+
+        def scored(q, pos, slope, m, l, acc, rows):
+            slot = g * span + jax.lax.broadcasted_iota(
+                jnp.int32, (q.shape[1], span), 1)
+            s, mask = _scores(q, kbuf[half], slot, pos, win, slope,
+                              scale=scale, softcap=softcap)
+            mask = jnp.logical_and(mask, slot < cs)       # stale pool slots
+            v = vbuf[half]
+            _online_update(m, l, acc, s, mask,
+                           v if value_lanes is None else v[..., :value_lanes])
+
+        on_live_rows(scored)
         return carry
 
     jax.lax.fori_loop(first, end, group, 0)
 
-    out = _chunk_and_finalize(
-        m_ref, l_ref, acc_ref, q, ck_ref[0],
-        cv_ref[0] if value_lanes is None else ck_ref[0][..., :value_lanes],
-        cpos_ref[0, 0].reshape(1, -1), pos, win, slope, scale=scale,
-        softcap=softcap)
-    o_ref[0] = out.astype(o_ref.dtype)
+    def finished(q, pos, slope, m, l, acc, rows):
+        out = _chunk_and_finalize(
+            m, l, acc, q, ck_ref[0],
+            cv_ref[0] if value_lanes is None else ck_ref[0][..., :value_lanes],
+            cpos_ref[0, 0].reshape(1, -1), pos, win, slope, scale=scale,
+            softcap=softcap)
+        if rows is None:
+            o_ref[0] = out.astype(o_ref.dtype)
+        else:
+            o_ref[0, :, rows] = out.astype(o_ref.dtype)
+
+    on_live_rows(finished)
+
+
+def _row_tiles_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref, live_ref,
+                      *refs, **kw):
+    """``_live_pages_kernel`` for a many-rows step by head, whose sixth
+    prefetched scalar is each slot's live query rows."""
+    _live_pages_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref, *refs,
+                       live_ref=live_ref, **kw)
 
 
 def _latent_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref, live_ref,
@@ -380,8 +451,10 @@ def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
         window = 0
     softcap = float(softcap or 0.0)
 
+    tile = None
     if not latent:
-        hs, K = _tiling(rows, kvh, mb, page_size, d, kpool.dtype.itemsize)
+        hs, K, tile = _tiling(rows, kvh, mb, page_size, d,
+                              kpool.dtype.itemsize)
     # a step takes ``hs`` kv heads: all of them on grid (slots,), or a
     # share on grid (slots, kv heads / hs)
     split = hs < kvh
@@ -426,20 +499,42 @@ def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
     else:
         slopes = jnp.zeros((kvh, rows, 1), jnp.float32)
 
+    scalars = (lyr, block_tables, chunk_start, lo, win_arr)
+    if latent or tile:
+        # the live chunk indices [first, end) of each slot (consecutive:
+        # ``kv_commit``'s contract too); by head, as query rows
+        n_live = jnp.sum(valid, axis=1, dtype=jnp.int32)
+        first = jnp.argmax(valid, axis=1).astype(jnp.int32)
+        live = jnp.stack([first, first + n_live])
+        scalars += (live if latent else live * group,)
+
     def head_of(idx):
         return idx[0] if split else 0
 
+    def read_at(bi, idx):
+        """(slot, head) of the blocks a step reads: its own, but for a step
+        by row tiles of a slot with no live row, which reads nothing and
+        names block (0, 0) so that a run of such steps fetches nothing."""
+        if not tile:
+            return bi, head_of(idx)
+        on = idx[-1][1, bi] > idx[-1][0, bi]
+        return jnp.where(on, bi, 0), jnp.where(on, head_of(idx), 0)
+
     def slot_map(bi, *idx):
+        return (*read_at(bi, idx), 0, 0)
+
+    def out_map(bi, *idx):
         return (bi, head_of(idx), 0, 0)
 
-    def row_map(bi, *_):
-        return (bi, 0, 0)
+    def row_map(bi, *idx):
+        return (read_at(bi, idx)[0], 0, 0)
 
     def row_map_4(bi, *_):
         return (bi, 0, 0, 0)
 
     def head_map(bi, *idx):
-        return (head_of(idx), 0, 0)
+        # the slopes' block never changes where no step reads it
+        return (read_at(bi, idx)[1] if use_alibi or not tile else 0, 0, 0)
 
     def tile_map(bi, *idx):
         return (bi, head_of(idx), 0)
@@ -447,17 +542,12 @@ def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
     # the latent format's every tile reads the chunk's one head of rows and
     # has its own positions
     chunk_map = row_map_4 if latent else slot_map
-    scalars = (lyr, block_tables, chunk_start, lo, win_arr)
-    if latent:
-        # the live chunk indices [first, end) of each slot (consecutive:
-        # ``kv_commit``'s contract too)
-        n_live = jnp.sum(valid, axis=1, dtype=jnp.int32)
-        first = jnp.argmax(valid, axis=1).astype(jnp.int32)
-        scalars += (jnp.stack([first, first + n_live]),)
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     chunk_spec = pl.BlockSpec((1, hs, c, d), chunk_map)
     buffer = pltpu.VMEM((2, hs, K * page_size, d), kpool.dtype)
     kernel, name, both = _live_pages_kernel, "paged_attn", [True, True]
+    if tile:
+        kernel = functools.partial(_row_tiles_kernel, row_tile=tile)
     if latent:
         kernel, name, both = functools.partial(
             _latent_kernel, tile_positions=c // kvh, tiles=kvh), \
@@ -485,7 +575,7 @@ def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
                 *pools(chunk_spec, chunk_spec),
                 pl.BlockSpec((1, 1, c), row_map),
             ],
-            out_specs=pl.BlockSpec((1, hs, rows, dv), slot_map),
+            out_specs=pl.BlockSpec((1, hs, rows, dv), out_map),
             scratch_shapes=[
                 *pools(buffer, buffer),
                 pltpu.SemaphoreType.DMA((2, 2)),

@@ -63,14 +63,14 @@ H, KVH, D, PAGE, WINDOW = 32, 8, 128, 128, 4096
 
 
 def _paged_step_shapes(sharding, h, kvh, chunk, table, b=16, layers=4,
-                       pages=416):
+                       pages=416, lanes=D):
     def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-    pool = sds((layers, kvh, pages, PAGE, D))
-    return (sds((b, chunk, h, D)), pool, pool, sds((b, table), jnp.int32),
-            sds((b, chunk), jnp.int32), sds((b, chunk, kvh, D)),
-            sds((b, chunk, kvh, D)), sds((), jnp.int32))
+    pool = sds((layers, kvh, pages, PAGE, lanes))
+    return (sds((b, chunk, h, lanes)), pool, pool, sds((b, table), jnp.int32),
+            sds((b, chunk), jnp.int32), sds((b, chunk, kvh, lanes)),
+            sds((b, chunk, kvh, lanes)), sds((), jnp.int32))
 
 
 def _paged_step(q, kpool, vpool, tables, positions, ck, cv, layer):
@@ -132,17 +132,20 @@ def test_wide_paged_attention_compiles_at_the_cells_shapes(one_chip, as_tpu,
     """A prefill chunk's kernel at the benchmark's shapes (C = 128: 512 rows
     a KV head in mistral, 128 in OLMoE, 1,024 in Mellum2 over its table of
     256 and its ring of 10): ONE Mosaic call a layer, named for the readers
-    of the trace, one KV head a grid step (four at 128 rows); the hand-made
-    page copies of those heads, the double buffer and the (R, K x 128) f32
-    score tile (8 pages a group, 4 at 1,024 rows) pass Mosaic's verifier
+    of the trace; the hand-made page copies of a step's heads, the double
+    buffer, the loop over the row tiles that hold a live row (dynamic
+    slices of 128 rows of the query, the positions and the running
+    softmax) and a tile's (128, K x 128) f32 scores pass Mosaic's verifier
     inside the VMEM budget ``_tiling`` reckons with, under the compiler's
     scoped default."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
     h, kvh, table, _, ring, _ = CELL_SHAPES[cell]
     rows = 128 * h // kvh
-    heads, pages = pa._tiling(rows, kvh, table, PAGE, D, 2)
-    assert (heads, pages) == {128: (4, 8), 512: (1, 8), 1024: (1, 4)}[rows]
-    assert pa._step_bytes(heads, rows, pages * PAGE, D, 2) <= pa._VMEM_BUDGET
+    heads, pages, tile = pa._tiling(rows, kvh, table, PAGE, D, 2)
+    assert (heads, pages, tile) == {128: (4, 8, 128), 512: (4, 8, 128),
+                                    1024: (2, 8, 128)}[rows]
+    assert pa._step_bytes(heads, rows, pages * PAGE, D, 2, tile=tile) \
+        <= pa._VMEM_BUDGET
     assert pa._VMEM_BUDGET < 16 << 20          # v5e's scoped default
     step, shapes = _cell_step(cell, 128, one_chip)
     text = _compile_text(step, *shapes)
@@ -186,6 +189,37 @@ def test_narrow_paged_attention_is_the_program_it_was(cell, chunk):
     jaxpr = jax.make_jaxpr(step)(*shapes)
     assert hashlib.sha256(str(jaxpr).encode()).hexdigest() == \
         NARROW_PAGED_JAXPRS[cell, chunk]
+
+
+# the same of the latent format's two programs at LongCat-Flash-Omni's
+# shape (64 query heads on the one head of a pool of 640-lane rows, values
+# the first 512, 8 attention layers, tables of 128), as PR 34 left them
+# (`git archive 162720a`)
+LATENT_PAGED_JAXPRS = {
+    1: "41488787d8ac541e277e73d9a3d37420d3725509ebb79b1adf41deabf3f4d742",
+    128: "f734a903f55f5e602792d03119b99d4c870fadad44eb0ce8abf77ca0d7ac1329",
+}
+
+
+@pytest.mark.parametrize("chunk", list(LATENT_PAGED_JAXPRS),
+                         ids=["narrow", "wide"])
+def test_latent_paged_attention_is_the_program_it_was(chunk):
+    """PR 38 cut the by-head wide step into row tiles inside the one page
+    walk, which the latent kernel calls with position tiles of its own: its
+    traced programs, narrow and wide, are PR 34's to the character."""
+    import hashlib
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_ragged_attention
+    q, pool, _, tables, positions, ck, _, layer = _paged_step_shapes(
+        None, 64, 1, chunk, 128, layers=8, pages=2049, lanes=640)
+
+    def step(q, pool, tables, positions, ck, layer):
+        return paged_ragged_attention(q, pool, None, tables, positions, ck,
+                                      None, layer=layer, value_lanes=512,
+                                      scale=192 ** -0.5)
+
+    jaxpr = jax.make_jaxpr(step)(q, pool, tables, positions, ck, layer)
+    assert hashlib.sha256(str(jaxpr).encode()).hexdigest() == \
+        LATENT_PAGED_JAXPRS[chunk]
 
 
 @pytest.mark.parametrize("chunk", [1, 3, 128],
@@ -597,7 +631,7 @@ def test_longcat_frame_programs_fit_the_chip(one_chip, as_tpu, width):
                jnp.bfloat16)
     key = jax.random.PRNGKey(0)
     runner = PagedModelRunner(model, PAGE, seq // PAGE)
-    assert runner.n_stats == 16 + 3 + 3 + 2
+    assert runner.n_stats == 18 + 3 + 3 + 2
     compiled = runner._build_frame_loop().lower(
         params, sds((slots, seq), i32), row, row, row,
         sds((slots,), jnp.float32), sds((slots, seq // PAGE), i32), row, row,

@@ -266,7 +266,7 @@ def test_narrow_steps_walk_live_pages(heads, c, chunk, feature):
         kvh = 1
         assert pa._latent_tiling(c, h, mb, bs, d, dv, 4)[0] == 1
     else:
-        assert pa._tiling(c * h // kvh, kvh, mb, bs, d, 4) == (kvh, 4)
+        assert pa._tiling(c * h // kvh, kvh, mb, bs, d, 4) == (kvh, 4, None)
     ctx = [0, 2 * bs, bs + 5, 5 * bs + 3, 7 * bs - c]
     b, nb = len(ctx), 1 + sum(-(-(x + c) // bs) for x in ctx)
     rng = np.random.default_rng(7)
@@ -332,25 +332,30 @@ def test_narrow_steps_walk_live_pages(heads, c, chunk, feature):
 
 
 def test_tiles_follow_from_shapes():
-    """(kv heads a step, pages a group) from static shapes against the one
-    VMEM budget: a decode or speculation step takes every local kv head, 4
-    pages a group; a prefill chunk's rows fill the MXU, so it takes the
-    widest group whose f32 score tile fits for one head and as many heads
-    as fit beside it: one head and 8 pages at mistral's 512 rows, four heads
-    at OLMoE's 128, one head and 4 pages at Mellum2's 1,024."""
-    from deepspeed_tpu.ops.pallas.paged_attention import _tiling
-    for kvh, group, heads in ((8, 4, 1), (16, 1, 4), (2, 4, 1)):
+    """(kv heads a step, pages a group, rows a row tile) from static shapes
+    against the one VMEM budget: a decode or speculation step takes every
+    local kv head, 4 pages a group, its rows not cut; a prefill chunk's rows
+    fill the MXU, so they are cut into tiles of 128 (one at OLMoE's 128
+    rows, four at mistral's 512, eight at Mellum2's 1,024) and a step takes
+    the widest group whose f32 score tile, a ROW TILE's, fits for one head
+    and as many heads as fit beside it: four heads and 8 pages at 128 and
+    at 512 rows, two heads and 8 pages at 1,024."""
+    from deepspeed_tpu.ops.pallas.paged_attention import _tiling, row_tile
+    assert [row_tile(r) for r in (4, 24, 128, 512, 1024)] == [
+        None, None, 128, 128, 128]
+    assert row_tile(192) == 192            # 128 does not divide it: one tile
+    for kvh, group, heads in ((8, 4, 4), (16, 1, 4), (2, 4, 2)):
         # mistral, OLMoE, mistral at tp=4
-        assert _tiling(128 * group, kvh, 64, 128, 128, 2) == (heads, 8)
+        assert _tiling(128 * group, kvh, 64, 128, 128, 2) == (heads, 8, 128)
         for c in (1, 3, 8):
-            assert _tiling(c * group, kvh, 64, 128, 128, 2) == (kvh, 4)
-    assert _tiling(128 * 8, 4, 256, 128, 128, 2) == (1, 4)    # Mellum2, full
-    assert _tiling(128 * 8, 4, 10, 128, 128, 2) == (1, 4)     # ... its ring
-    assert _tiling(4, 8, 2, 128, 128, 2) == (8, 2)        # a table of 2 pages
-    assert _tiling(512, 8, 2, 128, 128, 2) == (1, 2)
-    assert _tiling(512, 1, 64, 128, 128, 2) == (1, 8)     # MQA: grid (slots,)
-    assert _tiling(128, 3, 64, 128, 128, 2) == (3, 8)     # heads divide evenly
-    assert _tiling(128, 6, 64, 128, 128, 2) == (3, 8)
+            assert _tiling(c * group, kvh, 64, 128, 128, 2) == (kvh, 4, None)
+    assert _tiling(128 * 8, 4, 256, 128, 128, 2) == (2, 8, 128)  # Mellum2, full
+    assert _tiling(128 * 8, 4, 10, 128, 128, 2) == (2, 8, 128)   # ... its ring
+    assert _tiling(4, 8, 2, 128, 128, 2) == (8, 2, None)  # a table of 2 pages
+    assert _tiling(512, 8, 2, 128, 128, 2) == (4, 2, 128)
+    assert _tiling(512, 1, 64, 128, 128, 2) == (1, 8, 128)  # MQA: grid (slots,)
+    assert _tiling(128, 3, 64, 128, 128, 2) == (3, 8, 128)  # heads divide evenly
+    assert _tiling(128, 6, 64, 128, 128, 2) == (3, 8, 128)
 
 
 # ---- the wide path: live pages x the KV heads that fit a step -------------
@@ -390,6 +395,19 @@ _WIDE_LATENT = [(8, 0, 32, 2), (64, 0, 16, 8)]
 _WIDE_LATENT_FEATURES = ("plain", "window", "pool", "layer")
 
 
+def _write_pages(pools, lins, table, held, lyr=0):
+    """What a run wrote of one slot into numpy ``pools`` (layers, KVH, NB,
+    bs, D): the first ``held`` positions of its linear keys and values
+    ``lins`` (T, KVH, D), page after page through ``table`` (a ring wraps),
+    the last page's tail left stale."""
+    bs = pools[0].shape[3]
+    for page in range(-(-held // bs)):
+        rows = slice(page * bs, min((page + 1) * bs, held))
+        for pool, lin in zip(pools, lins):
+            pool[lyr, :, table[page % len(table)], :rows.stop - rows.start] = \
+                np.asarray(lin[rows]).transpose(1, 0, 2)
+
+
 def _wide_case(monkeypatch, h, kvh, c, heads, feature):
     """A prefill chunk of 128 rows a KV head, ``heads`` KV heads a grid step
     (the budget is set so that no more fit: at these sizes the real one
@@ -420,7 +438,8 @@ def _wide_case(monkeypatch, h, kvh, c, heads, feature):
     else:
         monkeypatch.setattr(pa, "_VMEM_BUDGET",
                             pa._step_bytes(heads, c * group, span, d, 4))
-        assert pa._tiling(c * group, kvh, mb, bs, d, 4) == (heads, min(8, mb))
+        assert pa._tiling(c * group, kvh, mb, bs, d, 4) == (
+            heads, min(8, mb), c * group)
     b, nb = len(ctx), 1 + len(ctx) * mb
     rng = np.random.default_rng(13)
 
@@ -443,12 +462,8 @@ def _wide_case(monkeypatch, h, kvh, c, heads, feature):
         live = 1 if s == 2 else c                         # slot 2 decodes
         positions[s, :live] = cs + np.arange(live)
         held = cs + live if feature == "pool" else cs     # what a run wrote
-        for page in range(-(-held // bs)):
-            rows = slice(page * bs, min((page + 1) * bs, held))
-            n = rows.stop - rows.start
-            for pool, lin in ((kpool, lin_k), (vpool, lin_v)):
-                pool[lyr, :, tables[s, page % mb], :n] = \
-                    np.asarray(lin[s, rows]).transpose(1, 0, 2)
+        _write_pages((kpool, vpool), (lin_k[s], lin_v[s]), tables[s], held,
+                     lyr)
     chunk = ()
     if feature != "pool":
         chunk = tuple(jnp.stack([lin[s, cs:cs + c] for s, cs in enumerate(ctx)])
@@ -498,33 +513,125 @@ def test_wide_steps_walk_live_pages(monkeypatch, heads, feature):
     _wide_case(monkeypatch, *heads, feature)
 
 
-class _Off:
-    """A scalar-prefetch ref that reads ``by`` off the truth."""
+# ---- row tiles: a many-rows step computes the tiles that hold a live row ---
 
-    def __init__(self, ref, by):
-        self.ref, self.by = ref, by
+# the serve cells' heads at C = 128: (query heads, kv heads, kv heads a
+# step, feature): mistral's 512 rows a kv head under its window, Mellum2's
+# 1,024 over whole tables and over its ring, OLMoE's 128 (one tile), four
+# heads a step
+_TILE_SHAPES = {"mistral": (32, 8, 1, "window"),
+                "mellum2-full": (32, 4, 1, "plain"),
+                "mellum2-ring": (32, 4, 1, "ring"),
+                "olmoe": (16, 16, 4, "plain")}
+# live positions of a slot's chunk: a frozen slot, a decoding row riding the
+# wide step, a prompt's last partial chunk, a full chunk
+_TILE_HEIGHTS = {"frozen": 0, "rider": 1, "partial": 37, "full": 128}
+
+
+def _tile_case(monkeypatch, shape, height):
+    """A wide step at a cell's heads (C = 128; head_dim 32, pages of 16 so
+    that interpret mode gets through it) whose slots hold ``height`` live
+    positions each, or every height in one batch (``mixed``), behind
+    contexts of 0, under a page, and several groups of pages: every live
+    row against attention over the LINEAR context, and every row of a tile
+    with no live row exactly zero."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    h, kvh, heads, feature = _TILE_SHAPES[shape]
+    c, d, bs, group = 128, 32, 16, h // kvh
+    rows, span = c * group, 8 * bs
+    tile = pa.row_tile(rows)
+    window = {"window": 2 * span + 2 * bs + 3, "ring": span + 2 * bs + 3}.get(
+        feature, 0)
+    ring = -(-(window + c) // bs) + 1 if feature == "ring" else None
+    far = 5 * ring * bs + 37 if ring else 3 * span + 7
+    if height == "mixed":
+        slots = [(0, 0), (1, bs - 5), (1, far), (37, 0), (37, far),
+                 (128, bs - 5), (128, far)]
+    else:
+        slots = [(_TILE_HEIGHTS[height], ctx) for ctx in (0, bs - 5, far)]
+    mb = ring or -(-(far + c) // bs)
+    monkeypatch.setattr(pa, "_VMEM_BUDGET",
+                        pa._step_bytes(heads, rows, span, d, 4, tile=tile))
+    assert pa._tiling(rows, kvh, mb, bs, d, 4) == (heads, 8, 128)
+    b, nb = len(slots), 1 + len(slots) * mb
+    rng = np.random.default_rng(17)
+
+    def rand(*shape, scale=1.0):
+        return jnp.asarray(rng.standard_normal(shape) * scale, jnp.float32)
+
+    total = far + c
+    lin_k, lin_v = rand(b, total, kvh, d), rand(b, total, kvh, d)
+    q = rand(b, c, h, d, scale=0.3)
+    kpool = np.array(rand(1, kvh, nb, bs, d))             # stale everywhere
+    vpool = np.array(rand(1, kvh, nb, bs, d))
+    tables = (1 + rng.permutation(nb - 1)[:b * mb]).reshape(b, mb)
+    positions = np.full((b, c), -1, np.int32)
+    for s, (w, cs) in enumerate(slots):
+        positions[s, :w] = cs + np.arange(w)
+        _write_pages((kpool, vpool), (lin_k[s], lin_v[s]), tables[s],
+                     cs if w else 0)                      # frozen: no context
+    ck, cv = (jnp.stack([lin[s, cs:cs + c] for s, (_, cs) in enumerate(slots)])
+              for lin in (lin_k, lin_v))
+    kw = {"window": window} if window else {}
+    out = np.asarray(pa.paged_ragged_attention(
+        q, jnp.asarray(kpool), jnp.asarray(vpool),
+        jnp.asarray(tables, jnp.int32), jnp.asarray(positions), ck, cv,
+        layer=0, **kw, **({"ring": ring} if ring else {})))
+    ref = np.asarray(_linear_reference(q, lin_k, lin_v, positions, **kw))
+    valid = positions >= 0
+    np.testing.assert_allclose(out[valid], ref[valid], rtol=3e-5, atol=3e-5)
+    for s, (w, _) in enumerate(slots):
+        computed = -(-w * group // tile) * tile // group  # chunk positions
+        assert not out[s, computed:].any(), (s, w)
+
+
+@pytest.mark.parametrize("height", [*_TILE_HEIGHTS, "mixed"])
+@pytest.mark.parametrize("shape", list(_TILE_SHAPES))
+def test_wide_steps_compute_their_live_row_tiles(monkeypatch, shape, height):
+    """A wide step's query rows are cut into tiles of 128 by chunk position
+    and a tile is computed only if it holds a live row (``_tile_case``):
+    none of a frozen slot's, the first of a decoding row's, two of four or
+    eight of a partial chunk's at G = 4 and 8, all of a full chunk's."""
+    _tile_case(monkeypatch, shape, height)
+
+
+class _Off:
+    """A scalar-prefetch ref that reads ``by`` off the truth, or off its
+    row ``row`` alone."""
+
+    def __init__(self, ref, by, row=None):
+        self.ref, self.by, self.row = ref, by, row
 
     def __getitem__(self, i):
+        if self.row is not None and i[0] != self.row:
+            return self.ref[i]
         return jnp.maximum(self.ref[i] + self.by, 0)
 
 
-@pytest.mark.parametrize("fault", ["last-page-skipped", "lo-a-page-late"])
+@pytest.mark.parametrize("fault", ["last-page-skipped", "lo-a-page-late",
+                                   "a-live-tile-skipped"])
 def test_wide_walk_comparison_sees_a_planted_fault(monkeypatch, fault):
-    """The comparison above is sharp enough: a walk that stops a page short
-    of ``cs``, or starts a page past ``lo``, fails it."""
+    """The comparisons above are sharp enough: a walk that stops a page
+    short of ``cs``, or starts a page past ``lo``, fails the first, and a
+    step that leaves out the last tile that holds a live row the second."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
     sound = pa._live_pages_kernel
 
     def faulty(lyr, bt, cs, lo, win, *rest, page_size, **kw):
         if fault == "last-page-skipped":
             cs = _Off(cs, -page_size)
-        else:
+        elif fault == "lo-a-page-late":
             lo = _Off(lo, page_size)
+        else:
+            kw["live_ref"] = _Off(kw["live_ref"], -kw["row_tile"], row=1)
         return sound(lyr, bt, cs, lo, win, *rest, page_size=page_size, **kw)
 
     monkeypatch.setattr(pa, "_live_pages_kernel", faulty)
     with pytest.raises(AssertionError, match="Not equal to tolerance"):
-        _wide_case(monkeypatch, 8, 2, 32, 1, "window")
+        if fault == "a-live-tile-skipped":
+            _tile_case(monkeypatch, "mistral", "partial")
+        else:
+            _wide_case(monkeypatch, 8, 2, 32, 1, "window")
 
 
 # ---- a ring of pages behind the window (caches by layer kind) -------------
@@ -549,7 +656,7 @@ def test_ring_of_pages_matches_the_linear_context(c, ctx):
     h, kvh, d, bs, ring, window, layers = 16, 2, 32, 16, 5, 40, 2
     assert ring * bs >= window + c + bs              # the ring's invariant
     assert pa._tiling(c * h // kvh, kvh, ring, bs, d, 4) == (
-        kvh, 4 if c < 16 else 5)     # at this size both heads fit a step
+        (kvh, 4, None) if c < 16 else (kvh, 5, 128))  # both heads fit a step
     start = {"shorter-than-the-ring": 23, "exactly-the-ring": ring * bs,
              "chunk-crosses-the-wrap": 2 * ring * bs - min(c, 5) + 1
              if c > 1 else 2 * ring * bs - 1,

@@ -22,7 +22,8 @@ import pytest
 from deepspeed_tpu.inference.v2.engine_v2 import (InferenceEngineV2,
                                                   RaggedInferenceEngineConfig)
 from deepspeed_tpu.inference.v2.ragged_manager import DeviceSlotTable
-from deepspeed_tpu.inference.v2.telemetry import (TTFT_COUNTERS,
+from deepspeed_tpu.inference.v2.telemetry import (TILE_STAT_NAMES,
+                                                  TTFT_COUNTERS,
                                                   LogBucketHistogram,
                                                   ServingTelemetry)
 from deepspeed_tpu.models import build_model
@@ -158,6 +159,42 @@ def test_counters_match_host_replay(served):
         assert d["serving/positions_computed"] \
             == d["serving/slot_steps_capacity"] * width, (step, d)
     assert last["serving/positions_computed"] == c["positions_computed"] > 0
+
+
+def test_row_tile_counters_match_host_replay():
+    """``attn_row_tiles`` / ``attn_row_tiles_live``: what the wide paged
+    kernel had to cut and what it computed, one layer's, replayed on the
+    host over a scripted serve whose wide frames hold a decoding row riding
+    the chunk, a full chunk, a prompt's last partial chunk and frozen
+    slots. 8 query heads on 2 kv heads and chunks of 64: 256 query rows a
+    kv head, two tiles of 128 rows = 32 positions."""
+    model = build_model("tiny", num_heads=8, num_kv_heads=2)
+    chunk, slots, steps, new = 64, 4, 4, 8
+    e = InferenceEngineV2(model, RaggedInferenceEngineConfig(
+        kv_block_size=16, prefill_chunk_size=chunk, max_tokens_per_step=256,
+        dtype="float32", max_ragged_batch_size=slots, frame_steps=steps),
+        params=model.init(jax.random.PRNGKey(0)), max_seq_len=256)
+    assert e.runner.row_heads == (2, 4)
+    rng = np.random.default_rng(9)
+    plens = {0: 10, 1: chunk + 37}
+    arrivals = [[(u, rng.integers(0, 200, (n,)).astype(np.int32))]
+                for u, n in plens.items()]
+    outs = dict(e.serve(iter(arrivals), max_new_tokens=new))
+    assert {len(v) for v in outs.values()} == {new}
+    # frame 1 (uid 0 alone): its 10 prompt tokens, then three decode steps
+    # riding the wide frame. Frame 2: uid 0 rides four more steps while
+    # uid 1 consumes a full chunk, its last 37 tokens, and rides two. Every
+    # later frame is narrow and cuts nothing.
+    wide = [[10, 1, 1, 1], [1, 1, 1, 1], [chunk, 37, 1, 1]]
+    kvh, group, tile = 2, 4, 128
+    live = sum(kvh * -(-w * group // tile) for row in wide for w in row)
+    c = e.telemetry.counters
+    assert c["attn_row_tiles"] == 2 * steps * slots * kvh * (chunk * group
+                                                             // tile)
+    assert c["attn_row_tiles_live"] == live == 2 * (4 + 4 + 6)
+    text = e.telemetry.render_prometheus()
+    assert f"ds_serving_attn_row_tiles_live_total {live}" in text
+    assert f"ds_serving_attn_row_tiles_total {c['attn_row_tiles']}" in text
 
 
 def test_eos_counted_in_graph(tiny_model_params, served):
@@ -510,8 +547,10 @@ def test_fifo_tuple_serve_matches_the_fifo_loop(tiny_model_params):
         r["tenant"] is None and r["priority"] is None and r["slo_ms"] is None
         for r in got["snapshot"]["requests"])
     # series younger than the golden tree: the TTFT stage counters, which
-    # stay 0 on an engine that no trace collector is attached to
-    younger = {f"ds_serving_{n}_total": "0" for n in TTFT_COUNTERS}
+    # stay 0 on an engine that no trace collector is attached to, and the
+    # wide steps' row tiles, of which a chunk of 16 x 1 rows has none
+    younger = {f"ds_serving_{n}_total": "0"
+               for n in TTFT_COUNTERS + TILE_STAT_NAMES}
     assert [s for s in got["series"] if s not in younger] == want["series"]
     assert younger.keys() <= set(got["series"])
     assert got["counters"] == {**want["counters"], **{
