@@ -322,7 +322,8 @@ class InferenceEngineV2:
             # latent attention: one row a token and attention layer
             from .model_implementations.archs import validate_latent_serving
             validate_latent_serving(c, cfg, draft=draft_model is not None)
-            self.kv = BlockedKVCache(cfg.attn_layers, 1, cfg.latent_lanes,
+            # the prediction module's layer keeps its rows behind the stack's
+            self.kv = BlockedKVCache(cfg.cache_layers, 1, cfg.latent_lanes,
                                      num_blocks=num_blocks, block_size=bs,
                                      dtype=cfg.act_dtype, latent=True)
         elif kinds is None:
@@ -347,6 +348,12 @@ class InferenceEngineV2:
         self.draft_params = None
         self.draft_runner = None
         self.draft_kv = None
+        # a model with a prediction module drafts for itself in serve()
+        # unless a draft model is attached or the caller says otherwise
+        self.self_draft = self.runner.has_mtp
+        if self.self_draft:
+            from .model_implementations.archs import validate_self_draft
+            validate_self_draft(c, cfg)
         self.telemetry = ServingTelemetry(enabled=c.telemetry,
                                           trace=c.telemetry_trace)
         # fault tolerance (faults.py): structured abnormal-retirement log,
@@ -1059,7 +1066,10 @@ class InferenceEngineV2:
         prompt widths) so the jit cache stays O(log).
 
         Speculative decoding (``speculate``; defaults to on when a draft is
-        attached): pure-decode frames run ``gamma`` draft proposals plus one
+        attached, or when the model has a prediction module of its own,
+        ``cfg.num_nextn_predict_layers``, which then drafts one token a
+        step into one more layer of the model's own cache: no draft model,
+        no second set of pools): pure-decode frames run ``gamma`` draft proposals plus one
         gamma+1-wide target verify per step, emitting 1 + accepted tokens
         per target forward. Acceptance, EOS, and rollback are in-graph; the
         host replay just reads the wider emit mask, so the frame-boundary
@@ -1120,12 +1130,23 @@ class InferenceEngineV2:
         c = self._config
         steps = frame_steps or c.frame_steps
         adaptive = c.adaptive_frame_steps and frame_steps is None
+        self_draft = self.self_draft and self.draft_model is None
         if speculate is None:
-            speculate = self.draft_model is not None
-        if speculate and self.draft_model is None:
+            speculate = self.draft_model is not None or self_draft
+        if speculate and self.draft_model is None and not self_draft:
             raise ValueError("speculate=True but no draft model is attached "
                              "(pass draft_model= at construction or call "
-                             "attach_draft())")
+                             "attach_draft()) and the model has no "
+                             "prediction module to draft with")
+        if speculate and self_draft:
+            # one draft a module, and the modules are what the model has
+            modules = self.model.cfg.num_nextn_predict_layers
+            if gamma not in (None, modules):
+                raise ValueError(
+                    f"gamma={gamma}: a model that drafts with its own "
+                    f"prediction modules drafts one token a module "
+                    f"({modules})")
+            gamma = modules
         gamma = int(gamma if gamma is not None else c.speculate_gamma)
         if speculate and gamma < 1:
             raise ValueError(f"speculate needs gamma >= 1, got {gamma}")
@@ -1145,7 +1166,9 @@ class InferenceEngineV2:
             table_width=1, rng=frame_rng, tp=self.tp_ctx,
             debug_replicas=c.tp_debug_replica_check,
             n_stats=self.runner.n_stats,
-            rings=[ring for _, ring in self.state.rings])
+            rings=[ring for _, ring in self.state.rings],
+            hidden=(self.model.cfg.hidden_size, self.model.cfg.act_dtype)
+            if speculate and self_draft else None)
         if faults is not None:
             faults.begin_serve()     # rearm the scripted schedule
         if self.prefix_cache is not None:
@@ -1180,7 +1203,8 @@ class InferenceEngineV2:
                                    kv_block_bytes=self.kv.block_bytes,
                                    layered=self.runner.kinds is not None,
                                    latent=bool(self.model.cfg.latent_lanes),
-                                   share=self.model.cfg.moe_is_share)
+                                   share=self.model.cfg.moe_is_share,
+                                   mtp=self.runner.has_mtp)
         sched = FifoPolicy() if scheduler is None else scheduler
         sched.begin_serve(self)
         return self._serve_guarded(slots, arrivals, sched, steps,
@@ -2420,7 +2444,9 @@ class InferenceEngineV2:
                 cur_steps = min(cur_steps, sched.frame_steps_cap(steps))
                 tel.on_frame_plan(ewma, saturated, cur_steps)
                 draft = None
-                if speculate:
+                if speculate and self.draft_model is None:
+                    draft = "self"      # the model's own prediction module
+                elif speculate:
                     draft = (self.draft_runner, self.draft_params, self.draft_kv,
                              gamma)
                 if faults is not None:

@@ -1447,7 +1447,9 @@ def validate_latent_serving(engine_config, cfg: TransformerConfig,
         probs.append("the swap tier / prefill-decode handoff (kv_swap_dir, "
                      "role): a record holds a K and a V payload")
     if draft:
-        probs.append("a draft model (the speculative loops carry four pools)")
+        probs.append("a draft model (the speculative loops carry four pools; "
+                     "a model's own prediction module drafts into its own "
+                     "pool: validate_self_draft)")
     if cfg.moe_is_share and cfg.moe_impl != "grouped":
         probs.append(f"moe_impl={cfg.moe_impl!r} with a router wider than "
                      "the experts held (only the dropless path knows which "
@@ -1455,6 +1457,29 @@ def validate_latent_serving(engine_config, cfg: TransformerConfig,
     if probs:
         raise NotImplementedError(
             "a model with a latent cache cannot be served with: "
+            + "; ".join(probs))
+
+
+def validate_self_draft(engine_config, cfg: TransformerConfig) -> None:
+    """Fail LOUDLY at engine build for a model whose prediction modules
+    (``cfg.num_nextn_predict_layers``) the serving loops cannot draft
+    with: they run ONE module (gamma 1), whose layer keeps its rows in one
+    more layer of a latent pool."""
+    probs = []
+    if cfg.num_nextn_predict_layers != 1:
+        probs.append(f"num_nextn_predict_layers="
+                     f"{cfg.num_nextn_predict_layers} (one module drafts "
+                     "one token a step; a chain of them is not written)")
+    if not cfg.latent_lanes or cfg.shortcut_moe:
+        probs.append("a cache other than one pool of latent rows a plain "
+                     "layer (the module's layer is the stack's last kind "
+                     "and shares its pool)")
+    if engine_config.prefill_chunk_size < 2:
+        probs.append("prefill_chunk_size < 2 (width-1 frames are the "
+                     "draft / verify frames)")
+    if probs:
+        raise NotImplementedError(
+            "this model's prediction module cannot draft for it with: "
             + "; ".join(probs))
 
 

@@ -32,8 +32,8 @@ from ...ops.attention import decode_attention
 from ..sampling import sample_logits_per_row, speculative_verify_per_row
 from .kv_cache import dequantize_kv_lanes, quantize_kv_lanes
 from .telemetry import (LATENT_STAT_NAMES, LAYER_STAT_NAMES,   # in-graph
-                        MAX_RUNGS, MOE_STAT_NAMES, n_stats,    # counter
-                        pack_ladder)                           # layout
+                        MAX_RUNGS, MOE_STAT_NAMES,             # counter
+                        MTP_STAT_NAMES, n_stats, pack_ladder)  # layout
 
 
 def _use_pallas_paged() -> bool:
@@ -145,13 +145,21 @@ class PagedModelRunner:
         return self.cfg.is_moe and self.cfg.moe_impl == "grouped"
 
     @property
+    def has_mtp(self) -> bool:
+        """The model has a prediction module (``params["mtp"]``): it can
+        draft for itself, and its stat vector ends with the module's lanes
+        (``telemetry.MTP_STAT_NAMES``)."""
+        return bool(self.cfg.num_nextn_predict_layers)
+
+    @property
     def n_stats(self) -> int:
         """Lanes of the stat vector this model's serving loops carry: a
         model with routed experts counts their work in lanes of its own
         (``telemetry.n_stats``)."""
         return n_stats(self.cfg.is_moe, self.kinds is not None,
                        share=self.cfg.moe_is_share,
-                       latent=bool(self.cfg.latent_lanes))
+                       latent=bool(self.cfg.latent_lanes),
+                       mtp=self.has_mtp)
 
     def set_tp(self, tp_ctx) -> None:
         """Bind a ``tp.TPContext`` (engine setup, before any serving loop
@@ -171,7 +179,8 @@ class PagedModelRunner:
         return run
 
     def _forward(self, params, ids, positions, block_tables, valid_counts,
-                 kpool, vpool, *, all_logits=False, tp=None, moe_work=False):
+                 kpool, vpool, *, all_logits=False, tp=None, moe_work=False,
+                 hidden=False, mtp=None):
         """ids/positions: (B, C); block_tables: (B, MB);
         valid_counts: (B,) number of real (non-pad) tokens in the chunk;
         kpool/vpool: (L, KVH, NB, bs, D). Returns (last_logits (B, V),
@@ -194,7 +203,19 @@ class PagedModelRunner:
         A model of mixed cache kinds (``self.kinds``) takes ``block_tables``,
         ``kpool`` and ``vpool`` as tuples, one a kind, and gives the pools
         back so. A model with latent attention (``cfg.latent_lanes``) takes
-        its one pool of rows as ``kpool`` and None as ``vpool``."""
+        its one pool of rows as ``kpool`` and None as ``vpool``.
+
+        ``hidden``: one value more, last, the stack's hidden state before
+        the final norm, (B, C, E): what the model's prediction module reads.
+        ``mtp`` = (hidden states (B, C, E), rows only): the forward of the
+        PREDICTION MODULE (``params["mtp"]``, the first) in place of the
+        stack's: ``ids`` at ``positions`` are the tokens AFTER the hidden
+        states' positions; the module's layer reads cache layer
+        ``cfg.attn_layers`` of the same pool through the same tables and
+        commits nothing. Returns (logits, rows (1, B, C, 1, lanes) for the
+        caller to commit, the module's experts' work), or the rows alone
+        (``rows only``: the module's cache at positions nobody drafts
+        from: no attention, no experts, no head)."""
         cfg = self.cfg
         bs = self.block_size
         kinds = self.kinds
@@ -243,6 +264,25 @@ class PagedModelRunner:
 
         with jax.named_scope("embed"):
             h = _on_live(pack, embed, ids, positions)
+        if mtp is not None:
+            module = params["mtp"]
+            # the first module's norms (their leaves are small; its layer
+            # and projection are read in place)
+            enorm, hnorm, module_norm = (
+                jax.tree.map(lambda a: a[0], module[n])
+                for n in ("enorm", "hnorm", "norm"))
+
+            def join(e, hid):
+                """``eh_proj`` over the next token's embedding and the
+                stack's hidden state, each under its own norm."""
+                both = jnp.concatenate(
+                    [L.apply_norm(enorm, e, cfg),
+                     L.apply_norm(hnorm, hid.astype(dt), cfg)], axis=-1)
+                return jnp.einsum("bsf,fe->bse", both,
+                                  L.dq(module["eh_proj"], dt)[0])
+
+            with jax.named_scope("mtp_proj"):
+                h = _on_live(pack, join, h, mtp[0])
         inv_freq = model._inv_freq
         rope_layers = model._rope_layers   # RoPE that differs by layer
         # positions < 0 mark padding
@@ -309,7 +349,16 @@ class PagedModelRunner:
                                  interleaved=cfg.rope_interleaved)
             return q, k, v
 
+        def latent_qkv(lp, h, pos):
+            """A latent layer's absorbed query and its cached row."""
+            lp = at(lp)
+            return L.mla_query_and_row(
+                lp["attn"], L.apply_norm(lp["norm1"], h, cfg), pos, cfg,
+                inv_freq)
+
         def attn_out(lp, out):
+            if cfg.latent_lanes:
+                return L.mla_output(at(lp)["attn"], out, cfg)
             # row-parallel output projection: under tp the per-shard product
             # covers only the local heads — all-reduce BEFORE the replicated
             # bias, so the bias is added exactly once
@@ -442,8 +491,13 @@ class PagedModelRunner:
                 from ...compression.compress import fake_quantize_activation
                 h = fake_quantize_activation(h, cfg.act_quant_bits)
             with jax.named_scope("attn_qkv"):
-                q, k, v = _on_live(pack, functools.partial(qkv, lp, l), h,
-                                   pos_safe)
+                if cfg.latent_lanes:
+                    (q, k), v = _on_live(
+                        pack, functools.partial(latent_qkv, lp), h,
+                        pos_safe), None
+                else:
+                    q, k, v = _on_live(pack, functools.partial(qkv, lp, l),
+                                       h, pos_safe)
             # the pools are LOOP-INVARIANT inside the layer scan: this
             # layer's chunk KV rides into the attention as separate blocks
             # and comes back out as scan ys; one commit after the walk
@@ -468,7 +522,8 @@ class PagedModelRunner:
                 if quantized_kv:
                     return h, (quantize_kv_lanes(k), quantize_kv_lanes(v),
                                *work)
-                return h, (k.astype(kp.dtype), v.astype(vp.dtype), *work)
+                return h, (k.astype(kp.dtype),
+                           None if v is None else v.astype(vp.dtype), *work)
 
         def sub(lp, j):
             """Attention, dense MLP and norms ``j`` of a double layer: one
@@ -479,7 +534,7 @@ class PagedModelRunner:
             return {n: jax.tree.map(lambda a: a[at_j], stack[n])
                     for n in ("attn", "mlp", "norm1", "norm2")}
 
-        def latent_qkv(lp, j, h, pos):
+        def half_qkv(lp, j, h, pos):
             p = sub(lp, j)
             return L.mla_query_and_row(
                 p["attn"], L.apply_norm(p["norm1"], h, cfg), pos, cfg,
@@ -509,7 +564,7 @@ class PagedModelRunner:
             for j in (0, 1):
                 with jax.named_scope("attn_qkv"):
                     q, row = _on_live(
-                        pack, functools.partial(latent_qkv, lp, j), h,
+                        pack, functools.partial(half_qkv, lp, j), h,
                         pos_safe)
                 with jax.named_scope("paged_attn"):
                     out = attend(q, row, None, kpool, None, block_tables,
@@ -528,6 +583,27 @@ class PagedModelRunner:
 
         if cfg.shortcut_moe:
             layer = double_layer
+        if mtp is not None:
+            # one layer of the stack's last kind over cache layer
+            # ``attn_layers``; its row goes back to the caller
+            lp = (module["layer"], 0)
+            if mtp[1]:
+                def row_of(h, pos):
+                    p = at(lp)
+                    return L.mla_row(p["attn"],
+                                     L.apply_norm(p["norm1"], h, cfg), pos,
+                                     cfg, inv_freq)
+                with jax.named_scope("attn_qkv"):
+                    row = _on_live(pack, row_of, h, pos_safe)
+                return row.astype(kpool.dtype)[None]
+            h, (row, _, *work) = layer(
+                h, (lp, cfg.attn_layers, None),
+                tag=cfg.layer_type(cfg.num_layers - 1))
+            with jax.named_scope("lm_head"):
+                h = L.apply_norm(module_norm, h, cfg)
+                logits = self._head(params, h, valid_counts, all_logits,
+                                    tp=tp)
+            return logits, row[None], work[0] if work else None
         if kinds is None:
             h, kpool, vpool, work = self._run_layers(
                 layer, h, params, kpool, vpool, windows,
@@ -539,9 +615,11 @@ class PagedModelRunner:
                 layer, h, params, kpool, vpool, block_tables,
                 functools.partial(commit, positions=positions))
         with jax.named_scope("lm_head"):
+            stack_h = h
             h = L.apply_norm(params["final_norm"], h, cfg)
             logits = self._head(params, h, valid_counts, all_logits, tp=tp)
-        return (logits, kpool, vpool) + ((work,) if moe_work else ())
+        return (logits, kpool, vpool) + ((work,) if moe_work else ()) \
+            + ((stack_h,) if hidden else ())
 
     def _run_layers(self, layer, h, params, kpool, vpool, windows, commit,
                     stacked=False):
@@ -781,7 +859,8 @@ class PagedModelRunner:
                                               ladder=self.pack_ladder,
                                               layers=self.layer_work,
                                               latent=self.latent_layers,
-                                              heads=self.row_heads)
+                                              heads=self.row_heads,
+                                              mtp=self.has_mtp)
 
                 zero = jnp.zeros((b,), jnp.int32)
                 no = jnp.zeros((b,), bool)
@@ -819,12 +898,13 @@ class PagedModelRunner:
 
         @functools.partial(jax.jit,
                            donate_argnums=(7, 8, 9, 10, 11, 12, 13, 14, 15,
-                                           16),
+                                           16, 17),
                            static_argnames=("width", "steps", "greedy",
                                             "repair"))
         def loop(params, prompts, prompt_lens, limits, eos_ids, temps, tables,
                  cached, produced, last_tok, done, poison, nonfinite, stats,
-                 rng, kpool, vpool, width, steps, greedy, repair=False):
+                 rng, kpool, vpool, hidden=None, *, width, steps, greedy,
+                 repair=False):
             """One K-step serving FRAME: the resumable generalization of
             ``mixed_loop``. All per-slot state is carry-IN/carry-OUT, so the
             host only touches the loop at frame boundaries (admit arrivals,
@@ -849,6 +929,15 @@ class PagedModelRunner:
             finite-check latch (``faults.py``): both ride the donated
             carry, so arming a fault or detecting a NaN never retraces.
 
+            ``hidden`` (B, E), given: the model drafts for itself with its
+            prediction module (``cfg.num_nextn_predict_layers``). It is the
+            stack's hidden state at position ``cached - 1`` and rides the
+            carry behind ``last_tok`` (returned there too); a width-1 frame
+            drafts one token a row with the module and verifies two
+            positions with the stack, and emissions are (steps, B, 2)
+            (``_self_spec_scan_body``). The module's cache is one more layer
+            of the same pool: no argument more.
+
             Tensor-parallel (``self.tp`` set): the same program compiles
             under shard_map on the 1-D tp mesh — params and KV pools
             sharded, every slot-state carry replicated, ``stats`` among
@@ -857,17 +946,20 @@ class PagedModelRunner:
             """
             def core(params, prompts, prompt_lens, limits, eos_ids, temps,
                      tables, cached, produced, last_tok, done, poison,
-                     nonfinite, stats, rng, kpool, vpool):
+                     nonfinite, stats, rng, kpool, vpool, *hidden):
                 body = _serving_scan_body(fwd, params, prompts, prompt_lens,
                                           limits, eos_ids, temps, tables,
-                                          width, greedy, repair=repair,
+                                          width, greedy,
+                                          draft="self" if hidden else None,
+                                          repair=repair,
                                           window=self.stat_window,
                                           ladder=self.pack_ladder,
                                           layers=self.layer_work,
                                           latent=self.latent_layers,
-                                          heads=self.row_heads)
-                carry = (cached, produced, last_tok, done, poison, nonfinite,
-                         stats, rng, kpool, vpool)
+                                          heads=self.row_heads,
+                                          mtp=self.has_mtp)
+                carry = (cached, produced, last_tok, *hidden, done, poison,
+                         nonfinite, stats, rng, kpool, vpool)
                 carry, (toks, emit) = jax.lax.scan(body, carry, None,
                                                    length=steps)
                 return (toks, emit) + carry
@@ -876,7 +968,8 @@ class PagedModelRunner:
                     tables, cached, produced, last_tok, done, poison,
                     nonfinite, stats, rng, kpool, vpool)
             if tp is None:
-                return core(*args)
+                return core(*args, *(() if hidden is None else (hidden,)))
+            assert hidden is None, "a self-draft is not served under tp"
             rep, kv = P(), tp.kv_spec
             return self._tp_call(
                 core, args,
@@ -928,7 +1021,8 @@ class PagedModelRunner:
                     temps, tables, width, greedy,
                     draft=(draft_fwd, draft_params, gamma), repair=repair,
                     window=self.stat_window,
-                    ladder=self.pack_ladder, heads=self.row_heads)
+                    ladder=self.pack_ladder, layers=self.layer_work,
+                    latent=self.latent_layers, heads=self.row_heads)
                 carry = (cached, produced, last_tok, penult, done, poison,
                          nonfinite, stats, rng, kpool, vpool, dkpool, dvpool)
                 carry, (toks, emit) = jax.lax.scan(body, carry, None,
@@ -988,6 +1082,8 @@ class PagedModelRunner:
                                                      gamma),
                                               window=self.stat_window,
                                               ladder=self.pack_ladder,
+                                              layers=self.layer_work,
+                                              latent=self.latent_layers,
                                               heads=self.row_heads)
 
                 zero = jnp.zeros((b,), jnp.int32)
@@ -1066,11 +1162,12 @@ class PagedModelRunner:
 
 
 def commit_scatter(kpool, vpool, chunk_k, chunk_v, block_tables, positions,
-                   ring=None):
+                   ring=None, layer0=0):
     """The chunk's (L, B, C, KVH, D) KV into the (L, KVH, NB, bs, D) pools
     at ``positions`` (B, C) through the rows' block tables, as one XLA
     scatter a pool; a pad (``positions < 0``) goes to trash page 0.
-    ``ring``: the (B, ring) tables are rings (``kv_commit``'s)."""
+    ``ring``: the (B, ring) tables are rings (``kv_commit``'s); ``layer0``:
+    a chunk of fewer layers than the pools lands from that layer on."""
     bs = kpool.shape[3]
     is_pad = positions < 0
     pos_safe = jnp.maximum(positions, 0)
@@ -1082,9 +1179,11 @@ def commit_scatter(kpool, vpool, chunk_k, chunk_v, block_tables, positions,
     off = pos_safe % bs
     # the advanced (B, C) indices are contiguous, so the indexed window is
     # (L, KVH, B, C, D); the latent format has one pool
-    return (kpool.at[:, :, blk, off].set(chunk_k.transpose(0, 3, 1, 2, 4)),
+    lyr = slice(None) if chunk_k.shape[0] == kpool.shape[0] \
+        else slice(layer0, layer0 + chunk_k.shape[0])
+    return (kpool.at[lyr, :, blk, off].set(chunk_k.transpose(0, 3, 1, 2, 4)),
             None if vpool is None else
-            vpool.at[:, :, blk, off].set(chunk_v.transpose(0, 3, 1, 2, 4)))
+            vpool.at[lyr, :, blk, off].set(chunk_v.transpose(0, 3, 1, 2, 4)))
 
 
 def _rung_of(positions, ladder):
@@ -1161,7 +1260,7 @@ def _on_live(pack, fn, *xs, live=None):
 def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                        temps, tables, width, greedy, draft=None,
                        repair=False, window=None, ladder=pack_ladder,
-                       layers=None, latent=None, heads=None):
+                       layers=None, latent=None, heads=None, mtp=False):
     """Shared scan-step for ``mixed_loop`` and ``frame_loop`` — the in-graph
     SplitFuse scheduling arithmetic lives in exactly one place.
 
@@ -1194,6 +1293,12 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
     while rejected target/draft KV entries simply sit beyond the watermark
     until the next step's writes overwrite them.
 
+    ``draft="self"``: the model's own prediction module drafts: no second
+    model and no pools of its own. The carry grows ``hidden`` (B, E) behind
+    ``last_tok`` and emissions are (B, 2). A wide step is THIS body with the
+    module's rows written behind the forward (``_mtp_calls``) and ``hidden``
+    following ``cached``; a width-1 step is ``_self_spec_scan_body``.
+
     ``repair=True`` (``nonfinite_policy="repair"``): a row whose logits go
     non-finite is not frozen — every carry field selects back to its
     PRE-STEP value (the step simply never happened for that row; the KV it
@@ -1209,16 +1314,27 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
     attention): the step counts the latent rows its attention layers read
     and the pairs they score (``LATENT_STAT_NAMES``). ``heads``
     (``PagedModelRunner.row_heads``): a wide step counts its row tiles
-    (``_row_tile_work``)."""
-    if draft is not None:
+    (``_row_tile_work``). ``mtp``: the model has a prediction module, so
+    its vector has that module's lanes, which stay 0 while it does not
+    draft."""
+    self_draft = draft == "self"
+    if self_draft and width == 1:
+        return _self_spec_scan_body(fwd, params, prompts, prompt_lens,
+                                    limits, eos_ids, temps, tables, greedy,
+                                    repair=repair, window=window,
+                                    ladder=ladder, latent=latent)
+    if draft is not None and not self_draft:
         return _spec_scan_body(fwd, params, prompts, prompt_lens, limits,
                                eos_ids, temps, tables, width, greedy, *draft,
                                repair=repair, window=window, ladder=ladder,
-                               heads=heads)
+                               layers=layers, latent=latent, heads=heads)
+    if self_draft:
+        module, commit_rows = _mtp_calls(fwd, params, tables, latent)
 
     def body(carry, _):
-        (cached, produced, last_tok, done, poison, nonfinite, stats, rng,
-         kpool, vpool) = carry
+        # ``hidden``: one field under a self-draft, none otherwise
+        (cached, produced, last_tok, *hidden, done, poison, nonfinite, stats,
+         rng, kpool, vpool) = carry
         prev_last, prev_done = last_tok, done
         with jax.named_scope("frame_plan"):
             prefilling, active, w, ids, positions = _wide_plan(
@@ -1233,8 +1349,19 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
             if latent:
                 layer_work = latent * jnp.stack(
                     [jnp.sum(kv_read), jnp.sum(attn_pairs)]).astype(jnp.int32)
-        logits, kpool, vpool, moe_work = fwd(params, ids, positions, tables,
-                                             w, kpool, vpool, moe_work=True)
+        logits, kpool, vpool, moe_work, *h_all = fwd(
+            params, ids, positions, tables, w, kpool, vpool, moe_work=True,
+            **({"hidden": True} if self_draft else {}))
+        if self_draft:
+            # the module's cache keeps up: its row at p - 1 is made of
+            # (h[p - 1], the token at p), no attention, experts or head;
+            # position -1 (a first chunk) is dead
+            behind = jnp.concatenate(
+                [hidden[0][:, None].astype(h_all[0].dtype),
+                 h_all[0][:, :-1]], axis=1)
+            pos_m = jnp.where(positions > 0, positions - 1, -1)
+            kpool = commit_rows(
+                kpool, module(ids, pos_m, behind, w, kpool, True), pos_m)
         with jax.named_scope("sample"):
             logits = _inject_poison(logits, poison)
             if greedy:
@@ -1254,18 +1381,33 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                 last_tok = jnp.where(bad, prev_last, last_tok)
                 done = jnp.where(bad, prev_done, done)
                 w = jnp.where(bad, 0, w)
+            if self_draft:
+                # the stack's hidden state at the last position computed
+                hidden = [jnp.where(
+                    (w > 0)[:, None],
+                    jnp.take_along_axis(
+                        h_all[0], jnp.maximum(w - 1, 0)[:, None, None],
+                        axis=1)[:, 0].astype(hidden[0].dtype), hidden[0])]
             stats = stats + _stat_delta(
                 positions, ladder(*positions.shape),
                 emitted=emit, active=active,
                 prefill_toks=jnp.where(prefilling, w, 0),
                 eos=emit & (nxt == eos_ids),
-                target_fwd=active & ~prefilling,
+                # under any draft the verify forwards alone are counted
+                # (``_spec_scan_body``): a row decoding in a wide frame of a
+                # self-drafting model is plain decode
+                target_fwd=None if self_draft else active & ~prefilling,
                 kv_read=kv_read, attn_pairs=attn_pairs, row_tiles=row_tiles,
-                moe_work=moe_work, layer_work=layer_work)
-        return ((cached + w, produced + emit.astype(jnp.int32),
-                 last_tok, done, poison, nonfinite, stats, rng, kpool,
-                 vpool),
-                (jnp.where(emit, nxt, -1), emit))
+                moe_work=moe_work, layer_work=layer_work,
+                mtp_work=jnp.zeros((len(MTP_STAT_NAMES),), jnp.int32)
+                if mtp else None)
+        carry = (cached + w, produced + emit.astype(jnp.int32), last_tok,
+                 *hidden, done, poison, nonfinite, stats, rng, kpool, vpool)
+        out = (jnp.where(emit, nxt, -1), emit)
+        if self_draft:        # a narrow step's two columns; the second empty
+            out = (jnp.stack([out[0], jnp.full_like(nxt, -1)], axis=1),
+                   jnp.stack([emit, jnp.zeros_like(emit)], axis=1))
+        return carry, out
 
     return body
 
@@ -1347,7 +1489,7 @@ def _attn_work_by_layer(cached, w, layer_windows):
 def _stat_delta(positions, ladder, emitted=None, active=None,
                 prefill_toks=None, eos=None, target_fwd=None, drafted=None,
                 accepted=None, kv_read=None, attn_pairs=None, row_tiles=None,
-                moe_work=None, layer_work=None):
+                moe_work=None, layer_work=None, mtp_work=None):
     """One step's (N_STATS,) in-graph counter increment. Each keyword is a
     bool mask / int array to sum, or None for zero — the layout is pinned by
     the STAT_* indices in ``telemetry.py`` and the host-mirror replay tests
@@ -1361,7 +1503,8 @@ def _stat_delta(positions, ladder, emitted=None, active=None,
     router is wider than the experts held), where the model has any
     (``telemetry.n_stats``), and last ``layer_work``: ``LAYER_STAT_NAMES``
     where the model mixes cache kinds, ``LATENT_STAT_NAMES`` where its
-    attention is latent."""
+    attention is latent; behind those ``mtp_work``, the prediction
+    module's own (``MTP_STAT_NAMES``), where the model drafts for itself."""
     vals = [emitted, active, prefill_toks, eos, target_fwd, drafted, accepted,
             kv_read, attn_pairs, *(row_tiles or (None, None))]
     z = jnp.zeros((), jnp.int32)
@@ -1373,7 +1516,8 @@ def _stat_delta(positions, ladder, emitted=None, active=None,
         * int(len(ladder) > 1)
     out = jnp.concatenate([jnp.stack(out), steps]
                           + ([] if moe_work is None else [moe_work])
-                          + ([] if layer_work is None else [layer_work]))
+                          + ([] if layer_work is None else [layer_work])
+                          + ([] if mtp_work is None else [mtp_work]))
     assert layer_work is None or layer_work.shape[0] in (
         len(LAYER_STAT_NAMES), len(LATENT_STAT_NAMES))
     return out
@@ -1420,9 +1564,9 @@ def _wide_emit(active, prefilling, cached, w, prompt_lens, eos_ids, nxt,
 def _spec_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                     temps, tables, width, greedy, draft_fwd, draft_params,
                     gamma, repair=False, window=None, ladder=pack_ladder,
-                    heads=None):
+                    layers=None, latent=None, heads=None):
     """Speculative variant of the serving scan step (see
-    ``_serving_scan_body``). Carry: (cached, produced, last_tok, penult,
+    ``_serving_scan_body``, also for ``layers`` and ``latent``). Carry: (cached, produced, last_tok, penult,
     done, poison, nonfinite, stats, rng, kpool, vpool, dkpool, dvpool);
     emissions are (B, gamma+1). The finite-check watches the TARGET's
     verify logits (a draft gone non-finite only garbles proposals, which
@@ -1442,6 +1586,14 @@ def _spec_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
     k_out = gamma + 1
     koffs = jnp.arange(k_out)
 
+    def tail_work(cached, w, kv_read, attn_pairs):
+        """The target's attention work by layer, for the vector's last
+        lanes, as the plain step counts it."""
+        if latent:
+            return _latent_work(latent, kv_read, attn_pairs)
+        return None if layers is None else \
+            _attn_work_by_layer(cached, w, layers)
+
     if width > 1:
         def body(carry, _):
             (cached, produced, last_tok, penult, done, poison, nonfinite,
@@ -1454,6 +1606,7 @@ def _spec_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                     last_tok, done)
                 kv_read, attn_pairs = _attn_work(cached, w, window)
                 row_tiles = _row_tile_work(w, width, heads)
+                layer_work = tail_work(cached, w, kv_read, attn_pairs)
             logits, kpool, vpool, moe_work = fwd(
                 params, ids, positions, tables, w, kpool, vpool,
                 moe_work=True)
@@ -1500,7 +1653,7 @@ def _spec_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                 prefill_toks=jnp.where(prefilling, w, 0),
                 eos=emit & (nxt == eos_ids),
                 kv_read=kv_read, attn_pairs=attn_pairs, row_tiles=row_tiles,
-                moe_work=moe_work)
+                moe_work=moe_work, layer_work=layer_work)
             return ((cached + w, produced + emit.astype(jnp.int32), last_tok,
                      penult, done, poison, nonfinite, stats, rng, kpool,
                      vpool, dkpool, dvpool),
@@ -1566,21 +1719,10 @@ def _spec_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
             params, ids_v, pos_v, tables, k_out * av, kpool, vpool,
             all_logits=True, moe_work=True)
         tlogits = _inject_poison(tlogits, poison)
-        n_acc, repl = speculative_verify_per_row(tlogits, dlogits, q, temps,
-                                                 rng=rng_v)
-
         # ---- accept + rollback: pure selects on the carry ----
-        q_pad = jnp.concatenate([q, q[:, -1:]], axis=1)   # (B, G+1)
-        e = jnp.where(koffs[None, :] < n_acc[:, None], q_pad, repl[:, None])
-        is_eos = e == eos_ids[:, None]
-        eos_before = jnp.cumsum(is_eos.astype(jnp.int32), axis=1) - is_eos
-        emit = (active[:, None] & (koffs[None, :] <= n_acc[:, None])
-                & (produced[:, None] + koffs[None, :] < limits[:, None])
-                & (eos_before == 0))
-        emit, done, nonfinite, bad = _finite_check(tlogits, active, emit,
-                                                   done, nonfinite)
-        m = jnp.sum(emit.astype(jnp.int32), axis=1)
-        seq_toks = jnp.concatenate([last_tok[:, None], e], axis=1)
+        e, emit, is_eos, m, seq_toks, done, nonfinite, bad = _accept(
+            tlogits, dlogits, q, temps, rng_v, active, produced, limits,
+            eos_ids, last_tok, done, nonfinite)
         new_last = jnp.take_along_axis(seq_toks, m[:, None], axis=1)[:, 0]
         new_penult = jnp.take_along_axis(
             seq_toks, jnp.maximum(m - 1, 0)[:, None], axis=1)[:, 0]
@@ -1603,9 +1745,182 @@ def _spec_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
             emitted=emit, active=active, eos=emit & is_eos,
             target_fwd=active, drafted=gamma * active.astype(jnp.int32),
             accepted=emit[:, 1:], kv_read=kv_read, attn_pairs=attn_pairs,
-            moe_work=moe_work)
+            moe_work=moe_work,
+            layer_work=tail_work(cached, k_out * av, kv_read, attn_pairs))
         return ((cached + m, produced + m, last_tok, penult, done, poison,
                  nonfinite, stats, rng, kpool, vpool, dkpool, dvpool),
+                (jnp.where(emit, e, -1), emit))
+
+    return body
+
+
+def _latent_work(latent, kv_read, attn_pairs):
+    """``LATENT_STAT_NAMES``' lanes of a step: one layer's rows read and
+    pairs scored x the ``latent`` attention layers."""
+    return latent * jnp.stack(
+        [jnp.sum(kv_read), jnp.sum(attn_pairs)]).astype(jnp.int32)
+
+
+def _accept(tlogits, dlogits, q, temps, rng_v, active, produced, limits,
+            eos_ids, last_tok, done, nonfinite):
+    """What a speculative step's verify lets through, per row: the tokens
+    ``e`` (B, G + 1) (accepted drafts, then the target's correction or
+    bonus token), their ``emit`` mask (a prefix: cut at the row's budget
+    and behind its first EOS, cleared where the target's logits are not
+    finite), ``is_eos``, the count ``m``, ``[last_tok | e]`` to pick the
+    new last tokens from, and ``_finite_check``'s (done, nonfinite,
+    bad)."""
+    koffs = jnp.arange(q.shape[1] + 1)
+    n_acc, repl = speculative_verify_per_row(tlogits, dlogits, q, temps,
+                                             rng=rng_v)
+    q_pad = jnp.concatenate([q, q[:, -1:]], axis=1)   # (B, G+1)
+    e = jnp.where(koffs[None, :] < n_acc[:, None], q_pad, repl[:, None])
+    is_eos = e == eos_ids[:, None]
+    eos_before = jnp.cumsum(is_eos.astype(jnp.int32), axis=1) - is_eos
+    emit = (active[:, None] & (koffs[None, :] <= n_acc[:, None])
+            & (produced[:, None] + koffs[None, :] < limits[:, None])
+            & (eos_before == 0))
+    emit, done, nonfinite, bad = _finite_check(tlogits, active, emit, done,
+                                               nonfinite)
+    m = jnp.sum(emit.astype(jnp.int32), axis=1)
+    seq_toks = jnp.concatenate([last_tok[:, None], e], axis=1)
+    return e, emit, is_eos, m, seq_toks, done, nonfinite, bad
+
+
+def _page_commit(kpool):
+    """What writes a step's rows into float pools: the kernel that writes
+    the touched pages in place on the chip, the scatter elsewhere (the
+    choice ``_forward`` makes for its own commit)."""
+    if _use_pallas_paged() and kpool.dtype != jnp.int8:
+        from ...ops.pallas.kv_commit import kv_commit
+        return kv_commit
+    return commit_scatter
+
+
+def _mtp_calls(fwd, params, tables, module_layer):
+    """What a self-drafting step asks of the prediction module, under the
+    scope ``mtp_draft``: ``module(ids, pos, hid, w, kpool, rows_only)``, its
+    forward over (the stack's hidden states ``hid``, the tokens one position
+    on) against cache layer ``module_layer`` of the pool, and
+    ``commit_rows(kpool, rows, pos)``, which writes the module's rows
+    there."""
+    def module(ids, pos, hid, w, kpool, rows_only=False):
+        with jax.named_scope("mtp_draft"):
+            return fwd(params, ids, pos, tables, w, kpool, None,
+                       mtp=(hid, rows_only))
+
+    def commit_rows(kpool, rows, pos):
+        with jax.named_scope("mtp_draft"), jax.named_scope("kv_commit"):
+            return _page_commit(kpool)(
+                kpool, None, rows, None, block_tables=tables, positions=pos,
+                layer0=module_layer)[0]
+
+    return module, commit_rows
+
+
+def _self_spec_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
+                         temps, tables, greedy, repair=False, window=None,
+                         ladder=pack_ladder, latent=None):
+    """The NARROW serving scan step of a model that drafts for itself with
+    its prediction module (DeepSeek-V3's MTP, one module: gamma 1). Carry:
+    (cached, produced, last_tok, hidden, done, poison, nonfinite, stats,
+    rng, kpool, vpool); emissions are (B, 2).
+
+    The module pairs the stack's hidden state at position p with the token
+    at p + 1 and predicts the token at p + 2; its one layer keeps a cache
+    of its own rows, layer ``latent`` (the stack's attention layers, so the
+    one behind them) of the SAME pool, through the same tables. Invariants
+    at every step boundary, per row: the stack's rows are committed for
+    [0, cached) and ``last_tok`` sits at ``cached``, as everywhere;
+    ``hidden`` is the stack's hidden state (before the final norm) at
+    ``cached - 1``, the position whose logits gave ``last_tok``; the
+    module's rows are committed for [0, cached - 1). So every step hands
+    the module the step's own ids one position BACK, beside the hidden
+    states shifted by one with the carry's in front:
+
+    - a wide step is ``_serving_scan_body``'s, which then writes the
+      module's rows alone (no attention, experts or head) at
+      ``positions - 1``;
+    - a narrow step, this one, DRAFTS: the module whole at ``cached - 1``
+      over (``hidden``, ``last_tok``) gives its row and the token after
+      next; VERIFIES: the stack over [last_tok, draft] at
+      [cached, cached + 1]; HEALS: the module's row at ``cached`` from the
+      verify's first hidden state and the draft (right if the draft is
+      accepted, dead behind the watermark and overwritten by the next draft
+      if not), and commits the module's two rows at once. Acceptance and
+      rollback are ``_spec_scan_body``'s (``_accept``), ``hidden`` rolls
+      back with ``cached``: it is the verify's hidden state at the last
+      emitted position.
+
+    ``target_forwards`` counts the verify forwards alone and
+    ``drafted_tokens`` one a verify, as under a separate draft."""
+    module, commit_rows = _mtp_calls(fwd, params, tables, latent)
+
+    def body(carry, _):
+        (cached, produced, last_tok, hidden, done, poison, nonfinite, stats,
+         rng, kpool, vpool) = carry
+        prev_last, prev_hidden, prev_done = last_tok, hidden, done
+        # as in ``_spec_scan_body``: no row prefills in a narrow frame, and
+        # a position past the row's reservation goes to the trash page
+        active = ~done & (cached >= prompt_lens) & (produced < limits)
+        cap = prompt_lens + limits
+
+        def pos_of(p):
+            return jnp.where(active[:, None] & (p >= 0) & (p <= cap[:, None]),
+                             p, -1)
+
+        rng_d = rng_v = None
+        if not greedy:
+            rng, rng_d, rng_v = jax.random.split(rng, 3)
+        av = active.astype(jnp.int32)
+        pos_d = pos_of((cached - 1)[:, None])
+        dlog, row_d, draft_moe = module(last_tok[:, None], pos_d,
+                                        hidden[:, None], av, kpool)
+        with jax.named_scope("sample"):
+            if greedy:
+                q = jnp.argmax(dlog, axis=-1).astype(jnp.int32)[:, None]
+            else:
+                q = sample_logits_per_row(dlog, rng_d, temps)[:, None]
+        ids_v = jnp.concatenate([last_tok[:, None], q], axis=1)
+        pos_v = pos_of(cached[:, None] + jnp.arange(2)[None, :])
+        tlogits, kpool, vpool, moe_work, h_v = fwd(
+            params, ids_v, pos_v, tables, 2 * av, kpool, vpool,
+            all_logits=True, moe_work=True, hidden=True)
+        tlogits = _inject_poison(tlogits, poison)
+        row_h = module(q, pos_v[:, :1], h_v[:, :1], av, kpool, True)
+        kpool = commit_rows(kpool, jnp.concatenate([row_d, row_h], axis=2),
+                            jnp.concatenate([pos_d, pos_v[:, :1]], axis=1))
+        with jax.named_scope("sample"):
+            e, emit, is_eos, m, seq_toks, done, nonfinite, bad = _accept(
+                tlogits, dlog[:, None], q, temps, rng_v, active, produced,
+                limits, eos_ids, last_tok, done, nonfinite)
+        with jax.named_scope("frame_plan"):
+            new_last = jnp.take_along_axis(seq_toks, m[:, None], axis=1)[:, 0]
+            last_tok = jnp.where(active, new_last, last_tok)
+            hidden = jnp.where(
+                (m > 0)[:, None],
+                jnp.take_along_axis(
+                    h_v, jnp.maximum(m - 1, 0)[:, None, None],
+                    axis=1)[:, 0].astype(hidden.dtype), hidden)
+            done = done | jnp.any(emit & is_eos, axis=1)
+            if repair:
+                # m is 0 for a bad row: cached, produced and hidden stand
+                last_tok = jnp.where(bad, prev_last, last_tok)
+                hidden = jnp.where(bad[:, None], prev_hidden, hidden)
+                done = jnp.where(bad, prev_done, done)
+            kv_read, attn_pairs = _attn_work(cached, 2 * av, window)
+            stats = stats + _stat_delta(
+                pos_v, ladder(*pos_v.shape),
+                emitted=emit, active=active, eos=emit & is_eos,
+                target_fwd=active, drafted=active, accepted=emit[:, 1:],
+                kv_read=kv_read, attn_pairs=attn_pairs, moe_work=moe_work,
+                layer_work=_latent_work(latent, kv_read, attn_pairs),
+                # the draft at cached - 1 reads its cached rows: its own too
+                mtp_work=jnp.concatenate(
+                    [jnp.sum(av * cached)[None].astype(jnp.int32),
+                     draft_moe[:2]]))
+        return ((cached + m, produced + m, last_tok, hidden, done, poison,
+                 nonfinite, stats, rng, kpool, vpool),
                 (jnp.where(emit, e, -1), emit))
 
     return body

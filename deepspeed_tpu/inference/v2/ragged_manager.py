@@ -9,6 +9,7 @@ admission control and never reads slot state back mid-frame.
 """
 
 import dataclasses
+import functools
 from typing import Dict, List, Tuple
 
 import jax
@@ -153,6 +154,35 @@ class DSStateManager:
         return dict(self.seqs)
 
 
+#: the slot arrays an admission rewrites a row of
+_ADMIT_STATE = ("prompts", "tables", "ring_tables", "prompt_lens", "limits",
+                "eos_ids", "temps", "cached", "produced", "last_tok",
+                "penult", "done", "poison", "nonfinite")
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _admit_rows(state, idx, prompts, tables, rings, ints, temps):
+    """``DeviceSlotTable.admit``'s device writes as one program: row i of
+    each staged array into row ``idx[i]`` of the slot array; ``idx`` past the
+    table drops the row. ``ints``: (slots, 4) prompt length, limit, EOS id,
+    admission watermark. A slot freed by quarantine must not hand its
+    poison / latch state to the next tenant of the row, so both clear."""
+    def put(a, v):
+        return a.at[idx].set(v, mode="drop")
+
+    out = dict(state, prompts=put(state["prompts"], prompts),
+               tables=put(state["tables"], tables),
+               ring_tables=tuple(map(put, state["ring_tables"], rings)),
+               temps=put(state["temps"], temps))
+    for j, name in enumerate(("prompt_lens", "limits", "eos_ids", "cached")):
+        out[name] = put(state[name], ints[:, j])
+    for name in ("produced", "last_tok", "penult"):
+        out[name] = put(state[name], 0)
+    for name in ("done", "poison", "nonfinite"):
+        out[name] = put(state[name], False)
+    return out
+
+
 class DeviceSlotTable:
     """Fixed set of serving slots whose state is device-resident.
 
@@ -180,7 +210,7 @@ class DeviceSlotTable:
 
     def __init__(self, n_slots: int, prompt_width: int, table_width: int, rng,
                  tp=None, debug_replicas: bool = False,
-                 n_stats: int = N_STATS, rings=()):
+                 n_stats: int = N_STATS, rings=(), hidden=None):
         self.n_slots = n_slots
         self.n_stats = n_stats     # lanes of the runner's stat vector
         # tensor-parallel serving (tp.TPContext): every slot array is
@@ -209,6 +239,11 @@ class DeviceSlotTable:
         self.produced = zi(n_slots)
         self.last_tok = zi(n_slots)
         self.penult = zi(n_slots)          # speculative carry: token at cached-1
+        # self-speculative carry (``hidden`` = (width, dtype), a model that
+        # drafts with its own prediction module): the stack's hidden state
+        # at cached-1. A new tenant's first chunk never reads it
+        self.hidden = None if hidden is None else self._dev(
+            jnp.zeros((n_slots, hidden[0]), hidden[1]))
         self.done = self._dev(jnp.ones((n_slots,), bool))
         # fault-injection flag (frame NaNs the row's logits while set) and
         # the in-graph finite-check latch — both ride the donated carry
@@ -296,8 +331,8 @@ class DeviceSlotTable:
         tokens whose pages are already valid in the row's block table
         (mapped prefix-cache blocks or swapped-in pages) — the frame body
         starts prefill there, exactly like resuming a mid-prefill row.
-        All device writes are batched — one ``.at[rows].set`` per array,
-        regardless of how many sequences arrive at this frame boundary."""
+        All device writes are ONE program (``_admit_rows``), whatever the
+        number of sequences that arrive at this frame boundary."""
         free = [i for i in range(self.n_slots) if self.uid_of_slot[i] < 0]
         assert len(items) <= len(free), "admit() beyond free slots"
         p_w = int(self.prompts.shape[1])
@@ -332,39 +367,37 @@ class DeviceSlotTable:
             eoss.append(-1 if eos is None else eos)
             temps.append(temp)
             cacheds.append(cached0)
-        # _dev places every staged operand replicated under tp, so each
-        # scatter below is one logical mesh-wide update (XLA keeps the
-        # result replicated), not a per-shard host loop
-        idx = self._dev(jnp.asarray(rows, jnp.int32))
-        self.prompts = self.prompts.at[idx].set(
-            self._dev(jnp.asarray(np.stack(p_rows))))
-        self.tables = self.tables.at[idx].set(
-            self._dev(jnp.asarray(np.stack(t_rows))))
-        self.ring_tables = tuple(
-            table.at[idx].set(self._dev(jnp.asarray(np.stack([
+        # ONE program whatever the batch: the batch's values ride padded to
+        # a row a slot, the index of a row past the batch is out of range
+        # and its write dropped. (One ``.at[rows].set`` an array, eagerly,
+        # was ~14 programs and ~28 transfers with the device idle: 53 ms a
+        # wide frame in PR 39's traces, and programs by batch size.) _dev
+        # places every staged operand replicated under tp, so this stays one
+        # logical mesh-wide update, not a per-shard host loop
+        k, n = len(rows), self.n_slots
+
+        def padded(values, dtype):
+            values = np.asarray(values, dtype)
+            out = np.zeros((n,) + values.shape[1:], dtype)
+            out[:k] = values
+            return self._dev(out)
+
+        state = {name: getattr(self, name) for name in _ADMIT_STATE}
+        idx = np.full((n,), n, np.int32)     # n: past the table, dropped
+        idx[:k] = rows
+        state = _admit_rows(
+            state, self._dev(idx),
+            padded(np.stack(p_rows), np.int32),
+            padded(np.stack(t_rows), np.int32),
+            tuple(padded(np.stack([
                 DSStateManager.block_table(item[1], table.shape[1],
                                            item[1].ring_blocks[i])
-                for item in items]))))
-            for i, table in enumerate(self.ring_tables))
-        self.prompt_lens = self.prompt_lens.at[idx].set(
-            self._dev(jnp.asarray(plens, jnp.int32)))
-        self.limits = self.limits.at[idx].set(
-            self._dev(jnp.asarray(lims, jnp.int32)))
-        self.eos_ids = self.eos_ids.at[idx].set(
-            self._dev(jnp.asarray(eoss, jnp.int32)))
-        self.temps = self.temps.at[idx].set(
-            self._dev(jnp.asarray(temps, jnp.float32)))
-        zero = self._dev(jnp.zeros((len(rows),), jnp.int32))
-        self.cached = self.cached.at[idx].set(
-            self._dev(jnp.asarray(cacheds, jnp.int32)))
-        self.produced = self.produced.at[idx].set(zero)
-        self.last_tok = self.last_tok.at[idx].set(zero)
-        self.penult = self.penult.at[idx].set(zero)
-        self.done = self.done.at[idx].set(False)
-        # a slot freed by quarantine must not hand its poison/latch state
-        # to the next tenant of the row
-        self.poison = self.poison.at[idx].set(False)
-        self.nonfinite = self.nonfinite.at[idx].set(False)
+                for item in items]), np.int32)
+                for i, table in enumerate(self.ring_tables)),
+            padded(np.stack([plens, lims, eoss, cacheds], axis=1), np.int32),
+            padded(temps, np.float32))
+        for name, value in state.items():
+            setattr(self, name, value)
 
     def retire(self, uid: int) -> None:
         """Free the slot on the host side; the device row is already frozen
@@ -405,23 +438,30 @@ class DeviceSlotTable:
         """Dispatch one K-step frame and swap the donated carry in place,
         returning the (tokens, emit) DEVICE arrays — no host transfer
         happens here (the telemetry transfer-guard test wraps exactly this
-        method). ``draft=(draft_runner, draft_params, draft_kv, gamma)``
+        method). ``draft="self"`` runs the frame in which the model's own
+        prediction module drafts (``hidden`` rides the carry, no pool more);
+        ``draft=(draft_runner, draft_params, draft_kv, gamma)``
         runs the speculative frame: the draft's paged KV pools ride the same
         donated carry and share this table's block tables. The in-graph
         telemetry counters (``self.stats``) ride the carry too and come back
         as a device array."""
-        if draft is None:
+        if draft is None or draft == "self":
             tables = self.tables
             if self.ring_tables:
                 tables = (tables,) + self.ring_tables
-            (toks, emit, self.cached, self.produced, self.last_tok, self.done,
-             self.poison, self.nonfinite, self.stats, self.rng, kv.k,
-             kv.v) = runner.frame_loop(
+            # a self-draft's ``hidden`` goes in last and comes back behind
+            # ``last_tok``, where the carry has it
+            hidden = [] if draft is None else [self.hidden]
+            (toks, emit, self.cached, self.produced, self.last_tok, *hidden,
+             self.done, self.poison, self.nonfinite, self.stats, self.rng,
+             kv.k, kv.v) = runner.frame_loop(
                 params, self.prompts, self.prompt_lens, self.limits,
                 self.eos_ids, self.temps, tables, self.cached,
                 self.produced, self.last_tok, self.done, self.poison,
-                self.nonfinite, self.stats, self.rng, kv.k, kv.v,
+                self.nonfinite, self.stats, self.rng, kv.k, kv.v, *hidden,
                 width=width, steps=steps, greedy=greedy, repair=repair)
+            if hidden:
+                self.hidden, = hidden
             return toks, emit
         draft_runner, draft_params, draft_kv, gamma = draft
         (toks, emit, self.cached, self.produced, self.last_tok, self.penult,

@@ -163,15 +163,26 @@ SHARE_STAT_NAMES = ("expert_selections", "zero_expert_selections",
 LATENT_STAT_NAMES = ("latent_positions_read", "latent_pairs")
 
 
+#: a model with a prediction module (``cfg.num_nextn_predict_layers``),
+#: the very last lanes of its vector: the module's work when it drafts, kept
+#: apart from the stack's (EXPERT_ROWS and the lanes above count the stack
+#: alone): cached rows its attention layer read, rows it sent through its
+#: routed experts, and experts those touched. 0 while it does not draft
+MTP_STAT_NAMES = ("mtp_latent_positions_read", "mtp_expert_rows",
+                  "mtp_experts_touched")
+
+
 def n_stats(routed: bool, layered: bool = False, share: bool = False,
-            latent: bool = False) -> int:
+            latent: bool = False, mtp: bool = False) -> int:
     """Lanes of the stat vector of a model with (or without) routed
     experts (all of them held, or a share), of mixed cache kinds or of
-    one, with latent attention or without."""
+    one, with latent attention or without, with a prediction module or
+    without."""
     return (N_STATS + (len(MOE_STAT_NAMES) if routed else 0)
             + (len(SHARE_STAT_NAMES) if share else 0)
             + (len(LAYER_STAT_NAMES) if layered else 0)
-            + (len(LATENT_STAT_NAMES) if latent else 0))
+            + (len(LATENT_STAT_NAMES) if latent else 0)
+            + (len(MTP_STAT_NAMES) if mtp else 0))
 
 
 #: host work between two frames, in loop order; ``dispatch`` and ``fetch``
@@ -596,7 +607,7 @@ class ServingTelemetry:
         # {gauge: {kind: value}}, beside the gauge of the same name (which
         # stays the table kind's pool with its trash page)
         self.kind_gauges: Dict[str, Dict[str, int]] = {}
-        self._tail_names, self._share = (), False
+        self._tail_names, self._share, self._mtp = (), False, False
         # per-class TTFT (the bench/SLO acceptance surface)
         self.class_ttft: Dict[str, LogBucketHistogram] = {}
         # live SLO signal windows (recent samples, seconds)
@@ -626,7 +637,7 @@ class ServingTelemetry:
                     n_slots: int, kv_blocks_total: int,
                     tp_degree: int = 1, kv_block_bytes: int = 0,
                     layered: bool = False, latent: bool = False,
-                    share: bool = False) -> None:
+                    share: bool = False, mtp: bool = False) -> None:
         """Called by ``serve()`` at generator construction.
         ``kv_block_bytes`` is the pool-resident footprint of one KV block
         across all layers (``BlockedKVCache.block_bytes``) — the
@@ -639,9 +650,13 @@ class ServingTelemetry:
         model's attention is latent, its cache one pool of rows and its
         vector's last lanes LATENT_STAT_NAMES, with the same gauges and
         sums. ``share``: its router is wider than the experts it holds
-        (SHARE_STAT_NAMES behind the experts' lanes)."""
+        (SHARE_STAT_NAMES behind the experts' lanes). ``mtp``: the model
+        has a prediction module, and its vector's very last lanes are
+        MTP_STAT_NAMES."""
         self.reset()
-        self._share = share
+        self._share, self._mtp = share, mtp
+        for n in MTP_STAT_NAMES if mtp else ():
+            self.counters[n] = 0
         self._tail_names = (LAYER_STAT_NAMES if layered else
                             LATENT_STAT_NAMES if latent else ())
         for n in SHARE_STAT_NAMES if share else ():
@@ -1232,6 +1247,12 @@ class ServingTelemetry:
             self.counters[name] += int(delta[i])
         # a model of mixed cache kinds ends its vector with the layered
         # work, one with latent attention with the latent rows' work
+        mtp = {}
+        if self._mtp:
+            delta, tail = np.split(delta, [len(delta) - len(MTP_STAT_NAMES)])
+            mtp = dict(zip(MTP_STAT_NAMES, map(int, tail)))
+            for name, value in mtp.items():
+                self.counters[name] += value
         layers = {}
         if self._tail_names:
             delta, tail = np.split(delta, [len(delta) - len(self._tail_names)])
@@ -1251,7 +1272,7 @@ class ServingTelemetry:
                     **{n: int(delta[i]) for i, n in
                        enumerate(STAT_NAMES + SPLIT_STAT_NAMES
                                  + TILE_STAT_NAMES)}, **moe,
-                    **layers):
+                    **layers, **mtp):
                 pass
         split = "wide" if width > 1 else "narrow"
         for i, name in enumerate(SPLIT_STAT_NAMES, len(STAT_NAMES)):

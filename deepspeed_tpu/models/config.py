@@ -88,6 +88,12 @@ class TransformerConfig:
     # num_experts > 0). Qwen2-MoE's mlp_only_layers / decoder_sparse_step
     # interleave dense-MLP layers into a routed-expert stack.
     layer_types: Optional[tuple] = None
+    # leading dense layers of a routed stack (DeepSeek-V3's
+    # first_k_dense_replace): layers [0, k) are "dense", the rest "moe".
+    # A RULE where ``layer_types`` is a list: it holds at any depth, so a
+    # preset survives a cut of num_layers alone. The two exclude each other
+    # (``layer_tags`` refuses a config that sets both)
+    moe_first_dense: int = 0
     # MoE (Mixtral-style; 0 experts → dense)
     num_experts: int = 0
     num_experts_per_tok: int = 2
@@ -95,6 +101,10 @@ class TransformerConfig:
     moe_aux_loss_coef: float = 0.01
     moe_norm_topk: bool = True          # renormalize top-k gates (Mixtral yes, Qwen2-MoE no)
     moe_shared_expert_size: int = 0     # always-on shared expert width (Qwen2-MoE)
+    moe_shared_expert_gate: bool = True  # its sigmoid gate (Qwen2-MoE yes, DeepSeek-V3's family no)
+    # what the router's logits become before the top-k: "softmax" over all
+    # experts, or "sigmoid" each on its own (DeepSeek-V3's family)
+    moe_router_score: str = "softmax"
     # "einsum": capacity-bounded one-hot dispatch (GShard/EP all-to-all);
     # "grouped": dropless sort-by-expert + ragged_dot (megablox pattern,
     # expert axis unsharded only)
@@ -130,6 +140,13 @@ class TransformerConfig:
     # attentions and two dense MLPs, and ONE routed block that reads the
     # stream after the first attention and joins at the layer's end
     shortcut_moe: bool = False
+    # multi-token prediction (DeepSeek-V3's MTP; GLM-4.7-Flash has one):
+    # modules beside the stack, each two norms, a projection 2E -> E, one
+    # layer of the stack's last kind with its own attention and a norm
+    # before the model's own head; module k predicts the token k + 2 ahead
+    # from the stack's hidden state and the next token's embedding. Served
+    # as the model's own draft (inference/v2); 0: none
+    num_nextn_predict_layers: int = 0
     # numerics
     dtype: str = "bfloat16"             # activation dtype
     param_dtype: str = "float32"        # stored parameter dtype
@@ -212,6 +229,26 @@ class TransformerConfig:
         shortcut-connected stack."""
         return self.num_layers * (2 if self.shortcut_moe else 1)
 
+    @property
+    def cache_layers(self) -> int:
+        """Layers of the serving cache: the stack's attention layers and,
+        behind them, one for each prediction module's."""
+        return self.attn_layers + self.num_nextn_predict_layers
+
+    @property
+    def layer_tags(self) -> Optional[tuple]:
+        """Per-layer structure tags, or None for a stack whose layers are
+        alike: ``layer_types``, or ``moe_first_dense`` dense layers ahead
+        of the routed ones; never both."""
+        if self.layer_types is not None:
+            assert not self.moe_first_dense, \
+                "layer_types and moe_first_dense both state the layout"
+            return self.layer_types
+        if self.is_moe and 0 < self.moe_first_dense < self.num_layers:
+            return ("dense",) * self.moe_first_dense + ("moe",) * (
+                self.num_layers - self.moe_first_dense)
+        return None
+
     def layer_windows(self) -> Optional[tuple]:
         """Per-layer window sizes (0 = global) of a stack that mixes
         windowed and global layers, or None where the layers are alike (a
@@ -230,8 +267,9 @@ class TransformerConfig:
                      for i in range(self.num_layers))
 
     def layer_type(self, i: int) -> str:
-        if self.layer_types is not None:
-            return self.layer_types[i]
+        tags = self.layer_tags
+        if tags is not None:
+            return tags[i]
         return "moe" if self.is_moe else "dense"
 
     def replace(self, **kw):
@@ -352,6 +390,23 @@ PRESETS = {
         moe_impl="grouped", kv_lora_rank=512, q_lora_rank=1536,
         qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
         mla_scale_q_lora=True, mla_scale_kv_lora=True, shortcut_moe=True),
+    # GLM-4.7-Flash (zai-org/GLM-4.7-Flash config.json, glm4_moe_lite:
+    # DeepSeek-V3's layer): MLA with ranks 768 / 512, 20 heads of 192 + 64
+    # (scores) and 256 (values), no rescaling; one leading dense layer
+    # 10240 wide, then 46 layers of 64 experts of width 1536 beside one
+    # ungated shared expert: sigmoid scores, the top 4 of score + bias,
+    # weights the scores renormalised x 1.8; one prediction module, served
+    # as the model's own draft; untied head
+    "glm-4.7-flash": TransformerConfig(
+        vocab_size=154880, hidden_size=2048, num_layers=47, num_heads=20,
+        intermediate_size=10240, moe_intermediate_size=1536, max_seq_len=202752,
+        rope_theta=1e6, rope_interleaved=True, norm_eps=1e-5,
+        num_experts=64, num_experts_per_tok=4, moe_first_dense=1,
+        moe_norm_topk=True, moe_router_bias=True, moe_routed_scale=1.8,
+        moe_router_score="sigmoid", moe_shared_expert_size=1536,
+        moe_shared_expert_gate=False, moe_impl="grouped",
+        kv_lora_rank=512, q_lora_rank=768, qk_nope_head_dim=192,
+        qk_rope_head_dim=64, v_head_dim=256, num_nextn_predict_layers=1),
     # BERT family (post-norm encoder, MLM head; acceptance config 2 trains
     # bert-large under ZeRO-1/2)
     "bert-base": TransformerConfig(vocab_size=30522, hidden_size=768, num_layers=12, num_heads=12,

@@ -285,6 +285,29 @@ def mla_scales(cfg: TransformerConfig):
             if cfg.mla_scale_kv_lora else 1.0)
 
 
+def _mla_lanes(cfg: TransformerConfig, *parts):
+    """``parts`` side by side, zeros behind them up to a cache row's lanes."""
+    pad = cfg.latent_lanes - cfg.kv_lora_rank - cfg.qk_rope_head_dim
+    zeros = [jnp.zeros(parts[0].shape[:-1] + (pad,), cfg.act_dtype)] \
+        if pad else []
+    return jnp.concatenate(list(parts) + zeros, axis=-1)
+
+
+@jax.named_scope("mla_kv")
+def mla_row(params, a, positions, cfg: TransformerConfig, inv_freq):
+    """The cached row alone (``mla_query_and_row``'s second value): what a
+    layer's cache needs of a position whose output nobody asks for."""
+    dt = cfg.act_dtype
+    s_kv, rkv = mla_scales(cfg)[1], cfg.kv_lora_rank
+    kv = jnp.einsum("bse,er->bsr", a, dq(params["wkv_a"], dt))
+    c = apply_norm(params["kv_norm"], kv[..., :rkv], cfg)
+    if s_kv != 1.0:
+        c = c * jnp.asarray(s_kv, dt)
+    k_rope = apply_rope(kv[..., None, rkv:], positions, inv_freq,
+                        interleaved=cfg.rope_interleaved)
+    return _mla_lanes(cfg, c[:, :, None], k_rope)
+
+
 def mla_query_and_row(params, a, positions, cfg: TransformerConfig, inv_freq):
     """The ABSORBED query and the cached row of normalised input ``a``
     (B, S, E) at ``positions`` (B, S), both ``cfg.latent_lanes`` wide:
@@ -293,15 +316,7 @@ def mla_query_and_row(params, a, positions, cfg: TransformerConfig, inv_freq):
     expanded form's q_nope . k_nope + q_rope . k_rope, and the row's first
     ``kv_lora_rank`` lanes are the value before W_UV."""
     dt = cfg.act_dtype
-    s_q, s_kv = mla_scales(cfg)
-    dn, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
-    pad = cfg.latent_lanes - rkv - cfg.qk_rope_head_dim
-
-    def lanes(*parts):
-        """``parts`` side by side, zeros behind them up to the row's lanes."""
-        zeros = [jnp.zeros(parts[0].shape[:-1] + (pad,), dt)] if pad else []
-        return jnp.concatenate(list(parts) + zeros, axis=-1)
-
+    s_q, dn = mla_scales(cfg)[0], cfg.qk_nope_head_dim
     with jax.named_scope("mla_q"):
         cq = apply_norm(params["q_norm"], jnp.einsum(
             "bse,er->bsr", a, dq(params["wq_a"], dt)), cfg)
@@ -310,18 +325,11 @@ def mla_query_and_row(params, a, positions, cfg: TransformerConfig, inv_freq):
             q = q * jnp.asarray(s_q, dt)
         q_rope = apply_rope(q[..., dn:], positions, inv_freq,
                             interleaved=cfg.rope_interleaved)
-    with jax.named_scope("mla_kv"):
-        kv = jnp.einsum("bse,er->bsr", a, dq(params["wkv_a"], dt))
-        c = apply_norm(params["kv_norm"], kv[..., :rkv], cfg)
-        if s_kv != 1.0:
-            c = c * jnp.asarray(s_kv, dt)
-        k_rope = apply_rope(kv[..., None, rkv:], positions, inv_freq,
-                            interleaved=cfg.rope_interleaved)
-        row = lanes(c[:, :, None], k_rope)
+    row = mla_row(params, a, positions, cfg, inv_freq)
     with jax.named_scope("mla_absorb"):
         q_lat = jnp.einsum("bshn,rhn->bshr", q[..., :dn],
                            dq(params["wkv_b"], dt)[..., :dn])
-    return lanes(q_lat, q_rope), row
+    return _mla_lanes(cfg, q_lat, q_rope), row
 
 
 def mla_output(params, o_lat, cfg: TransformerConfig):
@@ -502,7 +510,8 @@ EXPERT_MATRICES = ("wi_gate", "wi_up", "wo")
 
 def init_moe_mlp(rng, cfg: TransformerConfig):
     """Mixtral-style top-k routed experts with swiglu experts (+ optional
-    Qwen2-MoE always-on shared expert with its own sigmoid gate)."""
+    always-on shared expert: Qwen2-MoE's behind its own sigmoid gate,
+    DeepSeek-V3's family's without one, ``moe_shared_expert_gate``)."""
     e, f, x = cfg.hidden_size, cfg.moe_ffn_size, cfg.num_experts
     r = jax.random.split(rng, 8)
     std = 0.02
@@ -521,10 +530,12 @@ def init_moe_mlp(rng, cfg: TransformerConfig):
     if cfg.moe_router_bias:
         # the score correction that chooses the experts (a trained
         # parameter): drawn small beside the top scores (~4 / width), so it
-        # changes some of a token's picks and not most
+        # changes some of a token's picks and not most. Sigmoid scores of
+        # unit-variance logits lie ~0.02 apart around a token's 4th pick
         params["router_bias"] = _normal(
             jax.random.fold_in(rng, 8), (cfg.moe_router_width,), jnp.float32,
-            1.0 / cfg.moe_router_width)
+            0.02 if cfg.moe_router_score == "sigmoid"
+            else 1.0 / cfg.moe_router_width)
         axes["router_bias"] = ("unmodeled",)
     if cfg.moe_shared_expert_size:
         s = cfg.moe_shared_expert_size
@@ -532,20 +543,27 @@ def init_moe_mlp(rng, cfg: TransformerConfig):
             shared_wi_gate=_normal(r[4], (e, s), cfg.p_dtype, std),
             shared_wi_up=_normal(r[5], (e, s), cfg.p_dtype, std),
             shared_wo=_normal(r[6], (s, e), cfg.p_dtype,
-                              std / math.sqrt(2 * cfg.num_layers)),
-            shared_gate=_normal(r[7], (e, 1), cfg.p_dtype, std))
+                              std / math.sqrt(2 * cfg.num_layers)))
         axes.update(shared_wi_gate=("embed", "mlp"), shared_wi_up=("embed", "mlp"),
-                    shared_wo=("mlp", "embed"), shared_gate=("embed", "unmodeled"))
+                    shared_wo=("mlp", "embed"))
+        if cfg.moe_shared_expert_gate:
+            params["shared_gate"] = _normal(r[7], (e, 1), cfg.p_dtype, std)
+            axes["shared_gate"] = ("embed", "unmodeled")
     return params, axes
 
 
+@jax.named_scope("moe_shared")
 def _apply_shared_expert(params, x, cfg: TransformerConfig):
-    """Qwen2-MoE shared expert: swiglu MLP weighted by a sigmoid gate."""
+    """The shared expert every token passes beside its routed ones: a
+    swiglu MLP, weighted by a sigmoid gate where the model has one
+    (``shared_gate``: Qwen2-MoE's; DeepSeek-V3's family adds it as it is)."""
     dt = cfg.act_dtype
-    g = jnp.einsum("...e,ef->...f", x, params["shared_wi_gate"].astype(dt))
-    u = jnp.einsum("...e,ef->...f", x, params["shared_wi_up"].astype(dt))
+    g = jnp.einsum("...e,ef->...f", x, dq(params["shared_wi_gate"], dt))
+    u = jnp.einsum("...e,ef->...f", x, dq(params["shared_wi_up"], dt))
     sh = jnp.einsum("...f,fe->...e", jax.nn.silu(g) * u,
-                    params["shared_wo"].astype(dt))
+                    dq(params["shared_wo"], dt))
+    if "shared_gate" not in params:
+        return sh
     gate = jax.nn.sigmoid(
         jnp.einsum("...e,eo->...o", x, params["shared_gate"].astype(dt)))
     return gate * sh
@@ -587,7 +605,8 @@ def apply_moe_grouped(params, x, cfg: TransformerConfig, live=None,
                             params["router"].astype(jnp.float32))
         topk_idx, w, aux_loss = topk_gating_grouped(
             logits, k=k, normalize=cfg.moe_norm_topk,
-            bias=params.get("router_bias"), scale=cfg.moe_routed_scale)
+            bias=params.get("router_bias"), scale=cfg.moe_routed_scale,
+            score=cfg.moe_router_score)
 
     share = cfg.moe_is_share
     with jax.named_scope("moe_dispatch"):

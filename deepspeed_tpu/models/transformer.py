@@ -109,7 +109,7 @@ def layer_plan(cfg):
       ("segments", [(tag, start, length), ...]) — contiguous runs
         (mlp_only_layers prefixes): one scan per run.
     """
-    tags = cfg.layer_types
+    tags = cfg.layer_tags
     if tags is None or len(set(tags)) <= 1:
         return None
     n = len(tags)
@@ -137,7 +137,7 @@ def layer_groups(cfg):
         return None
     if plan[0] == "periodic":
         p = plan[1]
-        return [(cfg.layer_types[i], tuple(range(i, cfg.num_layers, p)))
+        return [(cfg.layer_tags[i], tuple(range(i, cfg.num_layers, p)))
                 for i in range(p)]
     return [(tag, tuple(range(start, start + ln)))
             for tag, start, ln in plan[1]]
@@ -253,7 +253,8 @@ class CausalLM:
         if cfg.shortcut_moe:
             return self._init_double_layer(rng)
         r_attn, r_mlp = jax.random.split(rng)
-        attn, attn_axes = L.init_attention(r_attn, cfg)
+        attn, attn_axes = (L.init_mla if cfg.kv_lora_rank
+                           else L.init_attention)(r_attn, cfg)
         if (cfg.is_moe if layer_type is None else layer_type == "moe"):
             mlp, mlp_axes = L.init_moe_mlp(r_mlp, cfg)
         else:
@@ -312,7 +313,39 @@ class CausalLM:
         out = {"embed": emb, "layers": stacked}
         if not cfg.post_norm:   # post-norm (BERT) normalizes inside each layer
             out["final_norm"] = L.init_norm(cfg)[0]
+        if cfg.num_nextn_predict_layers:
+            # a key of its own: the stack's weights do not depend on
+            # whether the model has its prediction modules
+            out["mtp"] = self._init_mtp(jax.random.fold_in(rng, 2))[0]
         return out
+
+    def _init_mtp(self, rng):
+        """The prediction modules (DeepSeek-V3's MTP), each leaf stacked
+        over them: ``enorm`` / ``hnorm`` on the next token's embedding and
+        on the stack's hidden state, ``eh_proj`` (2E -> E) over the two
+        side by side, ``layer`` one layer of the stack's last kind with an
+        attention of its own, ``norm`` before the head. The embedding and
+        the head are the model's own."""
+        cfg = self.cfg
+        e = cfg.hidden_size
+        tag = cfg.layer_type(cfg.num_layers - 1)
+
+        def one(r):
+            r_proj, r_layer = jax.random.split(r)
+            return {"enorm": L.init_norm(cfg)[0], "hnorm": L.init_norm(cfg)[0],
+                    "eh_proj": L._normal(r_proj, (2 * e, e), cfg.p_dtype, 0.02),
+                    "layer": self._init_layer(r_layer, tag)[0],
+                    "norm": L.init_norm(cfg)[0]}
+
+        norm_axes = L.init_norm(cfg)[1]
+        axes = {"enorm": norm_axes, "hnorm": norm_axes,
+                "eh_proj": ("unmodeled", "embed"),
+                "layer": _axes_of(lambda r: self._init_layer(r, tag)),
+                "norm": norm_axes}
+        params = jax.vmap(one)(
+            jax.random.split(rng, cfg.num_nextn_predict_layers))
+        return params, jax.tree.map(lambda a: ("layers",) + a, axes,
+                                    is_leaf=_is_axes_leaf)
 
     def abstract_params(self):
         """Shape/dtype tree without allocating (for sharded init)."""
@@ -337,6 +370,8 @@ class CausalLM:
         out = {"embed": emb_axes, "layers": stacked_axes}
         if not cfg.post_norm:
             out["final_norm"] = _axes_of(lambda r: L.init_norm(cfg))
+        if cfg.num_nextn_predict_layers:
+            out["mtp"] = self._init_mtp(jax.random.PRNGKey(0))[1]
         return out
 
     # -- forward --

@@ -84,11 +84,14 @@ def topk_gating_einsum(logits, k: int = 2, capacity_factor: float = 1.25,
 
 
 def topk_gating_grouped(logits, k: int = 2, normalize: bool = True,
-                        bias=None, scale: float = 1.0):
+                        bias=None, scale: float = 1.0,
+                        score: str = "softmax"):
     """Top-k gating for the grouped (megablox-style) dropless path.
     ``bias`` (X,): the k experts are the largest of score + bias, their
     weights the scores alone (DeepSeek-V3's / LongCat's correction bias);
-    ``scale`` multiplies the weights.
+    ``scale`` multiplies the weights. ``score``: "softmax" over all the
+    experts, or "sigmoid" each on its own (DeepSeek-V3's family, whose
+    renormalised weights are s / (sum s + 1e-20)).
 
     Returns (topk_idx (T, k) int32, weights (T, k) fp32 normalized over the
     k choices, aux_loss). No capacity buffers: every token reaches its
@@ -96,7 +99,10 @@ def topk_gating_grouped(logits, k: int = 2, normalize: bool = True,
     ``inference/v2/kernels/cutlass_ops/moe_gemm``).
     """
     x = logits.shape[1]
-    gates = jax.nn.softmax(logits, axis=-1)
+    sigmoid = score == "sigmoid"
+    assert sigmoid or score == "softmax", score
+    gates = jax.nn.sigmoid(logits) if sigmoid \
+        else jax.nn.softmax(logits, axis=-1)
     if bias is None:
         topk_vals, topk_idx = jax.lax.top_k(gates, k)
     else:
@@ -104,7 +110,8 @@ def topk_gating_grouped(logits, k: int = 2, normalize: bool = True,
         topk_vals = jnp.take_along_axis(gates, topk_idx, axis=-1)
     if normalize:
         denom = jnp.sum(topk_vals, axis=-1, keepdims=True)
-        w = topk_vals / jnp.maximum(denom, 1e-9)
+        w = topk_vals / (denom + 1e-20 if sigmoid
+                         else jnp.maximum(denom, 1e-9))
     else:
         w = topk_vals
     if scale != 1.0:

@@ -85,7 +85,7 @@ def _plan(positions, block_tables, rows, slots, chunk_rows, ring=None):
 
 
 def kv_commit(kpool, vpool, chunk_k, chunk_v, block_tables, positions,
-              ring=None):
+              ring=None, layer0=0):
     """Write a step's chunk KV into the page pools in place.
 
     kpool/vpool: (L, KVH, NB, bs, D), donated to the results;
@@ -99,8 +99,11 @@ def kv_commit(kpool, vpool, chunk_k, chunk_v, block_tables, positions,
     kernel is then named ``kv_commit_ring_c<C>``. The latent format
     (``kv_cache.py``): ``vpool`` and ``chunk_v`` are None, the one pool's
     rows are committed alone and the kernel is named
-    ``kv_commit_mla_c<C>``. Returns (kpool, vpool)."""
-    layers, kvh, _, page_size, d = kpool.shape
+    ``kv_commit_mla_c<C>``. The chunk may hold fewer layers than the pools:
+    it is then written to the pools' layers from ``layer0`` (static) on.
+    Returns (kpool, vpool)."""
+    _, kvh, _, page_size, d = kpool.shape
+    layers = chunk_k.shape[0]
     b, c = positions.shape
     # a slot is a whole page: blocks of 16 rows in a decode step moved an
     # eighth of the bytes (0.06 against ~0.1 ms a step) but once in ~3,500
@@ -117,7 +120,7 @@ def kv_commit(kpool, vpool, chunk_k, chunk_v, block_tables, positions,
         return (li, si // slots, 0, 0, 0)
 
     def pool_map(li, si, plan):
-        return (li, 0, plan[_PAGE, si], 0, 0)
+        return (li + layer0 if layer0 else li, 0, plan[_PAGE, si], 0, 0)
 
     chunk_spec = pl.BlockSpec((1, 1, kvh, chunk_rows, d), chunk_map)
     pool_spec = pl.BlockSpec((1, kvh, 1, rows, d), pool_map)

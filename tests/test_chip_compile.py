@@ -664,6 +664,143 @@ def test_longcat_frame_programs_fit_the_chip(one_chip, as_tpu, width):
     assert total < 15.75e9, total
 
 
+def test_latent_tilings_are_pinned():
+    """(tiles of chunk positions, pages a group) of the latent kernel at the
+    cells' shapes: LongCat's 64 heads come out as PR 34 left them (a decode
+    step whole, a chunk of 128 in 16 tiles of 8 positions = 512 rows against
+    groups of 8 pages); GLM-4.7-Flash's 20 heads give a decode step of 20
+    query rows, a verify of 40, and a chunk in 4 tiles of 32 positions = 640
+    rows against groups of 4 pages."""
+    from deepspeed_tpu.ops.pallas.paged_attention import _latent_tiling
+    got = {(c, h): _latent_tiling(c, h, mb, PAGE, 640, 512, 2)
+           for c, h, mb in ((1, 64, 128), (128, 64, 128), (1, 20, 64),
+                            (2, 20, 64), (128, 20, 64))}
+    assert got == {(1, 64): (1, 4), (128, 64): (16, 8), (1, 20): (1, 4),
+                   (2, 20): (1, 4), (128, 20): (4, 4)}
+
+
+def test_glm_reference_walks_candidates_in_programs_that_fit(one_chip):
+    """The plain reference of GLM-4.7-Flash runs BESIDE the server, which
+    fills the chip, and holds a row at a near-tie of the router to about ten
+    candidate routings: the two programs that walk and score them take a
+    block of 1,024 candidates whatever their number, lower with the chip's
+    compiler at the cell's sizes (4,096 of context, 20 heads, the whole
+    vocabulary a block of 16,384 at a time) and hold a few hundred MB."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "glm_reference", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "perfbench", "configs", "glm4_moe_lite_reference.py"))
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    bf, i32 = jnp.bfloat16, jnp.int32
+    e, h, t, v, n = 2048, 20, 4096, 154880, ref.TOKEN_BLOCK
+    lp = {"norm1": {"scale": sds((e,), bf)}, "norm2": {"scale": sds((e,), bf)},
+          "attn": {"wq_a": sds((e, 768), bf), "wq_b": sds((768, h, 256), bf),
+                   "q_norm": {"scale": sds((768,), bf)},
+                   "wkv_a": sds((e, 576), bf), "wkv_b": sds((512, h, 448), bf),
+                   "kv_norm": {"scale": sds((512,), bf)},
+                   "wo": sds((h, 256, e), bf)}}
+    sizes = dict(eps=1e-5, theta=1e6, d_n=192, d_r=64)
+    walk = ref.attend.lower(lp, sds((n, e)), sds((n,), i32),
+                            sds((t, h, 256)), sds((t, h, 256)),
+                            **sizes).compile().memory_analysis()
+    score = ref._envelope_at.lower(
+        sds((n, e)), sds((n,)), sds((n,), i32), sds((e,), bf),
+        sds((e, v), bf), sds((), i32), eps=1e-5,
+        size=ref.VOCAB_BLOCK).compile().memory_analysis()
+    for held in (walk, score):
+        assert held.temp_size_in_bytes + held.output_size_in_bytes < 400e6
+
+
+@pytest.mark.parametrize("width", [1, 128], ids=["narrow", "wide"])
+def test_glm_frame_programs_fit_the_chip(one_chip, as_tpu, width):
+    """The benchmark's GLM-4.7-Flash configuration (published widths; the
+    dense layer and 6 of 46 routed layers, all 64 experts, the whole
+    vocabulary, the prediction module, bf16; 16 slots, 8 steps, sequences to
+    8,192: tables of 64 pages over ONE pool of 577 pages of 640-lane rows
+    for the 7 + 1 cache layers): both SELF-SPECULATIVE frame programs
+    compile with the chip's compiler from shapes alone. The narrow one
+    drafts with the module (``paged_attn_mla_c1``, its grouped product at 64
+    rows) and verifies two wide (``paged_attn_mla_c2`` in the dense and in
+    the routed segment); the wide one computes the module's rows alone (no
+    attention, no experts of the module's). Each commits twice, the stack's
+    layers and the module's, in place: no value shaped like the pool that
+    XLA made, no buffer shaped like a layer's experts, neither an operand
+    of a conditional, and arguments and temporaries under 15.75 GB."""
+    import re
+    from deepspeed_tpu.inference.v2.model_runner import PagedModelRunner
+    from deepspeed_tpu.inference.v2.telemetry import pack_ladder
+    from deepspeed_tpu.models import build_model, get_config
+    slots, steps, pages, seq = 16, 8, 577, 8192
+    cfg = get_config("glm-4.7-flash", num_layers=7)
+    assert cfg.dtype == "bfloat16" and cfg.latent_lanes == 640
+    assert cfg.layer_tags == ("dense",) + ("moe",) * 6
+    assert (cfg.attn_layers, cfg.cache_layers) == (7, 8)
+    model = build_model(cfg.replace(param_dtype=cfg.dtype))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                          model.abstract_params())
+    i32, flag = jnp.int32, jnp.bool_
+    row = sds((slots,), i32)
+    pool = sds((cfg.cache_layers, 1, pages, PAGE, cfg.latent_lanes),
+               jnp.bfloat16)
+    key = jax.random.PRNGKey(0)
+    runner = PagedModelRunner(model, PAGE, seq // PAGE)
+    assert runner.has_mtp and runner.n_stats == 18 + 3 + 2 + 3
+    compiled = runner._build_frame_loop().lower(
+        params, sds((slots, 2048), i32), row, row, row,
+        sds((slots,), jnp.float32), sds((slots, seq // PAGE), i32), row, row,
+        row, sds((slots,), flag), sds((slots,), flag), sds((slots,), flag),
+        sds((runner.n_stats,), i32), sds(key.shape, key.dtype), pool, None,
+        sds((slots, cfg.hidden_size), jnp.bfloat16),
+        width=width, steps=steps, greedy=True).compile()
+    text = compiled.as_text()
+
+    def count(pattern):
+        return len(re.findall(rf"%{pattern}\S* = ", text))
+
+    if width == 1:
+        assert count("paged_attn_mla_c1") == 1      # the module's draft
+        assert count("paged_attn_mla_c2") == 2      # dense and routed scans
+        assert count("paged_attn_") == 3
+        assert count("kv_commit_mla_c2") == count("kv_commit_") == 2
+        assert count("grouped_mm_m128") == 3        # the verify's 128 rows
+        assert count("grouped_mm_m64") == 3         # the draft's 64
+    else:
+        rungs = len(pack_ladder(slots, width))
+        assert count("paged_attn_mla_c128") == count("paged_attn_") == 2
+        assert count("kv_commit_mla_c128") == count("kv_commit_") == 2
+        assert count("grouped_mm_") == 3 * rungs    # the stack's alone
+    assert "ragged-dot" not in text
+    pool_shape = ",".join(map(str, pool.shape))
+    experts = r"(?:1,|6,)?64,(?:2048,1536|1536,2048)"
+    made = re.findall(
+        rf"= bf16\[(?:{pool_shape}|{experts})\]\S* (copy|copy-start|fusion|"
+        rf"scatter|transpose|dynamic-update-slice|dynamic-slice)\(", text)
+    assert not made, made
+    for line in re.findall(r"^.* conditional\(.*$", text, re.M):
+        assert not re.search(rf"bf16\[(?:{pool_shape}|{experts})\]", line), \
+            line[:300]
+    m = compiled.memory_analysis()
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes
+    print(f"glm self-speculative frame program, width {width}: args "
+          f"{m.argument_size_in_bytes / 1e9:.3f} GB + temp "
+          f"{m.temp_size_in_bytes / 1e9:.3f} GB")
+    # the wide program never reads the module's experts and attention
+    want = 11.106e9 if width == 1 else 9.838e9
+    assert abs(m.argument_size_in_bytes - want) < 0.02e9
+    assert m.temp_size_in_bytes < 0.6e9
+    assert total < 15.75e9, total
+
+
 def test_chip_smoke_fails_without_a_chip():
     """The suite runs on the CPU: ``chip_smoke.py`` must exit nonzero there
     and never print its success line (the children stop before any phase)."""

@@ -675,3 +675,47 @@ def test_packing_adapts_to_the_model(variant, packs):
     for u in prompts:
         np.testing.assert_array_equal(outs["packed"][u], outs["whole"][u],
                                       err_msg=f"{variant} uid={u}")
+
+
+@pytest.mark.parametrize("batch", [1, 3, 4])
+def test_admission_is_one_program_whatever_the_batch(batch):
+    """``DeviceSlotTable.admit`` writes a batch's rows with ONE program of
+    fixed shapes (``_admit_rows``: the batch rides padded to a row a slot,
+    a row past the batch is dropped): the rows admitted read what they were
+    given, a row freed by quarantine hands on neither its poison flag nor
+    its latch, every other row stands, and no batch size compiles anew."""
+    from deepspeed_tpu.inference.v2 import ragged_manager as rm
+    slots = rm.DeviceSlotTable(4, 8, 2, jax.random.PRNGKey(0), rings=(3,))
+    live = rm.DSSequenceDescriptor(uid=9, blocks=[7], ring_blocks=[[5]])
+    slots.admit([(9, live, [3, 1, 4], 5, 0.0, None)])
+    if batch == 4:
+        slots.retire(9)
+    slots.poison = slots.poison.at[1:].set(True)
+    slots.nonfinite = slots.nonfinite.at[1:].set(True)
+    compiled = rm._admit_rows._cache_size()
+    items = [(20 + i, rm.DSSequenceDescriptor(
+                  uid=20 + i, blocks=[10 + i, 30 + i], ring_blocks=[[40 + i]]),
+              np.arange(2 + i) + 50, 6 + i, 0.5 * i, 2 if i else None, i % 2)
+             for i in range(batch)]
+    slots.admit(items)
+    assert rm._admit_rows._cache_size() == compiled
+    rows = [slots.slot_of_uid[20 + i] for i in range(batch)]
+    assert rows == list(range(1, 1 + batch) if batch < 4 else range(4))
+    for i, r in enumerate(rows):
+        assert list(np.asarray(slots.prompts[r])[:2 + i]) == \
+            list(range(50, 52 + i))
+        assert not np.asarray(slots.prompts[r])[2 + i:].any()
+        assert list(np.asarray(slots.tables[r])) == [10 + i, 30 + i]
+        assert np.asarray(slots.ring_tables[0][r])[0] == 40 + i
+        got = [int(np.asarray(getattr(slots, n)[r])) for n in
+               ("prompt_lens", "limits", "eos_ids", "cached", "produced",
+                "last_tok", "penult")]
+        assert got == [2 + i, 6 + i, 2 if i else -1, i % 2, 0, 0, 0]
+        assert float(slots.temps[r]) == 0.5 * i
+        assert not (bool(slots.done[r]) or bool(slots.poison[r])
+                    or bool(slots.nonfinite[r]))
+    if batch < 4:       # the tenant of row 0 stands
+        assert list(np.asarray(slots.prompts[0])[:3]) == [3, 1, 4]
+        assert int(slots.limits[0]) == 5 and int(slots.tables[0][0]) == 7
+        for r in set(range(1, 4)) - set(rows):
+            assert bool(slots.done[r]) and bool(slots.poison[r])
