@@ -1157,11 +1157,10 @@ def bench_disagg(model_name, batch, long_prompt, short_prompt,
             clk = _BusyClock()
             orig = eng._run_frame_resilient
 
-            def timed(slots, width, steps, greedy, draft, faults, frame):
+            def timed(*frame_args):
                 t0 = time.perf_counter()
                 try:
-                    return orig(slots, width, steps, greedy, draft,
-                                faults, frame)
+                    return orig(*frame_args)
                 finally:
                     clk.t += time.perf_counter() - t0
 
@@ -2232,9 +2231,10 @@ def bench_sim_fidelity(model_name, batch=8, tolerance=0.6,
     prev_mark = [None, 0.0]   # (boundary index, wall stamp) last frame
     orig_rfr = eng._run_frame_resilient
 
-    def timed_rfr(slots, width, cur_steps, greedy, draft, faults, frame):
-        out = orig_rfr(slots, width, cur_steps, greedy, draft, faults,
-                       frame)
+    def timed_rfr(slots, width, steps, cur_steps, greedy, draft, faults,
+                  frame):
+        out = orig_rfr(slots, width, steps, cur_steps, greedy, draft,
+                       faults, frame)
         t1 = time.monotonic()
         if prev_mark[0] == frame - 1:
             # consecutive dispatched boundaries: the delta prices one

@@ -52,7 +52,11 @@ these exactly — change them only together):
   of bytes/N costs the same wire as the psum itself.
 - Inside ``shard_map`` avals are per-shard, so every metric is PER DEVICE.
 - ``while_loop`` trip counts are unknown statically: the body is charged
-  once and ``unbounded_loops`` is flagged in the report.
+  once and ``unbounded_loops`` is flagged in the report. The exception is
+  a counted loop whose bound is an operand (a serving frame's ``n_steps``,
+  ``model_runner._run_steps``): the registry names the trips it traced the
+  program for (``TracedProgram.loop_trips``, the frame's capacity) and the
+  loop is charged as a scan of that length.
 - ``cond`` branches charge the elementwise MAX across branches.
 
 Like the findings baseline, the cost baseline is content-addressed per
@@ -123,7 +127,8 @@ class _Measurer:
     loop-invariant operands (scan consts/xs — the params) count once per
     frame while carries count per step."""
 
-    def __init__(self):
+    def __init__(self, loop_trips: Optional[int] = None):
+        self.loop_trips = loop_trips
         self.flops = 0
         self.hbm_read = 0.0
         self.hbm_write = 0.0
@@ -203,18 +208,31 @@ class _Measurer:
         self._bind(env, eqn.outvars, mult)
 
     def _while(self, eqn, env, mult, axis_sizes):
-        # trip count is dynamic: charge ONE trip and flag it — a serving
-        # program should never contain one (scan with static length is the
-        # compiled-loop idiom), so the report makes it visible
-        self.unbounded_loops += 1
         cn = eqn.params["cond_nconsts"]
         bn = eqn.params["body_nconsts"]
+        cond = eqn.params["cond_jaxpr"].jaxpr
+        body = eqn.params["body_jaxpr"].jaxpr
+        if self.loop_trips is not None and _is_counted(cond):
+            # a counted loop whose bound is an OPERAND (a serving frame's
+            # ``n_steps``): charged as a scan of the trips the registry
+            # names — consts once an execution, a fresh carry every trip
+            trip = self.loop_trips
+            self._charge_reads(env, eqn.invars[:cn + bn], mult)
+            for inner, nconsts in ((cond, cn), (body, bn)):
+                benv = dict(env)
+                for bv in inner.invars[:nconsts]:
+                    benv[bv] = 0           # already charged at the eqn
+                for bv in inner.invars[nconsts:]:
+                    benv[bv] = mult * trip
+                self._walk(inner, benv, mult * trip, axis_sizes)
+            self._bind(env, eqn.outvars, mult)
+            return
+        # trip count is dynamic and nothing names it: charge ONE trip and
+        # flag it — a serving program should never contain such a loop, so
+        # the report makes it visible
+        self.unbounded_loops += 1
         self._charge_reads(env, eqn.invars, mult)
-        for inner, consts, carry in (
-                (eqn.params["cond_jaxpr"].jaxpr, eqn.invars[:cn],
-                 eqn.invars[cn + bn:]),
-                (eqn.params["body_jaxpr"].jaxpr, eqn.invars[cn:cn + bn],
-                 eqn.invars[cn + bn:])):
+        for inner in (cond, body):
             benv = dict(env)
             for bv in inner.invars:
                 benv[bv] = 0
@@ -225,7 +243,7 @@ class _Measurer:
         self._charge_reads(env, eqn.invars, mult)
         branch_costs = []
         for br in eqn.params["branches"]:
-            sub = _Measurer()
+            sub = _Measurer(self.loop_trips)
             benv = {}
             for bv, ov in zip(br.jaxpr.invars, eqn.invars[1:]):
                 benv[bv] = 0               # operands charged at the eqn
@@ -418,8 +436,13 @@ def _base_name(name: str) -> str:
     return name.split("[")[0]
 
 
-def measure_jaxpr(closed) -> _Measurer:
-    m = _Measurer()
+def _is_counted(cond) -> bool:
+    """A ``while``'s condition is ``counter < bound`` and nothing else."""
+    return len(cond.eqns) == 1 and cond.eqns[0].primitive.name == "lt"
+
+
+def measure_jaxpr(closed, loop_trips: Optional[int] = None) -> _Measurer:
+    m = _Measurer(loop_trips)
     m.measure(closed)
     return m
 
@@ -430,7 +453,7 @@ def measure_program(prog: TracedProgram) -> Optional[CostReport]:
     if _trace_failure(prog) is not None:
         return None
     closed = _closed(prog.traced())
-    m = measure_jaxpr(closed)
+    m = measure_jaxpr(closed, prog.loop_trips)
     reads = HOST_READ_OUTPUTS.get(_base_name(prog.name), ())
     out_avals = list(closed.out_avals)
     d2h = sum(_aval_bytes(out_avals[i]) for i in reads

@@ -85,6 +85,9 @@ class TracedProgram:
     retrace: Optional[Callable[[], object]] = None
     variant: str = "exact"               # "exact" | "quantized" | "overlap"
     counterpart: str = ""                # exact twin's name (cost variants)
+    # trips the cost pass charges a counted loop of the program at, where
+    # the loop's bound is an operand (a serving frame's ``n_steps``)
+    loop_trips: Optional[int] = None
 
     _traced: object = dataclasses.field(default=None, repr=False)
     _trace_error: Optional[BaseException] = dataclasses.field(

@@ -93,9 +93,18 @@ def _program(name, builder, args, statics) -> TracedProgram:
     """Wrap one jitted entry point. ``builder()`` must return a FRESH jit
     every call (fresh trace, no jit-cache hit) — check_retrace depends on
     it."""
+    loop_trips = None
+    if name.startswith("frame_loop"):
+        # a frame's trip count is an operand (``n_steps``), handed in as
+        # the serve loop hands it; the cost pass charges the frame's loop
+        # at its capacity
+        loop_trips = statics["steps"]
+        statics = dict(statics, n_steps=loop_trips)
+
     def trace():
         return builder().trace(*args, **statics)
-    prog = TracedProgram(name=name, trace=trace, retrace=trace)
+    prog = TracedProgram(name=name, trace=trace, retrace=trace,
+                         loop_trips=loop_trips)
     try:
         import bisect
         import jax

@@ -58,15 +58,19 @@ class RaggedInferenceEngineConfig:
     prefill_chunk_size: int = 128            # Dynamic SplitFuse chunk
     max_tokens_per_step: int = 512           # token budget per step
     max_tracked_sequences: int = 2048
-    # serve(): steps per device-resident frame. Larger frames amortize the
-    # host boundary further but delay admission of new arrivals by up to
-    # frame_steps decode steps (see README "frame loop" tradeoff).
+    # serve(): the MOST steps a device-resident frame runs. Larger frames
+    # amortize the host boundary further but delay admission of new arrivals
+    # by up to frame_steps decode steps (see README "frame loop" tradeoff).
+    # How many a frame does run is an operand of the frame program, planned
+    # at each boundary: a wide frame whose prompts begin in it ends with its
+    # last prefilling row, so a first token is not held back by steps no
+    # prompt needs.
     frame_steps: int = 8
     # adaptive frame sizing (ROADMAP item (c)): re-pick the frame length
     # each frame from the pow2 bucket set {1, 2, ..., frame_steps} using an
     # EWMA arrival-rate estimate — small frames under bursty TTFT-sensitive
-    # traffic, frame_steps when saturated or drained. The pow2 buckets keep
-    # the frame jit cache O(log) (steps is a static arg).
+    # traffic, frame_steps when saturated or drained. The buckets are the
+    # policy's granularity only: every length runs the same program.
     adaptive_frame_steps: bool = False
     frame_steps_ewma_alpha: float = 0.25
     # speculative decoding (draft/verify on the frame carry): tokens the
@@ -1078,10 +1082,11 @@ class InferenceEngineV2:
 
         ``rng`` (key or int seed) makes sampled runs reproducible: it seeds
         the frame carry's device RNG directly instead of splitting from the
-        engine's stream. ``adaptive_frame_steps`` in the config re-picks the
-        frame length per frame (pow2 buckets up to ``frame_steps``) from an
-        EWMA arrival-rate estimate; an explicit ``frame_steps=`` argument
-        pins it.
+        engine's stream. A frame runs AT MOST ``frame_steps`` steps: a wide
+        frame whose prompts begin in it ends with its last prefilling row
+        (``_plan_frame_steps``; ``_serve_loop``, phase ``plan``). ``adaptive_frame_steps`` in the config re-picks that most
+        per frame (pow2 buckets up to ``frame_steps``) from an EWMA
+        arrival-rate estimate; an explicit ``frame_steps=`` argument pins it.
 
         ``scheduler`` is the admission policy: how waiting requests queue
         and who is admitted next. There is one serve loop and it knows
@@ -1248,12 +1253,46 @@ class InferenceEngineV2:
         for admission), while a saturated table (no free slots: admission
         can't act anyway) or a drained arrival stream gets the full
         ``max_steps`` to amortize the host boundary. Buckets are
-        {pow2 <= max_steps} ∪ {max_steps}, keeping the frame jit cache
-        O(log) in the face of a static ``steps`` argument."""
+        {pow2 <= max_steps} ∪ {max_steps}; the pick is an operand of the
+        frame program (``n_steps``), not a program of its own."""
         if saturated or ewma < 0.125:
             return max_steps
         target = max(1.0, max_steps / (1.0 + ewma))
         return min(BlockedKVCache.floor_pow2(target), max_steps)
+
+    @staticmethod
+    def _plan_frame_steps(cur_steps: int, max_steps: int, live: int,
+                          prefill_steps: int, finish_steps: int,
+                          carried: bool = False) -> int:
+        """How many of the ``cur_steps`` steps the policy allows a frame
+        runs: those in which its rows have something to do.
+
+        A WIDE frame (``prefill_steps`` > 0: what its neediest prefilling row
+        has left, ``DeviceSlotTable.prefill_steps_left``) whose prompts all
+        begin in it ends with its last prefilling row, whose first token
+        then leaves at the step that computed it; the decode steps behind it
+        run narrow in the next frame. It runs half of ``max_steps`` at
+        least: every step given up is a token of the new request that leaves
+        behind its first one and not with it (the first is out sooner, the
+        last no sooner), and under half a frame the boundary weighs on every
+        row that rides it. A frame that takes a prompt over from an earlier
+        one (``carried``, ``DeviceSlotTable.prefill_carried``) runs whole: a
+        first token that has waited a frame or more gains a small share of
+        its wait, and a full table of long prompts loses more to where its
+        tail frames end than the few steps weigh (``PERF.md``, PR 41).
+
+        A NARROW frame ends with the first row to emit the last token of its
+        budget (``finish_steps``, ``DeviceSlotTable.steps_to_first_finish``)
+        where that row would wait at least as many steps for the frame's end
+        as there are ``live`` rows to pay for one more boundary, about a
+        step each: a lone request's answer is complete at the step that
+        computed its last token, a full table keeps its frames."""
+        if prefill_steps:
+            if carried:
+                return cur_steps
+            return min(cur_steps, max(prefill_steps, max_steps // 2))
+        wait = cur_steps - finish_steps
+        return finish_steps if wait >= live else cur_steps
 
     def _validate_arrival(self, uid, toks, limit, in_flight: bool) -> int:
         """serve()'s enqueue-time validation; returns the (possibly
@@ -1588,8 +1627,8 @@ class InferenceEngineV2:
                 self.telemetry.on_fault("nonfinite_repaired")
         return repaired
 
-    def _run_frame_resilient(self, slots, width, cur_steps, greedy, draft,
-                             faults, frame: int):
+    def _run_frame_resilient(self, slots, width, steps, n_steps, greedy,
+                             draft, faults, frame: int):
         """Dispatch one frame under the resilience policy: injected-fault
         hooks, bounded retry with exponential backoff for transient
         dispatch failures (the donated carry is untouched by a
@@ -1607,13 +1646,15 @@ class InferenceEngineV2:
                 t0 = self._clock()
                 if faults is not None:
                     faults.before_dispatch(frame, attempt)
-                # one frame: dispatch, then fetch the (steps, B[, gamma+1])
-                # token/emit pair: the host waiting for the chip, and the
-                # only device->host transfer a frame performs
+                # one frame of ``n_steps`` steps: dispatch, then fetch the
+                # (steps, B[, gamma+1]) token/emit pair: the host waiting
+                # for the chip, and the only device->host transfer a frame
+                # performs
                 with self.telemetry.phase("dispatch"):
                     toks, emit = slots.dispatch_frame(
-                        self.runner, self.params, self.kv, width, cur_steps,
-                        greedy, draft=draft, repair=self._nonfinite_repair)
+                        self.runner, self.params, self.kv, width, steps,
+                        greedy, draft=draft, repair=self._nonfinite_repair,
+                        n_steps=n_steps)
                 with self.telemetry.phase("fetch"):
                     toks, emit = np.asarray(toks), np.asarray(emit)
                 dt_ms = (self._clock() - t0) * 1e3
@@ -1623,7 +1664,7 @@ class InferenceEngineV2:
                         "slow_frame", frame,
                         f"frame took {dt_ms:.1f} ms > watchdog "
                         f"{c.watchdog_frame_ms} ms (width={width} "
-                        f"steps={cur_steps})")
+                        f"steps={n_steps})")
                 return toks, emit
             except Exception as e:        # noqa: BLE001 — bounded + re-raised
                 attempt += 1
@@ -2431,17 +2472,24 @@ class InferenceEngineV2:
                 continue         # arrival gap: poll the clock again
             with tel.phase("plan"):
                 # ---- frame plan: wide while any slot prefills, else pure
-                # decode at width 1 (two shape buckets total; width-1 frames
-                # are the speculative draft/verify frames when a draft
-                # rides). The policy's pressure signal caps the frame length
-                # so admission boundaries come around sooner while
-                # interactive latency is at risk ----
-                width = c.prefill_chunk_size if slots.any_prefilling() else 1
+                # decode at width 1 (two programs in all, the frame's length
+                # their operand; width-1 frames are the speculative
+                # draft/verify frames when a draft rides). The policy's
+                # pressure signal caps the frame length so admission
+                # boundaries come around sooner while interactive latency is
+                # at risk, and a frame ends where its rows have nothing left
+                # to do in it (``_plan_frame_steps``: from the host mirrors,
+                # no device read) ----
+                need = slots.prefill_steps_left(c.prefill_chunk_size)
+                width = c.prefill_chunk_size if need else 1
                 cur_steps = steps
                 saturated = slots.free_slots() == 0
                 if adaptive:
                     cur_steps = self._pick_frame_steps(ewma, steps, saturated)
-                cur_steps = min(cur_steps, sched.frame_steps_cap(steps))
+                cur_steps = self._plan_frame_steps(
+                    min(cur_steps, sched.frame_steps_cap(steps)), steps,
+                    slots.live_count(), need, slots.steps_to_first_finish(),
+                    slots.prefill_carried())
                 tel.on_frame_plan(ewma, saturated, cur_steps)
                 draft = None
                 if speculate and self.draft_model is None:
@@ -2453,8 +2501,8 @@ class InferenceEngineV2:
                     slots.set_poison(faults.poison_uids(boundary))
             with tel.frame_trace(width, cur_steps):
                 toks, emit = self._run_frame_resilient(
-                    slots, width, cur_steps, slots.all_greedy(), draft,
-                    faults, boundary)
+                    slots, width, steps, cur_steps, slots.all_greedy(),
+                    draft, faults, boundary)
             with tel.phase("absorb"):
                 stats_synced = self._sync_frame_stats(
                     slots, width, cur_steps, ewma, sched.queued_count(),
@@ -2464,7 +2512,8 @@ class InferenceEngineV2:
                 # retires it as finished (repair-policy rows survive instead
                 # and get their mirrors resynced after the replay)
                 repaired = self._handle_nonfinite(slots, boundary, sched)
-                emissions, finished = slots.absorb(toks, emit, width)
+                emissions, finished = slots.absorb(toks, emit, width,
+                                                   cur_steps)
                 if repaired:
                     slots.resync_committed(repaired)
                 for uid, new_toks in emissions.items():

@@ -904,7 +904,7 @@ class PagedModelRunner:
         def loop(params, prompts, prompt_lens, limits, eos_ids, temps, tables,
                  cached, produced, last_tok, done, poison, nonfinite, stats,
                  rng, kpool, vpool, hidden=None, *, width, steps, greedy,
-                 repair=False):
+                 repair=False, n_steps=None):
             """One K-step serving FRAME: the resumable generalization of
             ``mixed_loop``. All per-slot state is carry-IN/carry-OUT, so the
             host only touches the loop at frame boundaries (admit arrivals,
@@ -919,8 +919,16 @@ class PagedModelRunner:
             positions go to -1, which the pager routes to the trash block.
             Free slots are rows with ``done=True, limits=0``.
 
-            Returns (tokens (steps, B), emit (steps, B), new carry...). All
-            carry arrays + pools are donated: the frame updates them in
+            ``steps`` (static) is the frame's capacity, the rows of its
+            emissions; ``n_steps`` (an int32 scalar OPERAND, neither static
+            nor donated; ``steps`` where it is left out) is how many of them
+            this frame runs. The serve loop plans it at each boundary from
+            what its rows have left to do, so one program a (width, steps,
+            greedy, repair) runs every frame length.
+
+            Returns (tokens (steps, B), emit (steps, B), new carry...): rows
+            ``[:n_steps]`` are the steps that ran, the rest -1 / not emitted.
+            All carry arrays + pools are donated: the frame updates them in
             place and the outputs ARE the next frame's inputs. ``stats`` is
             the (N_STATS,) in-graph telemetry accumulator — monotonically
             increasing device counters that surface only at frame
@@ -944,8 +952,8 @@ class PagedModelRunner:
             them (each shard accumulates its own replica-consistent copy;
             the boundary reads one).
             """
-            def core(params, prompts, prompt_lens, limits, eos_ids, temps,
-                     tables, cached, produced, last_tok, done, poison,
+            def core(n_steps, params, prompts, prompt_lens, limits, eos_ids,
+                     temps, tables, cached, produced, last_tok, done, poison,
                      nonfinite, stats, rng, kpool, vpool, *hidden):
                 body = _serving_scan_body(fwd, params, prompts, prompt_lens,
                                           limits, eos_ids, temps, tables,
@@ -960,20 +968,20 @@ class PagedModelRunner:
                                           mtp=self.has_mtp)
                 carry = (cached, produced, last_tok, *hidden, done, poison,
                          nonfinite, stats, rng, kpool, vpool)
-                carry, (toks, emit) = jax.lax.scan(body, carry, None,
-                                                   length=steps)
-                return (toks, emit) + carry
+                return _run_steps(body, carry, steps, n_steps,
+                                  cached.shape + ((2,) if hidden else ()))
 
-            args = (params, prompts, prompt_lens, limits, eos_ids, temps,
-                    tables, cached, produced, last_tok, done, poison,
-                    nonfinite, stats, rng, kpool, vpool)
+            args = (_trip_count(n_steps, steps), params, prompts, prompt_lens,
+                    limits, eos_ids, temps, tables, cached, produced,
+                    last_tok, done, poison, nonfinite, stats, rng, kpool,
+                    vpool)
             if tp is None:
                 return core(*args, *(() if hidden is None else (hidden,)))
             assert hidden is None, "a self-draft is not served under tp"
             rep, kv = P(), tp.kv_spec
             return self._tp_call(
                 core, args,
-                (tp.param_specs,) + (rep,) * 14 + (kv, kv),
+                (rep, tp.param_specs) + (rep,) * 14 + (kv, kv),
                 (rep,) * 10 + (kv, kv))
 
         return loop
@@ -997,7 +1005,7 @@ class PagedModelRunner:
         def loop(params, draft_params, prompts, prompt_lens, limits, eos_ids,
                  temps, tables, cached, produced, last_tok, penult, done,
                  poison, nonfinite, stats, rng, kpool, vpool, dkpool, dvpool,
-                 width, steps, greedy, gamma, repair=False):
+                 width, steps, greedy, gamma, repair=False, n_steps=None):
             """Speculative K-step serving frame: ``frame_loop`` with a second
             model riding the carry. Wide (prefill) frames run the target body
             unchanged while the draft ingests the same chunks (its paged KV
@@ -1008,14 +1016,15 @@ class PagedModelRunner:
             still touches the loop only at frame boundaries.
 
             Returns (tokens (steps, B, gamma+1), emit (steps, B, gamma+1),
-            new carry...). ``penult`` is the token at position ``cached - 1``
+            new carry...), ``n_steps`` of them run as in ``frame_loop``.
+            ``penult`` is the token at position ``cached - 1``
             per row; the first draft step of each speculative step re-feeds
             it so the draft cache self-heals after a fully-accepted step
             without a separate catch-up forward."""
-            def core(params, draft_params, prompts, prompt_lens, limits,
-                     eos_ids, temps, tables, cached, produced, last_tok,
-                     penult, done, poison, nonfinite, stats, rng, kpool,
-                     vpool, dkpool, dvpool):
+            def core(n_steps, params, draft_params, prompts, prompt_lens,
+                     limits, eos_ids, temps, tables, cached, produced,
+                     last_tok, penult, done, poison, nonfinite, stats, rng,
+                     kpool, vpool, dkpool, dvpool):
                 body = _serving_scan_body(
                     fwd, params, prompts, prompt_lens, limits, eos_ids,
                     temps, tables, width, greedy,
@@ -1025,12 +1034,12 @@ class PagedModelRunner:
                     latent=self.latent_layers, heads=self.row_heads)
                 carry = (cached, produced, last_tok, penult, done, poison,
                          nonfinite, stats, rng, kpool, vpool, dkpool, dvpool)
-                carry, (toks, emit) = jax.lax.scan(body, carry, None,
-                                                   length=steps)
-                return (toks, emit) + carry
+                return _run_steps(body, carry, steps, n_steps,
+                                  cached.shape + (gamma + 1,))
 
-            args = (params, draft_params, prompts, prompt_lens, limits,
-                    eos_ids, temps, tables, cached, produced, last_tok,
+            args = (_trip_count(n_steps, steps), params, draft_params,
+                    prompts, prompt_lens, limits, eos_ids, temps, tables,
+                    cached, produced, last_tok,
                     penult, done, poison, nonfinite, stats, rng, kpool,
                     vpool, dkpool, dvpool)
             if tp is None:
@@ -1038,7 +1047,7 @@ class PagedModelRunner:
             rep, kv = P(), tp.kv_spec
             return self._tp_call(
                 core, args,
-                (tp.param_specs, draft_runner.tp.param_specs)
+                (rep, tp.param_specs, draft_runner.tp.param_specs)
                 + (rep,) * 15 + (kv, kv, kv, kv),
                 (rep,) * 11 + (kv, kv, kv, kv))
 
@@ -1159,6 +1168,37 @@ class PagedModelRunner:
             f = self._fns.pop(name, None)
             if f is not None and hasattr(f, "_cache_size"):
                 self._evicted_programs += f._cache_size()
+
+
+def _trip_count(n_steps, steps):
+    """A frame's ``n_steps`` operand as an int32 scalar no larger than its
+    capacity ``steps``; the capacity itself where the caller named none."""
+    if n_steps is None:
+        return jnp.asarray(steps, jnp.int32)
+    return jnp.minimum(jnp.asarray(n_steps, jnp.int32), steps)
+
+
+def _run_steps(body, carry, steps, n_steps, shape):
+    """``n_steps`` (a traced int32 scalar, at most the static ``steps``)
+    trips of a serving frame's ``body`` (``_serving_scan_body``'s): what
+    ``lax.scan(body, carry, None, length=n_steps)`` would return, as
+    (tokens, emit, *carry), with the emissions (``shape`` a step) in buffers
+    of ``steps`` rows whose rows past ``n_steps`` stay -1 / not emitted. The
+    trip count is an operand, so one program runs every frame length the
+    serve loop plans; the carry (pools included) is updated in place as a
+    scan's is."""
+    toks = jnp.full((steps,) + shape, -1, jnp.int32)
+    emit = jnp.zeros((steps,) + shape, bool)
+
+    def step(state):
+        i, carry, toks, emit = state
+        carry, (t, e) = body(carry, None)
+        return i + 1, carry, toks.at[i].set(t), emit.at[i].set(e)
+
+    _, carry, toks, emit = jax.lax.while_loop(
+        lambda state: state[0] < n_steps, step,
+        (jnp.zeros((), jnp.int32), carry, toks, emit))
+    return (toks, emit) + carry
 
 
 def commit_scatter(kpool, vpool, chunk_k, chunk_v, block_tables, positions,

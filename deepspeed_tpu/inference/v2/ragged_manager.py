@@ -256,10 +256,13 @@ class DeviceSlotTable:
         # boundaries (stats_delta), so the int32 lanes can never wrap
         # within one read window. Under tp it is replicated like the rest.
         self.stats = self._fresh_stats()
+        # a frame's trip count by value, staged once each (``_trips``)
+        self._trip_scalars: Dict[int, jax.Array] = {}
         # host mirrors — admission control only
         self.uid_of_slot = np.full((n_slots,), -1, np.int64)
         self.slot_of_uid: Dict[int, int] = {}
         self.cached_h = np.zeros((n_slots,), np.int64)
+        self.start_h = np.zeros((n_slots,), np.int64)   # cached_h at admit
         self.plen_h = np.zeros((n_slots,), np.int64)
         self.produced_h = np.zeros((n_slots,), np.int64)
         self.limit_h = np.zeros((n_slots,), np.int64)
@@ -279,6 +282,18 @@ class DeviceSlotTable:
     def _fresh_stats(self):
         return self._dev(zero_stats(self.n_stats))
 
+    def _trips(self, n_steps):
+        """A frame's ``n_steps`` as the device scalar the program takes,
+        staged at a value's first use: a Python int would be one host to
+        device write a dispatch, 0.45 ms a frame on the chip (``PERF.md``,
+        PR 41)."""
+        if n_steps is None:
+            return None
+        n = self._trip_scalars.get(n_steps)
+        if n is None:
+            n = self._trip_scalars[n_steps] = self._dev(np.int32(n_steps))
+        return n
+
     @property
     def committed_h(self) -> np.ndarray:
         """Host mirror of the per-row committed watermark: tokens whose
@@ -294,9 +309,29 @@ class DeviceSlotTable:
     def live_count(self) -> int:
         return self.n_slots - self.free_slots()
 
-    def any_prefilling(self) -> bool:
+    def prefill_steps_left(self, width: int) -> int:
+        """Steps of a ``width``-wide frame until no live row prefills: a
+        prefilling row takes ``min(width, what is left)`` prompt tokens a
+        step whatever the other rows do, so the count is exact (0: nothing
+        prefills, the next frame is narrow)."""
         live = self.uid_of_slot >= 0
-        return bool(np.any(live & (self.cached_h < self.plen_h)))
+        left = np.where(live, self.plen_h - self.cached_h, 0).max(initial=0)
+        return int(-(-left // width))
+
+    def prefill_carried(self) -> bool:
+        """Whether a live row is partway through its prompt: an earlier
+        frame took chunks of it and left some (a row admitted at this
+        boundary, behind a prefix hit too, has begun nothing yet)."""
+        live = self.uid_of_slot >= 0
+        return bool((live & (self.start_h < self.cached_h)
+                     & (self.cached_h < self.plen_h)).any())
+
+    def steps_to_first_finish(self) -> int:
+        """Steps of a narrow frame until the first live row emits the LAST
+        token of its budget, at a token a step: exact without a draft and
+        without an EOS, with either the latest that it can be."""
+        live = self.uid_of_slot >= 0
+        return int(max(1, (self.limit_h - self.produced_h)[live].min()))
 
     def all_greedy(self) -> bool:
         live = self.uid_of_slot >= 0
@@ -348,7 +383,7 @@ class DeviceSlotTable:
             self.uid_of_slot[slot] = uid
             self.slot_of_uid[uid] = slot
             seq.slot = slot
-            self.cached_h[slot] = cached0
+            self.cached_h[slot] = self.start_h[slot] = cached0
             self.plen_h[slot] = len(toks)
             self.produced_h[slot] = 0
             self.limit_h[slot] = limit
@@ -434,9 +469,11 @@ class DeviceSlotTable:
     # ---------------- frame execution + host replay ----------------
 
     def dispatch_frame(self, runner, params, kv, width: int, steps: int,
-                       greedy: bool, draft=None, repair=False):
-        """Dispatch one K-step frame and swap the donated carry in place,
-        returning the (tokens, emit) DEVICE arrays — no host transfer
+                       greedy: bool, draft=None, repair=False, n_steps=None):
+        """Dispatch one frame of ``n_steps`` steps (an operand of the frame
+        program, at most its static capacity ``steps`` and that by default)
+        and swap the donated carry in place, returning the (tokens, emit)
+        DEVICE arrays of ``steps`` rows — no host transfer
         happens here (the telemetry transfer-guard test wraps exactly this
         method). ``draft="self"`` runs the frame in which the model's own
         prediction module drafts (``hidden`` rides the carry, no pool more);
@@ -459,7 +496,8 @@ class DeviceSlotTable:
                 self.eos_ids, self.temps, tables, self.cached,
                 self.produced, self.last_tok, self.done, self.poison,
                 self.nonfinite, self.stats, self.rng, kv.k, kv.v, *hidden,
-                width=width, steps=steps, greedy=greedy, repair=repair)
+                width=width, steps=steps, greedy=greedy, repair=repair,
+                n_steps=self._trips(n_steps))
             if hidden:
                 self.hidden, = hidden
             return toks, emit
@@ -472,7 +510,8 @@ class DeviceSlotTable:
             self.tables, self.cached, self.produced, self.last_tok,
             self.penult, self.done, self.poison, self.nonfinite, self.stats,
             self.rng, kv.k, kv.v, draft_kv.k, draft_kv.v, width=width,
-            steps=steps, greedy=greedy, gamma=gamma, repair=repair)
+            steps=steps, greedy=greedy, gamma=gamma, repair=repair,
+            n_steps=self._trips(n_steps))
         return toks, emit
 
     def set_poison(self, uids: List[int]) -> None:
@@ -559,13 +598,18 @@ class DeviceSlotTable:
         # ``check_stat_range`` holds the largest per-frame delta under
         return delta & 0xFFFFFFFF
 
-    def absorb(self, toks: np.ndarray, emit: np.ndarray, width: int):
+    def absorb(self, toks: np.ndarray, emit: np.ndarray, width: int,
+               n_steps=None):
         """Replay the frame against the host mirrors (same arithmetic as the
         in-graph body) → ({uid: [tokens emitted this frame]}, [finished uids]).
         A row finishes when it emits its EOS or reaches its token limit.
+        ``n_steps``: the steps the frame ran, where fewer than its rows (the
+        rows behind them are empty and did not happen: not replayed).
         Speculative frames hand in (steps, B, gamma+1) token/emit arrays —
         the mirrors replay the variable tokens-per-step emit mask exactly,
         so the committed watermark never needs a device read-back."""
+        if n_steps is not None:
+            toks, emit = toks[:n_steps], emit[:n_steps]
         if emit.ndim == 3:
             return self._absorb_spec(toks, emit, width)
         emissions: Dict[int, List[int]] = {}

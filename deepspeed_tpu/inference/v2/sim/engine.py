@@ -161,6 +161,7 @@ class _SimRow:
     temp: float
     eos: Optional[int]
     cached: int                # committed tokens (prefill watermark)
+    start: int                 # ``cached`` at admission
     gen_base: int              # seq.generated entries predating admission
 
 
@@ -590,7 +591,8 @@ class SimEngine:
                     self._rows[req.uid] = _SimRow(
                         uid=req.uid, plen=len(req.tokens),
                         limit=req.limit, temp=req.temp, eos=req.eos,
-                        cached=int(cached0), gen_base=req.gen_base)
+                        cached=int(cached0), start=int(cached0),
+                        gen_base=req.gen_base)
                     admits.append(req.uid)
                     tel.on_admit(req.uid)
                     self._emit_event("admit", req.uid, cached0=cached0)
@@ -614,14 +616,22 @@ class SimEngine:
                         queued_tokens=sched.queued_prompt_tokens())
                 continue
             # ---- frame plan (real arithmetic, virtual execution) ----
-            width = c.prefill_chunk_size if any(
-                r.cached < r.plen for r in self._rows.values()) else 1
+            rows = self._rows
+            need = -(-max(0, *(r.plen - r.cached for r in rows.values()))
+                     // c.prefill_chunk_size)
+            width = c.prefill_chunk_size if need else 1
             cur_steps = steps
-            saturated = int(n_slots) == len(self._rows)
+            saturated = int(n_slots) == len(rows)
             if adaptive:
                 cur_steps = InferenceEngineV2._pick_frame_steps(
                     ewma, steps, saturated)
-            cur_steps = min(cur_steps, sched.frame_steps_cap(steps))
+            # the engine's own plan of how many of them the frame runs
+            cur_steps = InferenceEngineV2._plan_frame_steps(
+                min(cur_steps, sched.frame_steps_cap(steps)), steps,
+                len(rows), need, max(1, min(
+                    r.limit - (len(self.state.seqs[u].generated) - r.gen_base)
+                    for u, r in rows.items())),
+                any(r.start < r.cached < r.plen for r in rows.values()))
             tel.on_frame_plan(ewma, saturated, cur_steps)
             emissions, finished, first_uids, delta = \
                 self._run_virtual_frame(width, cur_steps, speculate, gamma)

@@ -550,6 +550,10 @@ class ServingTelemetry:
                              requests_retired=0, admission_deferrals=0,
                              requests_shed=0, requests_preempted=0,
                              frames=0, slot_steps_capacity=0,
+                             # steps RUN, by the host's plan (a frame's
+                             # ``n_steps``): in all frames, and in the wide
+                             # ones, which end with their last prefilling row
+                             frame_steps=0, wide_steps=0,
                              # fault-tolerance surface (faults.py): total
                              # faults (kind-labeled), plus the per-kind
                              # headline counters the SLO dashboard plots
@@ -1309,6 +1313,9 @@ class ServingTelemetry:
                 self._inc_labeled("rung_steps", (("tokens", str(tokens)),),
                                   int(n))
         self.counters["frames"] += 1
+        self.counters["frame_steps"] += steps
+        if width > 1:
+            self.counters["wide_steps"] += steps
         self.lifetime_frames += 1
         # run-average occupancy = active_row_steps / slot_steps_capacity
         # (the gauge below is the LAST frame's figure — drain frames sit

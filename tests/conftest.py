@@ -22,6 +22,7 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
+import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 # GRAFT_SANITIZE=1 arms the dynamic sanitizers (see "sanitizer mode"
@@ -233,3 +234,34 @@ def mesh_2x4():
 @pytest.fixture
 def rng():
     return jax.random.PRNGKey(0)
+
+
+@pytest.fixture
+def planned_against_whole(monkeypatch):
+    """``run(eng, arrivals, **serve_kw)``: serve ``arrivals()`` twice on one
+    engine, as the serve loop plans its frames (a frame runs the steps its
+    rows have work for: ``InferenceEngineV2._plan_frame_steps``) and with
+    every frame forced to the whole length (``n_steps = steps``, what every
+    tree before PR 41 ran). Every request's tokens must be the same, the
+    forced run must compile nothing (the length is an operand of the frame
+    programs), and the planned run's ``frame_steps_hist`` is returned with
+    its outputs for the caller to say which frames ended early."""
+    from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
+
+    def run(eng, arrivals, **kw):
+        planned = dict(eng.serve(arrivals(), **kw))
+        hist = dict(eng.serve_stats["frame_steps_hist"])
+        programs = eng.runner.compile_count_total()
+        with monkeypatch.context() as m:
+            m.setattr(InferenceEngineV2, "_plan_frame_steps",
+                      staticmethod(lambda cur_steps, *rest: cur_steps))
+            whole = dict(eng.serve(arrivals(), **kw))
+        assert len(eng.serve_stats["frame_steps_hist"]) == 1   # all whole
+        assert eng.runner.compile_count_total() == programs
+        assert set(planned) == set(whole) and planned
+        for uid in whole:
+            np.testing.assert_array_equal(planned[uid], whole[uid],
+                                          err_msg=f"uid={uid} diverged")
+        return planned, hist
+
+    return run

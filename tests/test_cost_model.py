@@ -49,8 +49,8 @@ def _fixture(name):
     return mod
 
 
-def _measure(fn, *args):
-    return C.measure_jaxpr(jax.make_jaxpr(fn)(*args))
+def _measure(fn, *args, loop_trips=None):
+    return C.measure_jaxpr(jax.make_jaxpr(fn)(*args), loop_trips)
 
 
 # ---------------------------------------------------------------------------
@@ -121,6 +121,33 @@ def test_while_loop_flagged_unbounded():
                                   lambda c: c + 1.0, x)
     m = _measure(f, jnp.zeros((2, 2), jnp.float32))
     assert m.unbounded_loops == 1
+    # what the registry names for counted loops does not bound this one
+    named = _measure(f, jnp.zeros((2, 2), jnp.float32), loop_trips=2)
+    assert named.unbounded_loops == 1
+
+
+def test_counted_loop_with_an_operand_bound_charges_the_named_trips():
+    """A serving frame's steps (``model_runner._run_steps``): a ``while``
+    whose condition is ``counter < bound``, the bound an operand. Named 2
+    trips it is charged as the 2-trip scan above (the const once, the carry
+    a trip); unnamed it stays one trip and flagged."""
+    w = jnp.ones((4, 4), jnp.float32)
+    c0 = jnp.ones((4, 4), jnp.float32)
+
+    def f(w, c0, n):
+        return jax.lax.while_loop(
+            lambda s: s[0] < n, lambda s: (s[0] + 1, jnp.dot(s[1], w)),
+            (jnp.zeros((), jnp.int32), c0))
+
+    m = _measure(f, w, c0, jnp.int32(2), loop_trips=2)
+    assert m.unbounded_loops == 0
+    assert m.flops == 2 * (2 * 4 * 4 * 4)            # one matmul per trip
+    # w once (64 B) and the carry a trip (2 x 64 B) as the scan, and the
+    # loop's scalars (bound, counter, predicate) on top
+    assert 0 < m.hbm_read - (64 + 2 * 64) <= 32
+    assert 2 * 64 <= m.hbm_write <= 2 * 64 + 16
+    one = _measure(f, w, c0, jnp.int32(2))
+    assert one.unbounded_loops == 1 and one.flops == 2 * 4 * 4 * 4
 
 
 # ---------------------------------------------------------------------------

@@ -473,12 +473,14 @@ def test_adaptive_frame_steps_buckets(tiny_model_params):
     hist = e.serve_stats["frame_steps_hist"]
     assert any(k < 8 for k in hist), hist      # shrank under arrivals
     assert 8 in hist, hist                     # recovered when drained
-    assert e.serve_stats["frame_steps_last"] == 8
-    # explicit frame_steps= pins the size even with the config flag on
-    # (the same engine reuses its compiled {4, 8}-step programs)
+    # the run's last frame ends with the last request's last token
+    assert e.serve_stats["frame_steps_last"] < 8
+    # explicit frame_steps= pins the size even with the config flag on: the
+    # wide frame ends with its one-chunk prompt at half of the 4, a narrow
+    # one runs 4 steps, the last ends with the request's eighth token
     dict(e.serve(iter([[(9, rng.integers(0, 200, (4,)).astype(np.int32))]]),
                  max_new_tokens=8, frame_steps=4))
-    assert set(e.serve_stats["frame_steps_hist"]) == {4}
+    assert e.serve_stats["frame_steps_hist"] == {2: 2, 4: 1}
 
 
 def test_generate_degrades_to_stepwise_on_small_pool(tiny_model_params):
@@ -574,7 +576,7 @@ def test_packed_wide_frame_matches_unpacked(pack_engines, n_prefill, rung):
     its live count calls for, and says so in the per-rung counters."""
     packed, whole = pack_engines
     rng = np.random.default_rng(11 + n_prefill)
-    late = {u: rng.integers(0, 200, (100,)).astype(np.int32)
+    late = {u: rng.integers(0, 200, (70,)).astype(np.int32)
             for u in range(n_prefill)}
     early = {u: rng.integers(0, 200, (5,)).astype(np.int32)
              for u in range(n_prefill, 8)}
@@ -596,9 +598,9 @@ def test_packed_wide_frame_matches_unpacked(pack_engines, n_prefill, rung):
     steps = {int(dict(k)["tokens"]): v for k, v in
              packed.telemetry.labeled["rung_steps"].items()}
     assert steps.get(rung, 0) >= 1, steps
-    # a late prompt's last chunk is short, so the same wide frames also
-    # hold steps on lower rungs; with every row late there are two steps
-    assert n_prefill == 8 or len(steps) > 1, steps
+    # a late prompt's last chunk is short (6 tokens), so the same wide
+    # frame also holds a step on a lower rung
+    assert len(steps) > 1, steps
     c = packed.telemetry.counters
     assert c["rung_steps"] == sum(steps.values())
     assert c["positions_computed"] < whole.telemetry.counters[
@@ -719,3 +721,257 @@ def test_admission_is_one_program_whatever_the_batch(batch):
         assert int(slots.limits[0]) == 5 and int(slots.tables[0][0]) == 7
         for r in set(range(1, 4)) - set(rows):
             assert bool(slots.done[r]) and bool(slots.poison[r])
+
+
+# ---------------------------------------------------------------------------
+# a frame runs the steps its rows have work for (``n_steps``, an operand)
+# ---------------------------------------------------------------------------
+
+
+def _spy_frames(monkeypatch):
+    """Every frame the serve loop dispatches: (width, capacity, n_steps,
+    remaining prompt tokens and remaining budget of the live rows by the
+    host mirrors, the program's (tokens, emit) as it returned them, the
+    prompt tokens earlier frames took of each live row)."""
+    from deepspeed_tpu.inference.v2.ragged_manager import DeviceSlotTable
+    seen = []
+    dispatch = DeviceSlotTable.dispatch_frame
+
+    def spy(self, runner, params, kv, width, steps, *a, n_steps=None, **kw):
+        live = self.uid_of_slot >= 0
+        left = np.maximum(self.plen_h - self.cached_h, 0)[live]
+        budget = (self.limit_h - self.produced_h)[live]
+        toks, emit = dispatch(self, runner, params, kv, width, steps, *a,
+                              n_steps=n_steps, **kw)
+        seen.append((width, steps, n_steps, left, budget, toks, emit,
+                     (self.cached_h - self.start_h)[live]))
+        return toks, emit
+
+    monkeypatch.setattr(DeviceSlotTable, "dispatch_frame", spy)
+    return seen
+
+
+def _planned(width, steps, left, budget, began, cap=None):
+    """The plan's two rules, from the text: a wide frame ends with its last
+    prefilling row, at half of ``frame_steps`` at least, unless it takes a
+    prompt over from an earlier frame; a narrow one with the first row to
+    emit its last token, if that row would wait at least as many steps as
+    there are live rows; neither runs more than ``cap``."""
+    cap = steps if cap is None else cap
+    if width > 1 and ((began > 0) & (left > 0)).any():
+        return cap
+    if width > 1:
+        return min(cap, max(steps // 2, -(-int(left.max()) // width)))
+    first = max(1, int(budget.min()))
+    return first if cap - first >= len(budget) else cap
+
+
+@pytest.mark.parametrize("cur,live,prefill,finish,carried,want", [
+    (8, 3, 1, 50, False, 4),    # a one-chunk prompt: half of the frame
+    (8, 3, 3, 50, False, 4),
+    (8, 3, 5, 50, False, 5),    # ends with the last prefilling row
+    (8, 3, 8, 50, False, 8),
+    (8, 3, 16, 50, False, 8),   # a long prompt keeps every step
+    (8, 3, 1, 50, True, 8),     # and its last frame does: a prompt carried
+    (8, 16, 5, 50, True, 8),    # over from an earlier frame runs whole
+    (2, 3, 5, 50, True, 2),
+    (2, 3, 5, 50, False, 2),    # the policy's cap (pressure, adaptive) holds
+    (4, 3, 1, 50, False, 4),
+    (1, 1, 1, 1, False, 1),
+    (8, 1, 0, 3, False, 3),     # narrow: a lone row's last token ends it
+    (8, 5, 0, 3, False, 3),     # 5 steps to wait, 5 rows live
+    (8, 6, 0, 3, False, 8),     # more rows than steps to wait: whole
+    (8, 16, 0, 1, False, 8),    # a full table keeps its frames
+    (8, 1, 0, 8, False, 8),
+    (8, 1, 0, 50, False, 8),
+    (2, 1, 0, 1, False, 1),
+    (2, 2, 0, 1, False, 2),
+])
+def test_plan_frame_steps(cur, live, prefill, finish, carried, want):
+    assert InferenceEngineV2._plan_frame_steps(
+        cur, 8, live, prefill, finish, carried) == want
+
+
+@pytest.fixture(scope="module")
+def chunk128_engine():
+    """The benchmark's frame shape at a small size: chunks of 128, frames
+    of 8 steps, sequences to 2,048."""
+    model = build_model("tiny", max_seq_len=2048)
+    return InferenceEngineV2(model, RaggedInferenceEngineConfig(
+        kv_block_size=64, prefill_chunk_size=128, max_tokens_per_step=1024,
+        dtype="float32", max_ragged_batch_size=4, frame_steps=8,
+        num_kv_blocks=129), params=model.init(jax.random.PRNGKey(0)),
+        max_seq_len=2048)
+
+
+LENGTHS = (1500, 1, 127, 128, 129, 300, 1024)
+
+
+@POLICIES
+def test_planned_frames_emit_the_tokens_of_whole_frames(
+        chunk128_engine, planned_against_whole, monkeypatch, policy):
+    """Prompts of 1 to 1,500 tokens arrive one a boundary while other rows
+    are mid-decode (the longest with them: a serve's slot table grows to
+    its buckets once): every request's greedy tokens are those of a run whose
+    frames all run their 8 steps, every frame ran what the plan says for
+    what the host mirrors showed, one wide and one narrow program served
+    both runs, and what a program returns behind its ``n_steps`` rows is
+    -1 / not emitted."""
+    e = chunk128_engine
+    rng = np.random.default_rng(41)
+    early = [(100 + i, rng.integers(0, 200, (9,)).astype(np.int32), 150)
+             for i in range(2)]
+    late = [(n, rng.integers(0, 200, (n,)).astype(np.int32), 6)
+            for n in LENGTHS]
+
+    def arrivals():
+        yield early + late[:1]
+        for req in late[1:]:
+            yield []
+            yield [req]
+
+    seen = _spy_frames(monkeypatch)
+    planned, hist = planned_against_whole(
+        e, arrivals, max_new_tokens=8, scheduler=policy())
+    assert e.runner.compile_count() == {"frame": 2}
+    assert {len(planned[n]) for n in LENGTHS} == {6}
+    n_planned = sum(hist.values())
+    for width, steps, n, left, budget, toks, emit, began in seen[:n_planned]:
+        assert steps == 8 and (width == 1) == (not left.any())
+        assert n == _planned(width, steps, left, budget, began), (
+            width, left, budget, began)
+        toks, emit = np.asarray(toks), np.asarray(emit)
+        assert toks.shape == emit.shape == (8, 4)
+        assert (toks[n:] == -1).all() and not emit[n:].any()
+        assert ((toks[:n] >= 0) == emit[:n]).all()
+    assert len(seen) > n_planned and all(f[2] == 8
+                                         for f in seen[n_planned:])
+    wide = {f[2] for f in seen[:n_planned] if f[0] > 1}
+    # one to three chunks: half a frame; 1,024 tokens: all 8; 1,500: 8 and,
+    # carried over, 8 for the 4 chunks left
+    assert wide == {4, 8}
+    assert any(f[2] == 8 and 0 < f[3].max() <= 4 * 128 and f[7].any()
+               for f in seen[:n_planned] if f[0] > 1)
+    assert any(f[2] < 8 for f in seen[:n_planned] if f[0] == 1)
+    assert e.kv.free_blocks == e.kv.num_blocks - 1
+
+
+def test_absorb_ignores_rows_past_n_steps(chunk128_engine, monkeypatch):
+    """Whatever sits in the emission buffers behind a frame's ``n_steps``
+    rows did not happen: planted emissions there change no request's
+    tokens and no mirror."""
+    from deepspeed_tpu.inference.v2.ragged_manager import DeviceSlotTable
+    e = chunk128_engine
+    rng = np.random.default_rng(43)
+    reqs = [(u, rng.integers(0, 200, (n,)).astype(np.int32))
+            for u, n in enumerate((5, 200, 70))]
+    want = dict(e.serve(iter([reqs]), max_new_tokens=11))
+    absorb = DeviceSlotTable.absorb
+    planted = []
+
+    def plant(self, toks, emit, width, n_steps=None):
+        toks, emit = toks.copy(), emit.copy()
+        toks[n_steps:], emit[n_steps:] = 7, True
+        planted.append(len(toks) - n_steps)
+        return absorb(self, toks, emit, width, n_steps)
+
+    monkeypatch.setattr(DeviceSlotTable, "absorb", plant)
+    got = dict(e.serve(iter([reqs]), max_new_tokens=11))
+    assert any(planted)
+    for u in want:
+        np.testing.assert_array_equal(got[u], want[u])
+    assert e.kv.free_blocks == e.kv.num_blocks - 1
+
+
+def test_the_operand_is_staged_once_a_value():
+    """``dispatch_frame`` hands the program its trip count as an int32
+    device scalar staged at the value's first use, not as a Python int (one
+    host to device write a dispatch); ``None`` (run the capacity) stays so."""
+    from deepspeed_tpu.inference.v2 import ragged_manager as rm
+    slots = rm.DeviceSlotTable(4, 8, 2, jax.random.PRNGKey(0))
+    three = slots._trips(3)
+    assert isinstance(three, jax.Array) and three.dtype == np.int32
+    assert three.shape == () and int(three) == 3
+    assert slots._trips(3) is three and slots._trips(8) is not three
+    assert slots._trips(None) is None and set(slots._trip_scalars) == {3, 8}
+
+
+def test_a_prefix_hit_plans_from_what_is_left(tiny_model_params, monkeypatch):
+    """A prompt of seven chunks whose first five are in the prefix cache is
+    admitted at ``cached0`` = 80: its wide frame runs the half frame its two
+    chunks left ask for, not the seven steps the whole prompt would."""
+    model, params = tiny_model_params
+    e = _engine(model, params, prefix_cache=True, frame_steps=8)
+    rng = np.random.default_rng(44)
+    head = rng.integers(0, 200, (80,)).astype(np.int32)
+    first = np.concatenate([head, rng.integers(0, 200, (32,)).astype(np.int32)])
+    again = np.concatenate([head, rng.integers(0, 200, (32,)).astype(np.int32)])
+    seen = _spy_frames(monkeypatch)
+    got = dict(e.serve(iter([[(0, first)], [], [], [(1, again)]]),
+                       max_new_tokens=4))
+    assert e.telemetry.counters["prefix_hits"] == 1
+    wide = [(f[2], int(f[3].max())) for f in seen if f[0] > 1]
+    assert wide == [(7, 112), (4, 32)]
+    alone = _engine(model, params, frame_steps=8)
+    want = dict(alone.serve(iter([[(1, again)]]), max_new_tokens=4))
+    np.testing.assert_array_equal(got[1], want[1])
+
+
+@pytest.mark.parametrize("how", ["adaptive", "pressure"])
+def test_the_plan_runs_under_the_policys_cap(tiny_model_params, monkeypatch,
+                                             how):
+    """What ``_pick_frame_steps`` (adaptive sizing) and
+    ``frame_steps_cap`` (a scheduler under SLO pressure) return is the most
+    a frame runs, and an operand like the plan's own count: every frame
+    runs ``min(cap, what its rows need)``, through the two programs of an
+    engine that does neither."""
+    model, params = tiny_model_params
+    e = _engine(model, params, frame_steps=8,
+                adaptive_frame_steps=how == "adaptive")
+    caps = []
+    if how == "adaptive":
+        pick = InferenceEngineV2._pick_frame_steps
+        monkeypatch.setattr(
+            InferenceEngineV2, "_pick_frame_steps", staticmethod(
+                lambda *a: caps.append(pick(*a)) or caps[-1]))
+        sched = None
+    else:
+        sched = RequestScheduler()
+        monkeypatch.setattr(
+            RequestScheduler, "frame_steps_cap",
+            lambda self, most: caps.append((2, most, 1)[len(caps) % 3])
+            or caps[-1])
+    rng = np.random.default_rng(45)
+
+    def arrivals():
+        for k, n in enumerate((60, 40, 4, 20, 50, 9)):   # one a poll
+            yield [(k, rng.integers(0, 200, (n,)).astype(np.int32))]
+
+    seen = _spy_frames(monkeypatch)
+    got = dict(e.serve(arrivals(), max_new_tokens=64, scheduler=sched))
+    assert len(got) == 6 and all(len(v) == 64 for v in got.values())
+    assert len(caps) == len(seen) and len(set(caps)) > 1
+    for cap, (width, steps, n, left, budget, *_, began) in zip(caps, seen):
+        assert n == _planned(width, steps, left, budget, began, cap), (
+            cap, width)
+    assert any(n < cap for cap, (_, _, n, *_) in zip(caps, seen))
+    assert e.runner.compile_count() == {"frame": 2}
+
+
+@pytest.mark.parametrize("which", ["self_draft_engine",
+                                   "distinct_draft_engine"])
+def test_planned_frames_under_an_external_draft(request, which,
+                                                planned_against_whole,
+                                                greedy_base):
+    """``frame_loop_spec`` runs its steps under the same operand: with a
+    draft that is always accepted (rows reach their budget sooner than a
+    token a step, so a narrow frame's planned end is the latest it can be)
+    and one that never is, the tokens are those of whole frames and of the
+    run without a draft."""
+    e = request.getfixturevalue(which)
+    planned, hist = planned_against_whole(
+        e, _mid_stream_arrivals, max_new_tokens=8, gamma=2)
+    for u in SPEC_PROMPTS:
+        np.testing.assert_array_equal(greedy_base[u], planned[u])
+    assert min(hist) < 4 and set(e.runner.compile_count()) == {"spec_frame"}
+    assert e.kv.free_blocks == e.kv.num_blocks - 1

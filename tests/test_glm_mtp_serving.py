@@ -746,3 +746,23 @@ def test_reference_against_transformers(glm, reference):
     # to 6; every planted fault above reads over 0.02
     assert np.abs(want).max() > 2.0
     assert np.abs(got - want).max() < 2e-3, np.abs(got - want).max()
+
+
+def test_planned_frames_emit_the_tokens_of_whole_frames(
+        glm, planned_against_whole):
+    """``hidden`` in the carry and two columns a step in a frame whose step
+    count is an operand: the model drafting for itself gives every request
+    the tokens of a run whose frames all run their 4 steps (the wide frames
+    through ``_serving_scan_body``, the narrow ones through
+    ``_self_spec_scan_body``), through the same two programs."""
+    model, params = glm
+    e = engine(model, params)
+    prompts = prompts_of((33, 5, 16, 1, 40, 23), seed=7)
+    reqs = [(u, p, 7 + u) for u, p in prompts.items()]
+    planned, hist = planned_against_whole(
+        e, lambda: iter([reqs[:1], [], reqs[1:3], [], [], reqs[3:]]))
+    assert {u: len(t) for u, t in planned.items()} \
+        == {u: 7 + u for u in prompts}
+    assert {2, 3} & set(hist) and max(hist) == 4
+    assert e.runner.compile_count() == {"frame": 2}
+    assert e.kv.free_blocks == e.kv.num_blocks - 1 and not e.state.seqs

@@ -493,3 +493,21 @@ def test_reference_against_transformers(whole, reference):
     # every planted fault above reads over 0.02
     assert np.abs(want).max() > 2.0
     assert np.abs(got - want).max() < 2e-3, np.abs(got - want).max()
+
+
+def test_planned_frames_emit_the_tokens_of_whole_frames(
+        whole, planned_against_whole):
+    """A latent pool (``vpool`` None in the carry) in a frame whose step
+    count is an operand: a one-chunk prompt's wide frame runs one of its two
+    steps, a three-chunk prompt's both, and every request's tokens are
+    those of whole frames."""
+    model, params = whole
+    e = engine(tiny_longcat(4), share_of(params, 0, 4))
+    rng = np.random.default_rng(4)
+    reqs = [(u, rng.integers(0, 256, n).tolist(), limit)
+            for u, n, limit in ((0, 17, 7), (1, 130, 4), (2, 60, 6))]
+    planned, hist = planned_against_whole(
+        e, lambda: iter([[r] for r in reqs]))
+    assert {u: len(t) for u, t in planned.items()} == {0: 7, 1: 4, 2: 6}
+    assert set(hist) == {1, 2}
+    assert e.kv.free_blocks == e.kv.num_blocks - 1 and not e.state.seqs

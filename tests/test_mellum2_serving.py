@@ -506,3 +506,23 @@ def test_stepwise_api_serves_a_patterned_model_by_kind(pattern, api):
     assert not by_kind.state.seqs
     assert [g.free_blocks for g in by_kind.kv.groups] == [
         g.num_blocks - 1 for g in by_kind.kv.groups]
+
+
+def test_planned_frames_emit_the_tokens_of_whole_frames(
+        model_params, planned_against_whole):
+    """Tables by kind and rings in the carry of a frame whose step count is
+    an operand: prompts of one to nine chunks arriving while others decode
+    get the tokens of a run whose frames all run their 8 steps, through the
+    same two programs, and both kinds drain."""
+    e = engine(*model_params, frame_steps=8)
+    rng = np.random.default_rng(78)
+    reqs = [(uid, rng.integers(0, 256, n).astype(np.int32), limit)
+            for uid, n, limit in ((21, 70, 9), (22, 9, 14), (23, 33, 6),
+                                  (24, 8, 5))]
+    planned, hist = planned_against_whole(
+        e, lambda: iter([batch for r in reqs for batch in ([r], [])]))
+    assert {u: len(t) for u, t in planned.items()} \
+        == {21: 9, 22: 14, 23: 6, 24: 5}
+    assert 4 in hist and max(hist) == 8      # 9 chunks: 8 steps; 1-5: half
+    for g in e.kv.groups:
+        assert g.free_blocks == g.num_blocks - 1

@@ -408,14 +408,15 @@ def test_snapshot_restore_parity_without_crash(tiny_model_params,
         break
     gen.close()                              # abandon: cleanup must not
     _assert_clean(e)                         # invalidate the snapshot
-    # the first retirement (uid 0, smallest budget) lands before the
-    # abandoned generator ever polls uids 2/3 off the arrival schedule, so
-    # the snapshot covers exactly the other in-flight request
-    assert {r["uid"] for r in snap["requests"]} == {1}
+    # the first retirement (uid 0, smallest budget) lands after the
+    # generator polled uid 2 off the arrival schedule (the first wide frame
+    # ends with its prompts' last chunk) and before it polls uid 3, so the
+    # snapshot covers exactly the other two in-flight requests
+    assert {r["uid"] for r in snap["requests"]} == {1, 2}
     e2 = _engine(model, params)
     rest = dict(e2.serve(iter([[]]), max_new_tokens=8, resume_from=snap))
     collected.update(rest)
-    assert set(collected) == {0, 1}
+    assert set(collected) == {0, 1, 2}
     for u in collected:
         np.testing.assert_array_equal(fault_free_base[u], collected[u],
                                       err_msg=f"uid={u}")

@@ -556,7 +556,11 @@ def test_frame_steps_decision_trace(served_engine):
     assert len(trace) == e.serve_stats["frames"]
     for rec in trace:
         assert set(rec) == {"frame", "ewma", "saturated", "steps"}
-        assert rec["steps"] == 4           # fixed frame_steps, no pressure
+    # fixed frame_steps, no pressure: the wide frame ends with its prompt's
+    # one chunk, at half the length at least (the request's second token
+    # leaves with its first), and the narrow frame behind it runs the whole
+    # length to the sixth
+    assert [rec["steps"] for rec in trace] == [2, 4]
     assert [rec["frame"] for rec in trace] == list(range(len(trace)))
     prom = e.telemetry.render_prometheus()
     assert "ds_serving_frame_steps_chosen 4" in prom

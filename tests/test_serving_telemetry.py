@@ -22,9 +22,7 @@ import pytest
 from deepspeed_tpu.inference.v2.engine_v2 import (InferenceEngineV2,
                                                   RaggedInferenceEngineConfig)
 from deepspeed_tpu.inference.v2.ragged_manager import DeviceSlotTable
-from deepspeed_tpu.inference.v2.telemetry import (TILE_STAT_NAMES,
-                                                  TTFT_COUNTERS,
-                                                  LogBucketHistogram,
+from deepspeed_tpu.inference.v2.telemetry import (LogBucketHistogram,
                                                   ServingTelemetry)
 from deepspeed_tpu.models import build_model
 from deepspeed_tpu.utils.logging import logger as ds_logger
@@ -161,6 +159,75 @@ def test_counters_match_host_replay(served):
     assert last["serving/positions_computed"] == c["positions_computed"] > 0
 
 
+def _layer_reader(name):
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "perfbench", "layer_metrics",
+        f"{name}.py")
+    spec = importlib.util.spec_from_file_location(f"reader_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def test_counters_count_the_steps_that_ran(tiny_model_params, monkeypatch):
+    """A fixed arrival list served as the loop plans its frames and with
+    every frame forced whole: ``frame_steps_hist``, ``frame_steps`` /
+    ``wide_steps``, ``slot_steps_capacity`` and ``positions_computed``
+    count the steps each run RAN; the work is the same, so the benchmark's
+    ``useful_position_share`` and ``slot_occupancy`` read the same up to
+    the steps saved, and ``wide_step_share`` reads the saving."""
+    model, params = tiny_model_params
+    e = _engine(model, params, frame_steps=8)
+    rng = np.random.default_rng(46)
+    reqs = [(u, rng.integers(0, 200, (n,)).astype(np.int32))
+            for u, n in enumerate((60, 7, 24, 33, 5, 90))]
+    slots, runs = 8, {}
+    for how in ("planned", "whole"):
+        if how == "whole":
+            monkeypatch.setattr(InferenceEngineV2, "_plan_frame_steps",
+                                staticmethod(lambda cur, *rest: cur))
+        frames = []
+        e.telemetry.attach_monitor(type("Log", (), {"write_events": (
+            lambda self, ev: frames.append((
+                e.telemetry.counters["prefill_tokens"],
+                e.serve_stats["frame_steps_last"])))})())
+        outs = dict(e.serve(iter([[r] for r in reqs]), max_new_tokens=12))
+        c, hist = dict(e.telemetry.counters), e.serve_stats["frame_steps_hist"]
+        steps = sum(k * n for k, n in hist.items())
+        assert c["frame_steps"] == steps and c["frames"] == sum(hist.values())
+        assert c["slot_steps_capacity"] == slots * steps
+        narrow = c["frame_steps"] - c["wide_steps"]
+        assert c["positions_computed"] == slots * (
+            CHUNK * c["wide_steps"] + narrow)
+        text = e.telemetry.render_prometheus()
+        assert f"ds_serving_wide_steps_total {c['wide_steps']}" in text
+        assert f"ds_serving_frame_steps_total {steps}" in text
+        # the monitor's rows, as perfbench's FrameLog keeps them
+        rows = [(0.0, 0, p - q, n) for (q, _), (p, n)
+                in zip([(0, 0)] + frames, frames)]
+        assert sum(r[3] for r in rows) == steps
+        runs[how] = (outs, c, _layer_reader("wide_step_share")(
+            {"frames": rows}))
+    (outs, c, share), (outs_w, w, share_w) = runs["planned"], runs["whole"]
+    for u in outs_w:
+        np.testing.assert_array_equal(outs[u], outs_w[u])
+    for name in ("prefill_tokens", "target_forwards", "active_row_steps",
+                 "tokens_emitted"):
+        assert c[name] == w[name], name
+    assert c["wide_steps"] < w["wide_steps"]
+    assert c["frame_steps"] < w["frame_steps"]
+    assert share < share_w == pytest.approx(
+        100.0 * w["wide_steps"] / w["frame_steps"])
+    for name, total in (("useful_position_share", "positions_computed"),
+                        ("slot_occupancy", "slot_steps_capacity")):
+        read = _layer_reader(name)
+        assert read({"counters": c}) * c[total] == pytest.approx(
+            read({"counters": w}) * w[total])
+        assert read({"counters": c}) > read({"counters": w})
+
+
 def test_row_tile_counters_match_host_replay():
     """``attn_row_tiles`` / ``attn_row_tiles_live``: what the wide paged
     kernel had to cut and what it computed, one layer's, replayed on the
@@ -181,17 +248,20 @@ def test_row_tile_counters_match_host_replay():
                 for u, n in plens.items()]
     outs = dict(e.serve(iter(arrivals), max_new_tokens=new))
     assert {len(v) for v in outs.values()} == {new}
-    # frame 1 (uid 0 alone): its 10 prompt tokens, then three decode steps
-    # riding the wide frame. Frame 2: uid 0 rides four more steps while
-    # uid 1 consumes a full chunk, its last 37 tokens, and rides two. Every
-    # later frame is narrow and cuts nothing.
-    wide = [[10, 1, 1, 1], [1, 1, 1, 1], [chunk, 37, 1, 1]]
+    # frame 1 (uid 0 alone): its 10 prompt tokens and one decode step: half
+    # of the four. Frame 2: uid 0 rides two steps while uid 1 consumes a
+    # full chunk and its last 37 tokens, and the frame ends. Every later
+    # frame is narrow and cuts nothing: four steps, then three to uid 1's
+    # last token (uid 0's is the fourth of the four: nothing to wait for).
+    wide = [[10, 1], [1, 1], [chunk, 37]]
     kvh, group, tile = 2, 4, 128
     live = sum(kvh * -(-w * group // tile) for row in wide for w in row)
     c = e.telemetry.counters
-    assert c["attn_row_tiles"] == 2 * steps * slots * kvh * (chunk * group
-                                                             // tile)
-    assert c["attn_row_tiles_live"] == live == 2 * (4 + 4 + 6)
+    assert e.serve_stats["frame_steps_hist"] == {2: 2, 3: 1, steps: 1}
+    assert (c["wide_steps"], c["frame_steps"]) == (2 + 2, 2 + 2 + 4 + 3)
+    assert c["attn_row_tiles"] == (2 + 2) * slots * kvh * (chunk * group
+                                                           // tile)
+    assert c["attn_row_tiles_live"] == live == 2 * (2 + 2 + 4)
     text = e.telemetry.render_prometheus()
     assert f"ds_serving_attn_row_tiles_live_total {live}" in text
     assert f"ds_serving_attn_row_tiles_total {c['attn_row_tiles']}" in text
@@ -501,9 +571,14 @@ def _fifo_tuple_serve_record(e):
     slots so that requests queue and admissions defer: the retirement
     order, ``snapshot_serving_state()`` taken mid-serve (every field but
     the clock's), and ``render_prometheus()`` as the set of series (name
-    and labels) plus every counter's value. The golden file is this
+    and labels) plus every counter's value. The golden file was this
     function's result at b6ef6c1, the last tree with ``_serve_loop`` for
-    FIFO and ``_serve_loop_sched`` for a scheduler."""
+    FIFO and ``_serve_loop_sched`` for a scheduler, and the one loop gave
+    it to the digit until PR 41 planned how many steps a frame runs: that
+    moved where frames end, so the boundary the snapshot is taken at,
+    ``frames``, ``positions_computed`` and the work's split by width, and
+    nothing a policy writes (the order, every request's tokens, no label).
+    Recorded again there, with the series younger than b6ef6c1."""
     rng = np.random.default_rng(5)
     prompts = {u: rng.integers(0, 200, (n,)).astype(np.int32)
                for u, n in {0: 7, 1: 24, 2: 33, 3: 5}.items()}
@@ -546,15 +621,8 @@ def test_fifo_tuple_serve_matches_the_fifo_loop(tiny_model_params):
     assert [r["uid"] for r in got["snapshot"]["requests"]] and all(
         r["tenant"] is None and r["priority"] is None and r["slo_ms"] is None
         for r in got["snapshot"]["requests"])
-    # series younger than the golden tree: the TTFT stage counters, which
-    # stay 0 on an engine that no trace collector is attached to, and the
-    # wide steps' row tiles, of which a chunk of 16 x 1 rows has none
-    younger = {f"ds_serving_{n}_total": "0"
-               for n in TTFT_COUNTERS + TILE_STAT_NAMES}
-    assert [s for s in got["series"] if s not in younger] == want["series"]
-    assert younger.keys() <= set(got["series"])
-    assert got["counters"] == {**want["counters"], **{
-        k: v for k, v in younger.items() if not k.endswith(_UNPINNED)}}
+    assert got["series"] == want["series"]
+    assert got["counters"] == want["counters"]
     assert got["counters"]["ds_serving_admission_deferrals_total"] != "0"
 
 
@@ -634,7 +702,8 @@ def test_telemetry_disabled_keeps_serve_stats_shape(served):
         e.telemetry.enabled = True
     np.testing.assert_array_equal(got[0], outs[0])
     view = e.serve_stats
-    assert view["frames"] >= 1 and view["frame_steps_last"] == 4
+    assert view["frames"] >= 1 and 4 in view["frame_steps_hist"]
+    assert view["frame_steps_last"] in view["frame_steps_hist"]
     assert e.telemetry.counters["tokens_emitted"] == 0   # host path idle
     assert e.telemetry.hists["ttft"].total == 0
 

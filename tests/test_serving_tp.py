@@ -295,3 +295,17 @@ def test_tp_vocab_fallback_replicates(tp_model_params):
     base = dict(e1.serve(iter([[(0, p)]]), max_new_tokens=MAX_NEW))
     got = dict(e8.serve(iter([[(0, p)]]), max_new_tokens=MAX_NEW))
     np.testing.assert_array_equal(base[0], got[0])
+
+
+def test_tp8_planned_frames_emit_the_tokens_of_whole_frames(
+        tp8_engine, greedy_base, planned_against_whole):
+    """The frame's step count is a replicated operand of the ``shard_map``
+    program: the sharded engine's planned frames give the tokens of whole
+    frames and of the tp=1 run, through the programs it had."""
+    planned, hist = planned_against_whole(
+        tp8_engine, _mid_stream_arrivals, max_new_tokens=MAX_NEW)
+    for u in PROMPTS:
+        np.testing.assert_array_equal(greedy_base[u], planned[u],
+                                      err_msg=f"uid={u} diverged")
+    assert min(hist) < 4
+    assert tp8_engine.kv.free_blocks == tp8_engine.kv.num_blocks - 1

@@ -242,3 +242,33 @@ def test_calibrate_from_boundaries_fits_per_program(tmp_path):
     r1 = FleetSimulator(small_cfg(calibration=loaded)).run(trace)
     r2 = FleetSimulator(small_cfg(calibration=loaded)).run(trace)
     assert r1.event_lines() == r2.event_lines()
+
+
+def test_sim_plans_a_frames_steps_as_the_engine_does():
+    """``SimEngine`` prices a frame by its steps: it asks the engine's own
+    plan (``InferenceEngineV2._plan_frame_steps``) how many a frame runs,
+    so a wide frame of its virtual serve ends with its last prefilling row
+    (at half a frame at least) unless it takes a prompt over from the frame
+    before, and a narrow one with a lone row's last token, and the counters
+    say so as the server's do."""
+    from deepspeed_tpu.inference.v2.engine_v2 import (
+        RaggedInferenceEngineConfig)
+    from deepspeed_tpu.inference.v2.scheduler import RequestScheduler
+    from deepspeed_tpu.inference.v2.sim.engine import SimEngine
+    eng = SimEngine(config=RaggedInferenceEngineConfig(
+        max_ragged_batch_size=4, frame_steps=4, prefill_chunk_size=16),
+        max_seq_len=256)
+    arrivals = [[{"uid": 0, "tokens": list(range(20))},
+                 {"uid": 1, "tokens": list(range(40))}],
+                [{"uid": 2, "tokens": list(range(100))}]]
+    got = dict(eng.serve(iter(arrivals), max_new_tokens=7,
+                         scheduler=RequestScheduler()))
+    assert {u: len(t) for u, t in got.items()} == {0: 7, 1: 7, 2: 7}
+    trace = [r["steps"] for r in eng.telemetry.steps_trace]
+    # 40 tokens: three steps; then 100 tokens: four steps and, carried over,
+    # four for the three chunks left; a whole narrow frame; uid 2's last
+    # token ends one at its first step
+    assert trace == [3, 4, 4, 4, 1]
+    c = eng.telemetry.counters
+    assert (c["wide_steps"], c["frame_steps"]) == (3 + 4 + 4, sum(trace))
+    assert eng.virtual_steps == sum(trace)

@@ -443,8 +443,10 @@ def test_work_counters_equal_the_host_mirror(window, tp, monkeypatch):
     orig = DeviceSlotTable.dispatch_frame
 
     def spy(self, runner, params, kv, width, steps, greedy, **kw):
-        kv_read, pairs, positions, rungs = _mirror_frame(self, width, steps,
-                                                         window)
+        # the steps the frame runs (its ``n_steps`` operand), not the
+        # ``steps`` rows of its emission buffers
+        kv_read, pairs, positions, rungs = _mirror_frame(
+            self, width, kw["n_steps"], window)
         split = "wide" if width > 1 else "narrow"
         want[f"kv_positions_read_{split}"] += kv_read
         want[f"attn_pairs_{split}"] += pairs
@@ -551,13 +553,28 @@ def test_spans_reach_the_profiler(tiny_model_params, tmp_path):
     names = {n for n, _ in events}
     assert {f"serve/{n}" for n in PHASES if n != "publish"} <= names
     assert "serve/publish" in names
-    assert any(n.startswith("serve_frame/w16/s4") for n in names)
+    # the wide frame ends with uid 1's second chunk of 16, the narrow
+    # frames behind it run the engine's 4 steps or end with a last token
+    assert any(n.startswith("serve_frame/w16/s2") for n in names)
+    assert any(n.startswith("serve_frame/w1/s4") for n in names)
+    assert not any(n.startswith("serve_frame/w16/s4") for n in names)
     assert {"train_batch", "train/stage", "train/dispatch"} <= names
     steps = [s for n, s in events if n == "train_batch"]
     assert steps and steps[0]["step_num"] == 0
     work = [s for n, s in events if n == "serve/frame_work"]
     c = e.telemetry.counters
     assert len(work) == c["frames"]
+    # a frame is one length everywhere it is told: the plan's trace, the
+    # span's ``steps``, the histogram, the step counters, and the positions
+    # the device counted (8 slots x width a step: a chunk of 16 has one rung)
+    ran = [(int(w["width"]), int(w["steps"])) for w in work]
+    assert ran[0] == (16, 2) and (1, 4) in ran[1:]
+    assert {w for w, _ in ran[1:]} == {1}
+    assert [s for _, s in ran] \
+        == [r["steps"] for r in e.serve_stats["frame_steps_trace"]]
+    assert e.serve_stats["frame_steps_last"] == ran[-1][1]
+    assert (c["wide_steps"], c["frame_steps"]) == (2, sum(s for _, s in ran))
+    assert c["positions_computed"] == sum(8 * w * s for w, s in ran)
     assert sum(int(w["prefill_tokens"]) for w in work) == c["prefill_tokens"]
     assert sum(int(w["attn_pairs"]) for w in work) \
         == c["attn_pairs_narrow"] + c["attn_pairs_wide"]
