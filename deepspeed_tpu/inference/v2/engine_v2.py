@@ -331,7 +331,15 @@ class InferenceEngineV2:
                                      num_blocks=num_blocks, block_size=bs,
                                      dtype=cfg.act_dtype, latent=True)
         elif kinds is None:
-            self.kv = BlockedKVCache(cfg.num_layers, cfg.kv_heads, cfg.dims_per_head,
+            if cfg.linear_layers:
+                # linear layers cache no keys: the pool is the full layers'
+                from .model_implementations.archs import \
+                    validate_recurrent_serving
+                validate_recurrent_serving(c, cfg,
+                                           draft=draft_model is not None)
+            self.kv = BlockedKVCache(cfg.cache_layers if cfg.linear_layers
+                                     else cfg.num_layers, cfg.kv_heads,
+                                     cfg.dims_per_head,
                                      num_blocks=num_blocks, block_size=bs,
                                      dtype=cfg.act_dtype, kv_dtype=c.kv_dtype)
         else:
@@ -558,6 +566,11 @@ class InferenceEngineV2:
                 f"{what}: this model keeps one pool of latent rows "
                 "(kv_cache.BlockedKVCache(latent=True)); it is served "
                 "without a draft, a swap tier or a prefix cache")
+        if self.model.cfg.linear_layers:
+            raise NotImplementedError(
+                f"{what}: this model's linear layers keep a recurrent state "
+                "a slot, which is no page; it is served without a draft, a "
+                "swap tier or a prefix cache")
 
     def attach_kv_tier(self, tier, tag: Optional[str] = None) -> None:
         """Attach an EXTERNAL (typically shared) ``KVSwapTier`` — the
@@ -1173,7 +1186,9 @@ class InferenceEngineV2:
             n_stats=self.runner.n_stats,
             rings=[ring for _, ring in self.state.rings],
             hidden=(self.model.cfg.hidden_size, self.model.cfg.act_dtype)
-            if speculate and self_draft else None)
+            if speculate and self_draft else None,
+            recurrent=self.runner.recurrent_shapes(n_slots)
+            if self.runner.linear_layers else ())
         if faults is not None:
             faults.begin_serve()     # rearm the scripted schedule
         if self.prefix_cache is not None:
@@ -1206,10 +1221,16 @@ class InferenceEngineV2:
                                    kv_blocks_total=self.kv.num_blocks,
                                    tp_degree=self._config.tp,
                                    kv_block_bytes=self.kv.block_bytes,
-                                   layered=self.runner.kinds is not None,
+                                   layered=self.runner.layer_work is not None,
                                    latent=bool(self.model.cfg.latent_lanes),
                                    share=self.model.cfg.moe_is_share,
-                                   mtp=self.runner.has_mtp)
+                                   mtp=self.runner.has_mtp,
+                                   recurrent_slot_bytes=sum(
+                                       math.prod(shape)
+                                       * jnp.dtype(dtype).itemsize
+                                       for shape, dtype in
+                                       self.runner.recurrent_shapes(1))
+                                   if self.runner.linear_layers else 0)
         sched = FifoPolicy() if scheduler is None else scheduler
         sched.begin_serve(self)
         return self._serve_guarded(slots, arrivals, sched, steps,
@@ -1346,7 +1367,7 @@ class InferenceEngineV2:
                 live_slots=slots.live_count(),
                 kv_blocks_in_use=self.kv.num_blocks - self.kv.free_blocks,
                 arrival_ewma=ewma, queue_depth=queue_depth,
-                kv_kinds=self.kv.in_use() if self.runner.kinds
+                kv_kinds=self.kv.in_use() if self.runner.layer_work
                 or self.kv.latent else None)
             return True
         if tel.enabled:

@@ -160,10 +160,12 @@ class BlockedKVCache:
                 * per_row)
 
     def in_use(self):
-        """``LayeredKVCache.in_use`` of the latent format's one kind."""
+        """``LayeredKVCache.in_use`` of a cache of one kind: the latent
+        format's, or the full-attention layers' of a model whose other
+        layers are linear."""
         pages = self.num_blocks - self.free_blocks - 1
-        return ([("latent", pages, self.block_bytes)],
-                pages * self.block_size)
+        return ([("latent" if self.latent else "full", pages,
+                  self.block_bytes)], pages * self.block_size)
 
     def blocks_for(self, num_tokens: int) -> int:
         return (num_tokens + self.block_size - 1) // self.block_size

@@ -1427,6 +1427,47 @@ def validate_layered_serving(engine_config, draft: bool = False) -> None:
             "kind and cannot be served with: " + "; ".join(probs))
 
 
+def validate_recurrent_serving(engine_config, cfg: TransformerConfig,
+                               draft: bool = False) -> None:
+    """Fail LOUDLY at engine build for what a model with linear (Gated
+    DeltaNet) layers cannot be served with yet. Such a layer keeps, a slot,
+    a recurrent state and a convolution tail that are no pages: whatever
+    moves, shares or rewinds a sequence by its block list alone would leave
+    them behind (ROADMAP M4's remainder). An evicted or preempted sequence
+    is not refused: it gives its pages back, is queued again with its
+    prompt and the tokens it had emitted, and recomputes its state from
+    them in a slot that admission zeroed."""
+    c = engine_config
+    probs = []
+    if c.tp > 1:
+        probs.append(f"tp={c.tp} (the states are not sharded by head; no "
+                     "exchange for a share of the experts)")
+    if c.prefix_cache:
+        probs.append("prefix_cache (a shared prefix's pages come without the "
+                     "linear layers' state at its end)")
+    if c.kv_swap_dir or c.role != "unified":
+        probs.append("the swap tier / prefill-decode handoff (kv_swap_dir, "
+                     "role): a record holds pages, no state")
+    if c.kv_dtype == "int8":
+        probs.append("kv_dtype='int8' (no packed form of the state; the "
+                     "gather path is not walked by mixer kind)")
+    if draft or cfg.num_nextn_predict_layers:
+        probs.append("a draft, a second model's or the model's own "
+                     "prediction module's (a rollback of a recurrent state "
+                     "to the accepted prefix is not defined)")
+    if c.nonfinite_policy == "repair":
+        probs.append("nonfinite_policy='repair' (it rolls a row's step back "
+                     "by its watermark; the state has moved)")
+    if cfg.moe_is_share and cfg.moe_impl != "grouped":
+        probs.append(f"moe_impl={cfg.moe_impl!r} with a router wider than "
+                     "the experts held (only the dropless path knows which "
+                     "experts it holds)")
+    if probs:
+        raise NotImplementedError(
+            "a model with linear layers keeps a recurrent state a slot and "
+            "cannot be served with: " + "; ".join(probs))
+
+
 def validate_latent_serving(engine_config, cfg: TransformerConfig,
                             draft: bool = False) -> None:
     """Fail LOUDLY at engine build for what a model with a latent (MLA)

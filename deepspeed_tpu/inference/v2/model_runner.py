@@ -90,8 +90,36 @@ class PagedModelRunner:
         """What the layered work counters of a model of mixed cache kinds
         (``telemetry.LAYER_STAT_NAMES``) are computed from: every layer's
         window (0: a global layer; the others have the ring); None for a
-        model of one kind, whose stat vector has no such lanes."""
+        model of one kind, whose stat vector has no such lanes. A model
+        with linear layers counts its full-attention layers so: they are
+        the layers that read keys."""
+        if self.linear_layers:
+            return (0,) * self.cfg.cache_layers
         return None if self.kinds is None else self.cfg.layer_windows()
+
+    @property
+    def linear_layers(self) -> int:
+        """Layers whose mixer is a Gated DeltaNet (``cfg.mixer_pattern``):
+        their states ride the frame programs' carry (``recurrent``) and
+        their work is the stat vector's last lanes
+        (``telemetry.RECURRENT_STAT_NAMES``); 0 for every other model."""
+        return self.cfg.linear_layers
+
+    def recurrent_shapes(self, slots: int):
+        """((shape, dtype) of the state, (shape, dtype) of the convolution
+        tail) that ``slots`` rows carry: float32 states (linear layers,
+        slots, Hv, dk, dv), the tail (linear layers, K - 1, slots, channels)
+        in the activations' dtype. Layers first, and the tail's slots beside
+        its channels: the order the chip's compiler gives both inside the
+        wide program whatever it is handed (a frame relaid both, in and
+        out, while the slots came first: ``tests/test_chip_compile.py``);
+        the slots' axes are ``ragged_manager.RECURRENT_SLOT_AXES``."""
+        cfg = self.cfg
+        return (((cfg.linear_layers, slots, cfg.linear_num_value_heads,
+                  cfg.linear_key_head_dim, cfg.linear_value_head_dim),
+                 jnp.float32),
+                ((cfg.linear_layers, cfg.linear_conv_kernel - 1, slots,
+                  cfg.linear_channels), cfg.act_dtype))
 
     @property
     def latent_layers(self):
@@ -117,6 +145,8 @@ class PagedModelRunner:
         and for a model of mixed kinds the layers' own, summed."""
         if self.latent_layers:
             return self.latent_layers * max_seq_len
+        if self.linear_layers:
+            return self.cfg.cache_layers * (max_seq_len + chunk) - chunk
         if self.kinds is None:
             return self.stat_window or max_seq_len
         return sum((w or max_seq_len) + chunk
@@ -156,10 +186,11 @@ class PagedModelRunner:
         """Lanes of the stat vector this model's serving loops carry: a
         model with routed experts counts their work in lanes of its own
         (``telemetry.n_stats``)."""
-        return n_stats(self.cfg.is_moe, self.kinds is not None,
+        return n_stats(self.cfg.is_moe,
+                       self.kinds is not None or bool(self.linear_layers),
                        share=self.cfg.moe_is_share,
                        latent=bool(self.cfg.latent_lanes),
-                       mtp=self.has_mtp)
+                       mtp=self.has_mtp, recurrent=bool(self.linear_layers))
 
     def set_tp(self, tp_ctx) -> None:
         """Bind a ``tp.TPContext`` (engine setup, before any serving loop
@@ -180,7 +211,7 @@ class PagedModelRunner:
 
     def _forward(self, params, ids, positions, block_tables, valid_counts,
                  kpool, vpool, *, all_logits=False, tp=None, moe_work=False,
-                 hidden=False, mtp=None):
+                 hidden=False, mtp=None, recurrent=None):
         """ids/positions: (B, C); block_tables: (B, MB);
         valid_counts: (B,) number of real (non-pad) tokens in the chunk;
         kpool/vpool: (L, KVH, NB, bs, D). Returns (last_logits (B, V),
@@ -215,8 +246,22 @@ class PagedModelRunner:
         commits nothing. Returns (logits, rows (1, B, C, 1, lanes) for the
         caller to commit, the module's experts' work), or the rows alone
         (``rows only``: the module's cache at positions nobody drafts
-        from: no attention, no experts, no head)."""
+        from: no attention, no experts, no head).
+
+        ``recurrent`` = (state (linear layers, B, Hv, dk, dv) float32, tail
+        (linear layers, K - 1, B, channels)): a model with linear (Gated
+        DeltaNet) layers (``cfg.mixer_pattern``) carries every row's state
+        through the step and gives the pair back as the LAST value. Row b
+        moves its state by its ``valid_counts[b]`` live positions, which
+        are the first of its chunk; a row with none keeps state and tail to
+        the bit. The pools hold the full-attention layers alone."""
         cfg = self.cfg
+        if cfg.mixer_pattern is not None and recurrent is None:
+            raise NotImplementedError(
+                "a model with linear (Gated DeltaNet) layers keeps a "
+                "recurrent state a slot, which rides the frame programs' "
+                "carry: it is served by serve() alone, not by put() / "
+                "step() / generate() nor the compiled mixed loop")
         bs = self.block_size
         kinds = self.kinds
         for kind in kinds or ():
@@ -330,6 +375,11 @@ class PagedModelRunner:
                 y = jnp.einsum("bse,ef->bsf", a_in, w.reshape(w.shape[0], -1))
                 return y.reshape(y.shape[:2] + w.shape[1:])
             q, k, v = (proj(lp["attn"][n]) for n in ("wq", "wk", "wv"))
+            gate = ()
+            if cfg.attn_output_gate:
+                # q_proj is doubled: a head's query, then its output gate
+                d = cfg.dims_per_head
+                q, gate = q[..., :d], (q[..., d:],)
             if cfg.use_bias or cfg.qkv_bias:
                 q = q + L.bcast(lp["attn"]["bq"].astype(dt), q.ndim)
                 k = k + L.bcast(lp["attn"]["bk"].astype(dt), k.ndim)
@@ -347,7 +397,7 @@ class PagedModelRunner:
                                  interleaved=cfg.rope_interleaved)
                 k = L.apply_rope(k, pos, freq, factor=factor,
                                  interleaved=cfg.rope_interleaved)
-            return q, k, v
+            return (q, k, v) + gate
 
         def latent_qkv(lp, h, pos):
             """A latent layer's absorbed query and its cached row."""
@@ -356,9 +406,13 @@ class PagedModelRunner:
                 lp["attn"], L.apply_norm(lp["norm1"], h, cfg), pos, cfg,
                 inv_freq)
 
-        def attn_out(lp, out):
+        def attn_out(lp, out, gate=None):
             if cfg.latent_lanes:
                 return L.mla_output(at(lp)["attn"], out, cfg)
+            if gate is not None:
+                with jax.named_scope("attn_gate"):
+                    out = out * jax.nn.sigmoid(
+                        gate.astype(jnp.float32)).astype(out.dtype)
             # row-parallel output projection: under tp the per-shard product
             # covers only the local heads — all-reduce BEFORE the replicated
             # bias, so the bias is added exactly once
@@ -490,14 +544,15 @@ class PagedModelRunner:
             if cfg.act_quant_bits:   # QAT models serve with quantized acts
                 from ...compression.compress import fake_quantize_activation
                 h = fake_quantize_activation(h, cfg.act_quant_bits)
+            gate = ()       # the output gate, where the model has one
             with jax.named_scope("attn_qkv"):
                 if cfg.latent_lanes:
                     (q, k), v = _on_live(
                         pack, functools.partial(latent_qkv, lp), h,
                         pos_safe), None
                 else:
-                    q, k, v = _on_live(pack, functools.partial(qkv, lp, l),
-                                       h, pos_safe)
+                    q, k, v, *gate = _on_live(
+                        pack, functools.partial(qkv, lp, l), h, pos_safe)
             # the pools are LOOP-INVARIANT inside the layer scan: this
             # layer's chunk KV rides into the attention as separate blocks
             # and comes back out as scan ys; one commit after the walk
@@ -505,14 +560,19 @@ class PagedModelRunner:
             # per-layer pool slices as xs/ys restacks the pools every step.
             with jax.named_scope("paged_attn"):
                 out = attend(q, k, v, kp, vp, tables, ring, at_pool, win)
-            def dense_out(h, out, live=None):
+            def dense_out(h, out, *rest):
+                # rest: the output gate where the model has one, then the
+                # live mask where it routes
+                rest = list(rest)
+                gate = rest.pop(0) if cfg.attn_output_gate else None
                 with jax.named_scope("attn_out"):
-                    y = attn_out(lp, out)
-                # group tag overrides
-                return mlp(lp, h, y,
-                           cfg.is_moe if tag is None else tag == "moe", live)
+                    y = attn_out(lp, out, gate)
+                # group tag overrides (a mixer's tag leaves the MLP the
+                # config's)
+                return mlp(lp, h, y, cfg.is_moe if tag in (None, "full")
+                           else tag == "moe", *rest)
             with jax.named_scope("mlp"):
-                h = _on_live(pack, dense_out, h, out,
+                h = _on_live(pack, dense_out, h, out, *gate,
                              live=~is_pad if routed else None)
                 h, *work = h if routed else (h,)
             # quantize-at-append: the chunk's KV leaves the layer already in
@@ -524,6 +584,51 @@ class PagedModelRunner:
                                *work)
                 return h, (k.astype(kp.dtype),
                            None if v is None else v.astype(vp.dtype), *work)
+
+        def linear_layer(h, lp, li, state, tail):
+            """A layer whose mixer is a Gated DeltaNet: ``state`` / ``tail``
+            are every linear layer's (``recurrent``), ``li`` (traced) this
+            layer's index among them. The projections, the gated norm, the
+            output projection and the MLP treat every position alike and
+            run on the live ones; the convolution and the rule run on the
+            (B, C) chunk, a row's live positions first."""
+            n_live = jnp.sum(~is_pad, axis=1).astype(jnp.int32)
+            with jax.named_scope("attn"):
+                def project(h):
+                    p = at(lp)
+                    return L.gdn_project(
+                        p["attn"], L.apply_norm(p["norm1"], h, cfg), cfg)
+                u, z, b_in, a_in = _on_live(pack, project, h)
+                # the mixer's small leaves; its matrices are sliced where
+                # they are used
+                small = {n: lp[0]["attn"][n][lp[1]]
+                         for n in ("conv", "A_log", "dt_bias")}
+                tail_l = jnp.moveaxis(
+                    jax.lax.dynamic_index_in_dim(tail, li, 0, False), 0, 1)
+                state_l = jax.lax.dynamic_index_in_dim(state, li, 0, False)
+                u, new_tail = L.gdn_conv(small, u, tail_l, n_live, cfg)
+                with jax.named_scope("gdn_scan"):
+                    q, k, v = L.gdn_split(u, cfg)
+                    beta, g = L.gdn_gates(small, b_in, a_in, ~is_pad)
+                out, new_state = L.gdn_rule(q, k, v, beta, g, state_l)
+                # a row that sat the step out keeps both to the bit
+                moved = n_live > 0
+                state = jax.lax.dynamic_update_index_in_dim(
+                    state, jnp.where(moved[:, None, None, None], new_state,
+                                     state_l), li, 0)
+                tail = jax.lax.dynamic_update_index_in_dim(
+                    tail, jnp.moveaxis(jnp.where(
+                        moved[:, None, None], new_tail, tail_l), 1, 0), li, 0)
+
+            def dense_out(h, out, z, *live):
+                with jax.named_scope("attn"):
+                    y = L.gdn_output(at(lp)["attn"], out, z, cfg)
+                return mlp(lp, h, y, cfg.is_moe, *live)
+            with jax.named_scope("mlp"):
+                h = _on_live(pack, dense_out, h, out, z,
+                             live=~is_pad if routed else None)
+                h, *work = h if routed else (h,)
+            return h, state, tail, work
 
         def sub(lp, j):
             """Attention, dense MLP and norms ``j`` of a double layer: one
@@ -604,7 +709,12 @@ class PagedModelRunner:
                 logits = self._head(params, h, valid_counts, all_logits,
                                     tp=tp)
             return logits, row[None], work[0] if work else None
-        if kinds is None:
+        if recurrent is not None:
+            h, kpool, vpool, work, recurrent = self._run_layers_recurrent(
+                layer, linear_layer, h, params, kpool, vpool, block_tables,
+                functools.partial(commit, block_tables=block_tables,
+                                  positions=positions), recurrent)
+        elif kinds is None:
             h, kpool, vpool, work = self._run_layers(
                 layer, h, params, kpool, vpool, windows,
                 functools.partial(commit, block_tables=block_tables,
@@ -619,7 +729,8 @@ class PagedModelRunner:
             h = L.apply_norm(params["final_norm"], h, cfg)
             logits = self._head(params, h, valid_counts, all_logits, tp=tp)
         return (logits, kpool, vpool) + ((work,) if moe_work else ()) \
-            + ((stack_h,) if hidden else ())
+            + ((stack_h,) if hidden else ()) \
+            + (() if recurrent is None else (recurrent,))
 
     def _run_layers(self, layer, h, params, kpool, vpool, windows, commit,
                     stacked=False):
@@ -671,6 +782,54 @@ class PagedModelRunner:
         with jax.named_scope("kv_commit"):
             kpool, vpool = commit(kpool, vpool, ck_all, cv_all)
         return h, kpool, vpool, jnp.sum(work[0], axis=0) if work else None
+
+    def _run_layers_recurrent(self, layer, linear_layer, h, params, kpool,
+                              vpool, tables, commit, recurrent):
+        """``_run_layers`` for a stack that mixes linear and full attention
+        layers (``cfg.mixer_pattern``): a scan over the pattern's PERIODS
+        with a period's layers unrolled in its body, so that which mixer a
+        layer has is static. A period's layer j has its weights in group
+        ``g{j}`` (``layer_groups``), sliced where they are used; a full
+        layer reads its index among the full layers of the one pool and
+        its chunk KV comes back as scan ys for ONE commit after the walk; a
+        linear layer reads and writes its index among the linear layers of
+        ``recurrent`` = (state, tail), which rides the scan's carry and is
+        updated in place. Returns (h, kpool, vpool, the routed experts'
+        work summed or None, recurrent)."""
+        pattern = tuple(self.cfg.mixer_pattern)
+        p, n = len(pattern), self.cfg.num_layers
+        per = {kind: pattern.count(kind) for kind in ("linear", "full")}
+        rank = [pattern[:j].count(pattern[j]) for j in range(p)]
+        layers = params["layers"]
+
+        def period(carry, t):
+            h, state, tail = carry
+            ys, work = [], []
+            for j, kind in enumerate(pattern):
+                lp = (layers[f"g{j}"], t)
+                at_kind = t * per[kind] + rank[j]
+                if kind == "linear":
+                    h, state, tail, w = linear_layer(h, lp, at_kind, state,
+                                                     tail)
+                else:
+                    h, (k, v, *w) = layer(
+                        h, (lp, t * p + j, None), tag="full",
+                        cache=(kpool, vpool, tables, None, at_kind))
+                    ys.append((k, v))
+                work += w
+            return (h, state, tail), (
+                jax.tree.map(lambda *z: jnp.stack(z), *ys),
+                sum(work) if work else None)
+
+        (h, *recurrent), ((ck, cv), work) = jax.lax.scan(
+            period, (h, *recurrent), jnp.arange(n // p, dtype=jnp.int32))
+        # (periods, full layers a period, ...) -> the full layers, in order
+        ck, cv = (a.reshape((-1,) + a.shape[2:]) for a in (ck, cv))
+        with jax.named_scope("kv_commit"):
+            kpool, vpool = commit(kpool, vpool, ck, cv)
+        return (h, kpool, vpool,
+                None if work is None else jnp.sum(work, axis=0),
+                tuple(recurrent))
 
     def _run_layers_by_kind(self, layer, h, params, kpools, vpools, tables,
                             commit):
@@ -898,13 +1057,13 @@ class PagedModelRunner:
 
         @functools.partial(jax.jit,
                            donate_argnums=(7, 8, 9, 10, 11, 12, 13, 14, 15,
-                                           16, 17),
+                                           16, 17, 18),
                            static_argnames=("width", "steps", "greedy",
                                             "repair"))
         def loop(params, prompts, prompt_lens, limits, eos_ids, temps, tables,
                  cached, produced, last_tok, done, poison, nonfinite, stats,
-                 rng, kpool, vpool, hidden=None, *, width, steps, greedy,
-                 repair=False, n_steps=None):
+                 rng, kpool, vpool, hidden=None, recurrent=None, *, width,
+                 steps, greedy, repair=False, n_steps=None):
             """One K-step serving FRAME: the resumable generalization of
             ``mixed_loop``. All per-slot state is carry-IN/carry-OUT, so the
             host only touches the loop at frame boundaries (admit arrivals,
@@ -946,6 +1105,11 @@ class PagedModelRunner:
             (``_self_spec_scan_body``). The module's cache is one more layer
             of the same pool: no argument more.
 
+            ``recurrent`` = (state, tail), given: the model has linear
+            (Gated DeltaNet) layers (``recurrent_shapes``). The pair is the
+            carry's LAST field, donated like the pools, and comes back last:
+            it rides through the frame's steps and from frame to frame.
+
             Tensor-parallel (``self.tp`` set): the same program compiles
             under shard_map on the 1-D tp mesh — params and KV pools
             sharded, every slot-state carry replicated, ``stats`` among
@@ -954,7 +1118,8 @@ class PagedModelRunner:
             """
             def core(n_steps, params, prompts, prompt_lens, limits, eos_ids,
                      temps, tables, cached, produced, last_tok, done, poison,
-                     nonfinite, stats, rng, kpool, vpool, *hidden):
+                     nonfinite, stats, rng, kpool, vpool, *hidden,
+                     recurrent=None):
                 body = _serving_scan_body(fwd, params, prompts, prompt_lens,
                                           limits, eos_ids, temps, tables,
                                           width, greedy,
@@ -965,9 +1130,11 @@ class PagedModelRunner:
                                           layers=self.layer_work,
                                           latent=self.latent_layers,
                                           heads=self.row_heads,
-                                          mtp=self.has_mtp)
+                                          mtp=self.has_mtp,
+                                          linear=self.linear_layers)
                 carry = (cached, produced, last_tok, *hidden, done, poison,
-                         nonfinite, stats, rng, kpool, vpool)
+                         nonfinite, stats, rng, kpool, vpool) \
+                    + (() if recurrent is None else (tuple(recurrent),))
                 return _run_steps(body, carry, steps, n_steps,
                                   cached.shape + ((2,) if hidden else ()))
 
@@ -976,8 +1143,10 @@ class PagedModelRunner:
                     last_tok, done, poison, nonfinite, stats, rng, kpool,
                     vpool)
             if tp is None:
-                return core(*args, *(() if hidden is None else (hidden,)))
-            assert hidden is None, "a self-draft is not served under tp"
+                return core(*args, *(() if hidden is None else (hidden,)),
+                            recurrent=recurrent)
+            assert hidden is None and recurrent is None, \
+                "neither a self-draft nor a recurrent state is served under tp"
             rep, kv = P(), tp.kv_spec
             return self._tp_call(
                 core, args,
@@ -1300,7 +1469,8 @@ def _on_live(pack, fn, *xs, live=None):
 def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                        temps, tables, width, greedy, draft=None,
                        repair=False, window=None, ladder=pack_ladder,
-                       layers=None, latent=None, heads=None, mtp=False):
+                       layers=None, latent=None, heads=None, mtp=False,
+                       linear=0):
     """Shared scan-step for ``mixed_loop`` and ``frame_loop`` — the in-graph
     SplitFuse scheduling arithmetic lives in exactly one place.
 
@@ -1356,8 +1526,15 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
     (``PagedModelRunner.row_heads``): a wide step counts its row tiles
     (``_row_tile_work``). ``mtp``: the model has a prediction module, so
     its vector has that module's lanes, which stay 0 while it does not
-    draft."""
+    draft. ``linear`` (``PagedModelRunner.linear_layers``): the model's
+    linear layers; their (state, tail) pair is the carry's last field, the
+    forward takes and returns it, and the step counts their work
+    (``RECURRENT_STAT_NAMES``): live positions and live rows, each x the
+    linear layers. Such a model is served without a draft and without
+    ``repair``, which would have to roll the state back
+    (``archs.validate_recurrent_serving``)."""
     self_draft = draft == "self"
+    assert not linear or (draft is None and not repair)
     if self_draft and width == 1:
         return _self_spec_scan_body(fwd, params, prompts, prompt_lens,
                                     limits, eos_ids, temps, tables, greedy,
@@ -1372,6 +1549,8 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
         module, commit_rows = _mtp_calls(fwd, params, tables, latent)
 
     def body(carry, _):
+        # the linear layers' (state, tail): the last field, where there is one
+        carry, recurrent = (carry[:-1], carry[-1:]) if linear else (carry, ())
         # ``hidden``: one field under a self-draft, none otherwise
         (cached, produced, last_tok, *hidden, done, poison, nonfinite, stats,
          rng, kpool, vpool) = carry
@@ -1391,7 +1570,11 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                     [jnp.sum(kv_read), jnp.sum(attn_pairs)]).astype(jnp.int32)
         logits, kpool, vpool, moe_work, *h_all = fwd(
             params, ids, positions, tables, w, kpool, vpool, moe_work=True,
-            **({"hidden": True} if self_draft else {}))
+            **({"hidden": True} if self_draft else {}),
+            **({"recurrent": recurrent[0]} if linear else {}))
+        if linear:
+            *h_all, state = h_all
+            recurrent = (state,)
         if self_draft:
             # the module's cache keeps up: its row at p - 1 is made of
             # (h[p - 1], the token at p), no attention, experts or head;
@@ -1440,9 +1623,13 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                 kv_read=kv_read, attn_pairs=attn_pairs, row_tiles=row_tiles,
                 moe_work=moe_work, layer_work=layer_work,
                 mtp_work=jnp.zeros((len(MTP_STAT_NAMES),), jnp.int32)
-                if mtp else None)
+                if mtp else None,
+                recurrent_work=linear * jnp.stack(
+                    [jnp.sum(w), jnp.sum(w > 0)]).astype(jnp.int32)
+                if linear else None)
         carry = (cached + w, produced + emit.astype(jnp.int32), last_tok,
-                 *hidden, done, poison, nonfinite, stats, rng, kpool, vpool)
+                 *hidden, done, poison, nonfinite, stats, rng, kpool, vpool,
+                 *recurrent)
         out = (jnp.where(emit, nxt, -1), emit)
         if self_draft:        # a narrow step's two columns; the second empty
             out = (jnp.stack([out[0], jnp.full_like(nxt, -1)], axis=1),
@@ -1529,7 +1716,8 @@ def _attn_work_by_layer(cached, w, layer_windows):
 def _stat_delta(positions, ladder, emitted=None, active=None,
                 prefill_toks=None, eos=None, target_fwd=None, drafted=None,
                 accepted=None, kv_read=None, attn_pairs=None, row_tiles=None,
-                moe_work=None, layer_work=None, mtp_work=None):
+                moe_work=None, layer_work=None, mtp_work=None,
+                recurrent_work=None):
     """One step's (N_STATS,) in-graph counter increment. Each keyword is a
     bool mask / int array to sum, or None for zero — the layout is pinned by
     the STAT_* indices in ``telemetry.py`` and the host-mirror replay tests
@@ -1544,7 +1732,9 @@ def _stat_delta(positions, ladder, emitted=None, active=None,
     (``telemetry.n_stats``), and last ``layer_work``: ``LAYER_STAT_NAMES``
     where the model mixes cache kinds, ``LATENT_STAT_NAMES`` where its
     attention is latent; behind those ``mtp_work``, the prediction
-    module's own (``MTP_STAT_NAMES``), where the model drafts for itself."""
+    module's own (``MTP_STAT_NAMES``), where the model drafts for itself, or
+    ``recurrent_work`` (``RECURRENT_STAT_NAMES``), where it has linear
+    layers."""
     vals = [emitted, active, prefill_toks, eos, target_fwd, drafted, accepted,
             kv_read, attn_pairs, *(row_tiles or (None, None))]
     z = jnp.zeros((), jnp.int32)
@@ -1557,7 +1747,9 @@ def _stat_delta(positions, ladder, emitted=None, active=None,
     out = jnp.concatenate([jnp.stack(out), steps]
                           + ([] if moe_work is None else [moe_work])
                           + ([] if layer_work is None else [layer_work])
-                          + ([] if mtp_work is None else [mtp_work]))
+                          + ([] if mtp_work is None else [mtp_work])
+                          + ([] if recurrent_work is None
+                             else [recurrent_work]))
     assert layer_work is None or layer_work.shape[0] in (
         len(LAYER_STAT_NAMES), len(LATENT_STAT_NAMES))
     return out

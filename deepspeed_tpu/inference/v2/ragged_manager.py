@@ -157,7 +157,12 @@ class DSStateManager:
 #: the slot arrays an admission rewrites a row of
 _ADMIT_STATE = ("prompts", "tables", "ring_tables", "prompt_lens", "limits",
                 "eos_ids", "temps", "cached", "produced", "last_tok",
-                "penult", "done", "poison", "nonfinite")
+                "penult", "done", "poison", "nonfinite", "recurrent")
+
+
+#: the slots' axis of a model's recurrent state and of its convolution tail
+#: (``PagedModelRunner.recurrent_shapes``)
+RECURRENT_SLOT_AXES = (1, 2)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -166,7 +171,10 @@ def _admit_rows(state, idx, prompts, tables, rings, ints, temps):
     each staged array into row ``idx[i]`` of the slot array; ``idx`` past the
     table drops the row. ``ints``: (slots, 4) prompt length, limit, EOS id,
     admission watermark. A slot freed by quarantine must not hand its
-    poison / latch state to the next tenant of the row, so both clear."""
+    poison / latch state to the next tenant of the row, so both clear; nor
+    may a model with linear layers hand on the row's recurrent state and
+    convolution tail (``recurrent``, empty for every other model): a new
+    tenant's are zeros, what a sequence has before its first token."""
     def put(a, v):
         return a.at[idx].set(v, mode="drop")
 
@@ -180,6 +188,12 @@ def _admit_rows(state, idx, prompts, tables, rings, ints, temps):
         out[name] = put(state[name], 0)
     for name in ("done", "poison", "nonfinite"):
         out[name] = put(state[name], False)
+    # (no operation at all where the tuple is empty)
+    out["recurrent"] = tuple(
+        jnp.where(put(jnp.zeros((a.shape[axis],), bool), True).reshape(
+            (1,) * axis + (-1,) + (1,) * (a.ndim - axis - 1)),
+            jnp.zeros((), a.dtype), a)
+        for a, axis in zip(state["recurrent"], RECURRENT_SLOT_AXES))
     return out
 
 
@@ -210,7 +224,8 @@ class DeviceSlotTable:
 
     def __init__(self, n_slots: int, prompt_width: int, table_width: int, rng,
                  tp=None, debug_replicas: bool = False,
-                 n_stats: int = N_STATS, rings=(), hidden=None):
+                 n_stats: int = N_STATS, rings=(), hidden=None,
+                 recurrent=()):
         self.n_slots = n_slots
         self.n_stats = n_stats     # lanes of the runner's stat vector
         # tensor-parallel serving (tp.TPContext): every slot array is
@@ -244,6 +259,12 @@ class DeviceSlotTable:
         # at cached-1. A new tenant's first chunk never reads it
         self.hidden = None if hidden is None else self._dev(
             jnp.zeros((n_slots, hidden[0]), hidden[1]))
+        # a model with linear layers (``recurrent`` = the (shape, dtype) of
+        # each, ``PagedModelRunner.recurrent_shapes``): every slot's
+        # recurrent states and convolution tails, not a table of pages but
+        # a row a slot; ``admit`` zeroes a new tenant's. () otherwise
+        self.recurrent = tuple(self._dev(jnp.zeros(shape, dtype))
+                               for shape, dtype in recurrent)
         self.done = self._dev(jnp.ones((n_slots,), bool))
         # fault-injection flag (frame NaNs the row's logits while set) and
         # the in-graph finite-check latch — both ride the donated carry
@@ -489,15 +510,22 @@ class DeviceSlotTable:
             # a self-draft's ``hidden`` goes in last and comes back behind
             # ``last_tok``, where the carry has it
             hidden = [] if draft is None else [self.hidden]
-            (toks, emit, self.cached, self.produced, self.last_tok, *hidden,
-             self.done, self.poison, self.nonfinite, self.stats, self.rng,
-             kv.k, kv.v) = runner.frame_loop(
+            # the linear layers' (state, tail) goes in by name and comes
+            # back last
+            recurrent = {"recurrent": self.recurrent} if self.recurrent \
+                else {}
+            out = runner.frame_loop(
                 params, self.prompts, self.prompt_lens, self.limits,
                 self.eos_ids, self.temps, tables, self.cached,
                 self.produced, self.last_tok, self.done, self.poison,
                 self.nonfinite, self.stats, self.rng, kv.k, kv.v, *hidden,
                 width=width, steps=steps, greedy=greedy, repair=repair,
-                n_steps=self._trips(n_steps))
+                n_steps=self._trips(n_steps), **recurrent)
+            if recurrent:
+                *out, self.recurrent = out
+            (toks, emit, self.cached, self.produced, self.last_tok, *hidden,
+             self.done, self.poison, self.nonfinite, self.stats, self.rng,
+             kv.k, kv.v) = out
             if hidden:
                 self.hidden, = hidden
             return toks, emit

@@ -172,17 +172,27 @@ MTP_STAT_NAMES = ("mtp_latent_positions_read", "mtp_expert_rows",
                   "mtp_experts_touched")
 
 
+#: a model with linear (Gated DeltaNet) layers (``cfg.mixer_pattern``), the
+#: very last lanes of its vector (it has no prediction module's): positions
+#: its linear layers moved their states by (live positions x linear layers)
+#: and states they read and wrote (live rows x linear layers, a step). Its
+#: full-attention layers count their reads in LAYER_STAT_NAMES
+RECURRENT_STAT_NAMES = ("gdn_positions", "gdn_state_rw")
+
+
 def n_stats(routed: bool, layered: bool = False, share: bool = False,
-            latent: bool = False, mtp: bool = False) -> int:
+            latent: bool = False, mtp: bool = False,
+            recurrent: bool = False) -> int:
     """Lanes of the stat vector of a model with (or without) routed
     experts (all of them held, or a share), of mixed cache kinds or of
     one, with latent attention or without, with a prediction module or
-    without."""
+    without, with linear layers or without."""
     return (N_STATS + (len(MOE_STAT_NAMES) if routed else 0)
             + (len(SHARE_STAT_NAMES) if share else 0)
             + (len(LAYER_STAT_NAMES) if layered else 0)
             + (len(LATENT_STAT_NAMES) if latent else 0)
-            + (len(MTP_STAT_NAMES) if mtp else 0))
+            + (len(MTP_STAT_NAMES) if mtp else 0)
+            + (len(RECURRENT_STAT_NAMES) if recurrent else 0))
 
 
 #: host work between two frames, in loop order; ``dispatch`` and ``fetch``
@@ -612,6 +622,7 @@ class ServingTelemetry:
         # stays the table kind's pool with its trash page)
         self.kind_gauges: Dict[str, Dict[str, int]] = {}
         self._tail_names, self._share, self._mtp = (), False, False
+        self._recurrent_bytes = 0       # a live slot's, a model with any
         # per-class TTFT (the bench/SLO acceptance surface)
         self.class_ttft: Dict[str, LogBucketHistogram] = {}
         # live SLO signal windows (recent samples, seconds)
@@ -641,7 +652,8 @@ class ServingTelemetry:
                     n_slots: int, kv_blocks_total: int,
                     tp_degree: int = 1, kv_block_bytes: int = 0,
                     layered: bool = False, latent: bool = False,
-                    share: bool = False, mtp: bool = False) -> None:
+                    share: bool = False, mtp: bool = False,
+                    recurrent_slot_bytes: int = 0) -> None:
         """Called by ``serve()`` at generator construction.
         ``kv_block_bytes`` is the pool-resident footprint of one KV block
         across all layers (``BlockedKVCache.block_bytes``) — the
@@ -656,11 +668,21 @@ class ServingTelemetry:
         sums. ``share``: its router is wider than the experts it holds
         (SHARE_STAT_NAMES behind the experts' lanes). ``mtp``: the model
         has a prediction module, and its vector's very last lanes are
-        MTP_STAT_NAMES."""
+        MTP_STAT_NAMES. ``recurrent_slot_bytes`` (nonzero: the model has
+        linear layers, its very last lanes are RECURRENT_STAT_NAMES and
+        ``layered`` says how its full layers count): what a live slot holds
+        of recurrent state and convolution tail, for the gauge
+        ``recurrent_bytes_in_use`` and its sum over frames."""
         self.reset()
         self._share, self._mtp = share, mtp
+        self._recurrent_bytes = recurrent_slot_bytes
         for n in MTP_STAT_NAMES if mtp else ():
             self.counters[n] = 0
+        if recurrent_slot_bytes:
+            assert not mtp, "both claim the vector's last lanes"
+            self.counters.update(dict.fromkeys(RECURRENT_STAT_NAMES, 0),
+                                 recurrent_bytes_in_use_sum=0)
+            self.gauges["recurrent_bytes_in_use"] = 0
         self._tail_names = (LAYER_STAT_NAMES if layered else
                             LATENT_STAT_NAMES if latent else ())
         for n in SHARE_STAT_NAMES if share else ():
@@ -1249,14 +1271,23 @@ class ServingTelemetry:
             return
         for i, name in enumerate(STAT_NAMES):
             self.counters[name] += int(delta[i])
+        # the vector's very last lanes: the prediction module's, or the
+        # linear layers' (a model has one or the other)
+        last = {}
+        last_names = (MTP_STAT_NAMES if self._mtp else
+                      RECURRENT_STAT_NAMES if self._recurrent_bytes else ())
+        if last_names:
+            delta, tail = np.split(delta, [len(delta) - len(last_names)])
+            last = dict(zip(last_names, map(int, tail)))
+            for name, value in last.items():
+                self.counters[name] += value
+        if self._recurrent_bytes:
+            # a live slot holds its states whatever its context
+            nbytes = live_slots * self._recurrent_bytes
+            self.gauges["recurrent_bytes_in_use"] = nbytes
+            self.counters["recurrent_bytes_in_use_sum"] += nbytes
         # a model of mixed cache kinds ends its vector with the layered
         # work, one with latent attention with the latent rows' work
-        mtp = {}
-        if self._mtp:
-            delta, tail = np.split(delta, [len(delta) - len(MTP_STAT_NAMES)])
-            mtp = dict(zip(MTP_STAT_NAMES, map(int, tail)))
-            for name, value in mtp.items():
-                self.counters[name] += value
         layers = {}
         if self._tail_names:
             delta, tail = np.split(delta, [len(delta) - len(self._tail_names)])
@@ -1276,7 +1307,7 @@ class ServingTelemetry:
                     **{n: int(delta[i]) for i, n in
                        enumerate(STAT_NAMES + SPLIT_STAT_NAMES
                                  + TILE_STAT_NAMES)}, **moe,
-                    **layers, **mtp):
+                    **layers, **last):
                 pass
         split = "wide" if width > 1 else "narrow"
         for i, name in enumerate(SPLIT_STAT_NAMES, len(STAT_NAMES)):

@@ -147,6 +147,25 @@ class TransformerConfig:
     # from the stack's hidden state and the next token's embedding. Served
     # as the model's own draft (inference/v2); 0: none
     num_nextn_predict_layers: int = 0
+    # token mixers by layer, ONE period of the pattern ("linear": a Gated
+    # DeltaNet layer, arXiv:2412.06464; "full": softmax attention), as
+    # ``window_pattern``: a cut of num_layers alone keeps it (Qwen3-Next:
+    # three linear layers, then a full one). None: every layer attends.
+    # A linear layer keeps no keys or values: per sequence a state of
+    # linear_num_value_heads x linear_key_head_dim x linear_value_head_dim
+    # and the last linear_conv_kernel - 1 inputs of its causal depthwise
+    # convolution. Served on the paged path (inference/v2) only
+    mixer_pattern: Optional[tuple] = None
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel: int = 4
+    # q_proj is doubled: a head's query and, beside it, the gate whose
+    # sigmoid multiplies the attention's output elementwise before o_proj
+    attn_output_gate: bool = False
+    # RMSNorm multiplies by 1 + w (w stored, drawn around 0), q/k norms too
+    norm_unit_offset: bool = False
     # numerics
     dtype: str = "bfloat16"             # activation dtype
     param_dtype: str = "float32"        # stored parameter dtype
@@ -231,9 +250,11 @@ class TransformerConfig:
 
     @property
     def cache_layers(self) -> int:
-        """Layers of the serving cache: the stack's attention layers and,
-        behind them, one for each prediction module's."""
-        return self.attn_layers + self.num_nextn_predict_layers
+        """Layers of the serving cache: the stack's attention layers (a
+        linear layer caches no keys) and, behind them, one for each
+        prediction module's."""
+        return (self.attn_layers - self.linear_layers
+                + self.num_nextn_predict_layers)
 
     @property
     def layer_tags(self) -> Optional[tuple]:
@@ -265,6 +286,32 @@ class TransformerConfig:
         n = self.local_attention_every
         return tuple(self.sliding_window if i % n == n - 1 else 0
                      for i in range(self.num_layers))
+
+    def layer_mixers(self) -> Optional[tuple]:
+        """Per-layer mixer kinds of a stack that mixes linear and full
+        attention layers, or None where every layer attends."""
+        if self.mixer_pattern is None:
+            return None
+        p = tuple(self.mixer_pattern)
+        assert set(p) <= {"linear", "full"}, p
+        if self.num_layers % len(p):
+            raise ValueError(
+                f"mixer_pattern of {len(p)} layers does not tile "
+                f"num_layers={self.num_layers}")
+        return p * (self.num_layers // len(p))
+
+    @property
+    def linear_layers(self) -> int:
+        """Layers whose mixer is linear: each holds a recurrent state and
+        a convolution tail a sequence, and no cache layer."""
+        return (self.layer_mixers() or ()).count("linear")
+
+    @property
+    def linear_channels(self) -> int:
+        """Channels of a linear layer's convolution: q, k and v side by
+        side (Qwen3-Next: 2,048 + 2,048 + 4,096)."""
+        return (2 * self.linear_num_key_heads * self.linear_key_head_dim
+                + self.linear_num_value_heads * self.linear_value_head_dim)
 
     def layer_type(self, i: int) -> str:
         tags = self.layer_tags
@@ -407,6 +454,26 @@ PRESETS = {
         moe_shared_expert_gate=False, moe_impl="grouped",
         kv_lora_rank=512, q_lora_rank=768, qk_nope_head_dim=192,
         qk_rope_head_dim=64, v_head_dim=256, num_nextn_predict_layers=1),
+    # Qwen3-Next-80B-A3B-Instruct (Qwen/Qwen3-Next-80B-A3B-Instruct
+    # config.json, qwen3_next): periods of three Gated DeltaNet layers (16
+    # key / 32 value heads of 128, convolution of 4 taps) and one of gated
+    # softmax attention (16 / 2 heads of 256, a quarter rotary, one RMSNorm
+    # a head on q and k); every layer routes to 10 of 512 experts of width
+    # 512, softmax over all, the ten renormalised, beside a shared expert
+    # of 512 under its sigmoid gate; norms 1 + w; untied head. The
+    # checkpoint's prediction module is not among the config's keys and is
+    # not served. ``--set num_experts=128 moe_router_experts=512`` is one
+    # chip of 4 that share each layer
+    "qwen3-next-80b-a3b": TransformerConfig(
+        vocab_size=151936, hidden_size=2048, num_layers=48, num_heads=16, num_kv_heads=2,
+        head_dim=256, intermediate_size=5120, moe_intermediate_size=512, max_seq_len=262144,
+        rope_theta=1e7, rotary_pct=0.25, norm_eps=1e-6, qk_norm="head_dim",
+        norm_unit_offset=True, attn_output_gate=True,
+        mixer_pattern=("linear", "linear", "linear", "full"),
+        linear_num_key_heads=16, linear_num_value_heads=32, linear_key_head_dim=128,
+        linear_value_head_dim=128, linear_conv_kernel=4,
+        num_experts=512, num_experts_per_tok=10, moe_norm_topk=True,
+        moe_shared_expert_size=512, moe_shared_expert_gate=True, moe_impl="grouped"),
     # BERT family (post-norm encoder, MLM head; acceptance config 2 trains
     # bert-large under ZeRO-1/2)
     "bert-base": TransformerConfig(vocab_size=30522, hidden_size=768, num_layers=12, num_heads=12,

@@ -54,8 +54,25 @@ def dq(w, dt):
 
 # ---- norms --------------------------------------------------------------
 
-def init_norm(cfg: TransformerConfig):
-    params = {"scale": _ones((cfg.hidden_size,), cfg.p_dtype)}
+def _norm_scale(cfg: TransformerConfig, shape, rng=None):
+    """A norm's stored weight: ones, or under ``norm_unit_offset`` (the
+    norm multiplies by 1 + w) zeros, drawn around 0 where a key is given
+    so that seeded weights exercise the offset."""
+    if not cfg.norm_unit_offset:
+        return _ones(shape, cfg.p_dtype)
+    if rng is None:
+        return _zeros(shape, cfg.p_dtype)
+    return _normal(rng, shape, cfg.p_dtype, 0.02)
+
+
+def norm_scale(params, cfg: TransformerConfig):
+    """The float32 factor a norm multiplies by: its weight, or 1 + it."""
+    w = params["scale"].astype(jnp.float32)
+    return 1.0 + w if cfg.norm_unit_offset else w
+
+
+def init_norm(cfg: TransformerConfig, rng=None):
+    params = {"scale": _norm_scale(cfg, (cfg.hidden_size,), rng)}
     axes = {"scale": ("embed",)}
     if cfg.norm == "layernorm":
         params["bias"] = _zeros((cfg.hidden_size,), cfg.p_dtype)
@@ -68,8 +85,7 @@ def apply_norm(params, x, cfg: TransformerConfig):
     if cfg.norm == "rmsnorm":
         var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
         y = x32 * jax.lax.rsqrt(var + cfg.norm_eps)
-        return (y * bcast(params["scale"].astype(jnp.float32),
-                          y.ndim)).astype(x.dtype)
+        return (y * bcast(norm_scale(params, cfg), y.ndim)).astype(x.dtype)
     mean = jnp.mean(x32, axis=-1, keepdims=True)
     var = jnp.var(x32, axis=-1, keepdims=True)
     y = (x32 - mean) * jax.lax.rsqrt(var + cfg.norm_eps)
@@ -205,8 +221,10 @@ def init_attention(rng, cfg: TransformerConfig):
     e, h, kvh, d = cfg.hidden_size, cfg.num_heads, cfg.kv_heads, cfg.dims_per_head
     r = jax.random.split(rng, 4)
     std = 0.02
+    # ``attn_output_gate``: a head's query and, behind it, its output gate
     params = {
-        "wq": _normal(r[0], (e, h, d), cfg.p_dtype, std),
+        "wq": _normal(r[0], (e, h, d * (2 if cfg.attn_output_gate else 1)),
+                      cfg.p_dtype, std),
         "wk": _normal(r[1], (e, kvh, d), cfg.p_dtype, std),
         "wv": _normal(r[2], (e, kvh, d), cfg.p_dtype, std),
         "wo": _normal(r[3], (h, d, e), cfg.p_dtype, std / math.sqrt(2 * cfg.num_layers)),
@@ -232,8 +250,11 @@ def init_attention(rng, cfg: TransformerConfig):
             "head_dim": ((d,), (d,)),
             "per_head": ((h, d), (kvh, d)),
         }[cfg.qk_norm]
-        for nm, shape in (("q_norm", q_shape), ("k_norm", k_shape)):
-            grp = {"scale": _ones(shape, cfg.p_dtype)}
+        for i, (nm, shape) in enumerate((("q_norm", q_shape),
+                                         ("k_norm", k_shape))):
+            grp = {"scale": _norm_scale(
+                cfg, shape, jax.random.fold_in(rng, 4 + i)
+                if cfg.norm_unit_offset else None)}
             grp_axes = {"scale": tuple("unmodeled" for _ in shape)}
             if cfg.norm == "layernorm" and cfg.qk_norm_bias:
                 grp["bias"] = _zeros(shape, cfg.p_dtype)
@@ -342,6 +363,204 @@ def mla_output(params, o_lat, cfg: TransformerConfig):
     return jnp.einsum("bshv,hve->bse", o, dq(params["wo"], dt))
 
 
+# ---- Gated DeltaNet (linear attention; arXiv:2412.06464) ------------------
+
+#: the delta rule's products are float32 to the bit the chip can give: its
+#: default rounds a float32 product's operands to bfloat16
+_RULE_PRECISION = jax.lax.Precision.HIGHEST
+#: positions the chunked rule solves at once (the WY representation's block)
+GDN_CHUNK = 64
+
+
+def init_gdn(rng, cfg: TransformerConfig):
+    """A Gated DeltaNet mixer's weights (Qwen3-Next's names): ``w_qkvz`` the
+    input projection, its outputs q | k | v | z side by side (the
+    checkpoint groups them by key head: with seeded weights a permutation),
+    ``w_ba`` the per-head write strength b and decay input a, ``conv`` the
+    taps of the causal depthwise convolution over q | k | v (tap j meets the
+    input 3 - j positions back), ``A_log`` / ``dt_bias`` the decay's
+    parameters a value head (the DeltaNet paper's draw: A ~ U(0, 16),
+    softplus(dt_bias) log-uniform in [0.001, 0.1]), ``norm`` the gated
+    RMSNorm's plain weight, ``w_out`` the output projection."""
+    e, hv = cfg.hidden_size, cfg.linear_num_value_heads
+    dv, ch = cfg.linear_value_head_dim, cfg.linear_channels
+    r = jax.random.split(rng, 6)
+    std = 0.02
+    a = jax.random.uniform(r[3], (hv,), jnp.float32, 1e-3, 16.0)
+    dt = jnp.exp(jax.random.uniform(r[4], (hv,), jnp.float32,
+                                    math.log(1e-3), math.log(0.1)))
+    params = {
+        "w_qkvz": _normal(r[0], (e, ch + hv * dv), cfg.p_dtype, std),
+        "w_ba": _normal(r[1], (e, 2 * hv), cfg.p_dtype, std),
+        "conv": _normal(r[2], (cfg.linear_conv_kernel, ch), cfg.p_dtype,
+                        cfg.linear_conv_kernel ** -0.5),
+        "A_log": jnp.log(a),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),   # softplus's inverse
+        "norm": {"scale": _ones((dv,), cfg.p_dtype)},
+        "w_out": _normal(r[5], (hv * dv, e), cfg.p_dtype,
+                         std / math.sqrt(2 * cfg.num_layers)),
+    }
+    axes = {"w_qkvz": ("embed", "unmodeled"), "w_ba": ("embed", "unmodeled"),
+            "conv": ("unmodeled", "unmodeled"), "A_log": ("unmodeled",),
+            "dt_bias": ("unmodeled",), "norm": {"scale": ("unmodeled",)},
+            "w_out": ("unmodeled", "embed")}
+    return params, axes
+
+
+@jax.named_scope("gdn_proj")
+def gdn_project(params, x, cfg: TransformerConfig):
+    """Normalised input (B, S, E) -> the convolution's input u (B, S,
+    channels) = q | k | v, the output gate z (B, S, Hv, dv), and b, a
+    (B, S, Hv) in float32. Treats every position alike."""
+    dt = cfg.act_dtype
+    hv, dv = cfg.linear_num_value_heads, cfg.linear_value_head_dim
+    qkvz = jnp.einsum("bse,ef->bsf", x, dq(params["w_qkvz"], dt))
+    ba = jnp.einsum("bse,ef->bsf", x, dq(params["w_ba"], dt)).astype(
+        jnp.float32)
+    ch = cfg.linear_channels
+    return (qkvz[..., :ch], qkvz[..., ch:].reshape(x.shape[:2] + (hv, dv)),
+            ba[..., :hv], ba[..., hv:])
+
+
+@jax.named_scope("gdn_conv")
+def gdn_conv(params, u, tail, n, cfg: TransformerConfig):
+    """The causal depthwise convolution and its ``silu`` over a chunk ``u``
+    (B, C, channels) whose row b holds ``n[b]`` live positions, behind the
+    ``tail`` (B, K - 1, channels) of inputs the sequence had before the
+    chunk (zeros before its first token). Returns the chunk's outputs (a
+    dead position's are garbage) and the new tail: the last K - 1 inputs of
+    the LIVE positions, so a row with n = 0 keeps its tail to the bit."""
+    k = cfg.linear_conv_kernel
+    c = u.shape[1]
+    ext = jnp.concatenate([tail.astype(u.dtype), u], axis=1)   # (B, K-1+C, ch)
+    taps = params["conv"].astype(jnp.float32)
+    y = sum(ext[:, j:j + c].astype(jnp.float32) * taps[j][None, None]
+            for j in range(k))
+    # input t sits at ext[t + K - 1]: the live ones' last K - 1 start at n
+    idx = n[:, None] + jnp.arange(k - 1)[None, :]              # (B, K-1)
+    new_tail = jnp.take_along_axis(ext, idx[:, :, None], axis=1)
+    return jax.nn.silu(y).astype(u.dtype), new_tail.astype(tail.dtype)
+
+
+def gdn_split(u, cfg: TransformerConfig):
+    """The convolved channels back into q, k (B, S, Hk, dk) and v (B, S,
+    Hv, dv), float32; q and k L2-normalised over dk (eps 1e-6), q scaled by
+    dk ** -0.5, each key head repeated for the value heads it serves."""
+    hk, dk = cfg.linear_num_key_heads, cfg.linear_key_head_dim
+    hv, dv = cfg.linear_num_value_heads, cfg.linear_value_head_dim
+    u = u.astype(jnp.float32)
+    q = u[..., :hk * dk].reshape(u.shape[:2] + (hk, dk))
+    k = u[..., hk * dk:2 * hk * dk].reshape(u.shape[:2] + (hk, dk))
+    v = u[..., 2 * hk * dk:].reshape(u.shape[:2] + (hv, dv))
+
+    def l2norm(x):
+        return x * jax.lax.rsqrt(
+            jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+
+    q = jnp.repeat(l2norm(q) * dk ** -0.5, hv // hk, axis=2)
+    return q, jnp.repeat(l2norm(k), hv // hk, axis=2), v
+
+
+def gdn_gates(params, b, a, live):
+    """The write strength beta = sigmoid(b) and the log decay g =
+    -exp(A_log) softplus(a + dt_bias), (B, S, Hv) float32; both 0 at a dead
+    position (``live`` (B, S) False), which then leaves the state as it
+    was."""
+    beta = jax.nn.sigmoid(b)
+    g = -jnp.exp(params["A_log"].astype(jnp.float32))[None, None] \
+        * jax.nn.softplus(a + params["dt_bias"].astype(jnp.float32)[None, None])
+    live = live[:, :, None]
+    return jnp.where(live, beta, 0.0), jnp.where(live, g, 0.0)
+
+
+@jax.named_scope("gdn_scan")
+def gdn_rule(q, k, v, beta, g, state, chunk=GDN_CHUNK):
+    """The gated delta rule over a chunk: per head, with S (dk, dv) the
+    state, for each position S' = exp(g) S; delta = beta (v - S'^T k);
+    S = S' + k (x) delta; o = S^T q. q, k (B, C, H, dk), v (B, C, H, dv),
+    beta, g (B, C, H), state (B, H, dk, dv), all float32. Returns o (B, C,
+    H, dv) and the new state.
+
+    C = 1 is the recurrence itself. A wider chunk runs the paper's chunked
+    form, an algebraic rewriting: within a block of ``chunk`` positions the
+    deltas solve a unit lower-triangular system ((I - A)^-1, A strictly
+    lower and so nilpotent: the product of (I + A^(2^m)), six products for
+    64), the blocks follow each other through the state. A position with
+    beta = g = 0 changes nothing, so a row's dead positions, behind its live
+    ones, leave the state the live ones gave."""
+    p = _RULE_PRECISION
+    b, c, h, dk = q.shape
+    if c == 1:
+        s = state * jnp.exp(g[:, 0])[:, :, None, None]
+        sk = jnp.einsum("bhkv,bhk->bhv", s, k[:, 0], precision=p)
+        delta = beta[:, 0, :, None] * (v[:, 0] - sk)
+        s = s + k[:, 0, :, :, None] * delta[:, :, None, :]
+        return jnp.einsum("bhkv,bhk->bhv", s, q[:, 0], precision=p)[:, None], s
+    cs = min(chunk, c)
+    pad = -c % cs
+    if pad:   # dead positions behind the chunk
+        q, k, v, beta, g = (jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+                            for x in (q, k, v, beta, g))
+    n = (c + pad) // cs
+
+    def blocks(x):      # (B, C, H, ...) -> (B, H, N, cs, ...)
+        x = x.reshape((b, n, cs) + x.shape[2:])
+        return jnp.moveaxis(x, 3, 1)
+
+    q, k, v, beta, g = map(blocks, (q, k, v, beta, g))
+    gc = jnp.cumsum(g, axis=-1)                                 # (B, H, N, cs)
+    lower = jnp.tril(jnp.ones((cs, cs), bool))
+    strict = jnp.tril(jnp.ones((cs, cs), bool), -1)
+    decay = jnp.where(lower, jnp.exp(jnp.where(
+        lower, gc[..., :, None] - gc[..., None, :], 0.0)), 0.0)
+    kb = k * beta[..., None]
+    a = jnp.where(strict, -jnp.einsum("bhnik,bhnjk->bhnij", kb, k,
+                                      precision=p) * decay, 0.0)
+    eye = jnp.eye(cs, dtype=jnp.float32)
+    t, power = eye + a, a
+    for _ in range(max(0, (cs - 1).bit_length() - 1)):
+        power = jnp.einsum("bhnij,bhnjk->bhnik", power, power, precision=p)
+        t = t + jnp.einsum("bhnij,bhnjk->bhnik", t, power, precision=p)
+    u = jnp.einsum("bhnij,bhnjv->bhniv", t, v * beta[..., None], precision=p)
+    w = jnp.einsum("bhnij,bhnjk->bhnik", t, kb * jnp.exp(gc)[..., None],
+                   precision=p)
+    qk = jnp.where(lower, jnp.einsum("bhnik,bhnjk->bhnij", q, k,
+                                     precision=p) * decay, 0.0)
+    outs = []
+    for i in range(n):
+        v_new = u[:, :, i] - jnp.einsum("bhck,bhkv->bhcv", w[:, :, i], state,
+                                        precision=p)
+        outs.append(
+            jnp.einsum("bhck,bhkv->bhcv",
+                       q[:, :, i] * jnp.exp(gc[:, :, i])[..., None], state,
+                       precision=p)
+            + jnp.einsum("bhij,bhjv->bhiv", qk[:, :, i], v_new, precision=p))
+        last = gc[:, :, i, -1]
+        state = state * jnp.exp(last)[:, :, None, None] + jnp.einsum(
+            "bhck,bhcv->bhkv",
+            k[:, :, i] * jnp.exp(last[..., None] - gc[:, :, i])[..., None],
+            v_new, precision=p)
+    o = jnp.stack(outs, axis=2)                                 # (B, H, N, cs, dv)
+    o = jnp.moveaxis(o, 1, 3).reshape(b, n * cs, h, -1)
+    return o[:, :c], state
+
+
+def gdn_output(params, o, z, cfg: TransformerConfig):
+    """The rule's output (B, S, Hv, dv) float32 under its gated norm, a head
+    at a time: rmsnorm(o) w_n silu(z); then the output projection. Treats
+    every position alike."""
+    dt = cfg.act_dtype
+    with jax.named_scope("gdn_norm_gate"):
+        o = o.astype(jnp.float32)
+        y = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+                              + cfg.norm_eps)
+        y = y * bcast(params["norm"]["scale"].astype(jnp.float32), y.ndim)
+        y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(dt)
+    with jax.named_scope("gdn_out"):
+        return jnp.einsum("bsf,fe->bse", y.reshape(y.shape[:2] + (-1,)),
+                          dq(params["w_out"], dt))
+
+
 def apply_qk_norm(norm_params, x, cfg: TransformerConfig):
     """Normalize q or k heads: x (B, S, H, D).
 
@@ -362,7 +581,7 @@ def apply_qk_norm(norm_params, x, cfg: TransformerConfig):
         mean = jnp.mean(x32, axis=-1, keepdims=True)
         var = jnp.var(x32, axis=-1, keepdims=True)
         y = (x32 - mean) * jax.lax.rsqrt(var + cfg.norm_eps)
-    y = y * bcast(norm_params["scale"].astype(jnp.float32), y.ndim)
+    y = y * bcast(norm_scale(norm_params, cfg), y.ndim)
     if "bias" in norm_params:
         y = y + bcast(norm_params["bias"].astype(jnp.float32), y.ndim)
     return y.reshape(b, s, h, d).astype(x.dtype)

@@ -109,6 +109,14 @@ def layer_plan(cfg):
       ("segments", [(tag, start, length), ...]) — contiguous runs
         (mlp_only_layers prefixes): one scan per run.
     """
+    if cfg.mixer_pattern is not None:
+        # layers of linear and of full attention hold different weights:
+        # always the pattern's period, one scan step a period even where
+        # the stack is one period deep, so every depth lays its weights
+        # out alike (``layer_tags`` beside it is not written)
+        assert cfg.layer_tags is None, "mixer_pattern beside layer tags"
+        cfg.layer_mixers()      # the pattern tiles the stack
+        return ("periodic", len(cfg.mixer_pattern))
     tags = cfg.layer_tags
     if tags is None or len(set(tags)) <= 1:
         return None
@@ -137,7 +145,8 @@ def layer_groups(cfg):
         return None
     if plan[0] == "periodic":
         p = plan[1]
-        return [(cfg.layer_tags[i], tuple(range(i, cfg.num_layers, p)))
+        tags = cfg.layer_mixers() or cfg.layer_tags
+        return [(tags[i], tuple(range(i, cfg.num_layers, p)))
                 for i in range(p)]
     return [(tag, tuple(range(start, start + ln)))
             for tag, start, ln in plan[1]]
@@ -253,14 +262,22 @@ class CausalLM:
         if cfg.shortcut_moe:
             return self._init_double_layer(rng)
         r_attn, r_mlp = jax.random.split(rng)
-        attn, attn_axes = (L.init_mla if cfg.kv_lora_rank
+        # a group's tag names its MLP ("dense" | "moe") or, in a stack of
+        # mixed mixers, its mixer ("linear" | "full"; the MLP is the
+        # config's): a linear layer holds its mixer under "attn" too
+        mixer = layer_type if cfg.mixer_pattern is not None else None
+        attn, attn_axes = (L.init_gdn if mixer == "linear" else
+                           L.init_mla if cfg.kv_lora_rank
                            else L.init_attention)(r_attn, cfg)
-        if (cfg.is_moe if layer_type is None else layer_type == "moe"):
+        if (cfg.is_moe if layer_type is None or mixer
+                else layer_type == "moe"):
             mlp, mlp_axes = L.init_moe_mlp(r_mlp, cfg)
         else:
             mlp, mlp_axes = L.init_mlp(r_mlp, cfg)
-        norm1, norm1_axes = L.init_norm(cfg)
-        norm2, norm2_axes = L.init_norm(cfg)
+        r_norm = (jax.random.split(jax.random.fold_in(rng, 2))
+                  if cfg.norm_unit_offset else (None, None))
+        norm1, norm1_axes = L.init_norm(cfg, r_norm[0])
+        norm2, norm2_axes = L.init_norm(cfg, r_norm[1])
         params = {"attn": attn, "mlp": mlp, "norm1": norm1, "norm2": norm2}
         axes = {"attn": attn_axes, "mlp": mlp_axes, "norm1": norm1_axes, "norm2": norm2_axes}
         if cfg.sandwich_norm:   # Gemma-2 post-attn / post-ffw output norms
@@ -312,7 +329,9 @@ class CausalLM:
                         layer_rngs[jnp.asarray(idxs)])
         out = {"embed": emb, "layers": stacked}
         if not cfg.post_norm:   # post-norm (BERT) normalizes inside each layer
-            out["final_norm"] = L.init_norm(cfg)[0]
+            out["final_norm"] = L.init_norm(
+                cfg, jax.random.fold_in(rng, 3)
+                if cfg.norm_unit_offset else None)[0]
         if cfg.num_nextn_predict_layers:
             # a key of its own: the stack's weights do not depend on
             # whether the model has its prediction modules
@@ -400,9 +419,10 @@ class CausalLM:
     def _layer_fn(self, lp, h, positions, segment_ids, attn_bias=None, window=None,
                   layer_type=None, rope=None):
         cfg = self.cfg
-        if cfg.kv_lora_rank or cfg.shortcut_moe:
+        if cfg.kv_lora_rank or cfg.shortcut_moe or cfg.mixer_pattern:
             raise NotImplementedError(
-                "latent attention and shortcut-connected layers run on the "
+                "latent attention, shortcut-connected layers and linear "
+                "(Gated DeltaNet) mixers run on the "
                 "paged serving path (inference/v2) only: no training or "
                 "cache-less forward is written for them")
         rope = self._rope_args(rope)

@@ -869,6 +869,85 @@ def test_glm_frame_programs_fit_the_chip(one_chip, as_tpu, width):
     assert total < 15.75e9, total
 
 
+QWEN3_NEXT_CUT = dict(num_layers=8, num_experts=128, moe_router_experts=512)
+
+
+@pytest.mark.parametrize("width", [1, 128], ids=["narrow", "wide"])
+def test_qwen3_next_frame_programs_fit_the_chip(one_chip, as_tpu, width):
+    """The benchmark's Qwen3-Next-80B-A3B configuration (published widths; 8
+    of 48 layers = two periods of three Gated DeltaNet layers and one of
+    gated attention, experts 0..127 of 512 beside the whole router, the
+    whole vocabulary, bf16; 16 slots, 8 steps, sequences to 16,384: tables
+    of 128 pages over pools of 2,049 pages for the TWO full layers, 2 KV
+    heads of 256; beside them every slot's recurrent state (16, 6, 32, 128,
+    128) float32 and convolution tail (16, 6, 3, 8192)): both frame programs
+    compile with the chip's compiler from shapes alone. A scan step is one
+    period: the paged kernel once (head_dim 256, 8 query rows a KV head: a
+    shape no other cell has), one commit in place after the walk, the
+    grouped-product kernel three times a layer and rung, NO value shaped
+    like a pool or like the states that XLA copied (the state rides the
+    scan's carry and is updated in place, a layer's part at a time: 201 MB
+    that a copy a layer would move 48 times a frame), no buffer shaped like a layer's held experts, and arguments and
+    temporaries under 15.75 GB."""
+    import re
+    from deepspeed_tpu.inference.v2.model_runner import PagedModelRunner
+    from deepspeed_tpu.inference.v2.telemetry import pack_ladder
+    from deepspeed_tpu.models import build_model, get_config
+    slots, steps, pages, seq = 16, 8, 2049, 16384
+    cfg = get_config("qwen3-next-80b-a3b", **QWEN3_NEXT_CUT)
+    assert cfg.dtype == "bfloat16" and cfg.linear_layers == 6
+    assert cfg.cache_layers == 2 and cfg.moe_is_share
+    model = build_model(cfg.replace(param_dtype=cfg.dtype))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                          model.abstract_params())
+    i32, flag = jnp.int32, jnp.bool_
+    row = sds((slots,), i32)
+    pool = sds((2, 2, pages, PAGE, 256), jnp.bfloat16)
+    key = jax.random.PRNGKey(0)
+    runner = PagedModelRunner(model, PAGE, seq // PAGE)
+    assert runner.n_stats == 18 + 3 + 3 + 3 + 2
+    state, tail = (sds(*shape) for shape in runner.recurrent_shapes(slots))
+    assert state.shape == (6, 16, 32, 128, 128) and state.dtype == jnp.float32
+    assert tail.shape == (6, 3, 16, 8192) and tail.dtype == jnp.bfloat16
+    compiled = runner._build_frame_loop().lower(
+        params, sds((slots, seq), i32), row, row, row,
+        sds((slots,), jnp.float32), sds((slots, seq // PAGE), i32), row, row,
+        row, sds((slots,), flag), sds((slots,), flag), sds((slots,), flag),
+        sds((runner.n_stats,), i32), sds(key.shape, key.dtype), pool, pool,
+        recurrent=(state, tail), width=width, steps=steps, greedy=True,
+        n_steps=sds((), i32)).compile()
+    text = compiled.as_text()
+    rungs = len(pack_ladder(slots, width))
+    assert len(re.findall(rf"%paged_attn_\S*c{width}\S* = ", text)) == 1
+    assert len(re.findall(r"%paged_attn_\S* = ", text)) == 1
+    assert len(re.findall(r"%kv_commit_\S* = ", text)) == 1
+    assert len(re.findall(r"%grouped_mm_m128\S* = ", text)) == 4 * 3 * rungs
+    assert "ragged-dot" not in text
+    for kind, value in (("bf16", pool), ("f32", state)):
+        # the state's update in place is a fusion XLA names for it
+        shape = ",".join(map(str, value.shape))
+        made = [(name, op) for name, op in re.findall(
+            rf"%(\S+) = {kind}\[{shape}\]\S* (copy|copy-start|fusion|"
+            rf"scatter|transpose)\(", text)
+            if "dynamic-update-slice" not in name]
+        assert not made, (shape, made)
+    stacks = re.findall(
+        r"= bf16\[(?:1,)?128,(?:2048,512|512,2048)\]\S* "
+        r"(?!parameter|bitcast)(\w[\w-]*)\(", text)
+    assert not stacks, stacks
+    m = compiled.memory_analysis()
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes
+    print(f"qwen3-next frame program, width {width}: args "
+          f"{m.argument_size_in_bytes / 1e9:.3f} GB + temp "
+          f"{m.temp_size_in_bytes / 1e9:.3f} GB")
+    assert 9.4e9 < m.argument_size_in_bytes < 9.8e9
+    assert total < 15.75e9, total
+
+
 def test_chip_smoke_fails_without_a_chip():
     """The suite runs on the CPU: ``chip_smoke.py`` must exit nonzero there
     and never print its success line (the children stop before any phase)."""
