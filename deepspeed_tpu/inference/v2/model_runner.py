@@ -282,6 +282,10 @@ class PagedModelRunner:
         # head keep the (B, C) layout. None = this shape does not pack.
         with jax.named_scope("frame_plan"):
             pack = _pack_plan(positions, self.pack_ladder(*ids.shape))
+            # a wide step's linear layers run the delta rule on their rows
+            # by what each holds (``_rule_by_rows``)
+            rows = _row_plan(positions) \
+                if cfg.mixer_pattern is not None and ids.shape[1] > 1 else None
 
         def embed(ids, positions):
             if tp is not None and tp.vocab_sharded:
@@ -590,8 +594,10 @@ class PagedModelRunner:
             are every linear layer's (``recurrent``), ``li`` (traced) this
             layer's index among them. The projections, the gated norm, the
             output projection and the MLP treat every position alike and
-            run on the live ones; the convolution and the rule run on the
-            (B, C) chunk, a row's live positions first."""
+            run on the live ones; the convolution runs on the (B, C) chunk,
+            a row's live positions first, and the rule on the rows by what
+            they hold (``_rule_by_rows``; a narrow step's is the recurrence
+            on every row)."""
             n_live = jnp.sum(~is_pad, axis=1).astype(jnp.int32)
             with jax.named_scope("attn"):
                 def project(h):
@@ -607,10 +613,14 @@ class PagedModelRunner:
                     jax.lax.dynamic_index_in_dim(tail, li, 0, False), 0, 1)
                 state_l = jax.lax.dynamic_index_in_dim(state, li, 0, False)
                 u, new_tail = L.gdn_conv(small, u, tail_l, n_live, cfg)
-                with jax.named_scope("gdn_scan"):
-                    q, k, v = L.gdn_split(u, cfg)
-                    beta, g = L.gdn_gates(small, b_in, a_in, ~is_pad)
-                out, new_state = L.gdn_rule(q, k, v, beta, g, state_l)
+
+                def rule(u, b_in, a_in, pad, state):
+                    with jax.named_scope("gdn_scan"):
+                        q, k, v = L.gdn_split(u, cfg)
+                        beta, g = L.gdn_gates(small, b_in, a_in, ~pad)
+                    return L.gdn_rule(q, k, v, beta, g, state)
+                out, new_state = _rule_by_rows(rows, rule, u, b_in, a_in,
+                                               is_pad, state_l)
                 # a row that sat the step out keeps both to the bit
                 moved = n_live > 0
                 state = jax.lax.dynamic_update_index_in_dim(
@@ -1466,6 +1476,93 @@ def _on_live(pack, fn, *xs, live=None):
     return jax.lax.switch(rung, [on_rung(t) for t in ladder], *xs)
 
 
+#: rows the chunked delta rule takes a trip of a wide step's loop
+#: (``_rule_by_rows``), where the step has as many
+RULE_ROWS = 2
+
+
+def _rule_trips(n_live):
+    """(Trips of ``_rule_by_rows``' loop in a step whose rows hold
+    ``n_live`` (B,) live positions, rows a trip): the rows that hold more
+    than one, ``RULE_ROWS`` a trip."""
+    rows = min(RULE_ROWS, n_live.shape[0])
+    n = jnp.sum((n_live > 1).astype(jnp.int32))
+    return (n + rows - 1) // rows, rows
+
+
+def _rule_positions(n_live, width):
+    """Positions ONE linear layer's delta rule computes in a step whose
+    rows hold ``n_live`` (B,) live positions of ``width``
+    (``_rule_by_rows``): a trip's rows x ``width`` a trip of the chunked
+    form, and every row through the recurrence."""
+    b = n_live.shape[0]
+    if width == 1:
+        return jnp.asarray(b, jnp.int32)
+    trips, rows = _rule_trips(n_live)
+    return trips * rows * width + b
+
+
+def _row_plan(positions):
+    """Which rows of a wide chunk hold more than one live position
+    (``positions >= 0``), packed in slot order: (trips of the chunked
+    form's loop, rows a trip, src the row of each packed row, B past their
+    count and one entry for every row a trip may take, rider (B,) the rows
+    that hold exactly one). As ``_pack_plan`` does for tokens: a cumulative
+    sum and its inverse, no sort."""
+    n_live = jnp.sum((positions >= 0).astype(jnp.int32), axis=1)
+    chunked = n_live > 1
+    trips, rows = _rule_trips(n_live)
+    src = jnp.searchsorted(
+        jnp.cumsum(chunked.astype(jnp.int32)),
+        jnp.arange(chunked.size + rows - 1, dtype=jnp.int32),
+        side="right", method="compare_all")
+    return trips, rows, src, n_live == 1
+
+
+def _rule_by_rows(plan, rule, u, b_in, a_in, pad, state):
+    """The gated delta rule of one linear layer's step, ``rule(u, b_in,
+    a_in, pad, state) -> (out, new state)`` over rows (B, C, ...) and
+    their states (B, H, dk, dv), computed for each row by what it holds
+    (``plan``, ``_row_plan``; None: a narrow step, ``rule`` itself, the
+    recurrence on every row):
+
+    * one live position (a decoding row riding the step, a prompt's last
+      token): the recurrence on position 0, run on all B rows at once (what
+      a narrow step runs) and kept for these;
+    * more (a prefilling row): the chunked form, ``RULE_ROWS`` of those
+      rows gathered a trip of ONE loop body whose trip count is computed in
+      the graph (as the frame program's step count is an operand): no trip
+      where no row prefills, B / ``RULE_ROWS`` where every row does. A last
+      trip's row past the count computes a clipped row and is written
+      nowhere;
+    * none: the state it had.
+
+    Returns out (B, C, H, dv), zeros at the rows that sat the step out and
+    behind a rider's one position, and the new states."""
+    if plan is None:
+        return rule(u, b_in, a_in, pad, state)
+    trips, take, src, rider = plan
+    xs = (u, b_in, a_in, pad)
+    with jax.named_scope("gdn_scan"):
+        out1, state1 = rule(*(x[:, :1] for x in xs), state)
+        rider = rider[:, None, None, None]
+        rides = rider & (jnp.arange(pad.shape[1]) == 0)[None, :, None, None]
+
+        def trip(i, carry):
+            out, new = carry
+            # past the count ``src`` names row B: read clipped, dropped
+            # by the writes
+            to = jax.lax.dynamic_slice_in_dim(src, i * take, take)
+            rows = jnp.minimum(to, pad.shape[0] - 1)
+            o, s = rule(*(x[rows] for x in xs), state[rows])
+            return (out.at[to].set(o, mode="drop"),
+                    new.at[to].set(s, mode="drop"))
+
+        return jax.lax.fori_loop(
+            0, trips, trip, (jnp.where(rides, out1, 0.0),
+                             jnp.where(rider, state1, state)))
+
+
 def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                        temps, tables, width, greedy, draft=None,
                        repair=False, window=None, ladder=pack_ladder,
@@ -1625,7 +1722,8 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                 mtp_work=jnp.zeros((len(MTP_STAT_NAMES),), jnp.int32)
                 if mtp else None,
                 recurrent_work=linear * jnp.stack(
-                    [jnp.sum(w), jnp.sum(w > 0)]).astype(jnp.int32)
+                    [jnp.sum(w), jnp.sum(w > 0),
+                     _rule_positions(w, width)]).astype(jnp.int32)
                 if linear else None)
         carry = (cached + w, produced + emit.astype(jnp.int32), last_tok,
                  *hidden, done, poison, nonfinite, stats, rng, kpool, vpool,

@@ -176,8 +176,13 @@ MTP_STAT_NAMES = ("mtp_latent_positions_read", "mtp_expert_rows",
 #: very last lanes of its vector (it has no prediction module's): positions
 #: its linear layers moved their states by (live positions x linear layers)
 #: and states they read and wrote (live rows x linear layers, a step). Its
-#: full-attention layers count their reads in LAYER_STAT_NAMES
-RECURRENT_STAT_NAMES = ("gdn_positions", "gdn_state_rw")
+#: full-attention layers count their reads in LAYER_STAT_NAMES. Last, the
+#: positions the delta rule COMPUTED for them: a wide step runs its chunked
+#: form on the rows that hold more than one live position, two a trip of a
+#: loop (trips x 2 x width), and the one-position recurrence on every row;
+#: a narrow step the recurrence alone (``model_runner._rule_by_rows``)
+RECURRENT_STAT_NAMES = ("gdn_positions", "gdn_state_rw",
+                        "gdn_positions_computed")
 
 
 def n_stats(routed: bool, layered: bool = False, share: bool = False,

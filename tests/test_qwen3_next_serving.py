@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.inference.v2 import model_runner
 from deepspeed_tpu.inference.v2.engine_v2 import (InferenceEngineV2,
                                                   RaggedInferenceEngineConfig)
 from deepspeed_tpu.inference.v2.ragged_manager import DeviceSlotTable
@@ -276,6 +277,96 @@ def test_dead_positions_leave_state_and_tail(whole):
     assert float(jnp.abs(state[0] - state0[0]).max()) > 0.1
 
 
+#: live positions a row of a wide step of 8 rows x 96, and the trips the
+#: chunked form's loop must make, ``RULE_ROWS`` = 2 rows a trip: the count
+#: of rows that hold more than one, even and odd, from none to every row.
+#: Every mix but the last has a frozen row (0) and a rider (1)
+ROW_MIXES = [
+    ([0, 1, 0, 1, 1, 0, 0, 1], 0),         # nothing prefills
+    ([1, 0, 1, 96, 1, 0, 0, 1], 1),        # one row: half a trip
+    ([41, 1, 0, 1, 0, 96, 1, 1], 1),       # a trip full
+    ([1, 2, 0, 96, 1, 95, 0, 1], 2),       # one row past it
+    ([96, 64, 1, 0, 65, 1, 3, 0], 2),
+    ([96, 5, 1, 37, 0, 96, 1, 2], 3),      # the LAST row half a trip
+    ([96, 2, 95, 64, 65, 3, 96, 40], 4),   # every row prefills
+]
+_MIXER_STEPS = {}
+
+
+def mixer_step(model, params, by_rows):
+    """One linear mixer's wide step as ``linear_layer`` runs it, jitted once
+    a module: the rule on the rows by what they hold (``by_rows``), or
+    ``L.gdn_rule`` over the whole (B, C) chunk, what every wide step ran
+    before rows were told apart. The rows' live counts are an operand."""
+    if by_rows not in _MIXER_STEPS:
+        cfg = model.cfg
+        mix = jax.tree.map(lambda w: w[0], params["layers"]["g2"]["attn"])
+
+        def rule(u, b, a, pad, state):
+            q, k, v = L.gdn_split(u, cfg)
+            return L.gdn_rule(q, k, v, *L.gdn_gates(mix, b, a, ~pad), state)
+
+        def step(x, n, state, tail):
+            at = jnp.arange(x.shape[1])[None]
+            positions = jnp.where(at < n[:, None], at, -1)
+            pad = positions < 0
+            u, _, b, a = L.gdn_project(mix, x, cfg)
+            u, new_tail = L.gdn_conv(mix, u, tail, n, cfg)
+            plan = model_runner._row_plan(positions) if by_rows else None
+            out, new = model_runner._rule_by_rows(plan, rule, u, b, a, pad,
+                                                  state)
+            moved = n > 0
+            return (jnp.where(pad[:, :, None, None], 0.0, out),
+                    jnp.where(moved[:, None, None, None], new, state),
+                    jnp.where(moved[:, None, None], new_tail, tail),
+                    -1 if plan is None else plan[0],
+                    model_runner._rule_positions(n, x.shape[1]))
+        _MIXER_STEPS[by_rows] = jax.jit(step)
+    return _MIXER_STEPS[by_rows]
+
+
+@pytest.mark.parametrize("n_live,trips", ROW_MIXES)
+def test_a_wide_steps_rule_runs_the_rows_by_what_they_hold(whole, n_live,
+                                                           trips):
+    """A wide step whose rows mix the kinds (frozen, riding, part of a
+    chunk, a full chunk): riders through the recurrence, the prefilling
+    rows gathered two a trip into the chunked form, the trips counted in
+    the graph, give the outputs, states and tails that the chunked form
+    over every row gives, to float32 rounding (the tolerance of
+    ``test_chunked_rule_is_the_recurrence``: a rider's update is the same
+    algebra summed in another order); a row with no live position keeps
+    state and tail to the bit. Over the mixes every trip count is made."""
+    assert model_runner.RULE_ROWS == 2
+    assert {t for _, t in ROW_MIXES} == set(range(SLOTS // 2 + 1))
+    model, params = whole
+    cfg = model.cfg
+    rng = np.random.default_rng(sum(n_live))
+    x = jnp.asarray(rng.standard_normal((SLOTS, WIDTH, 64)), jnp.float32)
+    n = jnp.asarray(n_live, jnp.int32)
+    state0 = jnp.asarray(rng.standard_normal((SLOTS, 4, 8, 8)), jnp.float32)
+    tail0 = jnp.asarray(
+        rng.standard_normal((SLOTS, 3, cfg.linear_channels)), jnp.float32)
+    out, state, tail, chose, computed = mixer_step(model, params, True)(
+        x, n, state0, tail0)
+    want, want_state, want_tail, _, _ = mixer_step(model, params, False)(
+        x, n, state0, tail0)
+    assert int(chose) == trips
+    assert int(computed) == trips * 2 * WIDTH + SLOTS
+    scale = float(jnp.abs(want).max())
+    assert scale > 0.1 or not any(n_live)
+    assert float(jnp.abs(out - want).max()) < 2e-5 * max(scale, 1.0)
+    assert float(jnp.abs(state - want_state).max()) < 2e-5
+    assert np.array_equal(np.asarray(tail), np.asarray(want_tail))
+    for row, live in enumerate(n_live):
+        if live == 0:
+            assert np.array_equal(np.asarray(state[row]),
+                                  np.asarray(state0[row]))
+            assert np.array_equal(np.asarray(tail[row]),
+                                  np.asarray(tail0[row]))
+        else:
+            assert float(jnp.abs(state[row] - state0[row]).max()) > 1e-3
+
+
 # ---- the served path against the reference ---------------------------------
 
 
@@ -447,9 +538,9 @@ def test_state_rides_steps_and_frames(whole):
 def test_a_rider_moves_by_one_position_and_a_frozen_slot_not_at_all(whole):
     """A wide step with a prefilling row, a rider and a frozen slot (done,
     its state left by a tenant that finished): the rider's state after the
-    step is the state one recurrent update gives (the narrow program's, to
-    rounding), the frozen slot's state and tail are what they were, to the
-    bit."""
+    step is the state one recurrent update gives (the narrow program's: the
+    wide step runs the same recurrence on it), the frozen slot's state and
+    tail are what they were, to the bit."""
     model, params = whole
     rng = np.random.default_rng(41)
     first = rng.integers(0, 256, 30).astype(np.int32)
@@ -476,7 +567,14 @@ def test_a_rider_moves_by_one_position_and_a_frozen_slot_not_at_all(whole):
     narrow = [np.asarray(a) for a in tslots.recurrent]
     assert np.array_equal(after[0][:, 0], frozen[0][:, 0])
     assert np.array_equal(after[1][:, :, 0], frozen[1][:, :, 0])
-    assert np.abs(after[0][:, 1] - narrow[0][:, 1]).max() < 1e-5
+    # both are the recurrence now, on inputs that differ in their last bit:
+    # the wide step projects the rider's token in a packed buffer beside
+    # the prompt's, the narrow one in a batch of its own, and the products
+    # round elsewhere (the tails, plain copies of those inputs, differ by
+    # as much). Measured 4.8e-7 through the six layers (4.5e-7 while the
+    # wide step ran the chunked form on the rider: the inputs' last bits
+    # set the gap, not the rule's form)
+    assert np.abs(after[0][:, 1] - narrow[0][:, 1]).max() < 2e-6
     np.testing.assert_allclose(after[1][:, :, 1], narrow[1][:, :, 1],
                                atol=1e-5)
     assert np.abs(after[0][:, 1] - frozen[0][:, 1]).max() > 1e-3
@@ -499,10 +597,18 @@ def test_a_second_tenant_reads_a_fresh_slot(whole):
     c = e.telemetry.counters
     assert c["gdn_positions"] == 6 * (120 + 70 + 2 * 4)
     assert c["gdn_state_rw"] == 6 * e.telemetry.counters["active_row_steps"]
+    # computed: the one row's chunk whole (one trip of one row: the table
+    # has no second) in the three wide steps that took more than one prompt
+    # token (96 + 24 of the first prompt, 70 of the second), and the
+    # recurrence on it in every step of every frame
+    assert c["wide_steps"] == 3
+    assert c["gdn_positions_computed"] == 6 * (3 * 96 + c["frame_steps"])
+    assert c["gdn_positions"] <= c["gdn_positions_computed"]
     assert c["recurrent_bytes_in_use_sum"] > 0
     assert c["kv_positions_read_layers_wide"] > 0
     text = e.telemetry.render_prometheus()
-    for name in ("gdn_positions", "gdn_state_rw", "recurrent_bytes_in_use"):
+    for name in ("gdn_positions", "gdn_state_rw", "gdn_positions_computed",
+                 "recurrent_bytes_in_use"):
         assert f"ds_serving_{name}" in text, name
 
 
@@ -596,7 +702,6 @@ def test_frame_lowering_names_the_mixers_scopes(width, whole, monkeypatch):
     the kernels every model of K and V by head has, and the routed block's
     scopes."""
     import re
-    from deepspeed_tpu.inference.v2 import model_runner
     monkeypatch.setattr(model_runner, "_use_pallas_paged", lambda: True)
     model, params = whole
     e = engine(model, params)
@@ -619,6 +724,24 @@ def test_frame_lowering_names_the_mixers_scopes(width, whole, monkeypatch):
         assert all("/attn/" in "/" + n.split(f"/{scope}/")[0] + "/"
                    for n in under), scope
     assert any("attn_out/attn_gate/" in n for n in names)
+    # a wide step's rule by rows: the loop, every op of its body (the
+    # gathers, the chunked form's products, the writes) and the recurrence
+    # that runs beside it sit under ``gdn_scan`` inside ``attn``, so
+    # ``gdn_share`` and ``gdn_scan_roofline`` see all of it; a narrow step
+    # has the recurrence and no loop
+    mixer = [("/" + n).split("/attn/", 1)[1]
+             for joined in names for n in joined.split(";")
+             if "/attn/" in "/" + n]
+    loops = [n for n in mixer if "while" in n.split("/")]
+    chunked = [n for n in names if "bhnij,bhnjk->bhnik" in n]
+    recurrence = [n for n in mixer if "bhkv,bhk->bhv" in n]
+    assert bool(loops) == bool(chunked) == (width > 1)
+    assert all((n + "/").startswith("gdn_scan/while/") for n in loops)
+    assert all("/attn/gdn_scan/while/body/gdn_scan/" in "/" + n
+               for n in chunked)
+    assert recurrence and all(n.startswith("gdn_scan/")
+                              and "while" not in n.split("/")
+                              for n in recurrence)
     parts = {p for n in names for p in n.split("/")}
     assert {f"paged_attn_c{width}", f"kv_commit_c{width}", "moe_route",
             "moe_dispatch", "moe_experts", "moe_combine", "moe_shared",
