@@ -19,6 +19,18 @@ product, at the rows the cells' rungs give (128 / 1,152 / 2,176 / 4,224 /
 8,320 / 16,384 selection rows) and the three served models' (K, N, groups),
 over even groups and groups drawn at the cell's ``expert_load_max_over_mean``;
 one JSON line a row, the bytes and FLOPs floors beside the times.
+
+``--bookkeeping-sweep`` times what a routed block does AROUND its products,
+on the chip: ``apply_moe_grouped``'s route, dispatch and combine with the
+products stubbed out, us a layer, at the three wide cells' shapes (tokens x k
+x hidden = 1,040 x 12 x 6,144; 1,040 x 10 x 2,048; 528 x 8 x 2,304) over the
+share of the router's experts the chip holds (2 / 25 / 100%) and the live
+share of the positions: the unbounded form (every selection row moved, what
+every tree before PR 45 ran) beside the bounded one at each ``--blocks`` rows
+a trip, then what the rule ships at that shape (``layers._move_block``) and
+the same with the gather's buffer zeroed first instead of unwritten; one JSON
+line a row, the rows in groups and the buffer's MB
+beside the times.
 """
 
 import functools
@@ -184,7 +196,7 @@ def grouped_sweep(args):
     # "256": that row tile with the rule's tk, tn; "128x6144x1024": all three
     tilings = [None] + [tuple(map(int, t.split("x")))
                         for t in args.tilings.split(",") if t]
-    for name in args.models.split(","):
+    for name in args.models.split(",") if args.models else SWEEP_MODELS:
         hidden, width, groups, skew, held, layers = SWEEP_MODELS[name]
         for k, n in ((hidden, width), (width, hidden)):
             # made on the device: a cell's stack is 1-2 GB
@@ -237,6 +249,108 @@ def grouped_sweep(args):
                     print(json.dumps(row), flush=True)
 
 
+# (tokens a wide step's rung holds, selections a token, hidden size, the
+# router's outputs): the three cells whose routed layers run wide (ledger, PR 44)
+BOOKKEEPING_SHAPES = {
+    "longcat-flash-omni": (1040, 12, 6144, 768),
+    "qwen3-next-80b-a3b": (1040, 10, 2048, 512),
+    "mellum2-12b-a2.5b": (528, 8, 2304, 64),
+}
+BOOKKEEPING_SHARES = (0.02, 0.25, 1.0)
+
+
+def bookkeeping_sweep(args):
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.models import layers as L
+    from deepspeed_tpu.models.config import TransformerConfig
+    from deepspeed_tpu.ops.pallas import grouped_gemm as G
+    dev = jax.devices()[0]
+    if dev.platform != "tpu" and not args.anywhere:
+        sys.exit(f"--bookkeeping-sweep needs the chip, found {dev.platform}")
+    # no products: the rows come back as they went in, through a barrier so
+    # that the sorted buffer is made as a kernel's operand is
+    G.moe_expert_ffn = lambda tokens, *_: jax.lax.optimization_barrier(tokens)
+    rule, unwritten = L._move_block, G.unwritten
+
+    def timed(cfg, params, x, live, block, zeroed=False):
+        """(us a layer, rows in groups, rows moved) of ``iters`` routed
+        blocks in one program, each over the last one's output, at ``block``
+        rows a trip (None: unbounded; "rule": what ships). ``zeroed``: the
+        gather's buffer starts as zeros, not unwritten."""
+        L._move_block = rule if block == "rule" else lambda cfg, rows: block
+        G.unwritten = (lambda rows, like: jnp.zeros(
+            (rows, like.shape[1]), like.dtype)) if zeroed else unwritten
+        rows = x.shape[1] * cfg.num_experts_per_tok
+
+        @jax.jit
+        def loop(params, x, live):
+            def body(_, carry):
+                c, held, moved = carry
+                out, _, sizes, *_ = L.apply_moe_grouped(params, c, cfg,
+                                                        live=live)
+                c = (0.5 * c + 0.5 * out).astype(c.dtype)
+                return (c, held + jnp.sum(sizes),
+                        moved + L.moe_rows_moved(cfg, sizes, rows))
+            zero = jnp.zeros((), jnp.int32)
+            return jax.lax.fori_loop(0, args.iters, body, (x, zero, zero))
+        try:
+            jax.block_until_ready(loop(params, x, live))
+            best = float("inf")
+            for _ in range(3):
+                t = time.perf_counter()
+                _, held, moved = jax.block_until_ready(loop(params, x, live))
+                best = min(best, time.perf_counter() - t)
+        finally:
+            L._move_block, G.unwritten = rule, unwritten
+        return (best / args.iters * 1e6, int(held) / args.iters,
+                int(moved) / args.iters)
+
+    rng = np.random.default_rng(args.seed)
+    blocks = [int(b) for b in args.blocks.split(",") if b]
+    for name in args.models.split(",") if args.models else BOOKKEEPING_SHAPES:
+        tokens, k, hidden, width = BOOKKEEPING_SHAPES[name]
+        if args.anywhere and dev.platform != "tpu":
+            tokens, hidden = tokens // 8, hidden // 16   # a rehearsal
+        for share in BOOKKEEPING_SHARES:
+            held = max(1, round(width * share))
+            cfg = TransformerConfig(
+                vocab_size=256, hidden_size=hidden, num_layers=1, num_heads=8,
+                intermediate_size=128, moe_intermediate_size=128,
+                num_experts=held, num_experts_per_tok=k, moe_impl="grouped",
+                max_seq_len=4096, dtype="bfloat16",
+                **({} if held == width else {"moe_router_experts": width}))
+            params = {"router": jnp.asarray(
+                          rng.normal(size=(hidden, width)), jnp.float32),
+                      **{n: jnp.zeros((1,), jnp.bfloat16)
+                         for n in L.EXPERT_MATRICES}}
+            x = jnp.asarray(rng.normal(size=(1, tokens, hidden)), jnp.bfloat16)
+            for live_share in map(float, args.live.split(",")):
+                live = jnp.asarray(
+                    rng.random((1, tokens)) < live_share)
+                row = {"model": name, "tokens": tokens, "k": k,
+                       "hidden": hidden, "router_width": width, "held": held,
+                       "live_share": live_share,
+                       "buffer_mb": round(tokens * k * hidden * 2 / 1e6, 1)}
+                us, in_groups, _ = timed(cfg, params, x, live, None)
+                row.update(rows=tokens * k, rows_in_groups=round(in_groups),
+                           unbounded_us=round(us, 1))
+                for block in blocks:
+                    us, _, moved = timed(cfg, params, x, live, block)
+                    row[f"bounded_us_b{block}"] = round(us, 1)
+                    row[f"rows_moved_b{block}"] = round(moved)
+                # what ships: the rule's block, or the unbounded lines
+                block = rule(cfg, tokens * k)
+                us, _, moved = timed(cfg, params, x, live, "rule")
+                row.update(rule_block=block, rule_us=round(us, 1),
+                           rule_rows_moved=round(moved))
+                if block:
+                    row["rule_zeroed_us"] = round(timed(
+                        cfg, params, x, live, block, zeroed=True)[0], 1)
+                row["device"] = dev.device_kind
+                print(json.dumps(row), flush=True)
+
+
 def main():
     import argparse
     ap = argparse.ArgumentParser()
@@ -245,16 +359,30 @@ def main():
     ap.add_argument("--grouped-sweep", action="store_true",
                     help="the grouped product alone: ragged_dot against the "
                          "kernel, us a product (needs the chip)")
-    ap.add_argument("--models", default=",".join(SWEEP_MODELS))
+    ap.add_argument("--models", default="",
+                    help="a sweep's models (default: all of its own)")
     ap.add_argument("--rows", default=",".join(map(str, SWEEP_ROWS)))
     ap.add_argument("--tilings", default="",
                     help="tilings to time beside the rule's: TM or TMxTKxTN, "
                          "comma-separated")
+    ap.add_argument("--bookkeeping-sweep", action="store_true",
+                    help="a routed block's route, dispatch and combine "
+                         "without its products: every row moved against "
+                         "the bounded form, us a layer (needs the chip)")
+    ap.add_argument("--blocks", default="128,256,512,1024",
+                    help="--bookkeeping-sweep: rows a trip to time")
+    ap.add_argument("--live", default="0.8",
+                    help="--bookkeeping-sweep: live shares of the positions")
+    ap.add_argument("--anywhere", action="store_true",
+                    help="--bookkeeping-sweep at an eighth of the size on "
+                         "whatever device there is: a rehearsal, no timing")
     ap.add_argument("--iters", type=int, default=24)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
     if args.grouped_sweep:
         return grouped_sweep(args)
+    if args.bookkeeping_sweep:
+        return bookkeeping_sweep(args)
     if args.ep_virtual:
         print(json.dumps(bench_ep_virtual(tokens=2048, hidden=256, ffn=512,
                                           experts=8, k=2)))

@@ -33,7 +33,8 @@ from ..sampling import sample_logits_per_row, speculative_verify_per_row
 from .kv_cache import dequantize_kv_lanes, quantize_kv_lanes
 from .telemetry import (LATENT_STAT_NAMES, LAYER_STAT_NAMES,   # in-graph
                         MAX_RUNGS, MOE_STAT_NAMES,             # counter
-                        MTP_STAT_NAMES, n_stats, pack_ladder)  # layout
+                        MOVED_STAT_NAMES, MTP_STAT_NAMES,      # layout
+                        n_stats, pack_ladder)
 
 
 def _use_pallas_paged() -> bool:
@@ -444,17 +445,23 @@ class PagedModelRunner:
                                          for n in L.EXPERT_MATRICES}}
             out, _, groups, *picks = L.apply_moe_mlp(
                 experts, m_in, cfg, live=live, layer=at_layer)
-            work = jnp.stack([jnp.sum(groups), jnp.sum(groups > 0),
-                              jnp.max(groups)]).astype(jnp.int32)
+            work = jnp.stack([
+                jnp.sum(groups), jnp.sum(groups > 0), jnp.max(groups),
+                L.moe_rows_moved(cfg, groups, m_in.shape[0] * m_in.shape[1]
+                                 * cfg.num_experts_per_tok)]).astype(jnp.int32)
             return out, jnp.concatenate([work] + picks) if picks else work
 
         def mlp(lp, h, y, moe, live=None):
             """The rest of the layer after attention and, in a model with
             routed experts (``routed``), their work in this layer
             (``MOE_STAT_NAMES``): rows sent through experts, experts
-            touched, the largest group."""
+            touched, the largest group, and behind them the rows its
+            dispatch moved (``MOVED_STAT_NAMES``)."""
             stack, lp = lp, at(lp)
-            work = jnp.zeros((len(MOE_STAT_NAMES),), jnp.int32)
+            # a dense model drops this value unread: its traced programs
+            # stay the ones they were, lanes and all
+            work = jnp.zeros((len(MOE_STAT_NAMES) + (
+                len(MOVED_STAT_NAMES) if routed else 0),), jnp.int32)
             if cfg.parallel_block:   # NeoX/Falcon: attn and mlp share input
                 m_in = L.apply_norm(lp["norm2"], h, cfg)
             else:

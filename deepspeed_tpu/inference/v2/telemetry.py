@@ -93,15 +93,20 @@ from ...utils.logging import logger
 #                  shape has one rung only
 #   RUNG0..        steps run at rung i of the step's ``pack_ladder``
 #                  (``MAX_RUNGS`` lanes; a one-rung shape counts in none)
-# A model with routed experts carries ``len(MOE_STAT_NAMES)`` lanes more,
-# behind these (``n_stats``); a dense model's vector, and so its frame
-# programs, have no trace of them:
+# A model with routed experts carries MOE_STAT_NAMES' and MOVED_STAT_NAMES'
+# lanes more, behind these (``n_stats``); a dense model's vector, and so its
+# frame programs, have no trace of them:
 #   EXPERT_ROWS    rows the step sent through routed experts (token x
 #                  chosen expert), summed over the MoE layers: the group
 #                  sizes the grouped product was handed
 #   EXPERTS_TOUCHED  experts that got at least one row, summed over the MoE
 #                  layers: each is a read of that expert's three matrices
 #   EXPERT_ROWS_MAX  the largest group of each MoE layer, summed over them
+#   EXPERT_ROWS_MOVED  rows the layers' dispatch gathered and their combine
+#                  added (``layers.moe_rows_moved``): the whole blocks that
+#                  hold the groups' rows where the chip holds a share of the
+#                  experts, every selection row (tokens x k, dead positions
+#                  too) elsewhere
 # KV_READ and ATTN_PAIRS are work, not events: the host splits each frame's
 # delta by the frame's width into ``<name>_narrow`` / ``<name>_wide``
 # counters (SPLIT_STAT_NAMES), the operands of the paged kernels' roofline
@@ -137,6 +142,9 @@ SPLIT_STAT_NAMES = ("kv_positions_read", "attn_pairs")
 TILE_STAT_NAMES = ("attn_row_tiles", "attn_row_tiles_live")
 #: the routed experts' work, lanes STAT_EXPERT_ROWS..: counters of their own
 MOE_STAT_NAMES = ("expert_rows", "experts_touched", "expert_rows_max")
+#: one lane right behind them (EXPERT_ROWS_MOVED above): what the routed
+#: layers' bookkeeping moved for the rows MOE_STAT_NAMES count
+MOVED_STAT_NAMES = ("expert_rows_moved",)
 
 
 #: the attention's work SUMMED OVER LAYERS with each layer's own window,
@@ -192,7 +200,8 @@ def n_stats(routed: bool, layered: bool = False, share: bool = False,
     experts (all of them held, or a share), of mixed cache kinds or of
     one, with latent attention or without, with a prediction module or
     without, with linear layers or without."""
-    return (N_STATS + (len(MOE_STAT_NAMES) if routed else 0)
+    return (N_STATS
+            + (len(MOE_STAT_NAMES) + len(MOVED_STAT_NAMES) if routed else 0)
             + (len(SHARE_STAT_NAMES) if share else 0)
             + (len(LAYER_STAT_NAMES) if layered else 0)
             + (len(LATENT_STAT_NAMES) if latent else 0)
@@ -1298,11 +1307,14 @@ class ServingTelemetry:
             delta, tail = np.split(delta, [len(delta) - len(self._tail_names)])
             layers = dict(zip(self._tail_names, map(int, tail)))
         # a dense model's vector ends with the rung lanes
-        moe_names = MOE_STAT_NAMES + (SHARE_STAT_NAMES if self._share else ())
-        moe = dict.fromkeys(moe_names, 0)
+        moe_names = MOE_STAT_NAMES + MOVED_STAT_NAMES \
+            + (SHARE_STAT_NAMES if self._share else ())
+        # (its counters read 0; MOVED_STAT_NAMES' exists once a model with
+        # routed experts has run a frame)
+        moe = dict.fromkeys(MOE_STAT_NAMES, 0)
         moe.update(zip(moe_names, map(int, delta[STAT_EXPERT_ROWS:])))
         for name, value in moe.items():
-            self.counters[name] += value
+            self.counters[name] = self.counters.get(name, 0) + value
         if self.trace:
             # this frame's work on the profiler's clock, right after its
             # serve_frame span: a reduction of the trace matches work to

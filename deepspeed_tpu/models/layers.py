@@ -788,6 +788,91 @@ def _apply_shared_expert(params, x, cfg: TransformerConfig):
     return gate * sh
 
 
+#: about what a trip of the bounded dispatch and combine moves, in whole row
+#: tiles of 128: past it the chip's scatter-add of a block costs more a row
+#: (``benchmarks/moe_bench.py --bookkeeping-sweep``: 256 rows of 6,144 take
+#: 2.5 times what 128 take a row, 512 of 2,048 twice what 256 take)
+MOVE_BYTES = 1 << 20
+
+
+def _move_block(cfg: TransformerConfig, rows: int):
+    """Rows a trip of the bounded dispatch and combine moves, from static
+    shapes alone (``rows`` = T x k of the sorted buffer): the row tiles
+    nearest ``MOVE_BYTES``. None where the bound could save next to nothing
+    and the unbounded lines run: two blocks hold every row (every narrow
+    program), or the experts are all held (not ``cfg.moe_is_share``), where
+    the rows in groups are the live positions', over half of any rung
+    (``pack_ladder``), and one pass over every row is the cheaper form."""
+    tile = 128 * cfg.hidden_size * jnp.dtype(cfg.act_dtype).itemsize
+    block = 128 * max(1, round(MOVE_BYTES / tile))
+    return block if cfg.moe_is_share and rows > 2 * block else None
+
+
+def _move_trips(n, block: int):
+    """Blocks that hold the ``n`` rows of the groups, which sort first: the
+    trip count of the two loops below, taken in the graph."""
+    return (n + block - 1) // block
+
+
+def moe_rows_moved(cfg: TransformerConfig, group_sizes, rows: int):
+    """Rows one routed block's dispatch gathered (= its combine added) for
+    ``group_sizes`` out of ``rows`` = T x k selections (serving): whole
+    blocks over the groups' rows, every row where the bound cannot bind
+    (``_move_block``) or the dispatch is not the dropless one."""
+    block = _move_block(cfg, rows) if cfg.moe_impl == "grouped" else None
+    if block is None:
+        return jnp.asarray(rows, jnp.int32)
+    return (_move_trips(jnp.sum(group_sizes), block)
+            * block).astype(jnp.int32)
+
+
+def _block_at(i, block: int, rows: int):
+    """First row of block ``i`` of a buffer of ``rows``: the last block of
+    a buffer that is no multiple of ``block`` ends with the buffer, over
+    rows the block before it holds too."""
+    return jnp.minimum(i * block, rows - block)
+
+
+def _gather_held(tokens, tok_of_sorted, trips, block: int):
+    """``take(tokens, tok_of_sorted)`` for the first ``trips`` blocks of
+    rows, a block a trip of one loop; the rows behind them belong to no
+    group and no trip writes them (``grouped_gemm.unwritten``: on the chip
+    they hold anything)."""
+    from ..ops.pallas.grouped_gemm import unwritten
+    rows = tok_of_sorted.shape[0]
+
+    def trip(i, buf):
+        at = _block_at(i, block, rows)
+        idx = jax.lax.dynamic_slice(tok_of_sorted, (at,), (block,))
+        return jax.lax.dynamic_update_slice(
+            buf, tokens.at[idx].get(mode="promise_in_bounds"), (at, 0))
+
+    return jax.lax.fori_loop(0, trips, trip, unwritten(rows, tokens))
+
+
+def _add_held(rows, w_sorted, tok_of_sorted, n, block: int, t: int):
+    """The weighted rows of the groups (the first ``n``) added to their
+    tokens' (t, E) outputs, a block a trip. A row past ``n`` is unwritten on
+    the chip and may hold anything: the last block's tail is taken out by
+    ``where``, never by a product, and no block behind it is read."""
+    total, e = rows.shape
+
+    def trip(i, out):
+        at = _block_at(i, block, total)
+        row = at + jnp.arange(block)
+        mine = (row >= i * block) & (row < n)
+        blk = jnp.where(
+            mine[:, None],
+            jax.lax.dynamic_slice(rows, (at, 0), (block, e)),
+            jnp.zeros((), rows.dtype))
+        w = jax.lax.dynamic_slice(w_sorted, (at,), (block,))
+        tok = jax.lax.dynamic_slice(tok_of_sorted, (at,), (block,))
+        return out.at[tok].add(blk * w[:, None], mode="promise_in_bounds")
+
+    return jax.lax.fori_loop(0, _move_trips(n, block), trip,
+                             jnp.zeros((t, e), rows.dtype))
+
+
 def apply_moe_grouped(params, x, cfg: TransformerConfig, live=None,
                       layer=None):
     """Dropless grouped-GEMM MoE (megablox pattern; reference analog:
@@ -807,6 +892,12 @@ def apply_moe_grouped(params, x, cfg: TransformerConfig, live=None,
     written (zero on the CPU, whatever the buffer held on the chip), so the
     combine zeroes them. With ``live`` the group sizes come back as a third
     value (the rows each expert computed).
+    Where the chip holds a share of the router's experts
+    (``cfg.moe_is_share``) the rows of the groups, which sort first, are few
+    of the T x k: the gather of token rows and the weighted scatter-add then
+    run over the blocks that hold them alone (``_gather_held``,
+    ``_add_held``: a trip count taken in the graph from ``group_sizes``;
+    ``_move_block`` says where, ``moe_rows_moved`` counts them).
     ``layer``: the three expert matrices in ``params`` are stacked over
     layers and this is the one to use (``grouped_gemm`` says why).
     """
@@ -842,22 +933,32 @@ def apply_moe_grouped(params, x, cfg: TransformerConfig, live=None,
                                       expert_of_row, n_exp)
         order = jnp.argsort(expert_of_row, stable=True)
         tok_of_sorted = order // k                            # token each row copies
-        sorted_tokens = jnp.take(tokens, tok_of_sorted, axis=0)   # (T*k, E)
+        block = _move_block(cfg, t * k)
+        if block is None:   # (before the group sizes: the trace it was)
+            sorted_tokens = jnp.take(tokens, tok_of_sorted, axis=0)  # (T*k, E)
         # bincount drops what lies past ``length``: the dead rows
         group_sizes = jnp.bincount(expert_of_row,
                                    length=n_exp).astype(jnp.int32)
+        if block is not None:
+            in_groups = jnp.sum(group_sizes)
+            sorted_tokens = _gather_held(tokens.astype(dt), tok_of_sorted,
+                                         _move_trips(in_groups, block), block)
 
     with jax.named_scope("moe_experts"):
         rows = moe_expert_ffn(sorted_tokens.astype(dt), params["wi_gate"],
                               params["wi_up"], params["wo"], group_sizes,
                               layer)
     with jax.named_scope("moe_combine"):
-        if live is not None or share:
+        if block is None and (live is not None or share):
             in_group = jnp.arange(t * k) < jnp.sum(group_sizes)
             rows = jnp.where(in_group[:, None], rows, jnp.zeros((), dt))
         w_sorted = jnp.take(w.reshape(-1), order, axis=0).astype(dt)
-        out = jnp.zeros((t, e), dt).at[tok_of_sorted].add(
-            rows * w_sorted[:, None])
+        if block is None:
+            out = jnp.zeros((t, e), dt).at[tok_of_sorted].add(
+                rows * w_sorted[:, None])
+        else:
+            out = _add_held(rows, w_sorted, tok_of_sorted, in_groups, block,
+                            t)
         if cfg.moe_zero_experts:
             out = out + tokens.astype(dt) * jnp.sum(
                 jnp.where(is_zero, w, 0.0), axis=-1, keepdims=True).astype(dt)

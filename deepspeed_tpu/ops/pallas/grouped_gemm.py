@@ -53,6 +53,22 @@ def _kernel_runs():
     return manual == set(mesh.axis_names) if manual else mesh.devices.size == 1
 
 
+def unwritten(rows, like):
+    """A (rows, E) buffer that nothing has written, of the dtype of ``like``
+    (T, E) and varying as it does, for rows that are filled as far as their
+    reader goes and no further: where the kernel runs, the result of a call
+    no step of which touches it (whatever the memory held, as the product
+    leaves its rows past the groups); zeros elsewhere."""
+    shape = (rows, like.shape[1])
+    if not _kernel_runs():
+        return jnp.zeros(shape, like.dtype)
+    return pl.pallas_call(
+        lambda out: None, name="unwritten_rows",
+        out_shape=jax.ShapeDtypeStruct(shape, like.dtype,
+                                       vma=jax.typeof(like).vma),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY))()
+
+
 def _cuts(x):
     """``x`` and the multiples of 128 that divide it, largest first: the
     sizes a block may have along a lane or contraction axis."""

@@ -695,7 +695,7 @@ def test_longcat_frame_programs_fit_the_chip(one_chip, as_tpu, width):
                jnp.bfloat16)
     key = jax.random.PRNGKey(0)
     runner = PagedModelRunner(model, PAGE, seq // PAGE)
-    assert runner.n_stats == 18 + 3 + 3 + 2
+    assert runner.n_stats == 18 + 4 + 3 + 2
     compiled = runner._build_frame_loop().lower(
         params, sds((slots, seq), i32), row, row, row,
         sds((slots,), jnp.float32), sds((slots, seq // PAGE), i32), row, row,
@@ -720,6 +720,21 @@ def test_longcat_frame_programs_fit_the_chip(one_chip, as_tpu, width):
         r"= bf16\[(?:1,)?(?:16,(?:6144,2048|2048,6144)|2,(?:6144,12288|"
         r"12288,6144))\]\S* (?!parameter|bitcast)(\w[\w-]*)\(", text)
     assert not stacks, stacks
+    # the chip holds a share: a wide rung's dispatch and combine move blocks
+    # of rows in two loops over a buffer nothing zeroes, and XLA makes no
+    # value of a rung's (tokens x 12, 6144) selection rows (it selected,
+    # weighted and scattered every one of them before; the scheduler may
+    # still move the smallest rung's buffer into fast memory for the product)
+    ladder = [rung for rung in pack_ladder(slots, width) if 12 * rung > 256]
+    assert len(ladder) == (5 if width > 1 else 0)
+    assert len(re.findall(r"%unwritten_rows\S* = ", text)) == len(ladder)
+    sorted_rows = "|".join(str(12 * rung) for rung in ladder) or "none"
+    made = re.findall(
+        rf"= bf16\[(?:{sorted_rows}),6144\]\S* (?!parameter|bitcast|"
+        rf"get-tuple-element|custom-call|dynamic-update-slice|copy-start|"
+        rf"copy-done)(\w[\w-]*)\(",
+        text)
+    assert not made, made
     m = compiled.memory_analysis()
     total = m.argument_size_in_bytes + m.temp_size_in_bytes
     print(f"longcat frame program, width {width}: args "
@@ -819,7 +834,7 @@ def test_glm_frame_programs_fit_the_chip(one_chip, as_tpu, width):
                jnp.bfloat16)
     key = jax.random.PRNGKey(0)
     runner = PagedModelRunner(model, PAGE, seq // PAGE)
-    assert runner.has_mtp and runner.n_stats == 18 + 3 + 2 + 3
+    assert runner.has_mtp and runner.n_stats == 18 + 4 + 2 + 3
     compiled = runner._build_frame_loop().lower(
         params, sds((slots, 2048), i32), row, row, row,
         sds((slots,), jnp.float32), sds((slots, seq // PAGE), i32), row, row,
@@ -909,7 +924,7 @@ def test_qwen3_next_frame_programs_fit_the_chip(one_chip, as_tpu, width):
     pool = sds((2, 2, pages, PAGE, 256), jnp.bfloat16)
     key = jax.random.PRNGKey(0)
     runner = PagedModelRunner(model, PAGE, seq // PAGE)
-    assert runner.n_stats == 18 + 3 + 3 + 3 + 3
+    assert runner.n_stats == 18 + 4 + 3 + 3 + 3
     state, tail = (sds(*shape) for shape in runner.recurrent_shapes(slots))
     assert state.shape == (6, 16, 32, 128, 128) and state.dtype == jnp.float32
     assert tail.shape == (6, 3, 16, 8192) and tail.dtype == jnp.bfloat16

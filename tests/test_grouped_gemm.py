@@ -181,6 +181,146 @@ def test_dead_positions_rows_are_left_alone(request):
     assert not np.asarray(got[0])[~np.asarray(live)].any()
 
 
+def _routed_block(held, dtype):
+    """A routed block of top 4 over 16 outputs of which the chip holds
+    ``held`` (16: all of them, no share), and 40 x 4 = 160 selection rows."""
+    from deepspeed_tpu.models import layers as L
+    from deepspeed_tpu.models.config import TransformerConfig
+    share = {} if held == 16 else dict(
+        moe_router_experts=14, moe_expert_first=4, moe_zero_experts=2)
+    cfg = TransformerConfig(
+        vocab_size=64, hidden_size=32, num_layers=1, num_heads=4,
+        intermediate_size=64, moe_intermediate_size=16, num_experts=held,
+        num_experts_per_tok=4, moe_impl="grouped", max_seq_len=64,
+        dtype=dtype, **share)
+    params, _ = L.init_moe_mlp(jax.random.PRNGKey(0), cfg)
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (4, 10, 32),
+                                  cfg.act_dtype)) + 0.1
+    return cfg, params, x
+
+
+#: (experts held of 16 outputs, live mask over the 40 positions, rows a
+#: trip): the rows in groups ``n`` beside the 160 selection rows
+BOUNDED = {
+    # every pick held and live: n = T x k, the last of 3 blocks of 64 ends
+    # with the buffer, over 32 rows the second holds too
+    "all-held": (16, "all", 64),
+    "all-held-exact-blocks": (16, "all", 32),        # n = T x k = 5 x 32
+    "all-held-dead": (16, "some", 64),               # 23 live: n = 92
+    "all-held-multiple": (16, "24", 32),             # n = 96 = 3 x 32
+    "quarter": (4, "all", 32),
+    "quarter-dead": (4, "some", 32),
+    "one-of-16": (1, "all", 32),                     # a few rows in 160
+    "one-of-16-dead": (1, "some", 64),
+    "none-picked": (2, "all", 32),                   # held share 0: n = 0
+    "nothing-live": (16, "none", 32),                # n = 0
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(BOUNDED))
+def test_bounded_dispatch_and_combine_move_the_groups_rows(monkeypatch, case,
+                                                           dtype):
+    """``apply_moe_grouped`` with its gather and scatter-add bounded by the
+    rows in groups (a small block forced on every case, whatever the chip
+    holds) against the unbounded lines, the gather's buffer and the
+    product's result poisoned past what is written, as the chip leaves
+    them: outputs within the rounding of a k-term sum, no NaN, the same
+    group sizes and share counts, whole blocks counted."""
+    from deepspeed_tpu.models import layers as L
+    held, mask, block = BOUNDED[case]
+    cfg, params, x = _routed_block(held, dtype)
+    if case == "none-picked":
+        # positive tokens against negative columns: no pick is a held expert
+        first = cfg.moe_expert_first
+        params["router"] = jnp.abs(params["router"]).at[
+            :, first:first + held].multiply(-1)
+    live = {"all": jnp.ones((4, 10), bool), "none": jnp.zeros((4, 10), bool),
+            "24": jnp.arange(40).reshape(4, 10) < 24,
+            "some": jnp.arange(40).reshape(4, 10) % 7 % 2 == 0}[mask]
+    run = lambda: jax.jit(lambda p, x, l: L.apply_moe_grouped(
+        p, x, cfg, live=l))(params, x, live)
+    monkeypatch.setattr(L, "_move_block", lambda cfg, rows: None)
+    want = run()
+    n = int(want[2].sum())
+    assert (n == 0) == (case in ("none-picked", "nothing-live"))
+    assert n == {"all-held": 160, "all-held-exact-blocks": 160,
+                 "all-held-multiple": 96}.get(case, n)
+    real = G.moe_expert_ffn
+
+    def poisoned(tokens, wg, wu, wo, sizes, layer=None):
+        rows = real(tokens, wg, wu, wo, sizes, layer)
+        return jnp.where((jnp.arange(rows.shape[0]) < jnp.sum(sizes))[:, None],
+                         rows, jnp.nan)
+    monkeypatch.setattr(G, "moe_expert_ffn", poisoned)
+    monkeypatch.setattr(G, "unwritten", lambda rows, like: jnp.full(
+        (rows, like.shape[1]), jnp.nan, like.dtype))
+    monkeypatch.setattr(L, "_move_block", lambda cfg, rows: block)
+    assert "while" in str(jax.make_jaxpr(lambda p, x, l: L.apply_moe_grouped(
+        p, x, cfg, live=l))(params, x, live))
+    got = run()
+    assert not np.isnan(np.asarray(got[0], np.float32)).any()
+    tol = 1e-5 if dtype == "float32" else 2.0 ** -6
+    np.testing.assert_allclose(
+        np.asarray(got[0], np.float32), np.asarray(want[0], np.float32),
+        rtol=tol, atol=tol * float(jnp.abs(want[0]).max()))
+    for a, b in zip(got[2:], want[2:]):
+        np.testing.assert_array_equal(a, b)
+    assert not np.asarray(got[0], np.float32)[~np.asarray(live)].any() \
+        or cfg.moe_zero_experts        # a zero expert answers a dead token too
+    assert int(L.moe_rows_moved(cfg, got[2], 160)) == -(-n // block) * block
+
+
+def test_a_block_where_every_row_is_in_a_group_keeps_the_unbounded_lines(
+        monkeypatch):
+    """Without ``live`` and with every expert held (training, the v1
+    modules) every row is in a group by construction: the trace is the one
+    it is when the bound cannot bind, with no loop in it."""
+    from deepspeed_tpu.models import layers as L
+    cfg, params, x = _routed_block(16, "float32")
+    trace = lambda: str(jax.make_jaxpr(
+        lambda p, x: L.apply_moe_grouped(p, x, cfg))(params, x))
+    want = trace()
+    monkeypatch.setattr(L, "MOVE_BYTES", 1)       # a row tile a trip
+    assert L._move_block(cfg, 4096) is None
+    assert L._move_block(_routed_block(4, "float32")[0], 4096) == 128
+    assert trace() == want and "while" not in want
+    grads = jax.grad(lambda p: jnp.sum(L.apply_moe_grouped(p, x, cfg)[0]))(
+        params)
+    assert float(jnp.abs(grads["wo"]).max()) > 0
+
+
+@pytest.mark.parametrize("name,cut,rows,block", [
+    # the wide rungs of the two cells whose chip holds a share, and a narrow
+    # step's 16 slots x k: one block
+    ("longcat-flash-omni", dict(num_experts=16, moe_router_experts=512),
+     1040 * 12, 128),
+    ("longcat-flash-omni", dict(num_experts=16, moe_router_experts=512),
+     528 * 12, 128),
+    ("longcat-flash-omni", dict(num_experts=16, moe_router_experts=512),
+     16 * 12, None),
+    ("qwen3-next-80b-a3b", dict(num_experts=128, moe_router_experts=512),
+     1040 * 10, 256),
+    ("qwen3-next-80b-a3b", dict(num_experts=128, moe_router_experts=512),
+     16 * 10, None),
+    # every expert held: the live positions' rows fill most of any rung
+    ("mellum2-12b-a2.5b", {}, 528 * 8, None),
+    ("olmoe-1b-7b", {}, 144 * 8, None),
+    ("glm-4.7-flash", {}, 144 * 4, None)])
+def test_the_bound_binds_where_the_chip_holds_a_share(name, cut, rows, block):
+    """``_move_block`` from the served configurations' static shapes: about
+    a MiB of bf16 rows a trip where the router is wider than the experts
+    held, None (the unbounded lines) everywhere else; ``moe_rows_moved``
+    counts whole blocks there and every row here."""
+    from deepspeed_tpu.models import get_config, layers as L
+    cfg = get_config(name, dtype="bfloat16", moe_impl="grouped", **cut)
+    assert cfg.moe_is_share == bool(cut)
+    assert L._move_block(cfg, rows) == block
+    sizes = jnp.zeros((cfg.num_experts,), jnp.int32).at[3].set(130).at[0].set(7)
+    assert int(L.moe_rows_moved(cfg, sizes, rows)) == (
+        rows if block is None else -(-137 // block) * block)
+
+
 def _tiny_moe_pair():
     from deepspeed_tpu.models import build_model, get_config
     cfg = get_config("tiny-moe").replace(moe_capacity_factor=8.0)

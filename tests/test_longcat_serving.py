@@ -194,7 +194,7 @@ def test_served_path_matches_the_reference(monkeypatch, whole, reference,
     for logits, last, work, live in paged_steps(e, params, seqs):
         for s, at in last.items():
             worst = max(worst, np.abs(logits[s] - want[s][at]).max())
-        rows, _, _, picked, zeros, absent = work
+        rows, _, _, _, picked, zeros, absent = work
         assert picked == live * CONFIG["moe_topk"] * LAYERS
         assert rows + zeros + absent == picked
         assert (absent == 0) == (held == EXPERTS)

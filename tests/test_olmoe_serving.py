@@ -287,7 +287,8 @@ def test_expert_lanes_ride_only_a_model_that_routes(model_params):
     dense = build_model("tiny")
     for model, params, lanes in (
             (dense, dense.init(jax.random.PRNGKey(0)), 0),
-            (*model_params, len(T.MOE_STAT_NAMES))):
+            (*model_params,
+             len(T.MOE_STAT_NAMES) + len(T.MOVED_STAT_NAMES))):
         runner = PagedModelRunner(model, 16, 8)
         assert runner.n_stats == T.N_STATS + lanes
         cfg = runner.cfg

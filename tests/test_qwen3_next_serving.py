@@ -682,7 +682,7 @@ def test_a_share_is_served_as_the_reference_computes_it(whole, reference):
     for logits, last, work, live, _ in paged_steps(e, params, seqs):
         for s, at in last.items():
             worst = max(worst, np.abs(logits[s] - want[s][at]).max())
-        rows, _, _, picked, zeros, away = work
+        rows, _, _, _, picked, zeros, away = work
         assert picked == live * 4 * LAYERS and rows + away == picked
         assert zeros == 0
         absent += away

@@ -789,11 +789,12 @@ def _mixed_engine():
 
 def test_layered_lanes_and_gauges_exist_for_a_model_of_mixed_kinds():
     from deepspeed_tpu.inference.v2.telemetry import (LAYER_STAT_NAMES,
-                                                      MOE_STAT_NAMES, N_STATS,
-                                                      n_stats)
+                                                      MOE_STAT_NAMES,
+                                                      MOVED_STAT_NAMES,
+                                                      N_STATS, n_stats)
     e = _mixed_engine()
     assert e.runner.n_stats == N_STATS + len(MOE_STAT_NAMES) \
-        + len(LAYER_STAT_NAMES) == n_stats(True, True)
+        + len(MOVED_STAT_NAMES) + len(LAYER_STAT_NAMES) == n_stats(True, True)
     rng = np.random.default_rng(3)
     prompts = {u: rng.integers(0, 256, n).astype(np.int32)
                for u, n in ((0, 45), (1, 9))}
@@ -881,12 +882,15 @@ def _latent_engine():
 
 def test_latent_and_share_lanes_exist_for_such_a_model():
     from deepspeed_tpu.inference.v2.telemetry import (LATENT_STAT_NAMES,
-                                                      MOE_STAT_NAMES, N_STATS,
+                                                      MOE_STAT_NAMES,
+                                                      MOVED_STAT_NAMES,
+                                                      N_STATS,
                                                       SHARE_STAT_NAMES,
                                                       n_stats)
     e = _latent_engine()
     assert e.runner.n_stats == N_STATS + len(MOE_STAT_NAMES) \
-        + len(SHARE_STAT_NAMES) + len(LATENT_STAT_NAMES) \
+        + len(MOVED_STAT_NAMES) + len(SHARE_STAT_NAMES) \
+        + len(LATENT_STAT_NAMES) \
         == n_stats(True, share=True, latent=True)
     assert e.runner.latent_layers == 4 and e.kv.v is None
     rng = np.random.default_rng(3)
