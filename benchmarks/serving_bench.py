@@ -1702,32 +1702,6 @@ def bench_quant(model_name, batch, prompt_len, new_tokens, n_arrivals=8):
     }
 
 
-def bench_mixed_compiled(model_name, batch, prompt_lens, new_tokens):
-    """Mixed SplitFuse via the COMPILED loop (generate_compiled): staggered
-    prompt lengths make early finishers decode inside wide prefill steps —
-    the same fused mixed step, with zero host driving between steps."""
-    eng = _mk_engine(model_name, batch)
-    rng = np.random.default_rng(2)
-    vocab = eng.model.cfg.vocab_size
-    prompts = [rng.integers(0, vocab, (prompt_lens[i % len(prompt_lens)],))
-               .astype(np.int32) for i in range(batch)]
-    eng.generate_compiled(prompts, max_new_tokens=new_tokens)   # compile
-    t0 = time.perf_counter()
-    outs = eng.generate_compiled(prompts, max_new_tokens=new_tokens)
-    dt = time.perf_counter() - t0
-    produced = sum(len(o) for o in outs)
-    return {
-        "workload": "mixed-splitfuse-compiled", "batch": batch,
-        "prompt_lens": list(prompt_lens), "new_tokens": new_tokens,
-        "generated_tok_per_sec": round(produced / dt, 1),
-        "e2e_tok_per_sec": round(
-            (produced + sum(len(p) for p in prompts)) / dt, 1),
-        "note": "one jit for chunked prefill + staggered transitions + "
-                "decode; compare generated_tok_per_sec with the host-driven "
-                "mixed-splitfuse row",
-    }
-
-
 def bench_decode_collapse_probe(model_name, prompt_len, new_tokens):
     """Round-3 left the batch-64 decode collapse (3.2x the batch-32 step
     time) unexplained. Probe the two candidate causes directly: KV-pool
@@ -2495,7 +2469,6 @@ def main():
         decode_cfgs = [(8, 128, 128), (32, 128, 128), (64, 128, 128)]
         prefill_cfgs = [(8, long_prompt)]
         mixed = (16, 256, 64)
-        mixed_compiled = (16, (256, 64), 64)
         mixed_dynamic = (16, 256, 64, 32)      # last field: n_arrivals
         delta = (32, 512, 128)
         # near-full contexts (832 + 128 + 1 lookahead slot = 961 <= 1024,
@@ -2508,7 +2481,6 @@ def main():
         decode_cfgs = [(4, 16, 16)]
         prefill_cfgs = [(4, long_prompt)]
         mixed = (4, 32, 8)
-        mixed_compiled = (4, (32, 16), 8)
         mixed_dynamic = (4, 32, 8, 8)
         delta = (4, 32, 16)
         delta_long = None
@@ -2756,8 +2728,6 @@ def main():
     for b, p in prefill_cfgs:
         guarded("prefill-heavy", bench_prefill, model, b, p)
     guarded("mixed-splitfuse", bench_mixed, model, *mixed)
-    guarded("mixed-splitfuse-compiled", bench_mixed_compiled, model,
-            *mixed_compiled)
     b, p, n, arr = mixed_dynamic
     guarded("mixed-splitfuse-dynamic", bench_mixed_dynamic, model, b, p, n,
             n_arrivals=arr)

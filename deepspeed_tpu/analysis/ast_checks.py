@@ -385,15 +385,12 @@ def _check_node(path: str, region_name: str, node: ast.AST,
 #: callee attr name -> (positions of donated args AT THE CALL SITE,
 #: counting positional args only). Derived from the runner's jit
 #: donate_argnums shifted by any leading non-jit params of the wrapper
-#: (frame_loop_spec/mixed_loop_spec take draft_runner first, run takes
-#: chunk first). tests/test_static_analysis.py cross-checks these against
-#: the live ``Traced.donate_argnums`` so the table cannot rot silently.
+#: (frame_loop_spec takes draft_runner first, run takes chunk first).
+#: tests/test_static_analysis.py cross-checks these against the live
+#: ``Traced.donate_argnums`` so the table cannot rot silently.
 DISPATCH_DONATIONS: Dict[str, Tuple[int, ...]] = {
     "frame_loop": tuple(range(7, 17)),
     "frame_loop_spec": tuple(range(9, 22)),
-    "mixed_loop": (4, 5),
-    "mixed_loop_spec": (6, 7, 8, 9),
-    "decode_loop": (4, 5),
     "run": (6, 7),
     # KV memory-hierarchy page movers (kv_cache.py): both donate the two
     # pools they rewrite in place (COW copies / swap-in restores)

@@ -358,20 +358,16 @@ HOST_READ_OUTPUTS: Dict[str, Sequence[int]] = {
     # (toks, emit, cached, produced, last_tok, penult, done, poison,
     #  nonfinite, stats, rng, k, v, dk, dv)
     "frame_loop_spec": (0, 1, 2, 8, 9),
-    "mixed_loop": (0, 1),                  # (toks, emit, k, v)
-    "mixed_loop_spec": (0, 1),
-    "decode_loop": (0,),                   # (toks, k, v)
     "run": (0,),                           # host-step path reads its logits
     "copy_blocks": (),                     # donated pools only
     "scatter_pages": (),
     "gather_pages": (0, 1),                # swap-out D2H-reads the pages
 }
 
-#: the frame/mixed/decode loops carry the GL203 budget; `run` (the chunked
-#: host-step path reads (B, V) logits by contract) and the page movers
-#: (gather_pages IS a bulk D2H, that's its job) are reported but not gated
-D2H_BUDGET_SCOPE = ("frame_loop", "frame_loop_spec", "mixed_loop",
-                    "mixed_loop_spec", "decode_loop")
+#: the frame loops carry the GL203 budget; `run` (the chunked host-step
+#: path reads (B, V) logits by contract) and the page movers (gather_pages
+#: IS a bulk D2H, that's its job) are reported but not gated
+D2H_BUDGET_SCOPE = ("frame_loop", "frame_loop_spec")
 
 #: bytes of per-row boundary lanes GL203 allows beyond the emission stream
 #: (cached/produced watermarks, latches, a stats row): 16 int32 lanes. The

@@ -8,8 +8,7 @@ tp=8 twin plus self-draft speculative variants) and returns one
 
 - ``frame_loop`` at width=chunk (prefill frames) and width=1 (decode),
 - ``frame_loop_spec`` (speculative decode frames, gamma=2),
-- ``mixed_loop`` / ``mixed_loop_spec`` (the compiled-generation path),
-- ``decode_loop`` and the per-chunk ``run`` program.
+- the per-chunk ``run`` program of the host-step API and the page movers.
 
 Tracing never compiles or executes — ``jit.trace`` stops at the jaxpr — so
 the whole registry costs seconds on CPU. Donation indices come from the
@@ -77,18 +76,6 @@ def _spec_args(eng, slots):
             slots.rng, kv.k, kv.v, dkv.k, dkv.v)
 
 
-def _mixed_args(eng):
-    import jax
-    import jax.numpy as jnp
-    b, pmax = 2, 8
-    prompts = jnp.zeros((b, pmax), jnp.int32)
-    plens = jnp.full((b,), pmax, jnp.int32)
-    limits = jnp.full((b,), 4, jnp.int32)
-    tables = jnp.zeros((b, 4), jnp.int32)
-    rng = jax.random.PRNGKey(0)
-    return prompts, plens, limits, tables, rng, jnp.float32(0.0)
-
-
 def _program(name, builder, args, statics) -> TracedProgram:
     """Wrap one jitted entry point. ``builder()`` must return a FRESH jit
     every call (fresh trace, no jit-cache hit) — check_retrace depends on
@@ -131,8 +118,7 @@ def _engine_programs(eng, tag: str) -> List[TracedProgram]:
     slots = _slot_table(eng)
     frame = functools.partial(_frame_args, eng, slots)
     spec = functools.partial(_spec_args, eng, slots)
-    prompts, plens, limits, tables, rng, temp = _mixed_args(eng)
-    kv, dkv = eng.kv, eng.draft_kv
+    kv = eng.kv
     progs = [
         _program(f"frame_loop[w=8]{tag}", runner._build_frame_loop, frame(),
                  dict(width=8, steps=2, greedy=True)),
@@ -165,25 +151,10 @@ def _engine_programs(eng, tag: str) -> List[TracedProgram]:
                  lambda: runner._build_frame_loop_spec(draft_runner), spec(),
                  dict(width=8, steps=2, greedy=True, gamma=_GAMMA,
                       repair=True)),
-        _program(f"mixed_loop{tag}", runner._build_mixed_loop,
-                 (eng.params, prompts, plens, limits, kv.k, kv.v, tables,
-                  rng, temp),
-                 dict(chunk=8, wide_steps=1, narrow_steps=2, greedy=True)),
-        _program(f"mixed_loop_spec{tag}",
-                 lambda: runner._build_mixed_loop_spec(draft_runner),
-                 (eng.params, eng.draft_params, prompts, plens, limits,
-                  kv.k, kv.v, dkv.k, dkv.v, tables, rng, temp),
-                 dict(chunk=8, wide_steps=1, narrow_steps=2, greedy=True,
-                      gamma=_GAMMA)),
     ]
     if eng.tp_ctx is None:
-        # host-step paths never compile under shard_map; trace them once
-        last = jnp.zeros((2,), jnp.int32)
-        lens = jnp.full((2,), 8, jnp.int32)
-        progs.append(_program(
-            f"decode_loop{tag}", runner._build_decode_loop,
-            (eng.params, last, lens, tables, kv.k, kv.v, rng, temp),
-            dict(steps=2, greedy=True)))
+        # the host-step path never compiles under shard_map; trace it once
+        tables = jnp.zeros((2, 4), jnp.int32)
         ids = jnp.zeros((2, 8), jnp.int32)
         pos = jnp.zeros((2, 8), jnp.int32)
         valid = jnp.full((2,), 8, jnp.int32)
@@ -249,13 +220,12 @@ def build_serving_programs(include_tp: Optional[bool] = None
 
 
 #: base entry points re-traced under each non-default collective lowering
-#: for the Family C payload contracts (GL202): the frame/mixed loops issue
+#: for the Family C payload contracts (GL202): the frame loops issue
 #: the per-layer psums + the logit gather, which is everything the
 #: quantized/overlap flags touch. Repair twins are skipped — the repair
 #: selects change no collective, so their payloads are the non-repair ones.
 _COST_VARIANT_BASES = ("frame_loop[w=8]", "frame_loop[w=1]",
-                       "frame_loop_spec[w=1]", "frame_loop_spec[w=8]",
-                       "mixed_loop", "mixed_loop_spec")
+                       "frame_loop_spec[w=1]", "frame_loop_spec[w=8]")
 
 
 def _variant_programs(eng, tag: str, variant: str) -> List[TracedProgram]:

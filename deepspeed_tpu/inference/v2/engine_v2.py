@@ -8,11 +8,10 @@ near the token budget so latency stays flat while the MXU stays fed
 (reference ``can_schedule:184`` admission logic).
 
 Serving surface (MII-compatible): ``put(batch_uids, batch_tokens)``,
-``scheduled step()``, ``query``, ``can_schedule``, ``flush``; plus a
-convenience ``generate`` driving the loop to completion and the
+``scheduled step()``, ``query``, ``can_schedule``, ``flush``; plus the
 frame-based ``serve(arrivals)`` loop for continuous batching with dynamic
 arrivals at compiled-loop speed (host touches the device only at K-step
-frame boundaries).
+frame boundaries) and ``generate``, a closed batch through that loop.
 """
 
 import collections
@@ -543,7 +542,7 @@ class InferenceEngineV2:
         # the speculative loops close over the draft runner's _forward: a
         # re-attach must evict them or the old draft would keep running
         # (evict() folds their programs into the monotonic compile total)
-        self.runner.evict("spec_frame", "spec_mixed")
+        self.runner.evict("spec_frame")
         if self.prefix_cache is not None:
             # spilled prefix pages now carry the draft pool's page too,
             # so a restored block keeps draft acceptance
@@ -722,12 +721,12 @@ class InferenceEngineV2:
                    greedy=True, temperature=0.0):
         """Run one padded (B, chunk) forward over paged KV for ``seqs``.
 
-        The batch dimension is padded to the next power of two (mirroring
-        ``_block_tables``'s width bucketing): the per-chunk jit cache keys
-        only on chunk width, so without padding every distinct live batch
-        size B compiles a fresh program. Pad rows carry positions -1 — the
-        pager routes their writes to the trash block and the attention mask
-        kills their reads — and their sampled tokens are never consumed."""
+        The batch dimension is padded to the next power of two: the
+        per-chunk jit cache keys only on chunk width, so without padding
+        every distinct live batch size B compiles a fresh program. Pad rows
+        carry positions -1 — the pager routes their writes to the trash
+        block and the attention mask kills their reads — and their sampled
+        tokens are never consumed."""
         b = len(seqs)
         bp = BlockedKVCache.bucket_width(
             b, max(b, self._config.max_ragged_batch_size))
@@ -786,96 +785,38 @@ class InferenceEngineV2:
         return produced
 
     # ------------------------------------------------------------------
-    # convenience serving loop
+    # convenience: a closed batch through serve()
     # ------------------------------------------------------------------
 
     def generate(self, prompts: List[np.ndarray], max_new_tokens: int = 32,
-                 temperature: float = 0.0, eos_token_id: Optional[int] = None):
-        """Batch generation: SplitFuse prefill via step(), then ONE compiled
-        multi-token decode loop (lax.scan inside a single jit — no per-token
-        host round-trip). EOS handling is host-side truncation after the
-        loop; the loop itself runs the full token budget."""
-        uids = list(range(len(prompts)))
-        self.put(uids, prompts)
-        # --- prefill (+ first generated token) via the SplitFuse scheduler ---
-        while any(self.state.seqs[u].in_prefill for u in uids):
-            self.step(temperature=temperature)
-        remaining = max_new_tokens - 1
-        if remaining > 0:
-            seqs = [self.state.seqs[u] for u in uids]
-            if not all(self.state.ensure_capacity(s, s.seen_tokens + remaining + 1)
-                       for s in seqs):
-                # The pool can't cover the whole compiled decode budget up
-                # front. Degrade to the chunked step() loop, which allocates
-                # per step and stops cleanly when the pool truly runs dry —
-                # a smaller/slower answer beats failing the batch. The
-                # all() above short-circuited, leaving earlier rows holding
-                # their full budget; release everything beyond what their
-                # next decode write needs so the fallback shares the pool.
-                for s in seqs:
-                    keep = self.kv.blocks_for(s.seen_tokens + 1)
-                    if len(s.blocks) > keep:
-                        self.kv.allocator.free(s.blocks[keep:])
-                        del s.blocks[keep:]
-                logger.warning(
-                    "KV pool cannot cover the compiled decode budget "
-                    f"({self.kv.free_blocks} blocks free); degrading to the "
-                    "chunked step() loop for the remainder")
-                self._stepwise_decode(seqs, max_new_tokens, temperature)
-                return self._finalize(uids, max_new_tokens, eos_token_id)
-            last_ids = np.asarray([s.generated[-1] for s in seqs], np.int32)
-            lens = np.asarray([s.seen_tokens for s in seqs], np.int32)
-            tables = self._block_tables(seqs)
-            self._rng, sub = jax.random.split(self._rng)
-            toks, self.kv.k, self.kv.v = self.runner.decode_loop(
-                self.params, jnp.asarray(last_ids), jnp.asarray(lens),
-                tables, self.kv.k, self.kv.v, sub,
-                jnp.float32(temperature), steps=remaining,
-                greedy=(temperature == 0.0))
-            toks = np.asarray(toks)                      # (steps, B)
-            for i, s in enumerate(seqs):
-                s.generated.extend(int(t) for t in toks[:, i])
-                s.seen_tokens += remaining
-                s.done = True
-        return self._finalize(uids, max_new_tokens, eos_token_id)
+                 temperature: float = 0.0, eos_token_id: Optional[int] = None,
+                 speculate: Optional[bool] = None,
+                 gamma: Optional[int] = None):
+        """Batch generation: ``prompts`` as ONE closed batch through
+        ``serve()``, every request arrived at t = 0, run to its end. Returns
+        each prompt's generated tokens, in prompt order (an EOS ends a row
+        and is its last token).
 
-    def _stepwise_decode(self, seqs, max_new_tokens: int, temperature: float):
-        """Drive step() until every sequence reaches ``max_new_tokens`` or
-        the KV pool stops yielding progress (partial generations returned).
-        Finished rows release their KV blocks immediately — in this path the
-        pool is by definition too small, so a done row's pages are exactly
-        what lets a straggler keep decoding."""
-        while True:
-            for s in seqs:
-                if len(s.generated) >= max_new_tokens and not s.done:
-                    s.done = True
-                    self.state.release_blocks(s)
-            if all(s.done for s in seqs):
-                return
-            if not self.step(temperature=temperature):
-                logger.warning(
-                    "KV pool exhausted mid-decode; returning partial "
-                    f"generations ({self.kv.free_blocks} blocks free)")
-                return
-
-    def _finalize(self, uids, max_new_tokens: int, eos_token_id):
-        outs = []
-        for u in uids:
-            g = self.state.seqs[u].generated[:max_new_tokens]
-            if eos_token_id is not None and eos_token_id in g:
-                g = g[: g.index(eos_token_id) + 1]
-            outs.append(np.asarray(g))
-        self.flush(uids)
-        return outs
-
-    def _block_tables(self, seqs):
-        """Block tables sized to the pages THIS call can touch (padded to a
-        power of two to bound recompiles): attention cost per decode token
-        scales with table width, so a 1k-ctx model serving 192-token
-        requests pays for 4 pages, not 16."""
-        need = max(len(s.blocks) for s in seqs)
-        mb = BlockedKVCache.bucket_width(need, self.max_blocks_per_seq)
-        return self._kind_tables(seqs, mb)
+        A call IS a serve run, of the same frame program: it resets and
+        fills ``engine.telemetry`` / ``serve_stats``, speculates by
+        ``serve()``'s default where a draft model or a prediction module is
+        attached (``speculate`` / ``gamma`` pass straight through; the same
+        tokens under greedy), admits more prompts than there are slots or
+        pages in turns, raises where a prompt + budget can never fit the KV
+        pool, and leaves the engine drained. Sampled tokens follow the frame
+        carry's key, split from the engine's stream: the same engine and the
+        same calls give the same tokens."""
+        done = dict(self.serve(
+            iter([list(enumerate(prompts))]), max_new_tokens=max_new_tokens,
+            temperature=temperature, eos_token_id=eos_token_id,
+            speculate=speculate, gamma=gamma))
+        uids = range(len(prompts))
+        lost = [u for u in uids if u not in done]
+        if lost:
+            raise RuntimeError(
+                f"generate(): requests {lost} were retired without an "
+                "answer (quarantined rows: engine.fault_log says why)")
+        return [done[u] for u in uids]
 
     def _kind_tables(self, seqs, width: int, rows: Optional[int] = None):
         """``seqs``' block tables as the runner's programs take them:
@@ -894,83 +835,6 @@ class InferenceEngineV2:
         return (tables,) + tuple(
             stack(ring, lambda s, i=i: s.ring_blocks[i])
             for i, (_, ring) in enumerate(self.state.rings))
-
-    def generate_compiled(self, prompts: List[np.ndarray],
-                          max_new_tokens: int = 32, temperature: float = 0.0,
-                          eos_token_id: Optional[int] = None,
-                          speculate: Optional[bool] = None,
-                          gamma: Optional[int] = None):
-        """Fully-compiled SplitFuse generation: chunked prefill, staggered
-        prefill->decode transitions, and decode run as ONE jit (two scans
-        sharing per-row state) — no host round-trips between steps. Same
-        outputs as ``generate`` for static workloads; ``step()`` remains the
-        path for continuous batching with dynamic arrivals. With a draft
-        attached (or ``speculate=True``) the narrow scan runs speculative
-        draft/verify steps — same outputs under greedy decoding, fewer
-        target forwards per emitted token."""
-        c = self._config
-        if speculate is None:
-            speculate = self.draft_model is not None
-        if speculate and self.draft_model is None:
-            raise ValueError("speculate=True but no draft model is attached")
-        gamma = int(gamma if gamma is not None else c.speculate_gamma)
-        if speculate and gamma < 1:
-            raise ValueError(f"speculate needs gamma >= 1, got {gamma}")
-        uids = list(range(len(prompts)))
-        self.put(uids, prompts)
-        seqs = [self.state.seqs[u] for u in uids]
-        for s in seqs:
-            if not self.state.ensure_capacity(
-                    s, len(s.pending) + max_new_tokens + 1):
-                raise RuntimeError("KV pool exhausted for compiled mixed loop")
-        b = len(seqs)
-        plens = np.asarray([len(s.pending) for s in seqs], np.int32)
-        pmax = int(plens.max())
-        prompts_p = np.zeros((b, pmax), np.int32)
-        for i, s in enumerate(seqs):
-            prompts_p[i, :plens[i]] = s.pending
-        tables = self._block_tables(seqs)
-        chunk = c.prefill_chunk_size
-        wide_steps = -(-pmax // chunk)
-        self._rng, sub = jax.random.split(self._rng)
-        if speculate:
-            (toks, emit, self.kv.k, self.kv.v, self.draft_kv.k,
-             self.draft_kv.v) = self.runner.mixed_loop_spec(
-                self.draft_runner, self.params, self.draft_params,
-                jnp.asarray(prompts_p), jnp.asarray(plens),
-                jnp.full((b,), max_new_tokens, jnp.int32),
-                self.kv.k, self.kv.v, self.draft_kv.k, self.draft_kv.v,
-                tables, sub, jnp.float32(temperature),
-                chunk=chunk, wide_steps=wide_steps,
-                narrow_steps=max(0, max_new_tokens - 1),
-                greedy=temperature == 0.0, gamma=gamma)
-        else:
-            toks, emit, self.kv.k, self.kv.v = self.runner.mixed_loop(
-                self.params, jnp.asarray(prompts_p), jnp.asarray(plens),
-                jnp.full((b,), max_new_tokens, jnp.int32), self.kv.k, self.kv.v,
-                tables, sub, jnp.float32(temperature),
-                chunk=chunk, wide_steps=wide_steps,
-                narrow_steps=max(0, max_new_tokens - 1),
-                greedy=temperature == 0.0)
-        toks = np.asarray(toks)
-        emit = np.asarray(emit)
-        outs = []
-        for i, s in enumerate(seqs):
-            if emit.ndim == 3:   # speculative emissions: flatten (steps, K)
-                g = [int(t) for t, e in zip(toks[:, i, :].reshape(-1),
-                                            emit[:, i, :].reshape(-1)) if e]
-            else:
-                g = [int(t) for t, e in zip(toks[:, i], emit[:, i]) if e]
-            g = g[:max_new_tokens]
-            if eos_token_id is not None and eos_token_id in g:
-                g = g[: g.index(eos_token_id) + 1]
-            s.pending = []
-            s.generated.extend(g)
-            s.seen_tokens = int(plens[i]) + max_new_tokens
-            s.done = True
-            outs.append(np.asarray(g))
-        self.flush(uids)
-        return outs
 
     # ------------------------------------------------------------------
     # frame-based persistent serving loop (dynamic arrivals)
@@ -1070,11 +934,10 @@ class InferenceEngineV2:
         briefly (e.g. ``queue.get(timeout=...)``) on an empty queue, or the
         idle loop busy-spins a host core.
 
-        Execution model (the 9.5x host-scheduling gap closer): decoding runs
-        as K-step FRAMES — one ``lax.scan``-based jit over a fixed set of
-        slots — with all per-slot state (last token, cached counts, per-row
-        limits/EOS/temperature, RNG, padded block tables) device-resident
-        between frames. The host touches the loop only at frame boundaries:
+        Execution model: decoding runs as K-step FRAMES — one
+        ``lax.scan``-based jit over a fixed set of slots — with all per-slot
+        state (last token, cached counts, per-row limits/EOS/temperature,
+        RNG, padded block tables) device-resident between frames. The host touches the loop only at frame boundaries:
         admit arrivals into free slots (KV capacity reserved up front —
         admission control defers arrivals the pool can't hold), retire
         finished rows (EOS detection is in-graph; the host replays the emit
@@ -1140,7 +1003,10 @@ class InferenceEngineV2:
         consumers keep the ``(uid, tokens)``-only stream.
 
         While a ``serve`` generator is live it owns the engine's scheduler
-        state — don't interleave ``step()``/``generate()`` calls.
+        state — don't interleave ``step()``/``generate()`` calls: ``step()``
+        allocates pages and tracks sequences behind the loop's mirrors, and
+        ``generate()`` is a serve run of its own, which resets the ledger
+        and the telemetry the live generator reads.
         """
         # argument validation is EAGER (serve() itself is not a generator):
         # a misconfigured call raises here, at the call site, not at the
@@ -1330,7 +1196,7 @@ class InferenceEngineV2:
         if uid in self.state.seqs:
             raise ValueError(
                 f"uid={uid} is already tracked by the engine "
-                "(stale from an earlier put()/generate()?) — "
+                "(stale from an earlier put()?) — "
                 "flush it before serving, or it would inherit "
                 "the old descriptor's tokens")
         if len(toks) + 2 > self.max_seq_len:

@@ -261,8 +261,8 @@ class PagedModelRunner:
             raise NotImplementedError(
                 "a model with linear (Gated DeltaNet) layers keeps a "
                 "recurrent state a slot, which rides the frame programs' "
-                "carry: it is served by serve() alone, not by put() / "
-                "step() / generate() nor the compiled mixed loop")
+                "carry: it is served by serve() and generate(), not by "
+                "put() / step()")
         bs = self.block_size
         kinds = self.kinds
         for kind in kinds or ():
@@ -933,48 +933,6 @@ class PagedModelRunner:
             logits = tp.coll.gather_logits(logits)
         return logits.astype(jnp.float32)
 
-    def _build_decode_loop(self):
-        fwd = self._forward
-
-        @functools.partial(jax.jit, donate_argnums=(4, 5),
-                           static_argnames=("steps", "greedy"))
-        def loop(params, last_ids, seq_lens, block_tables, kpool, vpool, rng,
-                 temperature, steps, greedy):
-            """Compiled multi-token decode (reference serves one jit + host
-            sync per token, ``engine_v2.py:158``; this is the lax.scan
-            path): `steps` greedy/sampled
-            tokens per sequence with NO host round-trips in between.
-
-            last_ids: (B,) previous token; seq_lens: (B,) tokens already in
-            cache. Block tables must already cover seq_lens + steps slots.
-            Returns (tokens (steps, B), kpool, vpool)."""
-            b = last_ids.shape[0]
-            ones = jnp.ones((b,), jnp.int32)
-
-            def body(carry, _):
-                ids, lens, rng, kpool, vpool = carry
-                logits, kpool, vpool = fwd(params, ids[:, None], lens[:, None],
-                                           block_tables, ones, kpool, vpool)
-                if greedy:
-                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                else:
-                    rng, sub = jax.random.split(rng)
-                    nxt = jax.random.categorical(
-                        sub, logits / jnp.maximum(temperature, 1e-6), axis=-1
-                    ).astype(jnp.int32)
-                return (nxt, lens + 1, rng, kpool, vpool), nxt
-
-            (_, _, _, kpool, vpool), toks = jax.lax.scan(
-                body, (last_ids, seq_lens, rng, kpool, vpool), None, length=steps)
-            return toks, kpool, vpool
-
-        return loop
-
-    def decode_loop(self, *args, **kwargs):
-        if "loop" not in self._fns:
-            self._fns["loop"] = self._build_decode_loop()
-        return self._fns["loop"](*args, **kwargs)
-
     def _tp_call(self, core, args, carry_specs, out_specs):
         """Run ``core`` under shard_map on the tp mesh (``self.tp``):
         ``carry_specs``/``out_specs`` are flat tuples of PartitionSpecs for
@@ -989,85 +947,6 @@ class PagedModelRunner:
         return jax.shard_map(core, mesh=tp.mesh, in_specs=carry_specs,
                              out_specs=out_specs, check_vma=False)(*args)
 
-    def _build_mixed_loop(self):
-        tp = self.tp
-        fwd = functools.partial(self._forward, tp=tp)
-
-        @functools.partial(jax.jit, donate_argnums=(4, 5),
-                           static_argnames=("chunk", "wide_steps",
-                                            "narrow_steps", "greedy"))
-        def loop(params, prompts, prompt_lens, new_limits, kpool, vpool,
-                 block_tables, rng, temperature, chunk, wide_steps,
-                 narrow_steps, greedy):
-            """Compiled Dynamic-SplitFuse: the WHOLE mixed workload — chunked
-            prefill, staggered prefill->decode transitions, and decode — in
-            one jit (reference FastGen fuses these per step but drives each
-            step from the host, ``engine_v2.py:158``; the round-3 artifact's
-            mixed row was host-bound because of exactly that).
-
-            Two scans share per-row state (cached tokens, produced count,
-            last token): a width-``chunk`` scan until the longest prompt is
-            consumed (rows finishing early decode within the wide step at
-            valid=1 — SplitFuse's mixed step), then a width-1 scan for the
-            remaining decode. Rows at their ``new_limits`` freeze: their
-            positions go to -1, which the pager routes to the trash block.
-
-            prompts: (B, P_max) padded prompt ids; returns tokens
-            (wide_steps + narrow_steps, B), an emit mask of the same shape,
-            and the updated pools.
-            """
-            def core(params, prompts, prompt_lens, new_limits, kpool, vpool,
-                     block_tables, rng, temperature):
-                b = prompts.shape[0]
-                # no EOS in this loop (host truncates after); sampled ids
-                # are never negative, so -1 can't match. Uniform per-row
-                # temps make the scalar-temperature sampling bit-identical
-                # to before.
-                no_eos = jnp.full((b,), -1, jnp.int32)
-                temps = jnp.full((b,), temperature, jnp.float32)
-
-                def make_body(width):
-                    return _serving_scan_body(fwd, params, prompts,
-                                              prompt_lens, new_limits,
-                                              no_eos, temps, block_tables,
-                                              width, greedy,
-                                              window=self.stat_window,
-                                              ladder=self.pack_ladder,
-                                              layers=self.layer_work,
-                                              latent=self.latent_layers,
-                                              heads=self.row_heads,
-                                              mtp=self.has_mtp)
-
-                zero = jnp.zeros((b,), jnp.int32)
-                no = jnp.zeros((b,), bool)
-                carry = (zero, zero, zero, no, no, no,
-                         jnp.zeros((self.n_stats,), jnp.int32), rng, kpool,
-                         vpool)
-                carry, (toks_w, emit_w) = jax.lax.scan(
-                    make_body(chunk), carry, None, length=wide_steps)
-                carry, (toks_n, emit_n) = jax.lax.scan(
-                    make_body(1), carry, None, length=narrow_steps)
-                kpool, vpool = carry[8], carry[9]
-                return (jnp.concatenate([toks_w, toks_n]),
-                        jnp.concatenate([emit_w, emit_n]), kpool, vpool)
-
-            args = (params, prompts, prompt_lens, new_limits, kpool, vpool,
-                    block_tables, rng, temperature)
-            if tp is None:
-                return core(*args)
-            rep, kv = P(), tp.kv_spec
-            return self._tp_call(
-                core, args,
-                (tp.param_specs, rep, rep, rep, kv, kv, rep, rep, rep),
-                (rep, rep, kv, kv))
-
-        return loop
-
-    def mixed_loop(self, *args, **kwargs):
-        if "mixed" not in self._fns:
-            self._fns["mixed"] = self._build_mixed_loop()
-        return self._fns["mixed"](*args, **kwargs)
-
     def _build_frame_loop(self):
         tp = self.tp
         fwd = functools.partial(self._forward, tp=tp)
@@ -1081,8 +960,8 @@ class PagedModelRunner:
                  cached, produced, last_tok, done, poison, nonfinite, stats,
                  rng, kpool, vpool, hidden=None, recurrent=None, *, width,
                  steps, greedy, repair=False, n_steps=None):
-            """One K-step serving FRAME: the resumable generalization of
-            ``mixed_loop``. All per-slot state is carry-IN/carry-OUT, so the
+            """One K-step serving FRAME, the one program that generates
+            tokens. All per-slot state is carry-IN/carry-OUT, so the
             host only touches the loop at frame boundaries (admit arrivals,
             retire finished rows); between frames the state — last token,
             cached-token counts, per-row limits, EOS/temperature vectors,
@@ -1244,75 +1123,6 @@ class PagedModelRunner:
             self._fns["spec_frame"] = self._build_frame_loop_spec(draft_runner)
         return self._fns["spec_frame"](*args, **kwargs)
 
-    def _build_mixed_loop_spec(self, draft_runner):
-        tp = self.tp
-        fwd = functools.partial(self._forward, tp=tp)
-        draft_fwd = functools.partial(draft_runner._forward,
-                                      tp=draft_runner.tp)
-
-        @functools.partial(jax.jit, donate_argnums=(5, 6, 7, 8),
-                           static_argnames=("chunk", "wide_steps",
-                                            "narrow_steps", "greedy", "gamma"))
-        def loop(params, draft_params, prompts, prompt_lens, new_limits,
-                 kpool, vpool, dkpool, dvpool, block_tables, rng, temperature,
-                 chunk, wide_steps, narrow_steps, greedy, gamma):
-            """``mixed_loop`` with speculation: the wide scan prefills both
-            models, the narrow scan runs draft/verify speculative steps —
-            rows freeze at their limits, so ``narrow_steps`` stays the
-            worst-case (no-acceptance) budget and early finishers coast.
-            Returns tokens/emit shaped (steps, B, gamma+1)."""
-            def core(params, draft_params, prompts, prompt_lens, new_limits,
-                     kpool, vpool, dkpool, dvpool, block_tables, rng,
-                     temperature):
-                b = prompts.shape[0]
-                no_eos = jnp.full((b,), -1, jnp.int32)
-                temps = jnp.full((b,), temperature, jnp.float32)
-
-                def make_body(width):
-                    return _serving_scan_body(fwd, params, prompts,
-                                              prompt_lens, new_limits,
-                                              no_eos, temps, block_tables,
-                                              width, greedy,
-                                              draft=(draft_fwd, draft_params,
-                                                     gamma),
-                                              window=self.stat_window,
-                                              ladder=self.pack_ladder,
-                                              layers=self.layer_work,
-                                              latent=self.latent_layers,
-                                              heads=self.row_heads)
-
-                zero = jnp.zeros((b,), jnp.int32)
-                no = jnp.zeros((b,), bool)
-                carry = (zero, zero, zero, zero, no, no, no,
-                         jnp.zeros((self.n_stats,), jnp.int32), rng,
-                         kpool, vpool, dkpool, dvpool)
-                carry, (toks_w, emit_w) = jax.lax.scan(
-                    make_body(chunk), carry, None, length=wide_steps)
-                carry, (toks_n, emit_n) = jax.lax.scan(
-                    make_body(1), carry, None, length=narrow_steps)
-                return (jnp.concatenate([toks_w, toks_n]),
-                        jnp.concatenate([emit_w, emit_n]),
-                        carry[9], carry[10], carry[11], carry[12])
-
-            args = (params, draft_params, prompts, prompt_lens, new_limits,
-                    kpool, vpool, dkpool, dvpool, block_tables, rng,
-                    temperature)
-            if tp is None:
-                return core(*args)
-            rep, kv = P(), tp.kv_spec
-            return self._tp_call(
-                core, args,
-                (tp.param_specs, draft_runner.tp.param_specs, rep, rep, rep,
-                 kv, kv, kv, kv, rep, rep, rep),
-                (rep, rep, kv, kv, kv, kv))
-
-        return loop
-
-    def mixed_loop_spec(self, draft_runner, *args, **kwargs):
-        if "spec_mixed" not in self._fns:
-            self._fns["spec_mixed"] = self._build_mixed_loop_spec(draft_runner)
-        return self._fns["spec_mixed"](*args, **kwargs)
-
     def run(self, chunk: int, *args):
         if chunk not in self._fns:
             self._fns[chunk] = self._build(chunk)
@@ -1323,9 +1133,8 @@ class PagedModelRunner:
         retraces per distinct arg shape/static combo, so these are the real
         program counts (the recompile-budget tests pin the function that
         recompiled instead of asserting one aggregate). Keys: "frame",
-        "mixed", "loop", "spec_frame", "spec_mixed", and "chunk<W>" for the
-        per-chunk ``run`` programs; ``sum(compile_count().values())`` is the
-        old aggregate."""
+        "spec_frame" and "chunk<W>" for the per-chunk ``run`` programs;
+        ``sum(compile_count().values())`` is the old aggregate."""
         return {(f"chunk{k}" if isinstance(k, int) else str(k)): f._cache_size()
                 for k, f in self._fns.items() if hasattr(f, "_cache_size")}
 
@@ -1575,7 +1384,7 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                        repair=False, window=None, ladder=pack_ladder,
                        layers=None, latent=None, heads=None, mtp=False,
                        linear=0):
-    """Shared scan-step for ``mixed_loop`` and ``frame_loop`` — the in-graph
+    """The scan-step of ``frame_loop`` and ``frame_loop_spec``: the in-graph
     SplitFuse scheduling arithmetic lives in exactly one place.
 
     Carry: (cached, produced, last_tok, done, poison, nonfinite, stats, rng,

@@ -330,7 +330,7 @@ def test_every_registered_program_measures(cost_reports):
     assert all(r is not None for r in cost_reports)
     names = {r.name for r in cost_reports}
     for base in ("frame_loop[w=1]", "frame_loop[w=8]", "frame_loop_spec[w=1]",
-                 "frame_loop_spec[w=8]", "mixed_loop", "mixed_loop_spec"):
+                 "frame_loop_spec[w=8]"):
         assert base in names and f"{base}[tp=8]" in names, base
     for r in cost_reports:
         assert r.hbm_read > 0 and r.hbm_write > 0
@@ -365,8 +365,8 @@ def test_host_read_table_matches_live_traces(cost_programs):
         assert all(i < len(outs) for i in reads), (prog.name, reads)
         if base in C.D2H_BUDGET_SCOPE:
             toks = outs[0]
-            # (steps, B[, gamma+1]): 2 frame steps, or 1+2 mixed steps
-            assert toks.shape[0] in (2, 3) and len(toks.shape) in (2, 3), \
+            # (steps, B[, gamma+1]): the registry's frames hold 2 steps
+            assert toks.shape[0] == 2 and len(toks.shape) in (2, 3), \
                 prog.name
             for i in reads:
                 # every boundary lane beyond the stream is O(batch)-small

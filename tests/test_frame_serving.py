@@ -126,17 +126,24 @@ def test_frame_serving_in_graph_eos(tiny_model_params):
         np.testing.assert_array_equal(got[1], base[1])   # neighbor untouched
 
 
-def test_frame_serving_admission_control_overload(tiny_model_params):
+@pytest.mark.parametrize("api", ["serve", "generate"])
+def test_frame_serving_admission_control_overload(tiny_model_params, api):
     """More arrivals than slots: admission defers (FIFO) until retirements
-    free slots; everything still finishes and the pool drains clean."""
+    free slots; everything still finishes and the pool drains clean.
+    ``generate()`` is that closed batch, its uids the prompts' order."""
     model, params = tiny_model_params
     rng = np.random.default_rng(7)
     prompts = {u: rng.integers(0, 200, (6 + u,)).astype(np.int32)
                for u in range(6)}
     e = _engine(model, params, max_ragged_batch_size=2)
 
-    got = dict(e.serve(iter([[(u, prompts[u]) for u in prompts]]),
-                       max_new_tokens=5, frame_slots=2))
+    if api == "generate":
+        got = dict(enumerate(e.generate(list(prompts.values()),
+                                        max_new_tokens=5)))
+        assert e.telemetry.counters["admission_deferrals"] > 0
+    else:
+        got = dict(e.serve(iter([[(u, prompts[u]) for u in prompts]]),
+                           max_new_tokens=5, frame_slots=2))
     assert set(got) == set(prompts)
     assert all(len(v) == 5 for v in got.values())
     assert e.kv.free_blocks == e.kv.num_blocks - 1
@@ -454,6 +461,18 @@ def test_serve_rng_reproducible(self_draft_engine, tiny_model_params):
     c, d = one(en, 7, speculate=False), one(en, 7, speculate=False)
     for u in c:
         np.testing.assert_array_equal(c[u], d[u])
+    # generate() hands serve() no rng: its key is split from the engine's
+    # stream, so engines of one seed give one answer, call after call (the
+    # serves above took an explicit rng and split nothing of `en`'s stream:
+    # both engines start level)
+    prompts = [SPEC_PROMPTS[0][:20], SPEC_PROMPTS[1][:9]]
+    twin = _engine(model, params)
+    twin.runner = en.runner         # one model, one geometry: one compile
+    runs = [[e.generate(prompts, max_new_tokens=8, temperature=0.8)
+             for _ in range(2)] for e in (en, twin)]
+    for x, y in zip(*runs):
+        for t, u in zip(x, y):
+            np.testing.assert_array_equal(t, u)
 
 
 def test_adaptive_frame_steps_buckets(tiny_model_params):
@@ -483,23 +502,26 @@ def test_adaptive_frame_steps_buckets(tiny_model_params):
     assert e.serve_stats["frame_steps_hist"] == {2: 2, 4: 1}
 
 
-def test_generate_degrades_to_stepwise_on_small_pool(tiny_model_params):
-    """generate() with a KV pool too small for the compiled decode budget
-    falls back to chunked step() serving instead of raising, and the tokens
-    it does produce are the greedy prefix of the full-pool output."""
+def test_generate_refuses_what_the_pool_can_never_hold(tiny_model_params):
+    """generate() is a closed batch through serve() and takes its answer: a
+    request whose prompt + budget an EMPTY pool cannot hold is refused
+    loudly (no partial prefix by host steps), the pool is whole afterwards,
+    and the same engine then serves a budget that fits."""
     model, params = tiny_model_params
     rng = np.random.default_rng(11)
     prompt = rng.integers(0, 200, (24,)).astype(np.int32)
 
-    full = _engine(model, params).generate([prompt], max_new_tokens=32)[0]
+    full = _engine(model, params).generate([prompt], max_new_tokens=16)[0]
 
-    # trash + 3 blocks = 48 tokens: holds the 24-token prompt and some
-    # decode, but not the 24 + 31 + 1 the compiled loop reserves up front
+    # trash + 3 blocks = 48 tokens: holds the 24-token prompt and 16 new
+    # tokens, never the 24 + 32 + 1 that admission reserves up front
     small = _engine(model, params, num_kv_blocks=4)
-    got = small.generate([prompt], max_new_tokens=32)[0]
-    assert 0 < len(got) < 32                          # partial, no raise
-    np.testing.assert_array_equal(got, full[:len(got)])
-    small.flush(list(small.state.seqs))
+    with pytest.raises(RuntimeError, match="can never fit the KV pool"):
+        small.generate([prompt], max_new_tokens=32)
+    assert not small.state.seqs
+    assert small.kv.free_blocks == small.kv.num_blocks - 1
+    np.testing.assert_array_equal(
+        small.generate([prompt], max_new_tokens=16)[0], full)
     assert small.kv.free_blocks == small.kv.num_blocks - 1
 
 

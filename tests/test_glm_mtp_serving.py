@@ -348,20 +348,30 @@ def echo():
     return model, params
 
 
-@pytest.mark.parametrize("limit", [8, 9], ids=["even", "odd"])
-def test_acceptance_one_emits_two_tokens_a_step(echo, reference, limit):
+def generate(e, prompts, limits, **kw):
+    """``serve`` above as the closed batch ``generate()`` makes of it (one
+    budget for all: generate() takes no limit a row)."""
+    limit, = set(limits.values())
+    return dict(enumerate(e.generate(list(prompts.values()),
+                                     max_new_tokens=limit, **kw)))
+
+
+@pytest.mark.parametrize("limit,run", [(8, serve), (9, serve), (8, generate)],
+                         ids=["even", "odd", "generate"])
+def test_acceptance_one_emits_two_tokens_a_step(echo, reference, limit, run):
     """Every draft accepted: two tokens a verify, half the verify forwards,
     the tokens still the module-off run's and the reference's; a budget
-    that the second token of a step would overshoot is met exactly."""
+    that the second token of a step would overshoot is met exactly.
+    ``generate()`` self-drafts by serve()'s default and counts the same."""
     model, params = echo
     # one chunk each and frames of one step: every row leaves its prefill
     # in the first frame, and every later token comes from a verify
     prompts = prompts_of((7, 15, 3), seed=5)
     limits = {u: limit for u in prompts}
     e = engine(model, params, frame_steps=1)
-    spec = serve(e, prompts, limits)
+    spec = run(e, prompts, limits)
     c = e.telemetry.counters
-    plain = serve(engine(model, params), prompts, limits, speculate=False)
+    plain = run(engine(model, params), prompts, limits, speculate=False)
     for u, toks in spec.items():
         assert len(toks) == limit
         np.testing.assert_array_equal(toks, plain[u])

@@ -123,35 +123,36 @@ def test_flush_releases_blocks():
     assert e.kv.free_blocks == free0
 
 
-def test_generate_compiled_loop_matches_stepwise():
-    """generate() (one jitted lax.scan decode loop) must produce the same
-    greedy tokens as per-token step() serving."""
-    from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2, RaggedInferenceEngineConfig
-    from deepspeed_tpu.models import build_model
+def _stepwise(eng, prompts, n):
+    """``n`` greedy tokens a prompt through the host-step API (put / step),
+    the oracle ``generate()``'s closed batch through ``serve()`` is held to."""
+    uids = list(range(len(prompts)))
+    eng.put(uids, prompts)
+    counts = {u: 0 for u in uids}
+    while not all(counts[u] >= n for u in uids):
+        for u in eng.step(temperature=0.0):
+            counts[u] += 1
+            if counts[u] >= n:
+                eng.state.seqs[u].done = True
+    outs = [np.asarray(eng.state.seqs[u].generated[:n]) for u in uids]
+    eng.flush(uids)
+    return outs
 
-    model = build_model("tiny")
+
+def test_generate_matches_stepwise():
+    """generate() (a closed batch through serve(): the frame program) must
+    produce the same greedy tokens as per-token step() serving."""
     cfg = RaggedInferenceEngineConfig(dtype="float32")
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, 256, (n,)).astype(np.int32) for n in (5, 12, 3)]
 
     eng1 = InferenceEngineV2(build_model("tiny"), cfg)
-    params = eng1.params
     outs_loop = eng1.generate(prompts, max_new_tokens=8, temperature=0.0)
+    assert eng1.serve_stats["frames"] > 0 and not eng1.state.seqs
 
     # stepwise baseline on a fresh engine with the SAME params
-    eng2 = InferenceEngineV2(build_model("tiny"), cfg, params=params)
-    uids = [0, 1, 2]
-    eng2.put(uids, prompts)
-    counts = {u: 0 for u in uids}
-    while not all(counts[u] >= 8 for u in uids):
-        out = eng2.step(temperature=0.0)
-        for u in out:
-            counts[u] += 1
-            if counts[u] >= 8:
-                eng2.state.seqs[u].done = True
-    outs_step = [np.asarray(eng2.state.seqs[u].generated[:8]) for u in uids]
-
-    for a, b in zip(outs_loop, outs_step):
+    eng2 = InferenceEngineV2(build_model("tiny"), cfg, params=eng1.params)
+    for a, b in zip(outs_loop, _stepwise(eng2, prompts, 8)):
         np.testing.assert_array_equal(a, b)
 
 
@@ -219,10 +220,10 @@ def test_ragged_heterogeneous_stack_matches_dense(layer_types):
     np.testing.assert_array_equal(dense, ragged)
 
 
-def test_generate_compiled_mixed_matches_stepwise():
-    """The fully-compiled SplitFuse loop (chunked prefill + staggered
-    transitions + decode in ONE jit) produces exactly what the host-driven
-    scheduler produces, including prompts that straddle chunk boundaries."""
+def test_generate_staggered_prompts_match_stepwise():
+    """generate() (chunked prefill + staggered transitions + decode inside
+    the frame program) produces exactly what the host-driven scheduler
+    produces, including prompts that straddle chunk boundaries."""
     model = build_model("tiny")
     params = model.init(jax.random.PRNGKey(0))
     rng = np.random.default_rng(3)
@@ -237,7 +238,7 @@ def test_generate_compiled_mixed_matches_stepwise():
         e.params = jax.device_put(params)
         return e
 
-    ref = engine().generate(prompts, max_new_tokens=8)
-    got = engine().generate_compiled(prompts, max_new_tokens=8)
+    ref = _stepwise(engine(), prompts, 8)
+    got = engine().generate(prompts, max_new_tokens=8)
     for a, b in zip(ref, got):
         np.testing.assert_array_equal(a, b)

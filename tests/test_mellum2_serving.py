@@ -449,11 +449,11 @@ def test_refused_draft_and_swap_tier(eng, model_params):
 
 
 # ---------------------------------------------------------------------------
-# the stepwise API: put / step / generate / generate_compiled hand the
-# runner's programs a table a kind, so the patterned models the engine
-# always served that way (Gemma-2's window_pattern, GPT-Neo's
-# local_attention_every) keep running when their ring is shorter than the
-# table
+# the stepwise API (put / step) hands the runner's chunk programs a table a
+# kind, and generate() is a closed batch through serve(), so the patterned
+# models the engine always served that way (Gemma-2's window_pattern,
+# GPT-Neo's local_attention_every) keep running when their ring is shorter
+# than the table
 # ---------------------------------------------------------------------------
 
 PATTERNS = {"gemma2": dict(window_pattern=(WINDOW, 0)),
@@ -472,13 +472,15 @@ def patterned(pattern):
     return model, params
 
 
-@pytest.mark.parametrize("api", ["generate", "generate_compiled", "step"])
+@pytest.mark.parametrize("api", ["generate", "generate-queued", "step"])
 @pytest.mark.parametrize("pattern", sorted(PATTERNS))
 def test_stepwise_api_serves_a_patterned_model_by_kind(pattern, api):
     """Greedy tokens through caches by kind (ring of 4 pages beside a table
     of 16) equal those of the one pool under traced windows (a chunk so
     wide that the ring would be no shorter than the table), prompts several
-    times the ring and shorter than the window; every page comes back."""
+    times the ring and shorter than the window; every page comes back.
+    ``generate-queued``: two slots for the three prompts, so the third is
+    admitted when a row retires, into a ring another request has used."""
     model, params = patterned(pattern)
     rng = np.random.default_rng(5)
     prompts = [rng.integers(0, model.cfg.vocab_size, n).astype(np.int32)
@@ -486,8 +488,8 @@ def test_stepwise_api_serves_a_patterned_model_by_kind(pattern, api):
 
     def run(e):
         if api != "step":
-            return [t.tolist() for t in getattr(e, api)(
-                prompts, max_new_tokens=20)]
+            return [t.tolist() for t in e.generate(prompts,
+                                                   max_new_tokens=20)]
         e.put([0, 1, 2], prompts)
         out = {0: [], 1: [], 2: []}
         while min(len(t) for t in out.values()) < 20:
@@ -496,13 +498,16 @@ def test_stepwise_api_serves_a_patterned_model_by_kind(pattern, api):
         e.flush([0, 1, 2])
         return [out[u][:20] for u in (0, 1, 2)]
 
-    by_kind = engine(model, params)
+    slots = dict(max_ragged_batch_size=2) if api == "generate-queued" else {}
+    by_kind = engine(model, params, **slots)
     assert isinstance(by_kind.kv, LayeredKVCache)
     assert [k.ring for k in by_kind.runner.kinds] == [None, 4]
     one_pool = engine(model, params, prefill_chunk_size=128,
-                      max_tokens_per_step=512)
+                      max_tokens_per_step=512, **slots)
     assert one_pool.runner.kinds is None
     assert run(by_kind) == run(one_pool)
+    if slots:
+        assert by_kind.telemetry.counters["admission_deferrals"] > 0
     assert not by_kind.state.seqs
     assert [g.free_blocks for g in by_kind.kv.groups] == [
         g.num_blocks - 1 for g in by_kind.kv.groups]

@@ -612,17 +612,24 @@ def test_a_second_tenant_reads_a_fresh_slot(whole):
         assert f"ds_serving_{name}" in text, name
 
 
-def test_served_tokens_are_the_references(whole, reference):
-    """serve() end to end (admission, wide frames with a rider, narrow
-    frames, slots reused): every emitted token is the reference's greedy
-    choice to within the tolerance."""
+@pytest.fixture(scope="module")
+def served(whole):
+    """Four prompts through serve() on two slots: (prompts, tokens by uid)."""
     model, params = whole
     rng = np.random.default_rng(61)
     prompts = {u: rng.integers(0, 256, n).astype(np.int32)
                for u, n in ((1, 150), (2, 30), (3, 97), (4, 64))}
     e = engine(model, params, max_ragged_batch_size=2)
-    out = dict(e.serve(iter([[(u, p) for u, p in prompts.items()]]),
-                       max_new_tokens=6, frame_slots=2))
+    return prompts, dict(e.serve(iter([list(prompts.items())]),
+                                 max_new_tokens=6, frame_slots=2))
+
+
+def test_served_tokens_are_the_references(whole, reference, served):
+    """serve() end to end (admission, wide frames with a rider, narrow
+    frames, slots reused): every emitted token is the reference's greedy
+    choice to within the tolerance."""
+    model, params = whole
+    prompts, out = served
     for u, p in prompts.items():
         gen = np.asarray(out[u])
         ids = list(p) + list(gen[:-1])
@@ -818,10 +825,19 @@ def test_the_validator_refuses(option, match):
             **{k: v for k, v in build.items() if k == "draft_model"})
 
 
-def test_the_other_entry_points_say_where_the_state_lives(whole):
-    """put() / step() / generate() walk the forward without the frame
-    programs' carry: refused with the reason, not served wrongly."""
+def test_the_other_entry_points_say_where_the_state_lives(whole, served):
+    """put() / step() walk the forward without the frame programs' carry:
+    refused with the reason, not served wrongly. generate() is a closed
+    batch through serve(), so it serves, and its tokens are the served
+    run's (which are the reference's)."""
     model, params = whole
-    e = engine(model, params)
+    e = engine(model, params, max_ragged_batch_size=2)
+    e.put([0], [np.arange(10, dtype=np.int32)])
     with pytest.raises(NotImplementedError, match="serve\\(\\)"):
-        e.generate([np.arange(10, dtype=np.int32)], max_new_tokens=2)
+        e.step()
+    e.flush([0])
+    prompts, out = served
+    got = e.generate(list(prompts.values()), max_new_tokens=6)
+    for u, tokens in zip(prompts, got):
+        np.testing.assert_array_equal(tokens, out[u])
+    assert e.kv.free_blocks == e.kv.num_blocks - 1

@@ -300,8 +300,7 @@ def serving_programs():
 
 #: leading wrapper-only params of each runner entry point (the jit sees
 #: the args after them), mirroring the call-site shift in DISPATCH_DONATIONS
-_WRAPPER_OFFSET = {"frame_loop": 0, "frame_loop_spec": 1, "mixed_loop": 0,
-                   "mixed_loop_spec": 1, "decode_loop": 0, "run": 1,
+_WRAPPER_OFFSET = {"frame_loop": 0, "frame_loop_spec": 1, "run": 1,
                    "copy_blocks": 0, "scatter_pages": 0}
 
 
@@ -339,10 +338,13 @@ def test_registry_completeness_against_dispatch_sites(serving_programs):
                          f"frame_loop[{w},repair]{tp}",
                          f"frame_loop_spec[{w}]{tp}",
                          f"frame_loop_spec[{w},repair]{tp}"}
-        expected |= {f"mixed_loop{tp}", f"mixed_loop_spec{tp}"}
     # host-step + page-mover programs never compile under shard_map
-    expected |= {"decode_loop", "run[chunk=8]", "copy_blocks",
-                 "scatter_pages", "gather_pages"}
+    expected |= {"run[chunk=8]", "copy_blocks", "scatter_pages",
+                 "gather_pages"}
+    # the frame programs are the only ones that scan: a program that
+    # generates tokens beside them would be a registry entry of another base
+    assert {n.split("[")[0] for n in names} == {
+        n.split("[")[0] for n in expected}
     missing = expected - names
     assert not missing, f"registry is missing production variants: " \
                         f"{sorted(missing)}"
