@@ -17,6 +17,50 @@ def sample_logits_per_row(logits, rng, temps):
     return jnp.where(temps <= 0.0, greedy_toks, sampled)
 
 
+def block_unmask(logits, masked, rng, temps, *, per_step: int,
+                 threshold=None):
+    """One denoising step's choice for rows that generate by diffusion over
+    blocks: WHAT each masked position would become and WHICH of them are
+    unmasked now, entirely in-graph.
+
+    logits: (B, L, V) float32, the model's scores of the token AT each of
+    the block's L positions; masked: (B, L) bool; temps: (B,). Returns
+    (x0 (B, L) int32, unmask (B, L) bool).
+
+    ``x0`` is the argmax of a position's logits (a row with temp > 0: a
+    draw from softmax(l / temp), ``rng`` None when every row is greedy);
+    its confidence is softmax(l)[x0], the untempered probability.
+    ``unmask`` holds the ``min(per_step, masked positions)`` masked
+    positions of largest confidence (ties: the lowest position;
+    "low_confidence_static"). ``threshold`` (a float;
+    "low_confidence_dynamic"): every masked position whose confidence
+    passes it, where those are at least ``per_step``. Confidences are
+    compared as log-probabilities, x0's logit minus the logsumexp: the
+    same order, no underflow over a wide vocabulary."""
+    b, l, v = logits.shape
+    flat = logits.reshape(b * l, v)
+    if rng is None:
+        x0 = jnp.argmax(flat, axis=-1).astype(jnp.int32)
+    else:
+        x0 = sample_logits_per_row(flat, rng, jnp.repeat(temps, l))
+    logc = (jnp.take_along_axis(flat, x0[:, None], axis=1)[:, 0]
+            - jax.nn.logsumexp(flat, axis=-1)).reshape(b, l)
+    x0 = x0.reshape(b, l)
+    logc = jnp.where(masked, logc, -jnp.inf)
+    # rank of a position among its block's by confidence, ties to the left
+    # (L is small: the L x L comparison is cheaper than a sort)
+    at = jnp.arange(l)
+    ahead = (logc[:, None, :] > logc[:, :, None]) | (
+        (logc[:, None, :] == logc[:, :, None])
+        & (at[None, None, :] < at[None, :, None]))
+    unmask = masked & (jnp.sum(ahead, axis=-1) < per_step)
+    if threshold is not None:
+        passing = masked & (logc > jnp.log(jnp.float32(threshold)))
+        unmask = jnp.where(
+            (jnp.sum(passing, axis=1) >= per_step)[:, None], passing, unmask)
+    return x0, unmask
+
+
 def speculative_verify_per_row(target_logits, draft_logits, draft_toks, temps,
                                rng=None):
     """Per-row draft verification for the speculative serving frame: decides

@@ -336,6 +336,13 @@ class InferenceEngineV2:
                     validate_recurrent_serving
                 validate_recurrent_serving(c, cfg,
                                            draft=draft_model is not None)
+            if cfg.block_length:
+                # generation by diffusion over blocks: a row past its
+                # prompt holds a half-denoised block on the carry
+                from .model_implementations.archs import \
+                    validate_block_diffusion_serving
+                validate_block_diffusion_serving(
+                    c, cfg, draft=draft_model is not None)
             self.kv = BlockedKVCache(cfg.cache_layers if cfg.linear_layers
                                      else cfg.num_layers, cfg.kv_heads,
                                      cfg.dims_per_head,
@@ -551,6 +558,14 @@ class InferenceEngineV2:
                  f"(layers={dcfg.num_layers} gamma={c.speculate_gamma})",
                  ranks=[0])
 
+    @property
+    def _lookahead(self) -> int:
+        """Positions past prompt + budget a row's pages must hold: the one
+        slot a step writes ahead, and for a model that generates by
+        diffusion over blocks the whole of a last block that the budget
+        cuts (its every position is denoised and written)."""
+        return max(1, self.model.cfg.block_length)
+
     def _one_kind_only(self, what: str) -> None:
         """A model of mixed cache kinds is served without what moves a
         sequence's pages by one block list or rolls a step back across a
@@ -570,6 +585,12 @@ class InferenceEngineV2:
                 f"{what}: this model's linear layers keep a recurrent state "
                 "a slot, which is no page; it is served without a draft, a "
                 "swap tier or a prefix cache")
+        if self.model.cfg.block_length:
+            raise NotImplementedError(
+                f"{what}: this model generates by diffusion over blocks and "
+                "a row holds a half-denoised block, which is no page and no "
+                "token a draft could verify; it is served without a draft, "
+                "a swap tier or a prefix cache")
 
     def attach_kv_tier(self, tier, tag: Optional[str] = None) -> None:
         """Attach an EXTERNAL (typically shared) ``KVSwapTier`` — the
@@ -1054,7 +1075,10 @@ class InferenceEngineV2:
             hidden=(self.model.cfg.hidden_size, self.model.cfg.act_dtype)
             if speculate and self_draft else None,
             recurrent=self.runner.recurrent_shapes(n_slots)
-            if self.runner.linear_layers else ())
+            if self.runner.linear_layers else (),
+            block=(self.model.cfg.block_length,
+                   self.model.cfg.unmask_per_step)
+            if self.runner.block_length else None)
         if faults is not None:
             faults.begin_serve()     # rearm the scripted schedule
         if self.prefix_cache is not None:
@@ -1096,7 +1120,8 @@ class InferenceEngineV2:
                                        * jnp.dtype(dtype).itemsize
                                        for shape, dtype in
                                        self.runner.recurrent_shapes(1))
-                                   if self.runner.linear_layers else 0)
+                                   if self.runner.linear_layers else 0,
+                                   block=self.runner.block_length)
         sched = FifoPolicy() if scheduler is None else scheduler
         sched.begin_serve(self)
         return self._serve_guarded(slots, arrivals, sched, steps,
@@ -1199,15 +1224,16 @@ class InferenceEngineV2:
                 "(stale from an earlier put()?) — "
                 "flush it before serving, or it would inherit "
                 "the old descriptor's tokens")
-        if len(toks) + 2 > self.max_seq_len:
+        ahead = self._lookahead
+        if len(toks) + 1 + ahead > self.max_seq_len:
             raise ValueError(
                 f"uid={uid}: prompt of {len(toks)} tokens can "
                 f"never fit max_seq_len={self.max_seq_len}")
-        if len(toks) + limit + 1 > self.max_seq_len:
-            clamped = self.max_seq_len - len(toks) - 1
+        if len(toks) + limit + ahead > self.max_seq_len:
+            clamped = self.max_seq_len - len(toks) - ahead
             logger.warning(
                 f"uid={uid}: prompt ({len(toks)}) + budget "
-                f"({limit}) + 1 exceeds max_seq_len="
+                f"({limit}) + {ahead} exceeds max_seq_len="
                 f"{self.max_seq_len}; clamping budget to "
                 f"{clamped}")
             limit = clamped
@@ -1619,7 +1645,7 @@ class InferenceEngineV2:
         cache blocks. A deferred request KEEPS its mapped shared blocks
         (refcount bumps, zero pool cost) and its ``resume_cached`` mark,
         so the retry at the next boundary resumes where it left off."""
-        total = len(toks) + limit + 1
+        total = len(toks) + limit + self._lookahead
         if self.prefix_cache is None and self.kv_swap is None:
             return 0 if self.state.ensure_capacity(seq, total) else None
         chunk = self._config.prefill_chunk_size
@@ -2368,7 +2394,10 @@ class InferenceEngineV2:
                 # to do in it (``_plan_frame_steps``: from the host mirrors,
                 # no device read) ----
                 need = slots.prefill_steps_left(c.prefill_chunk_size)
-                width = c.prefill_chunk_size if need else 1
+                # (a block wide where the model generates by diffusion
+                # over blocks)
+                width = c.prefill_chunk_size if need \
+                    else max(1, self.runner.block_length)
                 cur_steps = steps
                 saturated = slots.free_slots() == 0
                 if adaptive:

@@ -203,6 +203,51 @@ class OlmoeContainer(LlamaContainer):
             moe_norm_topk=bool(_get(hf_cfg, "norm_topk_prob", default=False)))
 
 
+class SdarMoeContainer(LlamaContainer):
+    """SDAR-MoE (JetLM/SDAR-30B-A3B-Chat ``config.json``, ``model_type``
+    "sdar_moe"): Qwen3-MoE's layer in its published layout: GQA with an
+    explicit ``head_dim``, one RMSNorm of ``head_dim`` lanes on every q and
+    k head before RoPE (``self_attn.{q,k}_norm``), every MLP routed
+    (``mlp.gate``, ``mlp.experts.{x}.{gate,up,down}_proj``) with experts of
+    ``moe_intermediate_size``, no shared expert, untied head. Generated by
+    diffusion over blocks; ``config.json`` names neither the block's length
+    nor the schedule, so those are the family's released defaults unless
+    the config object carries them (the ``sdar-30b-a3b`` preset's). Served
+    dropless."""
+
+    layer_mapping = dict(OlmoeContainer.layer_mapping)
+
+    @classmethod
+    def config(cls, hf_cfg):
+        n = hf_cfg.num_hidden_layers
+        step = int(_get(hf_cfg, "decoder_sparse_step", default=1))
+        only = set(_get(hf_cfg, "mlp_only_layers", default=()) or ())
+        if step != 1 or only:
+            raise NotImplementedError(
+                f"decoder_sparse_step={step}, mlp_only_layers={sorted(only)}"
+                f" of {n} layers: only a stack whose every MLP is routed is "
+                "mapped")
+        if _get(hf_cfg, "use_sliding_window", default=False):
+            raise NotImplementedError(
+                "use_sliding_window: a window measures from the query's own "
+                "position, a block's mask from its block's end")
+        return _llama_family_config(
+            hf_cfg, head_dim=int(hf_cfg.head_dim), qk_norm="head_dim",
+            qk_norm_bias=False, qkv_bias=bool(
+                _get(hf_cfg, "attention_bias", default=False)),
+            moe_impl="grouped", num_experts=int(hf_cfg.num_experts),
+            num_experts_per_tok=int(hf_cfg.num_experts_per_tok),
+            moe_intermediate_size=int(hf_cfg.moe_intermediate_size),
+            moe_norm_topk=bool(_get(hf_cfg, "norm_topk_prob", default=True)),
+            block_length=int(_get(hf_cfg, "block_length", default=4)),
+            denoising_steps=int(_get(hf_cfg, "denoising_steps", default=4)),
+            remasking_strategy=_get(hf_cfg, "remasking_strategy",
+                                    default="low_confidence_dynamic"),
+            confidence_threshold=float(
+                _get(hf_cfg, "confidence_threshold", default=0.9)),
+            mask_token_id=int(_get(hf_cfg, "mask_token_id", default=151669)))
+
+
 def _pair(transform=t_identity):
     """A leaf stacked over a shortcut-connected layer's pair: the two
     sources each through ``transform``."""
@@ -1306,6 +1351,7 @@ ARCH_CONTAINERS: Dict[str, Type[LayerContainer]] = {
     "mixtral": MixtralContainer,
     "qwen2moe": Qwen2MoeContainer,
     "olmoe": OlmoeContainer,
+    "sdarmoe": SdarMoeContainer,
     "mellum": MellumContainer,
     "longcatflash": LongcatFlashContainer,
     "qwen2": Qwen2Container,
@@ -1466,6 +1512,61 @@ def validate_recurrent_serving(engine_config, cfg: TransformerConfig,
         raise NotImplementedError(
             "a model with linear layers keeps a recurrent state a slot and "
             "cannot be served with: " + "; ".join(probs))
+
+
+def validate_block_diffusion_serving(engine_config, cfg: TransformerConfig,
+                                     draft: bool = False) -> None:
+    """Fail LOUDLY at engine build for what a model that generates by
+    diffusion over blocks (``cfg.block_length``) cannot be served with yet.
+    A row past its prompt holds a half-denoised block (tokens and masked
+    flags) on the frame programs' carry, its watermark moves a block at a
+    time, and what a denoising step writes past it is not a sequence's
+    final K, V: whatever moves, shares or rewinds a sequence by pages and
+    tokens alone would leave the block behind or read those rows. An
+    evicted or preempted sequence is not refused: its committed blocks'
+    tokens join its prompt, which then ends on a block's edge."""
+    c = engine_config
+    blk = cfg.block_length
+    probs = []
+    if c.tp > 1:
+        probs.append(f"tp={c.tp} (the block step's logits at {blk} positions "
+                     "a row are not exchanged; the carry's block is not "
+                     "placed on a mesh)")
+    if c.prefix_cache:
+        probs.append("prefix_cache (a shared prefix must end on a block's "
+                     "edge under the block mask, and its publisher's pages "
+                     "past the watermark hold a denoising step's rows)")
+    if c.kv_swap_dir or c.role != "unified":
+        probs.append("the swap tier / prefill-decode handoff (kv_swap_dir, "
+                     "role): a record holds pages, no block")
+    if c.kv_dtype == "int8":
+        probs.append("kv_dtype='int8' (the block's own keys ride beside the "
+                     "pool unpacked; the packed path is not walked under the "
+                     "block mask)")
+    if draft or cfg.num_nextn_predict_layers:
+        probs.append("a draft, a second model's or a prediction module's (a "
+                     "step yields a block or nothing: there is no next token "
+                     "to verify)")
+    if c.nonfinite_policy == "repair":
+        probs.append("nonfinite_policy='repair' (it rolls a row's step back "
+                     "by its watermark; the block's flags have moved)")
+    if c.prefill_chunk_size % blk:
+        probs.append(f"prefill_chunk_size={c.prefill_chunk_size}, no multiple "
+                     f"of block_length={blk} (a chunk must end on a block's "
+                     "edge: a position sees its whole block)")
+    if blk & (blk - 1):
+        probs.append(f"block_length={blk} (a power of two is what the pages, "
+                     "the chunks and the narrow step's tiles divide by)")
+    if cfg.sliding_window or cfg.position != "rope" or cfg.linear_layers \
+            or cfg.latent_lanes:
+        probs.append("a window, ALiBi or learned positions, linear layers or "
+                     "a latent cache (the block mask is walked for full "
+                     "attention by head under RoPE alone)")
+    if probs:
+        raise NotImplementedError(
+            "a model that generates by diffusion over blocks holds a "
+            "half-denoised block a slot and cannot be served with: "
+            + "; ".join(probs))
 
 
 def validate_latent_serving(engine_config, cfg: TransformerConfig,

@@ -29,7 +29,8 @@ from jax.sharding import PartitionSpec as P
 from ...models import layers as L
 from ...models.transformer import CausalLM
 from ...ops.attention import decode_attention
-from ..sampling import sample_logits_per_row, speculative_verify_per_row
+from ..sampling import (block_unmask, sample_logits_per_row,
+                        speculative_verify_per_row)
 from .kv_cache import dequantize_kv_lanes, quantize_kv_lanes
 from .telemetry import (LATENT_STAT_NAMES, LAYER_STAT_NAMES,   # in-graph
                         MAX_RUNGS, MOE_STAT_NAMES,             # counter
@@ -191,7 +192,17 @@ class PagedModelRunner:
                        self.kinds is not None or bool(self.linear_layers),
                        share=self.cfg.moe_is_share,
                        latent=bool(self.cfg.latent_lanes),
-                       mtp=self.has_mtp, recurrent=bool(self.linear_layers))
+                       mtp=self.has_mtp, recurrent=bool(self.linear_layers),
+                       block=bool(self.block_length))
+
+    @property
+    def block_length(self) -> int:
+        """Positions of a block of a model that generates by diffusion over
+        blocks (``cfg.block_length``): a row past its prompt holds one,
+        tokens and masked flags, on the frame programs' carry (``block``),
+        a narrow step is that many positions wide, and the stat vector ends
+        with ``telemetry.BLOCK_STAT_NAMES``; 0 for every other model."""
+        return self.cfg.block_length
 
     def set_tp(self, tp_ctx) -> None:
         """Bind a ``tp.TPContext`` (engine setup, before any serving loop
@@ -257,6 +268,17 @@ class PagedModelRunner:
         are the first of its chunk; a row with none keeps state and tail to
         the bit. The pools hold the full-attention layers alone."""
         cfg = self.cfg
+        # a model that attends causally by block (``cfg.block_length``): a
+        # position sees every key up to the last position of its block, in
+        # prefill chunks and block steps alike, on either attention path;
+        # RoPE and the pages keep the true positions. Its logits are those
+        # AT the first ``block_length`` positions of each row's chunk, (B,
+        # block_length, V): what a row past its prompt holds there
+        see = None
+        if cfg.block_length:
+            blk = cfg.block_length
+            see = jnp.where(positions >= 0, positions // blk * blk + blk - 1,
+                            -1)
         if cfg.mixer_pattern is not None and recurrent is None:
             raise NotImplementedError(
                 "a model with linear (Gated DeltaNet) layers keeps a "
@@ -510,7 +532,8 @@ class PagedModelRunner:
                     q, kp, vp, tables, positions, k, v, layer=at_pool,
                     scale=scale, window=win, alibi_slopes=slopes,
                     softcap=cfg.attn_softcap, ring=ring,
-                    **({"value_lanes": rkv} if rkv else {}))
+                    **({"value_lanes": rkv} if rkv else {}),
+                    **({} if see is None else {"visible_to": see}))
             kvh_loc = kp.shape[1]   # local KV heads (KVH/tp under tp)
             lanes = kp.shape[-1]    # D, or D + scale lanes when int8
             kl = jnp.take(kp, at_pool, axis=0)   # escape hatch: copies 1/L
@@ -541,7 +564,7 @@ class PagedModelRunner:
             return _paged_attention(q, kpages, vpages, positions, cfg,
                                     window=win, chunk_k=k, chunk_v=v,
                                     chunk_start=chunk_start,
-                                    alibi_slopes=slopes)
+                                    alibi_slopes=slopes, visible_to=see)
 
         def layer(h, xs, tag=None, cache=None):
             """``cache`` (a model of mixed cache kinds): the layer's kind's
@@ -743,6 +766,8 @@ class PagedModelRunner:
                 functools.partial(commit, positions=positions))
         with jax.named_scope("lm_head"):
             stack_h = h
+            if cfg.block_length:
+                h, all_logits = h[:, :cfg.block_length], True
             h = L.apply_norm(params["final_norm"], h, cfg)
             logits = self._head(params, h, valid_counts, all_logits, tp=tp)
         return (logits, kpool, vpool) + ((work,) if moe_work else ()) \
@@ -953,13 +978,13 @@ class PagedModelRunner:
 
         @functools.partial(jax.jit,
                            donate_argnums=(7, 8, 9, 10, 11, 12, 13, 14, 15,
-                                           16, 17, 18),
+                                           16, 17, 18, 19),
                            static_argnames=("width", "steps", "greedy",
                                             "repair"))
         def loop(params, prompts, prompt_lens, limits, eos_ids, temps, tables,
                  cached, produced, last_tok, done, poison, nonfinite, stats,
-                 rng, kpool, vpool, hidden=None, recurrent=None, *, width,
-                 steps, greedy, repair=False, n_steps=None):
+                 rng, kpool, vpool, hidden=None, recurrent=None, block=None,
+                 *, width, steps, greedy, repair=False, n_steps=None):
             """One K-step serving FRAME, the one program that generates
             tokens. All per-slot state is carry-IN/carry-OUT, so the
             host only touches the loop at frame boundaries (admit arrivals,
@@ -1006,6 +1031,14 @@ class PagedModelRunner:
             carry's LAST field, donated like the pools, and comes back last:
             it rides through the frame's steps and from frame to frame.
 
+            ``block`` = (tokens (B, L) int32, masked (B, L) bool), given: the
+            model generates by diffusion over blocks of L positions
+            (``cfg.block_length``). The pair is the carry's LAST field as
+            ``recurrent`` is another model's, donated and returned last; the
+            body is ``_block_scan_body``, a narrow frame is ``width`` = L,
+            and emissions are (steps, B, L): a row's step emits a committed
+            block's tokens or none.
+
             Tensor-parallel (``self.tp`` set): the same program compiles
             under shard_map on the 1-D tp mesh — params and KV pools
             sharded, every slot-state carry replicated, ``stats`` among
@@ -1015,7 +1048,18 @@ class PagedModelRunner:
             def core(n_steps, params, prompts, prompt_lens, limits, eos_ids,
                      temps, tables, cached, produced, last_tok, done, poison,
                      nonfinite, stats, rng, kpool, vpool, *hidden,
-                     recurrent=None):
+                     recurrent=None, block=None):
+                if block is not None:
+                    body = _block_scan_body(
+                        fwd, params, prompts, prompt_lens, limits, eos_ids,
+                        temps, tables, width, greedy, self.cfg,
+                        window=self.stat_window, ladder=self.pack_ladder,
+                        heads=self.row_heads)
+                    carry = (cached, produced, last_tok, done, poison,
+                             nonfinite, stats, rng, kpool, vpool,
+                             tuple(block))
+                    return _run_steps(body, carry, steps, n_steps,
+                                      cached.shape + (self.block_length,))
                 body = _serving_scan_body(fwd, params, prompts, prompt_lens,
                                           limits, eos_ids, temps, tables,
                                           width, greedy,
@@ -1040,9 +1084,10 @@ class PagedModelRunner:
                     vpool)
             if tp is None:
                 return core(*args, *(() if hidden is None else (hidden,)),
-                            recurrent=recurrent)
-            assert hidden is None and recurrent is None, \
-                "neither a self-draft nor a recurrent state is served under tp"
+                            recurrent=recurrent, block=block)
+            assert hidden is None and recurrent is None and block is None, \
+                "neither a self-draft, a recurrent state nor a half-denoised " \
+                "block is served under tp"
             rep, kv = P(), tp.kv_spec
             return self._tp_call(
                 core, args,
@@ -1124,6 +1169,12 @@ class PagedModelRunner:
         return self._fns["spec_frame"](*args, **kwargs)
 
     def run(self, chunk: int, *args):
+        if self.block_length:
+            raise NotImplementedError(
+                "a model that generates by diffusion over blocks holds a "
+                "half-denoised block a slot, which rides the frame programs' "
+                "carry: it is served by serve() and generate(), not by "
+                "put() / step()")
         if chunk not in self._fns:
             self._fns[chunk] = self._build(chunk)
         return self._fns[chunk](*args)
@@ -1631,7 +1682,7 @@ def _stat_delta(positions, ladder, emitted=None, active=None,
                 prefill_toks=None, eos=None, target_fwd=None, drafted=None,
                 accepted=None, kv_read=None, attn_pairs=None, row_tiles=None,
                 moe_work=None, layer_work=None, mtp_work=None,
-                recurrent_work=None):
+                recurrent_work=None, block_work=None):
     """One step's (N_STATS,) in-graph counter increment. Each keyword is a
     bool mask / int array to sum, or None for zero — the layout is pinned by
     the STAT_* indices in ``telemetry.py`` and the host-mirror replay tests
@@ -1648,7 +1699,8 @@ def _stat_delta(positions, ladder, emitted=None, active=None,
     attention is latent; behind those ``mtp_work``, the prediction
     module's own (``MTP_STAT_NAMES``), where the model drafts for itself, or
     ``recurrent_work`` (``RECURRENT_STAT_NAMES``), where it has linear
-    layers."""
+    layers, or ``block_work`` (``BLOCK_STAT_NAMES``), where it generates
+    by diffusion over blocks."""
     vals = [emitted, active, prefill_toks, eos, target_fwd, drafted, accepted,
             kv_read, attn_pairs, *(row_tiles or (None, None))]
     z = jnp.zeros((), jnp.int32)
@@ -1663,7 +1715,8 @@ def _stat_delta(positions, ladder, emitted=None, active=None,
                           + ([] if layer_work is None else [layer_work])
                           + ([] if mtp_work is None else [mtp_work])
                           + ([] if recurrent_work is None
-                             else [recurrent_work]))
+                             else [recurrent_work])
+                          + ([] if block_work is None else [block_work]))
     assert layer_work is None or layer_work.shape[0] in (
         len(LAYER_STAT_NAMES), len(LATENT_STAT_NAMES))
     return out
@@ -1705,6 +1758,151 @@ def _wide_emit(active, prefilling, cached, w, prompt_lens, eos_ids, nxt,
     last_tok = jnp.where(emit, nxt, last_tok)
     done = done | (emit & (nxt == eos_ids))
     return emit, last_tok, done
+
+
+def _block_plan(prompts, prompt_lens, limits, width, blk, mask_id, cached,
+                produced, done, btok, bmask):
+    """``_wide_plan`` for rows that generate by diffusion over blocks of
+    ``blk`` positions (``width`` a multiple of it; ``cached`` always is
+    one): a row prefills while ``cached`` is under its prompt's whole
+    blocks, ``prompt_lens // blk * blk``, and consumes up to ``width``
+    prompt tokens of them; past them a row with budget left holds the block
+    at ``cached .. cached + blk - 1``: the prompt's remainder where it
+    reaches into the block, then the carried tokens (``btok``) where the
+    carried flags (``bmask``) say a position is unmasked and ``mask_id``
+    where they say it is not. Whether a position is masked is that flag,
+    never the token's value: a prompt may hold ``mask_id``.
+
+    Returns (prefilling, active, w, ids, positions, tok (B, blk) the
+    block's tokens, masked (B, blk)); ``DeviceSlotTable``'s replay mirrors
+    this arithmetic on the host, so it must not fork."""
+    offs = jnp.arange(width)
+    whole = prompt_lens // blk * blk
+    prefilling = cached < whole
+    active = ~done & (prefilling | (produced < limits))
+    w = jnp.where(active,
+                  jnp.where(prefilling, jnp.minimum(width, whole - cached),
+                            blk), 0)
+    at = cached[:, None] + offs[None, :]
+    from_prompt = jnp.take_along_axis(
+        prompts, jnp.clip(at, 0, prompts.shape[1] - 1), axis=1)
+    in_prompt = at[:, :blk] < prompt_lens[:, None]
+    masked = ~prefilling[:, None] & ~in_prompt & bmask
+    tok = jnp.where(in_prompt, from_prompt[:, :blk], btok)
+    held = jnp.pad(jnp.where(masked, mask_id, tok),
+                   ((0, 0), (0, width - blk)))
+    ids = jnp.where(prefilling[:, None], from_prompt, held)
+    positions = jnp.where(offs[None, :] < w[:, None], at, -1)
+    return prefilling, active, w, ids, positions, tok, masked
+
+
+def _block_emit(commit, cached, produced, prompt_lens, limits, eos_ids, tok):
+    """What a committed block gives out (the other half of ``_block_plan``'s
+    contract): its positions at or past the prompt's end, in order, up to
+    the row's budget and up to and including its first EOS. Returns (emit
+    (B, blk), is_eos (B, blk))."""
+    blk = tok.shape[1]
+    gen = cached[:, None] + jnp.arange(blk)[None, :] >= prompt_lens[:, None]
+    nth = jnp.cumsum(gen.astype(jnp.int32), axis=1) - 1
+    is_eos = gen & (tok == eos_ids[:, None])
+    eos_before = jnp.cumsum(is_eos.astype(jnp.int32), axis=1) - is_eos
+    emit = (commit[:, None] & gen
+            & (produced[:, None] + nth < limits[:, None])
+            & (eos_before == 0))
+    return emit, is_eos
+
+
+def _block_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
+                     temps, tables, width, greedy, cfg, window=None,
+                     ladder=pack_ladder, heads=None):
+    """The serving scan step of a model that generates by diffusion over
+    blocks of L = ``cfg.block_length`` positions (SDAR), at every width:
+    a narrow frame is ``width`` = L, a wide one lets block rows ride its
+    chunks with w = L. Carry: (cached, produced, last_tok, done, poison,
+    nonfinite, stats, rng, kpool, vpool, (block tokens (B, L), masked
+    (B, L))); emissions are (B, L).
+
+    Invariants at every step boundary, per row: ``cached`` is a multiple
+    of L and the committed watermark, K and V final for [0, cached); a row
+    past its prompt's whole blocks holds the block at [cached, cached + L)
+    (``_block_plan``). A step forwards its L positions over the pages and
+    over the block's own keys, under the mask that lets a position see its
+    whole block, and reads the logits AT them (no shift):
+
+    - some position masked: a DENOISING step. ``block_unmask`` fills the
+      ``cfg.unmask_per_step`` masked positions of largest confidence (more
+      under "low_confidence_dynamic" where more pass the threshold); the
+      row emits nothing and ``cached`` stands: the K, V the step wrote
+      lie at and past the watermark, which no step reads (the attention
+      masks pool slots from the chunk's first position on) and the next
+      step of the row overwrites, as rejected speculation is;
+    - none masked: the COMMIT. The same forward's K, V are the block's
+      final ones, its logits go unused, ``cached`` moves by L and the row
+      emits the block's positions past its prompt (``_block_emit``); the
+      next block starts all masked.
+
+    So a block of m masked positions costs ceil(m / unmask_per_step) + 1
+    forwards. ``last_tok`` rides the carry unread. No draft and no repair
+    (``archs.validate_block_diffusion_serving``). ``target_forwards``
+    counts a block row's every forward; ``BLOCK_STAT_NAMES`` split them."""
+    blk, per_step = cfg.block_length, cfg.unmask_per_step
+    assert width % blk == 0, (width, blk)
+    threshold = cfg.confidence_threshold \
+        if cfg.remasking_strategy == "low_confidence_dynamic" else None
+
+    def body(carry, _):
+        (cached, produced, last_tok, done, poison, nonfinite, stats, rng,
+         kpool, vpool, (btok, bmask)) = carry
+        with jax.named_scope("frame_plan"):
+            prefilling, active, w, ids, positions, tok, masked = _block_plan(
+                prompts, prompt_lens, limits, width, blk, cfg.mask_token_id,
+                cached, produced, done, btok, bmask)
+            holds = active & ~prefilling
+            denoise = holds & jnp.any(masked, axis=1)
+            commit = holds & ~denoise
+            kv_read, attn_pairs = _attn_work(cached, w, window)
+            row_tiles = _row_tile_work(w, width, heads)
+        logits, kpool, vpool, moe_work = fwd(
+            params, ids, positions, tables, w, kpool, vpool, moe_work=True)
+        with jax.named_scope("sample"), jax.named_scope("bd_unmask"):
+            logits = _inject_poison(logits, poison)
+            sub = None
+            if not greedy:
+                rng, sub = jax.random.split(rng)
+            x0, unmask = block_unmask(
+                logits, masked & denoise[:, None], sub, temps,
+                per_step=per_step, threshold=threshold)
+            tok = jnp.where(unmask, x0, tok)
+        with jax.named_scope("frame_plan"):
+            emit, is_eos = _block_emit(commit, cached, produced, prompt_lens,
+                                       limits, eos_ids, tok)
+            emit, done, nonfinite, bad = _finite_check(logits, active, emit,
+                                                       done, nonfinite)
+            done = done | jnp.any(emit & is_eos, axis=1)
+            moved = jnp.where(holds, jnp.where(commit, blk, 0), w)
+            # a committed block's successor starts all masked
+            bmask = jnp.where(commit[:, None], True,
+                              jnp.where(denoise[:, None], masked & ~unmask,
+                                        bmask))
+            btok = jnp.where(denoise[:, None], tok, btok)
+            stats = stats + _stat_delta(
+                positions, ladder(*positions.shape),
+                emitted=emit, active=active,
+                prefill_toks=jnp.where(prefilling, w, 0),
+                eos=emit & is_eos, target_fwd=holds,
+                kv_read=kv_read, attn_pairs=attn_pairs, row_tiles=row_tiles,
+                moe_work=moe_work,
+                block_work=jnp.stack([
+                    jnp.sum(denoise), jnp.sum(commit), jnp.sum(unmask),
+                    jnp.sum(commit & ~bad),
+                    jnp.sum(masked & holds[:, None])]).astype(jnp.int32))
+        carry = (cached + moved,
+                 produced + jnp.sum(emit.astype(jnp.int32), axis=1),
+                 last_tok, done, poison, nonfinite, stats, rng, kpool, vpool,
+                 (btok, bmask))
+        return carry, (jnp.where(emit, tok, -1), emit)
+
+    return body
 
 
 def _spec_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
@@ -2074,7 +2272,7 @@ def _self_spec_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
 
 def _paged_attention(q, kpages, vpages, positions, cfg, window=None,
                      chunk_k=None, chunk_v=None, chunk_start=None,
-                     alibi_slopes=None, scale=None):
+                     alibi_slopes=None, scale=None, visible_to=None):
     """q: (B, C, H, D); kpages/vpages: (B, S_pad, KVH, D); positions: (B, C)
     absolute slot of each query (−1 = pad). Query at slot p attends slots ≤ p.
     ``window``: sliding-window width (may be traced; <= 0 = global).
@@ -2082,7 +2280,9 @@ def _paged_attention(q, kpages, vpages, positions, cfg, window=None,
     pool slots >= ``chunk_start`` (B,) are stale and masked; the chunk keys
     attend at key positions = ``positions``. ``alibi_slopes``: per-head
     slopes matching q's head count — the caller slices them under tensor
-    parallelism, where q carries only this shard's heads."""
+    parallelism, where q carries only this shard's heads. ``visible_to``
+    (B, C), given: the last key position each query sees, in place of its
+    own (``paged_ragged_attention``'s: attention causal by block)."""
     h = q.shape[2]
     s_pad = kpages.shape[1]
     k_pos = jnp.arange(s_pad)[None, :] * jnp.ones(
@@ -2114,7 +2314,8 @@ def _paged_attention(q, kpages, vpages, positions, cfg, window=None,
     if cfg.attn_softcap:
         logits = cfg.attn_softcap * jnp.tanh(logits / cfg.attn_softcap)
     kp = k_pos[:, None, :]                               # (B, 1, S_total)
-    mask = (kp >= 0) & (kp <= positions[:, :, None])     # pad keys/rows dead
+    see = positions if visible_to is None else visible_to
+    mask = (kp >= 0) & (kp <= see[:, :, None])           # pad keys/rows dead
     if window is not None:
         from ...ops.attention import window_mask
         mask = mask & window_mask(positions[:, :, None], kp, window)

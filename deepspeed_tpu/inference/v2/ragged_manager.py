@@ -157,7 +157,8 @@ class DSStateManager:
 #: the slot arrays an admission rewrites a row of
 _ADMIT_STATE = ("prompts", "tables", "ring_tables", "prompt_lens", "limits",
                 "eos_ids", "temps", "cached", "produced", "last_tok",
-                "penult", "done", "poison", "nonfinite", "recurrent")
+                "penult", "done", "poison", "nonfinite", "recurrent",
+                "block")
 
 
 #: the slots' axis of a model's recurrent state and of its convolution tail
@@ -174,7 +175,10 @@ def _admit_rows(state, idx, prompts, tables, rings, ints, temps):
     poison / latch state to the next tenant of the row, so both clear; nor
     may a model with linear layers hand on the row's recurrent state and
     convolution tail (``recurrent``, empty for every other model): a new
-    tenant's are zeros, what a sequence has before its first token."""
+    tenant's are zeros, what a sequence has before its first token. Nor
+    may a model that generates by diffusion over blocks hand on a
+    half-denoised block (``block`` = (tokens, masked), empty for every
+    other model): a new tenant's is all masked."""
     def put(a, v):
         return a.at[idx].set(v, mode="drop")
 
@@ -194,6 +198,9 @@ def _admit_rows(state, idx, prompts, tables, rings, ints, temps):
             (1,) * axis + (-1,) + (1,) * (a.ndim - axis - 1)),
             jnp.zeros((), a.dtype), a)
         for a, axis in zip(state["recurrent"], RECURRENT_SLOT_AXES))
+    # (tokens, masked), or empty: no operation at all
+    out["block"] = tuple(put(a, fill)
+                         for a, fill in zip(state["block"], (0, True)))
     return out
 
 
@@ -225,7 +232,7 @@ class DeviceSlotTable:
     def __init__(self, n_slots: int, prompt_width: int, table_width: int, rng,
                  tp=None, debug_replicas: bool = False,
                  n_stats: int = N_STATS, rings=(), hidden=None,
-                 recurrent=()):
+                 recurrent=(), block=None):
         self.n_slots = n_slots
         self.n_stats = n_stats     # lanes of the runner's stat vector
         # tensor-parallel serving (tp.TPContext): every slot array is
@@ -265,6 +272,20 @@ class DeviceSlotTable:
         # a row a slot; ``admit`` zeroes a new tenant's. () otherwise
         self.recurrent = tuple(self._dev(jnp.zeros(shape, dtype))
                                for shape, dtype in recurrent)
+        # a model that generates by diffusion over blocks (``block`` = (L
+        # positions a block, the positions a denoising step unmasks at
+        # least)): the block a
+        # row past its prompt holds, tokens and masked flags, L a slot, on
+        # the carry as ``recurrent`` is; ``admit`` masks a new tenant's
+        # whole. () otherwise. The replay then mirrors
+        # ``model_runner._block_plan`` (``_absorb_block``), a block wide
+        # where another model's narrow frame is one position
+        self.block_length, self.unmask_per_step = block or (0, 0)
+        self.block = () if block is None else (
+            zi(n_slots, block[0]),
+            self._dev(jnp.ones((n_slots, block[0]), bool)))
+        # denoising steps a row has run on the block it holds (host mirror)
+        self.denoised_h = np.zeros((n_slots,), np.int64)
         self.done = self._dev(jnp.ones((n_slots,), bool))
         # fault-injection flag (frame NaNs the row's logits while set) and
         # the in-graph finite-check latch — both ride the donated carry
@@ -330,13 +351,23 @@ class DeviceSlotTable:
     def live_count(self) -> int:
         return self.n_slots - self.free_slots()
 
+    @property
+    def prefill_end_h(self) -> np.ndarray:
+        """Where each row's prefill ends: its prompt's length, and for a
+        model that generates by diffusion over blocks the prompt's whole
+        blocks (the remainder joins the first generated block)."""
+        if not self.block_length:
+            return self.plen_h
+        return self.plen_h // self.block_length * self.block_length
+
     def prefill_steps_left(self, width: int) -> int:
         """Steps of a ``width``-wide frame until no live row prefills: a
         prefilling row takes ``min(width, what is left)`` prompt tokens a
         step whatever the other rows do, so the count is exact (0: nothing
         prefills, the next frame is narrow)."""
         live = self.uid_of_slot >= 0
-        left = np.where(live, self.plen_h - self.cached_h, 0).max(initial=0)
+        left = np.where(live, self.prefill_end_h - self.cached_h,
+                        0).max(initial=0)
         return int(-(-left // width))
 
     def prefill_carried(self) -> bool:
@@ -345,14 +376,46 @@ class DeviceSlotTable:
         boundary, behind a prefix hit too, has begun nothing yet)."""
         live = self.uid_of_slot >= 0
         return bool((live & (self.start_h < self.cached_h)
-                     & (self.cached_h < self.plen_h)).any())
+                     & (self.cached_h < self.prefill_end_h)).any())
 
     def steps_to_first_finish(self) -> int:
         """Steps of a narrow frame until the first live row emits the LAST
         token of its budget, at a token a step: exact without a draft and
         without an EOS, with either the latest that it can be."""
         live = self.uid_of_slot >= 0
+        if self.block_length:
+            return int(max(1, min(self._block_steps_left(i)
+                                  for i in np.flatnonzero(live))))
         return int(max(1, (self.limit_h - self.produced_h)[live].min()))
+
+    def _block_cost(self, start: int, plen: int) -> int:
+        """Forwards the block at ``start`` costs a row whose prompt is
+        ``plen`` long, at the fewest positions a denoising step unmasks
+        (L / S): the steps that unmask its positions past the prompt, and
+        the commit."""
+        masked = start + self.block_length - max(start, plen)
+        return -(-masked // self.unmask_per_step) + 1
+
+    def _block_steps_left(self, i: int) -> int:
+        """Steps until row ``i`` emits the last token of its budget: its
+        blocks still to commit at ``_block_cost`` each, less the denoising
+        steps it has run on the one it holds. Exact where a step unmasks L
+        / S positions (``low_confidence_static``, or no confidence past
+        the threshold) and without an EOS; with either the latest that it
+        can be."""
+        blk = self.block_length
+        start, plen = int(self.cached_h[i]), int(self.plen_h[i])
+        start = max(start, plen // blk * blk)
+        want = int(self.limit_h[i] - self.produced_h[i])
+        steps = -int(self.denoised_h[i])
+        if want <= 0:
+            return steps
+        # the block it holds may begin inside the prompt; every block
+        # behind it is past the prompt and costs alike
+        first = start + blk - max(start, plen)
+        further = -(-max(0, want - first) // blk)
+        return (steps + self._block_cost(start, plen)
+                + further * self._block_cost(start + blk, plen))
 
     def all_greedy(self) -> bool:
         live = self.uid_of_slot >= 0
@@ -407,6 +470,7 @@ class DeviceSlotTable:
             self.cached_h[slot] = self.start_h[slot] = cached0
             self.plen_h[slot] = len(toks)
             self.produced_h[slot] = 0
+            self.denoised_h[slot] = 0
             self.limit_h[slot] = limit
             self.eos_h[slot] = -1 if eos is None else eos
             self.temps_h[slot] = temp
@@ -511,18 +575,21 @@ class DeviceSlotTable:
             # ``last_tok``, where the carry has it
             hidden = [] if draft is None else [self.hidden]
             # the linear layers' (state, tail) goes in by name and comes
-            # back last
-            recurrent = {"recurrent": self.recurrent} if self.recurrent \
-                else {}
+            # back last; a half-denoised block likewise (a model has one
+            # or the other)
+            last = "block" if self.block else \
+                "recurrent" if self.recurrent else None
+            extra = {last: getattr(self, last)} if last else {}
             out = runner.frame_loop(
                 params, self.prompts, self.prompt_lens, self.limits,
                 self.eos_ids, self.temps, tables, self.cached,
                 self.produced, self.last_tok, self.done, self.poison,
                 self.nonfinite, self.stats, self.rng, kv.k, kv.v, *hidden,
                 width=width, steps=steps, greedy=greedy, repair=repair,
-                n_steps=self._trips(n_steps), **recurrent)
-            if recurrent:
-                *out, self.recurrent = out
+                n_steps=self._trips(n_steps), **extra)
+            if last:
+                *out, carried = out
+                setattr(self, last, carried)
             (toks, emit, self.cached, self.produced, self.last_tok, *hidden,
              self.done, self.poison, self.nonfinite, self.stats, self.rng,
              kv.k, kv.v) = out
@@ -638,6 +705,8 @@ class DeviceSlotTable:
         so the committed watermark never needs a device read-back."""
         if n_steps is not None:
             toks, emit = toks[:n_steps], emit[:n_steps]
+        if self.block_length:
+            return self._absorb_block(toks, emit, width)
         if emit.ndim == 3:
             return self._absorb_spec(toks, emit, width)
         emissions: Dict[int, List[int]] = {}
@@ -703,6 +772,50 @@ class DeviceSlotTable:
                                 or self.produced_h[i] >= self.limit_h[i]):
                             self.done_h[i] = True
                     self.cached_h[i] += m
+        for i in live:
+            if self.done_h[i]:
+                finished.append(int(self.uid_of_slot[i]))
+        return emissions, finished
+
+    def _absorb_block(self, toks: np.ndarray, emit: np.ndarray, width: int):
+        """Replay of a model that generates by diffusion over blocks, the
+        arithmetic of ``model_runner._block_plan`` / ``_block_scan_body``: a
+        row prefills its prompt's whole blocks by the chunk; past them each
+        step of a row with budget left either denoises the block it holds
+        (nothing out, the watermark stands) or commits it: the watermark
+        moves a block and the row's emit columns carry the block's tokens
+        past the prompt, cut at the budget and behind the first EOS. A
+        commit always emits (a block past the prompt's whole ones reaches
+        past the prompt, and a row with no budget left takes no step), so
+        the emit mask tells the two apart with no device read-back."""
+        emissions: Dict[int, List[int]] = {}
+        finished: List[int] = []
+        blk = self.block_length
+        whole = self.prefill_end_h
+        live = [i for i in range(self.n_slots) if self.uid_of_slot[i] >= 0]
+        commits = emit.any(axis=-1).tolist()     # one pass, no array a row
+        for s in range(toks.shape[0]):
+            for i in live:
+                if self.done_h[i]:
+                    continue
+                if self.cached_h[i] < whole[i]:
+                    self.cached_h[i] += min(width, whole[i] - self.cached_h[i])
+                    continue
+                if self.produced_h[i] >= self.limit_h[i]:
+                    continue
+                if not commits[s][i]:
+                    self.denoised_h[i] += 1
+                    continue
+                self.cached_h[i] += blk
+                self.denoised_h[i] = 0
+                uid = int(self.uid_of_slot[i])
+                for k in np.flatnonzero(emit[s, i]):
+                    t = int(toks[s, i, k])
+                    emissions.setdefault(uid, []).append(t)
+                    self.produced_h[i] += 1
+                    if (t == self.eos_h[i]
+                            or self.produced_h[i] >= self.limit_h[i]):
+                        self.done_h[i] = True
         for i in live:
             if self.done_h[i]:
                 finished.append(int(self.uid_of_slot[i]))

@@ -193,20 +193,36 @@ RECURRENT_STAT_NAMES = ("gdn_positions", "gdn_state_rw",
                         "gdn_positions_computed")
 
 
+#: a model that generates by diffusion over blocks (``cfg.block_length``),
+#: the very last lanes of its vector (it has neither a prediction module
+#: nor linear layers): of the row-forwards ``target_forwards`` counts for
+#: it (a row past its prompt forwarding its block of L positions), those
+#: that denoise (emit nothing, keep no K, V) and those that commit (the
+#: mask-free block: K, V kept, L tokens or fewer out); positions the
+#: denoising steps unmasked; blocks whose commit moved the watermark; and
+#: masked positions those forwards computed (the rest of their L x
+#: forwards held a token already)
+BLOCK_STAT_NAMES = ("bd_denoise_forwards", "bd_commit_forwards",
+                    "bd_positions_unmasked", "bd_blocks_committed",
+                    "bd_masked_positions_computed")
+
+
 def n_stats(routed: bool, layered: bool = False, share: bool = False,
             latent: bool = False, mtp: bool = False,
-            recurrent: bool = False) -> int:
+            recurrent: bool = False, block: bool = False) -> int:
     """Lanes of the stat vector of a model with (or without) routed
     experts (all of them held, or a share), of mixed cache kinds or of
     one, with latent attention or without, with a prediction module or
-    without, with linear layers or without."""
+    without, with linear layers or without, generating by diffusion over
+    blocks or left to right."""
     return (N_STATS
             + (len(MOE_STAT_NAMES) + len(MOVED_STAT_NAMES) if routed else 0)
             + (len(SHARE_STAT_NAMES) if share else 0)
             + (len(LAYER_STAT_NAMES) if layered else 0)
             + (len(LATENT_STAT_NAMES) if latent else 0)
             + (len(MTP_STAT_NAMES) if mtp else 0)
-            + (len(RECURRENT_STAT_NAMES) if recurrent else 0))
+            + (len(RECURRENT_STAT_NAMES) if recurrent else 0)
+            + (len(BLOCK_STAT_NAMES) if block else 0))
 
 
 #: host work between two frames, in loop order; ``dispatch`` and ``fetch``
@@ -637,6 +653,7 @@ class ServingTelemetry:
         self.kind_gauges: Dict[str, Dict[str, int]] = {}
         self._tail_names, self._share, self._mtp = (), False, False
         self._recurrent_bytes = 0       # a live slot's, a model with any
+        self._block = 0                 # a block's positions, such a model
         # per-class TTFT (the bench/SLO acceptance surface)
         self.class_ttft: Dict[str, LogBucketHistogram] = {}
         # live SLO signal windows (recent samples, seconds)
@@ -667,7 +684,7 @@ class ServingTelemetry:
                     tp_degree: int = 1, kv_block_bytes: int = 0,
                     layered: bool = False, latent: bool = False,
                     share: bool = False, mtp: bool = False,
-                    recurrent_slot_bytes: int = 0) -> None:
+                    recurrent_slot_bytes: int = 0, block: int = 0) -> None:
         """Called by ``serve()`` at generator construction.
         ``kv_block_bytes`` is the pool-resident footprint of one KV block
         across all layers (``BlockedKVCache.block_bytes``) — the
@@ -686,10 +703,18 @@ class ServingTelemetry:
         linear layers, its very last lanes are RECURRENT_STAT_NAMES and
         ``layered`` says how its full layers count): what a live slot holds
         of recurrent state and convolution tail, for the gauge
-        ``recurrent_bytes_in_use`` and its sum over frames."""
+        ``recurrent_bytes_in_use`` and its sum over frames. ``block``
+        (nonzero: the model generates by diffusion over blocks of that many
+        positions): its very last lanes are BLOCK_STAT_NAMES, and its
+        narrow frames are that wide."""
         self.reset()
         self._share, self._mtp = share, mtp
         self._recurrent_bytes = recurrent_slot_bytes
+        self._block = block
+        if block:
+            assert not mtp and not recurrent_slot_bytes, \
+                "each claims the vector's last lanes"
+            self.counters.update(dict.fromkeys(BLOCK_STAT_NAMES, 0))
         for n in MTP_STAT_NAMES if mtp else ():
             self.counters[n] = 0
         if recurrent_slot_bytes:
@@ -1289,7 +1314,8 @@ class ServingTelemetry:
         # linear layers' (a model has one or the other)
         last = {}
         last_names = (MTP_STAT_NAMES if self._mtp else
-                      RECURRENT_STAT_NAMES if self._recurrent_bytes else ())
+                      RECURRENT_STAT_NAMES if self._recurrent_bytes else
+                      BLOCK_STAT_NAMES if self._block else ())
         if last_names:
             delta, tail = np.split(delta, [len(delta) - len(last_names)])
             last = dict(zip(last_names, map(int, tail)))
@@ -1326,7 +1352,9 @@ class ServingTelemetry:
                                  + TILE_STAT_NAMES)}, **moe,
                     **layers, **last):
                 pass
-        split = "wide" if width > 1 else "narrow"
+        # (a block-diffusion model's narrow frames are a block wide)
+        wide = width > max(1, self._block)
+        split = "wide" if wide else "narrow"
         for i, name in enumerate(SPLIT_STAT_NAMES, len(STAT_NAMES)):
             self.counters[f"{name}_{split}"] += int(delta[i])
         for name, value in layers.items():
@@ -1362,7 +1390,7 @@ class ServingTelemetry:
                                   int(n))
         self.counters["frames"] += 1
         self.counters["frame_steps"] += steps
-        if width > 1:
+        if wide:
             self.counters["wide_steps"] += steps
         self.lifetime_frames += 1
         # run-average occupancy = active_row_steps / slot_steps_capacity

@@ -166,6 +166,21 @@ class TransformerConfig:
     attn_output_gate: bool = False
     # RMSNorm multiplies by 1 + w (w stored, drawn around 0), q/k norms too
     norm_unit_offset: bool = False
+    # generation by diffusion over blocks (SDAR, arXiv:2510.06303): the
+    # sequence is cut into blocks of ``block_length`` positions; a position
+    # sees every key up to the END of its own block, the logits at a
+    # position score the token AT it (no shift), and a block is generated
+    # by ``denoising_steps`` forwards that each unmask block_length /
+    # denoising_steps of its masked positions (those of largest confidence;
+    # under "low_confidence_dynamic" every one whose confidence passes
+    # ``confidence_threshold`` where those are more), then one forward of
+    # the mask-free block whose K, V are kept. 0: left to right, a token a
+    # forward. Served on the paged path (inference/v2) only
+    block_length: int = 0
+    denoising_steps: int = 0            # 0 -> block_length (one a step)
+    remasking_strategy: str = "low_confidence_dynamic"
+    confidence_threshold: float = 0.9
+    mask_token_id: int = 0
     # numerics
     dtype: str = "bfloat16"             # activation dtype
     param_dtype: str = "float32"        # stored parameter dtype
@@ -312,6 +327,19 @@ class TransformerConfig:
         side (Qwen3-Next: 2,048 + 2,048 + 4,096)."""
         return (2 * self.linear_num_key_heads * self.linear_key_head_dim
                 + self.linear_num_value_heads * self.linear_value_head_dim)
+
+    @property
+    def unmask_per_step(self) -> int:
+        """Positions a denoising step of a block unmasks at least: the
+        block's length over the steps (0 for a left-to-right model)."""
+        if not self.block_length:
+            return 0
+        steps = self.denoising_steps or self.block_length
+        if self.block_length % steps:
+            raise ValueError(
+                f"denoising_steps={steps} does not divide "
+                f"block_length={self.block_length}")
+        return self.block_length // steps
 
     def layer_type(self, i: int) -> str:
         tags = self.layer_tags
@@ -474,6 +502,22 @@ PRESETS = {
         linear_value_head_dim=128, linear_conv_kernel=4,
         num_experts=512, num_experts_per_tok=10, moe_norm_topk=True,
         moe_shared_expert_size=512, moe_shared_expert_gate=True, moe_impl="grouped"),
+    # SDAR-30B-A3B-Chat (JetLM/SDAR-30B-A3B-Chat config.json, sdar_moe):
+    # Qwen3-MoE's layer (GQA 32/4 x 128, one RMSNorm of 128 lanes a q and k
+    # head before RoPE; every layer routes to 8 of 128 experts of width
+    # 768, softmax over all, the eight renormalised, no shared expert; the
+    # published dense intermediate_size 6144 is used by no layer), untied
+    # head; generated by diffusion over blocks: config.json names neither
+    # the block length nor the schedule, these are the family's released
+    # defaults (blocks of 4, 4 steps, low_confidence_dynamic at 0.9, mask
+    # token 151669)
+    "sdar-30b-a3b": TransformerConfig(
+        vocab_size=151936, hidden_size=2048, num_layers=48, num_heads=32, num_kv_heads=4,
+        head_dim=128, intermediate_size=6144, moe_intermediate_size=768, max_seq_len=32768,
+        rope_theta=1e6, norm_eps=1e-6, qk_norm="head_dim", qk_norm_bias=False,
+        num_experts=128, num_experts_per_tok=8, moe_norm_topk=True, moe_impl="grouped",
+        block_length=4, denoising_steps=4, remasking_strategy="low_confidence_dynamic",
+        confidence_threshold=0.9, mask_token_id=151669),
     # BERT family (post-norm encoder, MLM head; acceptance config 2 trains
     # bert-large under ZeRO-1/2)
     "bert-base": TransformerConfig(vocab_size=30522, hidden_size=768, num_layers=12, num_heads=12,

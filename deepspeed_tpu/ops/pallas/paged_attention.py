@@ -383,7 +383,8 @@ def _latent_kernel(lyr_ref, bt_ref, cs_ref, lo_ref, win_ref, live_ref,
 def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
                            chunk_k=None, chunk_v=None, *, layer=None,
                            scale=None, window=0, alibi_slopes=None,
-                           softcap=0.0, ring=None, value_lanes=None):
+                           softcap=0.0, ring=None, value_lanes=None,
+                           visible_to=None):
     """Unified paged attention for decode AND chunked prefill.
 
     q: (B, C, H, D) — C query tokens per sequence (1 = decode);
@@ -421,6 +422,13 @@ def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
     the absorbed queries, ``value_lanes`` (static) the row's leading lanes
     that are its value. Returns (B, C, H, value_lanes); the kernel is named
     ``paged_attn_mla_c<C>``.
+
+    ``visible_to`` (B, C) int32, given: the last key position each query
+    sees, in place of its own (a model that attends causally BY BLOCK hands
+    in the last position of the query's block; -1 at a pad). The keys keep
+    their true positions and the chunk its true start: the kernel's one
+    comparison ``key <= pos`` reads another operand. Neither a window nor
+    ALiBi, which measure from the query's own position, goes with it.
     """
     latent = vpool is None
     assert latent == (value_lanes is not None) and not (latent and ring)
@@ -433,6 +441,10 @@ def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
         layer = 0
     b, c, h, d = q.shape
     _, kvh, nb, page_size, _ = kpool.shape
+    see = positions
+    if visible_to is not None:
+        assert not window and alibi_slopes is None and ring is None
+        see = visible_to
     lyr = jnp.asarray(layer, jnp.int32).reshape(1)
     mb = block_tables.shape[1]
     pool_heads, dv = kvh, d
@@ -463,13 +475,13 @@ def paged_ragged_attention(q, kpool, vpool, block_tables, positions,
         # (B, C, H, D) as it lies: tile t holds positions [t C/T, (t+1) C/T)
         # with every head, row r = c*H + h of the tile
         qg = q.reshape(b, kvh, rows, d)
-        pos_rep = jnp.repeat(positions, h, axis=1).reshape(b, kvh * rows, 1)
+        pos_rep = jnp.repeat(see, h, axis=1).reshape(b, kvh * rows, 1)
     else:
         # (B, C, H, D) → (B, KVH, C*G, D): row r = c*G + g
         qg = q.reshape(b, c, kvh, group, d).transpose(0, 2, 1, 3, 4).reshape(
             b, kvh, rows, d)
         # per-row positions: row r = c*G + g sits at positions[c]
-        pos_rep = jnp.repeat(positions, group, axis=1).reshape(b, rows, 1)
+        pos_rep = jnp.repeat(see, group, axis=1).reshape(b, rows, 1)
     valid = positions >= 0
     win_arr = jnp.asarray(window, jnp.int32).reshape(1)
     minpos = jnp.min(jnp.where(valid, positions, 1 << 30), axis=1)
