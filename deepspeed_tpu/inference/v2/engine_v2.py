@@ -2394,10 +2394,10 @@ class InferenceEngineV2:
                 # to do in it (``_plan_frame_steps``: from the host mirrors,
                 # no device read) ----
                 need = slots.prefill_steps_left(c.prefill_chunk_size)
-                # (a block wide where the model generates by diffusion
+                # (two blocks wide where the model generates by diffusion
                 # over blocks)
                 width = c.prefill_chunk_size if need \
-                    else max(1, self.runner.block_length)
+                    else self.runner.narrow_width
                 cur_steps = steps
                 saturated = slots.free_slots() == 0
                 if adaptive:

@@ -1520,11 +1520,13 @@ def validate_block_diffusion_serving(engine_config, cfg: TransformerConfig,
     diffusion over blocks (``cfg.block_length``) cannot be served with yet.
     A row past its prompt holds a half-denoised block (tokens and masked
     flags) on the frame programs' carry, its watermark moves a block at a
-    time, and what a denoising step writes past it is not a sequence's
-    final K, V: whatever moves, shares or rewinds a sequence by pages and
-    tokens alone would leave the block behind or read those rows. An
-    evicted or preempted sequence is not refused: its committed blocks'
-    tokens join its prompt, which then ends on a block's edge."""
+    time (by the step that commits the block and, in the same forward,
+    begins to denoise the next), and what a denoising step writes past it
+    is not a sequence's final K, V: whatever moves, shares or rewinds a
+    sequence by pages and tokens alone would leave the block behind or
+    read those rows. An evicted or preempted sequence is not refused: its
+    committed blocks' tokens join its prompt, which then ends on a block's
+    edge."""
     c = engine_config
     blk = cfg.block_length
     probs = []
@@ -1554,6 +1556,11 @@ def validate_block_diffusion_serving(engine_config, cfg: TransformerConfig,
         probs.append(f"prefill_chunk_size={c.prefill_chunk_size}, no multiple "
                      f"of block_length={blk} (a chunk must end on a block's "
                      "edge: a position sees its whole block)")
+    elif c.prefill_chunk_size < 2 * blk:
+        probs.append(f"prefill_chunk_size={c.prefill_chunk_size}, under two "
+                     f"blocks of block_length={blk} (a row riding a chunk "
+                     "forwards the block it commits and the next in one "
+                     "step)")
     if blk & (blk - 1):
         probs.append(f"block_length={blk} (a power of two is what the pages, "
                      "the chunks and the narrow step's tiles divide by)")

@@ -200,9 +200,17 @@ class PagedModelRunner:
         """Positions of a block of a model that generates by diffusion over
         blocks (``cfg.block_length``): a row past its prompt holds one,
         tokens and masked flags, on the frame programs' carry (``block``),
-        a narrow step is that many positions wide, and the stat vector ends
-        with ``telemetry.BLOCK_STAT_NAMES``; 0 for every other model."""
+        and the stat vector ends with ``telemetry.BLOCK_STAT_NAMES``; 0 for
+        every other model."""
         return self.cfg.block_length
+
+    @property
+    def narrow_width(self) -> int:
+        """Positions a row of a NARROW frame (no row prefills) may forward:
+        one token, or for a model that generates by diffusion over blocks
+        two blocks, the one a step commits and the next, which the same
+        step begins to denoise (``_block_scan_body``)."""
+        return max(1, 2 * self.cfg.block_length)
 
     def set_tp(self, tp_ctx) -> None:
         """Bind a ``tp.TPContext`` (engine setup, before any serving loop
@@ -223,7 +231,7 @@ class PagedModelRunner:
 
     def _forward(self, params, ids, positions, block_tables, valid_counts,
                  kpool, vpool, *, all_logits=False, tp=None, moe_work=False,
-                 hidden=False, mtp=None, recurrent=None):
+                 hidden=False, mtp=None, recurrent=None, head_at=None):
         """ids/positions: (B, C); block_tables: (B, MB);
         valid_counts: (B,) number of real (non-pad) tokens in the chunk;
         kpool/vpool: (L, KVH, NB, bs, D). Returns (last_logits (B, V),
@@ -266,14 +274,20 @@ class PagedModelRunner:
         through the step and gives the pair back as the LAST value. Row b
         moves its state by its ``valid_counts[b]`` live positions, which
         are the first of its chunk; a row with none keeps state and tail to
-        the bit. The pools hold the full-attention layers alone."""
+        the bit. The pools hold the full-attention layers alone.
+
+        ``head_at`` (B,) int32, a model that generates by diffusion over
+        blocks: the chunk index of the first of the ``block_length``
+        positions whose logits a row's step reads (None: 0 in every row)."""
         cfg = self.cfg
         # a model that attends causally by block (``cfg.block_length``): a
         # position sees every key up to the last position of its block, in
         # prefill chunks and block steps alike, on either attention path;
         # RoPE and the pages keep the true positions. Its logits are those
-        # AT the first ``block_length`` positions of each row's chunk, (B,
-        # block_length, V): what a row past its prompt holds there
+        # AT ``block_length`` positions of each row's chunk, (B,
+        # block_length, V): the block a row past its prompt denoises there,
+        # the chunk's first positions or, behind a block the same step
+        # commits, the next (``head_at``)
         see = None
         if cfg.block_length:
             blk = cfg.block_length
@@ -767,7 +781,12 @@ class PagedModelRunner:
         with jax.named_scope("lm_head"):
             stack_h = h
             if cfg.block_length:
-                h, all_logits = h[:, :cfg.block_length], True
+                # the head on a block's rows a slot, never on a chunk's
+                all_logits = True
+                if head_at is None:
+                    head_at = jnp.zeros(h.shape[:1], jnp.int32)
+                h = jax.vmap(lambda row, at: jax.lax.dynamic_slice_in_dim(
+                    row, at, blk))(h, head_at)
             h = L.apply_norm(params["final_norm"], h, cfg)
             logits = self._head(params, h, valid_counts, all_logits, tp=tp)
         return (logits, kpool, vpool) + ((work,) if moe_work else ()) \
@@ -1035,9 +1054,9 @@ class PagedModelRunner:
             model generates by diffusion over blocks of L positions
             (``cfg.block_length``). The pair is the carry's LAST field as
             ``recurrent`` is another model's, donated and returned last; the
-            body is ``_block_scan_body``, a narrow frame is ``width`` = L,
-            and emissions are (steps, B, L): a row's step emits a committed
-            block's tokens or none.
+            body is ``_block_scan_body``, a narrow frame is ``width`` = 2 L
+            (``narrow_width``), and emissions are (steps, B, L): a row's
+            step emits a committed block's tokens or none.
 
             Tensor-parallel (``self.tp`` set): the same program compiles
             under shard_map on the 1-D tp mesh — params and KV pools
@@ -1760,40 +1779,56 @@ def _wide_emit(active, prefilling, cached, w, prompt_lens, eos_ids, nxt,
     return emit, last_tok, done
 
 
-def _block_plan(prompts, prompt_lens, limits, width, blk, mask_id, cached,
-                produced, done, btok, bmask):
+def _block_plan(prompts, prompt_lens, limits, eos_ids, width, blk, mask_id,
+                cached, produced, done, btok, bmask):
     """``_wide_plan`` for rows that generate by diffusion over blocks of
-    ``blk`` positions (``width`` a multiple of it; ``cached`` always is
-    one): a row prefills while ``cached`` is under its prompt's whole
-    blocks, ``prompt_lens // blk * blk``, and consumes up to ``width``
-    prompt tokens of them; past them a row with budget left holds the block
-    at ``cached .. cached + blk - 1``: the prompt's remainder where it
-    reaches into the block, then the carried tokens (``btok``) where the
-    carried flags (``bmask``) say a position is unmasked and ``mask_id``
-    where they say it is not. Whether a position is masked is that flag,
-    never the token's value: a prompt may hold ``mask_id``.
+    ``blk`` positions (``width`` a multiple of it and two at least;
+    ``cached`` always is a multiple): a row prefills while ``cached`` is
+    under its prompt's whole blocks, ``prompt_lens // blk * blk``, and
+    consumes up to ``width`` prompt tokens of them; past them a row with
+    budget left holds the block at ``cached .. cached + blk - 1``: the
+    prompt's remainder where it reaches into the block, then the carried
+    tokens (``btok``) where the carried flags (``bmask``) say a position is
+    unmasked and ``mask_id`` where they say it is not. Whether a position is
+    masked is that flag, never the token's value: a prompt may hold
+    ``mask_id``.
 
-    Returns (prefilling, active, w, ids, positions, tok (B, blk) the
-    block's tokens, masked (B, blk)); ``DeviceSlotTable``'s replay mirrors
-    this arithmetic on the host, so it must not fork."""
+    A held block with no masked position is committed by this step and
+    gives out ``emit`` (``_block_emit``). Where that does not end the row
+    (no EOS among them, budget left behind them) the step is FUSED: the row
+    forwards ``2 blk`` positions, the block's tokens and behind them the
+    next block, all masked. Every other holding row forwards ``blk``.
+
+    Returns (prefilling, active, w, ids, positions, tok (B, blk) the held
+    block's tokens, masked (B, blk), commit (B,), fused (B,), emit (B,
+    blk), is_eos (B, blk)); ``DeviceSlotTable``'s replay mirrors this
+    arithmetic on the host, so it must not fork."""
     offs = jnp.arange(width)
     whole = prompt_lens // blk * blk
     prefilling = cached < whole
     active = ~done & (prefilling | (produced < limits))
-    w = jnp.where(active,
-                  jnp.where(prefilling, jnp.minimum(width, whole - cached),
-                            blk), 0)
     at = cached[:, None] + offs[None, :]
     from_prompt = jnp.take_along_axis(
         prompts, jnp.clip(at, 0, prompts.shape[1] - 1), axis=1)
     in_prompt = at[:, :blk] < prompt_lens[:, None]
     masked = ~prefilling[:, None] & ~in_prompt & bmask
     tok = jnp.where(in_prompt, from_prompt[:, :blk], btok)
-    held = jnp.pad(jnp.where(masked, mask_id, tok),
-                   ((0, 0), (0, width - blk)))
+    commit = active & ~prefilling & ~jnp.any(masked, axis=1)
+    emit, is_eos = _block_emit(commit, cached, produced, prompt_lens, limits,
+                               eos_ids, tok)
+    ends = jnp.any(emit & is_eos, axis=1) | (
+        produced + jnp.sum(emit.astype(jnp.int32), axis=1) >= limits)
+    fused = commit & ~ends
+    w = jnp.where(active,
+                  jnp.where(prefilling, jnp.minimum(width, whole - cached),
+                            jnp.where(fused, 2 * blk, blk)), 0)
+    held = jnp.pad(jnp.concatenate([jnp.where(masked, mask_id, tok),
+                                    jnp.full_like(tok, mask_id)], axis=1),
+                   ((0, 0), (0, width - 2 * blk)))
     ids = jnp.where(prefilling[:, None], from_prompt, held)
     positions = jnp.where(offs[None, :] < w[:, None], at, -1)
-    return prefilling, active, w, ids, positions, tok, masked
+    return (prefilling, active, w, ids, positions, tok, masked, commit, fused,
+            emit, is_eos)
 
 
 def _block_emit(commit, cached, produced, prompt_lens, limits, eos_ids, tok):
@@ -1817,36 +1852,48 @@ def _block_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                      ladder=pack_ladder, heads=None):
     """The serving scan step of a model that generates by diffusion over
     blocks of L = ``cfg.block_length`` positions (SDAR), at every width:
-    a narrow frame is ``width`` = L, a wide one lets block rows ride its
-    chunks with w = L. Carry: (cached, produced, last_tok, done, poison,
-    nonfinite, stats, rng, kpool, vpool, (block tokens (B, L), masked
-    (B, L))); emissions are (B, L).
+    a narrow frame is ``width`` = 2 L, a wide one lets block rows ride its
+    chunks with w = L or 2 L. Carry: (cached, produced, last_tok, done,
+    poison, nonfinite, stats, rng, kpool, vpool, (block tokens (B, L),
+    masked (B, L))); emissions are (B, L).
 
     Invariants at every step boundary, per row: ``cached`` is a multiple
     of L and the committed watermark, K and V final for [0, cached); a row
     past its prompt's whole blocks holds the block at [cached, cached + L)
-    (``_block_plan``). A step forwards its L positions over the pages and
-    over the block's own keys, under the mask that lets a position see its
-    whole block, and reads the logits AT them (no shift):
+    (``_block_plan``). A step forwards it over the pages and over the
+    chunk's own keys, under the mask that lets a position see its whole
+    block and every block before it, and reads logits AT L positions (no
+    shift):
 
-    - some position masked: a DENOISING step. ``block_unmask`` fills the
-      ``cfg.unmask_per_step`` masked positions of largest confidence (more
-      under "low_confidence_dynamic" where more pass the threshold); the
-      row emits nothing and ``cached`` stands: the K, V the step wrote
-      lie at and past the watermark, which no step reads (the attention
-      masks pool slots from the chunk's first position on) and the next
-      step of the row overwrites, as rejected speculation is;
-    - none masked: the COMMIT. The same forward's K, V are the block's
-      final ones, its logits go unused, ``cached`` moves by L and the row
-      emits the block's positions past its prompt (``_block_emit``); the
-      next block starts all masked.
+    - some position masked: a DENOISING step of L positions. ``block_unmask``
+      fills the ``cfg.unmask_per_step`` masked positions of largest
+      confidence (more under "low_confidence_dynamic" where more pass the
+      threshold); the row emits nothing and ``cached`` stands: the K, V
+      the step wrote lie at and past the watermark, which no step reads
+      (the attention masks pool slots from the chunk's first position on)
+      and the next step of the row overwrites, as rejected speculation is;
+    - none masked, and the row goes on behind the block (``_block_plan``'s
+      ``fused``): the FUSED step of 2 L positions, the block's tokens and
+      the next block, all masked. The first L positions' K, V are the
+      block's final ones: ``cached`` moves by L and the row emits the
+      block's positions past its prompt (``_block_emit``). The logits are
+      read at the second L, which see the first's keys of this very
+      forward as a prefill chunk's later blocks see its earlier ones, and
+      ``block_unmask`` runs on them: the next block's first denoising
+      step. Its K, V lie past the new watermark like any denoising step's;
+    - none masked, and the block is the row's last (an EOS among what it
+      emits, or the budget spent): the COMMIT alone, L positions, logits
+      unused.
 
-    So a block of m masked positions costs ceil(m / unmask_per_step) + 1
-    forwards. ``last_tok`` rides the carry unread. No draft and no repair
+    So a block of m masked positions costs ceil(m / unmask_per_step)
+    forwards, and a row one more for its last block: S a block where the
+    plain walk takes S + 1, the same tokens. ``last_tok`` rides the carry
+    unread. No draft and no repair
     (``archs.validate_block_diffusion_serving``). ``target_forwards``
-    counts a block row's every forward; ``BLOCK_STAT_NAMES`` split them."""
+    counts a block row's every forward, a fused one once;
+    ``BLOCK_STAT_NAMES`` split them."""
     blk, per_step = cfg.block_length, cfg.unmask_per_step
-    assert width % blk == 0, (width, blk)
+    assert width % blk == 0 and width >= 2 * blk, (width, blk)
     threshold = cfg.confidence_threshold \
         if cfg.remasking_strategy == "low_confidence_dynamic" else None
 
@@ -1854,37 +1901,39 @@ def _block_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
         (cached, produced, last_tok, done, poison, nonfinite, stats, rng,
          kpool, vpool, (btok, bmask)) = carry
         with jax.named_scope("frame_plan"):
-            prefilling, active, w, ids, positions, tok, masked = _block_plan(
-                prompts, prompt_lens, limits, width, blk, cfg.mask_token_id,
-                cached, produced, done, btok, bmask)
+            (prefilling, active, w, ids, positions, tok, masked, commit, fused,
+             emit, is_eos) = _block_plan(
+                prompts, prompt_lens, limits, eos_ids, width, blk,
+                cfg.mask_token_id, cached, produced, done, btok, bmask)
             holds = active & ~prefilling
-            denoise = holds & jnp.any(masked, axis=1)
-            commit = holds & ~denoise
+            denoise = holds & ~commit
+            # the positions whose logits the step reads: the held block's
+            # masked ones, or every one of the block behind it
+            reads = (masked & denoise[:, None]) | fused[:, None]
             kv_read, attn_pairs = _attn_work(cached, w, window)
             row_tiles = _row_tile_work(w, width, heads)
         logits, kpool, vpool, moe_work = fwd(
-            params, ids, positions, tables, w, kpool, vpool, moe_work=True)
+            params, ids, positions, tables, w, kpool, vpool, moe_work=True,
+            head_at=jnp.where(fused, blk, 0))
         with jax.named_scope("sample"), jax.named_scope("bd_unmask"):
             logits = _inject_poison(logits, poison)
             sub = None
             if not greedy:
                 rng, sub = jax.random.split(rng)
-            x0, unmask = block_unmask(
-                logits, masked & denoise[:, None], sub, temps,
-                per_step=per_step, threshold=threshold)
-            tok = jnp.where(unmask, x0, tok)
+            x0, unmask = block_unmask(logits, reads, sub, temps,
+                                      per_step=per_step, threshold=threshold)
         with jax.named_scope("frame_plan"):
-            emit, is_eos = _block_emit(commit, cached, produced, prompt_lens,
-                                       limits, eos_ids, tok)
             emit, done, nonfinite, bad = _finite_check(logits, active, emit,
                                                        done, nonfinite)
             done = done | jnp.any(emit & is_eos, axis=1)
             moved = jnp.where(holds, jnp.where(commit, blk, 0), w)
-            # a committed block's successor starts all masked
-            bmask = jnp.where(commit[:, None], True,
-                              jnp.where(denoise[:, None], masked & ~unmask,
-                                        bmask))
-            btok = jnp.where(denoise[:, None], tok, btok)
+            # the block the row holds next: the same one less what this
+            # step unmasked, or the one behind a committed block, all
+            # masked but for what a fused step unmasked of it
+            denoised = (denoise | fused)[:, None]
+            bmask = jnp.where(denoised, reads & ~unmask,
+                              jnp.where(commit[:, None], True, bmask))
+            btok = jnp.where(denoised, jnp.where(unmask, x0, tok), btok)
             stats = stats + _stat_delta(
                 positions, ladder(*positions.shape),
                 emitted=emit, active=active,
@@ -1893,9 +1942,9 @@ def _block_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                 kv_read=kv_read, attn_pairs=attn_pairs, row_tiles=row_tiles,
                 moe_work=moe_work,
                 block_work=jnp.stack([
-                    jnp.sum(denoise), jnp.sum(commit), jnp.sum(unmask),
-                    jnp.sum(commit & ~bad),
-                    jnp.sum(masked & holds[:, None])]).astype(jnp.int32))
+                    jnp.sum(denoise | fused), jnp.sum(commit & ~fused),
+                    jnp.sum(unmask), jnp.sum(commit & ~bad), jnp.sum(reads),
+                    jnp.sum(fused)]).astype(jnp.int32))
         carry = (cached + moved,
                  produced + jnp.sum(emit.astype(jnp.int32), axis=1),
                  last_tok, done, poison, nonfinite, stats, rng, kpool, vpool,

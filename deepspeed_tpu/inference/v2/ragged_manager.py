@@ -278,7 +278,7 @@ class DeviceSlotTable:
         # row past its prompt holds, tokens and masked flags, L a slot, on
         # the carry as ``recurrent`` is; ``admit`` masks a new tenant's
         # whole. () otherwise. The replay then mirrors
-        # ``model_runner._block_plan`` (``_absorb_block``), a block wide
+        # ``model_runner._block_plan`` (``_absorb_block``), two blocks wide
         # where another model's narrow frame is one position
         self.block_length, self.unmask_per_step = block or (0, 0)
         self.block = () if block is None else (
@@ -391,18 +391,20 @@ class DeviceSlotTable:
     def _block_cost(self, start: int, plen: int) -> int:
         """Forwards the block at ``start`` costs a row whose prompt is
         ``plen`` long, at the fewest positions a denoising step unmasks
-        (L / S): the steps that unmask its positions past the prompt, and
-        the commit."""
+        (L / S): the steps that unmask its positions past the prompt. The
+        first of them is also the commit of the block before; a row's last
+        block takes one forward more, its commit alone
+        (``model_runner._block_scan_body``)."""
         masked = start + self.block_length - max(start, plen)
-        return -(-masked // self.unmask_per_step) + 1
+        return -(-masked // self.unmask_per_step)
 
     def _block_steps_left(self, i: int) -> int:
         """Steps until row ``i`` emits the last token of its budget: its
         blocks still to commit at ``_block_cost`` each, less the denoising
-        steps it has run on the one it holds. Exact where a step unmasks L
-        / S positions (``low_confidence_static``, or no confidence past
-        the threshold) and without an EOS; with either the latest that it
-        can be."""
+        steps it has run on the one it holds, and the last one's commit.
+        Exact where a step unmasks L / S positions
+        (``low_confidence_static``, or no confidence past the threshold)
+        and without an EOS; with either the latest that it can be."""
         blk = self.block_length
         start, plen = int(self.cached_h[i]), int(self.plen_h[i])
         start = max(start, plen // blk * blk)
@@ -415,7 +417,7 @@ class DeviceSlotTable:
         first = start + blk - max(start, plen)
         further = -(-max(0, want - first) // blk)
         return (steps + self._block_cost(start, plen)
-                + further * self._block_cost(start + blk, plen))
+                + further * self._block_cost(start + blk, plen) + 1)
 
     def all_greedy(self) -> bool:
         live = self.uid_of_slot >= 0
@@ -787,7 +789,10 @@ class DeviceSlotTable:
         past the prompt, cut at the budget and behind the first EOS. A
         commit always emits (a block past the prompt's whole ones reaches
         past the prompt, and a row with no budget left takes no step), so
-        the emit mask tells the two apart with no device read-back."""
+        the emit mask tells the two apart with no device read-back; and a
+        commit that does not end the row (no EOS out, budget left) was a
+        FUSED step, the first denoising step of the block the row holds
+        next."""
         emissions: Dict[int, List[int]] = {}
         finished: List[int] = []
         blk = self.block_length
@@ -807,7 +812,6 @@ class DeviceSlotTable:
                     self.denoised_h[i] += 1
                     continue
                 self.cached_h[i] += blk
-                self.denoised_h[i] = 0
                 uid = int(self.uid_of_slot[i])
                 for k in np.flatnonzero(emit[s, i]):
                     t = int(toks[s, i, k])
@@ -816,6 +820,7 @@ class DeviceSlotTable:
                     if (t == self.eos_h[i]
                             or self.produced_h[i] >= self.limit_h[i]):
                         self.done_h[i] = True
+                self.denoised_h[i] = 0 if self.done_h[i] else 1
         for i in live:
             if self.done_h[i]:
                 finished.append(int(self.uid_of_slot[i]))

@@ -196,15 +196,21 @@ RECURRENT_STAT_NAMES = ("gdn_positions", "gdn_state_rw",
 #: a model that generates by diffusion over blocks (``cfg.block_length``),
 #: the very last lanes of its vector (it has neither a prediction module
 #: nor linear layers): of the row-forwards ``target_forwards`` counts for
-#: it (a row past its prompt forwarding its block of L positions), those
-#: that denoise (emit nothing, keep no K, V) and those that commit (the
-#: mask-free block: K, V kept, L tokens or fewer out); positions the
-#: denoising steps unmasked; blocks whose commit moved the watermark; and
-#: masked positions those forwards computed (the rest of their L x
-#: forwards held a token already)
+#: it (a row past its prompt forwarding the block of L positions it holds,
+#: or 2 L in a fused step: ONE row-forward), those that denoise (read
+#: logits at L positions and unmask some; a fused one among them) and those
+#: that ONLY commit (the mask-free block of a row it ends: K, V kept, L
+#: tokens or fewer out, logits unused); positions the denoising steps
+#: unmasked; blocks whose watermark moved (by a fused step or a commit);
+#: masked positions those forwards computed (the rest of their positions
+#: held a token already); and ``bd_fused_forwards``, the denoising forwards
+#: that also committed the block before: its K, V kept and its tokens out
+#: from the first L positions, the next block's first denoising step at
+#: the second L (``model_runner._block_scan_body``). A row's every block
+#: but its last is committed so
 BLOCK_STAT_NAMES = ("bd_denoise_forwards", "bd_commit_forwards",
                     "bd_positions_unmasked", "bd_blocks_committed",
-                    "bd_masked_positions_computed")
+                    "bd_masked_positions_computed", "bd_fused_forwards")
 
 
 def n_stats(routed: bool, layered: bool = False, share: bool = False,
@@ -706,7 +712,7 @@ class ServingTelemetry:
         ``recurrent_bytes_in_use`` and its sum over frames. ``block``
         (nonzero: the model generates by diffusion over blocks of that many
         positions): its very last lanes are BLOCK_STAT_NAMES, and its
-        narrow frames are that wide."""
+        narrow frames are two blocks wide."""
         self.reset()
         self._share, self._mtp = share, mtp
         self._recurrent_bytes = recurrent_slot_bytes
@@ -1352,8 +1358,8 @@ class ServingTelemetry:
                                  + TILE_STAT_NAMES)}, **moe,
                     **layers, **last):
                 pass
-        # (a block-diffusion model's narrow frames are a block wide)
-        wide = width > max(1, self._block)
+        # (a block-diffusion model's narrow frames are two blocks wide)
+        wide = width > max(1, 2 * self._block)
         split = "wide" if wide else "narrow"
         for i, name in enumerate(SPLIT_STAT_NAMES, len(STAT_NAMES)):
             self.counters[f"{name}_{split}"] += int(delta[i])

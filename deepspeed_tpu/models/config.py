@@ -173,9 +173,12 @@ class TransformerConfig:
     # by ``denoising_steps`` forwards that each unmask block_length /
     # denoising_steps of its masked positions (those of largest confidence;
     # under "low_confidence_dynamic" every one whose confidence passes
-    # ``confidence_threshold`` where those are more), then one forward of
-    # the mask-free block whose K, V are kept. 0: left to right, a token a
-    # forward. Served on the paged path (inference/v2) only
+    # ``confidence_threshold`` where those are more); the mask-free
+    # block's K, V are kept from one forward more of it, which the paged
+    # path fuses with the next block's first denoising step (2 x
+    # block_length positions in one forward: ``denoising_steps`` forwards a
+    # block, and one more for a request's last). 0: left to right, a token
+    # a forward. Served on the paged path (inference/v2) only
     block_length: int = 0
     denoising_steps: int = 0            # 0 -> block_length (one a step)
     remasking_strategy: str = "low_confidence_dynamic"

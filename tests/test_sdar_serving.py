@@ -4,11 +4,13 @@ The preset (``models/config.py`` ``sdar-30b-a3b``) generates by diffusion
 over blocks: a position sees its whole block, the logits at a position score
 the token AT it, a row past its prompt holds a block of L positions on the
 frame program's carry and each of its steps either denoises it (nothing out,
-no K, V kept) or commits it (L tokens or fewer out). The reference is the
-benchmark's (``perfbench/configs/sdar_moe_reference.py``: float32, one
-sequence, no paging), which shares no code with the program. Sizes here are
-small and keep every ratio that matters: GQA, a norm a head, top 2 of 8
-experts renormalised, L = 4.
+no K, V kept) or commits it (L tokens or fewer out), and a commit that does
+not end the row is the next block's first denoising step too: one forward of
+2 L positions. The reference is the benchmark's
+(``perfbench/configs/sdar_moe_reference.py``: float32, one sequence, no
+paging, S + 1 forwards a block), which shares no code with the program.
+Sizes here are small and keep every ratio that matters: GQA, a norm a head,
+top 2 of 8 experts renormalised, L = 4.
 
 One engine a schedule (module fixtures) and ONE jitted forward a width are
 shared by every case.
@@ -297,22 +299,29 @@ def test_served_tokens_equal_the_reference(engine, params, reference,
     c = engine.telemetry.counters
     assert mirrored and max(mirrored) == SLOTS
     assert c["tokens_emitted"] == 10 * len(prompts)
-    # S + 1 forwards a block, fewer for a first block's remainder
+    # S forwards a block (fewer for a first block's remainder), the first
+    # of them fused with the commit of the block before; one forward more
+    # a request, its last block's commit alone
     assert c["target_forwards"] == \
         c["bd_denoise_forwards"] + c["bd_commit_forwards"]
-    assert c["bd_blocks_committed"] == c["bd_commit_forwards"]
+    assert c["bd_blocks_committed"] == \
+        c["bd_commit_forwards"] + c["bd_fused_forwards"]
     assert c["bd_positions_unmasked"] == c["bd_denoise_forwards"]
     blocks = sum(-(-(n % BLK + 10) // BLK) for n in lengths)
-    assert c["bd_commit_forwards"] == blocks
+    assert c["bd_blocks_committed"] == blocks
+    assert c["bd_commit_forwards"] == len(lengths)
     assert c["bd_positions_unmasked"] == \
         blocks * BLK - sum(n % BLK for n in lengths)
+    assert c["bd_masked_positions_computed"] >= c["bd_positions_unmasked"]
     # some block row rode a wide step: a frame that prefilled also forwarded
     # rows past their prompt (9 requests over 4 slots)
     assert c["wide_steps"] > 0 and c["prefill_tokens"] == sum(
         n // BLK * BLK for n in lengths)
-    # every expert row is a live position's: 2 a position and layer
+    # every expert row is a live position's: 2 a position and layer, and a
+    # fused forward is two blocks of positions
     assert c["expert_rows"] == 2 * 2 * (
-        c["prefill_tokens"] + BLK * c["target_forwards"])
+        c["prefill_tokens"]
+        + BLK * (c["target_forwards"] + c["bd_fused_forwards"]))
 
 
 def test_a_prompt_may_hold_the_mask_token(engine, params, reference):
@@ -344,7 +353,7 @@ def test_eos_inside_a_block_ends_the_row_there(engine, params, reference):
 @pytest.mark.parametrize("steps", [1, 2])
 def test_fewer_denoising_steps(params, reference, steps):
     """S = 1 unmasks a whole block in one forward, S = 2 two positions a
-    step: 2 and 3 forwards a block."""
+    step: 1 and 2 forwards a block, and one more a request."""
     eng = engine_of(params, steps=steps)
     prompts = prompts_of([16, 18, 7], seed=6)
     outs = eng.generate(prompts, max_new_tokens=8)
@@ -352,7 +361,9 @@ def test_fewer_denoising_steps(params, reference, steps):
     for prompt, out in zip(prompts, outs):
         assert list(out) == reference.generate(params, prompt, 8, config)
     c = eng.telemetry.counters
-    assert c["bd_commit_forwards"] == 2 + 3 + 3
+    assert c["bd_blocks_committed"] == 2 + 3 + 3
+    assert c["bd_commit_forwards"] == 3
+    assert c["bd_fused_forwards"] == 1 + 2 + 2
     # a whole block: S forwards; a remainder of 2 or 3: 1 at either S
     assert c["bd_denoise_forwards"] == (2 * steps) + (1 + 2 * steps) \
         + (1 + 2 * steps)
@@ -401,22 +412,307 @@ def test_temperature_draws_by_its_rng(engine):
 
 
 # ---------------------------------------------------------------------------
+# the fused step: a block's commit rides the next block's first denoising
+# step, against a plain walk of S + 1 forwards a block through the same
+# ``_forward``
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def walker(params):
+    return Served(params)
+
+
+def plain_walk(served, prompt, budget, *, eos=None, per_step=1,
+               threshold=None):
+    """The block-diffusion walk with nothing fused, a forward a step
+    through the runner's ``_forward`` (chunks of 16 and of L): a block's
+    denoising steps, each unmasking the ``per_step`` masked positions of
+    largest confidence (every one past ``threshold`` where those are at
+    least as many), then ONE forward of the mask-free block, whose K, V
+    stay. Returns (tokens, forwards of block rows, blocks committed)."""
+    prompt = np.asarray(prompt, np.int32)
+    whole, at = len(prompt) // BLK * BLK, 0
+    while at < whole:
+        n = 16 if whole - at >= 16 else BLK
+        served.step(prompt[at:at + n], at)
+        at += n
+    out, forwards, blocks, ended = [], 0, 0, False
+    while len(out) < budget and not ended:
+        rest = prompt[at:at + BLK]
+        tok = np.concatenate([rest, np.zeros(BLK - len(rest), np.int32)])
+        masked = np.arange(BLK) >= len(rest)
+        while masked.any():
+            logits = served.step(np.where(masked, MASK_ID, tok), at)
+            forwards += 1
+            x0 = logits.argmax(-1)
+            z = logits.astype(np.float64)
+            logc = z[np.arange(BLK), x0] - np.log(np.exp(
+                z - z.max(-1, keepdims=True)).sum(-1)) - z.max(-1)
+            cand = sorted(np.flatnonzero(masked), key=lambda j: (-logc[j], j))
+            pick = cand[:per_step]
+            if threshold is not None:
+                passing = [j for j in cand if logc[j] > np.log(threshold)]
+                if len(passing) >= per_step:
+                    pick = passing
+            tok[pick], masked[pick] = x0[pick], False
+        served.step(tok, at)                     # the commit's own forward
+        forwards += 1
+        blocks += 1
+        for j in range(BLK):
+            if at + j < len(prompt) or len(out) >= budget or ended:
+                continue
+            out.append(int(tok[j]))
+            ended = tok[j] == eos
+        at += BLK
+    return out, forwards, blocks
+
+
+#: (prompt lengths, budget, schedule): what the fused step must not move
+FUSED_CASES = {
+    "prompt_ends_inside_a_block": ([18, 7], 12, {}),
+    "prompt_ends_on_an_edge": ([16, 8], 12, {}),
+    "budget_ends_inside_a_block": ([16, 21], 10, {}),
+    "two_positions_a_step": ([17, 12], 11, dict(steps=2)),
+    "dynamic_unmasks_more_than_a_step_must": (
+        [16, 9], 12, dict(strategy="low_confidence_dynamic", threshold=0.02)),
+}
+
+
+@pytest.mark.parametrize("case", list(FUSED_CASES))
+def test_fused_tokens_equal_a_plain_walk(params, engine, walker, reference,
+                                         mirrored, case):
+    """Token for token, and the forwards counted: ceil(m / per step) a block
+    of m masked positions and ONE more a request, where the walk takes one
+    more a block."""
+    lengths, budget, kw = FUSED_CASES[case]
+    eng = engine_of(params, **kw) if kw else engine
+    cfg = eng.model.cfg
+    prompts = prompts_of(lengths, seed=len(case))
+    outs = eng.generate(prompts, max_new_tokens=budget)
+    forwards = blocks = 0
+    for prompt, out in zip(prompts, outs):
+        want, f, b = plain_walk(
+            walker, prompt, budget, per_step=cfg.unmask_per_step,
+            threshold=cfg.confidence_threshold
+            if cfg.remasking_strategy == "low_confidence_dynamic" else None)
+        assert list(out) == want
+        assert want == reference.generate(params, prompt, budget,
+                                          config_of(**kw))
+        forwards, blocks = forwards + f, blocks + b
+    c = eng.telemetry.counters
+    assert c["bd_blocks_committed"] == blocks
+    assert c["bd_commit_forwards"] == len(prompts)
+    assert c["bd_fused_forwards"] == blocks - len(prompts)
+    # the walk's forwards less the commits that rode a denoising step
+    assert c["target_forwards"] == forwards - c["bd_fused_forwards"]
+    if not kw:
+        assert c["target_forwards"] == len(prompts) + sum(
+            BLK * -(-(n % BLK + budget) // BLK) - n % BLK for n in lengths)
+    if case.startswith("dynamic"):
+        # some step unmasked more than L / S
+        assert c["bd_positions_unmasked"] > c["bd_denoise_forwards"]
+    assert mirrored
+
+
+def test_an_eos_in_a_committed_block_ends_the_row_unfused(
+        params, engine, walker, mirrored):
+    """The block that holds the EOS is the row's last: it commits alone,
+    no block behind it is denoised, and the masked positions the forwards
+    computed are the walk's own."""
+    prompt = prompts_of([13], seed=4)[0]
+    free, *_ = plain_walk(walker, prompt, 16)
+    eos = free[4]           # the second position of the second block
+    cut = free.index(eos) + 1
+    assert cut < 16
+    out, = engine.generate([prompt], max_new_tokens=16, eos_token_id=eos)
+    want, forwards, blocks = plain_walk(walker, prompt, 16, eos=eos)
+    assert list(out) == want == free[:cut]
+    c = engine.telemetry.counters
+    assert c["bd_blocks_committed"] == blocks == -(-(1 + cut) // BLK)
+    assert c["bd_commit_forwards"] == 1
+    assert c["bd_fused_forwards"] == blocks - 1
+    assert c["target_forwards"] == forwards - (blocks - 1)
+    # a block's m masked positions are computed m, m - 1 .. 1 times at one
+    # a step: nothing behind the last block
+    assert c["bd_masked_positions_computed"] == 3 * 4 // 2 + (blocks - 1) * 10
+    assert c["eos_events"] == 1
+
+
+def test_a_fused_step_rides_a_wide_frame(params, engine, walker, mirrored,
+                                         monkeypatch):
+    """A row past its prompt beside a row that prefills: its fused step
+    takes 2 L positions of the chunk's width, and both rows' tokens are the
+    plain walk's."""
+    from deepspeed_tpu.inference.v2.telemetry import ServingTelemetry
+    frames, real = [], ServingTelemetry.on_frame
+
+    def on_frame(self, *, delta, width, **kw):
+        frames.append((width, int(delta[-1])))
+        return real(self, delta=delta, width=width, **kw)
+
+    monkeypatch.setattr(ServingTelemetry, "on_frame", on_frame)
+    first, second = prompts_of([18, 40], seed=12)
+    done = dict(engine.serve(iter([[(0, first, 14)], [], [(1, second, 6)]])))
+    assert list(done[0]) == plain_walk(walker, first, 14)[0]
+    assert list(done[1]) == plain_walk(walker, second, 6)[0]
+    assert any(width == WIDTH and fused for width, fused in frames), frames
+    assert any(width == 2 * BLK and fused for width, fused in frames), frames
+    assert {width for width, _ in frames} == {WIDTH, 2 * BLK}
+
+
+def test_rows_past_the_watermark_are_never_read(params, reference):
+    """What a denoising step and a fused step's second half write lies at
+    and past the watermark: with every such row of both pools overwritten
+    between steps (frames of one step), the tokens stand."""
+    eng = InferenceEngineV2(
+        tiny_sdar(), RaggedInferenceEngineConfig(**dict(SHAPE, frame_steps=1)),
+        params=params, max_seq_len=SEQ)
+    prompts = prompts_of([18, 16, 5], seed=13)
+    want = [list(o) for o in eng.generate(prompts, max_new_tokens=10)]
+    assert want == [reference.generate(params, p, 10, config_of())
+                    for p in prompts]
+    real, spoiled = DeviceSlotTable.absorb, []
+
+    def absorb(self, toks, emit, width, n_steps=None):
+        out = real(self, toks, emit, width, n_steps)
+        tables = np.asarray(self.tables)
+        at = np.arange(tables.shape[1] * PAGE)
+        rows = [i for i in range(self.n_slots) if self.uid_of_slot[i] >= 0]
+        for i in rows:
+            past = at[at >= self.cached_h[i]]
+            page, slot = tables[i, past // PAGE], past % PAGE
+            # (a page past the row's last is page 0, which nobody reads)
+            eng.kv.k = eng.kv.k.at[:, :, page, slot].set(1e4)
+            eng.kv.v = eng.kv.v.at[:, :, page, slot].set(-1e4)
+            spoiled.append(int((page > 0).sum()))
+        return out
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(DeviceSlotTable, "absorb", absorb)
+        got = [list(o) for o in eng.generate(prompts, max_new_tokens=10)]
+    assert got == want
+    assert sum(spoiled) > 100 and eng.telemetry.counters["bd_fused_forwards"]
+
+
+@pytest.mark.parametrize("steps", [4, 2, 1])
+def test_host_mirror_equals_a_walk_of_the_devices_plan(steps):
+    """``_block_scan_body`` itself, a step at a time over logits drawn at
+    random (a stub for the forward), against ``DeviceSlotTable``'s replay
+    of its emissions: watermark, tokens produced and done flags equal the
+    carry's after every step, at both widths; ``_block_steps_left`` names
+    the step at which a row past its prompt emits its last token (the
+    latest it can be where an EOS may cut it short); forwards a row:
+    ceil(m / per step) a block and one more."""
+    from deepspeed_tpu.inference.v2.telemetry import (BLOCK_STAT_NAMES,
+                                                      STAT_NAMES, n_stats)
+    cfg = tiny_sdar(steps=steps).cfg
+    per, n, vocab = cfg.unmask_per_step, 8, 12
+    rng = np.random.default_rng(steps)
+    plens = rng.integers(1, 40, n)
+    limits = rng.integers(1, 26, n)
+    eos = np.where(np.arange(n) % 2, -1, 3)      # every other row has none
+    prompts = rng.integers(4, vocab, (n, 64)).astype(np.int32)
+    slots = DeviceSlotTable(n, 64, 4, jax.random.PRNGKey(0),
+                            block=(BLK, per))
+    slots.uid_of_slot[:] = np.arange(n)
+    slots.plen_h[:], slots.limit_h[:], slots.eos_h[:] = plens, limits, eos
+    slots.done_h[:] = False
+
+    def stub(key):
+        def fwd(params, ids, positions, tables, w, kpool, vpool, **kw):
+            del params, ids, tables, w
+            assert kw["head_at"].shape == (n,)
+            # a fused row's logits are read behind its 2 L live positions'
+            # first half, any other row's at its chunk's start
+            live = jnp.sum(positions >= 0, axis=1)
+            assert positions.shape[1] >= 2 * BLK
+            ok = jnp.where(kw["head_at"] > 0, live == 2 * BLK, True)
+            logits = jax.random.normal(key, (n, BLK, vocab))
+            return jnp.where(ok[:, None, None], logits, jnp.nan), kpool, \
+                vpool, None
+        return fwd
+
+    def step(width):
+        def run(key, carry):
+            body = model_runner._block_scan_body(
+                stub(key), None, jnp.asarray(prompts),
+                jnp.asarray(plens, jnp.int32), jnp.asarray(limits, jnp.int32),
+                jnp.asarray(eos, jnp.int32), jnp.zeros((n,)), None, width,
+                True, cfg)
+            return body(carry, None)
+        return jax.jit(run)
+
+    steppers = {w: step(w) for w in (16, 2 * BLK)}
+    zeros, pool = jnp.zeros((n,), jnp.int32), jnp.zeros((1,))
+    carry = (zeros, zeros, zeros, jnp.zeros((n,), bool),
+             jnp.zeros((n,), bool), jnp.zeros((n,), bool),
+             jnp.zeros((n_stats(False, block=True),), jnp.int32),
+             jax.random.PRNGKey(1), pool, pool,
+             (jnp.zeros((n, BLK), jnp.int32), jnp.ones((n, BLK), bool)))
+    due, forwards, widths = {}, np.zeros(n, int), set()
+    for t in range(400):
+        if slots.done_h.all():
+            break
+        width = 16 if slots.prefill_steps_left(16) else 2 * BLK
+        widths.add(width)
+        past = ~slots.done_h & (slots.cached_h >= slots.prefill_end_h)
+        for i in np.flatnonzero(past):
+            due.setdefault(i, t + slots._block_steps_left(i))
+            # the closed form counts down a step at a time
+            assert eos[i] >= 0 or due[i] == t + slots._block_steps_left(i)
+        forwards += past
+        carry, (toks, emit) = steppers[width](jax.random.PRNGKey(100 + t),
+                                              carry)
+        _, finished = slots.absorb(np.asarray(toks)[None],
+                                   np.asarray(emit)[None], width)
+        assert (np.asarray(carry[0]) == slots.cached_h).all()
+        assert (np.asarray(carry[1]) == slots.produced_h).all()
+        # (the carry's flag latches an EOS; a spent budget freezes by count)
+        assert ((np.asarray(carry[3]) | (slots.produced_h >= limits))
+                == slots.done_h).all()
+        assert not np.asarray(carry[5]).any()       # no NaN was read
+        for i in finished:
+            if i in due:
+                # the step that emitted the last token is t: ``due`` counted
+                # the steps up to and including it
+                assert due[i] == t + 1 if eos[i] < 0 else due[i] >= t + 1
+                del due[i]
+    assert slots.done_h.all() and widths == {16, 2 * BLK}
+    lanes = np.asarray(carry[6])
+    stats = dict(zip(STAT_NAMES, lanes),
+                 **dict(zip(BLOCK_STAT_NAMES, lanes[-len(BLOCK_STAT_NAMES):])))
+    assert stats["target_forwards"] == forwards.sum() \
+        == stats["bd_denoise_forwards"] + stats["bd_commit_forwards"]
+    assert stats["bd_blocks_committed"] == \
+        stats["bd_fused_forwards"] + stats["bd_commit_forwards"]
+    assert stats["bd_commit_forwards"] == n
+    for i in np.flatnonzero(eos < 0):
+        first = BLK - plens[i] % BLK
+        further = -(-max(0, limits[i] - first) // BLK)
+        assert forwards[i] == -(-first // per) + further * (BLK // per) + 1
+    assert (slots.produced_h[eos < 0] == limits[eos < 0]).all()
+
+
+# ---------------------------------------------------------------------------
 # the host mirror's planning, the mask in the kernel, the refusals
 # ---------------------------------------------------------------------------
 
 
 def test_steps_to_first_finish_counts_blocks():
-    """The narrow frame's plan: blocks still to commit at S + 1 forwards (a
-    first block's remainder fewer), less the denoising steps already run."""
+    """The narrow frame's plan: blocks still to commit at S forwards (a
+    first block's remainder fewer) and the last one's commit, less the
+    denoising steps already run."""
     slots = DeviceSlotTable(2, 16, 4, jax.random.PRNGKey(0), block=(BLK, 1))
     slots.uid_of_slot[:] = [5, 6]
     slots.plen_h[:] = [18, 16]
     slots.cached_h[:] = [16, 16]
     slots.limit_h[:] = [10, 4]
     assert list(slots.prefill_end_h) == [16, 16]
-    # row 0: 2 of its first block, then two whole ones: 3 + 5 + 5
-    assert slots._block_steps_left(0) == 13
-    # row 1: one whole block
+    # row 0: 2 of its first block, then two whole ones: 2 + 4 + 4, and the
+    # last one's commit
+    assert slots._block_steps_left(0) == 11
+    # row 1: one whole block and its commit
     assert slots._block_steps_left(1) == 5
     slots.denoised_h[1] = 3
     assert slots.steps_to_first_finish() == 2
@@ -447,6 +743,7 @@ def test_block_steps_left_equals_a_walk_of_the_blocks(unmask):
             steps += slots._block_cost(start, plen)
             want -= start + BLK - max(start, plen)
             start += BLK
+            steps += want <= 0          # the last block's commit alone
         assert slots._block_steps_left(0) == steps, (plen, cached, run)
 
 
