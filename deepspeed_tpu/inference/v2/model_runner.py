@@ -29,6 +29,7 @@ from jax.sharding import PartitionSpec as P
 from ...models import layers as L
 from ...models.transformer import CausalLM
 from ...ops.attention import decode_attention
+from ...ops.pallas import gated_delta_rule
 from ..sampling import (block_unmask, sample_logits_per_row,
                         speculative_verify_per_row)
 from .kv_cache import dequantize_kv_lanes, quantize_kv_lanes
@@ -320,7 +321,7 @@ class PagedModelRunner:
         with jax.named_scope("frame_plan"):
             pack = _pack_plan(positions, self.pack_ladder(*ids.shape))
             # a wide step's linear layers run the delta rule on their rows
-            # by what each holds (``_rule_by_rows``)
+            # by what each holds (``_rule_by_rows``, or the chip's kernel)
             rows = _row_plan(positions) \
                 if cfg.mixer_pattern is not None and ids.shape[1] > 1 else None
 
@@ -640,8 +641,11 @@ class PagedModelRunner:
             output projection and the MLP treat every position alike and
             run on the live ones; the convolution runs on the (B, C) chunk,
             a row's live positions first, and the rule on the rows by what
-            they hold (``_rule_by_rows``; a narrow step's is the recurrence
-            on every row)."""
+            they hold: on the chip a wide step's is ONE kernel over the
+            list of rows that hold anything, the states moved in place
+            (``gated_delta_rule.gdn_rule_rows``, where ``_rule_kernel_runs``);
+            elsewhere ``_rule_by_rows`` (a narrow step's is the recurrence
+            on every row, on every backend)."""
             n_live = jnp.sum(~is_pad, axis=1).astype(jnp.int32)
             with jax.named_scope("attn"):
                 def project(h):
@@ -655,21 +659,33 @@ class PagedModelRunner:
                          for n in ("conv", "A_log", "dt_bias")}
                 tail_l = jnp.moveaxis(
                     jax.lax.dynamic_index_in_dim(tail, li, 0, False), 0, 1)
-                state_l = jax.lax.dynamic_index_in_dim(state, li, 0, False)
+                # the kernel names the listed rows' states in the stack: no
+                # layer is sliced out of it or put back
+                kernel = rows is not None and _rule_kernel_runs(
+                    cfg, is_pad.shape[1])
+                state_l = None if kernel else \
+                    jax.lax.dynamic_index_in_dim(state, li, 0, False)
                 u, new_tail = L.gdn_conv(small, u, tail_l, n_live, cfg)
-
-                def rule(u, b_in, a_in, pad, state):
+                if kernel:
                     with jax.named_scope("gdn_scan"):
-                        q, k, v = L.gdn_split(u, cfg)
-                        beta, g = L.gdn_gates(small, b_in, a_in, ~pad)
-                    return L.gdn_rule(q, k, v, beta, g, state)
-                out, new_state = _rule_by_rows(rows, rule, u, b_in, a_in,
-                                               is_pad, state_l)
-                # a row that sat the step out keeps both to the bit
+                        beta, g = L.gdn_gates(small, b_in, a_in, ~is_pad)
+                        out, state = gated_delta_rule.gdn_rule_rows(
+                            u, beta, g, state, li, rows[4])
+                else:
+                    def rule(u, b_in, a_in, pad, state):
+                        with jax.named_scope("gdn_scan"):
+                            q, k, v = L.gdn_split(u, cfg)
+                            beta, g = L.gdn_gates(small, b_in, a_in, ~pad)
+                        return L.gdn_rule(q, k, v, beta, g, state)
+                    out, new_state = _rule_by_rows(rows, rule, u, b_in, a_in,
+                                                   is_pad, state_l)
+                # a row that sat the step out keeps both to the bit (the
+                # kernel touches no such row's state)
                 moved = n_live > 0
-                state = jax.lax.dynamic_update_index_in_dim(
-                    state, jnp.where(moved[:, None, None, None], new_state,
-                                     state_l), li, 0)
+                if not kernel:
+                    state = jax.lax.dynamic_update_index_in_dim(
+                        state, jnp.where(moved[:, None, None, None],
+                                         new_state, state_l), li, 0)
                 tail = jax.lax.dynamic_update_index_in_dim(
                     tail, jnp.moveaxis(jnp.where(
                         moved[:, None, None], new_tail, tail_l), 1, 0), li, 0)
@@ -1090,7 +1106,9 @@ class PagedModelRunner:
                                           latent=self.latent_layers,
                                           heads=self.row_heads,
                                           mtp=self.has_mtp,
-                                          linear=self.linear_layers)
+                                          linear=self.linear_layers,
+                                          kernel_rule=_rule_kernel_runs(
+                                              self.cfg, width))
                 carry = (cached, produced, last_tok, *hidden, done, poison,
                          nonfinite, stats, rng, kpool, vpool) \
                     + (() if recurrent is None else (tuple(recurrent),))
@@ -1376,25 +1394,46 @@ def _rule_trips(n_live):
     return (n + rows - 1) // rows, rows
 
 
-def _rule_positions(n_live, width):
+def _rule_kernel_runs(cfg, width) -> bool:
+    """Whether the delta rule of a step ``width`` wide is the repo's kernel
+    (``ops/pallas/gated_delta_rule.py``): a wide step's of a model with
+    linear layers, on the chip, as the paged kernels are chosen
+    (``_use_pallas_paged``), at the shapes the kernel takes. A narrow step
+    and every other backend keep ``_rule_by_rows``."""
+    return bool(cfg.linear_layers) and width > 1 and _use_pallas_paged() \
+        and gated_delta_rule.supported(
+            width, cfg.linear_num_key_heads, cfg.linear_num_value_heads,
+            cfg.linear_key_head_dim, cfg.linear_value_head_dim)
+
+
+def _rule_positions(n_live, width, kernel=False):
     """Positions ONE linear layer's delta rule computes in a step whose
-    rows hold ``n_live`` (B,) live positions of ``width``
-    (``_rule_by_rows``): a trip's rows x ``width`` a trip of the chunked
-    form, and every row through the recurrence."""
+    rows hold ``n_live`` (B,) live positions of ``width``. By
+    ``_rule_by_rows``: a trip's rows x ``width`` a trip of the chunked
+    form, and every row through the recurrence. By the chip's ``kernel``
+    (``_rule_kernel_runs``): one for a row that holds one, a prefilling
+    row's live blocks of ``GDN_CHUNK``, none for a row that sits out."""
     b = n_live.shape[0]
     if width == 1:
         return jnp.asarray(b, jnp.int32)
+    if kernel:
+        blocks = -(-n_live // L.GDN_CHUNK) * L.GDN_CHUNK
+        return jnp.sum(jnp.where(n_live > 1, blocks, n_live)).astype(
+            jnp.int32)
     trips, rows = _rule_trips(n_live)
     return trips * rows * width + b
 
 
 def _row_plan(positions):
-    """Which rows of a wide chunk hold more than one live position
-    (``positions >= 0``), packed in slot order: (trips of the chunked
-    form's loop, rows a trip, src the row of each packed row, B past their
-    count and one entry for every row a trip may take, rider (B,) the rows
-    that hold exactly one). As ``_pack_plan`` does for tokens: a cumulative
-    sum and its inverse, no sort."""
+    """Which rows of a wide chunk hold what (``positions >= 0`` live). For
+    ``_rule_by_rows``, the rows that hold more than one live position,
+    packed in slot order: trips of the chunked form's loop, rows a trip,
+    src the row of each packed row, B past their count and one entry for
+    every row a trip may take, rider (B,) the rows that hold exactly one.
+    As ``_pack_plan`` does for tokens: a cumulative sum and its inverse, no
+    sort. For the chip's kernel, last, the list of every row that holds
+    one or more, with its count (``gated_delta_rule.row_list``). What a
+    program does not use, its compiler drops."""
     n_live = jnp.sum((positions >= 0).astype(jnp.int32), axis=1)
     chunked = n_live > 1
     trips, rows = _rule_trips(n_live)
@@ -1402,7 +1441,7 @@ def _row_plan(positions):
         jnp.cumsum(chunked.astype(jnp.int32)),
         jnp.arange(chunked.size + rows - 1, dtype=jnp.int32),
         side="right", method="compare_all")
-    return trips, rows, src, n_live == 1
+    return trips, rows, src, n_live == 1, gated_delta_rule.row_list(n_live)
 
 
 def _rule_by_rows(plan, rule, u, b_in, a_in, pad, state):
@@ -1410,7 +1449,9 @@ def _rule_by_rows(plan, rule, u, b_in, a_in, pad, state):
     a_in, pad, state) -> (out, new state)`` over rows (B, C, ...) and
     their states (B, H, dk, dv), computed for each row by what it holds
     (``plan``, ``_row_plan``; None: a narrow step, ``rule`` itself, the
-    recurrence on every row):
+    recurrence on every row). The path of every backend but the chip, of
+    the chip at the shapes its kernel does not take (``_rule_kernel_runs``),
+    and the kernel's oracle (``tests/test_gated_delta_rule_kernel.py``):
 
     * one live position (a decoding row riding the step, a prompt's last
       token): the recurrence on position 0, run on all B rows at once (what
@@ -1427,7 +1468,7 @@ def _rule_by_rows(plan, rule, u, b_in, a_in, pad, state):
     behind a rider's one position, and the new states."""
     if plan is None:
         return rule(u, b_in, a_in, pad, state)
-    trips, take, src, rider = plan
+    trips, take, src, rider = plan[:4]
     xs = (u, b_in, a_in, pad)
     with jax.named_scope("gdn_scan"):
         out1, state1 = rule(*(x[:, :1] for x in xs), state)
@@ -1453,7 +1494,7 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                        temps, tables, width, greedy, draft=None,
                        repair=False, window=None, ladder=pack_ladder,
                        layers=None, latent=None, heads=None, mtp=False,
-                       linear=0):
+                       linear=0, kernel_rule=False):
     """The scan-step of ``frame_loop`` and ``frame_loop_spec``: the in-graph
     SplitFuse scheduling arithmetic lives in exactly one place.
 
@@ -1513,7 +1554,9 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
     linear layers; their (state, tail) pair is the carry's last field, the
     forward takes and returns it, and the step counts their work
     (``RECURRENT_STAT_NAMES``): live positions and live rows, each x the
-    linear layers. Such a model is served without a draft and without
+    linear layers, and the positions their rule computes, by
+    ``_rule_by_rows`` or by the chip's kernel (``kernel_rule``:
+    ``_rule_kernel_runs``). Such a model is served without a draft and without
     ``repair``, which would have to roll the state back
     (``archs.validate_recurrent_serving``)."""
     self_draft = draft == "self"
@@ -1609,7 +1652,8 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                 if mtp else None,
                 recurrent_work=linear * jnp.stack(
                     [jnp.sum(w), jnp.sum(w > 0),
-                     _rule_positions(w, width)]).astype(jnp.int32)
+                     _rule_positions(w, width, kernel_rule)]).astype(
+                         jnp.int32)
                 if linear else None)
         carry = (cached + w, produced + emit.astype(jnp.int32), last_tok,
                  *hidden, done, poison, nonfinite, stats, rng, kpool, vpool,

@@ -185,10 +185,15 @@ MTP_STAT_NAMES = ("mtp_latent_positions_read", "mtp_expert_rows",
 #: its linear layers moved their states by (live positions x linear layers)
 #: and states they read and wrote (live rows x linear layers, a step). Its
 #: full-attention layers count their reads in LAYER_STAT_NAMES. Last, the
-#: positions the delta rule COMPUTED for them: a wide step runs its chunked
-#: form on the rows that hold more than one live position, two a trip of a
-#: loop (trips x 2 x width), and the one-position recurrence on every row;
-#: a narrow step the recurrence alone (``model_runner._rule_by_rows``)
+#: positions the delta rule COMPUTED for them (``model_runner
+#: ._rule_positions``). On the chip a wide step's rule is one kernel over
+#: the rows that hold anything (``ops/pallas/gated_delta_rule.py``): one
+#: position for a row that holds one, a prefilling row's live blocks of 64,
+#: none for a row that sits out. Elsewhere (``model_runner._rule_by_rows``)
+#: a wide step runs the chunked form on the rows that hold more than one
+#: live position, two a trip of a loop (trips x 2 x width), and the
+#: one-position recurrence on every row. A narrow step is the recurrence on
+#: every row, on every backend
 RECURRENT_STAT_NAMES = ("gdn_positions", "gdn_state_rw",
                         "gdn_positions_computed")
 

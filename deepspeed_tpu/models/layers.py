@@ -436,9 +436,19 @@ def gdn_conv(params, u, tail, n, cfg: TransformerConfig):
     taps = params["conv"].astype(jnp.float32)
     y = sum(ext[:, j:j + c].astype(jnp.float32) * taps[j][None, None]
             for j in range(k))
-    # input t sits at ext[t + K - 1]: the live ones' last K - 1 start at n
+    # input t sits at ext[t + K - 1]: the live ones' last K - 1 start at n.
+    # They are read where they lie, the chunk's as rows of (B C, channels)
+    # and the old tail's, not out of ``ext``: a gather along the positions
+    # of a (B, K - 1 + C, channels) operand made the chip's compiler lay the
+    # whole chunk out positions-first for it (PERF.md, PR 49)
     idx = n[:, None] + jnp.arange(k - 1)[None, :]              # (B, K-1)
-    new_tail = jnp.take_along_axis(ext, idx[:, :, None], axis=1)
+    rows = jnp.arange(u.shape[0])[:, None] * c + jnp.clip(idx - (k - 1), 0,
+                                                         c - 1)
+    new_tail = jnp.where(
+        (idx >= k - 1)[:, :, None],
+        u.reshape(-1, u.shape[2])[rows],
+        jnp.take_along_axis(tail.astype(u.dtype),
+                            jnp.minimum(idx, k - 2)[:, :, None], axis=1))
     return jax.nn.silu(y).astype(u.dtype), new_tail.astype(tail.dtype)
 
 

@@ -884,6 +884,91 @@ def test_glm_frame_programs_fit_the_chip(one_chip, as_tpu, width):
     assert total < 15.75e9, total
 
 
+def test_gated_delta_rule_compiles_at_the_cells_shapes(one_chip, as_tpu):
+    """The delta rule's kernel of a wide step (``gated_delta_rule.py``) at
+    Qwen3-Next's shape in the benchmark's cell: 16 rows x 128 positions, 16
+    key / 32 value heads of 128 x 128 float32 states, the 6 linear layers'
+    states in one stack, bf16 activations. One Mosaic call named for the
+    chunk (its products' precision: ``test_gated_delta_rule_kernel.py``) and
+    NOTHING beside its operands: the states are moved in place (aliased, no
+    temporary), the row list and the gates' relayout are kilobytes."""
+    import re
+    from deepspeed_tpu.ops.pallas import gated_delta_rule as gdr
+    b, c, hk, hv, d, layers = 16, 128, 16, 32, 128, 6
+    assert gdr.supported(c, hk, hv, d, d)
+    assert gdr.pairs_a_step(hk, hv, d, d) == 4      # 64 grid steps a layer
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(u, beta, g, state, layer, n_live):
+        return gdr.gdn_rule_rows(u, beta, g, state, layer,
+                                 gdr.row_list(n_live))
+
+    state = sds((layers, b, hv, d, d), jnp.float32)
+    gate = sds((b, c, hv), jnp.float32)
+    lowered = jax.jit(step, donate_argnums=(3,)).lower(
+        sds((b, c, 2 * hk * d + hv * d), jnp.bfloat16), gate, gate, state,
+        sds((), jnp.int32), sds((b,), jnp.int32))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%gdn_rule_c128\S* = ", text)) == 1
+    assert "tpu_custom_call" in text
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == layers * b * hv * d * d * 4
+    assert m.temp_size_in_bytes < 1 << 20, m.temp_size_in_bytes
+
+
+# sha256 of the traced frame programs (``.trace(...).jaxpr``: the chip's
+# kernels are in, no source locations) of two configurations WITHOUT linear
+# layers at small presets, 4 slots x 2 steps, pages of 8, as the parent of
+# PR 49 traced them (`git archive 897d6f5`): the delta rule's kernel and
+# its row list are traced for a model with linear layers alone
+NO_LINEAR_LAYERS_JAXPRS = {
+    ("mistral", 1):
+        "43857c5ebb989a535af62e12c7e65cbcf339846e75a65125498dc496b52cd23f",
+    ("mistral", 16):
+        "6b658872dfc0a68520d289bce6e9f471790565eb7974162be5567b7265ec9d68",
+    ("olmoe", 1):
+        "7be839670cdd67660d345e8d61c0c98157515186844121ae01a7556bf187fb10",
+    ("olmoe", 16):
+        "6856f45ee69612a7fb6226fb7384eb2e89cb645532383ccabcf4c038eefe5f52",
+}
+NO_LINEAR_LAYERS = {
+    "mistral": ("mistral-7b", dict(num_kv_heads=2, intermediate_size=128,
+                                   sliding_window=64)),
+    "olmoe": ("olmoe-1b-7b", dict(num_kv_heads=4, intermediate_size=32,
+                                  num_experts=8, num_experts_per_tok=2)),
+}
+
+
+@pytest.mark.parametrize("family,width", list(NO_LINEAR_LAYERS_JAXPRS),
+                         ids=[f"{f}-w{w}" for f, w in NO_LINEAR_LAYERS_JAXPRS])
+def test_frame_programs_without_linear_layers_are_the_parents(as_tpu, family,
+                                                              width):
+    import hashlib
+    from deepspeed_tpu.inference.v2.model_runner import PagedModelRunner
+    from deepspeed_tpu.models import build_model, get_config
+    preset, kw = NO_LINEAR_LAYERS[family]
+    cfg = get_config(preset, vocab_size=256, hidden_size=64, max_seq_len=256,
+                     dtype="float32", num_layers=2, num_heads=4, **kw)
+    model = build_model(cfg)
+    runner = PagedModelRunner(model, 8, 32)
+    slots, steps, i32, sds = 4, 2, jnp.int32, jax.ShapeDtypeStruct
+    row, flag = sds((slots,), i32), sds((slots,), jnp.bool_)
+    key = jax.random.PRNGKey(0)
+    pool = sds((cfg.num_layers, cfg.kv_heads, 33, 8, cfg.dims_per_head),
+               jnp.float32)
+    jaxpr = runner._build_frame_loop().trace(
+        model.abstract_params(), sds((slots, 256), i32), row, row, row,
+        sds((slots,), jnp.float32), sds((slots, 32), i32), row, row, row,
+        flag, flag, flag, sds((runner.n_stats,), i32),
+        sds(key.shape, key.dtype), pool, pool, width=width, steps=steps,
+        greedy=True, n_steps=sds((), i32)).jaxpr
+    assert hashlib.sha256(str(jaxpr).encode()).hexdigest() == \
+        NO_LINEAR_LAYERS_JAXPRS[family, width]
+
+
 QWEN3_NEXT_CUT = dict(num_layers=8, num_experts=128, moe_router_experts=512)
 
 
@@ -902,7 +987,9 @@ def test_qwen3_next_frame_programs_fit_the_chip(one_chip, as_tpu, width):
     grouped-product kernel three times a layer and rung, NO value shaped
     like a pool or like the states that XLA copied (the state rides the
     scan's carry and is updated in place, a layer's part at a time: 201 MB
-    that a copy a layer would move 48 times a frame), no buffer shaped like a layer's held experts, and arguments and
+    that a copy a layer would move 48 times a frame; in the wide program by
+    the delta rule's kernel, once a linear layer, which names the layer and
+    the rows it moves and slices nothing out), no buffer shaped like a layer's held experts, and arguments and
     temporaries under 15.75 GB."""
     import re
     from deepspeed_tpu.inference.v2.model_runner import PagedModelRunner
@@ -941,6 +1028,9 @@ def test_qwen3_next_frame_programs_fit_the_chip(one_chip, as_tpu, width):
     assert len(re.findall(r"%paged_attn_\S* = ", text)) == 1
     assert len(re.findall(r"%kv_commit_\S* = ", text)) == 1
     assert len(re.findall(r"%grouped_mm_m128\S* = ", text)) == 4 * 3 * rungs
+    # a wide step's delta rule: the kernel, once a linear layer of a period
+    assert len(re.findall(r"%gdn_rule_c128\S* = ", text)) == \
+        (3 if width > 1 else 0)
     assert "ragged-dot" not in text
     for kind, value in (("bf16", pool), ("f32", state)):
         # the state's update in place is a fusion XLA names for it
@@ -962,10 +1052,11 @@ def test_qwen3_next_frame_programs_fit_the_chip(one_chip, as_tpu, width):
     assert 9.4e9 < m.argument_size_in_bytes < 9.8e9
     assert total < 15.75e9, total
     # the narrow program holds what it held before a wide step's rows were
-    # told apart (0.082 GB); the wide one, whose chunked delta rule takes
-    # two gathered rows a trip of one loop, 0.389 GB where every row
-    # through it at once held 0.536
-    assert m.temp_size_in_bytes < (0.083e9 if width == 1 else 0.45e9)
+    # told apart (0.082 GB); the wide one, whose delta rule is the kernel
+    # (the states in place, its output and the gates by block its only
+    # temporaries), 0.366 GB where two gathered rows a trip of XLA's
+    # chunked form held 0.389 and every row through it at once 0.536
+    assert m.temp_size_in_bytes < (0.083e9 if width == 1 else 0.40e9)
 
 
 def test_chip_smoke_fails_without_a_chip():

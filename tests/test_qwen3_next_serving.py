@@ -367,6 +367,25 @@ def test_a_wide_steps_rule_runs_the_rows_by_what_they_hold(whole, n_live,
             assert float(jnp.abs(state[row] - state0[row]).max()) > 1e-3
 
 
+@pytest.mark.parametrize("n_live,trips", ROW_MIXES)
+def test_the_kernels_rule_computes_live_blocks(n_live, trips):
+    """What the counter ``gdn_positions_computed`` reads where the chip's
+    kernel runs the rule (``_rule_positions(kernel=True)``): one position
+    for a rider, a prefilling row's live blocks of 64 (a second block none
+    of whose positions is live is not computed), nothing for a row that
+    sits out; never more than ``_rule_by_rows`` computes, never fewer than
+    the live positions. A narrow step is the recurrence on every row on
+    either path."""
+    n = jnp.asarray(n_live, jnp.int32)
+    want = sum(1 if x == 1 else -(-x // 64) * 64 for x in n_live)
+    got = int(model_runner._rule_positions(n, WIDTH, kernel=True))
+    assert got == want
+    assert sum(n_live) <= got <= int(model_runner._rule_positions(n, WIDTH))
+    assert int(model_runner._rule_positions(n, WIDTH)) == \
+        trips * 2 * WIDTH + SLOTS
+    assert int(model_runner._rule_positions(n, 1, kernel=True)) == SLOTS
+
+
 # ---- the served path against the reference ---------------------------------
 
 
