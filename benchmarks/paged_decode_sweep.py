@@ -12,7 +12,9 @@ OLMoE-1B-7B: 16 / 16, no window, table width 32; Mellum2-12B-A2.5B: 32 / 4,
 its two global layers over a table of 256 and its six windowed ones over a
 ring of 10 pages behind a window of 1,024; LongCat-Flash-Omni: 64 query
 heads on the ONE latent head of a pool of 640-lane rows, values the first
-512 lanes, scale 1 / sqrt(192), table width 128; 16 slots, pages of 128,
+512 lanes, scale 1 / sqrt(192), table width 128; LFM2-24B-A2B: 32 / 8 heads
+of 64 lanes as 4 rows of two heads, table width 32, beside the same bytes
+as 4 heads of 128 lanes; 16 slots, pages of 128,
 head_dim 128, bf16) over live slots 1 / 3 / 16 and contexts 256 / 1,024 /
 4,096 / 7,168 (Mellum2: to 30,000): microseconds a layer, the pages a layer had to move
 (``live x ceil((cs - lo) / page)``, K and V of every KV head), their
@@ -45,7 +47,15 @@ CONFIGS = {
     "mellum2-full": (32, 4, 0, 256, None, 4097),
     "mellum2-window": (32, 4, 1024, 256, 10, 161),
     "longcat-latent": (64, 1, 0, 128, None, 2049),
+    "lfm2-pairs": (32, 4, 0, 32, None, 513),
+    "lfm2-x4": (32, 4, 0, 32, None, 513),
 }
+# heads of 64 lanes, two a 128-lane row of a page (``kv_cache.heads_per_row``;
+# Mosaic refuses a page copy 64 lanes wide): the kernel sees 4 heads of 128
+# lanes and queries whose other 64 lanes are zeros, as ``_forward`` hands
+# them. "lfm2-x4" is the same bytes under dense queries, what 4 KV heads of
+# 128 lanes cost
+PAIRED = {"lfm2-pairs": 64}
 # the latent format: (a row's lanes, its leading lanes that are the value,
 # the scores' scale); one pool, no second
 LATENT = {"longcat-latent": (640, 512, 192 ** -0.5)}
@@ -118,12 +128,16 @@ def main():
                           precision="highest").reshape(-1, h, v.shape[-1])
 
     def bench(pool, h, window, mb, live, ctx, ring, dv=None, scale=None,
-              riders=0):
+              riders=0, paired=0):
         kvh, pool_pages, D = pool.shape[1], pool.shape[2], pool.shape[-1]
         vpool = None if dv else pool
         rng = np.random.default_rng(live * 10007 + ctx)
-        q = jnp.asarray(rng.standard_normal((SLOTS, chunk, h, D)) * 0.1,
-                        jnp.bfloat16)
+        q = rng.standard_normal((SLOTS, chunk, h, D)) * 0.1
+        if paired:
+            # query head g of a row's 2 x (h / 2 kvh): its own head's lanes
+            own = (np.arange(h) // (h // (2 * kvh))) % 2
+            q = q * (np.arange(D)[None, :] // paired == own[:, None])
+        q = jnp.asarray(q, jnp.bfloat16)
         ck = jnp.asarray(rng.standard_normal((SLOTS, chunk, kvh, D)),
                          jnp.bfloat16)
         # a ring holds min(pages, ring) pages whatever the context
@@ -205,7 +219,7 @@ def main():
                            "chunk": chunk, "live": live, "riders": riders,
                            "context": ctx, "device": dev.device_kind,
                            **bench(pool, h, window, mb, live, ctx, ring, dv,
-                                   scale, riders)}
+                                   scale, riders, PAIRED.get(name, 0))}
                     print(json.dumps(row), flush=True)
 
 

@@ -30,8 +30,9 @@ from ..config import DeepSpeedInferenceConfig
 from ..sampling import sample_logits
 from .faults import (FaultReason, FrameDispatchError, LedgerEntry,
                      snapshot_ledger)
-from .kv_cache import BlockedKVCache, LayeredKVCache, cache_kinds
-from .model_runner import PagedModelRunner
+from .kv_cache import (BlockedKVCache, LayeredKVCache, cache_kinds,
+                       heads_per_row)
+from .model_runner import PagedModelRunner, _use_pallas_paged
 from .ragged_manager import DeviceSlotTable, DSStateManager
 from .scheduler import FifoPolicy
 from .telemetry import ServingTelemetry, check_stat_range
@@ -330,8 +331,9 @@ class InferenceEngineV2:
                                      num_blocks=num_blocks, block_size=bs,
                                      dtype=cfg.act_dtype, latent=True)
         elif kinds is None:
-            if cfg.linear_layers:
-                # linear layers cache no keys: the pool is the full layers'
+            if cfg.recurrent_kinds:
+                # linear and conv layers cache no keys: the pool is the
+                # full layers'
                 from .model_implementations.archs import \
                     validate_recurrent_serving
                 validate_recurrent_serving(c, cfg,
@@ -343,9 +345,16 @@ class InferenceEngineV2:
                     validate_block_diffusion_serving
                 validate_block_diffusion_serving(
                     c, cfg, draft=draft_model is not None)
-            self.kv = BlockedKVCache(cfg.cache_layers if cfg.linear_layers
-                                     else cfg.num_layers, cfg.kv_heads,
-                                     cfg.dims_per_head,
+            # where the chip's kernels read and write the pages in place,
+            # heads narrower than a 128-lane row share one (kv_cache.
+            # heads_per_row); the forward reads that off the pool's shape
+            in_row = heads_per_row(cfg.kv_heads, cfg.dims_per_head) \
+                if _use_pallas_paged() and c.kv_dtype != "int8" \
+                and c.tp == 1 and cfg.position != "alibi" else 1
+            self.kv = BlockedKVCache(cfg.cache_layers if cfg.recurrent_kinds
+                                     else cfg.num_layers,
+                                     cfg.kv_heads // in_row,
+                                     cfg.dims_per_head * in_row,
                                      num_blocks=num_blocks, block_size=bs,
                                      dtype=cfg.act_dtype, kv_dtype=c.kv_dtype)
         else:
@@ -580,11 +589,12 @@ class InferenceEngineV2:
                 f"{what}: this model keeps one pool of latent rows "
                 "(kv_cache.BlockedKVCache(latent=True)); it is served "
                 "without a draft, a swap tier or a prefix cache")
-        if self.model.cfg.linear_layers:
+        if self.model.cfg.recurrent_kinds:
             raise NotImplementedError(
-                f"{what}: this model's linear layers keep a recurrent state "
-                "a slot, which is no page; it is served without a draft, a "
-                "swap tier or a prefix cache")
+                f"{what}: this model's "
+                f"{' and '.join(self.model.cfg.recurrent_kinds)} layers keep "
+                "a state a slot, which is no page; it is served without a "
+                "draft, a swap tier or a prefix cache")
         if self.model.cfg.block_length:
             raise NotImplementedError(
                 f"{what}: this model generates by diffusion over blocks and "
@@ -1074,8 +1084,7 @@ class InferenceEngineV2:
             rings=[ring for _, ring in self.state.rings],
             hidden=(self.model.cfg.hidden_size, self.model.cfg.act_dtype)
             if speculate and self_draft else None,
-            recurrent=self.runner.recurrent_shapes(n_slots)
-            if self.runner.linear_layers else (),
+            recurrent=self.runner.recurrent_shapes(n_slots),
             block=(self.model.cfg.block_length,
                    self.model.cfg.unmask_per_step)
             if self.runner.block_length else None)
@@ -1119,8 +1128,9 @@ class InferenceEngineV2:
                                        math.prod(shape)
                                        * jnp.dtype(dtype).itemsize
                                        for shape, dtype in
-                                       self.runner.recurrent_shapes(1))
-                                   if self.runner.linear_layers else 0,
+                                       self.runner.recurrent_shapes(1)),
+                                   recurrent_stats=self.runner
+                                   .recurrent_stat_names,
                                    block=self.runner.block_length)
         sched = FifoPolicy() if scheduler is None else scheduler
         sched.begin_serve(self)

@@ -126,6 +126,25 @@ def cache_kinds(windows, block_size: int, max_blocks: int, chunk: int):
                       window, ring))
 
 
+#: lanes of a vector register row on the chip: the minor extent a Mosaic
+#: copy of a page moves whole
+ROW_LANES = 128
+
+
+def heads_per_row(kv_heads: int, head_dim: int) -> int:
+    """KV heads that share one ``ROW_LANES``-lane row of a page where the
+    pages are read and written in place by the chip's kernels: 1 for heads
+    of 128 lanes or more, 2 for heads of 64 (``kv_heads`` even). Mosaic
+    refuses a copy whose minor extent is half a row (``tests/
+    test_chip_compile_lfm2.py``), so such a pool is laid out (layers,
+    kv_heads / p, pages, page, p x head_dim): head j p + r of a token in
+    lanes [r head_dim, (r + 1) head_dim) of row j. The same bytes a token;
+    ``model_runner._forward`` reads the packing off the pool's shape."""
+    p = ROW_LANES // head_dim \
+        if head_dim < ROW_LANES and ROW_LANES % head_dim == 0 else 1
+    return p if kv_heads % p == 0 else 1
+
+
 class BlockedKVCache:
     def __init__(self, num_layers: int, kv_heads: int, head_dim: int,
                  num_blocks: int, block_size: int = 64, dtype=jnp.bfloat16,

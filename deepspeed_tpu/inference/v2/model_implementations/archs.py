@@ -248,6 +248,98 @@ class SdarMoeContainer(LlamaContainer):
             mask_token_id=int(_get(hf_cfg, "mask_token_id", default=151669)))
 
 
+def _lfm2_layers():
+    """``Lfm2MoeContainer``'s mappings by group tag (``layer_groups``: the
+    mixer, and ".dense" behind it for a leading dense layer)."""
+    norms = {"norm1.scale": Param("model.layers.{l}.operator_norm.weight"),
+             "norm2.scale": Param("model.layers.{l}.ffn_norm.weight")}
+    ff = "model.layers.{l}.feed_forward."
+    mixers = {
+        "conv": {
+            "attn.w_in": Param("model.layers.{l}.conv.in_proj.weight",
+                               t_linear),
+            # Conv1d's (channels, 1, K): tap j meets the input K - 1 - j
+            # positions back, as ``layers.causal_conv`` reads them
+            "attn.conv": Param("model.layers.{l}.conv.conv.weight",
+                               lambda w, cfg: w[:, 0, :].T),
+            "attn.w_out": Param("model.layers.{l}.conv.out_proj.weight",
+                                t_linear)},
+        "full": {
+            "attn.wq": Param("model.layers.{l}.self_attn.q_proj.weight",
+                             t_q_heads),
+            "attn.wk": Param("model.layers.{l}.self_attn.k_proj.weight",
+                             t_kv_heads),
+            "attn.wv": Param("model.layers.{l}.self_attn.v_proj.weight",
+                             t_kv_heads),
+            "attn.wo": Param("model.layers.{l}.self_attn.out_proj.weight",
+                             t_o_heads),
+            "attn.q_norm.scale": Param(
+                "model.layers.{l}.self_attn.q_layernorm.weight"),
+            "attn.k_norm.scale": Param(
+                "model.layers.{l}.self_attn.k_layernorm.weight")}}
+    mlps = {
+        ".dense": {"mlp.wi_gate": Param(ff + "w1.weight", t_linear),
+                   "mlp.wi_up": Param(ff + "w3.weight", t_linear),
+                   "mlp.wo": Param(ff + "w2.weight", t_linear)},
+        "": {"mlp.router": Param(ff + "gate.weight", t_linear),
+             "mlp.router_bias": Param(ff + "expert_bias"),
+             "mlp.wi_gate": Param(ff + "experts.{x}.w1.weight", t_linear),
+             "mlp.wi_up": Param(ff + "experts.{x}.w3.weight", t_linear),
+             "mlp.wo": Param(ff + "experts.{x}.w2.weight", t_linear)}}
+    return {mixer + mlp: {**norms, **m, **f}
+            for mixer, m in mixers.items() for mlp, f in mlps.items()}
+
+
+class Lfm2MoeContainer(LayerContainer):
+    """LFM2-MoE (LiquidAI/LFM2-24B-A2B ``config.json``, ``model_type``
+    "lfm2_moe"): ``layer_types`` lists each layer's mixer, a gated short
+    convolution (``conv.{in_proj,conv,out_proj}``) or GQA with one RMSNorm
+    of ``head_dim`` lanes on every q and k head before RoPE
+    (``self_attn.{q,k,v,out}_proj``, ``{q,k}_layernorm``); the first
+    ``num_dense_layers`` MLPs are dense (``feed_forward.w1/w3/w2``), the
+    others routed (``feed_forward.gate``, ``expert_bias``,
+    ``experts.{x}.w1/w3/w2``): sigmoid scores, the top k of score + bias,
+    weights the scores over (their sum + 1e-6) x ``routed_scaling_factor``;
+    norms ``operator_norm`` / ``ffn_norm``, the final one
+    ``embedding_norm``; the head tied to the embedding. The native stack
+    is ONE period as long as the stack (``cfg.mixer_pattern``). Served
+    dropless, on the paged path only."""
+
+    layer_mapping_by_type = _lfm2_layers()
+    non_layer_mapping = {
+        "embed.tok": Param("model.embed_tokens.weight"),
+        "embed.lm_head": Param("lm_head.weight", t_linear, optional=True),
+        "final_norm.scale": Param("model.embedding_norm.weight"),
+    }
+
+    @classmethod
+    def config(cls, hf_cfg):
+        if _get(hf_cfg, "conv_bias", default=False):
+            raise NotImplementedError("conv_bias: the convolution and its "
+                                      "projections are mapped without bias")
+        kinds = {"conv": "conv", "full_attention": "full"}
+        rope = _get(hf_cfg, "rope_parameters", default=None) or {}
+        return _llama_family_config(
+            hf_cfg, qk_norm="head_dim", qk_norm_bias=False,
+            norm_eps=float(_get(hf_cfg, "norm_eps", default=1e-5)),
+            rope_theta=float(_get(hf_cfg, "rope_theta", default=None)
+                             or rope.get("rope_theta", 1e6)),
+            mixer_pattern=tuple(kinds[t] for t in hf_cfg.layer_types),
+            conv_kernel=int(hf_cfg.conv_L_cache),
+            moe_first_dense=int(hf_cfg.num_dense_layers),
+            moe_impl="grouped", num_experts=int(hf_cfg.num_experts),
+            num_experts_per_tok=int(hf_cfg.num_experts_per_tok),
+            moe_intermediate_size=int(hf_cfg.moe_intermediate_size),
+            moe_norm_topk=bool(_get(hf_cfg, "norm_topk_prob", default=True)),
+            moe_router_bias=bool(_get(hf_cfg, "use_expert_bias",
+                                      default=True)),
+            moe_routed_scale=float(_get(hf_cfg, "routed_scaling_factor",
+                                        default=1.0)),
+            moe_router_score="sigmoid", moe_norm_eps=1e-6,
+            tie_embeddings=bool(_get(hf_cfg, "tie_word_embeddings",
+                                     default=True)))
+
+
 def _pair(transform=t_identity):
     """A leaf stacked over a shortcut-connected layer's pair: the two
     sources each through ``transform``."""
@@ -1352,6 +1444,7 @@ ARCH_CONTAINERS: Dict[str, Type[LayerContainer]] = {
     "qwen2moe": Qwen2MoeContainer,
     "olmoe": OlmoeContainer,
     "sdarmoe": SdarMoeContainer,
+    "lfm2moe": Lfm2MoeContainer,
     "mellum": MellumContainer,
     "longcatflash": LongcatFlashContainer,
     "qwen2": Qwen2Container,
@@ -1475,22 +1568,25 @@ def validate_layered_serving(engine_config, draft: bool = False) -> None:
 
 def validate_recurrent_serving(engine_config, cfg: TransformerConfig,
                                draft: bool = False) -> None:
-    """Fail LOUDLY at engine build for what a model with linear (Gated
-    DeltaNet) layers cannot be served with yet. Such a layer keeps, a slot,
-    a recurrent state and a convolution tail that are no pages: whatever
+    """Fail LOUDLY at engine build for what a model that keeps a state a
+    slot cannot be served with yet, whatever its mixer kind
+    (``cfg.recurrent_kinds``): a linear (Gated DeltaNet) layer keeps a
+    recurrent state and a convolution tail, a conv (gated short
+    convolution) layer a tail alone, and neither is a page: whatever
     moves, shares or rewinds a sequence by its block list alone would leave
     them behind (ROADMAP M4's remainder). An evicted or preempted sequence
     is not refused: it gives its pages back, is queued again with its
     prompt and the tokens it had emitted, and recomputes its state from
     them in a slot that admission zeroed."""
     c = engine_config
+    kinds = " and ".join(cfg.recurrent_kinds)
     probs = []
     if c.tp > 1:
         probs.append(f"tp={c.tp} (the states are not sharded by head; no "
                      "exchange for a share of the experts)")
     if c.prefix_cache:
         probs.append("prefix_cache (a shared prefix's pages come without the "
-                     "linear layers' state at its end)")
+                     f"{kinds} layers' state at its end)")
     if c.kv_swap_dir or c.role != "unified":
         probs.append("the swap tier / prefill-decode handoff (kv_swap_dir, "
                      "role): a record holds pages, no state")
@@ -1510,7 +1606,7 @@ def validate_recurrent_serving(engine_config, cfg: TransformerConfig,
                      "experts it holds)")
     if probs:
         raise NotImplementedError(
-            "a model with linear layers keeps a recurrent state a slot and "
+            f"a model with {kinds} layers keeps a state a slot and "
             "cannot be served with: " + "; ".join(probs))
 
 
@@ -1564,8 +1660,8 @@ def validate_block_diffusion_serving(engine_config, cfg: TransformerConfig,
     if blk & (blk - 1):
         probs.append(f"block_length={blk} (a power of two is what the pages, "
                      "the chunks and the narrow step's tiles divide by)")
-    if cfg.sliding_window or cfg.position != "rope" or cfg.linear_layers \
-            or cfg.latent_lanes:
+    if cfg.sliding_window or cfg.position != "rope" \
+            or cfg.recurrent_kinds or cfg.latent_lanes:
         probs.append("a window, ALiBi or learned positions, linear layers or "
                      "a latent cache (the block mask is walked for full "
                      "attention by head under RoPE alone)")

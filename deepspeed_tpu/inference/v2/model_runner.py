@@ -33,10 +33,11 @@ from ...ops.pallas import gated_delta_rule
 from ..sampling import (block_unmask, sample_logits_per_row,
                         speculative_verify_per_row)
 from .kv_cache import dequantize_kv_lanes, quantize_kv_lanes
-from .telemetry import (LATENT_STAT_NAMES, LAYER_STAT_NAMES,   # in-graph
-                        MAX_RUNGS, MOE_STAT_NAMES,             # counter
-                        MOVED_STAT_NAMES, MTP_STAT_NAMES,      # layout
-                        n_stats, pack_ladder)
+from .telemetry import (CONV_STAT_NAMES, LATENT_STAT_NAMES,    # in-graph
+                        LAYER_STAT_NAMES, MAX_RUNGS,           # counter
+                        MOE_STAT_NAMES, MOVED_STAT_NAMES,      # layout
+                        MTP_STAT_NAMES, RECURRENT_STAT_NAMES, n_stats,
+                        pack_ladder)
 
 
 def _use_pallas_paged() -> bool:
@@ -94,9 +95,9 @@ class PagedModelRunner:
         (``telemetry.LAYER_STAT_NAMES``) are computed from: every layer's
         window (0: a global layer; the others have the ring); None for a
         model of one kind, whose stat vector has no such lanes. A model
-        with linear layers counts its full-attention layers so: they are
-        the layers that read keys."""
-        if self.linear_layers:
+        with linear or conv layers counts its full-attention layers so:
+        they are the layers that read keys."""
+        if self.recurrent_kinds:
             return (0,) * self.cfg.cache_layers
         return None if self.kinds is None else self.cfg.layer_windows()
 
@@ -108,21 +109,52 @@ class PagedModelRunner:
         (``telemetry.RECURRENT_STAT_NAMES``); 0 for every other model."""
         return self.cfg.linear_layers
 
+    @property
+    def conv_layers(self) -> int:
+        """Layers whose mixer is a gated short convolution: their tails
+        ride the carry behind the linear layers' pair, and their work is
+        ``telemetry.CONV_STAT_NAMES``; 0 for every other model."""
+        return self.cfg.conv_layers
+
+    @property
+    def recurrent_kinds(self) -> tuple:
+        """The mixer kinds of the stack that keep a state a slot
+        (``cfg.recurrent_kinds``: "linear", "conv"); () for a model whose
+        layers all attend, which carries nothing for them."""
+        return self.cfg.recurrent_kinds
+
+    @property
+    def recurrent_stat_names(self) -> tuple:
+        """The stat vector's last lanes of a model with a state a slot: by
+        the kinds present, ``RECURRENT_STAT_NAMES`` for linear layers, then
+        ``CONV_STAT_NAMES`` for conv layers."""
+        return sum(({"linear": RECURRENT_STAT_NAMES,
+                     "conv": CONV_STAT_NAMES}[kind]
+                    for kind in self.recurrent_kinds), ())
+
     def recurrent_shapes(self, slots: int):
-        """((shape, dtype) of the state, (shape, dtype) of the convolution
-        tail) that ``slots`` rows carry: float32 states (linear layers,
-        slots, Hv, dk, dv), the tail (linear layers, K - 1, slots, channels)
-        in the activations' dtype. Layers first, and the tail's slots beside
-        its channels: the order the chip's compiler gives both inside the
-        wide program whatever it is handed (a frame relaid both, in and
-        out, while the slots came first: ``tests/test_chip_compile.py``);
-        the slots' axes are ``ragged_manager.RECURRENT_SLOT_AXES``."""
+        """The (shape, dtype) of each array that ``slots`` rows carry, by
+        what each mixer kind present keeps, in ``recurrent_kinds``' order.
+        Linear (Gated DeltaNet) layers: the state, float32 (linear layers,
+        slots, Hv, dk, dv), and the convolution tail (linear layers, K - 1,
+        slots, channels) in the activations' dtype. Conv (gated short
+        convolution) layers: the tail ALONE, (conv layers, K - 1, slots,
+        hidden) in the activations' dtype; no state of zero size rides
+        beside it. Layers first, and a tail's slots beside its channels:
+        the order the chip's compiler gives both inside the wide program
+        whatever it is handed (a frame relaid both, in and out, while the
+        slots came first: ``tests/test_chip_compile.py``); the slots' axes
+        are ``ragged_manager.RECURRENT_SLOT_AXES``, by rank."""
         cfg = self.cfg
-        return (((cfg.linear_layers, slots, cfg.linear_num_value_heads,
-                  cfg.linear_key_head_dim, cfg.linear_value_head_dim),
-                 jnp.float32),
-                ((cfg.linear_layers, cfg.linear_conv_kernel - 1, slots,
-                  cfg.linear_channels), cfg.act_dtype))
+        kept = {
+            "linear": (((cfg.linear_layers, slots, cfg.linear_num_value_heads,
+                         cfg.linear_key_head_dim, cfg.linear_value_head_dim),
+                        jnp.float32),
+                       ((cfg.linear_layers, cfg.linear_conv_kernel - 1, slots,
+                         cfg.linear_channels), cfg.act_dtype)),
+            "conv": (((cfg.conv_layers, cfg.conv_kernel - 1, slots,
+                       cfg.hidden_size), cfg.act_dtype),)}
+        return sum((kept[kind] for kind in self.recurrent_kinds), ())
 
     @property
     def latent_layers(self):
@@ -148,7 +180,7 @@ class PagedModelRunner:
         and for a model of mixed kinds the layers' own, summed."""
         if self.latent_layers:
             return self.latent_layers * max_seq_len
-        if self.linear_layers:
+        if self.recurrent_kinds:
             return self.cfg.cache_layers * (max_seq_len + chunk) - chunk
         if self.kinds is None:
             return self.stat_window or max_seq_len
@@ -190,10 +222,11 @@ class PagedModelRunner:
         model with routed experts counts their work in lanes of its own
         (``telemetry.n_stats``)."""
         return n_stats(self.cfg.is_moe,
-                       self.kinds is not None or bool(self.linear_layers),
+                       self.kinds is not None or bool(self.recurrent_kinds),
                        share=self.cfg.moe_is_share,
                        latent=bool(self.cfg.latent_lanes),
-                       mtp=self.has_mtp, recurrent=bool(self.linear_layers),
+                       mtp=self.has_mtp,
+                       recurrent=len(self.recurrent_stat_names),
                        block=bool(self.block_length))
 
     @property
@@ -269,13 +302,15 @@ class PagedModelRunner:
         (``rows only``: the module's cache at positions nobody drafts
         from: no attention, no experts, no head).
 
-        ``recurrent`` = (state (linear layers, B, Hv, dk, dv) float32, tail
-        (linear layers, K - 1, B, channels)): a model with linear (Gated
-        DeltaNet) layers (``cfg.mixer_pattern``) carries every row's state
-        through the step and gives the pair back as the LAST value. Row b
-        moves its state by its ``valid_counts[b]`` live positions, which
-        are the first of its chunk; a row with none keeps state and tail to
-        the bit. The pools hold the full-attention layers alone.
+        ``recurrent`` (``recurrent_shapes``): a model with linear (Gated
+        DeltaNet) layers (``cfg.mixer_pattern``) carries (state (linear
+        layers, B, Hv, dk, dv) float32, tail (linear layers, K - 1, B,
+        channels)), one with conv (gated short convolution) layers (tail
+        (conv layers, K - 1, B, hidden),): every row's through the step,
+        given back as the LAST value. Row b moves what it keeps by its
+        ``valid_counts[b]`` live positions, which are the first of its
+        chunk; a row with none keeps state and tail to the bit. The pools
+        hold the full-attention layers alone.
 
         ``head_at`` (B,) int32, a model that generates by diffusion over
         blocks: the chunk index of the first of the ``block_length``
@@ -296,10 +331,11 @@ class PagedModelRunner:
                             -1)
         if cfg.mixer_pattern is not None and recurrent is None:
             raise NotImplementedError(
-                "a model with linear (Gated DeltaNet) layers keeps a "
-                "recurrent state a slot, which rides the frame programs' "
-                "carry: it is served by serve() and generate(), not by "
-                "put() / step()")
+                f"a model with {' and '.join(cfg.recurrent_kinds)} layers "
+                "keeps a state a slot (a linear layer's recurrent state and "
+                "convolution tail, a conv layer's tail), which rides the "
+                "frame programs' carry: it is served by serve() and "
+                "generate(), not by put() / step()")
         bs = self.block_size
         kinds = self.kinds
         for kind in kinds or ():
@@ -323,7 +359,7 @@ class PagedModelRunner:
             # a wide step's linear layers run the delta rule on their rows
             # by what each holds (``_rule_by_rows``, or the chip's kernel)
             rows = _row_plan(positions) \
-                if cfg.mixer_pattern is not None and ids.shape[1] > 1 else None
+                if cfg.linear_layers and ids.shape[1] > 1 else None
 
         def embed(ids, positions):
             if tp is not None and tp.vocab_sharded:
@@ -522,6 +558,40 @@ class PagedModelRunner:
         # written (``kv_commit``) in place, in one layout
         quantized_kv = (kpool if kinds is None else kpool[0]).dtype == jnp.int8
         in_place = _use_pallas_paged() and not quantized_kv
+        # KV heads that share a row of a page (``kv_cache.heads_per_row``:
+        # heads of 64 lanes, two a 128-lane row, where the engine laid the
+        # pool out so for the chip's kernels), read off the pool: 1 for
+        # every pool of (kv heads, ..., head_dim) rows
+        in_row = 1 if quantized_kv or cfg.latent_lanes else \
+            (kpool if kinds is None else kpool[0]).shape[-1] \
+            // cfg.dims_per_head
+        assert in_place or in_row == 1, "only the chip's kernels read " \
+            "heads that share a row of a page"
+
+        def to_rows(x):
+            """(B, C, kv heads, D) -> (B, C, kv heads / p, p D): head j p + r
+            in lanes [r D, (r + 1) D) of row j, as the pool's pages hold
+            them (a reshape)."""
+            return x.reshape(x.shape[:2] + (x.shape[2] // in_row, -1))
+
+        def queries_by_row(q):
+            """(B, C, H, D) -> (B, C, H, p D): a query of KV head j p + r
+            in lanes [r D, (r + 1) D) and zeros in the others, so that its
+            product with row j's keys is its own head's score."""
+            b_, c_, h_, d_ = q.shape
+            eye = jnp.eye(in_row, dtype=q.dtype)
+            q = q.reshape(b_, c_, -1, in_row, h_ // cfg.kv_heads, d_)
+            return jnp.einsum("bcjrgd,rs->bcjrgsd", q, eye).reshape(
+                b_, c_, h_, in_row * d_)
+
+        def values_by_head(o):
+            """(B, C, H, p D) -> (B, C, H, D): of a row's values, the lanes
+            of the query's own KV head."""
+            b_, c_, h_, _ = o.shape
+            o = o.reshape(b_, c_, -1, in_row, h_ // cfg.kv_heads, in_row,
+                          cfg.dims_per_head)
+            return jnp.stack([o[:, :, :, r, :, r] for r in range(in_row)],
+                             axis=3).reshape(b_, c_, h_, -1)
         if in_place:
             from ...ops.pallas.kv_commit import kv_commit as commit
         else:
@@ -543,6 +613,14 @@ class PagedModelRunner:
                 # kernel indexes (layer, head, page) in the full pool
                 from ...ops.pallas.paged_attention import \
                     paged_ragged_attention
+                if in_row > 1:
+                    # the kernel sees kv heads / p heads of p D lanes
+                    return values_by_head(paged_ragged_attention(
+                        queries_by_row(q), kp, vp, tables, positions, k, v,
+                        layer=at_pool,
+                        scale=scale or cfg.dims_per_head ** -0.5, window=win,
+                        softcap=cfg.attn_softcap, ring=ring,
+                        **({} if see is None else {"visible_to": see})))
                 return paged_ragged_attention(
                     q, kp, vp, tables, positions, k, v, layer=at_pool,
                     scale=scale, window=win, alibi_slopes=slopes,
@@ -602,6 +680,8 @@ class PagedModelRunner:
                 else:
                     q, k, v, *gate = _on_live(
                         pack, functools.partial(qkv, lp, l), h, pos_safe)
+                    if in_row > 1:
+                        k, v = to_rows(k), to_rows(v)
             # the pools are LOOP-INVARIANT inside the layer scan: this
             # layer's chunk KV rides into the attention as separate blocks
             # and comes back out as scan ys; one commit after the walk
@@ -617,7 +697,8 @@ class PagedModelRunner:
                 with jax.named_scope("attn_out"):
                     y = attn_out(lp, out, gate)
                 # group tag overrides (a mixer's tag leaves the MLP the
-                # config's)
+                # config's; a leading dense layer of such a stack comes
+                # tagged "dense")
                 return mlp(lp, h, y, cfg.is_moe if tag in (None, "full")
                            else tag == "moe", *rest)
             with jax.named_scope("mlp"):
@@ -700,6 +781,41 @@ class PagedModelRunner:
                 h, *work = h if routed else (h,)
             return h, state, tail, work
 
+        def conv_layer(h, lp, li, tail, moe):
+            """A layer whose mixer is a gated short convolution: ``tail`` is
+            every conv layer's (``recurrent``'s last array), ``li``
+            (traced) this layer's index among them, ``moe`` whether its MLP
+            routes (a leading dense layer's does not). The projections and
+            the MLP treat every position alike and run on the live ones;
+            the convolution runs on the (B, C) chunk, a row's live
+            positions first (a narrow step: one position a row)."""
+            n_live = jnp.sum(~is_pad, axis=1).astype(jnp.int32)
+            with jax.named_scope("attn"):
+                def project(h):
+                    p = at(lp)
+                    return L.conv_project(
+                        p["attn"], L.apply_norm(p["norm1"], h, cfg), cfg)
+                g, gate = _on_live(pack, project, h)
+                tail_l = jnp.moveaxis(
+                    jax.lax.dynamic_index_in_dim(tail, li, 0, False), 0, 1)
+                c, new_tail = L.conv_mix(lp[0]["attn"]["conv"][lp[1]], g,
+                                         tail_l, n_live)
+                # a row that sat the step out keeps its tail to the bit
+                tail = jax.lax.dynamic_update_index_in_dim(
+                    tail, jnp.moveaxis(jnp.where(
+                        (n_live > 0)[:, None, None], new_tail, tail_l), 1, 0),
+                    li, 0)
+
+            def dense_out(h, c, gate, *live):
+                with jax.named_scope("attn"):
+                    y = L.conv_output(at(lp)["attn"], c, gate, cfg)
+                return mlp(lp, h, y, moe, *live)
+            with jax.named_scope("mlp"):
+                h = _on_live(pack, dense_out, h, c, gate,
+                             live=~is_pad if routed else None)
+                h, *work = h if routed else (h,)
+            return h, tail, work
+
         def sub(lp, j):
             """Attention, dense MLP and norms ``j`` of a double layer: one
             slice of the stack (a layer's pair sliced first is a copy of
@@ -781,7 +897,8 @@ class PagedModelRunner:
             return logits, row[None], work[0] if work else None
         if recurrent is not None:
             h, kpool, vpool, work, recurrent = self._run_layers_recurrent(
-                layer, linear_layer, h, params, kpool, vpool, block_tables,
+                layer, {"linear": linear_layer, "conv": conv_layer}, h,
+                params, kpool, vpool, block_tables,
                 functools.partial(commit, block_tables=block_tables,
                                   positions=positions), recurrent)
         elif kinds is None:
@@ -860,41 +977,54 @@ class PagedModelRunner:
             kpool, vpool = commit(kpool, vpool, ck_all, cv_all)
         return h, kpool, vpool, jnp.sum(work[0], axis=0) if work else None
 
-    def _run_layers_recurrent(self, layer, linear_layer, h, params, kpool,
-                              vpool, tables, commit, recurrent):
-        """``_run_layers`` for a stack that mixes linear and full attention
-        layers (``cfg.mixer_pattern``): a scan over the pattern's PERIODS
-        with a period's layers unrolled in its body, so that which mixer a
-        layer has is static. A period's layer j has its weights in group
-        ``g{j}`` (``layer_groups``), sliced where they are used; a full
-        layer reads its index among the full layers of the one pool and
-        its chunk KV comes back as scan ys for ONE commit after the walk; a
-        linear layer reads and writes its index among the linear layers of
-        ``recurrent`` = (state, tail), which rides the scan's carry and is
-        updated in place. Returns (h, kpool, vpool, the routed experts'
-        work summed or None, recurrent)."""
-        pattern = tuple(self.cfg.mixer_pattern)
-        p, n = len(pattern), self.cfg.num_layers
-        per = {kind: pattern.count(kind) for kind in ("linear", "full")}
+    def _run_layers_recurrent(self, layer, mixers, h, params, kpool, vpool,
+                              tables, commit, recurrent):
+        """``_run_layers`` for a stack that mixes linear or conv layers
+        with full attention layers (``cfg.mixer_pattern``): a scan over the
+        pattern's PERIODS with a period's layers unrolled in its body, so
+        that which mixer a layer has is static (a pattern as long as the
+        stack: one period, the whole stack unrolled; only such a stack may
+        begin with ``cfg.moe_first_dense`` dense layers, whose MLP is then
+        static too). A period's layer j has its weights in group ``g{j}``
+        (``layer_groups``), sliced where they are used; a full layer reads
+        its index among the full layers of the one pool and its chunk KV
+        comes back as scan ys for ONE commit after the walk; a linear layer
+        (``mixers["linear"]``) reads and writes its index among the linear
+        layers of ``recurrent``'s (state, tail), a conv layer
+        (``mixers["conv"]``) its index among the conv layers of
+        ``recurrent``'s last array, its tail: ``recurrent`` holds what the
+        kinds present keep (``recurrent_shapes``), rides the scan's carry
+        and is updated in place. Returns (h, kpool, vpool, the routed
+        experts' work summed or None, recurrent)."""
+        cfg = self.cfg
+        pattern = tuple(cfg.mixer_pattern)
+        p, n = len(pattern), cfg.num_layers
+        per = {kind: pattern.count(kind) for kind in set(pattern)}
         rank = [pattern[:j].count(pattern[j]) for j in range(p)]
         layers = params["layers"]
 
         def period(carry, t):
-            h, state, tail = carry
+            h, *kept = carry
             ys, work = [], []
             for j, kind in enumerate(pattern):
                 lp = (layers[f"g{j}"], t)
                 at_kind = t * per[kind] + rank[j]
+                # (static: leading dense layers only in a one-period stack)
+                moe = cfg.is_moe and j >= cfg.moe_first_dense
                 if kind == "linear":
-                    h, state, tail, w = linear_layer(h, lp, at_kind, state,
-                                                     tail)
+                    h, kept[0], kept[1], w = mixers[kind](
+                        h, lp, at_kind, kept[0], kept[1])
+                elif kind == "conv":
+                    h, kept[-1], w = mixers[kind](h, lp, at_kind, kept[-1],
+                                                  moe)
                 else:
                     h, (k, v, *w) = layer(
-                        h, (lp, t * p + j, None), tag="full",
+                        h, (lp, t * p + j, None),
+                        tag="full" if moe or not cfg.is_moe else "dense",
                         cache=(kpool, vpool, tables, None, at_kind))
                     ys.append((k, v))
                 work += w
-            return (h, state, tail), (
+            return (h, *kept), (
                 jax.tree.map(lambda *z: jnp.stack(z), *ys),
                 sum(work) if work else None)
 
@@ -1061,8 +1191,9 @@ class PagedModelRunner:
             (``_self_spec_scan_body``). The module's cache is one more layer
             of the same pool: no argument more.
 
-            ``recurrent`` = (state, tail), given: the model has linear
-            (Gated DeltaNet) layers (``recurrent_shapes``). The pair is the
+            ``recurrent``, given: the model has mixers that keep a state a
+            slot (``recurrent_shapes``: linear (Gated DeltaNet) layers'
+            (state, tail), conv layers' (tail,)). The tuple is the
             carry's LAST field, donated like the pools, and comes back last:
             it rides through the frame's steps and from frame to frame.
 
@@ -1108,7 +1239,8 @@ class PagedModelRunner:
                                           mtp=self.has_mtp,
                                           linear=self.linear_layers,
                                           kernel_rule=_rule_kernel_runs(
-                                              self.cfg, width))
+                                              self.cfg, width),
+                                          conv=self.conv_layers)
                 carry = (cached, produced, last_tok, *hidden, done, poison,
                          nonfinite, stats, rng, kpool, vpool) \
                     + (() if recurrent is None else (tuple(recurrent),))
@@ -1494,7 +1626,7 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                        temps, tables, width, greedy, draft=None,
                        repair=False, window=None, ladder=pack_ladder,
                        layers=None, latent=None, heads=None, mtp=False,
-                       linear=0, kernel_rule=False):
+                       linear=0, kernel_rule=False, conv=0):
     """The scan-step of ``frame_loop`` and ``frame_loop_spec``: the in-graph
     SplitFuse scheduling arithmetic lives in exactly one place.
 
@@ -1556,11 +1688,16 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
     (``RECURRENT_STAT_NAMES``): live positions and live rows, each x the
     linear layers, and the positions their rule computes, by
     ``_rule_by_rows`` or by the chip's kernel (``kernel_rule``:
-    ``_rule_kernel_runs``). Such a model is served without a draft and without
-    ``repair``, which would have to roll the state back
+    ``_rule_kernel_runs``). ``conv`` (``PagedModelRunner.conv_layers``):
+    the model's conv layers; their tail is the carry's last field (behind
+    the linear layers' pair where the stack has both), and the step counts
+    the live positions they moved it by, x the conv layers
+    (``CONV_STAT_NAMES``). A model with either is served without a draft
+    and without ``repair``, which would have to roll the state back
     (``archs.validate_recurrent_serving``)."""
     self_draft = draft == "self"
-    assert not linear or (draft is None and not repair)
+    keeps = linear or conv      # some mixer keeps a state a slot
+    assert not keeps or (draft is None and not repair)
     if self_draft and width == 1:
         return _self_spec_scan_body(fwd, params, prompts, prompt_lens,
                                     limits, eos_ids, temps, tables, greedy,
@@ -1575,8 +1712,9 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
         module, commit_rows = _mtp_calls(fwd, params, tables, latent)
 
     def body(carry, _):
-        # the linear layers' (state, tail): the last field, where there is one
-        carry, recurrent = (carry[:-1], carry[-1:]) if linear else (carry, ())
+        # what the mixers keep a slot (the linear layers' (state, tail), the
+        # conv layers' tail): the last field, where there is one
+        carry, recurrent = (carry[:-1], carry[-1:]) if keeps else (carry, ())
         # ``hidden``: one field under a self-draft, none otherwise
         (cached, produced, last_tok, *hidden, done, poison, nonfinite, stats,
          rng, kpool, vpool) = carry
@@ -1597,8 +1735,8 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
         logits, kpool, vpool, moe_work, *h_all = fwd(
             params, ids, positions, tables, w, kpool, vpool, moe_work=True,
             **({"hidden": True} if self_draft else {}),
-            **({"recurrent": recurrent[0]} if linear else {}))
-        if linear:
+            **({"recurrent": recurrent[0]} if keeps else {}))
+        if keeps:
             *h_all, state = h_all
             recurrent = (state,)
         if self_draft:
@@ -1650,11 +1788,8 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
                 moe_work=moe_work, layer_work=layer_work,
                 mtp_work=jnp.zeros((len(MTP_STAT_NAMES),), jnp.int32)
                 if mtp else None,
-                recurrent_work=linear * jnp.stack(
-                    [jnp.sum(w), jnp.sum(w > 0),
-                     _rule_positions(w, width, kernel_rule)]).astype(
-                         jnp.int32)
-                if linear else None)
+                recurrent_work=_recurrent_work(w, width, linear, kernel_rule,
+                                               conv))
         carry = (cached + w, produced + emit.astype(jnp.int32), last_tok,
                  *hidden, done, poison, nonfinite, stats, rng, kpool, vpool,
                  *recurrent)
@@ -1665,6 +1800,23 @@ def _serving_scan_body(fwd, params, prompts, prompt_lens, limits, eos_ids,
         return carry, out
 
     return body
+
+
+def _recurrent_work(w, width, linear, kernel_rule, conv):
+    """A step's work of the mixers that keep a state a slot, the stat
+    vector's last lanes (``PagedModelRunner.recurrent_stat_names``), from
+    the rows' live positions ``w`` (B,): ``RECURRENT_STAT_NAMES`` for
+    ``linear`` layers, then ``CONV_STAT_NAMES`` for ``conv`` layers; None
+    for a model with neither."""
+    work = []
+    if linear:
+        work.append(linear * jnp.stack(
+            [jnp.sum(w), jnp.sum(w > 0),
+             _rule_positions(w, width, kernel_rule)]).astype(jnp.int32))
+    if conv:
+        work.append((conv * jnp.sum(w)).astype(jnp.int32)[None])
+    return jnp.concatenate(work) if len(work) > 1 else \
+        work[0] if work else None
 
 
 def _inject_poison(logits, poison):

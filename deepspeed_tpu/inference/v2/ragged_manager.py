@@ -161,9 +161,12 @@ _ADMIT_STATE = ("prompts", "tables", "ring_tables", "prompt_lens", "limits",
                 "block")
 
 
-#: the slots' axis of a model's recurrent state and of its convolution tail
-#: (``PagedModelRunner.recurrent_shapes``)
-RECURRENT_SLOT_AXES = (1, 2)
+#: the slots' axis of what a model's mixers keep a slot
+#: (``PagedModelRunner.recurrent_shapes``), by the array's rank: a linear
+#: layer's state (layers, slots, Hv, dk, dv), and a convolution tail, a
+#: linear layer's or a conv layer's (layers, K - 1, slots, channels). A
+#: stack of conv layers carries tails alone
+RECURRENT_SLOT_AXES = {5: 1, 4: 2}
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -173,9 +176,10 @@ def _admit_rows(state, idx, prompts, tables, rings, ints, temps):
     table drops the row. ``ints``: (slots, 4) prompt length, limit, EOS id,
     admission watermark. A slot freed by quarantine must not hand its
     poison / latch state to the next tenant of the row, so both clear; nor
-    may a model with linear layers hand on the row's recurrent state and
-    convolution tail (``recurrent``, empty for every other model): a new
-    tenant's are zeros, what a sequence has before its first token. Nor
+    may a model with linear or conv layers hand on the row's recurrent
+    state and convolution tails (``recurrent``, empty for every other
+    model): a new tenant's are zeros, what a sequence has before its first
+    token. Nor
     may a model that generates by diffusion over blocks hand on a
     half-denoised block (``block`` = (tokens, masked), empty for every
     other model): a new tenant's is all masked."""
@@ -193,11 +197,14 @@ def _admit_rows(state, idx, prompts, tables, rings, ints, temps):
     for name in ("done", "poison", "nonfinite"):
         out[name] = put(state[name], False)
     # (no operation at all where the tuple is empty)
-    out["recurrent"] = tuple(
-        jnp.where(put(jnp.zeros((a.shape[axis],), bool), True).reshape(
-            (1,) * axis + (-1,) + (1,) * (a.ndim - axis - 1)),
+    def fresh(a):
+        axis = RECURRENT_SLOT_AXES[a.ndim]
+        return jnp.where(
+            put(jnp.zeros((a.shape[axis],), bool), True).reshape(
+                (1,) * axis + (-1,) + (1,) * (a.ndim - axis - 1)),
             jnp.zeros((), a.dtype), a)
-        for a, axis in zip(state["recurrent"], RECURRENT_SLOT_AXES))
+
+    out["recurrent"] = tuple(map(fresh, state["recurrent"]))
     # (tokens, masked), or empty: no operation at all
     out["block"] = tuple(put(a, fill)
                          for a, fill in zip(state["block"], (0, True)))
@@ -266,10 +273,11 @@ class DeviceSlotTable:
         # at cached-1. A new tenant's first chunk never reads it
         self.hidden = None if hidden is None else self._dev(
             jnp.zeros((n_slots, hidden[0]), hidden[1]))
-        # a model with linear layers (``recurrent`` = the (shape, dtype) of
-        # each, ``PagedModelRunner.recurrent_shapes``): every slot's
-        # recurrent states and convolution tails, not a table of pages but
-        # a row a slot; ``admit`` zeroes a new tenant's. () otherwise
+        # a model with linear or conv layers (``recurrent`` = the (shape,
+        # dtype) of each array, ``PagedModelRunner.recurrent_shapes``):
+        # every slot's recurrent states and convolution tails (a conv
+        # stack's: tails alone), not a table of pages but a row a slot;
+        # ``admit`` zeroes a new tenant's. () otherwise
         self.recurrent = tuple(self._dev(jnp.zeros(shape, dtype))
                                for shape, dtype in recurrent)
         # a model that generates by diffusion over blocks (``block`` = (L
@@ -576,9 +584,9 @@ class DeviceSlotTable:
             # a self-draft's ``hidden`` goes in last and comes back behind
             # ``last_tok``, where the carry has it
             hidden = [] if draft is None else [self.hidden]
-            # the linear layers' (state, tail) goes in by name and comes
-            # back last; a half-denoised block likewise (a model has one
-            # or the other)
+            # what the mixers keep a slot (``recurrent``) goes in by name
+            # and comes back last; a half-denoised block likewise (a model
+            # has one or the other)
             last = "block" if self.block else \
                 "recurrent" if self.recurrent else None
             extra = {last: getattr(self, last)} if last else {}

@@ -196,6 +196,13 @@ MTP_STAT_NAMES = ("mtp_latent_positions_read", "mtp_expert_rows",
 #: every row, on every backend
 RECURRENT_STAT_NAMES = ("gdn_positions", "gdn_state_rw",
                         "gdn_positions_computed")
+#: a model with conv (gated short convolution) layers, the very last lane
+#: of its vector, behind RECURRENT_STAT_NAMES where the stack has linear
+#: layers too: positions its conv layers moved their tails by (live
+#: positions x conv layers). A conv layer keeps no matrix state, so there
+#: is no lane of states read and written, and its convolution computes the
+#: chunk it is handed: none of positions computed either
+CONV_STAT_NAMES = ("conv_positions",)
 
 
 #: a model that generates by diffusion over blocks (``cfg.block_length``),
@@ -220,19 +227,20 @@ BLOCK_STAT_NAMES = ("bd_denoise_forwards", "bd_commit_forwards",
 
 def n_stats(routed: bool, layered: bool = False, share: bool = False,
             latent: bool = False, mtp: bool = False,
-            recurrent: bool = False, block: bool = False) -> int:
+            recurrent: int = 0, block: bool = False) -> int:
     """Lanes of the stat vector of a model with (or without) routed
     experts (all of them held, or a share), of mixed cache kinds or of
     one, with latent attention or without, with a prediction module or
-    without, with linear layers or without, generating by diffusion over
-    blocks or left to right."""
+    without, with ``recurrent`` lanes for the mixers that keep a state a
+    slot (the length of ``PagedModelRunner.recurrent_stat_names``),
+    generating by diffusion over blocks or left to right."""
     return (N_STATS
             + (len(MOE_STAT_NAMES) + len(MOVED_STAT_NAMES) if routed else 0)
             + (len(SHARE_STAT_NAMES) if share else 0)
             + (len(LAYER_STAT_NAMES) if layered else 0)
             + (len(LATENT_STAT_NAMES) if latent else 0)
             + (len(MTP_STAT_NAMES) if mtp else 0)
-            + (len(RECURRENT_STAT_NAMES) if recurrent else 0)
+            + recurrent
             + (len(BLOCK_STAT_NAMES) if block else 0))
 
 
@@ -664,6 +672,7 @@ class ServingTelemetry:
         self.kind_gauges: Dict[str, Dict[str, int]] = {}
         self._tail_names, self._share, self._mtp = (), False, False
         self._recurrent_bytes = 0       # a live slot's, a model with any
+        self._recurrent_stats = ()      # and the names of its last lanes
         self._block = 0                 # a block's positions, such a model
         # per-class TTFT (the bench/SLO acceptance surface)
         self.class_ttft: Dict[str, LogBucketHistogram] = {}
@@ -695,7 +704,9 @@ class ServingTelemetry:
                     tp_degree: int = 1, kv_block_bytes: int = 0,
                     layered: bool = False, latent: bool = False,
                     share: bool = False, mtp: bool = False,
-                    recurrent_slot_bytes: int = 0, block: int = 0) -> None:
+                    recurrent_slot_bytes: int = 0,
+                    recurrent_stats=RECURRENT_STAT_NAMES,
+                    block: int = 0) -> None:
         """Called by ``serve()`` at generator construction.
         ``kv_block_bytes`` is the pool-resident footprint of one KV block
         across all layers (``BlockedKVCache.block_bytes``) — the
@@ -711,16 +722,20 @@ class ServingTelemetry:
         (SHARE_STAT_NAMES behind the experts' lanes). ``mtp``: the model
         has a prediction module, and its vector's very last lanes are
         MTP_STAT_NAMES. ``recurrent_slot_bytes`` (nonzero: the model has
-        linear layers, its very last lanes are RECURRENT_STAT_NAMES and
-        ``layered`` says how its full layers count): what a live slot holds
-        of recurrent state and convolution tail, for the gauge
-        ``recurrent_bytes_in_use`` and its sum over frames. ``block``
+        linear or conv layers, its very last lanes are ``recurrent_stats``
+        (RECURRENT_STAT_NAMES for linear layers, CONV_STAT_NAMES for conv
+        layers, both in that order for a stack with both) and ``layered``
+        says how its full layers count): what a live slot holds of
+        recurrent state and convolution tails (a conv layer's: the tail
+        alone), for the gauge ``recurrent_bytes_in_use`` and its sum over
+        frames. ``block``
         (nonzero: the model generates by diffusion over blocks of that many
         positions): its very last lanes are BLOCK_STAT_NAMES, and its
         narrow frames are two blocks wide."""
         self.reset()
         self._share, self._mtp = share, mtp
         self._recurrent_bytes = recurrent_slot_bytes
+        self._recurrent_stats = tuple(recurrent_stats)
         self._block = block
         if block:
             assert not mtp and not recurrent_slot_bytes, \
@@ -730,7 +745,7 @@ class ServingTelemetry:
             self.counters[n] = 0
         if recurrent_slot_bytes:
             assert not mtp, "both claim the vector's last lanes"
-            self.counters.update(dict.fromkeys(RECURRENT_STAT_NAMES, 0),
+            self.counters.update(dict.fromkeys(self._recurrent_stats, 0),
                                  recurrent_bytes_in_use_sum=0)
             self.gauges["recurrent_bytes_in_use"] = 0
         self._tail_names = (LAYER_STAT_NAMES if layered else
@@ -1325,7 +1340,7 @@ class ServingTelemetry:
         # linear layers' (a model has one or the other)
         last = {}
         last_names = (MTP_STAT_NAMES if self._mtp else
-                      RECURRENT_STAT_NAMES if self._recurrent_bytes else
+                      self._recurrent_stats if self._recurrent_bytes else
                       BLOCK_STAT_NAMES if self._block else ())
         if last_names:
             delta, tail = np.split(delta, [len(delta) - len(last_names)])

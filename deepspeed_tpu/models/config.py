@@ -123,6 +123,9 @@ class TransformerConfig:
     moe_expert_first: int = 0
     moe_zero_experts: int = 0
     moe_router_bias: bool = False       # choose by score + bias, weigh by score
+    # added to the sum the chosen sigmoid scores are renormalised by
+    # (DeepSeek-V3's family: 1e-20; LFM2: 1e-6)
+    moe_norm_eps: float = 1e-20
     moe_routed_scale: float = 1.0       # routed_scaling_factor on the weights
     # latent attention (MLA, DeepSeek-V2; LongCat-Flash's layout): a
     # low-rank query (q_lora_rank), ONE cached row a token and layer of
@@ -148,14 +151,21 @@ class TransformerConfig:
     # as the model's own draft (inference/v2); 0: none
     num_nextn_predict_layers: int = 0
     # token mixers by layer, ONE period of the pattern ("linear": a Gated
-    # DeltaNet layer, arXiv:2412.06464; "full": softmax attention), as
-    # ``window_pattern``: a cut of num_layers alone keeps it (Qwen3-Next:
-    # three linear layers, then a full one). None: every layer attends.
-    # A linear layer keeps no keys or values: per sequence a state of
+    # DeltaNet layer, arXiv:2412.06464; "conv": a gated short convolution,
+    # LFM2's; "full": softmax attention), as ``window_pattern``: a cut of
+    # num_layers alone keeps it (Qwen3-Next: three linear layers, then a
+    # full one). A pattern as long as the stack is one period (LFM2's
+    # published ``layer_types`` has none that tiles a cut), and only such a
+    # stack may begin with ``moe_first_dense`` dense layers. None: every
+    # layer attends. Neither a linear nor a conv layer keeps keys or
+    # values. A linear layer keeps per sequence a state of
     # linear_num_value_heads x linear_key_head_dim x linear_value_head_dim
     # and the last linear_conv_kernel - 1 inputs of its causal depthwise
-    # convolution. Served on the paged path (inference/v2) only
+    # convolution; a conv layer the last conv_kernel - 1 rows of its
+    # convolution's input (hidden_size channels) and nothing else. Served
+    # on the paged path (inference/v2) only
     mixer_pattern: Optional[tuple] = None
+    conv_kernel: int = 3                # taps of a conv layer (conv_L_cache)
     linear_num_key_heads: int = 0
     linear_num_value_heads: int = 0
     linear_key_head_dim: int = 0
@@ -269,9 +279,9 @@ class TransformerConfig:
     @property
     def cache_layers(self) -> int:
         """Layers of the serving cache: the stack's attention layers (a
-        linear layer caches no keys) and, behind them, one for each
+        linear or a conv layer caches no keys) and, behind them, one for each
         prediction module's."""
-        return (self.attn_layers - self.linear_layers
+        return (self.attn_layers - self.linear_layers - self.conv_layers
                 + self.num_nextn_predict_layers)
 
     @property
@@ -306,12 +316,13 @@ class TransformerConfig:
                      for i in range(self.num_layers))
 
     def layer_mixers(self) -> Optional[tuple]:
-        """Per-layer mixer kinds of a stack that mixes linear and full
-        attention layers, or None where every layer attends."""
+        """Per-layer mixer kinds of a stack that mixes linear or conv
+        layers with full attention layers, or None where every layer
+        attends."""
         if self.mixer_pattern is None:
             return None
         p = tuple(self.mixer_pattern)
-        assert set(p) <= {"linear", "full"}, p
+        assert set(p) <= {"linear", "conv", "full"}, p
         if self.num_layers % len(p):
             raise ValueError(
                 f"mixer_pattern of {len(p)} layers does not tile "
@@ -323,6 +334,21 @@ class TransformerConfig:
         """Layers whose mixer is linear: each holds a recurrent state and
         a convolution tail a sequence, and no cache layer."""
         return (self.layer_mixers() or ()).count("linear")
+
+    @property
+    def conv_layers(self) -> int:
+        """Layers whose mixer is a gated short convolution: each holds a
+        convolution tail a sequence and nothing else, and no cache layer."""
+        return (self.layer_mixers() or ()).count("conv")
+
+    @property
+    def recurrent_kinds(self) -> tuple:
+        """The mixer kinds of this stack that keep a state a sequence, in
+        the order their arrays ride the serving carry
+        (``PagedModelRunner.recurrent_shapes``); () where every layer
+        attends."""
+        return tuple(kind for kind in ("linear", "conv")
+                     if kind in (self.layer_mixers() or ()))
 
     @property
     def linear_channels(self) -> int:
@@ -521,6 +547,23 @@ PRESETS = {
         num_experts=128, num_experts_per_tok=8, moe_norm_topk=True, moe_impl="grouped",
         block_length=4, denoising_steps=4, remasking_strategy="low_confidence_dynamic",
         confidence_threshold=0.9, mask_token_id=151669),
+    # LFM2-24B-A2B (LiquidAI/LFM2-24B-A2B config.json, lfm2_moe): three
+    # gated short convolutions (3 taps, no bias, no activation) to one layer
+    # of softmax attention (GQA 32 / 8 heads of 64, one RMSNorm of 64 lanes
+    # a q and k head before RoPE); two leading dense layers 11,776 wide,
+    # then 64 experts of width 1,536: sigmoid scores, the top 4 of score +
+    # expert_bias, weights the scores over (their sum + 1e-6), scale 1, no
+    # shared expert; plain RMSNorm weights; the head tied to the embedding
+    # (the family's checkpoints; the key is absent). ``layer_types`` as
+    # published: a cut names its own (a pattern as long as the stack)
+    "lfm2-24b-a2b": TransformerConfig(
+        vocab_size=65536, hidden_size=2048, num_layers=40, num_heads=32, num_kv_heads=8,
+        intermediate_size=11776, moe_intermediate_size=1536, max_seq_len=128000,
+        rope_theta=1e6, norm_eps=1e-5, qk_norm="head_dim", qk_norm_bias=False,
+        mixer_pattern=("conv", "conv", "full", "conv") * 10, conv_kernel=3,
+        num_experts=64, num_experts_per_tok=4, moe_first_dense=2, moe_norm_topk=True,
+        moe_router_bias=True, moe_routed_scale=1.0, moe_router_score="sigmoid",
+        moe_norm_eps=1e-6, moe_impl="grouped", tie_embeddings=True),
     # BERT family (post-norm encoder, MLM head; acceptance config 2 trains
     # bert-large under ZeRO-1/2)
     "bert-base": TransformerConfig(vocab_size=30522, hidden_size=768, num_layers=12, num_heads=12,

@@ -422,18 +422,19 @@ def gdn_project(params, x, cfg: TransformerConfig):
             ba[..., :hv], ba[..., hv:])
 
 
-@jax.named_scope("gdn_conv")
-def gdn_conv(params, u, tail, n, cfg: TransformerConfig):
-    """The causal depthwise convolution and its ``silu`` over a chunk ``u``
-    (B, C, channels) whose row b holds ``n[b]`` live positions, behind the
-    ``tail`` (B, K - 1, channels) of inputs the sequence had before the
-    chunk (zeros before its first token). Returns the chunk's outputs (a
-    dead position's are garbage) and the new tail: the last K - 1 inputs of
-    the LIVE positions, so a row with n = 0 keeps its tail to the bit."""
-    k = cfg.linear_conv_kernel
+def causal_conv(taps, u, tail, n):
+    """The causal depthwise convolution of ``taps`` (K, channels) over a
+    chunk ``u`` (B, C, channels) whose row b holds ``n[b]`` live positions,
+    behind the ``tail`` (B, K - 1, channels) of inputs the sequence had
+    before the chunk (zeros before its first token). Returns the chunk's
+    outputs in float32 (a dead position's are garbage) and the new tail:
+    the last K - 1 inputs of the LIVE positions, so a row with n = 0 keeps
+    its tail to the bit. What a Gated DeltaNet layer (``gdn_conv``) and a
+    gated short convolution (``conv_mix``) share."""
+    k = taps.shape[0]
     c = u.shape[1]
     ext = jnp.concatenate([tail.astype(u.dtype), u], axis=1)   # (B, K-1+C, ch)
-    taps = params["conv"].astype(jnp.float32)
+    taps = taps.astype(jnp.float32)
     y = sum(ext[:, j:j + c].astype(jnp.float32) * taps[j][None, None]
             for j in range(k))
     # input t sits at ext[t + K - 1]: the live ones' last K - 1 start at n.
@@ -449,7 +450,68 @@ def gdn_conv(params, u, tail, n, cfg: TransformerConfig):
         u.reshape(-1, u.shape[2])[rows],
         jnp.take_along_axis(tail.astype(u.dtype),
                             jnp.minimum(idx, k - 2)[:, :, None], axis=1))
-    return jax.nn.silu(y).astype(u.dtype), new_tail.astype(tail.dtype)
+    return y, new_tail.astype(tail.dtype)
+
+
+@jax.named_scope("gdn_conv")
+def gdn_conv(params, u, tail, n, cfg: TransformerConfig):
+    """``causal_conv`` of a Gated DeltaNet layer's q | k | v channels and
+    its ``silu``: the chunk's outputs in ``u``'s dtype and the new tail."""
+    y, new_tail = causal_conv(params["conv"], u, tail, n)
+    return jax.nn.silu(y).astype(u.dtype), new_tail
+
+
+# ---- gated short convolution (LFM2) ---------------------------------------
+
+
+def init_conv_mixer(rng, cfg: TransformerConfig):
+    """A gated short convolution: ``w_in`` the input projection to B | C | X
+    (in that order, no bias), ``conv`` the taps of the causal depthwise
+    convolution over B * X (tap j meets the input K - 1 - j positions back;
+    no bias, no activation; drawn like the matrices, as the family's own
+    initialisation draws its Conv1d: normal, std 0.02), ``w_out`` the output
+    projection of C * conv(B * X)."""
+    e = cfg.hidden_size
+    r = jax.random.split(rng, 3)
+    std = 0.02
+    params = {
+        "w_in": _normal(r[0], (e, 3 * e), cfg.p_dtype, std),
+        "conv": _normal(r[1], (cfg.conv_kernel, e), cfg.p_dtype, std),
+        "w_out": _normal(r[2], (e, e), cfg.p_dtype,
+                         std / math.sqrt(2 * cfg.num_layers)),
+    }
+    axes = {"w_in": ("embed", "unmodeled"),
+            "conv": ("unmodeled", "unmodeled"),
+            "w_out": ("unmodeled", "embed")}
+    return params, axes
+
+
+def conv_project(params, x, cfg: TransformerConfig):
+    """Normalised input (B, S, E) -> the convolution's input g = B * X and
+    the output gate C, both (B, S, E). Treats every position alike."""
+    e = cfg.hidden_size
+    with jax.named_scope("conv_proj"):
+        bcx = jnp.einsum("bse,ef->bsf", x, dq(params["w_in"], cfg.act_dtype))
+    with jax.named_scope("conv_mix"):
+        return bcx[..., :e] * bcx[..., 2 * e:], bcx[..., e:2 * e]
+
+
+@jax.named_scope("conv_mix")
+def conv_mix(taps, g, tail, n):
+    """``causal_conv`` of a gated short convolution's input ``g`` (no
+    activation): the chunk's outputs in ``g``'s dtype and the new tail."""
+    y, new_tail = causal_conv(taps, g, tail, n)
+    return y.astype(g.dtype), new_tail
+
+
+def conv_output(params, c, gate, cfg: TransformerConfig):
+    """The convolution's outputs under the gate C, through ``w_out``.
+    Treats every position alike."""
+    with jax.named_scope("conv_mix"):
+        y = gate * c
+    with jax.named_scope("conv_out"):
+        return jnp.einsum("bsf,fe->bse", y, dq(params["w_out"],
+                                               cfg.act_dtype))
 
 
 def gdn_split(u, cfg: TransformerConfig):
@@ -926,7 +988,7 @@ def apply_moe_grouped(params, x, cfg: TransformerConfig, live=None,
         topk_idx, w, aux_loss = topk_gating_grouped(
             logits, k=k, normalize=cfg.moe_norm_topk,
             bias=params.get("router_bias"), scale=cfg.moe_routed_scale,
-            score=cfg.moe_router_score)
+            score=cfg.moe_router_score, eps=cfg.moe_norm_eps)
 
     share = cfg.moe_is_share
     with jax.named_scope("moe_dispatch"):

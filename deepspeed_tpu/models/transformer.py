@@ -110,12 +110,17 @@ def layer_plan(cfg):
         (mlp_only_layers prefixes): one scan per run.
     """
     if cfg.mixer_pattern is not None:
-        # layers of linear and of full attention hold different weights:
-        # always the pattern's period, one scan step a period even where
-        # the stack is one period deep, so every depth lays its weights
-        # out alike (``layer_tags`` beside it is not written)
-        assert cfg.layer_tags is None, "mixer_pattern beside layer tags"
+        # layers of different mixers hold different weights: always the
+        # pattern's period, one scan step a period even where the stack is
+        # one period deep, so every depth lays its weights out alike
+        # (``layer_types`` beside it is not written; leading dense layers,
+        # ``moe_first_dense``, only where the stack is ONE period: which
+        # MLP a period's layer holds must not depend on the period)
+        assert cfg.layer_types is None, "mixer_pattern beside layer_types"
         cfg.layer_mixers()      # the pattern tiles the stack
+        assert not cfg.moe_first_dense \
+            or len(cfg.mixer_pattern) == cfg.num_layers, \
+            "leading dense layers in a stack of several mixer periods"
         return ("periodic", len(cfg.mixer_pattern))
     tags = cfg.layer_tags
     if tags is None or len(set(tags)) <= 1:
@@ -146,6 +151,11 @@ def layer_groups(cfg):
     if plan[0] == "periodic":
         p = plan[1]
         tags = cfg.layer_mixers() or cfg.layer_tags
+        if cfg.mixer_pattern is not None:
+            # a mixer's tag, and ".dense" behind it where the layer is one
+            # of a routed stack's leading dense ones
+            tags = [t + ".dense" * (cfg.is_moe and i < cfg.moe_first_dense)
+                    for i, t in enumerate(tags)]
         return [(tags[i], tuple(range(i, cfg.num_layers, p)))
                 for i in range(p)]
     return [(tag, tuple(range(start, start + ln)))
@@ -263,13 +273,16 @@ class CausalLM:
             return self._init_double_layer(rng)
         r_attn, r_mlp = jax.random.split(rng)
         # a group's tag names its MLP ("dense" | "moe") or, in a stack of
-        # mixed mixers, its mixer ("linear" | "full"; the MLP is the
-        # config's): a linear layer holds its mixer under "attn" too
-        mixer = layer_type if cfg.mixer_pattern is not None else None
+        # mixed mixers, its mixer ("linear" | "conv" | "full"; the MLP is
+        # the config's, or dense where ".dense" follows: ``layer_groups``):
+        # a linear or a conv layer holds its mixer under "attn" too
+        mixer, _, dense = (layer_type or "").partition(".") \
+            if cfg.mixer_pattern is not None else (None, "", "")
         attn, attn_axes = (L.init_gdn if mixer == "linear" else
+                           L.init_conv_mixer if mixer == "conv" else
                            L.init_mla if cfg.kv_lora_rank
                            else L.init_attention)(r_attn, cfg)
-        if (cfg.is_moe if layer_type is None or mixer
+        if (cfg.is_moe and not dense if layer_type is None or mixer
                 else layer_type == "moe"):
             mlp, mlp_axes = L.init_moe_mlp(r_mlp, cfg)
         else:
@@ -421,8 +434,9 @@ class CausalLM:
         cfg = self.cfg
         if cfg.kv_lora_rank or cfg.shortcut_moe or cfg.mixer_pattern:
             raise NotImplementedError(
-                "latent attention, shortcut-connected layers and linear "
-                "(Gated DeltaNet) mixers run on the "
+                "latent attention, shortcut-connected layers and mixers "
+                "that keep a state a sequence (this stack's: "
+                f"{', '.join(cfg.recurrent_kinds) or 'none'}) run on the "
                 "paged serving path (inference/v2) only: no training or "
                 "cache-less forward is written for them")
         rope = self._rope_args(rope)

@@ -85,13 +85,14 @@ def topk_gating_einsum(logits, k: int = 2, capacity_factor: float = 1.25,
 
 def topk_gating_grouped(logits, k: int = 2, normalize: bool = True,
                         bias=None, scale: float = 1.0,
-                        score: str = "softmax"):
+                        score: str = "softmax", eps: float = 1e-20):
     """Top-k gating for the grouped (megablox-style) dropless path.
     ``bias`` (X,): the k experts are the largest of score + bias, their
     weights the scores alone (DeepSeek-V3's / LongCat's correction bias);
     ``scale`` multiplies the weights. ``score``: "softmax" over all the
     experts, or "sigmoid" each on its own (DeepSeek-V3's family, whose
-    renormalised weights are s / (sum s + 1e-20)).
+    renormalised weights are s / (sum s + ``eps``): 1e-20 there, LFM2's
+    1e-6).
 
     Returns (topk_idx (T, k) int32, weights (T, k) fp32 normalized over the
     k choices, aux_loss). No capacity buffers: every token reaches its
@@ -110,7 +111,7 @@ def topk_gating_grouped(logits, k: int = 2, normalize: bool = True,
         topk_vals = jnp.take_along_axis(gates, topk_idx, axis=-1)
     if normalize:
         denom = jnp.sum(topk_vals, axis=-1, keepdims=True)
-        w = topk_vals / (denom + 1e-20 if sigmoid
+        w = topk_vals / (denom + eps if sigmoid
                          else jnp.maximum(denom, 1e-9))
     else:
         w = topk_vals

@@ -494,7 +494,7 @@ def test_a_near_tie_row_is_held_to_its_nearest_candidate(monkeypatch, glm,
     swapping = []
 
     def other(logits, k=2, normalize=True, bias=None, scale=1.0,
-              score="softmax"):
+              score="softmax", eps=1e-20):
         if not swapping:
             return sound(logits, k, normalize, bias, scale, score)
         top, order = jax.lax.top_k(jax.nn.sigmoid(logits) + bias[None], k + 1)

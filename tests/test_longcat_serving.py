@@ -312,7 +312,7 @@ def test_tolerance_catches(monkeypatch, whole, reference, fault):
         sound = sharded_moe.topk_gating_grouped
 
         def biased(logits, k=2, normalize=True, bias=None, scale=1.0,
-                   score="softmax"):
+                   score="softmax", eps=1e-20):
             idx, _, aux = sound(logits, k, normalize, bias, scale, score)
             gates = jax.nn.softmax(logits, -1) + bias[None]
             return idx, scale * jnp.take_along_axis(gates, idx, -1), aux
