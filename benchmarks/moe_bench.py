@@ -23,14 +23,16 @@ one JSON line a row, the bytes and FLOPs floors beside the times.
 ``--bookkeeping-sweep`` times what a routed block does AROUND its products,
 on the chip: ``apply_moe_grouped``'s route, dispatch and combine with the
 products stubbed out, us a layer, at the three wide cells' shapes (tokens x k
-x hidden = 1,040 x 12 x 6,144; 1,040 x 10 x 2,048; 528 x 8 x 2,304) over the
-share of the router's experts the chip holds (2 / 25 / 100%) and the live
-share of the positions: the unbounded form (every selection row moved, what
-every tree before PR 45 ran) beside the bounded one at each ``--blocks`` rows
-a trip, then what the rule ships at that shape (``layers._move_block``) and
-the same with the gather's buffer zeroed first instead of unwritten; one JSON
-line a row, the rows in groups and the buffer's MB
-beside the times.
+x hidden = 1,040 x 12 x 6,144; 1,040 x 10 x 2,048; 528 and 1,040 x 8 x 2,304)
+and the narrow steps' (16 x 8 and 128 x 8 x 2,048, 32 x 4 and 16 x 4 x 2,048)
+over the share of the router's experts the chip holds (2 / 25 / 100%) and the
+live share of the positions: the unbounded form (every selection row moved
+once each way: a gather by sorted row, a gather by token and a sum of k rows
+since PR 52) beside the bounded one at each ``--blocks`` rows a trip that
+leaves more than two blocks, then what the rule ships at that shape
+(``layers._move_block``) and the same with the gather's buffer zeroed first
+instead of unwritten; one JSON line a row, the rows in groups and the
+buffer's MB beside the times.
 """
 
 import functools
@@ -249,12 +251,19 @@ def grouped_sweep(args):
                     print(json.dumps(row), flush=True)
 
 
-# (tokens a wide step's rung holds, selections a token, hidden size, the
-# router's outputs): the three cells whose routed layers run wide (ledger, PR 44)
+# (tokens a step holds, selections a token, hidden size, the router's
+# outputs): the rungs of the three cells whose routed layers run wide (ledger,
+# PR 44; Mellum2's two), then the narrow steps of the cells that decode (16
+# slots; SDAR's 32 rows of 4 positions; GLM's verify of 2 positions a slot)
 BOOKKEEPING_SHAPES = {
     "longcat-flash-omni": (1040, 12, 6144, 768),
     "qwen3-next-80b-a3b": (1040, 10, 2048, 512),
     "mellum2-12b-a2.5b": (528, 8, 2304, 64),
+    "mellum2-12b-a2.5b.t1040": (1040, 8, 2304, 64),
+    "olmoe-1b-7b.narrow": (16, 8, 2048, 64),
+    "sdar-30b-a3b.narrow": (128, 8, 2048, 128),
+    "glm-4.7-flash.verify2": (32, 4, 2048, 64),
+    "lfm2-24b-a2b.narrow": (16, 4, 2048, 64),
 }
 BOOKKEEPING_SHARES = (0.02, 0.25, 1.0)
 
@@ -311,7 +320,7 @@ def bookkeeping_sweep(args):
     for name in args.models.split(",") if args.models else BOOKKEEPING_SHAPES:
         tokens, k, hidden, width = BOOKKEEPING_SHAPES[name]
         if args.anywhere and dev.platform != "tpu":
-            tokens, hidden = tokens // 8, hidden // 16   # a rehearsal
+            tokens, hidden = max(16, tokens // 8), hidden // 16   # a rehearsal
         for share in BOOKKEEPING_SHARES:
             held = max(1, round(width * share))
             cfg = TransformerConfig(
@@ -336,6 +345,8 @@ def bookkeeping_sweep(args):
                 row.update(rows=tokens * k, rows_in_groups=round(in_groups),
                            unbounded_us=round(us, 1))
                 for block in blocks:
+                    if tokens * k <= 2 * block:     # as the rule: no bound
+                        continue
                     us, _, moved = timed(cfg, params, x, live, block)
                     row[f"bounded_us_b{block}"] = round(us, 1)
                     row[f"rows_moved_b{block}"] = round(moved)

@@ -874,7 +874,8 @@ def _move_block(cfg: TransformerConfig, rows: int):
     and the unbounded lines run: two blocks hold every row (every narrow
     program), or the experts are all held (not ``cfg.moe_is_share``), where
     the rows in groups are the live positions', over half of any rung
-    (``pack_ladder``), and one pass over every row is the cheaper form."""
+    (``pack_ladder``): there one gather each way over every row is the
+    cheaper form (``_sum_by_token``)."""
     tile = 128 * cfg.hidden_size * jnp.dtype(cfg.act_dtype).itemsize
     block = 128 * max(1, round(MOVE_BYTES / tile))
     return block if cfg.moe_is_share and rows > 2 * block else None
@@ -887,7 +888,7 @@ def _move_trips(n, block: int):
 
 
 def moe_rows_moved(cfg: TransformerConfig, group_sizes, rows: int):
-    """Rows one routed block's dispatch gathered (= its combine added) for
+    """Rows one routed block's dispatch gathered (= its combine took back) for
     ``group_sizes`` out of ``rows`` = T x k selections (serving): whole
     blocks over the groups' rows, every row where the bound cannot bind
     (``_move_block``) or the dispatch is not the dropless one."""
@@ -945,6 +946,32 @@ def _add_held(rows, w_sorted, tok_of_sorted, n, block: int, t: int):
                              jnp.zeros((t, e), rows.dtype))
 
 
+def _sum_by_token(rows, order, w, keep):
+    """The (T, E) outputs of ``rows`` (T x k, E), the experts' results in
+    sorted order, by a gather: selection ``j`` of token ``i`` sits at the
+    sorted row ``r`` with ``order[r] == i * k + j``, so the rows are taken
+    back j-major, as k slabs of (T, E) (the inverse permutation by a second
+    sort, its keys the selections' j-major places), and summed over j under
+    the tokens' weights ``w`` (T, k), in float32, rounded once. No
+    scatter-add, no (T, E) of zeros; the derivative for ``rows`` is a scatter
+    over rows that are all different. ``keep`` (T, k) bool or None: the
+    selections whose row is in a group; another's is unwritten on the chip
+    and may hold anything, so it is taken out by ``where``, never by a
+    product. On the chip (``benchmarks/moe_bench.py --bookkeeping-sweep``,
+    PERF.md Findings PR 52) a sort reads cheaper for the inverse than a
+    scatter of T x k scalars, and slabs over j cheaper to sum than a
+    token's k rows side by side in a tile."""
+    t, k = w.shape
+    at = jnp.argsort(order % k * t + order // k)
+    picked = rows.at[at].get(mode="promise_in_bounds",
+                             unique_indices=True).reshape(k, t, -1)
+    if keep is not None:
+        picked = jnp.where(keep.T[..., None], picked,
+                           jnp.zeros((), rows.dtype))
+    return jnp.sum(picked.astype(jnp.float32) * w.T[..., None],
+                   axis=0).astype(rows.dtype)
+
+
 def apply_moe_grouped(params, x, cfg: TransformerConfig, live=None,
                       layer=None):
     """Dropless grouped-GEMM MoE (megablox pattern; reference analog:
@@ -962,14 +989,19 @@ def apply_moe_grouped(params, x, cfg: TransformerConfig, live=None,
     product skips them, they read no expert's weights and a live
     token's output does not depend on them. Rows past the groups are not
     written (zero on the CPU, whatever the buffer held on the chip), so the
-    combine zeroes them. With ``live`` the group sizes come back as a third
-    value (the rows each expert computed).
-    Where the chip holds a share of the router's experts
-    (``cfg.moe_is_share``) the rows of the groups, which sort first, are few
-    of the T x k: the gather of token rows and the weighted scatter-add then
-    run over the blocks that hold them alone (``_gather_held``,
-    ``_add_held``: a trip count taken in the graph from ``group_sizes``;
-    ``_move_block`` says where, ``moe_rows_moved`` counts them).
+    combine takes them out by ``where``. With ``live`` the group sizes come
+    back as a third value (the rows each expert computed).
+    The rows move by one of two forms, chosen from static shapes and
+    ``cfg.moe_is_share`` alone (``_move_block``). Everywhere but a share's
+    wide rungs every selection row moves once each way, by a gather each
+    way: token rows into sorted order, then a token's k result rows back
+    over the inverse permutation and summed under its weights
+    (``_sum_by_token``: no scatter-add, no (T, E) of zeros). Where the chip
+    holds a share of the router's experts (``cfg.moe_is_share``) the rows of
+    the groups, which sort first, are few of the T x k: the gather of token
+    rows and a weighted scatter-add then run over the blocks that hold them
+    alone (``_gather_held``, ``_add_held``: a trip count taken in the graph
+    from ``group_sizes``; ``moe_rows_moved`` counts them).
     ``layer``: the three expert matrices in ``params`` are stacked over
     layers and this is the one to use (``grouped_gemm`` says why).
     """
@@ -1021,16 +1053,15 @@ def apply_moe_grouped(params, x, cfg: TransformerConfig, live=None,
                               params["wi_up"], params["wo"], group_sizes,
                               layer)
     with jax.named_scope("moe_combine"):
-        if block is None and (live is not None or share):
-            in_group = jnp.arange(t * k) < jnp.sum(group_sizes)
-            rows = jnp.where(in_group[:, None], rows, jnp.zeros((), dt))
-        w_sorted = jnp.take(w.reshape(-1), order, axis=0).astype(dt)
         if block is None:
-            out = jnp.zeros((t, e), dt).at[tok_of_sorted].add(
-                rows * w_sorted[:, None])
+            # without ``live``, every expert held: every row is in a group
+            keep = ((expert_of_row < n_exp).reshape(t, k)
+                    if live is not None or share else None)
+            out = _sum_by_token(rows, order, w, keep)
         else:
-            out = _add_held(rows, w_sorted, tok_of_sorted, in_groups, block,
-                            t)
+            out = _add_held(
+                rows, jnp.take(w.reshape(-1), order, axis=0).astype(dt),
+                tok_of_sorted, in_groups, block, t)
         if cfg.moe_zero_experts:
             out = out + tokens.astype(dt) * jnp.sum(
                 jnp.where(is_zero, w, 0.0), axis=-1, keepdims=True).astype(dt)

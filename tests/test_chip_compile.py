@@ -923,16 +923,19 @@ def test_gated_delta_rule_compiles_at_the_cells_shapes(one_chip, as_tpu):
 # kernels are in, no source locations) of two configurations WITHOUT linear
 # layers at small presets, 4 slots x 2 steps, pages of 8, as the parent of
 # PR 49 traced them (`git archive 897d6f5`): the delta rule's kernel and
-# its row list are traced for a model with linear layers alone
+# its row list are traced for a model with linear layers alone. The two
+# ``olmoe`` entries are PR 52's own tree (the routed block's combine became a
+# gather by token there, so they moved with it; the parent, 14b980e, traced
+# 7be83967... and 6856f45e...); the two ``mistral`` ones held through it
 NO_LINEAR_LAYERS_JAXPRS = {
     ("mistral", 1):
         "43857c5ebb989a535af62e12c7e65cbcf339846e75a65125498dc496b52cd23f",
     ("mistral", 16):
         "6b658872dfc0a68520d289bce6e9f471790565eb7974162be5567b7265ec9d68",
     ("olmoe", 1):
-        "7be839670cdd67660d345e8d61c0c98157515186844121ae01a7556bf187fb10",
+        "638a66309912d43661eb2c340198d04b8eea1cc42dc7e76a954102cca7aadd8f",
     ("olmoe", 16):
-        "6856f45ee69612a7fb6226fb7384eb2e89cb645532383ccabcf4c038eefe5f52",
+        "3004f47a62c75e0313fc330f6a86fdc5b2bf28752d015524054ff819215f5ad2",
 }
 NO_LINEAR_LAYERS = {
     "mistral": ("mistral-7b", dict(num_kv_heads=2, intermediate_size=128,
@@ -1052,11 +1055,12 @@ def test_qwen3_next_frame_programs_fit_the_chip(one_chip, as_tpu, width):
     assert 9.4e9 < m.argument_size_in_bytes < 9.8e9
     assert total < 15.75e9, total
     # the narrow program holds what it held before a wide step's rows were
-    # told apart (0.082 GB); the wide one, whose delta rule is the kernel
+    # told apart (0.082 GB) and ~4 MB for the float32 sum of a token's 10
+    # gathered rows (PR 52: 0.086); the wide one, whose delta rule is the kernel
     # (the states in place, its output and the gates by block its only
     # temporaries), 0.366 GB where two gathered rows a trip of XLA's
     # chunked form held 0.389 and every row through it at once 0.536
-    assert m.temp_size_in_bytes < (0.083e9 if width == 1 else 0.40e9)
+    assert m.temp_size_in_bytes < (0.087e9 if width == 1 else 0.40e9)
 
 
 def test_chip_smoke_fails_without_a_chip():

@@ -199,6 +199,18 @@ def _routed_block(held, dtype):
     return cfg, params, x
 
 
+def _poison_past_the_groups(monkeypatch):
+    """The products' rows past the groups read NaN, as the chip may leave
+    them (on the CPU ``ragged_dot`` writes zeros there)."""
+    real = G.moe_expert_ffn
+
+    def poisoned(tokens, wg, wu, wo, sizes, layer=None):
+        rows = real(tokens, wg, wu, wo, sizes, layer)
+        return jnp.where((jnp.arange(rows.shape[0]) < jnp.sum(sizes))[:, None],
+                         rows, jnp.nan)
+    monkeypatch.setattr(G, "moe_expert_ffn", poisoned)
+
+
 #: (experts held of 16 outputs, live mask over the 40 positions, rows a
 #: trip): the rows in groups ``n`` beside the 160 selection rows
 BOUNDED = {
@@ -246,13 +258,7 @@ def test_bounded_dispatch_and_combine_move_the_groups_rows(monkeypatch, case,
     assert (n == 0) == (case in ("none-picked", "nothing-live"))
     assert n == {"all-held": 160, "all-held-exact-blocks": 160,
                  "all-held-multiple": 96}.get(case, n)
-    real = G.moe_expert_ffn
-
-    def poisoned(tokens, wg, wu, wo, sizes, layer=None):
-        rows = real(tokens, wg, wu, wo, sizes, layer)
-        return jnp.where((jnp.arange(rows.shape[0]) < jnp.sum(sizes))[:, None],
-                         rows, jnp.nan)
-    monkeypatch.setattr(G, "moe_expert_ffn", poisoned)
+    _poison_past_the_groups(monkeypatch)
     monkeypatch.setattr(G, "unwritten", lambda rows, like: jnp.full(
         (rows, like.shape[1]), jnp.nan, like.dtype))
     monkeypatch.setattr(L, "_move_block", lambda cfg, rows: block)
@@ -269,6 +275,165 @@ def test_bounded_dispatch_and_combine_move_the_groups_rows(monkeypatch, case,
     assert not np.asarray(got[0], np.float32)[~np.asarray(live)].any() \
         or cfg.moe_zero_experts        # a zero expert answers a dead token too
     assert int(L.moe_rows_moved(cfg, got[2], 160)) == -(-n // block) * block
+
+
+def _scatter_add_reference(params, x, cfg, live):
+    """A routed block written plainly, nothing of ``layers.py`` in it: every
+    selection's expert applied to its token (the expert's matrices gathered
+    a selection), the results weighted and scatter-added by token; a
+    selection that is dead or not held adds nothing; a zero expert hands the
+    token back under its weight."""
+    from deepspeed_tpu.moe.sharded_moe import topk_gating_grouped
+    k, n_exp = cfg.num_experts_per_tok, cfg.num_experts
+    tokens = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
+    t = tokens.shape[0]
+    idx, w, _ = topk_gating_grouped(
+        tokens @ params["router"].astype(jnp.float32), k=k,
+        normalize=cfg.moe_norm_topk)
+    local = idx.reshape(-1) - cfg.moe_expert_first
+    keep = (local >= 0) & (local < n_exp)
+    if live is not None:
+        keep &= jnp.repeat(live.reshape(-1), k)
+    at = jnp.clip(local, 0, n_exp - 1)
+    rows = jnp.repeat(tokens, k, axis=0)                       # (T*k, E)
+    f32 = lambda name: params[name].astype(jnp.float32)[at]    # (T*k, ., .)
+    h = jax.nn.silu(jnp.einsum("re,ref->rf", rows, f32("wi_gate"))) \
+        * jnp.einsum("re,ref->rf", rows, f32("wi_up"))
+    y = jnp.einsum("rf,rfe->re", h, f32("wo")) * w.reshape(-1, 1)
+    out = jnp.zeros_like(tokens).at[jnp.arange(t * k) // k].add(
+        jnp.where(keep[:, None], y, 0.0))
+    if cfg.moe_zero_experts:
+        is_zero = idx >= cfg.moe_router_width - cfg.moe_zero_experts
+        out = out + tokens * jnp.sum(jnp.where(is_zero, w, 0.0), axis=-1,
+                                     keepdims=True)
+    return out.reshape(x.shape)
+
+
+#: (experts held of 16 outputs, live mask, selections a token): every case
+#: runs the gather by token (``_move_block`` is None at 160 rows)
+BY_TOKEN = {
+    "all-live": (16, "all", 4),
+    "dead-poisoned": (16, "some", 4),
+    "nothing-live": (16, "none", 4),
+    "held-share-narrow": (4, "some", 4),     # zero experts on, most picks absent
+    "top-1": (16, "some", 1),
+    "training-no-live": (16, None, 4),
+}
+
+
+def _by_token_case(monkeypatch, case, dtype):
+    from deepspeed_tpu.models import layers as L
+    held, mask, k = BY_TOKEN[case]
+    cfg, params, x = _routed_block(held, dtype)
+    cfg = cfg.replace(num_experts_per_tok=k)
+    assert L._move_block(cfg, 40 * k) is None
+    live = {"all": jnp.ones((4, 10), bool), "none": jnp.zeros((4, 10), bool),
+            "some": jnp.arange(40).reshape(4, 10) % 7 % 2 == 0,
+            None: None}[mask]
+    _poison_past_the_groups(monkeypatch)
+    return L, cfg, params, x, live
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(BY_TOKEN))
+def test_the_combine_by_token_is_the_scatter_add(monkeypatch, case, dtype):
+    """Where ``_move_block`` is None the combine gathers a token's k rows
+    over the inverse permutation and sums them under its weights: the
+    outputs of a plain scatter-add over every selection, with the rows past
+    the groups poisoned as the chip leaves them: finite, and a dead token's
+    row zero (or what the zero experts answer)."""
+    L, cfg, params, x, live = _by_token_case(monkeypatch, case, dtype)
+    got = jax.jit(lambda p, x, l: L.apply_moe_grouped(p, x, cfg, live=l))(
+        params, x, live)
+    want = _scatter_add_reference(params, x, cfg, live)
+    out = np.asarray(got[0], np.float32)
+    assert np.isfinite(out).all()
+    tol = 2e-5 if dtype == "float32" else 2.0 ** -6
+    np.testing.assert_allclose(out, np.asarray(want), rtol=tol,
+                               atol=tol * float(jnp.abs(want).max()))
+    if live is not None:
+        dead = ~np.asarray(live)
+        assert not out[dead].any() or cfg.moe_zero_experts
+        assert out[~dead].any() or case == "nothing-live"
+        assert int(L.moe_rows_moved(cfg, got[2], 40 * cfg.num_experts_per_tok)
+                   ) == 40 * cfg.num_experts_per_tok
+    if case == "held-share-narrow":
+        picked, zero, absent = (int(v) for v in got[3])
+        assert absent > int(got[2].sum()) > 0 and zero > 0
+
+
+@pytest.mark.parametrize("case", list(BY_TOKEN))
+def test_the_combine_by_token_has_the_scatter_adds_gradient(monkeypatch,
+                                                            case):
+    """``jax.grad`` through the gather (a scatter over rows that are all
+    different, and the weights in token order) against the reference's, for
+    the tokens, the router and the three expert matrices."""
+    L, cfg, params, x, live = _by_token_case(monkeypatch, case, "float32")
+    cot = jax.random.normal(jax.random.PRNGKey(2), x.shape, jnp.float32)
+    got = jax.grad(lambda p, x: jnp.sum(
+        L.apply_moe_grouped(p, x, cfg, live=live)[0] * cot),
+        argnums=(0, 1))(params, x)
+    want = jax.grad(lambda p, x: jnp.sum(
+        _scatter_add_reference(p, x, cfg, live) * cot),
+        argnums=(0, 1))(params, x)
+    for name in ("router",) + L.EXPERT_MATRICES:
+        np.testing.assert_allclose(
+            got[0][name], want[0][name], rtol=2e-4,
+            atol=2e-5 * max(1e-3, float(jnp.abs(want[0][name]).max())),
+            err_msg=name)
+    np.testing.assert_allclose(got[1], want[1], rtol=2e-4, atol=2e-5 * float(
+        jnp.abs(want[1]).max() + 1e-3))
+    if case != "nothing-live":
+        assert float(jnp.abs(got[0]["wo"]).max()) > 0
+
+
+def _primitives_under(jaxpr, scope, inside=False):
+    """(primitive name, output shapes) of every equation under the named
+    scope, through every nested jaxpr (a scan's body, a jitted helper)."""
+    for eqn in jaxpr.eqns:
+        here = inside or scope in str(eqn.source_info.name_stack)
+        if here:
+            yield eqn.primitive.name, [v.aval.shape for v in eqn.outvars]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives_under(sub, scope, here)
+
+
+@pytest.mark.parametrize("width", [1, 16], ids=["narrow", "wide"])
+def test_a_served_program_that_holds_every_expert_has_no_scatter_add(width):
+    """The frame programs of an all-held routed model (OLMoE's layers at a
+    small size): under ``moe_combine`` there is a gather and a sum, no
+    ``scatter-add`` and no (tokens, hidden) of zeros to add into; the loops
+    of the bounded form are not there either."""
+    from deepspeed_tpu.inference.v2.model_runner import PagedModelRunner
+    from deepspeed_tpu.models import build_model, get_config
+    cfg = get_config("olmoe-1b-7b", vocab_size=256, hidden_size=64,
+                     max_seq_len=256, dtype="float32", num_layers=2,
+                     num_heads=4, num_kv_heads=4, intermediate_size=32,
+                     num_experts=8, num_experts_per_tok=2)
+    model = build_model(cfg)
+    runner = PagedModelRunner(model, 8, 32)
+    slots, i32, sds = 4, jnp.int32, jax.ShapeDtypeStruct
+    row, flag = sds((slots,), i32), sds((slots,), jnp.bool_)
+    key = jax.random.PRNGKey(0)
+    pool = sds((cfg.num_layers, cfg.kv_heads, 33, 8, cfg.dims_per_head),
+               jnp.float32)
+    jaxpr = runner._build_frame_loop().trace(
+        model.abstract_params(), sds((slots, 256), i32), row, row, row,
+        sds((slots,), jnp.float32), sds((slots, 32), i32), row, row, row,
+        flag, flag, flag, sds((runner.n_stats,), i32),
+        sds(key.shape, key.dtype), pool, pool, width=width, steps=2,
+        greedy=True, n_steps=sds((), i32)).jaxpr
+    found = list(_primitives_under(jaxpr.jaxpr, "moe_combine"))
+    names = {name for name, _ in found}
+    assert {"gather", "reduce_sum"} <= names, names
+    assert not names & {"scatter-add", "scatter_add", "while"}, names
+    tokens = slots * width
+    assert not [shapes for name, shapes in found
+                if name == "broadcast_in_dim"
+                and shapes == [(tokens, cfg.hidden_size)]]
+    # the reader reads something: the dispatch's gather is under its scope
+    assert "gather" in {n for n, _ in _primitives_under(jaxpr.jaxpr,
+                                                        "moe_dispatch")}
 
 
 def test_a_block_where_every_row_is_in_a_group_keeps_the_unbounded_lines(

@@ -165,10 +165,13 @@ def test_container_maps_a_random_lfm2_moe_state_dict(whole):
 #: steps, pages of 8, at the PARENT commit (2350b40, PR 50's anchor): the
 #: walk, the carry and the stat lanes this PR said of "what each kind
 #: keeps" trace the programs they traced (mistral's, OLMoE's and GLM's are
-#: pinned in ``tests/test_sdar_serving.py``)
+#: pinned in ``tests/test_sdar_serving.py``). Re-pinned by PR 52 to its own
+#: tree: at this size (256 selection rows, under two blocks) both programs
+#: run the combine that became a gather by token (at 14b980e: 5fa82809... and
+#: b8f53c6f...); at the cell's size the wide rungs' loops trace as they did
 QWEN3_NEXT_PARENTS = {
-    1: "5fa82809b98ac38dbb2b1d56fccc00392aa36ea82e1092fffbec55bdf826fe08",
-    16: "b8f53c6f7ab23a9571e62ce92251417f0e7b16338ba3001f4ac0887ef512f000",
+    1: "a7e7562b3f01e951e07edf605bc8922e6fb3742adf81fd07d12fdbab9f58fdd9",
+    16: "51f0d2b507d7a1756e5ae1008399b99ce025af4254576eac6e3a5c78573d3df3",
 }
 
 

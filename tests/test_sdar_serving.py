@@ -974,7 +974,10 @@ def test_container_round_trips_a_checkpoint_in_the_published_layout(params):
 #: sha256 of the lowered text of the frame program at 4 slots x 2 steps,
 #: pages of 8, at the PARENT commit (54d5e4d, PR 46): no frame program of
 #: another model changes with the block mask, the block carry or the
-#: vector's new lanes
+#: vector's new lanes. The ``olmoe`` and ``glm`` entries are PR 52's own
+#: tree (their routed blocks' combine became a gather by token; at 14b980e
+#: they read 985c4f63... / b27895b9... and 1db826b1... / 93642342...); the
+#: two ``mistral`` ones are still the commit's above
 SMALL = dict(vocab_size=256, hidden_size=64, max_seq_len=256, dtype="float32")
 FAMILIES = {
     "mistral": ("mistral-7b", dict(
@@ -995,13 +998,13 @@ PARENTS = {
     ("mistral", 16):
         "d0d1a945be5a107ec9671f2088842e1b73a39ffec7419f015b24e7085bf4bb91",
     ("olmoe", 1):
-        "985c4f6380f15fa6f60faea92d67faa421e37f08793a81f13f9d12b1ea7bf458",
+        "086813a69a469c631d004aa7b80841bedd45d3bdb5933cad2502b4f308979851",
     ("olmoe", 16):
-        "b27895b9429f37fe0845f07cd52d8951ccd837e76ae06377ff692d52b9f90d5c",
+        "8c7491bf84b5957f17262cd1a9c90b2db905b63dc135476997b970c7459cab3d",
     ("glm", 1):
-        "1db826b1ca5c45a1dbac2e0088f927d6e4315e91b860820273e77c727b4e0bb0",
+        "07bada29e5a50e1dbf0cd8b6bd55dc9265091cfe7fc8c7330dc67851c7d92774",
     ("glm", 16):
-        "9364234299b7a8bc7aced334cda71e29d4eff167b319d0ad621907f7c1c4a820",
+        "84c80e2c99708882f3b467fe69c7a0fc7db511151ccb3cef082ea958f8307522",
 }
 
 
